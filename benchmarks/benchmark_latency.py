@@ -73,8 +73,6 @@ def main(args):
 
 
 if __name__ == "__main__":
-    from intellillm_tpu.utils import apply_platform_override
-    apply_platform_override()
     parser = argparse.ArgumentParser(
         description="Benchmark the latency of processing a single batch "
         "of requests till completion.")

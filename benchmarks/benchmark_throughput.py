@@ -88,8 +88,6 @@ def main(args):
 
 
 if __name__ == "__main__":
-    from intellillm_tpu.utils import apply_platform_override
-    apply_platform_override()
     parser = argparse.ArgumentParser(description="Benchmark throughput.")
     parser.add_argument("--backend", type=str, default="intellillm",
                         choices=["intellillm", "hf"])

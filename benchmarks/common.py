@@ -90,19 +90,20 @@ def build_llm(args):
     return llm
 
 
-def save_dummy_checkpoint(model_spec: str, out_dir: str,
+def save_dummy_checkpoint(model_spec, out_dir: str,
                           tokenizer_vocab: Optional[int] = None) -> str:
-    """Materialize a `dummy:SIZE` spec as an on-disk checkpoint dir the
-    servers can boot with `--load-format dummy`: the Llama config.json
-    plus a self-contained word-level tokenizer (no hub access; decode →
-    encode roundtrips exactly, so client- and server-side token counts
-    agree in `benchmark_serving.py`)."""
+    """Materialize a `dummy:SIZE` spec (or an HF config object) as an
+    on-disk checkpoint dir the servers can boot with `--load-format
+    dummy`: the config.json plus a self-contained word-level tokenizer
+    (no hub access; decode → encode roundtrips exactly, so client- and
+    server-side token counts agree in `benchmark_serving.py`)."""
     from tokenizers import Tokenizer
     from tokenizers.models import WordLevel
     from tokenizers.pre_tokenizers import Whitespace
     from transformers import PreTrainedTokenizerFast
 
-    cfg = dummy_hf_config(model_spec)
+    cfg = (dummy_hf_config(model_spec) if isinstance(model_spec, str)
+           else model_spec)
     cfg.save_pretrained(out_dir)
     # Cover the full model vocab so detokenizing dummy-weight samples
     # (uniform over vocab_size ids) never hits an out-of-range token.

@@ -33,6 +33,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from benchmarks.benchmark_serving import (build_requests,  # noqa: E402
                                           compute_metrics, run_benchmark)
 from benchmarks.common import save_dummy_checkpoint  # noqa: E402
+from intellillm_tpu.utils import child_process_env  # noqa: E402
 
 
 def launch_server(model_dir: str, args,
@@ -76,7 +77,7 @@ def launch_server(model_dir: str, args,
             cmd += ["--spec-k-min", str(args.spec_k_min)]
         if args.spec_k_max is not None:
             cmd += ["--spec-k-max", str(args.spec_k_max)]
-    env = dict(os.environ)
+    env = child_process_env()
     env.setdefault("HF_HUB_OFFLINE", "1")
     # Server logs go to a file, not an undrained pipe (a full pipe buffer
     # would block the server's logging mid-benchmark).
@@ -709,11 +710,14 @@ def run_diurnal(args, model_dir, tokenizer) -> dict:
 def launch_generate_replica(model_dir: str, args, port: int,
                             log_path: str,
                             role: str = None,
-                            extra_args=None) -> subprocess.Popen:
+                            extra_args=None,
+                            index: int = 0) -> subprocess.Popen:
     """Launch one demo api_server replica (plain /generate protocol —
     the surface the router fronts). `role` maps to --replica-role for
     disaggregated fleets; `extra_args` appends raw CLI flags (the
-    multi-tenant scenario passes the LoRA/fairness knobs through it)."""
+    multi-tenant scenario passes the LoRA/fairness knobs through it).
+    Replica `index` of a fleet gets its own chip through its
+    environment (utils.child_process_env)."""
     cmd = [
         sys.executable, "-m", "intellillm_tpu.entrypoints.api_server",
         "--model", model_dir,
@@ -737,7 +741,7 @@ def launch_generate_replica(model_dir: str, args, port: int,
         cmd += ["--replica-role", role]
     if extra_args:
         cmd += list(extra_args)
-    env = dict(os.environ)
+    env = child_process_env(child_index=index)
     env.setdefault("HF_HUB_OFFLINE", "1")
     log = open(log_path, "wb")
     return subprocess.Popen(cmd, env=env, stdout=log,
@@ -765,7 +769,8 @@ def run_fleet(args, model_dir: str, tokenizer) -> dict:
         for i in range(args.num_replicas):
             port = args.replica_base_port + i
             log_path = f"{args.server_log}.replica{i}"
-            proc = launch_generate_replica(model_dir, args, port, log_path)
+            proc = launch_generate_replica(model_dir, args, port, log_path,
+                                           index=i)
             replicas.append((f"replica-{i}", f"http://127.0.0.1:{port}",
                              proc, log_path))
         for name, base, proc, log_path in replicas:
@@ -780,7 +785,7 @@ def run_fleet(args, model_dir: str, tokenizer) -> dict:
             "--block-size", str(args.block_size),
             "--health-interval", "1.0",
         ]
-        env = dict(os.environ)
+        env = child_process_env()
         env.setdefault("HF_HUB_OFFLINE", "1")
         log = open(router_log, "wb")
         router_proc = subprocess.Popen(router_cmd, env=env, stdout=log,
@@ -880,7 +885,7 @@ def _run_role_fleet(args, model_dir, tokenizer, roles, label,
             port = base_port + i
             log_path = f"{args.server_log}.{label}{i}"
             proc = launch_generate_replica(model_dir, args, port, log_path,
-                                           role=role)
+                                           role=role, index=i)
             replicas.append((f"{label}-{i}-{role}",
                              f"http://127.0.0.1:{port}", proc, log_path))
         for name, base, proc, log_path in replicas:
@@ -896,7 +901,7 @@ def _run_role_fleet(args, model_dir, tokenizer, roles, label,
             "--block-size", str(args.block_size),
             "--health-interval", "1.0",
         ]
-        env = dict(os.environ)
+        env = child_process_env()
         env.setdefault("HF_HUB_OFFLINE", "1")
         log = open(router_log, "wb")
         router_proc = subprocess.Popen(router_cmd, env=env, stdout=log,

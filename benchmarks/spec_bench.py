@@ -29,16 +29,17 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+# stdlib-only import: this parent stays off JAX, its children need the chip.
+from intellillm_tpu.utils import child_process_env  # noqa: E402
 
 _MODE_TIMEOUT_S = 2400.0
 
 
 def _run_bench_child(env: dict, timeout_s: float):
     """Run one bench.py mode in its OWN process group; on timeout SIGKILL
-    the whole group (same hardening as bench.py's backend probe: the TPU
-    runtime forks helpers that hold the device and the stderr pipe, so
-    `subprocess.run(timeout=...)` killing only the direct child leaves
-    the follow-up mode hanging on a wedged device). Returns
+    the whole group (the TPU runtime forks helpers that hold the device
+    and the stderr pipe, so `subprocess.run(timeout=...)` killing only
+    the direct child leaves the follow-up mode without a chip). Returns
     (returncode, stdout, stderr); raises TimeoutExpired carrying the
     output produced before the kill."""
     proc = subprocess.Popen(
@@ -65,7 +66,7 @@ def _run_bench_child(env: dict, timeout_s: float):
 def run_mode(mode: str, args) -> dict:
     """Each mode runs in a subprocess: one TPU process at a time, and the
     force-accept env must not leak between modes."""
-    env = dict(os.environ)
+    env = child_process_env()
     env["INTELLILLM_BENCH_SIZE"] = "7b"
     env["INTELLILLM_BENCH_BS"] = str(args.bs)
     env["INTELLILLM_BENCH_OUT"] = str(args.out)

@@ -222,7 +222,7 @@ class AsyncLLMEngine:
             self.engine.abort_request(finished_requests)
 
         # The device step blocks; run it off-loop. step_pipelined keeps
-        # the device busy across the fetch RTT (see llm_engine.py).
+        # the device busy across the fetch (see llm_engine.py).
         loop = asyncio.get_event_loop()
         step_fn = (self.engine.step_pipelined
                    if self.engine.pipeline_enabled else self.engine.step)

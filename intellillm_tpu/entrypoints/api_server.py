@@ -227,8 +227,6 @@ def build_app(enable_profiling: bool = False) -> web.Application:
 
 def main():
     global engine
-    from intellillm_tpu.utils import apply_platform_override
-    apply_platform_override()
     parser = argparse.ArgumentParser()
     parser.add_argument("--host", type=str, default="0.0.0.0")
     parser.add_argument("--port", type=int, default=8000)

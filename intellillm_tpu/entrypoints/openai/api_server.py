@@ -227,8 +227,6 @@ async def init_serving(engine: AsyncLLMEngine, served_model: str,
 
 
 def main():
-    from intellillm_tpu.utils import apply_platform_override
-    apply_platform_override()
     args = make_arg_parser().parse_args()
     engine_args = AsyncEngineArgs.from_cli_args(args)
     served_model = args.served_model_name or args.model

@@ -94,6 +94,7 @@ def request_to_sampling_params(request) -> SamplingParams:
         logprobs=getattr(request, "logprobs", None),
         skip_special_tokens=request.skip_special_tokens,
         spaces_between_special_tokens=request.spaces_between_special_tokens,
+        seed=request.seed,
     )
 
 

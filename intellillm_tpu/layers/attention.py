@@ -72,6 +72,9 @@ class AttentionMetadata:
     # sets this for single-prompt, no-prefix, no-ALiBi, no-sliding-window
     # prefills past the configured length threshold.
     sp: Optional[tuple] = struct.field(pytree_node=False, default=None)
+    # Static: the runner's device mesh, so the Pallas seams can run per
+    # kv-head shard when it spans chips (ops/dispatch.shard_over_heads).
+    mesh: Optional[object] = struct.field(pytree_node=False, default=None)
 
 
 class PagedAttention:
@@ -173,7 +176,7 @@ class PagedAttention:
             out, k_cache, v_cache = ragged_fused_attention(
                 query, flat_k, flat_v, k_cache, v_cache, slots,
                 attn_metadata.block_tables, attn_metadata.context_lens,
-                self.scale, self.alibi_slopes)
+                self.scale, self.alibi_slopes, mesh=attn_metadata.mesh)
         return out, (k_cache, v_cache)
 
     def _staged_decode(self, query, key, value, kv_cache, attn_metadata):
@@ -196,7 +199,7 @@ class PagedAttention:
         out_pool, lse_pool = _decode_dispatch(
             query, k_pool, v_pool, attn_metadata.block_tables,
             attn_metadata.context_lens, self.scale, self.alibi_slopes,
-            return_lse=True)
+            return_lse=True, mesh=attn_metadata.mesh)
         out_stage, lse_stage = staged_decode_attention(
             query, k_stage, v_stage, k_idx, self.scale)
         out = merge_attention_parts(out_pool, lse_pool, out_stage, lse_stage)
@@ -242,15 +245,27 @@ def _prefill_dispatch(query, key, value, context_lens, scale, sliding_window,
 
 
 def _decode_dispatch(q, k_cache, v_cache, block_tables, context_lens, scale,
-                     alibi_slopes, return_lse: bool = False):
+                     alibi_slopes, return_lse: bool = False, mesh=None):
     """Choose the decode kernel: Pallas paged attention on TPU, jnp gather
-    reference elsewhere (CPU tests / interpreters)."""
+    reference elsewhere (CPU tests / interpreters). `mesh` (multi-chip
+    runs) shards the kernel over kv heads."""
     from intellillm_tpu.ops import dispatch
     if dispatch.use_pallas():
         from intellillm_tpu.ops.pallas.paged_attention import paged_attention
-        return paged_attention(q, k_cache, v_cache, block_tables,
-                               context_lens, scale, alibi_slopes,
-                               return_lse=return_lse)
+
+        def kernel(q, k_cache, v_cache, tables, ctx, slopes):
+            # The kernel computes the logsumexp either way.
+            return paged_attention(q, k_cache, v_cache, tables, ctx, scale,
+                                   slopes, return_lse=True)
+
+        out, lse = dispatch.shard_over_heads(
+            kernel, mesh, k_cache.shape[1],
+            in_head_dims=(2, 1, 1, None, None,
+                          None if alibi_slopes is None else 0),
+            out_head_dims=(2, 1))(
+                q, k_cache, v_cache, block_tables, context_lens,
+                alibi_slopes)
+        return (out, lse) if return_lse else out
     return decode_attention_reference(q, k_cache, v_cache, block_tables,
                                       context_lens, scale, alibi_slopes,
                                       return_lse=return_lse)

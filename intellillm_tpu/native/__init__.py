@@ -3,13 +3,15 @@
 Role parity: the reference builds its native runtime pieces (`csrc/`)
 at install time via setup.py; here the single C++ translation unit
 (`native/batch_prep.cc`) is compiled lazily with g++ on first use and
-cached next to the source. Everything degrades to the pure-Python paths
-when no toolchain/.so is available (`is_available()` returns False), so
-the engine never hard-depends on the native build.
+cached next to the source under a name that carries the source's hash.
+Without a toolchain the pure-Python paths compute the same arrays
+(`is_available()` returns False); which path a process took is logged
+once and reported by `path_taken()`.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
@@ -23,53 +25,76 @@ logger = init_logger(__name__)
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "native", "batch_prep.cc")
-_LIB = os.path.join(_REPO_ROOT, "native", "libbatch_prep.so")
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+# "native" or "python": which batch-prep path this process uses (None
+# until the first call decides).
+_path_taken: Optional[str] = None
 
 _i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
 _i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
 
 
+def library_path(src: str = _SRC) -> str:
+    """Where the library built from `src` lives: the name carries a hash
+    of the source, so a library built from other source — a stale or
+    foreign .so lying in the tree — is never picked up, whatever its
+    mtime."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(os.path.dirname(src),
+                        f"libbatch_prep.{digest}.so")
+
+
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _path_taken
     if _tried:
         return _lib
     _tried = True
     from intellillm_tpu.utils import parse_env_flag
     if parse_env_flag(os.environ.get("INTELLILLM_DISABLE_NATIVE")):
+        _path_taken = "python"
+        logger.info("Batch prep: pure-Python path "
+                    "(INTELLILLM_DISABLE_NATIVE)")
         return None
     try:
-        if (not os.path.exists(_LIB)
-                or (os.path.exists(_SRC) and
-                    os.path.getmtime(_SRC) > os.path.getmtime(_LIB))):
-            if not os.path.exists(_SRC):
-                return None
+        lib_path = library_path()
+        if not os.path.exists(lib_path):
             # Build to a per-pid temp path and rename: concurrent
             # processes must never dlopen a half-written .so.
-            tmp = f"{_LIB}.{os.getpid()}.tmp"
+            tmp = f"{lib_path}.{os.getpid()}.tmp"
             subprocess.run(
                 ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
                 check=True, capture_output=True)
-            os.replace(tmp, _LIB)
-            logger.info("Built native batch-prep library at %s", _LIB)
-        lib = ctypes.CDLL(_LIB)
-        lib.build_decode_batch.argtypes = [
-            _i32p, _i64p, _i32p, _i32p, _i32p,
-            ctypes.c_int64, ctypes.c_int64,
-            _i32p, _i32p, _i32p, _i32p,
-        ]
-        lib.build_prompt_slots.argtypes = [
-            _i32p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int32, _i32p,
-        ]
-        _lib = lib
-    except Exception as e:  # no compiler / load failure → Python fallback
-        logger.warning("Native batch-prep unavailable (%s); using the "
-                       "pure-Python path", e)
-        _lib = None
+            os.replace(tmp, lib_path)
+        lib = ctypes.CDLL(lib_path)
+    except (OSError, subprocess.CalledProcessError) as e:
+        # No source, no compiler, or a library that will not load: the
+        # Python path computes the same arrays.
+        _path_taken = "python"
+        logger.info("Batch prep: pure-Python path (native library "
+                    "unavailable: %s)", e)
+        return None
+    lib.build_decode_batch.argtypes = [
+        _i32p, _i64p, _i32p, _i32p, _i32p,
+        ctypes.c_int64, ctypes.c_int64,
+        _i32p, _i32p, _i32p, _i32p,
+    ]
+    lib.build_prompt_slots.argtypes = [
+        _i32p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int32, _i32p,
+    ]
+    _lib = lib
+    _path_taken = "native"
+    logger.info("Batch prep: native library %s", lib_path)
     return _lib
+
+
+def path_taken() -> str:
+    """"native" or "python" — decided (and logged) once per process."""
+    _load()
+    return _path_taken
 
 
 def is_available() -> bool:

@@ -196,6 +196,7 @@ class EfficiencyTracker:
         self._lock = threading.Lock()
         self._warmup_depth = 0
         self._warmup_excluded = 0
+        self._co_batched_steps = 0
         self._tokens: Dict[str, Dict[str, int]] = {
             phase: {kind: 0 for kind in TOKEN_KINDS} for phase in PHASES}
         self._dispatches: Dict[str, int] = {phase: 0 for phase in PHASES}
@@ -289,6 +290,12 @@ class EfficiencyTracker:
                 self._warmup_depth -= 1
 
     # --- record side (model runner / engine hot path) ---------------------
+
+    def record_co_batched_step(self) -> None:
+        """One mixed step carried prefill-chunk rows AND decode rows."""
+        with self._lock:
+            if self._warmup_depth == 0:
+                self._co_batched_steps += 1
 
     def record_dispatch(self, phase: str, real_rows: int, padded_rows: int,
                         *, real_tokens: int, padded_tokens: int,
@@ -436,6 +443,7 @@ class EfficiencyTracker:
                                  if real + pad else None),
                 "fill_ratio_avg": fill_avg,
                 "dispatches": dict(self._dispatches),
+                "co_batched_steps": self._co_batched_steps,
                 "warmup_excluded_dispatches": self._warmup_excluded,
                 "top_waste": buckets[:top_n],
             }

@@ -349,10 +349,12 @@ class KernelLedger:
             "hbm_generated_code_bytes": None,
             "hbm_peak_bytes": None,
             "compile_seconds": round(float(elapsed), 6),
+            "introspect_seconds": None,
             "dispatches": 1,
             "analysis": "skipped",
         }
         if pending.introspect:
+            t0 = time.monotonic()
             try:
                 self._introspect_into(entry, pending)
             except Exception as e:
@@ -361,6 +363,8 @@ class KernelLedger:
                     "Kernel ledger: introspection failed for %s %s (%s); "
                     "entry fields stay null.", pending.program,
                     entry["bucket"], e)
+            # What the second lower+compile of this program cost.
+            entry["introspect_seconds"] = round(time.monotonic() - t0, 6)
         with self._lock:
             self._entries[(pending.program, entry["bucket"])] = entry
             self._account_flops_locked(entry)

@@ -2,8 +2,8 @@
 
 The flight recorder (obs/flight_recorder.py) is an in-memory ring — it
 answers "what just happened" but vanishes with the process, which is
-exactly when a post-mortem needs it (BENCH_r04/r05 went dark on hangs
-with no artifact). This module adds two durable escape hatches:
+exactly when a post-mortem needs it. This module adds two durable
+escape hatches:
 
 - `TraceSink`: a tail-sampling JSONL exporter. When a request reaches a
   terminal flight-recorder event, obs/slo.py hands the finished trace
@@ -17,9 +17,8 @@ with no artifact). This module adds two durable escape hatches:
 - `flush_black_box()`: a crash-safe dump of everything the in-memory
   observability stack knows — live + recently-finished traces, watchdog
   state and stall reports, the SLO summary — written as one JSON file.
-  bench.py calls it from its failure/watchdog paths so a hung round
-  leaves an artifact; `install_black_box_handlers()` hooks fatal
-  signals for long-running servers.
+  `install_black_box_handlers()` hooks fatal signals for long-running
+  servers and for bench.py.
 
 Config (environment; documented in docs/observability.md):
 

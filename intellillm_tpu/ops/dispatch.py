@@ -74,6 +74,35 @@ def kernel_selection() -> Dict[str, object]:
     return sel
 
 
+def shard_over_heads(fn, mesh, num_kv_heads: int, in_head_dims,
+                     out_head_dims):
+    """Wrap a Pallas seam for a multi-chip mesh. Mosaic kernels cannot be
+    partitioned by the compiler ("wrap the call in a shard_map"), so each
+    chip runs the kernel on its own kv-head shard of the "model" axis —
+    the axis the pool and the attention projections already shard on.
+    `in_head_dims`/`out_head_dims` give, per argument/result, the index
+    of its head dimension (None = replicated: row metadata). When the
+    kv heads do not divide the axis the pool is replicated
+    (parallel/mesh.shard_kv_cache) and every chip runs all heads. The
+    "data" axis is never named: the pool is replicated over it, so every
+    data replica must apply every row's write."""
+    if mesh is None or mesh.devices.size == 1:
+        return fn
+    from jax.sharding import PartitionSpec as P
+    axis = "model" if num_kv_heads % mesh.shape["model"] == 0 else None
+
+    def spec(head_dim):
+        if head_dim is None or axis is None:
+            return P()
+        return P(*([None] * head_dim + [axis]))
+
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(spec(d) for d in in_head_dims),
+        out_specs=tuple(spec(d) for d in out_head_dims),
+        check_vma=False)
+
+
 def _kernel_flag(kernel: str) -> Optional[bool]:
     from intellillm_tpu.utils import parse_env_flag
     env = _KERNEL_FLAGS[kernel]

@@ -271,8 +271,8 @@ def _paged_attention_call(q_grouped, slopes, k_cache, v_cache, block_tables,
         in_specs=[
             pl.BlockSpec((1, hp, g, d), lambda b_, h_, *_: (b_, h_, 0, 0)),
             pl.BlockSpec((hp, g, 128), lambda b_, h_, *_: (h_, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=(
             pl.BlockSpec((1, hp, g, d), lambda b_, h_, *_: (b_, h_, 0, 0)),

@@ -6,10 +6,12 @@ decode rows (one new token, long paged context) and prefill-chunk rows
 only in their per-row metadata, so a single grid over (row, kv-head
 block) handles both. Per grid step the kernel
 
-1. DMAs the row's new K/V (this head block's slice) into its pool block
+1. writes the row's new K/V (this head block's slice) into its pool block
    at `slot_mapping[row]` — the fused replacement for the separate
-   `ops/kv_cache.reshape_and_cache` scatter pass, saving one full K/V
-   round-trip through HBM per mixed step, and
+   `ops/kv_cache.reshape_and_cache` scatter pass. The pool's pages are
+   `(block_size, D)`-tiled and Mosaic refuses a one-row slice of a tiled
+   memref, so the write is a read-modify-write of the slot's
+   `[HP, BS, D]` slab (see `_page_slab_copies`), and
 2. walks the row's paged prior context with the same double-buffered
    multi-page DMA groups and flat-dot online softmax as
    `ops/pallas/paged_attention.py` (whose `_group_copies` walk it
@@ -18,7 +20,8 @@ block) handles both. Per grid step the kernel
 Write-before-read ordering across rows relies on the sequential grid
 (`dimension_semantics=("arbitrary", "arbitrary")`): chunk rows of the
 same sequence land in batch order, so row i+1's context walk sees row
-i's K/V because row i's write DMA completed inside row i's grid step.
+i's K/V because row i's write-back completed inside row i's grid step
+(and row i+1's slab read-modify-write starts from what row i wrote).
 
 The one hazard is the cross-step prefetch: the last page group of each
 step prefetches the NEXT step's group 0 — *before* that step's own
@@ -26,7 +29,9 @@ cache write. The kernel therefore never reads a row's own token back
 from HBM: the HBM walk is masked to `pos < ctx - 1` and the self-token
 score/value come straight from the VMEM K/V input block, merged into
 the online-softmax accumulators after the walk. (The in-flight prefetch
-may still copy the raced bytes; they are masked out of the math.)
+may still copy the raced bytes; they are masked out of the math. The
+slab write-back rewrites the page's other rows with the bytes they
+already hold, so a prefetch racing it reads the same values either way.)
 
 Numerics contract: callers pass `k_new`/`v_new` already cast to the
 cache dtype — the reference path reads the cache *after* the write, so
@@ -54,25 +59,25 @@ from intellillm_tpu.ops.pallas.paged_attention import (_default_hp,
                                                        _largest_divisor)
 
 _NEG_INF = -1e30
+# SMEM the flattened block tables of one launch may take (of 1 MiB on
+# v5e, shared with the other scalar-prefetch operands).
+_SMEM_TABLE_BYTES = 512 * 1024
 
 
-def _row_write_copies(k_new_ref, v_new_ref, k_hbm_ref, v_hbm_ref, kw_sem,
-                      vw_sem, slot, h0, *, heads_per_block, block_size):
-    """The DMAs writing this row's new K/V (heads h0..h0+HP-1) into its
-    pool slot. slot_mapping carries flat physical slots, so the page is
-    slot // BS with no table lookup. One [D] copy per (row, head):
-    chained single-axis dynamic slices, leading axis at a time."""
-    page = lax.div(slot, block_size)
-    off = lax.rem(slot, block_size)
-    copies = []
-    for hi in range(heads_per_block):
-        copies.append(pltpu.make_async_copy(
-            k_new_ref.at[0].at[hi],
-            k_hbm_ref.at[page].at[h0 + hi].at[off], kw_sem))
-        copies.append(pltpu.make_async_copy(
-            v_new_ref.at[0].at[hi],
-            v_hbm_ref.at[page].at[h0 + hi].at[off], vw_sem))
-    return copies
+def _page_slab_copies(k_hbm_ref, v_hbm_ref, kw_buf, vw_buf, kw_sem, vw_sem,
+                      page, h0, *, heads_per_block, to_hbm: bool):
+    """The two DMAs moving this head block's [HP, BS, D] slab of `page`
+    between the pool and the VMEM write buffers. Whole trailing
+    (BS, D) tiles only: Mosaic refuses a one-row slice of a tiled
+    memref ("Slice shape along dimension 0 must be aligned to tiling"),
+    so the token-granular write is a read-modify-write of the slab."""
+    k_hbm = k_hbm_ref.at[page].at[pl.ds(h0, heads_per_block)]
+    v_hbm = v_hbm_ref.at[page].at[pl.ds(h0, heads_per_block)]
+    if to_hbm:
+        return (pltpu.make_async_copy(kw_buf, k_hbm, kw_sem),
+                pltpu.make_async_copy(vw_buf, v_hbm, vw_sem))
+    return (pltpu.make_async_copy(k_hbm, kw_buf, kw_sem),
+            pltpu.make_async_copy(v_hbm, vw_buf, vw_sem))
 
 
 def _ragged_kernel(
@@ -98,6 +103,8 @@ def _ragged_kernel(
     v_buf,
     k_sem,              # read-DMA semaphores [2]
     v_sem,
+    kw_buf,             # [HP, BS, D] VMEM write-slab buffers
+    vw_buf,
     kw_sem,             # write-DMA semaphores (scalar)
     vw_sem,
     m_scr,              # [HP * G, 128] f32
@@ -124,22 +131,33 @@ def _ragged_kernel(
     # row's own token is merged from VMEM after the walk.
     num_groups = jnp.maximum(lax.div((ctx - 1) + bk - 1, bk), 1)
 
-    def write_copies():
-        return _row_write_copies(k_new_ref, v_new_ref, k_hbm_ref,
-                                 v_hbm_ref, kw_sem, vw_sem, slot,
-                                 hb * hp, heads_per_block=hp,
-                                 block_size=block_size)
-
-    # 1. Write this row's K/V before anything downstream can read it.
-    #    Pad rows (slot < 0) skip both start and wait.
+    # 1. Write this row's K/V before anything downstream can read it:
+    #    fetch the slot's [HP, BS, D] page slab, replace row `off` in
+    #    VMEM, write the slab back. Serial within the (sequential) grid
+    #    step, so the next row of the same sequence reads this row's
+    #    token. Pad rows (slot < 0) skip it entirely.
     @pl.when(slot >= 0)
-    def _start_write():
-        for c in write_copies():
+    def _write():
+        page = lax.div(slot, block_size)
+        off = lax.rem(slot, block_size)
+        slab = functools.partial(
+            _page_slab_copies, k_hbm_ref, v_hbm_ref, kw_buf, vw_buf,
+            kw_sem, vw_sem, page, hb * hp, heads_per_block=hp)
+        for c in slab(to_hbm=False):
             c.start()
-
-    @pl.when(slot >= 0)
-    def _wait_write():
-        for c in write_copies():
+        for c in slab(to_hbm=False):
+            c.wait()
+        is_row = lax.broadcasted_iota(jnp.int32, kw_buf.shape, 1) == off
+        for new_ref, buf in ((k_new_ref, kw_buf), (v_new_ref, vw_buf)):
+            # Select in f32: exact for bf16 and fp8 values, and avoids
+            # packed-dtype selects Mosaic does not lower on v5e.
+            new = new_ref[0].astype(jnp.float32)[:, None, :]
+            buf[...] = jnp.where(is_row, new,
+                                 buf[...].astype(jnp.float32)
+                                 ).astype(buf.dtype)
+        for c in slab(to_hbm=True):
+            c.start()
+        for c in slab(to_hbm=True):
             c.wait()
 
     def copies(b_, hb_, g_, buf_):
@@ -270,19 +288,21 @@ def _ragged_call(q_grouped, slopes, k_new, v_new, k_cache, v_cache,
             pl.BlockSpec((hp, g, 128), lambda b_, h_, *_: (h_, 0, 0)),
             pl.BlockSpec((1, hp, d), lambda b_, h_, *_: (b_, h_, 0)),
             pl.BlockSpec((1, hp, d), lambda b_, h_, *_: (b_, h_, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=(
             pl.BlockSpec((1, hp, g, d), lambda b_, h_, *_: (b_, h_, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ),
         scratch_shapes=[
             pltpu.VMEM((2, ppg, hp, bs, d), k_cache.dtype),
             pltpu.VMEM((2, ppg, hp, bs, d), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, )),
             pltpu.SemaphoreType.DMA((2, )),
+            pltpu.VMEM((hp, bs, d), k_cache.dtype),
+            pltpu.VMEM((hp, bs, d), v_cache.dtype),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
             pltpu.VMEM((hp * g, 128), jnp.float32),
@@ -362,8 +382,27 @@ def ragged_paged_attention(
         slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(hkv, g)
     else:
         slopes = jnp.zeros((hkv, g), jnp.float32)
-    out, k_cache, v_cache = _ragged_call(
-        q_grouped, slopes, k_new, v_new, k_cache, v_cache,
-        slot_mapping.astype(jnp.int32), block_tables,
-        context_lens.astype(jnp.int32), scale_static=float(scale))
+    # Pad rows arrive as -1 or as the scatter path's out-of-bounds
+    # sentinel (num_blocks * block_size, model_runner._decode_fn_single);
+    # the kernel knows one spelling, and must never DMA past the pool.
+    slots = slot_mapping.astype(jnp.int32)
+    num_slots = k_cache.shape[0] * k_cache.shape[2]
+    slots = jnp.where((slots >= 0) & (slots < num_slots), slots, -1)
+    # The block tables ride in SMEM (scalar prefetch), 1 MiB on v5e: a
+    # 2048-row budget with 128-block tables (max_model_len 2048) does
+    # not fit. Launch the grid over row chunks that do; the pool is
+    # aliased through, so chunk i's writes precede chunk i+1's reads
+    # exactly as rows do inside one launch.
+    w = block_tables.shape[1]
+    rows = _largest_divisor(b, max(_SMEM_TABLE_BYTES // (4 * w), 1))
+    ctx = context_lens.astype(jnp.int32)
+    outs = []
+    for r0 in range(0, b, rows):
+        rs = slice(r0, r0 + rows)
+        out, k_cache, v_cache = _ragged_call(
+            q_grouped[rs], slopes, k_new[rs], v_new[rs], k_cache, v_cache,
+            slots[rs], block_tables[rs], ctx[rs],
+            scale_static=float(scale))
+        outs.append(out)
+    out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
     return out.reshape(b, 1, hq, d), k_cache, v_cache

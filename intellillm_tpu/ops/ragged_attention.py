@@ -18,7 +18,8 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 
 from intellillm_tpu.ops.attention import decode_attention_reference
-from intellillm_tpu.ops.dispatch import use_pallas_kernel
+from intellillm_tpu.ops.dispatch import (shard_over_heads,
+                                         use_pallas_kernel)
 from intellillm_tpu.ops.kv_cache import reshape_and_cache
 
 Arrays3 = Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]
@@ -35,21 +36,33 @@ def ragged_fused_attention(
     context_lens: jnp.ndarray,  # [B] i32, counting the new token
     scale: float,
     alibi_slopes: Optional[jnp.ndarray] = None,
+    mesh=None,
 ) -> Arrays3:
     """Write each row's K/V into the paged pool and causally attend over
     it (decode rows and prefill-chunk rows alike — chunk rows just carry
-    `context_lens = position + 1`). Returns (out, k_cache, v_cache)."""
+    `context_lens = position + 1`). Returns (out, k_cache, v_cache).
+    `mesh` (multi-chip runs) shards the kernel over kv heads."""
     d = q.shape[-1]
     if use_pallas_kernel("ragged") and d % 128 == 0:
         from intellillm_tpu.ops.pallas.ragged_paged_attention import (
             ragged_paged_attention)
+
+        def kernel(q, k_new, v_new, k_cache, v_cache, slots, tables, ctx,
+                   slopes):
+            return ragged_paged_attention(q, k_new, v_new, k_cache, v_cache,
+                                          slots, tables, ctx, scale, slopes)
+
         # The kernel's DMAs cannot cast, and its self-token read must
         # match a reference read of the just-written cache line — cast
         # to the cache dtype (e.g. fp8 KV quantization) outside.
-        return ragged_paged_attention(
-            q, k_new.astype(k_cache.dtype), v_new.astype(v_cache.dtype),
-            k_cache, v_cache, slot_mapping, block_tables, context_lens,
-            scale, alibi_slopes)
+        return shard_over_heads(
+            kernel, mesh, k_cache.shape[1],
+            in_head_dims=(2, 1, 1, 1, 1, None, None, None,
+                          None if alibi_slopes is None else 0),
+            out_head_dims=(2, 1, 1))(
+                q, k_new.astype(k_cache.dtype), v_new.astype(v_cache.dtype),
+                k_cache, v_cache, slot_mapping, block_tables, context_lens,
+                alibi_slopes)
     return ragged_fused_attention_reference(
         q, k_new, v_new, k_cache, v_cache, slot_mapping, block_tables,
         context_lens, scale, alibi_slopes)

@@ -460,12 +460,27 @@ class HTTPReplica(Replica):
                 self.proc.kill()
 
 
+def _tensor_parallel_size(engine_argv: List[str]) -> int:
+    """The chips one replica spans, read off its engine flags."""
+    for flag in ("--tensor-parallel-size", "-tp"):
+        for i, arg in enumerate(engine_argv):
+            if arg == flag and i + 1 < len(engine_argv):
+                return int(engine_argv[i + 1])
+            if arg.startswith(flag + "="):
+                return int(arg.split("=", 1)[1])
+    return 1
+
+
 def launch_http_replica(replica_id: str, port: int,
                         engine_argv: List[str],
                         host: str = "127.0.0.1",
-                        role: str = "mixed") -> HTTPReplica:
-    """Launch a demo api_server subprocess as a replica (inherits this
-    process's environment, so INTELLILLM_JAX_PLATFORM etc. apply)."""
+                        role: str = "mixed",
+                        child_index: int = 0) -> HTTPReplica:
+    """Launch a demo api_server subprocess as a replica. It inherits this
+    process's environment (so JAX_PLATFORMS etc. apply) plus the checkout
+    on its path and, as replica `child_index` of this host, chips of its
+    own (utils.child_process_env): N replicas that each saw every chip
+    would all claim chip 0."""
     cmd = [
         sys.executable, "-m", "intellillm_tpu.entrypoints.api_server",
         "--host", host, "--port", str(port),
@@ -473,7 +488,11 @@ def launch_http_replica(replica_id: str, port: int,
     if role != "mixed" and "--replica-role" not in engine_argv:
         cmd += ["--replica-role", role]
     logger.info("launching replica %s: %s", replica_id, " ".join(cmd))
-    proc = subprocess.Popen(cmd)
+    from intellillm_tpu.utils import child_process_env
+    env = child_process_env(
+        child_index=child_index,
+        chips_per_child=_tensor_parallel_size(engine_argv))
+    proc = subprocess.Popen(cmd, env=env)
     return HTTPReplica(replica_id, f"http://{host}:{port}", proc=proc,
                        role=role)
 

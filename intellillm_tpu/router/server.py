@@ -973,7 +973,7 @@ def build_router_from_args(args, engine_argv: List[str]) -> Router:
     for i in range(args.launch_replicas):
         replica = launch_http_replica(
             f"launched-{i}", args.replica_base_port + i, engine_argv,
-            role=role_for(len(urls) + i))
+            role=role_for(len(urls) + i), child_index=i)
         router.add_replica(replica)
     if not router.manager.replicas:
         raise SystemExit(

@@ -66,6 +66,7 @@ class SamplingParams:
         skip_special_tokens: bool = True,
         spaces_between_special_tokens: bool = True,
         logits_processors: Optional[List[LogitsProcessor]] = None,
+        seed: Optional[int] = None,
     ) -> None:
         self.n = n
         self.best_of = best_of if best_of is not None else n
@@ -90,6 +91,11 @@ class SamplingParams:
         self.skip_special_tokens = skip_special_tokens
         self.spaces_between_special_tokens = spaces_between_special_tokens
         self.logits_processors = logits_processors or []
+        # With a seed, the request's samples depend on (seed, stream,
+        # step) only: the same request repeats its tokens whatever else
+        # is in the batch. Without one they derive from the engine seed
+        # and the sequence id.
+        self.seed = seed
 
         self._validate()
 

@@ -72,7 +72,8 @@ class SequenceData:
 
     __slots__ = ("_buf", "_len", "_prompt_len", "_prompt_list",
                  "cumulative_logprob", "_num_computed_tokens",
-                 "_prefill_complete", "_chunk_prompt_logprobs")
+                 "_prefill_complete", "_chunk_prompt_logprobs",
+                 "seed_stream")
 
     def __init__(self, prompt_token_ids: List[int]) -> None:
         n = len(prompt_token_ids)
@@ -93,6 +94,10 @@ class SequenceData:
         # reference-format list on the final chunk and cleared
         # (worker/model_runner.py:_attach_prompt_logprobs).
         self._chunk_prompt_logprobs: Optional[dict] = None
+        # Which of its request's sampling streams this is (0 = the prompt
+        # sequence; best_of/n forks take the next index in creation
+        # order). Only read for requests that name a seed.
+        self.seed_stream = 0
 
     def append_token_id(self, token_id: int, logprob: float) -> None:
         if self._len == self._buf.shape[0]:
@@ -179,6 +184,7 @@ class SequenceData:
         twin._num_computed_tokens = self._num_computed_tokens
         twin._prefill_complete = self._prefill_complete
         twin._chunk_prompt_logprobs = None
+        twin.seed_stream = self.seed_stream
         return twin
 
     def __deepcopy__(self, memo) -> "SequenceData":
@@ -331,6 +337,14 @@ class SequenceGroup:
         self.first_scheduled_time: Optional[float] = None
         self.first_token_time: Optional[float] = None
         self.last_token_time: Optional[float] = None
+        self._next_seed_stream = 1
+
+    def take_seed_stream(self) -> int:
+        """The next unused sampling-stream index of this request (given
+        to a fork; see SequenceData.seed_stream)."""
+        stream = self._next_seed_stream
+        self._next_seed_stream += 1
+        return stream
 
     def _any_seq(self) -> Sequence:
         return next(iter(self.seqs_dict.values()))

@@ -172,45 +172,28 @@ class Worker:
     def _estimate_step_temp_bytes(self) -> int:
         """Lower the largest mixed-dispatch shape against a tiny dummy
         cache and read temp memory from XLA's memory analysis."""
-        try:
-            from intellillm_tpu.utils import pad_to_bucket
+        from intellillm_tpu.utils import pad_to_bucket
 
-            runner = self.model_runner
-            b = pad_to_bucket(self.scheduler_config.max_num_batched_tokens,
-                              runner.mixed_token_buckets)
-            w = runner.mixed_token_buckets[-1]
+        runner = self.model_runner
+        b = pad_to_bucket(self.scheduler_config.max_num_batched_tokens,
+                          runner.mixed_token_buckets)
+        w = runner.max_table_width
 
-            from intellillm_tpu.utils import STR_DTYPE_TO_JNP
-            nkv = self.model_config.get_total_num_kv_heads()
-            hs = self.model_config.get_head_size()
-            nl = self.model_config.get_num_layers()
-            cache_dtype = (self.model_config.dtype
-                           if self.cache_config.cache_dtype == "auto" else
-                           self.cache_config.cache_dtype)
-            dummy_blocks = 64  # compile-only: temps don't depend on pool size
-            cache_shape = jax.ShapeDtypeStruct(
-                (dummy_blocks, nkv, self.cache_config.block_size, hs),
-                jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]))
-            kv_struct = [(cache_shape, cache_shape) for _ in range(nl)]
+        from intellillm_tpu.utils import STR_DTYPE_TO_JNP
+        nkv = self.model_config.get_total_num_kv_heads()
+        hs = self.model_config.get_head_size()
+        nl = self.model_config.get_num_layers()
+        cache_dtype = (self.model_config.dtype
+                       if self.cache_config.cache_dtype == "auto" else
+                       self.cache_config.cache_dtype)
+        dummy_blocks = 64  # compile-only: temps don't depend on pool size
+        cache_shape = jax.ShapeDtypeStruct(
+            (dummy_blocks, nkv, self.cache_config.block_size, hs),
+            jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]))
+        kv_struct = [(cache_shape, cache_shape) for _ in range(nl)]
 
-            i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-            f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
-            u32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.uint32)
-            lowered = runner._jit_decode_single.lower(
-                self.params, kv_struct, i32(b, 1), i32(b, 1), i32(b, w),
-                i32(b), f32(b), i32(b), f32(b), f32(b), u32(b),
-                f32(b), f32(b), f32(b), None, None,
-                num_samples=1, logprob_k=8,
-                do_topk=False, do_topp=False, do_minp=False,
-                do_penalties=False)
-            ma = lowered.compile().memory_analysis()
-            if ma is None:
-                return 2 * 2**30
-            return int(getattr(ma, "temp_size_in_bytes", 2 * 2**30))
-        except Exception as e:  # profiling is best-effort
-            logger.warning("Step-memory profiling failed (%s); assuming 2GiB",
-                           e)
-            return 2 * 2**30
+        lowered = runner.lower_mixed_step(kv_struct, b, w, logprob_k=8)
+        return int(lowered.compile().memory_analysis().temp_size_in_bytes)
 
     # --- cache -----------------------------------------------------------
 
@@ -315,125 +298,113 @@ class Worker:
                  do_minp=False, do_penalties=False, do_random=True),
         ]
         n = 0
-        try:
-            # The serving path (execute_model / _execute_mixed) binds
-            # every arg POSITIONALLY, and jax.jit keys its dispatch cache
-            # on the call structure — a keyword-bound warm-up would
-            # compile executables serving never reuses. Guard against
-            # parameter-order drift (ADVICE r3) with a signature check;
-            # inside the try so drift degrades to lazy compilation (the
-            # documented best-effort contract), not a boot failure.
-            import inspect
-            names = list(inspect.signature(
-                runner._decode_fn_single).parameters)
-            idx = names.index("output_tokens")
-            assert names[idx + 1:idx + 5] == \
-                ["lora", "fetch_indices", "plp_targets",
-                 "numerics_inject"], names
-            # Numerics sentinels (obs/numerics.py): an enabled engine
-            # dispatches EVERY mixed step with do_numerics=True plus the
-            # inject vector, so warm-up must add the same bindings —
-            # otherwise the warmed executables never match serving and
-            # the first real step compiles mid-serving. Disabled (the
-            # default) warms the exact pre-sentinel call structure.
-            from intellillm_tpu.obs import get_numerics_tracker
-            num_on = get_numerics_tracker().enabled
-            widths = buckets[:2] if full else buckets[:1]
-            for b in batch_sizes:
-                zeros_i = place(np.zeros((b, 1), np.int32))
-                # A LoRA-enabled engine passes the lora pytree on EVERY
-                # step (slot-0 zero adapter when no rows carry one), so
-                # warm-up must too — otherwise the warmed executables
-                # (lora=None structure) never match serving and the
-                # first real step recompiles mid-serving.
-                lora = (runner.lora_manager.set_active_loras([], b)
-                        if runner.lora_manager is not None else None)
-                for w in widths:
-                    args = (place(np.zeros((b, 1), np.int32)), zeros_i,
-                            place(np.zeros((b, w), np.int32)),
-                            place(np.zeros(b, np.int32)),
-                            place(np.zeros(b, np.float32)),
-                            place(np.full(b, -1, np.int32)),
-                            place(np.ones(b, np.float32)),
-                            place(np.zeros(b, np.float32)),
-                            place(np.zeros(b, np.uint32)),
-                            place(np.zeros(b, np.float32)),
-                            place(np.zeros(b, np.float32)),
-                            place(np.ones(b, np.float32)), None, None,
-                            lora)
-                    numerics_kwargs = (dict(
-                        do_numerics=True,
-                        numerics_inject=place(np.zeros(b, np.float32)))
-                        if num_on else {})
-                    for flags in flag_variants:
+        # The serving path (execute_model / _execute_mixed) binds
+        # every arg POSITIONALLY, and jax.jit keys its dispatch cache
+        # on the call structure — a keyword-bound warm-up would
+        # compile executables serving never reuses. Guard against
+        # parameter-order drift (ADVICE r3) with a signature check.
+        import inspect
+        names = list(inspect.signature(
+            runner._decode_fn_single).parameters)
+        idx = names.index("output_tokens")
+        assert names[idx + 1:idx + 5] == \
+            ["lora", "fetch_indices", "plp_targets",
+             "numerics_inject"], names
+        # Numerics sentinels (obs/numerics.py): an enabled engine
+        # dispatches EVERY mixed step with do_numerics=True plus the
+        # inject vector, so warm-up must add the same bindings —
+        # otherwise the warmed executables never match serving and
+        # the first real step compiles mid-serving. Disabled (the
+        # default) warms the exact pre-sentinel call structure.
+        from intellillm_tpu.obs import get_numerics_tracker
+        num_on = get_numerics_tracker().enabled
+        widths = buckets[:2] if full else buckets[:1]
+        for b in batch_sizes:
+            zeros_i = place(np.zeros((b, 1), np.int32))
+            # A LoRA-enabled engine passes the lora pytree on EVERY
+            # step (slot-0 zero adapter when no rows carry one), so
+            # warm-up must too — otherwise the warmed executables
+            # (lora=None structure) never match serving and the
+            # first real step recompiles mid-serving.
+            lora = (runner.lora_manager.set_active_loras([], b)
+                    if runner.lora_manager is not None else None)
+            for w in widths:
+                args = (place(np.zeros((b, 1), np.int32)), zeros_i,
+                        place(np.zeros((b, w), np.int32)),
+                        place(np.zeros(b, np.int32)),
+                        place(np.zeros(b, np.float32)),
+                        place(np.full(b, -1, np.int32)),
+                        place(np.ones(b, np.float32)),
+                        place(np.zeros(b, np.float32)),
+                        place(np.zeros(b, np.uint32)),
+                        place(np.zeros(b, np.float32)),
+                        place(np.zeros(b, np.float32)),
+                        place(np.ones(b, np.float32)), None, None,
+                        lora)
+                numerics_kwargs = (dict(
+                    do_numerics=True,
+                    numerics_inject=place(np.zeros(b, np.float32)))
+                    if num_on else {})
+                for flags in flag_variants:
+                    result = runner._jit_decode_single(
+                        self.params, self.cache_engine.device_cache,
+                        *args, **flags, **numerics_kwargs)
+                    # (packed, [sentinel panel,] caches) — the panel
+                    # rides along only under --enable-numerics.
+                    packed, caches = result[0], result[-1]
+                    self.cache_engine.device_cache = caches
+                    n += 1
+                    if (full and not flags["do_random"] and b == top
+                            and w == buckets[0]):
+                        # Passing fetch_indices changes the jit arg
+                        # pytree (logits_processors escape path) —
+                        # warm it too, so the first processor-bearing
+                        # request doesn't trigger a full XLA compile
+                        # mid-serving.
+                        m = pad_to_bucket(1, buckets)
+                        fargs = args + (
+                            place(np.zeros(m, np.int32)), )
                         result = runner._jit_decode_single(
-                            self.params, self.cache_engine.device_cache,
-                            *args, **flags, **numerics_kwargs)
-                        # (packed, [sentinel panel,] caches) — the panel
-                        # rides along only under --enable-numerics.
+                            self.params,
+                            self.cache_engine.device_cache,
+                            *fargs, **flags, **numerics_kwargs)
                         packed, caches = result[0], result[-1]
                         self.cache_engine.device_cache = caches
                         n += 1
-                        if (full and not flags["do_random"] and b == top
-                                and w == buckets[0]):
-                            # Passing fetch_indices changes the jit arg
-                            # pytree (logits_processors escape path) —
-                            # warm it too, so the first processor-bearing
-                            # request doesn't trigger a full XLA compile
-                            # mid-serving.
-                            m = pad_to_bucket(1, buckets)
-                            fargs = args + (
-                                place(np.zeros(m, np.int32)), )
-                            result = runner._jit_decode_single(
+                    k = self.scheduler_config.num_decode_steps
+                    if full and k > 1:
+                        packed, caches = runner._jit_decode(
+                            self.params, self.cache_engine.device_cache,
+                            *args, num_steps=k, **flags)
+                        self.cache_engine.device_cache = caches
+                        n += 1
+                        if self.warm_cont_program:
+                            # Pipelined continuation program: same arg
+                            # shapes, tokens sliced from the previous
+                            # step's packed output (which the fused
+                            # warm-up call above just produced with
+                            # exactly the runtime shape/dtype).
+                            packed, caches = runner._jit_decode_cont(
                                 self.params,
                                 self.cache_engine.device_cache,
-                                *fargs, **flags, **numerics_kwargs)
-                            packed, caches = result[0], result[-1]
+                                packed, *args[1:], prev_t1=k,
+                                num_steps=k, **flags)
                             self.cache_engine.device_cache = caches
                             n += 1
-                        k = self.scheduler_config.num_decode_steps
-                        if full and k > 1:
-                            packed, caches = runner._jit_decode(
-                                self.params, self.cache_engine.device_cache,
-                                *args, num_steps=k, **flags)
-                            self.cache_engine.device_cache = caches
-                            n += 1
-                            if self.warm_cont_program:
-                                # Pipelined continuation program: same arg
-                                # shapes, tokens sliced from the previous
-                                # step's packed output (which the fused
-                                # warm-up call above just produced with
-                                # exactly the runtime shape/dtype).
-                                packed, caches = runner._jit_decode_cont(
-                                    self.params,
-                                    self.cache_engine.device_cache,
-                                    packed, *args[1:], prev_t1=k,
-                                    num_steps=k, **flags)
-                                self.cache_engine.device_cache = caches
-                                n += 1
-                        # lint: allow(host-sync) reason=warm-up runs before serving; blocking here ensures executables are resident and the logged compile wall-time is honest
-                        jax.block_until_ready(packed)
-            seconds = _time.monotonic() - start
-            from intellillm_tpu.ops.dispatch import kernel_selection
-            self.warmup_stats = {"executables": n,
-                                 "seconds": round(seconds, 3),
-                                 # Selection is trace-time, so the paths
-                                 # recorded here are the ones baked into
-                                 # the executables just compiled.
-                                 "kernel_selection": kernel_selection()}
-            logger.info("Warm-up: compiled %d mixed-family executables "
-                        "(token buckets=%s) in %.1fs", n,
-                        "/".join(str(x) for x in batch_sizes), seconds)
-            return n
-        except Exception as e:  # warm-up is best-effort
-            logger.warning("Warm-up failed (%s); compiling lazily instead",
-                           e)
-            self.warmup_stats = {
-                "executables": n,
-                "seconds": round(_time.monotonic() - start, 3),
-                "error": str(e),
-            }
-            return None
+                    # lint: allow(host-sync) reason=warm-up runs before serving; blocking here ensures executables are resident and the logged compile wall-time is honest
+                    jax.block_until_ready(packed)
+        seconds = _time.monotonic() - start
+        from intellillm_tpu.ops.dispatch import kernel_selection
+        self.warmup_stats = {"executables": n,
+                             "seconds": round(seconds, 3),
+                             # Selection is trace-time, so the paths
+                             # recorded here are the ones baked into
+                             # the executables just compiled.
+                             "kernel_selection": kernel_selection()}
+        logger.info("Warm-up: compiled %d mixed-family executables "
+                    "(token buckets=%s) in %.1fs", n,
+                    "/".join(str(x) for x in batch_sizes), seconds)
+        return n
 
     # --- step ------------------------------------------------------------
 

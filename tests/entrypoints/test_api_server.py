@@ -36,7 +36,7 @@ def api_server(tmp_path_factory):
             d, safe_serialization=True)
 
     env = dict(os.environ)
-    env["INTELLILLM_JAX_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.Popen(
         [sys.executable, "-m", "intellillm_tpu.entrypoints.api_server",
          "--model", d, "--dtype", "float32", "--max-model-len", "128",
@@ -154,7 +154,7 @@ def test_top_renders_one_frame(api_server):
         [sys.executable, "-m", "intellillm_tpu.tools.top", "--once",
          "--url", BASE],
         capture_output=True, timeout=180, text=True,
-        env={**os.environ, "INTELLILLM_JAX_PLATFORM": "cpu"})
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-2000:]
     assert "intellillm-top" in out.stdout
     assert "Queues:" in out.stdout

@@ -43,7 +43,7 @@ def openai_server(tmp_path_factory):
     # Plain JAX_PLATFORMS is not honored when a site customization
     # pre-registers a TPU plugin; the server applies this override via
     # jax.config before backend init.
-    env["INTELLILLM_JAX_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.Popen(
         [sys.executable, "-m", "intellillm_tpu.entrypoints.openai.api_server",
          "--model", d, "--dtype", "float32", "--max-model-len", "128",

@@ -14,12 +14,6 @@ from jax.experimental.pallas import tpu as pltpu
 def _tpu_interpret_on_cpu():
     if jax.default_backend() == "tpu":
         yield
-    elif not hasattr(pltpu, "force_tpu_interpret_mode"):
-        # Version gate: without interpret mode the Mosaic kernels cannot
-        # run off-TPU at all — skip instead of erroring every kernel test
-        # on jax versions that predate the API.
-        pytest.skip("pallas force_tpu_interpret_mode is absent on this "
-                    "jax version; kernel grids need TPU or interpret mode")
     else:
         with pltpu.force_tpu_interpret_mode():
             yield

@@ -3,8 +3,7 @@
 Unlike tests/kernels/ (whose autouse fixture gates the WHOLE directory
 on interpret mode), only the parity suite here needs to execute Mosaic
 kernels — the reference-seam tests run everywhere on plain CPU — so the
-interpret gate is an opt-in fixture, with the same guarded-skip pattern
-as `test_sp_prefill_bench_smoke`.
+interpret gate is an opt-in fixture.
 """
 import jax
 import pytest
@@ -14,14 +13,9 @@ from jax.experimental.pallas import tpu as pltpu
 @pytest.fixture
 def tpu_interpret():
     """Run the test body with Pallas TPU kernels executable: natively on
-    TPU, under `force_tpu_interpret_mode` on CPU, guarded-skip on jax
-    versions that predate the interpret API."""
+    TPU, under `force_tpu_interpret_mode` on CPU."""
     if jax.default_backend() == "tpu":
         yield
-    elif not hasattr(pltpu, "force_tpu_interpret_mode"):
-        pytest.skip("pallas force_tpu_interpret_mode is absent on this "
-                    "jax version; kernel parity needs TPU or interpret "
-                    "mode")
     else:
         with pltpu.force_tpu_interpret_mode():
             yield

@@ -1,116 +1,154 @@
-"""bench.py backend-probe hardening (BENCH_r04/r05 dark trajectory).
+"""The start-up path hides nothing: what replaced bench.py's backend probe.
 
-A hung probe must (a) be killed — process GROUP and all — within its
-budget, (b) leave a structured probe record with the faulthandler stack,
-and (c) let the bench emit a parseable `skipped` record instead of
-hanging the whole run.
+bench.py asks `jax.devices()` in-process and refuses to run without a TPU
+unless a CPU run was asked for by name; the compile cache is placed from
+outside or at one fixed in-checkout path; the native library is keyed on
+its source; replica children get chips of their own; a TPU that reports
+no memory limit is an error, not a 16 GiB guess.
 """
 import json
-import time
+import os
+import subprocess
+import sys
 
 import pytest
 
 import bench
+from intellillm_tpu import native, utils
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-@pytest.fixture(autouse=True)
-def _reset_progress():
-    bench._PROGRESS["phase"] = "start"
-    bench._PROGRESS["probe"] = []
-    bench._PROGRESS["warmup_tok_s"] = None
-    yield
+class _Device:
+    def __init__(self, platform, kind="TPU v5 lite", stats=None):
+        self.platform = platform
+        self.device_kind = kind
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
 
 
-@pytest.fixture
-def fast_probe_env(monkeypatch):
-    monkeypatch.setenv("INTELLILLM_BENCH_PROBE_ATTEMPTS", "1")
-    monkeypatch.setenv("INTELLILLM_BENCH_PROBE_BACKOFF", "0")
-    monkeypatch.setenv("INTELLILLM_BENCH_PROBE_TIMEOUT", "3")
+def test_bench_refuses_without_tpu(monkeypatch, capsys):
+    """No TPU and no explicit JAX_PLATFORMS=cpu: one error line, exit 2,
+    nothing on stdout (no skip record, no exit 0)."""
+    import jax
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(jax, "devices", lambda: [_Device("cpu", "cpu")])
+    with pytest.raises(SystemExit) as exc:
+        bench.require_device()
+    assert exc.value.code == 2
+    out, err = capsys.readouterr()
+    assert out == ""
+    assert err.count("\n") == 1 and "no TPU" in err
 
 
-def test_hung_probe_is_killed_within_budget(monkeypatch, fast_probe_env):
-    monkeypatch.setattr(bench, "_probe_child_code",
-                        lambda timeout_s: "import time; time.sleep(600)")
-    t0 = time.monotonic()
-    assert bench.probe_backend() is False
-    assert time.monotonic() - t0 < 30
-    [rec] = bench._PROGRESS["probe"]
-    assert rec["ok"] is False
-    assert "hung" in rec["err"]
+def test_bench_record_names_device():
+    """The tiny size runs on CPU only when JAX_PLATFORMS=cpu says so, and
+    its record names platform, device kind and device count."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", INTELLILLM_BENCH_SIZE="tiny",
+               INTELLILLM_BENCH_BS="2", INTELLILLM_BENCH_IN="8",
+               INTELLILLM_BENCH_OUT="4", INTELLILLM_BENCH_K="2")
+    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
+                       env=env, capture_output=True, text=True, timeout=420)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    rec = json.loads(r.stdout.strip().splitlines()[-1])
+    assert rec["platform"] == "cpu" and rec["device_kind"] == "cpu"
+    assert rec["device_count"] >= 1
+    assert rec["metric"] not in ("skipped", "error") and rec["value"] > 0
 
 
-def test_hung_probe_with_grandchild_holding_pipe(monkeypatch,
-                                                 fast_probe_env):
-    """A child that forks a helper (TPU runtimes do) and hangs: the
-    helper inherits the stderr pipe, so a direct-child-only kill leaves
-    `communicate()` blocked forever. The process-group kill must reap
-    both within budget."""
-    child = ("import subprocess, sys, time\n"
-             "subprocess.Popen(['sleep', '600'], stderr=sys.stderr)\n"
-             "time.sleep(600)\n")
-    monkeypatch.setattr(bench, "_probe_child_code", lambda t: child)
-    t0 = time.monotonic()
-    assert bench.probe_backend() is False
-    assert time.monotonic() - t0 < 30
-    [rec] = bench._PROGRESS["probe"]
-    assert "hung" in rec["err"]
+def _cache_dir_after_enable(env_value):
+    """What the compile-cache config holds after the engine's call, in a
+    fresh process (the setting is process-global)."""
+    code = ("import jax\n"
+            "from intellillm_tpu.utils import "
+            "enable_persistent_compilation_cache as f\n"
+            "print(repr((f(), jax.config.jax_compilation_cache_dir)))\n")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.pop("INTELLILLM_COMPILE_CACHE", None)
+    if env_value is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_value
+    r = subprocess.run([sys.executable, "-c", code], env=env, cwd="/",
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return eval(r.stdout.strip().splitlines()[-1])
 
 
-def test_wedged_probe_captures_faulthandler_stack(monkeypatch,
-                                                  fast_probe_env):
-    """A child that self-dumps via faulthandler (the real probe's wedge
-    path) must yield a probe record carrying the stack."""
-    child = ("import faulthandler, time\n"
-             "faulthandler.dump_traceback_later(0.5, exit=True)\n"
-             "time.sleep(600)\n")
-    monkeypatch.setattr(bench, "_probe_child_code", lambda t: child)
-    assert bench.probe_backend() is False
-    [rec] = bench._PROGRESS["probe"]
-    assert "stack" in rec
-    assert "Timeout (" in rec["stack"]
+def test_compile_cache_env_set_is_untouched(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: that directory, and none set in
+    code."""
+    outside = str(tmp_path / "placed-from-outside")
+    returned, configured = _cache_dir_after_enable(outside)
+    assert returned == outside and configured == outside
 
 
-def test_probe_succeeds_on_cpu(monkeypatch):
-    """The real probe child against the CPU backend: exits 0, reports
-    the platform, one ok record."""
-    monkeypatch.setenv("INTELLILLM_BENCH_PROBE_ATTEMPTS", "1")
-    monkeypatch.setenv("INTELLILLM_BENCH_PROBE_BACKOFF", "0")
-    monkeypatch.setenv("INTELLILLM_BENCH_PROBE_TIMEOUT", "120")
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert bench.probe_backend() is True
-    [rec] = bench._PROGRESS["probe"]
-    assert rec["ok"] is True
-    assert rec["platform"] == "cpu"
+def test_compile_cache_default_is_fixed_in_checkout():
+    """Unset: one fixed path inside the checkout — never a pid, time or
+    temp-dir name, which would never hit."""
+    returned, configured = _cache_dir_after_enable(None)
+    assert returned == configured == os.path.join(REPO, ".cache", "jax")
+    again, _ = _cache_dir_after_enable(None)
+    assert again == returned
+    assert utils.DEFAULT_COMPILE_CACHE_DIR == returned
 
 
-def test_probe_budget_is_clamped(monkeypatch, capsys):
-    """Env overrides beyond the fail-fast budget are clamped IN the
-    loop (BENCH_r05 carried 3x300s through the env)."""
-    monkeypatch.setenv("INTELLILLM_BENCH_PROBE_ATTEMPTS", "5")
-    monkeypatch.setenv("INTELLILLM_BENCH_PROBE_BACKOFF", "0")
-    monkeypatch.setenv("INTELLILLM_BENCH_PROBE_TIMEOUT", "900")
-    monkeypatch.setattr(bench, "_probe_child_code",
-                        lambda t: "raise SystemExit(1)")
-    assert bench.probe_backend() is False
-    assert len(bench._PROGRESS["probe"]) == bench._MAX_PROBE_ATTEMPTS
-    assert "clamping probe budget" in capsys.readouterr().err
+def test_native_library_keyed_on_source_hash(tmp_path):
+    """The library's name carries the source's hash: a stale or foreign
+    .so in the tree is not picked up whatever its mtime, and changed
+    source means another file."""
+    src = tmp_path / "batch_prep.cc"
+    src.write_text("int f() { return 1; }\n")
+    first = native.library_path(str(src))
+    stale = tmp_path / "libbatch_prep.so"
+    stale.write_bytes(b"not a library")
+    os.utime(stale, (2**31, 2**31))         # newer than any source
+    assert native.library_path(str(src)) == first != str(stale)
+    src.write_text("int f() { return 2; }\n")
+    assert native.library_path(str(src)) != first
+    # The library this process loaded is the one named after the source
+    # git has.
+    assert native.path_taken() == "native"
+    assert os.path.exists(native.library_path())
 
 
-def test_extract_probe_stack():
-    dump = "noise\nTimeout (0:00:50)!\nThread 0x1 (most recent call)\n"
-    assert bench._extract_probe_stack(dump).startswith("Timeout (")
-    assert bench._extract_probe_stack(dump.encode()).startswith("Timeout (")
-    assert bench._extract_probe_stack("no marker here") is None
-    assert bench._extract_probe_stack(None) is None
+def test_replica_children_get_their_own_chips():
+    """Child i of width w sees chips [i*w, (i+1)*w), the checkout is on
+    its path, and asking for more chips than the host has is an error."""
+    base = {"PATH": "/usr/bin"}
+    envs = [utils.child_process_env(base, child_index=i, num_chips=4)
+            for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    assert len({e["TPU_MESH_CONTROLLER_PORT"] for e in envs}) == 4
+    assert all(e["PYTHONPATH"].split(os.pathsep)[0] == REPO for e in envs)
+    two = utils.child_process_env(base, child_index=1, chips_per_child=2,
+                                  num_chips=4)
+    assert two["TPU_VISIBLE_CHIPS"] == "2,3"
+    assert two["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+    with pytest.raises(ValueError, match="exceeds the chips"):
+        utils.child_process_env(base, child_index=2, chips_per_child=2,
+                                num_chips=4)
+    # A CPU host (no chips) and a lone child keep chip visibility as is.
+    assert "TPU_VISIBLE_CHIPS" not in utils.child_process_env(
+        base, child_index=3, num_chips=0)
+    assert "TPU_VISIBLE_CHIPS" not in utils.child_process_env(base)
+    # The router's launcher reads the width off the engine flags.
+    from intellillm_tpu.router.replica import _tensor_parallel_size
+    assert _tensor_parallel_size(["--model", "m", "-tp", "2"]) == 2
+    assert _tensor_parallel_size(["--tensor-parallel-size=4"]) == 4
+    assert _tensor_parallel_size(["--model", "m"]) == 1
 
 
-def test_skip_record_is_structured(capsys):
-    bench._PROGRESS["phase"] = "probe"
-    bench._PROGRESS["probe"] = [{"attempt": 1, "ok": False,
-                                 "err": "probe hung > 3s (killed)"}]
-    bench._skip_record("TPU backend unavailable after all probe retries")
-    rec = json.loads(capsys.readouterr().out.strip())
-    assert rec["metric"] == "skipped"
-    assert rec["value"] == 0
-    assert rec["phase"] == "probe"
-    assert rec["probe_attempts"][0]["err"].startswith("probe hung")
+def test_device_memory_without_limit_is_an_error_on_tpu():
+    """A TPU-named backend whose memory_stats() has no bytes_limit
+    raises; the CPU test path keeps its explicit stand-in."""
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        utils.get_device_memory_bytes(_Device("tpu", stats={}))
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        utils.get_device_memory_bytes(_Device("tpu", stats=None))
+    assert utils.get_device_memory_bytes(
+        _Device("tpu", stats={"bytes_limit": 123})) == 123
+    assert utils.get_device_memory_bytes(
+        _Device("cpu", "cpu")) == 16 * 1024**3

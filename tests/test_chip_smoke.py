@@ -1,0 +1,74 @@
+"""chip_smoke.py passes only on a chip.
+
+Run here at a tiny size under JAX_PLATFORMS=cpu it is a rehearsal of its
+control flow: every phase runs, then the device check refuses. A smoke
+that passes without an accelerator is the fallback this repo removed.
+"""
+import json
+import os
+import subprocess
+import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(*args, devices=None, timeout=600):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    if devices:
+        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), *args],
+        env=env, capture_output=True, text=True, timeout=timeout)
+    lines = [json.loads(x) for x in r.stdout.splitlines()
+             if x.startswith("{")]
+    return r, lines
+
+
+def _assert_refused(r, lines):
+    assert r.returncode != 0
+    assert '"ok"' not in r.stdout
+    assert "no accelerator" in r.stderr
+    assert not any("ok" in line for line in lines)
+
+
+def test_one_chip_flow_rehearses_then_refuses():
+    # As the driver runs it: nothing on stdout, non-zero, at once.
+    r, lines = _run(timeout=120)
+    _assert_refused(r, lines)
+    assert r.stdout.strip() == ""
+
+    r, lines = _run("--size", "tiny", "--rehearse")
+    _assert_refused(r, lines)
+    by_event = {(x.get("phase"), x.get("event")): x for x in lines}
+    assert by_event[("device", None)]["platform"] == "cpu"
+    answered = by_event[("serve", "answered")]
+    assert all(q["tokens"] == q["asked"] and q["finish"] == "length"
+               for q in answered["requests"].values())
+    assert answered["greedy_repeat_equal"] and answered["seeded_repeat_equal"]
+    programs = by_event[("serve", "programs")]
+    assert programs["co_batched_steps"] >= 1
+    assert {"mixed", "decode_fused", "decode_cont"} <= set(
+        programs["compiles"])
+    [parity] = [x for x in lines
+                if x.get("phase") == "parity" and "tokens_equal" in x]
+    assert parity["tokens_equal"] and parity["co_batched_steps"] >= 1
+    # It got as far as the device check, after every phase.
+    assert lines[-1]["phase"] == "all"
+
+
+def test_four_chip_flow_rehearses_then_refuses():
+    r, lines = _run("--chips", "4", "--size", "tiny", "--rehearse",
+                    devices=4)
+    _assert_refused(r, lines)
+    assert lines[0]["phase"] == "device"
+    assert lines[0]["device_count"] == 4
+    [tp] = [x for x in lines
+            if x.get("phase") == "tp" and "tokens_equal" in x]
+    assert tp["tensor_parallel_size"] == 4 and tp["tokens_equal"]
+    assert len(tp["param_bytes_per_device"]) == 4
+    assert all(tp["pool_bytes_per_device"].values())
+    assert tp["mixed_step_collectives"]
+    # Only the tp phase and what it is compared with: no server booted.
+    assert not any(x.get("phase") in ("serve", "parity") for x in lines)
+    assert lines[-1]["phase"] == "all"

@@ -1,0 +1,190 @@
+"""The main path's kernels compile for the chip — checked without one.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached (`/opt/skills/guides/on-chip-measurement` §2):
+what Mosaic refuses (a slice not aligned to the tiling, too much SMEM or
+VMEM) it refuses here, at no chip time. Interpret mode
+(tests/kernels/) cannot see any of that, which is how a kernel that had
+never compiled was the default for every prompt token.
+
+Each test lowers one kernel at Llama-2-7B widths (32 heads of 128,
+hidden 4096, inter 11008) for a described v5e device and asserts that
+`tpu_custom_call` is in the compiled text — a seam that resolved to a
+reference cannot pass as a kernel. A compile that passes is a compile,
+never a chip run.
+
+This file lives at the top of tests/ on purpose: tests/kernels/conftest.py
+wraps every test there in interpret mode, under which nothing reaches
+Mosaic. The topology is described inside a fixture (never at import: the
+driver's xdist workers all import this file, and only one process may
+load libtpu), and the compiles run in this process with the persistent
+compilation cache off around them (a described-device entry cannot be
+read back without a chip, and warns).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# Llama-2-7B attention and pool shapes, as served.
+HEADS, HEAD_DIM, HIDDEN, INTER = 32, 128, 4096, 11008
+BLOCKS, BLOCK_SIZE = 1600, 16
+POOL_DTYPES = {"bf16": jnp.bfloat16, "fp8_e5m2": jnp.float8_e5m2}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(one_chip):
+    """compile(fn, *(shape, dtype)) -> compiled text, for the described
+    chip, with the persistent compilation cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def compile_text(fn, *specs, donate=()):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in specs]
+        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+        return compiled.as_text()
+
+    yield compile_text
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+def _pool(dtype):
+    return ((BLOCKS, HEADS, BLOCK_SIZE, HEAD_DIM), dtype)
+
+
+@pytest.mark.parametrize("pool", sorted(POOL_DTYPES))
+def test_paged_attention_compiles(compile_for_chip, pool):
+    """The decode kernel of decode_fused/decode_cont at bs=96."""
+    from intellillm_tpu.ops.pallas.paged_attention import paged_attention
+    b, w, dtype = 96, 32, POOL_DTYPES[pool]
+
+    def fn(q, k_cache, v_cache, tables, ctx):
+        return paged_attention(q, k_cache, v_cache, tables, ctx,
+                               HEAD_DIM ** -0.5, return_lse=True)
+
+    text = compile_for_chip(
+        fn, ((b, 1, HEADS, HEAD_DIM), jnp.bfloat16), _pool(dtype),
+        _pool(dtype), ((b, w), jnp.int32), ((b, ), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows,width", [(256, 32), (2048, 256)])
+@pytest.mark.parametrize("pool", sorted(POOL_DTYPES))
+def test_ragged_paged_attention_compiles(compile_for_chip, pool, rows,
+                                         width):
+    """What the mixed seam selects on a TPU: the fused cache-write +
+    attend kernel. (2048, 256) is the default token budget with
+    max_model_len 4096 tables — more SMEM than one launch may take, so
+    the seam must have split it."""
+    from intellillm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    dtype = POOL_DTYPES[pool]
+
+    def fn(q, k_new, v_new, k_cache, v_cache, slots, tables, ctx):
+        return ragged_paged_attention(q, k_new, v_new, k_cache, v_cache,
+                                      slots, tables, ctx, HEAD_DIM ** -0.5)
+
+    text = compile_for_chip(
+        fn, ((rows, 1, HEADS, HEAD_DIM), jnp.bfloat16),
+        ((rows, HEADS, HEAD_DIM), dtype), ((rows, HEADS, HEAD_DIM), dtype),
+        _pool(dtype), _pool(dtype), ((rows, ), jnp.int32),
+        ((rows, width), jnp.int32), ((rows, ), jnp.int32), donate=(3, 4))
+    assert "tpu_custom_call" in text
+
+
+def test_mixed_seam_selects_the_kernel(compile_for_chip):
+    """The seam itself (ops/ragged_attention), told it is on a TPU,
+    resolves to the Pallas kernel and not to the reference."""
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    from intellillm_tpu.ops.ragged_attention import ragged_fused_attention
+    rows, width, dtype = 256, 32, jnp.float8_e5m2
+
+    def fn(q, k_new, v_new, k_cache, v_cache, slots, tables, ctx):
+        return ragged_fused_attention(q, k_new, v_new, k_cache, v_cache,
+                                      slots, tables, ctx, HEAD_DIM ** -0.5)
+
+    set_use_pallas(True)        # jax.default_backend() is "cpu" here
+    try:
+        text = compile_for_chip(
+            fn, ((rows, 1, HEADS, HEAD_DIM), jnp.bfloat16),
+            ((rows, HEADS, HEAD_DIM), jnp.bfloat16),
+            ((rows, HEADS, HEAD_DIM), jnp.bfloat16), _pool(dtype),
+            _pool(dtype), ((rows, ), jnp.int32), ((rows, width), jnp.int32),
+            ((rows, ), jnp.int32), donate=(3, 4))
+    finally:
+        set_use_pallas(None)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("seq_len", [512, 2048])
+def test_flash_attention_compiles(compile_for_chip, seq_len):
+    from intellillm_tpu.ops.pallas.flash_attention import flash_attention
+
+    def fn(q, k, v, ctx):
+        return flash_attention(q, k, v, ctx, HEAD_DIM ** -0.5)
+
+    qkv = ((1, seq_len, HEADS, HEAD_DIM), jnp.bfloat16)
+    text = compile_for_chip(fn, qkv, qkv, qkv, ((1, ), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dout", [HIDDEN, INTER])
+def test_bgmv_compiles(compile_for_chip, dout):
+    """Batched-LoRA delta, rank 16, 4 adapter slots, 4096 -> dout."""
+    from intellillm_tpu.ops.pallas.bgmv import bgmv
+    rows, slots, rank = 256, 4, 16
+    text = compile_for_chip(
+        bgmv, ((rows, 1, HIDDEN), jnp.bfloat16),
+        ((slots, HIDDEN, rank), jnp.bfloat16),
+        ((slots, rank, dout), jnp.bfloat16), ((rows, ), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_quant_matmul_int4_compiles(compile_for_chip):
+    """AWQ/GPTQ int4 dequant-matmul, group 128, 4096 -> 11008."""
+    from intellillm_tpu.ops.pallas.quant_matmul import quant_matmul_int4
+    rows, groups = 256, HIDDEN // 128
+
+    def fn(x, q4, s4, z4):
+        return quant_matmul_int4(x, {"q4": q4, "s4": s4, "z4": z4})
+
+    text = compile_for_chip(
+        fn, ((rows, HIDDEN), jnp.bfloat16),
+        ((HIDDEN // 2, INTER), jnp.uint8), ((groups, INTER), jnp.float32),
+        ((groups, INTER), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_quant_matmul_int4_lut_compiles(compile_for_chip):
+    """SqueezeLLM per-channel 16-entry LUT variant, 4096 -> 11008."""
+    from intellillm_tpu.ops.pallas.quant_matmul import quant_matmul_int4_lut
+
+    def fn(x, q4lut, lut):
+        return quant_matmul_int4_lut(x, {"q4lut": q4lut, "lut": lut})
+
+    text = compile_for_chip(
+        fn, ((256, HIDDEN), jnp.bfloat16),
+        ((HIDDEN // 2, INTER), jnp.uint8), ((16, INTER), jnp.float32))
+    assert "tpu_custom_call" in text
