@@ -12,6 +12,7 @@ JAX dispatch is thread-safe for this single-consumer pattern.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import time
 from functools import partial
 from typing import (AsyncIterator, Dict, Iterable, List, Optional, Set,
@@ -20,6 +21,7 @@ from typing import (AsyncIterator, Dict, Iterable, List, Optional, Set,
 from intellillm_tpu.engine.arg_utils import AsyncEngineArgs
 from intellillm_tpu.engine.llm_engine import LLMEngine
 from intellillm_tpu.logger import init_logger
+from intellillm_tpu.obs.tracing import get_step_tracer
 from intellillm_tpu.outputs import RequestOutput
 from intellillm_tpu.sampling_params import SamplingParams
 
@@ -208,18 +210,22 @@ class AsyncLLMEngine:
     async def engine_step(self) -> bool:
         """One schedule+execute+process pass; returns whether any request
         is in flight."""
-        new_requests, finished_requests = (
-            self._request_tracker.get_new_and_finished_requests())
+        # The between-step phases of the loop period (obs/tracing.py):
+        # admit, handoff, handback, stream_out.
+        tracer = get_step_tracer()
+        with tracer.span("admit"):
+            new_requests, finished_requests = (
+                self._request_tracker.get_new_and_finished_requests())
 
-        for new_request in new_requests:
-            try:
-                self.engine.add_request(**new_request)
-            except ValueError as e:
-                self._request_tracker.propagate_exception(
-                    e, new_request["request_id"])
+            for new_request in new_requests:
+                try:
+                    self.engine.add_request(**new_request)
+                except ValueError as e:
+                    self._request_tracker.propagate_exception(
+                        e, new_request["request_id"])
 
-        if finished_requests:
-            self.engine.abort_request(finished_requests)
+            if finished_requests:
+                self.engine.abort_request(finished_requests)
 
         # The device step blocks; run it off-loop. step_pipelined keeps
         # the device busy across the fetch (see llm_engine.py).
@@ -232,16 +238,23 @@ class AsyncLLMEngine:
             # also run on executor threads and re-bind the device cache.
             # getattr: engine doubles in tests don't carry the lock.
             lock = getattr(self.engine, "_kv_transfer_lock", None)
-            if lock is None:
-                return step_fn()
-            with lock:
-                return step_fn()
+            with lock if lock is not None else contextlib.nullcontext():
+                tracer.end_crossing("handoff")
+                try:
+                    return step_fn()
+                finally:
+                    tracer.begin_crossing()
 
-        request_outputs = await loop.run_in_executor(None, locked_step)
+        tracer.begin_crossing()
+        try:
+            request_outputs = await loop.run_in_executor(None, locked_step)
+        finally:
+            tracer.end_crossing("handback")
 
-        for request_output in request_outputs:
-            self._request_tracker.process_request_output(
-                request_output, verbose=self.log_requests)
+        with tracer.span("stream_out"):
+            for request_output in request_outputs:
+                self._request_tracker.process_request_output(
+                    request_output, verbose=self.log_requests)
 
         return len(request_outputs) > 0 or self.engine.has_inflight()
 
@@ -251,7 +264,10 @@ class AsyncLLMEngine:
             if not has_requests_in_progress:
                 await self._request_tracker.wait_for_new_requests()
             has_requests_in_progress = await self.engine_step()
-            await asyncio.sleep(0)
+            # The streams' consumers take their turn on the loop; the span
+            # ends when this task runs again.
+            with get_step_tracer().span("stream_out"):
+                await asyncio.sleep(0)
 
     async def add_request(
         self,

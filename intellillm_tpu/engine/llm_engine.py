@@ -185,6 +185,7 @@ class LLMEngine:
         self._slo = get_slo_tracker()
         self.last_step_phases: dict = {}
         self.last_step_time: float = 0.0
+        self.last_step_gap: Optional[float] = None
 
         # Device/HBM telemetry (obs/device_telemetry.py): install the
         # static memory ledger and start the HBM poller. Best-effort —
@@ -233,8 +234,7 @@ class LLMEngine:
         # stall report.
         self._watchdog = get_watchdog()
         self._watchdog.attach(
-            has_work=lambda: (self.scheduler.has_unfinished_seqs()
-                              or bool(self._inflight)),
+            has_work=self._has_work,
             queue_depths=lambda: {
                 "waiting": len(self.scheduler.waiting),
                 "running": len(self.scheduler.running),
@@ -698,6 +698,9 @@ class LLMEngine:
                                exc_info=True)
                 return None
             self._profiling = True
+            # Spans enter TraceAnnotations while the trace runs, so its
+            # host plane carries the phases (obs/tracing.py).
+            self._tracer.set_profiling(True)
             max_s = self._profiler_max_s()
             timer = threading.Timer(max_s, self._profile_expired, (max_s,))
             timer.daemon = True
@@ -736,6 +739,7 @@ class LLMEngine:
             if not getattr(self, "_profiling", False):
                 return
             self._profiling = False
+            self._tracer.set_profiling(False)
             timer = getattr(self, "_profile_timer", None)
             if timer is not None:
                 timer.cancel()
@@ -752,6 +756,10 @@ class LLMEngine:
 
     def has_unfinished_requests(self) -> bool:
         return self.scheduler.has_unfinished_seqs()
+
+    def _has_work(self) -> bool:
+        """Unfinished requests, or steps dispatched and not yet fetched."""
+        return self.scheduler.has_unfinished_seqs() or bool(self._inflight)
 
     # --- the hot loop -----------------------------------------------------
 
@@ -1024,6 +1032,55 @@ class LLMEngine:
         scheduler_outputs: SchedulerOutputs,
         is_step_boundary: bool = True,
     ) -> List[RequestOutput]:
+        with self._tracer.span("process_outputs"):
+            request_outputs = self._apply_model_outputs(
+                outputs_per_substep, scheduler_outputs)
+
+        # Drain the step-phase tracer even with stats logging off, so the
+        # breakdown stays readable off the engine (tests, benches). Only
+        # the once-per-logical-step call sites drain (is_step_boundary);
+        # the pipelined dispatch path may process ignored/swap-only plans
+        # mid-step, and an early drain there would consume the step timer
+        # and split one step's breakdown across multiple StatLogger rows.
+        phases: Dict[str, float] = {}
+        step_time = 0.0
+        step_gap = None
+        if is_step_boundary:
+            phases, step_time = self._tracer.end_step(
+                work_pending=self._has_work())
+            step_gap = self._tracer.last_gap
+            if phases or step_time:
+                self.last_step_phases = phases
+                self.last_step_time = step_time
+                self.last_step_gap = step_gap
+
+        # The telemetry's own cost: after end_step(), so it carries into
+        # the next drain like the other between-step phases.
+        with self._tracer.span("stats"):
+            if is_step_boundary:
+                self._watchdog.heartbeat_step()
+                # Fold this step's wall time into the rolling MFU (works
+                # with stats logging off — benches read the gauge/ledger).
+                self._efficiency.record_step(step_time)
+                # Cost-model MFU cross-check + the capture endpoint's
+                # step counter (obs/kernels.py).
+                self._kernel_ledger.record_step(step_time)
+            if self.stat_logger is not None:
+                stats = self._get_stats(scheduler_outputs)
+                stats.step_phase_times = phases
+                stats.step_time = step_time
+                stats.step_gap = step_gap
+                self.stat_logger.log(stats)
+        return request_outputs
+
+    def _apply_model_outputs(
+        self,
+        outputs_per_substep: List[SamplerOutput],
+        scheduler_outputs: SchedulerOutputs,
+    ) -> List[RequestOutput]:
+        """Append the step's tokens to their sequences, check stops, run
+        the finish hooks and build the RequestOutputs (the
+        `process_outputs` phase; `detokenize` nests inside)."""
         now = time.monotonic()
         scheduled_seq_groups = scheduler_outputs.scheduled_seq_groups
         for idx, seq_group in enumerate(scheduled_seq_groups):
@@ -1152,32 +1209,6 @@ class LLMEngine:
             if chunk is not None and chunk[2]:
                 seq_group.prefix.computed = True
 
-        # Drain the step-phase tracer even with stats logging off, so the
-        # breakdown stays readable off the engine (tests, benches). Only
-        # the once-per-logical-step call sites drain (is_step_boundary);
-        # the pipelined dispatch path may process ignored/swap-only plans
-        # mid-step, and an early drain there would consume the step timer
-        # and split one step's breakdown across multiple StatLogger rows.
-        phases: Dict[str, float] = {}
-        step_time = 0.0
-        if is_step_boundary:
-            phases, step_time = self._tracer.end_step()
-            if phases or step_time:
-                self.last_step_phases = phases
-                self.last_step_time = step_time
-            self._watchdog.heartbeat_step()
-            # Fold this step's wall time into the rolling MFU (works
-            # with stats logging off — benches read the gauge/ledger).
-            self._efficiency.record_step(step_time)
-            # Cost-model MFU cross-check + the capture endpoint's step
-            # counter (obs/kernels.py).
-            self._kernel_ledger.record_step(step_time)
-
-        if self.stat_logger is not None:
-            stats = self._get_stats(scheduler_outputs)
-            stats.step_phase_times = phases
-            stats.step_time = step_time
-            self.stat_logger.log(stats)
         return request_outputs
 
     # --- per-group output processing (incl. beam search) ------------------

@@ -50,6 +50,9 @@ class Stats:
     # tracing is disabled.
     step_phase_times: Dict[str, float] = field(default_factory=dict)
     step_time: float = 0.0
+    # Loop gap before this step: previous end_step() -> this begin_step(),
+    # None when the engine had no work pending then (or tracing is off).
+    step_gap: Optional[float] = None
 
 
 def _fmt_bytes(n: float) -> str:
@@ -121,6 +124,13 @@ class _Metrics:
             "Total wall time of one engine step.", labelnames,
             buckets=[0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                      0.5, 1.0, 2.5, 5.0])
+        self.histogram_step_gap = Histogram(
+            "intellillm_step_gap_seconds",
+            "Loop gap: wall time from one engine step's end to the next "
+            "step's begin while work was pending (obs.tracing).",
+            labelnames,
+            buckets=[0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                     0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5])
 
     @classmethod
     def reset_for_testing(cls) -> None:
@@ -156,6 +166,7 @@ class StatLogger:
         # Interval accumulators for the "step breakdown" log line.
         self.phase_seconds: Dict[str, float] = {}
         self.step_seconds: float = 0.0
+        self.gap_seconds: float = 0.0
         self.num_steps: int = 0
         self.metrics = _Metrics(list(labels.keys())) if _PROMETHEUS else None
 
@@ -188,6 +199,8 @@ class StatLogger:
                 m.histogram_step_phase.labels(*lv, phase).observe(secs)
             if stats.step_time > 0.0:
                 m.histogram_step_time.labels(*lv).observe(stats.step_time)
+            if stats.step_gap is not None:
+                m.histogram_step_gap.labels(*lv).observe(stats.step_gap)
 
         self.num_prompt_tokens.append(stats.num_prompt_tokens)
         self.num_generation_tokens.append(stats.num_generation_tokens)
@@ -196,6 +209,7 @@ class StatLogger:
                 self.phase_seconds.get(phase, 0.0) + secs)
         if stats.step_time > 0.0 or stats.step_phase_times:
             self.step_seconds += stats.step_time
+            self.gap_seconds += stats.step_gap or 0.0
             self.num_steps += 1
 
         if stats.now - self.last_local_log > self.local_interval:
@@ -227,18 +241,25 @@ class StatLogger:
                 ordered += [p for p in self.phase_seconds
                             if p not in ordered]
                 covered = sum(self.phase_seconds.values())
-                other = max(self.step_seconds - covered, 0.0)
+                # The loop's period is step + gap; the between-step phases
+                # (stats ... handoff) fall in the gap.
+                period = self.step_seconds + self.gap_seconds
+                other = max(period - covered, 0.0)
                 parts = ["%s %.1fms" % (
                     p, self.phase_seconds[p] / self.num_steps * 1e3)
                     for p in ordered]
                 parts.append("other %.1fms" % (other / self.num_steps * 1e3))
-                logger.info("Step breakdown over %d steps (avg/step): %s",
-                            self.num_steps, ", ".join(parts))
+                logger.info("Step breakdown over %d steps (avg/step): %s; "
+                            "step %.1fms + loop gap %.1fms",
+                            self.num_steps, ", ".join(parts),
+                            self.step_seconds / self.num_steps * 1e3,
+                            self.gap_seconds / self.num_steps * 1e3)
             self._log_slo_summary()
             self.num_prompt_tokens = []
             self.num_generation_tokens = []
             self.phase_seconds = {}
             self.step_seconds = 0.0
+            self.gap_seconds = 0.0
             self.num_steps = 0
             self.last_local_log = stats.now
 

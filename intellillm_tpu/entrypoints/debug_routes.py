@@ -424,10 +424,11 @@ def add_debug_routes(app: web.Application,
         return web.json_response({"ok": True})
 
     async def profiler_capture(request: web.Request) -> web.Response:
-        """Bounded capture-and-parse (obs/kernels.py): profile N engine
-        steps into a temp dir, fold the trace into per-op wall-time
-        totals, merge the top-K ops into the kernel ledger, and delete
-        the trace — no caller-chosen paths, no unbounded trace left
+        """Bounded capture-and-reduce (obs/kernels.py): profile N engine
+        steps into a temp dir, reduce the trace (device busy union and
+        idle, operations by self time, idle gaps by host phase), merge
+        the block into the kernel ledger, and delete the trace — no
+        caller-chosen paths, no unbounded trace left
         running (the step wait is capped by
         INTELLILLM_PROFILER_CAPTURE_TIMEOUT_S on idle engines, and the
         engine's INTELLILLM_PROFILER_MAX_S watchdog backstops both)."""
@@ -436,7 +437,7 @@ def add_debug_routes(app: web.Application,
             return web.json_response({"error": "engine not ready"},
                                      status=503)
         from intellillm_tpu.obs.kernels import (capture_max_steps,
-                                                parse_trace_dir,
+                                                capture_profile,
                                                 wait_for_steps)
         try:
             steps = int(request.query.get("steps", "8"))
@@ -459,10 +460,11 @@ def add_debug_routes(app: web.Application,
             observed = await loop.run_in_executor(
                 None, wait_for_steps, ledger, steps)
             await loop.run_in_executor(None, engine.stop_profile)
-            ops = await loop.run_in_executor(None, parse_trace_dir, tmpdir)
+            reduced = await loop.run_in_executor(None, capture_profile,
+                                                 tmpdir)
         finally:
             shutil.rmtree(tmpdir, ignore_errors=True)
-        block = ledger.merge_profile(ops, steps=observed, top=top)
+        block = ledger.merge_profile(reduced, steps=observed, top=top)
         return web.json_response({
             "steps_requested": steps,
             "steps_observed": observed,

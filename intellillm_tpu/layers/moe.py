@@ -54,7 +54,8 @@ def moe_ffn_dense(
     t, d = x.shape
     n = w1.shape[0]
 
-    topw, topi = _route(x, gate_w, top_k, renormalize)
+    with jax.named_scope("moe_router"):
+        topw, topi = _route(x, gate_w, top_k, renormalize)
     onehot = jax.nn.one_hot(topi, n, dtype=jnp.float32)       # [T, K, N]
     combine = (topw[..., None] * onehot).sum(axis=1)          # [T, N]
 
@@ -77,7 +78,10 @@ def moe_ffn_dense(
         mixed = jnp.einsum("ntd,tn->td", out, cc)
         return carry, mixed.astype(x.dtype)
 
-    _, outs = jax.lax.scan(chunk_fn, None, (x_chunks, c_chunks))
+    # named_scope: metadata only, so that the trace's device operations
+    # carry their layer (obs/kernels.py, docs/observability.md).
+    with jax.named_scope("moe_experts"):
+        _, outs = jax.lax.scan(chunk_fn, None, (x_chunks, c_chunks))
     return outs.reshape(pad_t, d)[:t]
 
 
@@ -95,7 +99,8 @@ def moe_ffn_grouped(
     n = w1.shape[0]
     tk = t * top_k
 
-    topw, topi = _route(x, gate_w, top_k, renormalize)
+    with jax.named_scope("moe_router"):
+        topw, topi = _route(x, gate_w, top_k, renormalize)
 
     flat_e = topi.reshape(-1)                                  # [T*K]
     flat_w = topw.reshape(-1)                                  # [T*K]
@@ -133,8 +138,9 @@ def moe_ffn_grouped(
         h = (jax.nn.silu(h1) * h3).astype(x.dtype)
         return carry, jnp.dot(h, w2e, preferred_element_type=jnp.float32)
 
-    _, out_blocks = jax.lax.scan(body, None,
-                                 (xb.reshape(nb, block, d), blk_expert))
+    with jax.named_scope("moe_experts"):
+        _, out_blocks = jax.lax.scan(body, None,
+                                     (xb.reshape(nb, block, d), blk_expert))
     out = out_blocks.reshape(s, d)                             # f32
 
     contrib = out[slot] * flat_w[sort_idx][:, None]            # [T*K, D]

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -71,9 +72,10 @@ class DeepseekForCausalLM(LlamaForCausalLM):
         out = moe_ffn(flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
                       self.top_k, renormalize=self.renormalize)
         if self.n_shared:
-            gate = flat @ lp["shared_gate"]
-            up = flat @ lp["shared_up"]
-            out = out + (self.act(gate) * up) @ lp["shared_down"]
+            with jax.named_scope("moe_shared"):
+                gate = flat @ lp["shared_gate"]
+                up = flat @ lp["shared_up"]
+                out = out + (self.act(gate) * up) @ lp["shared_down"]
         return out.reshape(b, l, e), residual, kv_cache
 
     def partition_specs(self):

@@ -24,7 +24,7 @@ from intellillm_tpu.obs.flight_recorder import (EVENTS, FlightRecorder,
                                                 get_flight_recorder)
 from intellillm_tpu.obs.history import MetricsHistory, get_metrics_history
 from intellillm_tpu.obs.kernels import (KernelLedger, get_kernel_ledger,
-                                        parse_trace_dir)
+                                        load_profile, reduce_profile)
 from intellillm_tpu.obs.kv_transfer import (KVTransferStats,
                                             get_kv_transfer_stats)
 from intellillm_tpu.obs.numerics import (CanaryLedger, KVIntegrityAuditor,
@@ -100,7 +100,8 @@ __all__ = [
     "numerics_debug_snapshot",
     "numerics_health_block",
     "parse_iwl",
-    "parse_trace_dir",
+    "load_profile",
+    "reduce_profile",
     "record_kernel_dispatch",
     "request_context",
     "sanitize_request_id",

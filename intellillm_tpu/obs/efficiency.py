@@ -58,6 +58,10 @@ except ImportError:  # pragma: no cover
     _PROMETHEUS = False
 
 PHASES = ("prefill", "decode")
+# The device programs whose dispatches are counted by name, and what the
+# device was doing when the host dispatched one.
+PROGRAMS = ("mixed", "decode_fused", "decode_cont")
+DEVICE_STATES = ("idle", "busy")
 TOKEN_KINDS = ("real", "pad")
 AXES = ("batch", "len", "block_width")
 _DEFAULT_MFU_WINDOW = 64
@@ -108,11 +112,33 @@ class _EfficiencyMetrics:
             "Rolling model FLOPs utilization: analytic per-token FLOPs x "
             "real tokens / (step wall-time x per-chip peak FLOPs). NaN "
             "when the chip's peak is unknown (e.g. CPU backend).")
+        self.counter_dispatch = Counter(
+            "intellillm_dispatch_total",
+            "Device dispatches by program (mixed | decode_fused | "
+            "decode_cont) and by whether anything dispatched earlier was "
+            "still running on the device at that moment (device: idle | "
+            "busy). device=idle is the profiler-free twin of a trace's "
+            "'idle gap before program X'.", ["program", "device"])
+        self.counter_dispatch_row_steps = Counter(
+            "intellillm_dispatch_row_steps_total",
+            "Live rows x substeps of the dispatched programs: attention "
+            "calls per layer, one per row and substep.", ["program"])
+        self.counter_dispatch_context_tokens = Counter(
+            "intellillm_dispatch_context_tokens_total",
+            "Context lengths the attention kernel walks, summed over the "
+            "live rows and substeps of the dispatched programs.",
+            ["program"])
         # Pre-create the label children so the series exist (at 0) from
-        # the first scrape, before any dispatch happens.
+        # the first scrape, before any dispatch happens: a ratio over a
+        # series that never appeared reads as "no answer", not as 0.
         for kind in TOKEN_KINDS:
             for phase in PHASES:
                 self.counter_tokens.labels(kind, phase)
+        for program in PROGRAMS:
+            for device in DEVICE_STATES:
+                self.counter_dispatch.labels(program, device)
+            self.counter_dispatch_row_steps.labels(program)
+            self.counter_dispatch_context_tokens.labels(program)
 
     @classmethod
     def reset_for_testing(cls) -> None:
@@ -200,6 +226,12 @@ class EfficiencyTracker:
         self._tokens: Dict[str, Dict[str, int]] = {
             phase: {kind: 0 for kind in TOKEN_KINDS} for phase in PHASES}
         self._dispatches: Dict[str, int] = {phase: 0 for phase in PHASES}
+        # program -> device dispatches by device state, row-steps and
+        # context tokens (the counts a kernel's bytes are computed from).
+        self._programs: Dict[str, Dict[str, int]] = {
+            program: dict.fromkeys(
+                DEVICE_STATES + ("row_steps", "context_tokens"), 0)
+            for program in PROGRAMS}
         # (phase, axis) -> [sum of fill ratios, observations]
         self._fill: Dict[Tuple[str, str], List[float]] = {}
         # (phase, batch_bucket, inner_bucket) -> cumulative waste row;
@@ -302,10 +334,21 @@ class EfficiencyTracker:
                         len_real: Optional[int] = None,
                         len_padded: Optional[int] = None,
                         width_real: Optional[int] = None,
-                        width_padded: Optional[int] = None) -> None:
+                        width_padded: Optional[int] = None,
+                        program: Optional[str] = None,
+                        device_idle: bool = False,
+                        row_steps: int = 0,
+                        context_tokens: int = 0) -> None:
         """Account one device dispatch. Extents are pre-padding vs
         post-padding; token counts are what the device actually
-        computes (prefill: rows x padded len; decode: rows x substeps)."""
+        computes (prefill: rows x padded len; decode: rows x substeps).
+
+        `program` counts the dispatch itself, once: a mixed step that
+        reports its prefill and its decode side separately names the
+        program on one of the two calls only. `device_idle`: nothing
+        dispatched earlier was still running. `row_steps` / `context_tokens`:
+        live rows x substeps, and the context lengths attention walks
+        summed over them."""
         if not self.enabled:
             return
         real_tokens = int(real_tokens)
@@ -339,8 +382,19 @@ class EfficiencyTracker:
             row["dispatches"] += 1
             row["real_tokens"] += real_tokens
             row["pad_tokens"] += pad_tokens
+            device = "idle" if device_idle else "busy"
+            if program is not None:
+                counts = self._programs[program]
+                counts[device] += 1
+                counts["row_steps"] += int(row_steps)
+                counts["context_tokens"] += int(context_tokens)
         if self._metrics is not None:
             m = self._metrics
+            if program is not None:
+                m.counter_dispatch.labels(program, device).inc()
+                m.counter_dispatch_row_steps.labels(program).inc(row_steps)
+                m.counter_dispatch_context_tokens.labels(program).inc(
+                    context_tokens)
             m.counter_tokens.labels("real", phase).inc(real_tokens)
             m.counter_tokens.labels("pad", phase).inc(pad_tokens)
             for axis, ratio in fills:
@@ -443,6 +497,8 @@ class EfficiencyTracker:
                                  if real + pad else None),
                 "fill_ratio_avg": fill_avg,
                 "dispatches": dict(self._dispatches),
+                "programs": {program: dict(counts) for program, counts
+                             in self._programs.items()},
                 "co_batched_steps": self._co_batched_steps,
                 "warmup_excluded_dispatches": self._warmup_excluded,
                 "top_waste": buckets[:top_n],

@@ -18,13 +18,21 @@ becomes a ledger entry with FLOPs, bytes accessed, argument/output/
 temp/peak HBM, compile-path wall time, a derived roofline intensity
 (FLOPs per byte accessed), and a dispatch counter.
 
-**Measured wall-time attribution on demand.** `POST
+**Measured device time on demand.** `POST
 /debug/profiler/capture?steps=N` (entrypoints/debug_routes.py) runs a
-bounded jax.profiler trace around N engine steps, then
-`parse_trace_dir()` reads the `*.trace.json.gz` the profiler wrote and
-sums per-op wall time host-side; `merge_profile()` stores the top-K op
-table next to the static feed so cost-model FLOPs sit beside measured
-seconds in one `GET /debug/kernels` response.
+bounded jax.profiler trace around N engine steps, then `load_profile()`
+reads the `.xplane.pb` the profiler wrote (`jax.profiler.ProfileData`)
+and `reduce_profile()` folds it: device window, busy time as the UNION
+of the device op line's intervals, idle, operations by SELF time (an
+enclosing `while` or program is not counted on top of its body), time
+by program, and every idle gap split over the `intellillm/<phase>`
+annotations that obs/tracing.py writes into the host plane while the
+trace runs — which host phase the device was waiting for.
+`merge_profile()` stores the block next to the static feed so cost-model
+FLOPs sit beside measured seconds in one `GET /debug/kernels` response.
+(The arithmetic is that of benchmarks/chip/harness/trace_reduce.py,
+written again here because the program may not import the benchmark; a
+test holds the two to the same busy, idle and self times.)
 
 **MFU cross-check.** `record_step()` (engine step boundary) folds the
 cost-model FLOPs dispatched that step into a rolling window and exports
@@ -45,14 +53,14 @@ INTELLILLM_KERNEL_LEDGER=0 disables everything (hooks become no-ops).
 """
 from __future__ import annotations
 
-import gzip
-import json
+import bisect
+import glob
 import math
 import os
+import re
 import threading
 import time
 from collections import deque
-from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
 from intellillm_tpu.logger import init_logger
@@ -463,27 +471,24 @@ class KernelLedger:
         with self._lock:
             return self._num_steps
 
-    def merge_profile(self, ops: List[Dict[str, Any]], *, steps: int,
-                      top: int = 16) -> Dict[str, Any]:
-        """Store a capture's per-op wall-time table (top-K by total
-        time) next to the static feed. Returns the stored block."""
-        total_us = sum(op.get("total_us") or 0.0 for op in ops)
-        table = []
-        for op in ops[:max(int(top), 1)]:
-            op_total = float(op.get("total_us") or 0.0)
-            table.append({
-                "name": str(op.get("name")),
-                "total_us": round(op_total, 3),
-                "count": int(op.get("count") or 0),
-                "share": (round(op_total / total_us, 4)
-                          if total_us > 0 else None),
-            })
-        block = {
-            "steps": int(steps),
-            "ops_total": len(ops),
-            "total_us": round(total_us, 3),
-            "ops": table,
-        }
+    def merge_profile(self, reduced: Optional[Dict[str, Any]], *,
+                      steps: int, top: int = 16) -> Dict[str, Any]:
+        """Store a capture's reduction (reduce_profile's result; None for
+        a capture that left no readable trace) next to the static feed,
+        its operation table cut to the top-K by self time and its
+        (phase, program) gap table to the top 2K. Returns the stored
+        block."""
+        block: Dict[str, Any] = dict(reduced or {
+            "window_s": 0.0, "busy_s": 0.0, "idle_s": 0.0,
+            "idle_share": None, "devices": [], "ops": [], "programs": [],
+            "idle_by_phase": {}, "idle_by_phase_program": [],
+            "annotations": {}})
+        ops = block["ops"]
+        block["ops_total"] = len(ops)
+        block["ops"] = ops[:max(int(top), 1)]
+        block["idle_by_phase_program"] = block["idle_by_phase_program"][
+            :2 * max(int(top), 1)]
+        block["steps"] = int(steps)
         with self._lock:
             block["captured_at_step"] = self._num_steps
             self._profile = block
@@ -603,63 +608,290 @@ class KernelLedger:
         self.__init__()
 
 
-def parse_trace_dir(logdir: str) -> List[Dict[str, Any]]:
-    """Fold the Chrome-trace JSON a jax.profiler capture wrote under
-    `logdir` into per-op wall-time totals, descending.
+# --- the profiler capture's reader ------------------------------------------
+#
+# doc = {"planes": [{"name", "lines": [{"name", "events":
+#        [[name, start_ns, duration_ns], ...]}]}]}: device planes whole, of
+# the host planes only the tracer's annotations. All planes share a clock.
 
-    The profiler writes `plugins/profile/<ts>/<host>.trace.json.gz`
-    whose `traceEvents` hold 'M' (metadata: pid -> process name) and
-    'X' (complete: pid/tid/ts/dur in µs) events. Device lanes are named
-    `/device:TPU:N ...`; when any exist, host-side python lanes are
-    dropped so the table is kernel time, not tracing overhead. On the
-    CPU backend everything shares one `/host:CPU` lane, where python
-    source-line frames (names `$`-prefixed, e.g. `$pjit.py:330
-    cache_miss`) are filtered so the totals cover op/executable events.
-    Returns [] on a missing/empty/corrupt trace — the capture endpoint
-    surfaces that as ops_total=0, not a 500."""
-    paths = sorted(Path(logdir).rglob("*.trace.json.gz"))
-    paths += sorted(Path(logdir).rglob("*.trace.json"))
+_DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
+_HOST_PLANE = re.compile(r"^/host:")
+_OP_LINE = re.compile(r"^XLA Ops$")
+_MODULE_LINE = re.compile(r"^XLA Modules$")
+# The CPU backend has no device plane: its executables' thunks run on the
+# client's own threads in the host plane, between bookkeeping events.
+_CPU_OP_LINE = re.compile(r"^tf_XLA\w*CpuClient")
+_CPU_NOT_AN_OP = re.compile(r"::|^end: |\(wait for completion\)")
+# jit names of the runner's step functions -> the programs' names.
+_PROGRAM_OF_MODULE = {"jit__decode_fn_single": "mixed",
+                      "jit__decode_fn": "decode_fused",
+                      "jit__decode_cont_fn": "decode_cont",
+                      "jit__decode_teacher_fn": "decode_teacher"}
+
+
+def load_profile(logdir: str) -> Optional[Dict[str, Any]]:
+    """The newest `*.xplane.pb` under `logdir` as a plain doc, or None
+    when there is none or it cannot be read (the capture endpoint then
+    answers an empty block, not a 500). jax's reader is imported here:
+    nothing on a serving path pays for it."""
+    paths = sorted(glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+    if not paths:
+        return None
+    from intellillm_tpu.obs.tracing import ANNOTATION_PREFIX
+    try:
+        from jax.profiler import ProfileData
+        data = ProfileData.from_file(paths[-1])
+        planes = []
+        for plane in data.planes:
+            device = bool(_DEVICE_PLANE.search(plane.name))
+            if not (device or _HOST_PLANE.search(plane.name)):
+                continue
+            lines = []
+            for line in plane.lines:
+                keep_all = device or _CPU_OP_LINE.search(line.name)
+                events = [[ev.name, int(ev.start_ns), int(ev.duration_ns)]
+                          for ev in line.events
+                          if keep_all
+                          or ev.name.startswith(ANNOTATION_PREFIX)]
+                if events:
+                    lines.append({"name": line.name, "events": events})
+            planes.append({"name": plane.name, "lines": lines})
+    except Exception as e:
+        logger.warning("Kernel ledger: unreadable profile %s (%s).",
+                       paths[-1], e)
+        return None
+    return {"planes": planes, "file": os.path.basename(paths[-1])}
+
+
+def _union(intervals: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
+    """Merged, sorted [start, end) intervals."""
+    out: List[List[int]] = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return [(s, e) for s, e in out]
+
+
+def _self_times(events: List[list]) -> Dict[str, List[float]]:
+    """name -> [self nanoseconds, count] on one line: an event's duration
+    minus the events nested directly inside it."""
     totals: Dict[str, List[float]] = {}
-    for path in paths:
-        try:
-            if path.suffix == ".gz":
-                with gzip.open(path, "rt", encoding="utf-8",
-                               errors="replace") as f:
-                    doc = json.load(f)
-            else:
-                doc = json.loads(path.read_text(encoding="utf-8",
-                                                errors="replace"))
-        except Exception as e:
-            logger.warning("Kernel ledger: unreadable trace file %s (%s).",
-                           path, e)
-            continue
-        events = doc.get("traceEvents") or []
-        pid_names: Dict[Any, str] = {}
-        for ev in events:
-            if (ev.get("ph") == "M"
-                    and ev.get("name") == "process_name"):
-                pid_names[ev.get("pid")] = str(
-                    (ev.get("args") or {}).get("name", ""))
-        device_pids = {pid for pid, name in pid_names.items()
-                       if "/device:" in name}
-        for ev in events:
-            if ev.get("ph") != "X":
-                continue
-            if device_pids and ev.get("pid") not in device_pids:
-                continue
-            name = ev.get("name")
-            dur = ev.get("dur")
-            if not name or not isinstance(dur, (int, float)):
-                continue
-            if str(name).startswith("$"):
-                continue
-            cell = totals.setdefault(str(name), [0.0, 0])
-            cell[0] += float(dur)
+    stack: List[list] = []      # [name, end, self_ns]
+
+    def close(upto: float) -> None:
+        while stack and stack[-1][1] <= upto:
+            name, _, self_ns = stack.pop()
+            cell = totals.setdefault(name, [0.0, 0])
+            cell[0] += max(0, self_ns)
             cell[1] += 1
-    ops = [{"name": name, "total_us": total, "count": count}
-           for name, (total, count) in totals.items()]
-    ops.sort(key=lambda op: op["total_us"], reverse=True)
-    return ops
+
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        close(start)
+        if stack:
+            stack[-1][2] -= min(dur, stack[-1][1] - start)
+        stack.append([name, start + dur, dur])
+    close(float("inf"))
+    return totals
+
+
+def _innermost_segments(spans: List[Tuple[int, int, str]]
+                        ) -> List[Tuple[int, int, str]]:
+    """Annotation spans (start, end, phase), nested or on several threads
+    -> disjoint sorted segments, each instant given to the span that began
+    last among those covering it (the innermost)."""
+    out: List[Tuple[int, int, str]] = []
+    stack: List[Tuple[int, str]] = []        # (end, phase)
+    cursor = 0
+
+    def advance(upto: float) -> None:
+        nonlocal cursor
+        while stack:
+            while stack and stack[-1][0] <= cursor:
+                stack.pop()
+            if not stack or cursor >= upto:
+                return
+            end = min(stack[-1][0], upto)
+            out.append((cursor, end, stack[-1][1]))
+            cursor = end
+
+    for start, end, phase in sorted(spans, key=lambda x: (x[0], -x[1])):
+        advance(start)
+        cursor = start
+        stack.append((end, phase))
+    advance(float("inf"))
+    return out
+
+
+def _split_gap(g0: int, g1: int, segments: List[Tuple[int, int, str]],
+               starts: List[int]) -> Dict[str, int]:
+    """Nanoseconds of the idle gap [g0, g1) by host phase. An instant no
+    annotation covers is a thread crossing if it lies between the
+    annotations at either side of one (`handoff` after admit, `handback`
+    before stream_out), else `unannotated`."""
+    out: Dict[str, int] = {}
+
+    def add(phase: str, ns: int) -> None:
+        if ns > 0:
+            out[phase] = out.get(phase, 0) + ns
+
+    def hole(before: Optional[str], after: Optional[str]) -> str:
+        if before == "admit":
+            return "handoff"
+        if after == "stream_out" and before not in (None, "stream_out"):
+            return "handback"
+        return "unannotated"
+
+    k = max(bisect.bisect_right(starts, g0) - 1, 0)
+    before = segments[k - 1][2] if k > 0 else None
+    cursor = g0
+    while k < len(segments) and segments[k][0] < g1:
+        s, e, phase = segments[k]
+        if e > cursor:
+            add(hole(before, phase), min(s, g1) - cursor)
+            lo = max(s, cursor)
+            add(phase, min(e, g1) - lo)
+            cursor = max(cursor, min(e, g1))
+        before = phase
+        k += 1
+    after = segments[k][2] if k < len(segments) else None
+    add(hole(before, after), g1 - cursor)
+    return out
+
+
+def _program_name(module_event: str) -> str:
+    base = re.sub(r"\(\d+\)$", "", module_event)
+    return _PROGRAM_OF_MODULE.get(base, base)
+
+
+def reduce_profile(doc: Dict[str, Any]) -> Dict[str, Any]:
+    """Busy union, idle, self time by operation, time by program, and the
+    idle gaps by host phase — averaged over the device planes. Raises
+    ValueError when the doc holds no device operations."""
+    from intellillm_tpu.obs.tracing import ANNOTATION_PREFIX
+    devices = [p for p in doc["planes"] if _DEVICE_PLANE.search(p["name"])]
+    op_line, cpu = _OP_LINE, False
+    if not devices:
+        devices = [p for p in doc["planes"] if _HOST_PLANE.search(p["name"])]
+        op_line, cpu = _CPU_OP_LINE, True
+    spans: List[Tuple[int, int, str]] = []
+    annotations: Dict[str, int] = {}
+    for plane in doc["planes"]:
+        if not _HOST_PLANE.search(plane["name"]):
+            continue
+        for line in plane["lines"]:
+            for name, start, dur in line["events"]:
+                if name.startswith(ANNOTATION_PREFIX):
+                    phase = name[len(ANNOTATION_PREFIX):]
+                    spans.append((start, start + dur, phase))
+                    annotations[phase] = annotations.get(phase, 0) + 1
+    segments = _innermost_segments(spans)
+    seg_starts = [s for s, _, _ in segments]
+
+    per_device = []
+    first, last = [], []
+    ops: Dict[str, List[float]] = {}
+    programs: Dict[str, List[float]] = {}
+    by_phase: Dict[str, float] = {}
+    by_phase_program: Dict[Tuple[str, str], List[float]] = {}
+    gap_inputs = []
+    for plane in devices:
+        op_events = [e for ln in plane["lines"] if op_line.search(ln["name"])
+                     for e in ln["events"]
+                     if not (cpu and (e[2] <= 0
+                                      or _CPU_NOT_AN_OP.search(e[0])))]
+        if not op_events:
+            continue
+        mod_events = [e for ln in plane["lines"]
+                      if _MODULE_LINE.search(ln["name"])
+                      for e in ln["events"]]
+        busy = _union([(s, s + d) for _, s, d in op_events])
+        per_device.append({"plane": plane["name"],
+                           "busy_s": sum(e - s for s, e in busy) / 1e9,
+                           "op_events": len(op_events)})
+        first.append(busy[0][0])
+        last.append(busy[-1][1])
+        for name, (ns, n) in _self_times(op_events).items():
+            cell = ops.setdefault(name, [0.0, 0])
+            cell[0] += ns
+            cell[1] += n
+        for name, _, d in mod_events:
+            cell = programs.setdefault(_program_name(name), [0.0, 0])
+            cell[0] += d
+            cell[1] += 1
+        gap_inputs.append((busy, sorted((s, name) for name, s, _ in
+                                        mod_events),
+                           # of two operations that start together, the
+                           # enclosing one
+                           {s: name for name, s, _ in
+                            sorted(op_events, key=lambda e: e[2])}))
+    if not per_device:
+        raise ValueError(
+            "no device operations in the profile; its planes are "
+            f"{[p['name'] for p in doc['planes']]}")
+    n = len(per_device)
+    w0, w1 = min(first), max(last)
+    window_s = (w1 - w0) / 1e9
+    busy_s = sum(d["busy_s"] for d in per_device) / n
+    # Idle gaps: between two busy intervals of a device, and from the
+    # window's edges to a device's first and last operation (several chips
+    # do not start and end together), so that they add up to window - busy.
+    # A gap is also named by what ended it: the program that starts at its
+    # end, else the operation (its name up to the `=`).
+    for busy, mod_starts, op_starts in gap_inputs:
+        edges = [(w0, w0)] + busy + [(w1, w1)]
+        for (_, g0), (g1, _) in zip(edges, edges[1:]):
+            if g1 <= g0:
+                continue
+            k = bisect.bisect_left(mod_starts, (g0, ""))
+            if k < len(mod_starts) and mod_starts[k][0] <= g1:
+                ended_by = _program_name(mod_starts[k][1])
+            elif g1 in op_starts:
+                ended_by = op_starts[g1].split(" = ")[0][:80]
+            else:
+                ended_by = "end of window"
+            for phase, ns in _split_gap(g0, g1, segments,
+                                        seg_starts).items():
+                by_phase[phase] = by_phase.get(phase, 0.0) + ns
+                cell = by_phase_program.setdefault((phase, ended_by),
+                                                   [0.0, 0])
+                cell[0] += ns
+                cell[1] += 1
+
+    def table(cells: Dict[str, List[float]]) -> List[list]:
+        return sorted(([name, ns / 1e9 / n, cnt]
+                       for name, (ns, cnt) in cells.items()),
+                      key=lambda r: -r[1])
+
+    idle_s = window_s - busy_s
+    return {
+        "window_s": window_s, "busy_s": busy_s,
+        "idle_s": idle_s,
+        "idle_share": idle_s / window_s if window_s else None,
+        "devices": per_device,
+        "ops": table(ops), "programs": table(programs),
+        "idle_by_phase": {phase: ns / 1e9 / n for phase, ns in
+                          sorted(by_phase.items(), key=lambda kv: -kv[1])},
+        "idle_by_phase_program": sorted(
+            ([phase, prog, ns / 1e9 / n, cnt] for (phase, prog), (ns, cnt)
+             in by_phase_program.items()), key=lambda r: -r[2]),
+        "annotations": annotations,
+    }
+
+
+def capture_profile(logdir: str) -> Optional[Dict[str, Any]]:
+    """load_profile + reduce_profile for the capture endpoint: None when
+    the capture left nothing to reduce."""
+    doc = load_profile(logdir)
+    if doc is None:
+        return None
+    try:
+        return reduce_profile(doc)
+    except ValueError as e:
+        logger.warning("Kernel ledger: %s", e)
+        return None
 
 
 _LEDGER: Optional[KernelLedger] = None

@@ -132,12 +132,16 @@ class InflightStep:
             # a 1-token prompt's final chunk) — only finals to assemble.
             host_plp = None
             if plp_dev is not None:
-                # lint: allow(host-sync) reason=the designed single D2H point for prompt logprobs: the panel must reach the host to be attached to request output
-                host_plp = np.asarray(plp_dev)
+                with r._tracer.span("fetch_wait"):
+                    # lint: allow(host-sync) reason=the designed single D2H point for prompt logprobs: the panel must reach the host to be attached to request output
+                    host_plp = np.asarray(plp_dev)
             r._attach_prompt_logprobs(host_plp, plp_k, jobs, finals)
-        # lint: allow(host-sync) reason=the one intentional fetch per step: sampled ids must cross to the host here so the engine can emit tokens; everything upstream stays async
-        packed = np.array(self.packed) if self.proc else np.asarray(
-            self.packed)
+        # The host waits for the device here and nowhere else in the step:
+        # its own phase, so that `sample` is host work only.
+        with r._tracer.span("fetch_wait"):
+            # lint: allow(host-sync) reason=the one intentional fetch per step: sampled ids must cross to the host here so the engine can emit tokens; everything upstream stays async
+            packed = np.array(self.packed) if self.proc else np.asarray(
+                self.packed)
         sampled, sampled_lp, topk_ids, topk_lp = r._unpack(
             packed, self.t1, self.t2, self.logprob_k)
         if self.proc:
@@ -195,6 +199,9 @@ class ModelRunner:
         self._compile_tracker = get_compile_tracker()
         self._efficiency = get_efficiency_tracker()
         self._kernel_ledger = get_kernel_ledger()
+        # Output of the newest dispatch: XLA runs programs in order, so
+        # its readiness says whether the device still has work queued.
+        self._last_packed = None
         from intellillm_tpu.obs import get_numerics_tracker
         self._numerics = get_numerics_tracker()
 
@@ -291,6 +298,12 @@ class ModelRunner:
             sds(f32, b), None, None, logprob_k=logprob_k, do_topk=False,
             do_topp=False, do_minp=False, do_penalties=False,
             do_random=do_random)
+
+    def _device_idle(self) -> bool:
+        """Whether nothing dispatched earlier is still running on the
+        device: one non-blocking readiness query, never a wait."""
+        last = self._last_packed
+        return last is None or last.is_ready()
 
     def _guarded_call(self, program, key, fn, /, *args, **kwargs):
         """Every jitted dispatch goes through here: compile tracking
@@ -389,13 +402,17 @@ class ModelRunner:
         numerics_inject is the forced-corruption testing hook — an
         additive [B] row vector (zeros, or NaN on a poisoned row)."""
         lora_vocab = lora is not None and "vocab" in lora
-        if lora_vocab:
-            # Extra-vocab LoRA: the model returns EXACTLY vocab+extra
-            # columns with invalid extras already -inf (lora/layers.py
-            # lora_logits) — no padding mask needed.
-            logits = self.model.compute_logits(params, hidden_rows, lora)
-        else:
-            logits = self.model.compute_logits(params, hidden_rows)
+        # named_scope: metadata only, so that the trace's device
+        # operations carry their layer.
+        with jax.named_scope("lm_head"):
+            if lora_vocab:
+                # Extra-vocab LoRA: the model returns EXACTLY vocab+extra
+                # columns with invalid extras already -inf (lora/layers.py
+                # lora_logits) — no padding mask needed.
+                logits = self.model.compute_logits(params, hidden_rows,
+                                                   lora)
+            else:
+                logits = self.model.compute_logits(params, hidden_rows)
         logits = logits.astype(jnp.float32)
         if not lora_vocab and logits.shape[-1] > self.vocab_size:
             # TP vocab padding (parallel/mesh.py): the padded columns hold
@@ -449,10 +466,12 @@ class ModelRunner:
             num_stats = jnp.stack(
                 [nan_c.astype(jnp.float32), inf_c.astype(jnp.float32),
                  max_abs, top1, entropy], axis=-1)
-        out = sample(logits, temperatures, top_ks, top_ps, min_ps, seeds,
-                     logprob_k=logprob_k, num_samples=num_samples,
-                     do_topk=do_topk, do_topp=do_topp, do_minp=do_minp,
-                     do_random=do_random)
+        with jax.named_scope("sampler"):
+            out = sample(logits, temperatures, top_ks, top_ps, min_ps,
+                         seeds, logprob_k=logprob_k,
+                         num_samples=num_samples, do_topk=do_topk,
+                         do_topp=do_topp, do_minp=do_minp,
+                         do_random=do_random)
         return out + (fetched, plp_out, num_stats)
 
     def _decode_cont_fn(self, params, kv_caches, prev_packed, positions,
@@ -729,6 +748,7 @@ class ModelRunner:
                   "context_lens": context_lens, "block_tables": block_tables}
         arrays["_eff"] = {
             "real_rows": len(rows),
+            "context_tokens": sum(ctxs),
             "width_real": max(len(t) for t in tables),
             "width_padded": w,
         }
@@ -893,6 +913,8 @@ class ModelRunner:
             *sampling_args, lora_state)
         fetched = None
         num_stats_dev = None
+        program = "mixed" if num_steps == 1 else "decode_fused"
+        device_idle = self._device_idle()
         if num_steps == 1:
             # Numerics sentinels (obs/numerics.py): opt-in extra device
             # output. When OFF the call binds exactly as pre-sentinel
@@ -919,7 +941,7 @@ class ModelRunner:
                 bucket = bucket + ("numerics", )
             with self._tracer.span("execute"):
                 result = self._guarded_call(
-                    "mixed", bucket, self._jit_decode_single,
+                    program, bucket, self._jit_decode_single,
                     *decode_args,
                     place(fetch_indices) if fetch_indices is not None
                     else None, **common, **numerics_kwargs)
@@ -939,17 +961,24 @@ class ModelRunner:
                       tuple(sorted(common.items())))
             with self._tracer.span("execute"):
                 packed, new_caches = self._guarded_call(
-                    "decode_fused", bucket, self._jit_decode,
+                    program, bucket, self._jit_decode,
                     *decode_args, num_steps=num_steps, **common)
+        self._last_packed = packed
         t1 = t2 = num_steps
 
-        # Each substep computes one token per row, pad rows included.
+        # Each substep computes one token per row, pad rows included; a
+        # row's substep j walks a context one token longer than j - 1's.
+        real_rows = eff_info["real_rows"]
         self._efficiency.record_dispatch(
-            "decode", eff_info["real_rows"], padded_n,
-            real_tokens=eff_info["real_rows"] * num_steps,
+            "decode", real_rows, padded_n,
+            real_tokens=real_rows * num_steps,
             padded_tokens=padded_n * num_steps,
             width_real=eff_info["width_real"],
-            width_padded=eff_info["width_padded"])
+            width_padded=eff_info["width_padded"],
+            program=program, device_idle=device_idle,
+            row_steps=real_rows * num_steps,
+            context_tokens=(eff_info["context_tokens"] * num_steps
+                            + real_rows * num_steps * (num_steps - 1) // 2))
 
         # ONE device→host transfer for everything, performed by
         # InflightStep.finalize() — immediately on the eager path, or
@@ -1147,6 +1176,7 @@ class ModelRunner:
                   tuple(sorted(common.items())))
         if num_on:
             bucket = bucket + ("numerics", )
+        device_idle = self._device_idle()
         with self._tracer.span("execute"):
             result = self._guarded_call(
                 "mixed", bucket, self._jit_decode_single,
@@ -1164,18 +1194,25 @@ class ModelRunner:
         fetched = result.pop(0) if proc_rows else None
         num_stats_dev = result.pop(0) if num_on else None
         new_caches = result.pop(0)
+        self._last_packed = packed
 
         # Per-phase efficiency attribution: each real token is counted
         # exactly once under its own phase; the flat batch's bucket
         # padding is charged to the decode side (whose row count it
-        # extends) unless the step is chunk-only.
+        # extends) unless the step is chunk-only. The dispatch itself
+        # (one program, every row one token) is counted once, with
+        # whichever side reports first.
         pad_rows = padded_n - len(rows)
+        dispatch = dict(program="mixed", device_idle=device_idle,
+                        row_steps=len(rows), context_tokens=sum(ctxs))
         if n_chunk_groups:
             self._efficiency.record_dispatch(
                 "prefill", n_chunk_groups, n_chunk_groups,
                 real_tokens=n_chunk_tokens,
                 padded_tokens=(n_chunk_tokens
-                               + (0 if n_decode_rows else pad_rows)))
+                               + (0 if n_decode_rows else pad_rows)),
+                **dispatch)
+            dispatch = {}
         if n_chunk_groups and n_decode_rows:
             self._efficiency.record_co_batched_step()
         if n_decode_rows:
@@ -1184,7 +1221,7 @@ class ModelRunner:
                 real_tokens=n_decode_rows,
                 padded_tokens=padded_n - n_chunk_tokens,
                 width_real=max(len(t) for t in tables),
-                width_padded=w)
+                width_padded=w, **dispatch)
 
         step = InflightStep(
             self, packed, seq_group_metadata_list, rows, num_samples, 1,
@@ -1256,12 +1293,14 @@ class ModelRunner:
                      do_penalties=False, do_random=st.do_random)
         bucket = (b, w, prev_t1, num_steps, lora_state is not None,
                   tuple(sorted(flags.items())))
+        device_idle = self._device_idle()
         with self._tracer.span("execute"):
             packed, new_caches = self._guarded_call(
                 "decode_cont", bucket, self._jit_decode_cont,
                 self.params, kv_caches, prev_packed, place(positions),
                 place(block_tables), place(ctx), *sampling_args, lora_state,
                 prev_t1=prev_t1, num_steps=num_steps, **flags)
+        self._last_packed = packed
 
         live_rows = int((cont.ctx0 > 0).sum())
         self._efficiency.record_dispatch(
@@ -1269,7 +1308,11 @@ class ModelRunner:
             real_tokens=live_rows * num_steps,
             padded_tokens=b * num_steps,
             width_real=max((len(t) for t in tables), default=1),
-            width_padded=w)
+            width_padded=w,
+            program="decode_cont", device_idle=device_idle,
+            row_steps=live_rows * num_steps,
+            context_tokens=(int(ctx.sum()) * num_steps
+                            + live_rows * num_steps * (num_steps - 1) // 2))
 
         step = InflightStep(self, packed, cont.metas, cont.rows, num_steps,
                             num_steps, st.logprob_k, False, num_steps)

@@ -57,19 +57,23 @@ def test_engine_run_exports_phase_breakdown(tiny_opt_dir, fresh_obs):
 
     phase_sum = _registry_value("intellillm_step_phase_seconds_sum")
     step_sum = _registry_value("intellillm_step_time_seconds_sum")
+    gap_sum = _registry_value("intellillm_step_gap_seconds_sum")
     n_steps = _registry_value("intellillm_step_time_seconds_count")
     assert n_steps > 0, "no step histogram samples exported"
     assert phase_sum > 0.0
-    # Exclusive phase accounting: the sum must cover at least 80% of step
-    # wall time (acceptance criterion) and can never exceed it by more
-    # than drain jitter.
-    assert phase_sum >= 0.8 * step_sum, (
-        f"phases cover only {phase_sum / step_sum:.0%} of step time")
-    assert phase_sum <= step_sum * 1.05 + 0.005
+    # Every step but the first follows one with work pending: a loop gap.
+    assert _registry_value("intellillm_step_gap_seconds_count") == n_steps - 1
+    # Exclusive phase accounting: the sum must cover at least 80% of the
+    # loop's period (step + gap; `stats` falls in the gap) and can never
+    # exceed it by more than drain jitter.
+    period = step_sum + gap_sum
+    assert phase_sum >= 0.8 * period, (
+        f"phases cover only {phase_sum / period:.0%} of the loop period")
+    assert phase_sum <= period * 1.05 + 0.005
 
     # The hot phases must all have fired on a prefill+decode run.
-    for phase in ("schedule", "prepare_inputs", "execute", "sample",
-                  "detokenize"):
+    for phase in ("schedule", "prepare_inputs", "execute", "fetch_wait",
+                  "sample", "process_outputs", "detokenize", "stats"):
         assert _registry_value("intellillm_step_phase_seconds_count",
                                {"phase": phase}) > 0, f"{phase} missing"
 
@@ -78,6 +82,60 @@ def test_engine_run_exports_phase_breakdown(tiny_opt_dir, fresh_obs):
     # so only non-emptiness is guaranteed.)
     assert engine.last_step_time > 0.0
     assert engine.last_step_phases
+
+
+@pytest.mark.skipif(not _PROMETHEUS, reason="needs prometheus_client")
+def test_async_engine_accounts_for_the_whole_loop_period(tiny_opt_dir,
+                                                         fresh_obs):
+    """Through AsyncLLMEngine the loop period (step + loop gap) is covered
+    by named phases, the between-step ones included: admit and stream_out
+    on the loop thread, handoff and handback across the threads."""
+    import asyncio
+
+    from intellillm_tpu.engine.arg_utils import AsyncEngineArgs
+    from intellillm_tpu.engine.async_llm_engine import AsyncLLMEngine
+    from intellillm_tpu.obs.tracing import PHASES
+
+    args = AsyncEngineArgs(model=tiny_opt_dir, dtype="float32",
+                           max_model_len=128,
+                           num_device_blocks_override=128, max_num_seqs=8,
+                           max_paddings=512, swap_space=0.01,
+                           disable_log_requests=True)
+    params = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
+
+    async def run():
+        engine = AsyncLLMEngine.from_engine_args(args)
+
+        async def one(i):
+            n = 0
+            async for out in engine.generate(
+                    f"hello my name is number {i}", params, str(i)):
+                n = len(out.outputs[0].token_ids)
+            return n
+
+        # two waves, so that requests are admitted while others decode
+        first = [asyncio.ensure_future(one(i)) for i in range(4)]
+        await asyncio.sleep(0.05)
+        counts = await asyncio.gather(*first, *(one(i) for i in range(4, 8)))
+        engine._background_loop_unshielded.cancel()
+        return counts
+
+    assert asyncio.run(run()) == [24] * 8
+
+    by_phase = {phase: _registry_value("intellillm_step_phase_seconds_sum",
+                                       {"phase": phase})
+                for phase in PHASES}
+    period = (_registry_value("intellillm_step_time_seconds_sum")
+              + _registry_value("intellillm_step_gap_seconds_sum"))
+    assert _registry_value("intellillm_step_gap_seconds_count") > 0
+    covered = sum(by_phase.values())
+    assert covered >= 0.95 * period, (
+        f"phases cover {covered / period:.1%} of the loop period: "
+        f"{by_phase}")
+    assert covered <= period * 1.05 + 0.005
+    for phase in ("fetch_wait", "process_outputs", "stats", "stream_out",
+                  "admit", "handoff", "handback"):
+        assert by_phase[phase] > 0.0, f"{phase} never recorded"
 
 
 def test_compile_counters_once_per_bucket(tiny_opt_dir, fresh_obs):

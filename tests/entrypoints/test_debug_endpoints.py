@@ -241,22 +241,27 @@ def test_kernels_endpoint_and_health_block_on_both_servers():
 
 
 def test_profiler_capture_runs_against_a_fake_engine(monkeypatch, tmp_path):
-    """Full capture-and-parse flow without a device: a fake engine
-    "profiles" by dropping a pre-baked trace file into the capture's
-    temp dir; the endpoint bounds the step wait, parses the trace,
-    merges the op table into the ledger, and 409s while a trace is
-    already running."""
-    import gzip
-    import json as jsonlib
+    """Full capture-and-reduce flow without an engine: a fake engine
+    "profiles" by taking a real (CPU) jax.profiler trace of two annotated
+    steps into the capture's temp dir; the endpoint bounds the step wait,
+    reduces the xplane, merges the block into the ledger, and 409s while
+    a trace is already running."""
+    import time
 
+    import jax
+    import jax.numpy as jnp
     from aiohttp import web
 
     from intellillm_tpu.entrypoints.debug_routes import add_debug_routes
     from intellillm_tpu.obs import get_kernel_ledger
+    from intellillm_tpu.obs.tracing import StepTracer
 
     monkeypatch.setenv("INTELLILLM_PROFILER_CAPTURE_TIMEOUT_S", "0.2")
     ledger = get_kernel_ledger()
     ledger.reset_for_testing()
+    fn = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((128, 128))
+    fn(x).block_until_ready()
 
     class _FakeEngine:
         def __init__(self):
@@ -266,16 +271,18 @@ def test_profiler_capture_runs_against_a_fake_engine(monkeypatch, tmp_path):
             if self.profiling:
                 return None
             self.profiling = True
-            doc = {"traceEvents": [
-                {"ph": "M", "pid": 9, "name": "process_name",
-                 "args": {"name": "/device:TPU:0"}},
-                {"ph": "X", "pid": 9, "tid": 1, "ts": 0, "dur": 300.0,
-                 "name": "fusion.7"},
-                {"ph": "X", "pid": 9, "tid": 1, "ts": 400, "dur": 100.0,
-                 "name": "copy.1"},
-            ]}
-            with gzip.open(f"{trace_dir}/host.trace.json.gz", "wt") as f:
-                jsonlib.dump(doc, f)
+            tracer = StepTracer(enabled=True)
+            jax.profiler.start_trace(trace_dir)
+            tracer.set_profiling(True)
+            for _ in range(2):
+                with tracer.span("schedule"):
+                    time.sleep(0.002)
+                with tracer.span("execute"):
+                    y = fn(x)
+                with tracer.span("fetch_wait"):
+                    y.block_until_ready()
+            tracer.set_profiling(False)
+            jax.profiler.stop_trace()
             return trace_dir
 
         def stop_profile(self):
@@ -293,12 +300,20 @@ def test_profiler_capture_runs_against_a_fake_engine(monkeypatch, tmp_path):
             assert data["steps_requested"] == 2
             assert data["steps_observed"] == 0  # idle fake engine
             profile = data["profile"]
-            assert profile["ops_total"] == 2
-            assert [op["name"] for op in profile["ops"]] == ["fusion.7"]
-            assert profile["ops"][0]["share"] == pytest.approx(0.75)
+            assert profile["steps"] == 0
+            assert profile["ops_total"] >= 1 and len(profile["ops"]) == 1
+            assert profile["busy_s"] > 0
+            assert profile["annotations"]["execute"] == 2
+            # the idle gaps by host phase add up to the idle time, and the
+            # sleep inside `schedule` is the largest of them
+            idle = profile["idle_by_phase"]
+            assert sum(idle.values()) == pytest.approx(profile["idle_s"])
+            assert max(idle, key=idle.get) == "schedule"
             # Merged into the ledger: /debug/kernels now carries it.
             resp = await client.get("/debug/kernels")
-            assert (await resp.json())["profile"]["ops_total"] == 2
+            stored = (await resp.json())["profile"]
+            assert stored["ops_total"] == profile["ops_total"]
+            assert stored["idle_by_phase"] == idle
 
             # Concurrent capture while a trace runs: 409, engine state
             # untouched.

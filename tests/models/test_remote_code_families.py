@@ -456,3 +456,65 @@ def test_config_shim_parses_without_remote_code(tmp_path, model_type,
     hf_config = get_hf_config(d, trust_remote_code=False)
     assert hf_config.model_type == model_type
     assert hf_config.hidden_size == 64
+
+
+def test_named_scopes_are_metadata_only_in_the_mixed_program(monkeypatch):
+    """`moe_router` / `moe_experts` / `moe_shared` / `lm_head` / `sampler`
+    name the layers in a profiler trace; the lowered `mixed` program is
+    otherwise the same, operation for operation."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from intellillm_tpu.config import (CacheConfig, ModelConfig,
+                                       ParallelConfig, SchedulerConfig)
+    from intellillm_tpu.engine.llm_engine import LLMEngine
+    from intellillm_tpu.transformers_utils.configs import DeepseekConfig
+
+    cfg = DeepseekConfig(vocab_size=128, hidden_size=64,
+                         intermediate_size=128, moe_intermediate_size=32,
+                         num_hidden_layers=2, num_attention_heads=4,
+                         num_key_value_heads=2, n_shared_experts=2,
+                         n_routed_experts=4, num_experts_per_tok=2,
+                         first_k_dense_replace=1, moe_layer_freq=1,
+                         norm_topk_prob=False, max_position_embeddings=128)
+    cfg.architectures = ["DeepseekForCausalLM"]
+    model_config = ModelConfig.from_hf_config(cfg, dtype="float32",
+                                              max_model_len=128,
+                                              load_format="dummy")
+    engine = LLMEngine(
+        model_config,
+        CacheConfig(block_size=16, num_device_blocks_override=32,
+                    swap_space_gib=0.01),
+        ParallelConfig(),
+        SchedulerConfig(max_num_batched_tokens=256, max_num_seqs=8,
+                        max_model_len=128, max_paddings=512),
+        log_stats=False, skip_tokenizer_init=True)
+    runner = engine.worker.model_runner
+    cache = jax.ShapeDtypeStruct((32, 2, 16, 16), jnp.float32)
+    kv_struct = [(cache, cache) for _ in range(2)]
+
+    def lowered():
+        # a new jit each time: the trace must not come from jit's cache
+        runner._jit_decode_single = jax.jit(
+            runner._decode_fn_single,
+            static_argnames=("num_samples", "plp_k", "logprob_k", "do_topk",
+                             "do_topp", "do_minp", "do_penalties",
+                             "do_random", "do_numerics"),
+            donate_argnames=("kv_caches", ))
+        return runner.lower_mixed_step(kv_struct, 16, 16)
+
+    with_scopes = lowered()
+    named = with_scopes.as_text(debug_info=True)
+    scopes = ("moe_router", "moe_experts", "moe_shared", "lm_head",
+              "sampler")
+    for scope in scopes:
+        assert f"/{scope}/" in named, scope     # an entry of the name stack
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = lowered()
+    unnamed = without.as_text(debug_info=True)
+    for scope in scopes:
+        assert f"/{scope}/" not in unnamed, scope
+    assert with_scopes.as_text() == without.as_text()

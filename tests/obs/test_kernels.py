@@ -2,12 +2,12 @@
 keying, introspection parsing (faked cost/memory analysis objects),
 top-K ordering, the CPU/no-TPU degradation contract (nulls, never an
 exception on the dispatch path), the cost-model MFU window, and the
-profiler-trace parser against a faked trace-event file.
+profiler capture's reducer (device busy union, self times, idle gaps by
+host phase) on a hand-made doc and on a real CPU jax.profiler trace.
 
 The module also prints its own wall-clock on teardown: the ledger tests
 run inside the tier-1 870s budget, so the suite self-reports what it
 costs (see docs/observability.md "Testing hooks")."""
-import gzip
 import json
 import math
 import sys
@@ -18,7 +18,8 @@ import numpy as np
 import pytest
 
 from intellillm_tpu.obs.kernels import (KernelLedger, _parse_cost_analysis,
-                                        get_kernel_ledger, parse_trace_dir)
+                                        capture_profile, get_kernel_ledger,
+                                        load_profile, reduce_profile)
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -224,83 +225,233 @@ def test_mfu_costmodel_window_and_unknown_poisoning(ledger, monkeypatch):
     assert ledger.record_step(0.01) == pytest.approx(0.5)
 
 
-def test_merge_profile_top_k_and_shares(ledger):
-    ops = [{"name": "fusion.1", "total_us": 600.0, "count": 3},
-           {"name": "fusion.2", "total_us": 300.0, "count": 2},
-           {"name": "copy.3", "total_us": 100.0, "count": 9}]
-    block = ledger.merge_profile(ops, steps=4, top=2)
+def _doc():
+    """One chip, two programs of a fused-decode server, and the host's
+    annotations on the same clock (ns):
+
+        device  mixed        [1000, 3000): copy [1000,1200) then a while
+                             [1200,3000) around two fusions of 400
+                decode_cont  [9000, 12000): one fusion
+        host    loop thread      stream_out [3100,4000) [4000,5000)
+                                 admit [5100,5600)
+                executor thread  schedule [6000,7000) holding
+                                 prepare_inputs [6200,6800);
+                                 execute [7000,9500); stats [12100,12500)
+    """
+    ops = [["%copy.1 = bf16[8,8] copy(%p)", 1000, 200],
+           ["%while.2 = (s32[]) while(%t), body=%b", 1200, 1800],
+           ["%fusion.3 = bf16[8,8] fusion(%x)", 1300, 400],
+           ["%fusion.3 = bf16[8,8] fusion(%x)", 2000, 400],
+           ["%fusion.9 = bf16[8,8] fusion(%y)", 9000, 3000]]
+    return {"planes": [
+        {"name": "/device:TPU:0", "lines": [
+            {"name": "XLA Modules", "events": [
+                ["jit__decode_fn_single(77)", 1000, 2000],
+                ["jit__decode_cont_fn(78)", 9000, 3000]]},
+            {"name": "XLA Ops", "events": ops},
+            {"name": "Async XLA Ops", "events": [
+                ["%copy-start.5 = copy-start(%p)", 0, 20000]]}]},
+        {"name": "/host:CPU", "lines": [
+            {"name": "python", "events": [
+                ["intellillm/stream_out", 3100, 900],
+                ["intellillm/stream_out", 4000, 1000],
+                ["intellillm/admit", 5100, 500]]},
+            {"name": "python", "events": [
+                ["intellillm/schedule", 6000, 1000],
+                ["intellillm/prepare_inputs", 6200, 600],
+                ["intellillm/execute", 7000, 2500],
+                ["intellillm/stats", 12100, 400]]}]},
+        {"name": "/host:metadata", "lines": []}]}
+
+
+def test_reduce_profile_busy_union_and_self_times():
+    r = reduce_profile(_doc())
+    assert r["window_s"] == pytest.approx(11000e-9)
+    assert r["busy_s"] == pytest.approx(5000e-9)     # 2000 + 3000
+    assert r["idle_s"] == pytest.approx(6000e-9)
+    assert r["idle_share"] == pytest.approx(6 / 11)
+    assert r["devices"] == [{"plane": "/device:TPU:0",
+                             "busy_s": pytest.approx(5000e-9),
+                             "op_events": 5}]
+    ops = {name.split(" = ")[0]: (sec, n) for name, sec, n in r["ops"]}
+    # the enclosing while keeps only what its body leaves: 1800 - 800
+    assert ops["%while.2"] == (pytest.approx(1000e-9), 1)
+    assert ops["%fusion.3"] == (pytest.approx(800e-9), 2)
+    assert ops["%fusion.9"] == (pytest.approx(3000e-9), 1)
+    assert ops["%copy.1"] == (pytest.approx(200e-9), 1)
+    # self times add up to the busy time: nothing counted twice, nothing
+    # from the module line, the async line or the host
+    assert sum(sec for _, sec, _ in r["ops"]) == pytest.approx(r["busy_s"])
+    assert not [n for n, _, _ in r["ops"] if "jit_" in n or "copy-start" in n]
+    # programs under the engine's names, whole durations
+    assert r["programs"] == [["decode_cont", pytest.approx(3000e-9), 1],
+                             ["mixed", pytest.approx(2000e-9), 1]]
+
+
+def test_reduce_profile_charges_the_idle_gap_to_host_phases():
+    """The one gap, [3000, 9000), split over the innermost annotation at
+    each instant; holes between the threads' annotations are the two
+    thread crossings, any other hole is `unannotated`."""
+    r = reduce_profile(_doc())
+    ns = {phase: round(sec * 1e9) for phase, sec in r["idle_by_phase"].items()}
+    assert ns == {
+        "stream_out": 1900,         # [3100, 5000)
+        "execute": 2000,            # [7000, 9000): the gap ends inside it
+        "schedule": 400,            # [6000,6200) + [6800,7000): exclusive
+        "prepare_inputs": 600,      # nested in schedule, innermost
+        "admit": 500,
+        "handoff": 400,             # after admit, before the executor's first
+        "unannotated": 200,         # [3000,3100) + [5000,5100)
+    }
+    assert sum(r["idle_by_phase"].values()) == pytest.approx(r["idle_s"])
+    assert list(r["idle_by_phase"])[0] == "execute"      # largest first
+    # every share of the gap also says which program ended it
+    assert {(p, prog) for p, prog, _, _ in r["idle_by_phase_program"]} == {
+        (p, "decode_cont") for p in ns}
+    assert r["annotations"] == {"stream_out": 2, "admit": 1, "schedule": 1,
+                                "prepare_inputs": 1, "execute": 1,
+                                "stats": 1}
+
+
+def test_reduce_profile_names_the_return_crossing_and_needs_device_ops():
+    doc = _doc()
+    # a third program after the stats span, then streaming: the hole
+    # between `stats` (executor) and `stream_out` (loop) is the handback
+    doc["planes"][0]["lines"][1]["events"].append(
+        ["%fusion.9 = bf16[8,8] fusion(%y)", 14000, 100])
+    doc["planes"][1]["lines"][0]["events"].append(
+        ["intellillm/stream_out", 12900, 1100])
+    r = reduce_profile(doc)
+    ns = {p: round(s * 1e9) for p, s in r["idle_by_phase"].items()}
+    assert ns["handback"] == 400                  # [12500, 12900)
+    assert ns["stats"] == 400 and ns["stream_out"] == 1900 + 1100
+    assert ns["unannotated"] == 200 + 100         # ... and [12000, 12100)
+    assert sum(r["idle_by_phase"].values()) == pytest.approx(r["idle_s"])
+    by_program = {}
+    for phase, prog, sec, _ in r["idle_by_phase_program"]:
+        by_program[prog] = by_program.get(prog, 0) + round(sec * 1e9)
+    assert by_program == {"decode_cont": 6000, "%fusion.9": 2000}
+    with pytest.raises(ValueError, match="no device operations"):
+        reduce_profile({"planes": [{"name": "/host:CPU", "lines": []}]})
+
+
+@pytest.fixture(scope="module")
+def cpu_trace_dir(tmp_path_factory):
+    """A real jax.profiler trace on the CPU backend: three 'steps', each
+    annotated by a StepTracer under the profile flag — the loop thread
+    admits, another thread schedules, dispatches and waits."""
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+
+    from intellillm_tpu.obs.tracing import StepTracer
+
+    logdir = str(tmp_path_factory.mktemp("cpu-trace"))
+    fn = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((256, 256))
+    fn(x).block_until_ready()
+    tracer = StepTracer(enabled=True)
+
+    def step():
+        tracer.end_crossing("handoff")
+        tracer.begin_step()
+        with tracer.span("schedule"):
+            time.sleep(0.002)
+        with tracer.span("execute"):
+            y = fn(x)
+        with tracer.span("sample"):
+            with tracer.span("fetch_wait"):
+                y.block_until_ready()
+        tracer.end_step(work_pending=True)
+        tracer.begin_crossing()
+
+    jax.profiler.start_trace(logdir)
+    tracer.set_profiling(True)
+    try:
+        for _ in range(3):
+            with tracer.span("admit"):
+                time.sleep(0.001)
+            tracer.begin_crossing()
+            t = threading.Thread(target=step)
+            t.start()
+            t.join()
+            tracer.end_crossing("handback")
+            with tracer.span("stream_out"):
+                time.sleep(0.001)
+    finally:
+        tracer.set_profiling(False)
+        jax.profiler.stop_trace()
+    return logdir
+
+
+def test_cpu_profile_carries_the_annotations_and_every_gap_has_a_phase(
+        cpu_trace_dir):
+    doc = load_profile(cpu_trace_dir)
+    assert doc["file"].endswith(".xplane.pb")
+    host = [p for p in doc["planes"] if p["name"] == "/host:CPU"]
+    assert host, [p["name"] for p in doc["planes"]]
+    names = {e[0] for ln in host[0]["lines"] for e in ln["events"]}
+    # the tracer's spans are in the host plane, on its clock; python
+    # frames of the host lines are not loaded
+    assert {"intellillm/admit", "intellillm/schedule", "intellillm/execute",
+            "intellillm/fetch_wait", "intellillm/sample",
+            "intellillm/stream_out"} <= names
+    assert not [n for n in names if n.startswith("$")]
+
+    r = reduce_profile(doc)
+    assert r["annotations"]["execute"] == 3
+    assert r["annotations"]["fetch_wait"] == 3
+    # the CPU client's thunks stand in for device operations, without the
+    # thread pool's bookkeeping events
+    assert r["busy_s"] > 0 and r["ops"]
+    assert not [n for n, _, _ in r["ops"] if "::" in n or n.startswith("end: ")]
+    assert r["programs"] == []          # no module line on this backend
+    # every idle gap between the three steps' operations lands in a phase
+    assert sum(r["idle_by_phase"].values()) == pytest.approx(r["idle_s"])
+    assert r["idle_s"] > 0.005          # 2 x (1 + 1 + 2) ms of sleeps
+    idle = r["idle_by_phase"]
+    for phase in ("admit", "schedule", "stream_out"):
+        assert idle[phase] >= 0.0015, idle
+    # and the two thread crossings are named, not left unannotated
+    assert idle.get("handoff", 0.0) > 0 and idle.get("handback", 0.0) > 0
+    assert capture_profile(cpu_trace_dir)["busy_s"] == r["busy_s"]
+
+
+def test_load_profile_missing_or_corrupt_is_none(tmp_path):
+    assert load_profile(str(tmp_path / "nowhere")) is None
+    assert capture_profile(str(tmp_path)) is None
+    bad = tmp_path / "plugins" / "profile" / "x"
+    bad.mkdir(parents=True)
+    (bad / "host.xplane.pb").write_bytes(b"not a profile at all")
+    # unreadable, or readable as an empty profile: nothing to reduce
+    assert capture_profile(str(tmp_path)) is None
+
+
+def test_merge_profile_stores_the_block_with_top_k_ops(ledger):
+    reduced = reduce_profile(_doc())
+    block = ledger.merge_profile(reduced, steps=4, top=2)
     assert block["steps"] == 4
-    assert block["ops_total"] == 3
-    assert block["total_us"] == pytest.approx(1000.0)
-    assert [op["name"] for op in block["ops"]] == ["fusion.1", "fusion.2"]
-    assert block["ops"][0]["share"] == pytest.approx(0.6)
+    assert block["ops_total"] == 4
+    assert [n.split(" = ")[0] for n, _, _ in block["ops"]] == [
+        "%fusion.9", "%while.2"]
+    assert block["idle_by_phase"]["execute"] == pytest.approx(2000e-9)
+    assert len(reduced["ops"]) == 4         # the caller's block is not cut
     snap = ledger.snapshot(top=0)
-    assert snap["profile"]["ops"][1]["share"] == pytest.approx(0.3)
+    assert snap["profile"]["busy_s"] == pytest.approx(5000e-9)
+    assert snap["profile"]["captured_at_step"] == 0
     json.dumps(snap)
-
-
-def _write_trace(path, events):
-    doc = {"displayTimeUnit": "ns", "metadata": {}, "traceEvents": events}
-    with gzip.open(path, "wt", encoding="utf-8") as f:
-        json.dump(doc, f)
-
-
-def test_parse_trace_dir_prefers_device_lanes(tmp_path):
-    plugin_dir = tmp_path / "plugins" / "profile" / "2026_08_08"
-    plugin_dir.mkdir(parents=True)
-    _write_trace(plugin_dir / "host.trace.json.gz", [
-        {"ph": "M", "pid": 1, "name": "process_name",
-         "args": {"name": "/host:CPU"}},
-        {"ph": "M", "pid": 9, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        # Host python frames: excluded once a device lane exists.
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 0, "dur": 9999.0,
-         "name": "$pjit.py:330 cache_miss"},
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 0, "dur": 5000.0,
-         "name": "PjitFunction(step)"},
-        # Device ops: summed by name across events.
-        {"ph": "X", "pid": 9, "tid": 2, "ts": 0, "dur": 120.5,
-         "name": "fusion.1"},
-        {"ph": "X", "pid": 9, "tid": 2, "ts": 200, "dur": 79.5,
-         "name": "fusion.1"},
-        {"ph": "X", "pid": 9, "tid": 3, "ts": 0, "dur": 50.0,
-         "name": "copy.2"},
-        # Malformed events are skipped, not fatal.
-        {"ph": "X", "pid": 9, "tid": 3, "ts": 0, "name": "no-dur"},
-        {"ph": "C", "pid": 9, "name": "counter", "dur": 1.0},
-    ])
-    ops = parse_trace_dir(str(tmp_path))
-    assert [op["name"] for op in ops] == ["fusion.1", "copy.2"]
-    assert ops[0]["total_us"] == pytest.approx(200.0)
-    assert ops[0]["count"] == 2
-
-
-def test_parse_trace_dir_cpu_single_lane_filters_python_frames(tmp_path):
-    _write_trace(tmp_path / "vm.trace.json.gz", [
-        {"ph": "M", "pid": 1, "name": "process_name",
-         "args": {"name": "/host:CPU"}},
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 0, "dur": 9999.0,
-         "name": "$profiler.py:91 start_trace"},
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 0, "dur": 42.0,
-         "name": "PjitFunction(decode)"},
-    ])
-    ops = parse_trace_dir(str(tmp_path))
-    assert [op["name"] for op in ops] == ["PjitFunction(decode)"]
-
-
-def test_parse_trace_dir_corrupt_or_missing_is_empty(tmp_path):
-    assert parse_trace_dir(str(tmp_path / "nowhere")) == []
-    bad = tmp_path / "x.trace.json.gz"
-    bad.write_bytes(b"not gzip at all")
-    assert parse_trace_dir(str(tmp_path)) == []
+    # a capture that left nothing readable: an empty block, not an error
+    empty = ledger.merge_profile(None, steps=0)
+    assert empty["ops_total"] == 0 and empty["idle_by_phase"] == {}
+    assert ledger.snapshot(top=0)["profile"]["idle_share"] is None
 
 
 def test_reset_for_testing_clears_everything(ledger):
     ledger.introspect_mode = "on"
     _dispatch_new(ledger, "mixed", ("k",),
                   _fake_fn([{"flops": 1.0}], _MEM))
-    ledger.merge_profile([{"name": "f", "total_us": 1.0, "count": 1}],
-                         steps=1)
+    ledger.merge_profile(reduce_profile(_doc()), steps=1)
     ledger.record_step(0.01)
     ledger.reset_for_testing()
     snap = ledger.snapshot(top=4)

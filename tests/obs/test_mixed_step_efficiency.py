@@ -86,6 +86,18 @@ def test_mixed_steps_attribute_tokens_exactly_once(tiny_opt_dir, trackers):
     assert snap["steps"] > 0
     assert snap["flops_per_token"] and snap["flops_per_token"] > 0
 
+    # The dispatch boundary counts each mixed step once (not once per
+    # side), every row one token: chunk tokens + decode rows, and the
+    # context each row's attention walks (its position + 1).
+    mixed = snap["programs"]["mixed"]
+    assert mixed["idle"] + mixed["busy"] == snap["steps"]
+    assert mixed["row_steps"] == sum(prompt_lens) + expected_decode
+    expected_ctx = sum(n * (n + 1) // 2 for n in prompt_lens) + sum(
+        n + j for n in prompt_lens for j in range(1, MAX_TOKENS))
+    assert mixed["context_tokens"] == expected_ctx
+    # the serial step() fetches each step before it dispatches the next
+    assert mixed["busy"] == 0 or engine.pipeline_enabled
+
     # The mixed flat-batch program is tracked under its own label.
     csnap = comp.snapshot()
     mixed_programs = [p for p in csnap["compiles"] if p == "mixed"]
@@ -120,3 +132,44 @@ def test_chunked_off_still_runs_only_mixed_family(tiny_opt_dir, trackers):
         f"non-mixed-family program dispatched: {compiles}")
     tokens = get_efficiency_tracker().snapshot()["tokens_total"]
     assert tokens["prefill"]["real"] == sum(prompt_lens)
+
+
+def test_fused_decode_programs_count_rows_steps_and_context(tiny_opt_dir,
+                                                            trackers):
+    """K-step decode programs: row_steps is rows x substeps (what
+    `tokens_total{phase=decode,kind=real}` counts too), and a row's
+    substep j walks a context one token longer than substep j - 1's."""
+    eff, _ = trackers
+    llm = LLM(model=tiny_opt_dir, dtype="float32",
+              num_device_blocks_override=128, max_model_len=128,
+              max_num_seqs=8, max_paddings=512, num_decode_steps=4)
+    eff.reset_for_testing()
+    engine = llm.llm_engine
+    tok = engine.tokenizer
+    prompt_lens = [len(tok.encode(p)) for p in PROMPTS]
+    n_out = 17
+    for i, p in enumerate(PROMPTS):
+        engine.add_request(str(i), p, SamplingParams(
+            temperature=0.0, max_tokens=n_out, ignore_eos=True))
+    outs = list(llm._run_engine(use_tqdm=False))
+    assert all(len(o.outputs[0].token_ids) == n_out for o in outs)
+
+    snap = eff.snapshot()
+    programs = snap["programs"]
+    decode = [programs["decode_fused"], programs["decode_cont"]]
+    assert programs["decode_fused"]["row_steps"] > 0
+    if engine.pipeline_enabled:
+        assert programs["decode_cont"]["row_steps"] > 0
+    # prompts are prefilled by `mixed`, one row a token
+    assert programs["mixed"]["row_steps"] >= sum(prompt_lens)
+    decode_rows = sum(d["row_steps"] for d in decode)
+    assert (decode_rows + programs["mixed"]["row_steps"] - sum(prompt_lens)
+            == snap["tokens_total"]["decode"]["real"])
+    # the mean context of a decode row-step lies between the shortest
+    # prompt and the longest finished sequence (overshoot rows included)
+    mean_ctx = sum(d["context_tokens"] for d in decode) / decode_rows
+    assert min(prompt_lens) < mean_ctx < max(prompt_lens) + n_out + 8
+    # first fused step of the batch: contexts n+1 .. n+4 for each row,
+    # so no decode program can have walked less than that
+    first = sum(4 * (n + 1) + 6 for n in prompt_lens)
+    assert sum(d["context_tokens"] for d in decode) >= first
