@@ -20,6 +20,16 @@ grouped-GEMM over experts + CUDA `moe_align_block_size`,
 `moe_ffn` dispatches between them by comparing each path's FLOP model
 (dense: N*T products; grouped: T*K plus up to one padding block/expert).
 
+Stored layout of the expert stacks: all three are `[N, I, D]` (experts,
+inner, hidden), so `w1` (gate) and `w3` (up) are the checkpoint's own
+`[out, in]` matrices stacked, and their matmuls contract on the last axis.
+That is the layout XLA's TPU layout assignment wants for them whenever the
+expert einsums sit inside a loop (the chunk `while` of a prefill step, the
+K-loop of `decode_fused`): handed `[N, D, I]` it copied both stacks, in
+every `mixed` step and for the life of the decode programs
+(tests/test_tpu_compile.py `test_moe_dense_copies_no_expert_stack` holds
+the compiled text to no such copy).
+
 Routing matches HF Mixtral: softmax over ALL experts → top-k → renormalize
 the selected weights.
 """
@@ -29,6 +39,10 @@ import jax
 import jax.numpy as jnp
 
 from intellillm_tpu.utils import cdiv
+
+# Rows of one bf16 sublane tile: a chunk of the dense path is padded to a
+# multiple of it and by no more.
+_ROW_TILE = 16
 
 
 def _route(x: jnp.ndarray, gate_w: jnp.ndarray, top_k: int,
@@ -41,16 +55,41 @@ def _route(x: jnp.ndarray, gate_w: jnp.ndarray, top_k: int,
     return topw, topi
 
 
+def random_gate_up_stack(key, n: int, d: int, i: int, dtype) -> jnp.ndarray:
+    """A random w1 or w3 for `load_format="dummy"`: drawn as [N, D, I], so
+    that a seed keeps giving the model it gave when that was the stored
+    layout, and returned as the stored [N, I, D]. Waits for the result:
+    the host otherwise runs layers ahead of the device and the draws'
+    float32 and untransposed intermediates (five times the stack) pile up
+    beside the weights."""
+    w = (jax.random.normal(key, (n, d, i), jnp.float32) * 0.02).astype(dtype)
+    return jax.block_until_ready(w.swapaxes(1, 2))
+
+
+def dense_chunking(t: int, chunk_size: int) -> tuple[int, int]:
+    """(number of chunks, rows a chunk) the dense path cuts `t` rows into:
+    as few chunks as `chunk_size` allows, of equal size, each rounded up
+    to the sublane tile — so a 128-row decode step is one chunk of 128 and
+    not 256 rows of which half are zeros. The pad is under one tile a
+    chunk."""
+    most = max(chunk_size // _ROW_TILE, 1) * _ROW_TILE
+    num_chunks = cdiv(t, most)
+    chunk = cdiv(cdiv(t, num_chunks), _ROW_TILE) * _ROW_TILE
+    return num_chunks, chunk
+
+
 def moe_ffn_dense(
     x: jnp.ndarray,        # [T, D]
     gate_w: jnp.ndarray,   # [D, N] router
-    w1: jnp.ndarray,       # [N, D, I]  (gate proj per expert)
+    w1: jnp.ndarray,       # [N, I, D]  (gate proj per expert)
     w2: jnp.ndarray,       # [N, I, D]  (down proj per expert)
-    w3: jnp.ndarray,       # [N, D, I]  (up proj per expert)
+    w3: jnp.ndarray,       # [N, I, D]  (up proj per expert)
     top_k: int,
     chunk_size: int = 256,
     renormalize: bool = True,
 ) -> jnp.ndarray:
+    """`chunk_size` is the most rows a chunk may hold (it bounds the
+    [N, C, I] activations); the rows present decide the rest."""
     t, d = x.shape
     n = w1.shape[0]
 
@@ -59,18 +98,18 @@ def moe_ffn_dense(
     onehot = jax.nn.one_hot(topi, n, dtype=jnp.float32)       # [T, K, N]
     combine = (topw[..., None] * onehot).sum(axis=1)          # [T, N]
 
-    # Chunk tokens so [N, C, I] activations stay in budget.
-    pad_t = cdiv(t, chunk_size) * chunk_size
+    num_chunks, chunk = dense_chunking(t, chunk_size)
+    pad_t = num_chunks * chunk
     x_pad = jnp.pad(x, ((0, pad_t - t), (0, 0)))
     comb_pad = jnp.pad(combine, ((0, pad_t - t), (0, 0)))
-    x_chunks = x_pad.reshape(pad_t // chunk_size, chunk_size, d)
-    c_chunks = comb_pad.reshape(pad_t // chunk_size, chunk_size, n)
+    x_chunks = x_pad.reshape(num_chunks, chunk, d)
+    c_chunks = comb_pad.reshape(num_chunks, chunk, n)
 
     def chunk_fn(carry, inp):
         xc, cc = inp
-        h1 = jnp.einsum("td,ndi->nti", xc, w1,
+        h1 = jnp.einsum("td,nid->nti", xc, w1,
                         preferred_element_type=jnp.float32)
-        h3 = jnp.einsum("td,ndi->nti", xc, w3,
+        h3 = jnp.einsum("td,nid->nti", xc, w3,
                         preferred_element_type=jnp.float32)
         h = jax.nn.silu(h1) * h3
         out = jnp.einsum("nti,nid->ntd", h.astype(x.dtype), w2,
@@ -88,9 +127,9 @@ def moe_ffn_dense(
 def moe_ffn_grouped(
     x: jnp.ndarray,        # [T, D]
     gate_w: jnp.ndarray,   # [D, N] router
-    w1: jnp.ndarray,       # [N, D, I]
+    w1: jnp.ndarray,       # [N, I, D]
     w2: jnp.ndarray,       # [N, I, D]
-    w3: jnp.ndarray,       # [N, D, I]
+    w3: jnp.ndarray,       # [N, I, D]
     top_k: int,
     block: int = 512,
     renormalize: bool = True,
@@ -133,8 +172,10 @@ def moe_ffn_grouped(
         w1e = jax.lax.dynamic_index_in_dim(w1, e, 0, keepdims=False)
         w3e = jax.lax.dynamic_index_in_dim(w3, e, 0, keepdims=False)
         w2e = jax.lax.dynamic_index_in_dim(w2, e, 0, keepdims=False)
-        h1 = jnp.dot(xc, w1e, preferred_element_type=jnp.float32)
-        h3 = jnp.dot(xc, w3e, preferred_element_type=jnp.float32)
+        h1 = jnp.einsum("bd,id->bi", xc, w1e,
+                        preferred_element_type=jnp.float32)
+        h3 = jnp.einsum("bd,id->bi", xc, w3e,
+                        preferred_element_type=jnp.float32)
         h = (jax.nn.silu(h1) * h3).astype(x.dtype)
         return carry, jnp.dot(h, w2e, preferred_element_type=jnp.float32)
 
@@ -158,18 +199,22 @@ def moe_ffn(
     block: int = 512,
     renormalize: bool = True,
 ) -> jnp.ndarray:
+    # All three stacks are [N, I, D] (module docstring): w1/w3 contract on
+    # their last axis, so no loop around this call makes XLA re-lay them.
     # QuantMixtral (reference mixtral_quant.py): expert stacks arrive as
-    # packed int4 — HBM holds only the packed bytes; the dense stack is a
-    # transient dequant feeding the expert einsums (XLA fuses the affine
-    # into the dot producers where profitable).
+    # packed int4 — HBM holds only the packed bytes, [N, in/2, out] as the
+    # checkpoint packs them; the dense stack is a transient dequant feeding
+    # the expert einsums (XLA fuses the affine into the dot producers where
+    # profitable), which for w1/w3 ([N, D, I] unpacked) is handed over
+    # with its last two axes swapped.
     from intellillm_tpu.layers.quantization import (dequant_int4_stack,
                                                     is_quantized)
     if is_quantized(w1):
-        w1 = dequant_int4_stack(w1, x.dtype)
+        w1 = dequant_int4_stack(w1, x.dtype).swapaxes(1, 2)
     if is_quantized(w2):
         w2 = dequant_int4_stack(w2, x.dtype)
     if is_quantized(w3):
-        w3 = dequant_int4_stack(w3, x.dtype)
+        w3 = dequant_int4_stack(w3, x.dtype).swapaxes(1, 2)
     t = x.shape[0]
     n = w1.shape[0]
     # Dense runs n*t token-expert rows; grouped runs the routed rows

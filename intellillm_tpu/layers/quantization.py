@@ -120,8 +120,10 @@ def dequant_int4_stack(w: QuantizedWeight, dtype) -> jnp.ndarray:
     """Expert-stacked int4 → dense [N, in, out] (QuantMixtral: reference
     `mixtral_quant.py` runs per-expert quantized linears; here the packed
     per-expert tensors persist in HBM and dequantize on the fly for the
-    grouped/dense MoE einsum). Optional "inv" [N, in] undoes per-expert
-    GPTQ act-order row sorting."""
+    grouped/dense MoE einsum). The packed format is the checkpoint's for
+    all three stacks; `layers/moe.py` `moe_ffn` swaps the last two axes of
+    what this returns for w1/w3, whose dense layout is [N, out, in].
+    Optional "inv" [N, in] undoes per-expert GPTQ act-order row sorting."""
     q4 = w["q4"]                                     # [N, in/2, out]
     n, in2, out = q4.shape
     lo = (q4 & 0xF)

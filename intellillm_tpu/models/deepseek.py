@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from intellillm_tpu.config import ModelConfig
-from intellillm_tpu.layers.moe import moe_ffn
+from intellillm_tpu.layers.moe import moe_ffn, random_gate_up_stack
 from intellillm_tpu.layers.normalization import fused_add_rms_norm, rms_norm
 from intellillm_tpu.models.llama import LlamaForCausalLM, Params
 from intellillm_tpu.models.weight_utils import (cast_array,
@@ -87,8 +87,10 @@ class DeepseekForCausalLM(LlamaForCausalLM):
             for k in ("gate", "up", "down"):
                 layer.pop(k, None)
             layer["gate_router"] = P()
-            layer["w1"] = P(None, None, "model")
-            layer["w3"] = P(None, None, "model")
+            # All three stacks are [N, I, D] (layers/moe.py): the sharded
+            # inner dim is axis 1.
+            layer["w1"] = P(None, "model", None)
+            layer["w3"] = P(None, "model", None)
             layer["w2"] = P(None, "model", None)
             layer["shared_gate"] = P(None, "model")
             layer["shared_up"] = P(None, "model")
@@ -114,9 +116,9 @@ class DeepseekForCausalLM(LlamaForCausalLM):
                 layer.pop(k, None)
             lk = jax.random.split(jax.random.fold_in(key, i), 7)
             layer["gate_router"] = rand(lk[0], (e, n)).astype(jnp.float32)
-            layer["w1"] = rand(lk[1], (n, e, mi))
+            layer["w1"] = random_gate_up_stack(lk[1], n, e, mi, dtype)
             layer["w2"] = rand(lk[2], (n, mi, e))
-            layer["w3"] = rand(lk[3], (n, e, mi))
+            layer["w3"] = random_gate_up_stack(lk[3], n, e, mi, dtype)
             si = mi * self.n_shared
             layer["shared_gate"] = rand(lk[4], (e, si))
             layer["shared_up"] = rand(lk[5], (e, si))
@@ -170,14 +172,17 @@ class DeepseekForCausalLM(LlamaForCausalLM):
                 m = p + "mlp."
                 layer["gate_router"] = cast_array(
                     raw[m + "gate.weight"].T, "float32")
+                # Stacks are [N, I, D] (layers/moe.py): gate_proj and
+                # up_proj as the checkpoint has them ([out, in]), down_proj
+                # transposed.
                 layer["w1"] = np.stack(
-                    [E(f"{m}experts.{j}.gate_proj.weight")
+                    [V(f"{m}experts.{j}.gate_proj.weight")
                      for j in range(n)])
                 layer["w2"] = np.stack(
                     [E(f"{m}experts.{j}.down_proj.weight")
                      for j in range(n)])
                 layer["w3"] = np.stack(
-                    [E(f"{m}experts.{j}.up_proj.weight")
+                    [V(f"{m}experts.{j}.up_proj.weight")
                      for j in range(n)])
                 if self.n_shared:
                     layer["shared_gate"] = E(

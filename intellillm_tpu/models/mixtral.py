@@ -3,8 +3,9 @@
 Role parity: reference `vllm/model_executor/models/mixtral.py` (MixtralMoE
 :57 routing through fused_moe :138) + `mixtral_quant.py`. Llama-style
 attention (GQA + rope + RMSNorm) with a top-2 MoE feed-forward.
-Expert weights stack to [num_experts, in, out] so expert parallelism is a
-mesh axis away (shard dim 0 over "model" for EP, or dims 1/2 for TP).
+Expert weights stack to [num_experts, inner, hidden] for all of w1, w2, w3
+(layers/moe.py says why), so expert parallelism is a mesh axis away (shard
+dim 0 over "model" for EP, or the inner dim 1 for TP).
 """
 from __future__ import annotations
 
@@ -15,7 +16,7 @@ import numpy as np
 
 from intellillm_tpu.config import ModelConfig
 from intellillm_tpu.layers.attention import KVCache
-from intellillm_tpu.layers.moe import moe_ffn
+from intellillm_tpu.layers.moe import moe_ffn, random_gate_up_stack
 from intellillm_tpu.layers.normalization import fused_add_rms_norm, rms_norm
 from intellillm_tpu.layers.quantization import qmatmul
 from intellillm_tpu.logger import init_logger
@@ -79,21 +80,26 @@ class MixtralForCausalLM(LlamaForCausalLM):
         from jax.sharding import PartitionSpec as P
         specs = super().partition_specs()
 
-        def ew(spec):
-            """Expert-stacked weights: dim 0 = expert axis (EP candidate);
-            quantized stacks shard q4 like the dense weight and the
-            per-group tensors on the out dim only (union over reprs, same
-            rationale as LlamaForCausalLM.partition_specs)."""
+        def ew(packed):
+            """Expert-stacked weights: dim 0 = expert axis (EP candidate),
+            the wide inner dim sharded over "model" for TP. Dense stacks
+            are [N, I, D] for all three (layers/moe.py). Quantized stacks
+            keep the checkpoint's packed [N, in/2, out]; `packed` is the
+            spec of q4 there, the per-group tensors shard on the out dim
+            only (union over reprs, same rationale as
+            LlamaForCausalLM.partition_specs), and "dense" is for a stack
+            that loaded dense after all (parallel/mesh.py)."""
+            dense = P(None, "model", None)
             if self.quantization in ("awq", "gptq"):
-                return {"q4": spec, "s4": P(None, None, spec[2]),
-                        "z4": P(None, None, spec[2]), "inv": P()}
-            return spec
+                return {"q4": packed, "s4": P(None, None, packed[2]),
+                        "z4": P(None, None, packed[2]), "inv": P(),
+                        "dense": dense}
+            return dense
 
         for layer in specs["layers"]:
             for k in ("gate", "up", "down"):
                 layer.pop(k, None)
             layer["gate_router"] = P()
-            # Shard the wide inner dim over "model" for TP.
             layer["w1"] = ew(P(None, None, "model"))
             layer["w3"] = ew(P(None, None, "model"))
             layer["w2"] = ew(P(None, "model", None))
@@ -115,9 +121,9 @@ class MixtralForCausalLM(LlamaForCausalLM):
                 layer.pop(k, None)
             lk = jax.random.split(jax.random.fold_in(key, li), 4)
             layer["gate_router"] = rand(lk[0], (e, n)).astype(jnp.float32)
-            layer["w1"] = rand(lk[1], (n, e, i))
+            layer["w1"] = random_gate_up_stack(lk[1], n, e, i, dtype)
             layer["w2"] = rand(lk[2], (n, i, e))
-            layer["w3"] = rand(lk[3], (n, e, i))
+            layer["w3"] = random_gate_up_stack(lk[3], n, e, i, dtype)
         return params
 
     def load_weights(self, model_name_or_path: str,
@@ -147,14 +153,16 @@ class MixtralForCausalLM(LlamaForCausalLM):
                                 raw.get(prefix + ".g_idx"))
 
         def E(moe_prefix, wname):
-            """Stacked expert weights [N, in, out]. fp checkpoints stack
-            dense; GPTQ/AWQ QuantMixtral checkpoints (reference
-            `mixtral_quant.py` — per-expert quantized linears) stack the
-            packed int4 tensors, executed by the MoE layer's on-the-fly
-            dequant. Irregular layouts fall back to dense fp (lossless,
-            just bigger)."""
+            """Stacked expert weights. fp checkpoints stack dense, all
+            three [N, I, D] (layers/moe.py): w1 and w3 as the checkpoint
+            has them ([out, in]), w2 transposed. GPTQ/AWQ QuantMixtral
+            checkpoints (reference `mixtral_quant.py` — per-expert
+            quantized linears) stack the packed int4 tensors [N, in/2,
+            out], executed by the MoE layer's on-the-fly dequant. Irregular
+            layouts fall back to dense fp (lossless, just bigger)."""
             keys = [f"{moe_prefix}experts.{j}.{wname}" for j in
                     range(self.num_experts)]
+            out_in = wname != "w2"       # stored [out, in]: no transpose
             if (self.quantization in ("awq", "gptq")
                     and keys[0] + ".qweight" in raw):
                 stacked = stack_expert_int4(
@@ -164,12 +172,14 @@ class MixtralForCausalLM(LlamaForCausalLM):
                 logger.warning(
                     "QuantMixtral expert stack %s* has an irregular "
                     "layout; loading dequantized fp instead.", moe_prefix)
-                return np.stack([
+                dense = np.stack([
                     load_linear(raw, k, self.dtype, self.quantization,
                                 fp_ok=True)
-                    for k in keys])
+                    for k in keys])                       # [N, in, out]
+                return dense.swapaxes(1, 2) if out_in else dense
             return np.stack(
-                [cast_array(raw[k + ".weight"].T, self.dtype)
+                [cast_array(raw[k + ".weight"] if out_in
+                            else raw[k + ".weight"].T, self.dtype)
                  for k in keys])
 
         def W(prefix):

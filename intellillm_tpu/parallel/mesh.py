@@ -97,8 +97,11 @@ def shard_params(host_params: Any, mesh: Mesh, model) -> Any:
             # fall back to a DENSE array at the parent path (irregular
             # group layouts, dummy weights). The dense weight has the
             # same dims as its packed "q4"/"q" form — inherit that spec
-            # instead of silently replicating a multi-GiB expert stack.
-            spec = (spec_by_path.get(key + "['q4']")
+            # instead of silently replicating a multi-GiB expert stack —
+            # unless the spec dict names another under "dense" (MoE
+            # w1/w3: stored [N, I, D], packed [N, D/2, I]).
+            spec = (spec_by_path.get(key + "['dense']")
+                    or spec_by_path.get(key + "['q4']")
                     or spec_by_path.get(key + "['q']") or P())
         fixed = []
         for dim, axis in enumerate(spec):

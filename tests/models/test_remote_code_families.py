@@ -285,9 +285,12 @@ def test_deepseek_moe_routing_no_renorm():
     w2 = rng.randn(n, i, d).astype(np.float32) * 0.1
     w3 = rng.randn(n, d, i).astype(np.float32) * 0.1
 
+    # The stored stacks are [N, I, D] for all three (layers/moe.py).
     out = np.asarray(moe_ffn_dense(jnp.asarray(x), jnp.asarray(gate_w),
-                                   jnp.asarray(w1), jnp.asarray(w2),
-                                   jnp.asarray(w3), k, renormalize=False))
+                                   jnp.asarray(w1.swapaxes(1, 2)),
+                                   jnp.asarray(w2),
+                                   jnp.asarray(w3.swapaxes(1, 2)), k,
+                                   renormalize=False))
 
     def silu(v):
         return v / (1.0 + np.exp(-v))

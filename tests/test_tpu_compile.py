@@ -7,11 +7,13 @@ VMEM) it refuses here, at no chip time. Interpret mode
 (tests/kernels/) cannot see any of that, which is how a kernel that had
 never compiled was the default for every prompt token.
 
-Each test lowers one kernel at Llama-2-7B widths (32 heads of 128,
+Each kernel test lowers one kernel at Llama-2-7B widths (32 heads of 128,
 hidden 4096, inter 11008) for a described v5e device and asserts that
 `tpu_custom_call` is in the compiled text — a seam that resolved to a
-reference cannot pass as a kernel. A compile that passes is a compile,
-never a chip run.
+reference cannot pass as a kernel. The MoE tests at the end hold the
+compiled expert FFN (plain XLA, no kernel) to the stored layout of the
+expert stacks: no copy of a stack in any program. A compile that passes is
+a compile, never a chip run.
 
 This file lives at the top of tests/ on purpose: tests/kernels/conftest.py
 wraps every test there in interpret mode, under which nothing reaches
@@ -21,7 +23,9 @@ load libtpu), and the compiles run in this process with the persistent
 compilation cache off around them (a described-device entry cannot be
 read back without a chip, and warns).
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -51,23 +55,29 @@ def one_chip(topo):
 
 
 @pytest.fixture(scope="module")
-def compile_for_chip(one_chip):
-    """compile(fn, *(shape, dtype)) -> compiled text, for the described
-    chip, with the persistent compilation cache off."""
+def compiled_for_chip(one_chip):
+    """compile(fn, *(shape, dtype)) -> the compiled program, for the
+    described chip, with the persistent compilation cache off."""
     from jax.experimental.compilation_cache import compilation_cache
     was_enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
 
-    def compile_text(fn, *specs, donate=()):
+    def compile_program(fn, *specs, donate=()):
         args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
                 for shape, dtype in specs]
-        compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
-        return compiled.as_text()
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
 
-    yield compile_text
+    yield compile_program
     jax.config.update("jax_enable_compilation_cache", was_enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(compiled_for_chip):
+    """compile(fn, *(shape, dtype)) -> compiled text."""
+    return lambda fn, *specs, donate=(): compiled_for_chip(
+        fn, *specs, donate=donate).as_text()
 
 
 def _pool(dtype):
@@ -188,3 +198,70 @@ def test_quant_matmul_int4_lut_compiles(compile_for_chip):
         fn, ((256, HIDDEN), jnp.bfloat16),
         ((HIDDEN // 2, INTER), jnp.uint8), ((16, INTER), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+# --- MoE expert FFN: no copy of an expert stack in any program -----------
+
+# (experts, hidden, expert inner, top-k) as published.
+DSMOE16B = (64, 2048, 1408, 6)
+MIXTRAL_8X7B = (8, 4096, 14336, 2)
+
+
+def _moe_specs(rows, widths):
+    """x, router, and the three stacks in their stored layout [N, I, D]."""
+    n, d, i, _ = widths
+    stack = ((n, i, d), jnp.bfloat16)
+    return (((rows, d), jnp.bfloat16), ((d, n), jnp.float32), stack, stack,
+            stack)
+
+
+def _stack_sized_copies(text, widths):
+    """Lines of the compiled text that copy an operand with as many
+    elements as one expert stack (N*D*I), in whatever layout."""
+    n, d, i, _ = widths
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= (.*?) copy(-start)?\(", line)
+        if m and any(math.prod(map(int, dims.split(","))) == n * d * i
+                     for dims in re.findall(r"\[([\d,]+)\]", m.group(1))):
+            found.append(line.strip()[:120])
+    return found
+
+
+@pytest.mark.parametrize("rows,loop", [(128, 0), (128, 8), (512, 0)],
+                         ids=["decode-128", "decode-128-in-loop-of-8",
+                              "prefill-512-two-chunks"])
+def test_moe_dense_copies_no_expert_stack(compiled_for_chip, rows, loop):
+    """`moe_ffn` at DeepSeek-MoE-16B widths takes the dense path at these
+    row counts. Alone at 128 rows XLA never copied; inside a loop (the
+    K=8 loop of decode_fused/decode_cont, the chunk `while` of a 512-row
+    prefill step) its layout assignment wants w1/w3 with D minor-most and,
+    handed [N, D, I], copied both 369 MB stacks in front of the loop
+    (0.74 GB of temporaries a layer)."""
+    from intellillm_tpu.layers.moe import moe_ffn
+    top_k = DSMOE16B[3]
+
+    def ffn(x, gate_w, w1, w2, w3):
+        return moe_ffn(x, gate_w, w1, w2, w3, top_k, renormalize=False)
+
+    def looped(x, *weights):
+        return jax.lax.fori_loop(0, loop, lambda _, h: ffn(h, *weights), x)
+
+    compiled = compiled_for_chip(looped if loop else ffn,
+                                 *_moe_specs(rows, DSMOE16B))
+    assert _stack_sized_copies(compiled.as_text(), DSMOE16B) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_moe_grouped_copies_no_expert_stack(compiled_for_chip):
+    """The grouped path at Mixtral-8x7B widths, a 2048-row prefill: each
+    block's dots read one expert's slab, and no stack is copied."""
+    from intellillm_tpu.layers.moe import moe_ffn_grouped
+    top_k = MIXTRAL_8X7B[3]
+
+    def ffn(x, gate_w, w1, w2, w3):
+        return moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k)
+
+    compiled = compiled_for_chip(ffn, *_moe_specs(2048, MIXTRAL_8X7B))
+    assert _stack_sized_copies(compiled.as_text(), MIXTRAL_8X7B) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25e9
