@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass, field
-from typing import Optional, Union
+from typing import Optional, Tuple, Union
 
 from intellillm_tpu.logger import init_logger
 from intellillm_tpu.transformers_utils.config import get_hf_config
@@ -123,6 +123,32 @@ class ModelConfig:
 
     def get_hidden_size(self) -> int:
         return self.hf_config.hidden_size
+
+    def get_latent_cache_width(self) -> Optional[int]:
+        """Latent attention (MLA), told by its mechanism: a config with
+        `kv_lora_rank` and `qk_rope_head_dim` caches ONE vector a token a
+        layer, `[c_kv | k_rope]`, with no head axis and no V. Returned as
+        stored: zero-padded up to whole 128-lane tiles (576 -> 640), which
+        is what the device would pad it to anyway and what the kernels'
+        DMA windows need. None for every per-head K/V model."""
+        rank = getattr(self.hf_config, "kv_lora_rank", None)
+        rope = getattr(self.hf_config, "qk_rope_head_dim", None)
+        if not rank or not rope:
+            return None
+        return -(-(rank + rope) // 128) * 128
+
+    def get_cache_row_shape(self) -> Tuple[int, int, int]:
+        """(pools, heads, width) of what one token stores in one layer's
+        cache: a layer's cache is `pools` arrays of
+        `[num_blocks, heads, block_size, width]`. Per-head K/V: (2, kv
+        heads, head size). Latent attention: (1, 1, stored latent width).
+        The cache engine, the worker's memory profile and the runner size
+        everything from this; `get_head_size` / `get_total_num_kv_heads`
+        keep describing the attention itself."""
+        latent = self.get_latent_cache_width()
+        if latent:
+            return 1, 1, latent
+        return 2, self.get_total_num_kv_heads(), self.get_head_size()
 
     def get_head_size(self) -> int:
         if hasattr(self.hf_config, "head_dim") and self.hf_config.head_dim:

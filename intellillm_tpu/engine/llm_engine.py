@@ -470,12 +470,23 @@ class LLMEngine:
         if stats is not None:
             self._boot.set_info("warmup", dict(stats))
         cc = self.cache_config
+        from intellillm_tpu.worker.cache_engine import CacheEngine
+        mc = self.model_config
         self._boot.set_info("cache", {
             "num_device_blocks": cc.num_device_blocks,
             "num_cpu_blocks": cc.num_cpu_blocks,
             "profiled": cc.num_device_blocks_override is None,
             "block_size": cc.block_size,
             "cache_dtype": cc.cache_dtype,
+            # "latent": one pool a layer holding [c_kv | k_rope] a token;
+            # "kv": a K pool and a V pool of per-head vectors.
+            "kind": "latent" if mc.get_latent_cache_width() else "kv",
+            "pools_per_layer": mc.get_cache_row_shape()[0],
+            # Device bytes one token takes in one layer's cache (lane
+            # padding included): block bytes / block size / layers.
+            "bytes_per_token_per_layer": CacheEngine.get_cache_block_size(
+                cc.block_size, cc.cache_dtype, mc, self.parallel_config)
+            // (cc.block_size * mc.get_num_layers()),
         })
         from intellillm_tpu.utils import runtime_facts
         self._boot.set_info("runtime", runtime_facts())

@@ -30,10 +30,15 @@ every `mixed` step and for the life of the decode programs
 (tests/test_tpu_compile.py `test_moe_dense_copies_no_expert_stack` holds
 the compiled text to no such copy).
 
-Routing matches HF Mixtral: softmax over ALL experts → top-k → renormalize
-the selected weights.
+Routing is a parameter (`Routing`): the default matches HF Mixtral
+(softmax over ALL experts → top-k → renormalize the selected weights);
+sigmoid scores, group-limited selection, a scaling factor and an
+expert-parallel share (a stack that holds fewer experts than the router
+has outputs) are the other settings.
 """
 from __future__ import annotations
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -45,14 +50,74 @@ from intellillm_tpu.utils import cdiv
 _ROW_TILE = 16
 
 
+@dataclasses.dataclass(frozen=True)
+class Routing:
+    """How the router's scores become (weights, experts), and which of the
+    router's experts the stack handed to `moe_ffn` holds.
+
+    - `scoring`: "softmax" over all experts (Mixtral, DeepSeek-MoE) or
+      "sigmoid" of each expert's logit alone.
+    - `n_group` / `topk_group`: group-limited selection. The experts are
+      `n_group` equal consecutive groups, a group's score is its largest
+      expert score, only experts of the best `topk_group` groups may be
+      chosen. 1 / 1 = no limit.
+    - `renormalize`: chosen weights divided by their sum; `scale`: then
+      multiplied by it (`routed_scaling_factor`).
+    - `held_start`: expert-parallel share. The stack's N experts are the
+      router's outputs `[held_start, held_start + N)`. Routing, the choice
+      of top-k and the normalisation are over ALL of the router's outputs;
+      only held experts are computed, and what the absent ones would add
+      is left out (it is another chip's part of the sum).
+    """
+    scoring: str = "softmax"
+    renormalize: bool = True
+    n_group: int = 1
+    topk_group: int = 1
+    scale: float = 1.0
+    held_start: int = 0
+
+
+_SOFTMAX_TOP_K = Routing()
+
+
 def _route(x: jnp.ndarray, gate_w: jnp.ndarray, top_k: int,
-           renormalize: bool = True):
+           routing: Routing = _SOFTMAX_TOP_K):
+    """(weights [T, K] f32, experts [T, K] i32) over the router's whole
+    width."""
     router_logits = (x.astype(jnp.float32) @ gate_w.astype(jnp.float32))
-    weights = jax.nn.softmax(router_logits, axis=-1)          # [T, N]
-    topw, topi = jax.lax.top_k(weights, top_k)                # [T, K]
-    if renormalize:
+    if routing.scoring == "softmax":
+        weights = jax.nn.softmax(router_logits, axis=-1)      # [T, N]
+    elif routing.scoring == "sigmoid":
+        weights = jax.nn.sigmoid(router_logits)
+    else:
+        raise ValueError(f"unknown router scoring {routing.scoring!r}")
+    choose_from = weights
+    if routing.n_group > 1:
+        t, n = weights.shape
+        per = n // routing.n_group
+        group_score = weights.reshape(t, routing.n_group, per).max(-1)
+        _, best = jax.lax.top_k(group_score, routing.topk_group)
+        keep = jnp.zeros((t, routing.n_group), bool).at[
+            jnp.arange(t)[:, None], best].set(True)
+        # Scores are positive (sigmoid, softmax): -1 never wins a top-k
+        # against an expert of a kept group.
+        choose_from = jnp.where(jnp.repeat(keep, per, axis=1), weights,
+                                -1.0)
+    _, topi = jax.lax.top_k(choose_from, top_k)               # [T, K]
+    topw = jnp.take_along_axis(weights, topi, axis=1)
+    if routing.renormalize:
         topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    if routing.scale != 1.0:
+        topw = topw * routing.scale
     return topw, topi
+
+
+def held_assignments(topi: jnp.ndarray, n_held: int,
+                     held_start: int) -> jnp.ndarray:
+    """[T] i32: how many of each token's chosen experts this stack holds
+    (the numerator of intellillm_moe_assignments_total{held="here"})."""
+    local = topi - held_start
+    return ((local >= 0) & (local < n_held)).sum(-1).astype(jnp.int32)
 
 
 def random_gate_up_stack(key, n: int, d: int, i: int, dtype) -> jnp.ndarray:
@@ -86,15 +151,23 @@ def moe_ffn_dense(
     w3: jnp.ndarray,       # [N, I, D]  (up proj per expert)
     top_k: int,
     chunk_size: int = 256,
-    renormalize: bool = True,
+    routing: Routing = _SOFTMAX_TOP_K,
+    routed=None,
 ) -> jnp.ndarray:
     """`chunk_size` is the most rows a chunk may hold (it bounds the
-    [N, C, I] activations); the rows present decide the rest."""
+    [N, C, I] activations); the rows present decide the rest. `routed` is
+    a (weights, experts) pair the caller already has from `_route`."""
     t, d = x.shape
     n = w1.shape[0]
 
-    with jax.named_scope("moe_router"):
-        topw, topi = _route(x, gate_w, top_k, renormalize)
+    if routed is None:
+        with jax.named_scope("moe_router"):
+            routed = _route(x, gate_w, top_k, routing)
+    topw, topi = routed
+    if routing.held_start:
+        # one_hot gives a zero row to an index outside [0, N): a choice
+        # of an expert held elsewhere adds nothing here.
+        topi = topi - routing.held_start
     onehot = jax.nn.one_hot(topi, n, dtype=jnp.float32)       # [T, K, N]
     combine = (topw[..., None] * onehot).sum(axis=1)          # [T, N]
 
@@ -132,40 +205,51 @@ def moe_ffn_grouped(
     w3: jnp.ndarray,       # [N, I, D]
     top_k: int,
     block: int = 512,
-    renormalize: bool = True,
+    routing: Routing = _SOFTMAX_TOP_K,
+    routed=None,
 ) -> jnp.ndarray:
     t, d = x.shape
     n = w1.shape[0]
     tk = t * top_k
 
-    with jax.named_scope("moe_router"):
-        topw, topi = _route(x, gate_w, top_k, renormalize)
+    if routed is None:
+        with jax.named_scope("moe_router"):
+            routed = _route(x, gate_w, top_k, routing)
+    topw, topi = routed
 
-    flat_e = topi.reshape(-1)                                  # [T*K]
-    flat_w = topw.reshape(-1)                                  # [T*K]
+    # A choice of an expert this stack does not hold (a share: the router
+    # is wider than the stack) goes to a group of its own past the last
+    # held expert, index n, which sorts last, which no block computes and
+    # whose weight is 0. A stack that holds every expert has no such row.
+    local = topi.reshape(-1) - routing.held_start              # [T*K]
+    here = (local >= 0) & (local < n)
+    flat_e = jnp.where(here, local, n)
+    flat_w = jnp.where(here, topw.reshape(-1), 0.0)
     sort_idx = jnp.argsort(flat_e, stable=True)                # [T*K]
     sorted_e = flat_e[sort_idx]
     token_idx = sort_idx // top_k                              # source token
 
-    counts = jnp.bincount(flat_e, length=n).astype(jnp.int32)
+    counts = jnp.bincount(flat_e, length=n).astype(jnp.int32)  # held only
     padded = cdiv(counts, block) * block                       # [N]
     pad_cum = jnp.cumsum(padded)
     starts = pad_cum - padded                                  # [N] slot base
     grp_cum = jnp.cumsum(counts)
     grp_start = grp_cum - counts                               # [N] in sorted
-    pos_in_grp = jnp.arange(tk) - grp_start[sorted_e]
-    slot = starts[sorted_e] + pos_in_grp                       # [T*K] dest
-
     # Static upper bound on sum of padded group sizes (block multiple).
     s = (cdiv(tk, block) + n) * block
     nb = s // block
-    xb = jnp.zeros((s, d), x.dtype).at[slot].set(x[token_idx])
+    # Absent rows get a slot past the buffer: the scatter drops them, the
+    # gather below clamps, and their weight is 0.
+    safe_e = jnp.minimum(sorted_e, n - 1)
+    pos_in_grp = jnp.arange(tk) - grp_start[safe_e]
+    slot = jnp.where(sorted_e < n, starts[safe_e] + pos_in_grp, s)
+    xb = jnp.zeros((s, d), x.dtype).at[slot].set(x[token_idx], mode="drop")
 
     # Expert owning each block; blocks past the last padded group get a
     # clipped id and compute on zeros (their output is never gathered).
-    blk_off = jnp.arange(nb) * block
-    blk_expert = jnp.clip(jnp.searchsorted(pad_cum, blk_off, side="right"),
-                          0, n - 1)
+    blk_expert = jnp.clip(
+        jnp.searchsorted(pad_cum, jnp.arange(nb) * block, side="right"),
+        0, n - 1)
 
     def body(carry, inp):
         xc, e = inp                                            # [B, D], []
@@ -184,7 +268,8 @@ def moe_ffn_grouped(
                                      (xb.reshape(nb, block, d), blk_expert))
     out = out_blocks.reshape(s, d)                             # f32
 
-    contrib = out[slot] * flat_w[sort_idx][:, None]            # [T*K, D]
+    contrib = (out[jnp.minimum(slot, s - 1)]
+               * flat_w[sort_idx][:, None])                    # [T*K, D]
     y = jnp.zeros((t, d), jnp.float32).at[token_idx].add(contrib)
     return y.astype(x.dtype)
 
@@ -197,7 +282,8 @@ def moe_ffn(
     w3: jnp.ndarray,
     top_k: int,
     block: int = 512,
-    renormalize: bool = True,
+    routing: Routing = _SOFTMAX_TOP_K,
+    routed=None,
 ) -> jnp.ndarray:
     # All three stacks are [N, I, D] (module docstring): w1/w3 contract on
     # their last axis, so no loop around this call makes XLA re-lay them.
@@ -227,9 +313,13 @@ def moe_ffn(
     # left prefill-sized batches on the slow dense path. The n*block
     # padding term must stay in the inequality: many-expert models
     # (DeepSeek n=64) pay n padding blocks on the grouped path, which
-    # dominates at small t.
-    if t * top_k + n * block <= n * t:
+    # dominates at small t. The routed rows are those routed HERE: a
+    # stack that holds n of the router's n_router experts is sent
+    # t*top_k*n/n_router of the choices in expectation (all of them when
+    # it holds every expert, as Mixtral and DeepSeek-MoE do).
+    n_router = gate_w.shape[-1]
+    if t * top_k * n + n * block * n_router <= n * t * n_router:
         return moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, block=block,
-                               renormalize=renormalize)
-    return moe_ffn_dense(x, gate_w, w1, w2, w3, top_k,
-                         renormalize=renormalize)
+                               routing=routing, routed=routed)
+    return moe_ffn_dense(x, gate_w, w1, w2, w3, top_k, routing=routing,
+                         routed=routed)

@@ -144,10 +144,10 @@ def _yarn_linear_ramp(low: float, high: float, dim: int) -> np.ndarray:
     return np.clip(ramp, 0, 1)
 
 
-def _yarn_get_mscale(scale: float) -> float:
+def _yarn_get_mscale(scale: float, mscale: float = 1.0) -> float:
     if scale <= 1:
         return 1.0
-    return 0.1 * math.log(scale) + 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
 
 
 class YaRNScalingRotaryEmbedding(RotaryEmbedding):
@@ -158,7 +158,17 @@ class YaRNScalingRotaryEmbedding(RotaryEmbedding):
                  extrapolation_factor: float = 1.0,
                  attn_factor: float = 1.0,
                  beta_fast: int = 32,
-                 beta_slow: int = 1) -> None:
+                 beta_slow: int = 1,
+                 mscale: Optional[float] = None,
+                 mscale_all_dim: Optional[float] = None) -> None:
+        # `mscale` / `mscale_all_dim` (the DeepSeek-V2 family's rule, which
+        # A.X-K1's config follows): cos/sin are scaled by the RATIO
+        # yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+        # and the model puts yarn_mscale(factor, mscale_all_dim)**2 on the
+        # whole attention score. Absent (Llama-style YaRN), cos/sin carry
+        # yarn_mscale(factor) themselves.
+        self.mscale = mscale
+        self.mscale_all_dim = mscale_all_dim
         self.scaling_factor = scaling_factor
         self.extrapolation_factor = extrapolation_factor
         self.attn_factor = attn_factor
@@ -168,6 +178,11 @@ class YaRNScalingRotaryEmbedding(RotaryEmbedding):
                          is_neox_style)
 
     def _mscale(self) -> float:
+        if self.mscale is not None or self.mscale_all_dim is not None:
+            return (_yarn_get_mscale(self.scaling_factor, self.mscale or 0.0)
+                    / _yarn_get_mscale(self.scaling_factor,
+                                       self.mscale_all_dim or 0.0)
+                    * self.attn_factor)
         return _yarn_get_mscale(self.scaling_factor) * self.attn_factor
 
     def _compute_freqs(self) -> np.ndarray:
@@ -226,7 +241,7 @@ def get_rope(
                 k: v
                 for k, v in rope_scaling.items()
                 if k in ("extrapolation_factor", "attn_factor", "beta_fast",
-                         "beta_slow")
+                         "beta_slow", "mscale", "mscale_all_dim")
             }
             rope = YaRNScalingRotaryEmbedding(head_size, rotary_dim,
                                               original_max, base,

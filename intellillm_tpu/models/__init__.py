@@ -7,6 +7,7 @@ via HF config introspection.
 """
 from typing import Dict, Type
 
+from intellillm_tpu.models.axk1 import AXK1ForCausalLM
 from intellillm_tpu.models.baichuan import (BaiChuanForCausalLM,
                                             BaichuanForCausalLM)
 from intellillm_tpu.models.bloom import BloomForCausalLM
@@ -63,6 +64,7 @@ _MODEL_REGISTRY: Dict[str, Type] = {
     "ChatGLMModel": ChatGLMForCausalLM,
     "ChatGLMForConditionalGeneration": ChatGLMForCausalLM,
     "DeepseekForCausalLM": DeepseekForCausalLM,
+    "AXK1ForCausalLM": AXK1ForCausalLM,          # latent attention + EP share
 }
 
 

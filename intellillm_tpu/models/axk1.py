@@ -1,0 +1,370 @@
+"""A.X-K1 (`model_type` "axk1"): latent attention + sigmoid-routed experts.
+
+Source: https://huggingface.co/skt/A.X-K1/blob/main/config.json (519 B
+parameters; the DeepSeek-V2/V3 block). Per layer (pre-norm residual, RMSNorm
+as Llama):
+
+- Attention (MLA). `c_q = RMSNorm(x W_qa)`; `[q_nope | q_rope]_h = c_q W_qb`;
+  `[c_kv | k_rope] = x W_kva`, `c_kv <- RMSNorm(c_kv)`; RoPE on `q_rope` and
+  the one shared `k_rope` (interleaved pairs, YaRN frequencies, cos/sin at
+  ratio `yarn_mscale(f, mscale) / yarn_mscale(f, mscale_all_dim)`); the
+  cache holds `[c_kv | k_rope]`. Scores are scaled by
+  `(d_nope + d_rope)^-1/2 * m^2`, `m = yarn_mscale(f, mscale_all_dim)`.
+  The program runs the absorbed form (`layers/mla_attention.py`): `W_kvb` is
+  stored split per head as `w_uk [H, d_nope, r_kv]` and `w_uv [H, r_kv,
+  d_v]` (`W_kvb,h = [W_UK,h | W_UV,h]`), `q~_h = q_nope,h W_UK,h^T` goes to
+  the kernel with `q_rope`, and the attended latent comes back through
+  `W_UV,h` and `W_o`.
+- FFN. The first `first_k_dense_replace` layers: SwiGLU of width
+  `intermediate_size`. The others: `sigmoid` router over ALL experts,
+  `n_group` groups of which the best `topk_group` are kept, top-k inside
+  them, weights normalised and scaled by `routed_scaling_factor`, plus a
+  shared expert (`layers/moe.py` `Routing`).
+
+Expert parallelism (`ep_size`, `ep_rank` in the config): with `ep_size` > 1
+`n_routed_experts` is what THIS chip holds, experts `[ep_rank * n,
+(ep_rank + 1) * n)` of a router `n * ep_size` wide. The layer routes over
+the whole router, computes its own experts' part and leaves the rest out:
+that is another chip's part of the sum, and no code here stands in for the
+other chips or their exchange. The shared expert, attention and the dense
+layer are whole on every chip (a latent cache has no head axis to divide).
+
+tp > 1 is not supported for this model yet (`partition_specs` says so).
+"""
+from __future__ import annotations
+
+import copy
+import math
+from typing import Dict, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from intellillm_tpu.config import ModelConfig
+from intellillm_tpu.layers.activation import get_act_fn
+from intellillm_tpu.layers.mla_attention import LatentAttention
+from intellillm_tpu.layers.moe import (Routing, _route, held_assignments,
+                                       moe_ffn, random_gate_up_stack)
+from intellillm_tpu.layers.normalization import fused_add_rms_norm, rms_norm
+from intellillm_tpu.layers.rotary_embedding import (_yarn_get_mscale,
+                                                    get_rope)
+from intellillm_tpu.models.weight_utils import (cast_array,
+                                                hf_model_weights_iterator)
+
+Params = Dict[str, object]
+
+
+class AXK1ForCausalLM:
+
+    supports_lora = False
+    supported_quantization = ()
+
+    def __init__(self, model_config: ModelConfig) -> None:
+        cfg = model_config.hf_config
+        if model_config.quantization is not None:
+            raise NotImplementedError(
+                "A.X-K1 runs unquantised (bf16 / float32) only")
+        self.config = cfg
+        self.model_config = model_config
+        self.dtype = model_config.dtype
+        self.num_layers = cfg.num_hidden_layers
+        self.num_heads = cfg.num_attention_heads
+        self.hidden_size = cfg.hidden_size
+        self.rms_eps = cfg.rms_norm_eps
+        self.act = get_act_fn(cfg.hidden_act)
+        self.q_rank = cfg.q_lora_rank
+        self.kv_rank = cfg.kv_lora_rank
+        self.d_nope = cfg.qk_nope_head_dim
+        self.d_rope = cfg.qk_rope_head_dim
+        self.d_v = cfg.v_head_dim
+        self.first_dense = cfg.first_k_dense_replace
+        self.moe_freq = cfg.moe_layer_freq
+        self.moe_inter = cfg.moe_intermediate_size
+        self.n_shared = cfg.n_shared_experts or 0
+        self.top_k = cfg.num_experts_per_tok
+        # The share: `n_routed_experts` held here of a router ep_size x wider.
+        self.ep_size = int(getattr(cfg, "ep_size", 1) or 1)
+        self.ep_rank = int(getattr(cfg, "ep_rank", 0) or 0)
+        if not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(f"ep_rank {self.ep_rank} outside ep_size "
+                             f"{self.ep_size}")
+        self.n_held = cfg.n_routed_experts
+        self.n_router = self.n_held * self.ep_size
+        if self.n_router % cfg.n_group:
+            raise ValueError(f"router width {self.n_router} does not divide "
+                             f"into n_group {cfg.n_group}")
+        self.routing = Routing(
+            scoring=cfg.scoring_func,
+            renormalize=bool(cfg.norm_topk_prob),
+            n_group=cfg.n_group, topk_group=cfg.topk_group,
+            scale=float(cfg.routed_scaling_factor),
+            held_start=self.ep_rank * self.n_held)
+        n_moe = sum(self._is_moe_layer(i) for i in range(self.num_layers))
+        # A share reports how many of each token's choices it holds
+        # (worker/model_runner.py `_pack`); the whole model has nothing
+        # to report.
+        self.moe_choices_per_token = (self.top_k * n_moe
+                                      if self.ep_size > 1 else 0)
+
+        scaling = getattr(cfg, "rope_scaling", None)
+        self.rope = get_rope(self.d_rope, self.d_rope,
+                             cfg.max_position_embeddings, cfg.rope_theta,
+                             is_neox_style=False, rope_scaling=scaling)
+        # YaRN's table has factor x original positions (131,072 rows, 34 MB
+        # for cos and sin) and every compiled program holds its own copy
+        # of it as a constant: keep the rows a position can reach.
+        limit = model_config.max_model_len
+        if limit and self.rope.cos_cache.shape[0] > limit:
+            self.rope = copy.copy(self.rope)
+            self.rope.cos_cache = self.rope.cos_cache[:limit]
+            self.rope.sin_cache = self.rope.sin_cache[:limit]
+        scale = (self.d_nope + self.d_rope)**-0.5
+        if scaling and scaling.get("type", scaling.get("rope_type")) == "yarn":
+            m = _yarn_get_mscale(scaling["factor"],
+                                 scaling.get("mscale_all_dim", 0.0))
+            scale *= m * m
+        self.attn = LatentAttention(self.num_heads, self.kv_rank,
+                                    self.d_rope, scale)
+
+    def _is_moe_layer(self, i: int) -> bool:
+        return i >= self.first_dense and i % self.moe_freq == 0
+
+    # --- forward ---------------------------------------------------------
+
+    def __call__(self, params: Params, input_ids, positions, kv_caches,
+                 attn_metadata):
+        h = params["embed_tokens"][input_ids]
+        residual = None
+        new_caches = []
+        held = None
+        for i in range(self.num_layers):
+            h, residual, cache, held_i = self._layer(
+                params["layers"][i], h, residual, kv_caches[i],
+                attn_metadata, positions)
+            new_caches.append(cache)
+            if held_i is not None:
+                held = held_i if held is None else held + held_i
+        h, _ = fused_add_rms_norm(h, residual, params["norm"], self.rms_eps)
+        if self.moe_choices_per_token:
+            return h, new_caches, held
+        return h, new_caches
+
+    def _attention(self, lp, h, kv_cache, attn_metadata, positions):
+        b, l, _ = h.shape
+        hh = self.num_heads
+        with jax.named_scope("mla_q_proj"):
+            c_q = rms_norm(h @ lp["q_a"], lp["q_a_norm"], self.rms_eps)
+            q = (c_q @ lp["q_b"]).reshape(b, l, hh, self.d_nope + self.d_rope)
+            q_nope, q_rope = q[..., :self.d_nope], q[..., self.d_nope:]
+        with jax.named_scope("mla_kv_proj"):
+            ckv = h @ lp["kv_a"]
+            c_kv = rms_norm(ckv[..., :self.kv_rank], lp["kv_a_norm"],
+                            self.rms_eps)
+            k_rope = ckv[..., self.kv_rank:][:, :, None, :]
+        q_rope, k_rope = self.rope(positions, q_rope, k_rope)
+        with jax.named_scope("mla_absorb_q"):
+            q_abs = jnp.einsum("blhn,hnr->blhr", q_nope, lp["w_uk"])
+        q_lat = jnp.concatenate([q_abs, q_rope], axis=-1)
+        kv_new = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)
+        o_lat, kv_cache = self.attn(q_lat, kv_new, kv_cache, attn_metadata)
+        with jax.named_scope("mla_absorb_o"):
+            o = jnp.einsum("blhr,hrv->blhv", o_lat, lp["w_uv"])
+        return o.reshape(b, l, hh * self.d_v) @ lp["o"], kv_cache
+
+    def _layer(self, lp, h, residual, kv_cache, attn_metadata, positions):
+        b, l, e = h.shape
+        if residual is None:
+            residual = h
+            h = rms_norm(h, lp["input_norm"], self.rms_eps)
+        else:
+            h, residual = fused_add_rms_norm(h, residual, lp["input_norm"],
+                                             self.rms_eps)
+        h, kv_cache = self._attention(lp, h, kv_cache, attn_metadata,
+                                      positions)
+        h, residual = fused_add_rms_norm(h, residual, lp["post_attn_norm"],
+                                         self.rms_eps)
+        if "w1" not in lp:
+            h = (self.act(h @ lp["gate"]) * (h @ lp["up"])) @ lp["down"]
+            return h, residual, kv_cache, None
+        flat = h.reshape(b * l, e)
+        with jax.named_scope("moe_router"):
+            routed = _route(flat, lp["gate_router"], self.top_k,
+                            routing=self.routing)
+        out = moe_ffn(flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
+                      self.top_k, routing=self.routing, routed=routed)
+        held = None
+        if self.moe_choices_per_token:
+            held = held_assignments(routed[1], self.n_held,
+                                    self.routing.held_start).reshape(b, l)
+        if self.n_shared:
+            with jax.named_scope("moe_shared"):
+                out = out + (self.act(flat @ lp["shared_gate"]) *
+                             (flat @ lp["shared_up"])) @ lp["shared_down"]
+        return out.reshape(b, l, e), residual, kv_cache, held
+
+    def compute_logits(self, params: Params, hidden, lora=None):
+        return hidden @ params["lm_head"]
+
+    def partition_specs(self):
+        """Only asked for when the mesh spans chips (parallel/mesh.py
+        `shard_params`)."""
+        raise NotImplementedError(
+            "A.X-K1 runs on one chip (tensor_parallel_size 1) in this "
+            "engine: its latent cache has no head axis to shard, and its "
+            "experts are placed by the config's ep_size / ep_rank (one "
+            "chip's share of an expert-parallel deployment), not by the "
+            "mesh")
+
+    def _param_shapes(self):
+        """The param tree's structure (leaves: shapes)."""
+        cfg = self.config
+        e, hh = self.hidden_size, self.num_heads
+        layers = []
+        for i in range(self.num_layers):
+            layer = {
+                "input_norm": (e, ), "post_attn_norm": (e, ),
+                "q_a": (e, self.q_rank), "q_a_norm": (self.q_rank, ),
+                "q_b": (self.q_rank, hh * (self.d_nope + self.d_rope)),
+                "kv_a": (e, self.kv_rank + self.d_rope),
+                "kv_a_norm": (self.kv_rank, ),
+                "w_uk": (hh, self.d_nope, self.kv_rank),
+                "w_uv": (hh, self.kv_rank, self.d_v),
+                "o": (hh * self.d_v, e),
+            }
+            if self._is_moe_layer(i):
+                mi, n = self.moe_inter, self.n_held
+                layer.update({
+                    "gate_router": (e, self.n_router),
+                    "w1": (n, mi, e), "w2": (n, mi, e), "w3": (n, mi, e)})
+                if self.n_shared:
+                    si = mi * self.n_shared
+                    layer.update({"shared_gate": (e, si),
+                                  "shared_up": (e, si),
+                                  "shared_down": (si, e)})
+            else:
+                inter = cfg.intermediate_size
+                layer.update({"gate": (e, inter), "up": (e, inter),
+                              "down": (inter, e)})
+            layers.append(layer)
+        return {"embed_tokens": (cfg.vocab_size, e), "norm": (e, ),
+                "lm_head": (e, cfg.vocab_size), "layers": layers}
+
+    # --- weights ---------------------------------------------------------
+
+    def init_random_params(self, seed: int = 0) -> Params:
+        """Seeded random weights for `--load-format dummy`. Every array is
+        waited for as it is drawn (as `random_gate_up_stack` does): the
+        host otherwise runs layers ahead of the device and the draws'
+        float32 intermediates pile up beside ~10 GB of weights."""
+        dtype = jnp.dtype(self.dtype)
+        key = jax.random.PRNGKey(seed)
+        count = [0]
+
+        def draw(shape, name):
+            count[0] += 1
+            k = jax.random.fold_in(key, count[0])
+            if name.endswith("norm"):
+                # Not all ones, so that a norm's weight left out shows.
+                w = 1.0 + 0.1 * jax.random.normal(k, shape, jnp.float32)
+                return jax.block_until_ready(w.astype(dtype))
+            if name in ("w1", "w3"):
+                n, i, d = shape
+                return random_gate_up_stack(k, n, d, i, dtype)
+            # Fan-in scaling keeps activations, scores and logits O(1) at
+            # any width, so a comparison with the reference means
+            # something. The projections that write to the residual stream
+            # are a tenth of that: a layer then adds a tenth of the stream's
+            # magnitude, as where training has let the stream dominate. At
+            # full scale seven random layers amplified bf16's multiplication
+            # noise to 0.2 nats of log-softmax against the float32 reference
+            # and 0.44 between two bf16 programs (PERF.md section 6, PR 26).
+            scale = (1.0 if name == "embed_tokens"
+                     else 1.0 / math.sqrt(shape[-2]))
+            if name in ("o", "down", "w2", "shared_down"):
+                scale *= 0.1
+            w = jax.random.normal(k, shape, jnp.float32) * scale
+            if name == "gate_router":
+                return jax.block_until_ready(w)       # float32 router
+            return jax.block_until_ready(w.astype(dtype))
+
+        def build(tree):
+            if isinstance(tree, dict):
+                return {k: (build(v) if isinstance(v, (dict, list))
+                            else draw(v, k)) for k, v in tree.items()}
+            return [build(v) for v in tree]
+
+        return build(self._param_shapes())
+
+    def load_weights(self, model_name_or_path: str,
+                     load_format: str = "auto",
+                     revision: Optional[str] = None) -> Params:
+        """Checkpoint names as the DeepSeek-V3 family publishes them
+        (`self_attn.{q_a_proj, q_a_layernorm, q_b_proj, kv_a_proj_with_mqa,
+        kv_a_layernorm, kv_b_proj, o_proj}`, `mlp.gate`, `mlp.experts.N`,
+        `mlp.shared_experts`). A share loads its own experts' slices."""
+        raw: Dict[str, np.ndarray] = {}
+        for name, arr in hf_model_weights_iterator(model_name_or_path,
+                                                   load_format, revision):
+            if "rotary_emb.inv_freq" in name:
+                continue
+            raw[name] = arr
+
+        def T(key):       # [out, in] -> [in, out]
+            return cast_array(raw[key].T, self.dtype)
+
+        def V(key):
+            return cast_array(raw[key], self.dtype)
+
+        hh = self.num_heads
+        vocab = self.config.vocab_size
+        params: Params = {
+            "embed_tokens": V("model.embed_tokens.weight")[:vocab],
+            "norm": V("model.norm.weight"),
+            "lm_head": T("lm_head.weight")[:, :vocab],
+            "layers": [],
+        }
+        first = self.routing.held_start
+        for i in range(self.num_layers):
+            p = f"model.layers.{i}."
+            a = p + "self_attn."
+            kv_b = V(a + "kv_b_proj.weight").reshape(
+                hh, self.d_nope + self.d_v, self.kv_rank)
+            layer = {
+                "input_norm": V(p + "input_layernorm.weight"),
+                "post_attn_norm": V(p + "post_attention_layernorm.weight"),
+                "q_a": T(a + "q_a_proj.weight"),
+                "q_a_norm": V(a + "q_a_layernorm.weight"),
+                "q_b": T(a + "q_b_proj.weight"),
+                "kv_a": T(a + "kv_a_proj_with_mqa.weight"),
+                "kv_a_norm": V(a + "kv_a_layernorm.weight"),
+                "w_uk": np.ascontiguousarray(kv_b[:, :self.d_nope, :]),
+                "w_uv": np.ascontiguousarray(
+                    kv_b[:, self.d_nope:, :].swapaxes(1, 2)),
+                "o": T(a + "o_proj.weight"),
+            }
+            m = p + "mlp."
+            if self._is_moe_layer(i):
+                held = range(first, first + self.n_held)
+                layer["gate_router"] = cast_array(raw[m + "gate.weight"].T,
+                                                  "float32")
+                # Stacks are [N, I, D] (layers/moe.py).
+                layer["w1"] = np.stack(
+                    [V(f"{m}experts.{j}.gate_proj.weight") for j in held])
+                layer["w2"] = np.stack(
+                    [T(f"{m}experts.{j}.down_proj.weight") for j in held])
+                layer["w3"] = np.stack(
+                    [V(f"{m}experts.{j}.up_proj.weight") for j in held])
+                if self.n_shared:
+                    layer["shared_gate"] = T(
+                        m + "shared_experts.gate_proj.weight")
+                    layer["shared_up"] = T(
+                        m + "shared_experts.up_proj.weight")
+                    layer["shared_down"] = T(
+                        m + "shared_experts.down_proj.weight")
+            else:
+                layer["gate"] = T(m + "gate_proj.weight")
+                layer["up"] = T(m + "up_proj.weight")
+                layer["down"] = T(m + "down_proj.weight")
+            params["layers"].append(layer)
+        return params

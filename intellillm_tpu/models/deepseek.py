@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from intellillm_tpu.config import ModelConfig
-from intellillm_tpu.layers.moe import moe_ffn, random_gate_up_stack
+from intellillm_tpu.layers.moe import (Routing, moe_ffn,
+                                       random_gate_up_stack)
 from intellillm_tpu.layers.normalization import fused_add_rms_norm, rms_norm
 from intellillm_tpu.models.llama import LlamaForCausalLM, Params
 from intellillm_tpu.models.weight_utils import (cast_array,
@@ -36,7 +37,8 @@ class DeepseekForCausalLM(LlamaForCausalLM):
         self.n_shared = getattr(cfg, "n_shared_experts", 0) or 0
         self.top_k = cfg.num_experts_per_tok
         self.moe_inter = cfg.moe_intermediate_size
-        self.renormalize = bool(getattr(cfg, "norm_topk_prob", False))
+        self.routing = Routing(
+            renormalize=bool(getattr(cfg, "norm_topk_prob", False)))
         self.first_dense = getattr(cfg, "first_k_dense_replace", 0)
         self.moe_freq = getattr(cfg, "moe_layer_freq", 1)
 
@@ -70,7 +72,7 @@ class DeepseekForCausalLM(LlamaForCausalLM):
                                          self.rms_eps)
         flat = h.reshape(b * l, e)
         out = moe_ffn(flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
-                      self.top_k, renormalize=self.renormalize)
+                      self.top_k, routing=self.routing)
         if self.n_shared:
             with jax.named_scope("moe_shared"):
                 gate = flat @ lp["shared_gate"]

@@ -128,6 +128,14 @@ class _EfficiencyMetrics:
             "Context lengths the attention kernel walks, summed over the "
             "live rows and substeps of the dispatched programs.",
             ["program"])
+        self.counter_moe_assignments = Counter(
+            "intellillm_moe_assignments_total",
+            "Token x expert choices of an expert-parallel share's router, "
+            "by whether this chip holds the chosen expert (held: here | "
+            "absent). Only a model whose expert stacks hold fewer experts "
+            "than its router has outputs counts here; here / (here + "
+            "absent) is held experts / router width in expectation.",
+            ["held"])
         # Pre-create the label children so the series exist (at 0) from
         # the first scrape, before any dispatch happens: a ratio over a
         # series that never appeared reads as "no answer", not as 0.
@@ -179,8 +187,6 @@ def analytic_flops_per_token(model_config) -> Optional[float]:
         h = int(model_config.get_hidden_size())
         layers = int(model_config.get_num_layers())
         vocab = int(model_config.get_vocab_size())
-        kv_dim = (int(model_config.get_total_num_kv_heads())
-                  * int(model_config.get_head_size()))
         hf = model_config.hf_config
         inter = getattr(hf, "intermediate_size", None) \
             or getattr(hf, "ffn_dim", None) or 4 * h
@@ -189,7 +195,24 @@ def analytic_flops_per_token(model_config) -> Optional[float]:
         # Gated MLPs (SwiGLU-family) carry a third h x inter matrix.
         mlp_mats = 3 if ("silu" in act or "swish" in act
                          or "glu" in act) else 2
-        attn = 2 * h * h + 2 * h * kv_dim      # q,o + k,v projections
+        rank = getattr(hf, "kv_lora_rank", None)
+        rope = getattr(hf, "qk_rope_head_dim", None)
+        if rank and rope:
+            # Latent attention as every step runs it (absorbed): x -> c_q
+            # -> q, x -> [c_kv | k_rope], q_nope W_UK and the attended
+            # latent W_UV per head, o.
+            heads = int(model_config.get_num_attention_heads())
+            qk = int(hf.qk_nope_head_dim) + rope
+            nope_v = int(hf.qk_nope_head_dim) + int(hf.v_head_dim)
+            q_rank = getattr(hf, "q_lora_rank", None)
+            q = (h * q_rank + q_rank * heads * qk if q_rank
+                 else h * heads * qk)
+            attn = (q + h * (rank + rope) + heads * rank * nope_v
+                    + heads * int(hf.v_head_dim) * h)
+        else:
+            kv_dim = (int(model_config.get_total_num_kv_heads())
+                      * int(model_config.get_head_size()))
+            attn = 2 * h * h + 2 * h * kv_dim  # q,o + k,v projections
         mlp = mlp_mats * h * int(inter)
         return float(2 * (layers * (attn + mlp) + h * vocab))
     except Exception as e:
@@ -399,6 +422,21 @@ class EfficiencyTracker:
             m.counter_tokens.labels("pad", phase).inc(pad_tokens)
             for axis, ratio in fills:
                 m.hist_fill_ratio.labels(phase, axis).observe(ratio)
+
+    def record_moe_assignments(self, here: int, total: int) -> None:
+        """Token x expert choices of a finished step: `here` went to
+        experts this chip holds, `total - here` to experts held
+        elsewhere. The numbers ride the step's packed device->host fetch
+        (worker/model_runner.py `_pack`)."""
+        if not self.enabled or self._metrics is None:
+            return
+        with self._lock:
+            if self._warmup_depth > 0:
+                return
+        m = self._metrics
+        m.counter_moe_assignments.labels("here").inc(int(here))
+        m.counter_moe_assignments.labels("absent").inc(
+            max(int(total) - int(here), 0))
 
     def record_step(self, step_time: float) -> Optional[float]:
         """Engine step boundary: fold the real tokens dispatched since

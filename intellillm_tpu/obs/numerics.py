@@ -352,16 +352,20 @@ class KVIntegrityAuditor:
         return int.from_bytes(h, "big") / 2**64 < self.sample
 
     @staticmethod
-    def _digest(k_arr: np.ndarray, v_arr: np.ndarray) -> str:
+    def _digest(k_arr: np.ndarray, v_arr: Optional[np.ndarray]) -> str:
         h = hashlib.blake2b(digest_size=16)
         h.update(np.ascontiguousarray(k_arr).view(np.uint8))
-        h.update(np.ascontiguousarray(v_arr).view(np.uint8))
+        if v_arr is not None:
+            h.update(np.ascontiguousarray(v_arr).view(np.uint8))
         return h.hexdigest()
 
     def record(self, path: str, layer: int, block: int,
-               k_arr: np.ndarray, v_arr: np.ndarray) -> None:
-        """Hash a sampled staged block. `swap_out` digests are kept for
-        later `verify`; export/import only count (see class docstring)."""
+               k_arr: np.ndarray, v_arr: Optional[np.ndarray] = None
+               ) -> None:
+        """Hash a sampled staged block: its K and V slabs, or (`v_arr`
+        None) the one slab of a latent pool. `swap_out` digests are kept
+        for later `verify`; export/import only count (see class
+        docstring)."""
         assert path in ("swap_out", "export", "import"), path
         digest = self._digest(k_arr, v_arr)
         with self._lock:
@@ -372,7 +376,8 @@ class KVIntegrityAuditor:
             self._metrics.counter_kv_checksums.labels(path).inc()
 
     def verify(self, path: str, layer: int, block: int,
-               k_arr: np.ndarray, v_arr: np.ndarray) -> Optional[bool]:
+               k_arr: np.ndarray, v_arr: Optional[np.ndarray] = None
+               ) -> Optional[bool]:
         """Re-hash a host block about to be swapped in. Returns True
         (match), False (CAUGHT corruption) or None (no digest on
         record — the block wasn't sampled at swap-out)."""

@@ -13,7 +13,9 @@ lockstep with XLA compiles and the jit bucket keys never change):
   INTELLILLM_PALLAS_<NAME> flag (default on), so one hot-path kernel can
   be reverted to its jnp reference without losing the others. Used by
   the ragged fused cache-write+attend kernel ("ragged") and the
-  batched-LoRA BGMV kernel ("bgmv"); see docs/kernels.md.
+  batched-LoRA BGMV kernel ("bgmv"); see docs/kernels.md. A kernel with
+  no flag of its own (the latent-attention seams, "mla") follows the
+  backend gate alone and still records its own label.
 """
 from __future__ import annotations
 
@@ -105,7 +107,9 @@ def shard_over_heads(fn, mesh, num_kv_heads: int, in_head_dims,
 
 def _kernel_flag(kernel: str) -> Optional[bool]:
     from intellillm_tpu.utils import parse_env_flag
-    env = _KERNEL_FLAGS[kernel]
+    env = _KERNEL_FLAGS.get(kernel)
+    if env is None:
+        return None
     raw = os.environ.get(env)
     flag = parse_env_flag(raw)
     if flag is None and raw is not None and raw.strip():

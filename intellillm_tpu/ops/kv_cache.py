@@ -29,6 +29,25 @@ import jax.numpy as jnp
 PAD_SLOT_ID = -1
 
 
+def scatter_rows(
+    rows: jnp.ndarray,     # [num_tokens, H, D]
+    pool: jnp.ndarray,     # [num_blocks, H, block_size, D]
+    slot_mapping: jnp.ndarray,  # [num_tokens] i32; slot = block*block_size+off
+) -> jnp.ndarray:
+    """Scatter new rows into one paged pool at their assigned slots (a K
+    pool, a V pool, or a latent pool with H = 1)."""
+    num_blocks, num_heads, block_size, _ = pool.shape
+    # Negative (padding) slots → OOB sentinel, dropped by the scatter.
+    safe_slots = jnp.where(slot_mapping < 0, num_blocks * block_size,
+                           slot_mapping)
+    block_idx = safe_slots // block_size           # [T]
+    off_idx = safe_slots % block_size              # [T]
+    head_idx = jnp.arange(num_heads, dtype=slot_mapping.dtype)
+    return pool.at[block_idx[:, None], head_idx[None, :],
+                   off_idx[:, None]].set(rows.astype(pool.dtype),
+                                         mode="drop")
+
+
 def reshape_and_cache(
     key: jnp.ndarray,      # [num_tokens, num_kv_heads, head_size]
     value: jnp.ndarray,    # [num_tokens, num_kv_heads, head_size]
@@ -37,33 +56,19 @@ def reshape_and_cache(
     slot_mapping: jnp.ndarray,  # [num_tokens] i32; slot = block*block_size+off
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Scatter new K/V rows into the paged pool at their assigned slots."""
-    num_blocks, num_heads, block_size, head_size = k_cache.shape
-    # Negative (padding) slots → OOB sentinel, dropped by the scatter.
-    safe_slots = jnp.where(slot_mapping < 0, num_blocks * block_size,
-                           slot_mapping)
-    block_idx = safe_slots // block_size           # [T]
-    off_idx = safe_slots % block_size              # [T]
-    head_idx = jnp.arange(num_heads, dtype=slot_mapping.dtype)
-
-    k_cache = k_cache.at[block_idx[:, None], head_idx[None, :],
-                         off_idx[:, None]].set(
-                             key.astype(k_cache.dtype), mode="drop")
-    v_cache = v_cache.at[block_idx[:, None], head_idx[None, :],
-                         off_idx[:, None]].set(
-                             value.astype(v_cache.dtype), mode="drop")
-    return k_cache, v_cache
+    return (scatter_rows(key, k_cache, slot_mapping),
+            scatter_rows(value, v_cache, slot_mapping))
 
 
 def commit_staged_chunk(
-    k_stage: jnp.ndarray,       # [B, C, Hkv, D]
-    v_stage: jnp.ndarray,
-    k_pool: jnp.ndarray,        # [NB, Hkv, BS, D]
-    v_pool: jnp.ndarray,
+    stages: Tuple[jnp.ndarray, ...],   # each [B, C, Hkv, D]
+    pools: Tuple[jnp.ndarray, ...],    # each [NB, Hkv, BS, D]
     start_pos: jnp.ndarray,     # [B] i32: pool position of stage slot 0
     n_valid: jnp.ndarray,       # [B] i32: staged tokens to commit (0=pad)
     block_tables: jnp.ndarray,  # [B, W] i32
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, ...]:
     """Commit a fused-decode staging chunk into the pool, page-granular.
+    `stages`/`pools` are a layer's (K, V) pair or its one latent pool.
 
     Role parity: reference `csrc/cache_kernels.cu:221` (reshape_and_cache),
     specialized to the chunk commit where each sequence writes C
@@ -83,8 +88,8 @@ def commit_staged_chunk(
     and block ownership (copy-on-write gives running sequences exclusive
     tail pages) rules out cross-sequence duplicates.
     """
-    b, c, hkv, d = k_stage.shape
-    nb, _, bs, _ = k_pool.shape
+    b, c, hkv, d = stages[0].shape
+    nb, _, bs, _ = pools[0].shape
     w = block_tables.shape[1]
     npages = (c + bs - 1) // bs + 1
 
@@ -116,7 +121,7 @@ def commit_staged_chunk(
         scatter_ids = jnp.where(live, page_ids, nb)          # OOB → drop
         return pool.at[scatter_ids].set(merged, mode="drop")
 
-    return merge(k_stage, k_pool), merge(v_stage, v_pool)
+    return tuple(merge(stage, pool) for stage, pool in zip(stages, pools))
 
 
 def gather_kv_for_attention(
@@ -145,18 +150,15 @@ def _pad_indices(idx: List[int], sentinel: int) -> "np.ndarray":
 
 @functools.partial(jax.jit, donate_argnums=(0, ))
 def _copy_blocks_jit(kv_caches, src_idx, dst_idx):
-    out = []
-    for k_cache, v_cache in kv_caches:
-        k_cache = k_cache.at[dst_idx].set(k_cache[src_idx], mode="drop")
-        v_cache = v_cache.at[dst_idx].set(v_cache[src_idx], mode="drop")
-        out.append((k_cache, v_cache))
-    return out
+    # A layer's cache is (K, V) or the 1-tuple (latent pool,).
+    return [tuple(pool.at[dst_idx].set(pool[src_idx], mode="drop")
+                  for pool in pools) for pools in kv_caches]
 
 
 def copy_blocks(
-    kv_caches: List[Tuple[jnp.ndarray, jnp.ndarray]],
+    kv_caches: List[Tuple[jnp.ndarray, ...]],
     src_to_dsts: Dict[int, List[int]],
-) -> List[Tuple[jnp.ndarray, jnp.ndarray]]:
+) -> List[Tuple[jnp.ndarray, ...]]:
     """Copy-on-write block copies, applied to every layer's pool.
 
     Runs as one donated jit call so XLA updates the pools in place (an

@@ -7,6 +7,7 @@ load them without executing remote code. Falcon/MPT need no shim here —
 current transformers versions parse them natively.
 """
 from intellillm_tpu.transformers_utils.configs.aquila import AquilaConfig
+from intellillm_tpu.transformers_utils.configs.axk1 import AXK1Config
 from intellillm_tpu.transformers_utils.configs.baichuan import BaichuanConfig
 from intellillm_tpu.transformers_utils.configs.chatglm import ChatGLMConfig
 from intellillm_tpu.transformers_utils.configs.decilm import DeciLMConfig
@@ -17,6 +18,7 @@ from intellillm_tpu.transformers_utils.configs.yi import YiConfig
 
 _CONFIG_REGISTRY = {
     "aquila": AquilaConfig,
+    "axk1": AXK1Config,
     "baichuan": BaichuanConfig,
     "chatglm": ChatGLMConfig,
     "deci": DeciLMConfig,
@@ -28,7 +30,7 @@ _CONFIG_REGISTRY = {
 }
 
 __all__ = [
-    "AquilaConfig", "BaichuanConfig", "ChatGLMConfig", "DeciLMConfig",
+    "AquilaConfig", "AXK1Config", "BaichuanConfig", "ChatGLMConfig", "DeciLMConfig",
     "DeepseekConfig", "InternLMConfig", "QWenConfig", "YiConfig",
     "_CONFIG_REGISTRY",
 ]

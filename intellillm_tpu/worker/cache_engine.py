@@ -10,6 +10,13 @@ TPU redesign:
 - Pool layout [num_blocks, num_kv_heads, block_size, head_size] (bf16 tile
   aligned; the reference's x=16/elem_size key trick is a CUDA coalescing
   detail with no TPU analogue).
+- Two cache kinds. Per-head K/V: a layer's cache is the pair (K pool, V
+  pool). Latent attention (`ModelConfig.get_latent_cache_width()`): a
+  layer's cache is the 1-tuple (latent pool,), laid out
+  [num_blocks, 1, block_size, stored_width] — no head axis to shard and no
+  V. Every block op below maps over the tuple, so swap, copy and the KV
+  audit's swap checksums work on both; KV export/import speaks (K, V) on
+  the wire and refuses the latent kind with a message.
 - Swaps are jax device↔host transfers (no CUDA streams/events; JAX's async
   dispatch overlaps them with compute until the arrays are consumed).
 - Copies (CoW) are fused gather/scatter updates executed functionally; the
@@ -30,7 +37,8 @@ from intellillm_tpu.utils import STR_DTYPE_TO_JNP
 
 logger = init_logger(__name__)
 
-KVCache = Tuple[jnp.ndarray, jnp.ndarray]
+# (K pool, V pool), or (latent pool,).
+KVCache = Tuple[jnp.ndarray, ...]
 
 
 class CacheEngine:
@@ -46,11 +54,15 @@ class CacheEngine:
         self.model_config = model_config
         self.parallel_config = parallel_config
 
-        self.head_size = model_config.get_head_size()
         self.num_layers = model_config.get_num_layers()
-        # Full (unsharded) kv-head count: the pool is a logically global
-        # array sharded over the mesh "model" axis by the head dim.
-        self.num_kv_heads = model_config.get_total_num_kv_heads()
+        # What a token stores in a layer (`get_cache_row_shape`): 2 pools
+        # (K, V) of kv heads x head size, or the 1 latent pool of one
+        # "head" of the stored latent width. The head count is the full
+        # (unsharded) one: the pool is a logically global array sharded
+        # over the mesh "model" axis by the head dim.
+        self.num_pools, self.num_kv_heads, self.head_size = \
+            model_config.get_cache_row_shape()
+        self.latent = model_config.get_latent_cache_width() is not None
 
         self.block_size = cache_config.block_size
         self.num_device_blocks = cache_config.num_device_blocks
@@ -63,7 +75,7 @@ class CacheEngine:
 
         self.sharding = sharding
         self.device_cache: List[KVCache] = self._allocate_device_cache()
-        self.cpu_cache: List[Tuple[np.ndarray, np.ndarray]] = \
+        self.cpu_cache: List[Tuple[np.ndarray, ...]] = \
             self._allocate_cpu_cache()
 
         # Byte sizes for the obs swap accounting: swaps move host↔device
@@ -94,12 +106,13 @@ class CacheEngine:
         shape = self._block_shape(self.num_device_blocks)
         caches = []
         for _ in range(self.num_layers):
-            k = jnp.zeros(shape, dtype=self.dtype)
-            v = jnp.zeros(shape, dtype=self.dtype)
-            if self.sharding is not None:
-                k = jax.device_put(k, self.sharding)
-                v = jax.device_put(v, self.sharding)
-            caches.append((k, v))
+            pools = []
+            for _ in range(self.num_pools):
+                pool = jnp.zeros(shape, dtype=self.dtype)
+                if self.sharding is not None:
+                    pool = jax.device_put(pool, self.sharding)
+                pools.append(pool)
+            caches.append(tuple(pools))
         return caches
 
     def _allocate_cpu_cache(self):
@@ -111,8 +124,8 @@ class CacheEngine:
             # ml_dtypes so swap in/out is lossless.
             import ml_dtypes
             np_dtype = np.dtype(getattr(ml_dtypes, self.dtype.name))
-        return [(np.zeros(shape, dtype=np_dtype),
-                 np.zeros(shape, dtype=np_dtype))
+        return [tuple(np.zeros(shape, dtype=np_dtype)
+                      for _ in range(self.num_pools))
                 for _ in range(self.num_layers)]
 
     # --- block-op execution ---------------------------------------------
@@ -120,8 +133,6 @@ class CacheEngine:
     def swap_in(self, src_to_dst: Dict[int, int]) -> None:
         audit = self._kv_audit
         for i in range(self.num_layers):
-            k_dev, v_dev = self.device_cache[i]
-            k_cpu, v_cpu = self.cpu_cache[i]
             if audit.enabled:
                 # Verify sampled host blocks BEFORE they re-enter the
                 # device pool: a bit that flipped while the block sat
@@ -131,20 +142,19 @@ class CacheEngine:
                 for src in src_to_dst:
                     if audit.should_audit(i, int(src)):
                         audit.verify("swap_in", i, int(src),
-                                     k_cpu[int(src)], v_cpu[int(src)])
-            k_dev = swap_blocks(k_cpu, k_dev, src_to_dst, direction="in")
-            v_dev = swap_blocks(v_cpu, v_dev, src_to_dst, direction="in")
-            self.device_cache[i] = (k_dev, v_dev)
+                                     *(cpu[int(src)]
+                                       for cpu in self.cpu_cache[i]))
+            self.device_cache[i] = tuple(
+                swap_blocks(cpu, dev, src_to_dst, direction="in")
+                for cpu, dev in zip(self.cpu_cache[i], self.device_cache[i]))
         self._telemetry.record_swap("in", len(src_to_dst),
                                     self.logical_block_bytes)
 
     def swap_out(self, src_to_dst: Dict[int, int]) -> None:
         audit = self._kv_audit
         for i in range(self.num_layers):
-            k_dev, v_dev = self.device_cache[i]
-            k_cpu, v_cpu = self.cpu_cache[i]
-            swap_blocks(k_dev, k_cpu, src_to_dst, direction="out")
-            swap_blocks(v_dev, v_cpu, src_to_dst, direction="out")
+            for dev, cpu in zip(self.device_cache[i], self.cpu_cache[i]):
+                swap_blocks(dev, cpu, src_to_dst, direction="out")
             if audit.enabled:
                 # swap_blocks(direction="out") is synchronous host
                 # numpy, so the freshly written blocks are safe to hash
@@ -153,7 +163,8 @@ class CacheEngine:
                 for dst in src_to_dst.values():
                     if audit.should_audit(i, int(dst)):
                         audit.record("swap_out", i, int(dst),
-                                     k_cpu[int(dst)], v_cpu[int(dst)])
+                                     *(cpu[int(dst)]
+                                       for cpu in self.cpu_cache[i]))
         self._telemetry.record_swap("out", len(src_to_dst),
                                     self.logical_block_bytes)
 
@@ -169,6 +180,7 @@ class CacheEngine:
         of the fixed swap pool, so exports never contend with scheduler
         swap plans for CPU block numbers.
         """
+        self._refuse_latent("export")
         src_to_dst = {int(b): j for j, b in enumerate(block_numbers)}
         shape = self._block_shape(len(block_numbers))
         np_dtype = self.cpu_cache[0][0].dtype if self.cpu_cache else \
@@ -196,6 +208,7 @@ class CacheEngine:
                       block_numbers: List[int]) -> None:
         """Scatter a KV handoff payload into device blocks (inverse of
         export_blocks; staging slot j → device block j's target)."""
+        self._refuse_latent("import")
         if len(layers) != self.num_layers:
             raise ValueError(f"payload has {len(layers)} layers, cache has "
                              f"{self.num_layers}")
@@ -214,6 +227,14 @@ class CacheEngine:
         self._telemetry.record_swap("in", len(block_numbers),
                                     self.logical_block_bytes)
 
+    def _refuse_latent(self, what: str) -> None:
+        if self.latent:
+            raise NotImplementedError(
+                f"KV {what} (worker/kv_transfer.py) carries per-layer K and "
+                f"V slabs; this model caches one latent pool a layer, which "
+                f"the IKV1 wire format cannot describe. Disaggregated "
+                f"prefill/decode is not supported for it yet.")
+
     def copy(self, src_to_dsts: Dict[int, List[int]]) -> None:
         self.device_cache = copy_blocks(self.device_cache, src_to_dsts)
         self._telemetry.record_swap(
@@ -229,7 +250,8 @@ class CacheEngine:
         model_config: ModelConfig,
         parallel_config: ParallelConfig,
     ) -> int:
-        """PHYSICAL bytes per block across all layers (K + V), whole model.
+        """PHYSICAL bytes per block across all layers (K + V, or the one
+        latent pool), whole model.
 
         TPU HBM arrays are tiled: the pool layout [NB, H, BS, D] pads the
         minor dim to the 128-lane width. For D=128 models physical ==
@@ -241,8 +263,8 @@ class CacheEngine:
         bytes — sizing the pool by logical bytes made the memory profile
         allocate past HBM and OOM at engine init.
         """
-        head_size = model_config.get_head_size()
-        num_kv_heads = model_config.get_total_num_kv_heads()
+        num_pools, num_kv_heads, head_size = \
+            model_config.get_cache_row_shape()
         num_layers = model_config.get_num_layers()
         if cache_dtype == "auto":
             cache_dtype = model_config.dtype
@@ -256,8 +278,8 @@ class CacheEngine:
             # OOMs at init (e.g. head_size 256 with block_size 8).
             sublane = {4: 8, 2: 16, 1: 32}.get(itemsize, 8)
             eff_block_size = -(-block_size // sublane) * sublane
-        return (2 * num_layers * num_kv_heads * eff_block_size * lanes *
-                itemsize)
+        return (num_pools * num_layers * num_kv_heads * eff_block_size *
+                lanes * itemsize)
 
     @staticmethod
     def get_logical_cache_block_size(
@@ -265,13 +287,15 @@ class CacheEngine:
         cache_dtype: str,
         model_config: ModelConfig,
     ) -> int:
-        """Unpadded bytes per block across all layers (K + V) — sizes the
-        host (numpy) swap pool, which has no TPU tiling."""
-        head_size = model_config.get_head_size()
-        num_kv_heads = model_config.get_total_num_kv_heads()
+        """Unpadded bytes per block across all layers (K + V, or the
+        latent pool at its stored width: the host pool holds the same
+        zero-padded rows) — sizes the host (numpy) swap pool, which has no
+        TPU tiling."""
+        num_pools, num_kv_heads, head_size = \
+            model_config.get_cache_row_shape()
         num_layers = model_config.get_num_layers()
         if cache_dtype == "auto":
             cache_dtype = model_config.dtype
         itemsize = jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]).itemsize
-        return (2 * num_layers * num_kv_heads * block_size * head_size *
-                itemsize)
+        return (num_pools * num_layers * num_kv_heads * block_size *
+                head_size * itemsize)

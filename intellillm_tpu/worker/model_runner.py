@@ -144,6 +144,11 @@ class InflightStep:
                 self.packed)
         sampled, sampled_lp, topk_ids, topk_lp = r._unpack(
             packed, self.t1, self.t2, self.logprob_k)
+        choices = getattr(r.model, "moe_choices_per_token", 0)
+        if choices:
+            # The two columns `_pack` appended for an expert-parallel share.
+            r._efficiency.record_moe_assignments(
+                int(packed[:, -2].sum()), int(packed[:, -1].sum()) * choices)
         if self.proc:
             proc_rows, fetched, row_params, row_tokens, row_seeds = self.proc
             r._resample_processor_rows(
@@ -333,13 +338,20 @@ class ModelRunner:
     # --- packing helpers --------------------------------------------------
 
     @staticmethod
-    def _pack(sampled, sampled_lp, topk_ids, topk_lp):
+    def _pack(sampled, sampled_lp, topk_ids, topk_lp, moe_held=None,
+              live=None, substeps=1):
         """[B,T1] i32, [B,T1] f32, [B,T2,Kt] i32, [B,T2,Kt] f32 →
         single [B, 2*T1 + 2*T2*Kt] int32 for a 1-fetch D2H.
 
         Packed as INT (floats bitcast to their bit patterns): small ints
         bitcast to f32 are denormals, which TPU ops flush to zero — the
         reverse direction is safe.
+
+        An expert-parallel share (`moe_held` [B]: a row's expert choices
+        held on this chip, summed over layers and substeps) appends two
+        columns after everything `_unpack` reads: the held choices of
+        live rows and the substeps of live rows (0 for pad rows), so
+        intellillm_moe_assignments_total rides this same fetch.
         """
         b = sampled.shape[0]
         parts = [
@@ -348,6 +360,9 @@ class ModelRunner:
             topk_ids.reshape(b, -1),
             jax.lax.bitcast_convert_type(topk_lp, jnp.int32).reshape(b, -1),
         ]
+        if moe_held is not None:
+            parts += [jnp.where(live, moe_held, 0)[:, None],
+                      jnp.where(live, substeps, 0).astype(jnp.int32)[:, None]]
         return jnp.concatenate(parts, axis=-1)
 
     @staticmethod
@@ -366,10 +381,14 @@ class ModelRunner:
         """Models outside the llama family don't take a `lora` kwarg; only
         pass it when a batch actually uses adapters."""
         if lora is None:
-            return self.model(params, token_ids, positions, kv_caches,
-                              attn_metadata)
-        return self.model(params, token_ids, positions, kv_caches,
-                          attn_metadata, lora=lora)
+            out = self.model(params, token_ids, positions, kv_caches,
+                             attn_metadata)
+        else:
+            out = self.model(params, token_ids, positions, kv_caches,
+                             attn_metadata, lora=lora)
+        # An expert-parallel share also returns, per token, how many of
+        # its expert choices this chip holds ([B, L] i32); None otherwise.
+        return out if len(out) == 3 else out + (None, )
 
     # --- jitted step functions -------------------------------------------
 
@@ -581,13 +600,14 @@ class ModelRunner:
                     stage_index=k,
                     mesh=self.mesh,
                 )
-                caches4 = [(kp, vp, sk, sv)
-                           for (kp, vp), (sk, sv) in zip(cur_caches, stages)]
-                hidden, caches4 = self._call_model(params,
-                                                   cur_tokens[:, None],
-                                                   pos_k[:, None], caches4,
-                                                   meta, lora)
-                stages = [(c[2], c[3]) for c in caches4]
+                # A layer's cache is (K, V) or the 1-tuple (latent pool,);
+                # staged, the layer gets its pools then their stages.
+                caches4 = [tuple(pools) + tuple(stage)
+                           for pools, stage in zip(cur_caches, stages)]
+                hidden, caches4, held = self._call_model(
+                    params, cur_tokens[:, None], pos_k[:, None], caches4,
+                    meta, lora)
+                stages = [tuple(c[len(c) // 2:]) for c in caches4]
                 g = (chunk_base + k).astype(jnp.uint32)
                 seeds_k = seeds + g * _SEED_STRIDE
                 (sampled, lp, tk_ids,
@@ -599,8 +619,10 @@ class ModelRunner:
                     do_minp=do_minp, do_penalties=do_penalties,
                     do_random=do_random)
                 next_tokens = sampled[:, 0]
-                return ((next_tokens, stages),
-                        (next_tokens, lp[:, 0], tk_ids, tk_lp))
+                ys = (next_tokens, lp[:, 0], tk_ids, tk_lp)
+                if held is not None:
+                    ys += (held[:, 0], )
+                return (next_tokens, stages), ys
             return substep
 
         cur_caches = kv_caches
@@ -615,9 +637,8 @@ class ModelRunner:
                 base_ctx > 0,
                 jnp.minimum(base_ctx - 1 + chunk_base, self.max_model_len),
                 0)
-            stages = [(jnp.zeros((b, csize, hkv, d), cache_dtype),
-                       jnp.zeros((b, csize, hkv, d), cache_dtype))
-                      for _ in range(len(cur_caches))]
+            stages = [tuple(jnp.zeros((b, csize, hkv, d), cache_dtype)
+                            for _ in pools) for pools in cur_caches]
             (cur_tokens, stages), ys = jax.lax.scan(
                 make_substep(pool_ctx, cur_caches, chunk_base),
                 (cur_tokens, stages),
@@ -634,21 +655,23 @@ class ModelRunner:
                 base_ctx > 0,
                 jnp.clip(self.max_model_len - start, 0, csize), 0)
             cur_caches = [
-                commit_staged_chunk(sk, sv, kp, vp, start, n_valid,
+                commit_staged_chunk(stage, pools, start, n_valid,
                                     block_tables)
-                for (kp, vp), (sk, sv) in zip(cur_caches, stages)]
+                for pools, stage in zip(cur_caches, stages)]
             chunk_base += csize
 
         new_caches = cur_caches
         # [K, B, ...] per ys leaf, chunks concatenated along the step axis.
         ys = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0),
                           *ys_chunks) if len(ys_chunks) > 1 else ys_chunks[0]
-        sampled_k, lp_k, tk_ids_k, tk_lp_k = ys
+        sampled_k, lp_k, tk_ids_k, tk_lp_k = ys[:4]
         # [K, B, ...] → [B, K, ...]
         packed = self._pack(jnp.swapaxes(sampled_k, 0, 1),
                             jnp.swapaxes(lp_k, 0, 1),
                             jnp.swapaxes(tk_ids_k, 0, 1),
-                            jnp.swapaxes(tk_lp_k, 0, 1))
+                            jnp.swapaxes(tk_lp_k, 0, 1),
+                            moe_held=(ys[4].sum(0) if len(ys) > 4 else None),
+                            live=base_ctx > 0, substeps=num_steps)
         return packed, new_caches
 
     def _decode_fn_single(self, params, kv_caches, token_ids, positions,
@@ -690,9 +713,8 @@ class ModelRunner:
             block_tables=block_tables,
             mesh=self.mesh,
         )
-        hidden, new_caches = self._call_model(params, token_ids,
-                                              pos[:, None], kv_caches, meta,
-                                              lora)
+        hidden, new_caches, held = self._call_model(
+            params, token_ids, pos[:, None], kv_caches, meta, lora)
         (sampled, lp, tk_ids, tk_lp, fetched, plp_out,
          num_stats) = self._compute_logits_and_sample(
             params, hidden[:, 0], temperatures, top_ks, top_ps, min_ps,
@@ -704,7 +726,9 @@ class ModelRunner:
             plp_k=plp_k, do_numerics=do_numerics,
             numerics_inject=numerics_inject)
         packed = self._pack(sampled, lp, tk_ids[:, None, :],
-                            tk_lp[:, None, :])
+                            tk_lp[:, None, :],
+                            moe_held=None if held is None else held[:, 0],
+                            live=context_lens > 0)
         extras = ()
         if plp_out is not None:
             extras += (plp_out, )

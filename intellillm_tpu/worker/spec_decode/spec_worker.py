@@ -160,7 +160,7 @@ class SpecDecodeWorker(Worker):
 
         draft_mc = self.spec_config.draft_model_config
         kv_sharding = shard_kv_cache(self.mesh,
-                                     draft_mc.get_total_num_kv_heads())
+                                     draft_mc.get_cache_row_shape()[1])
         # Same block count/size as the target pool: the scheduler's block
         # tables index BOTH pools.
         self.draft_cache_engine = CacheEngine(cache_config, draft_mc,
@@ -306,7 +306,7 @@ class SpecDecodeWorker(Worker):
                                               draft_mc,
                                               self.parallel_config)
         tp = self.parallel_config.tensor_parallel_size
-        nkv = draft_mc.get_total_num_kv_heads()
+        nkv = draft_mc.get_cache_row_shape()[1]
         return bb // tp if tp > 1 and nkv % tp == 0 else bb
 
     # --- step ------------------------------------------------------------

@@ -124,7 +124,7 @@ class Worker:
 
         # KV pool shards by kv-head over the "model" axis when divisible.
         tp = self.parallel_config.tensor_parallel_size
-        nkv = self.model_config.get_total_num_kv_heads()
+        pools, nkv, row_width = self.model_config.get_cache_row_shape()
         block_bytes_per_chip = (block_bytes // tp
                                 if tp > 1 and nkv % tp == 0 else block_bytes)
         block_bytes_per_chip += self._extra_block_bytes(block_size,
@@ -144,10 +144,9 @@ class Worker:
         from intellillm_tpu.utils import STR_DTYPE_TO_JNP as _M
         stage_dtype = (self.model_config.dtype
                        if cache_dtype == "auto" else cache_dtype)
-        stage_bytes = (2 * self.model_config.get_num_layers() *
+        stage_bytes = (pools * self.model_config.get_num_layers() *
                        self.scheduler_config.max_num_seqs * k_steps *
-                       self.model_config.get_total_num_kv_heads() *
-                       self.model_config.get_head_size() *
+                       nkv * row_width *
                        _jnp.dtype(_M[stage_dtype]).itemsize)
         temp_bytes += stage_bytes + int(0.10 * weights_bytes)
         available = int(total * hbm_utilization) - weights_bytes - temp_bytes
@@ -180,8 +179,7 @@ class Worker:
         w = runner.max_table_width
 
         from intellillm_tpu.utils import STR_DTYPE_TO_JNP
-        nkv = self.model_config.get_total_num_kv_heads()
-        hs = self.model_config.get_head_size()
+        pools, nkv, hs = self.model_config.get_cache_row_shape()
         nl = self.model_config.get_num_layers()
         cache_dtype = (self.model_config.dtype
                        if self.cache_config.cache_dtype == "auto" else
@@ -190,7 +188,7 @@ class Worker:
         cache_shape = jax.ShapeDtypeStruct(
             (dummy_blocks, nkv, self.cache_config.block_size, hs),
             jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]))
-        kv_struct = [(cache_shape, cache_shape) for _ in range(nl)]
+        kv_struct = [(cache_shape, ) * pools for _ in range(nl)]
 
         lowered = runner.lower_mixed_step(kv_struct, b, w, logprob_k=8)
         return int(lowered.compile().memory_analysis().temp_size_in_bytes)
@@ -200,7 +198,7 @@ class Worker:
     def init_cache_engine(self, cache_config: CacheConfig) -> None:
         self.cache_config = cache_config
         kv_sharding = shard_kv_cache(
-            self.mesh, self.model_config.get_total_num_kv_heads())
+            self.mesh, self.model_config.get_cache_row_shape()[1])
         self.cache_engine = CacheEngine(cache_config, self.model_config,
                                         self.parallel_config,
                                         sharding=kv_sharding)
@@ -221,7 +219,7 @@ class Worker:
             # Same per-chip division as the memory profile: the pool
             # shards by kv-head over "model" only when divisible.
             tp = self.parallel_config.tensor_parallel_size
-            nkv = self.model_config.get_total_num_kv_heads()
+            nkv = self.model_config.get_cache_row_shape()[1]
             if tp > 1 and nkv % tp == 0:
                 block_bytes //= tp
             ledger["kv_pool"] = block_bytes * cc.num_device_blocks

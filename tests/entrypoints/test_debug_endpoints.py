@@ -276,7 +276,10 @@ def test_profiler_capture_runs_against_a_fake_engine(monkeypatch, tmp_path):
             tracer.set_profiling(True)
             for _ in range(2):
                 with tracer.span("schedule"):
-                    time.sleep(0.002)
+                    # long against a 128x128 matmul even on a loaded
+                    # machine (2 ms lost to `fetch_wait` under six test
+                    # workers)
+                    time.sleep(0.02)
                 with tracer.span("execute"):
                     y = fn(x)
                 with tracer.span("fetch_wait"):

@@ -51,7 +51,7 @@ def test_commit_staged_chunk_matches_row_scatter(start_offsets, chunk,
     n_valid = jnp.asarray(np.asarray([chunk, chunk, chunk // 2, 0],
                                      np.int32))
 
-    got_k, got_v = commit_staged_chunk(k_stage, v_stage, k_pool, v_pool,
+    got_k, got_v = commit_staged_chunk((k_stage, v_stage), (k_pool, v_pool),
                                        start, n_valid, tables)
     ref_k, ref_v = _reference(k_stage, v_stage, k_pool, v_pool,
                               np.asarray(start), np.asarray(n_valid),
@@ -77,7 +77,7 @@ def test_commit_last_table_column_no_duplicate_write():
     start = jnp.asarray(np.asarray([48], np.int32))     # last column, o=0
     n_valid = jnp.asarray(np.asarray([c], np.int32))
 
-    got_k, _ = commit_staged_chunk(k_stage, v_stage, k_pool, v_pool,
+    got_k, _ = commit_staged_chunk((k_stage, v_stage), (k_pool, v_pool),
                                    start, n_valid, tables)
     got_k = np.asarray(got_k)
     for t in range(c):
@@ -101,7 +101,7 @@ def test_commit_page_straddle_two_pages():
     start = jnp.asarray(np.asarray([8, 24], np.int32))
     n_valid = jnp.asarray(np.asarray([c, c], np.int32))
 
-    got_k, _ = commit_staged_chunk(k_stage, v_stage, k_pool, v_pool,
+    got_k, _ = commit_staged_chunk((k_stage, v_stage), (k_pool, v_pool),
                                    start, n_valid, tables)
     got_k = np.asarray(got_k)
     tables_np = np.asarray(tables)

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from intellillm_tpu.layers.moe import (dense_chunking, moe_ffn,
+from intellillm_tpu.layers.moe import (Routing, dense_chunking, moe_ffn,
                                        moe_ffn_dense, moe_ffn_grouped)
 
 
@@ -62,10 +62,10 @@ def test_both_paths_match_per_token_reference(t, renormalize, n, top_k):
     x = jax.random.normal(jax.random.fold_in(key, 1), (t, d), jnp.float32)
     ref = _per_token_reference(x, gate_w, w1, w2, w3, top_k, renormalize)
 
-    dense = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k,
-                          renormalize=renormalize)
+    routing = Routing(renormalize=renormalize)
+    dense = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k, routing=routing)
     grouped = moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, block=16,
-                              renormalize=renormalize)
+                              routing=routing)
     assert dense.shape == grouped.shape == (t, d)
     np.testing.assert_allclose(np.asarray(dense), ref, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(np.asarray(grouped), ref, rtol=2e-4,

@@ -275,7 +275,7 @@ def test_deepseek_moe_routing_no_renorm():
     """Un-renormalized top-k routing vs a numpy loop (deepseek semantics
     differ from Mixtral exactly here)."""
     import jax.numpy as jnp
-    from intellillm_tpu.layers.moe import moe_ffn_dense
+    from intellillm_tpu.layers.moe import Routing, moe_ffn_dense
 
     rng = np.random.RandomState(0)
     t, d, i, n, k = 10, 8, 16, 4, 2
@@ -290,7 +290,7 @@ def test_deepseek_moe_routing_no_renorm():
                                    jnp.asarray(w1.swapaxes(1, 2)),
                                    jnp.asarray(w2),
                                    jnp.asarray(w3.swapaxes(1, 2)), k,
-                                   renormalize=False))
+                                   routing=Routing(renormalize=False)))
 
     def silu(v):
         return v / (1.0 + np.exp(-v))

@@ -146,8 +146,7 @@ def test_ledger_math_against_fake_param_tree_and_cache_sizing():
 
     model_config = SimpleNamespace(
         dtype="float32",
-        get_head_size=lambda: 16,
-        get_total_num_kv_heads=lambda: 4,
+        get_cache_row_shape=lambda: (2, 4, 16),
         get_num_layers=lambda: 2)
     w = Worker.__new__(Worker)
     w.params = params

@@ -59,6 +59,23 @@ def test_analytic_flops_per_token_ungated():
     assert analytic_flops_per_token(cfg) == pytest.approx(143872.0)
 
 
+def test_analytic_flops_per_token_latent_attention_by_its_mechanism():
+    """A config with `kv_lora_rank` + `qk_rope_head_dim` is reckoned from
+    its low-rank projections (absorbed form), not from kv heads x head
+    size, which say nothing about what such a layer multiplies."""
+    cfg = _FakeModelConfig(hidden=64, layers=1, heads=4, vocab=100,
+                           intermediate_size=128, hidden_act="silu",
+                           q_lora_rank=24, kv_lora_rank=32,
+                           qk_nope_head_dim=16, qk_rope_head_dim=8,
+                           v_head_dim=16)
+    attn = (64 * 24 + 24 * 4 * (16 + 8)      # x -> c_q -> q
+            + 64 * (32 + 8)                  # x -> [c_kv | k_rope]
+            + 4 * 32 * (16 + 16)             # q_nope W_UK, attended W_UV
+            + 4 * 16 * 64)                   # o
+    assert analytic_flops_per_token(cfg) == pytest.approx(
+        2.0 * (attn + 3 * 64 * 128 + 64 * 100))
+
+
 def test_analytic_flops_per_token_gated_mlp_counts_third_matrix():
     base = _FakeModelConfig(intermediate_size=128, hidden_act="gelu")
     gated = _FakeModelConfig(intermediate_size=128, hidden_act="silu")

@@ -238,11 +238,12 @@ def test_moe_dense_copies_no_expert_stack(compiled_for_chip, rows, loop):
     prefill step) its layout assignment wants w1/w3 with D minor-most and,
     handed [N, D, I], copied both 369 MB stacks in front of the loop
     (0.74 GB of temporaries a layer)."""
-    from intellillm_tpu.layers.moe import moe_ffn
+    from intellillm_tpu.layers.moe import Routing, moe_ffn
     top_k = DSMOE16B[3]
 
     def ffn(x, gate_w, w1, w2, w3):
-        return moe_ffn(x, gate_w, w1, w2, w3, top_k, renormalize=False)
+        return moe_ffn(x, gate_w, w1, w2, w3, top_k,
+                       routing=Routing(renormalize=False))
 
     def looped(x, *weights):
         return jax.lax.fori_loop(0, loop, lambda _, h: ffn(h, *weights), x)
@@ -265,3 +266,93 @@ def test_moe_grouped_copies_no_expert_stack(compiled_for_chip):
     compiled = compiled_for_chip(ffn, *_moe_specs(2048, MIXTRAL_8X7B))
     assert _stack_sized_copies(compiled.as_text(), MIXTRAL_8X7B) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 0.25e9
+
+
+# A.X-K1's latent attention, as the cell `axk1-ep16-decode-closed` runs it:
+# 64 heads over one stored vector of 640 lanes (512 + 64 + pad), the value
+# its first 512; a 9,000-block bf16 pool; max_model_len 4096 tables.
+MLA_HEADS, MLA_DK, MLA_DV, MLA_BLOCKS = 64, 640, 512, 9000
+MLA_SCALE = 192 ** -0.5 * 1.3466 ** 2
+
+
+def _latent_pool(dtype=jnp.bfloat16):
+    return ((MLA_BLOCKS, 1, BLOCK_SIZE, MLA_DK), dtype)
+
+
+def test_mla_paged_attention_compiles(compile_for_chip):
+    """The pool walk of decode_fused/decode_cont at 128 rows x 64-block
+    tables."""
+    from intellillm_tpu.ops.pallas.mla_attention import mla_paged_attention
+    b, w = 128, 64
+
+    def fn(q, pool, tables, ctx):
+        return mla_paged_attention(q, pool, tables, ctx, MLA_SCALE, MLA_DV)
+
+    text = compile_for_chip(
+        fn, ((b, MLA_HEADS, MLA_DK), jnp.bfloat16), _latent_pool(),
+        ((b, w), jnp.int32), ((b, ), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows,width", [(256, 32), (4096, 256)])
+def test_mla_ragged_paged_attention_compiles(compile_for_chip, rows, width):
+    """The mixed step's fused latent-cache write + walk. (4096, 256) is
+    the warm-up shape: more SMEM than one launch may take, so the wrapper
+    must have split it."""
+    from intellillm_tpu.ops.pallas.mla_attention import (
+        mla_ragged_paged_attention)
+
+    def fn(q, new, pool, slots, tables, ctx):
+        return mla_ragged_paged_attention(q, new, pool, slots, tables, ctx,
+                                          MLA_SCALE, MLA_DV)
+
+    text = compile_for_chip(
+        fn, ((rows, MLA_HEADS, MLA_DK), jnp.bfloat16),
+        ((rows, MLA_DK), jnp.bfloat16), _latent_pool(),
+        ((rows, ), jnp.int32), ((rows, width), jnp.int32),
+        ((rows, ), jnp.int32), donate=(2, ))
+    assert "tpu_custom_call" in text
+    # The pool is updated in place: no copy of it in the program.
+    pool_elems = MLA_BLOCKS * BLOCK_SIZE * MLA_DK
+    assert not [
+        line for line in text.splitlines()
+        if re.search(r"= (.*?) copy(-start)?\(", line) and any(
+            math.prod(map(int, dims.split(","))) == pool_elems
+            for dims in re.findall(r"\[([\d,]+)\]", line.split("=")[1]))]
+
+
+def test_mla_seams_select_the_kernels(compile_for_chip):
+    """`layers/mla_attention.py` through both seams with Pallas forced on
+    (what a TPU resolves to): each program holds a kernel."""
+    from intellillm_tpu.layers.attention import AttentionMetadata
+    from intellillm_tpu.layers.mla_attention import LatentAttention
+    from intellillm_tpu.ops import dispatch
+    attn = LatentAttention(MLA_HEADS, MLA_DV, 64, MLA_SCALE)
+    b, w, c = 128, 64, 8
+
+    def mixed(q, new, pool, slots, tables, ctx):
+        meta = AttentionMetadata(is_prompt=False, slot_mapping=slots,
+                                 context_lens=ctx, block_tables=tables)
+        return attn(q, new, (pool, ), meta)
+
+    def staged(q, new, pool, stage, tables, ctx, k):
+        meta = AttentionMetadata(is_prompt=False, context_lens=ctx,
+                                 block_tables=tables, staged=True,
+                                 stage_index=k)
+        return attn(q, new, (pool, stage), meta)
+
+    dispatch.set_use_pallas(True)
+    try:
+        q = ((b, 1, MLA_HEADS, 576), jnp.bfloat16)
+        new = ((b, 1, 576), jnp.bfloat16)
+        text = compile_for_chip(
+            mixed, q, new, _latent_pool(), ((b, 1), jnp.int32),
+            ((b, w), jnp.int32), ((b, ), jnp.int32), donate=(2, ))
+        assert "tpu_custom_call" in text
+        text = compile_for_chip(
+            staged, q, new, _latent_pool(),
+            ((b, c, 1, MLA_DK), jnp.bfloat16), ((b, w), jnp.int32),
+            ((b, ), jnp.int32), ((), jnp.int32))
+        assert "tpu_custom_call" in text
+    finally:
+        dispatch.set_use_pallas(None)
