@@ -1,0 +1,264 @@
+"""Per-call device time of the two paged kernels at a serving cell's shapes.
+
+Times `_paged_attention_call` (a 128-row decode step's pool walk) and
+`_ragged_call` (a 512-row prefill-chunk step) on contexts drawn from a
+traffic file of `benchmarks/chip/traffic/`, plus rows that fill their
+whole table (`--full-width`: 256 blocks = 4,096 tokens, 16 full page
+groups). Every kernel is first held to the jnp reference on the same
+inputs. `--root DIR` imports the package from another checkout (the
+parent's, unpacked beside this one), so one chip call times both:
+
+    python benchmarks/kernels/benchmark_paged_walk.py --heads 8x4 --out a.json
+    python benchmarks/kernels/benchmark_paged_walk.py --heads 8x4 \
+        --root chipscratch/parent --out b.json
+
+Device time is read from a profiler trace of the timed calls (the sum of
+the kernel's own events over the calls made); a host clock around the
+same calls is printed beside it. A CPU run is a rehearsal of control
+flow, never a timing (`--tiny`).
+"""
+from __future__ import annotations
+
+import argparse
+import functools
+import glob
+import json
+import os
+import sys
+import tempfile
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+CHECKOUT = os.path.join(HERE, "..", "..")
+
+
+def contexts(traffic: dict, rows: int, seed: int):
+    """Decode rows at a uniform point of their output, and prefill-chunk
+    rows (every position of whole prompts) with ~8 % pad rows."""
+    import numpy as np
+    sys.path.insert(0, os.path.join(CHECKOUT, "benchmarks", "chip"))
+    from harness.loadgen import length_pairs
+    rng = np.random.default_rng(seed)
+    pairs = length_pairs(traffic, traffic.get("block", 128))
+    drawn = (pairs[i] for i in rng.integers(0, len(pairs), rows))
+    decode = np.asarray([p + int(rng.random() * o) for p, o in drawn],
+                        np.int32)
+    live = rows - rows * 8 // 100
+    prefill, seq_of = [], []
+    for n, i in enumerate(rng.permutation(len(pairs))):
+        p = pairs[i][0]
+        prefill += list(range(1, p + 1))
+        seq_of += [n] * p
+    prefill = np.asarray(prefill[:live] + [0] * (rows - live), np.int32)
+    seq_of = np.asarray(seq_of[:live] + [-1] * (rows - live), np.int32)
+    return decode, prefill, seq_of
+
+
+def main(args) -> None:
+    sys.path.insert(0, os.path.abspath(args.root or CHECKOUT))
+    import jax
+    if jax.default_backend() == "tpu":
+        return measure(args)
+    from jax.experimental.pallas import tpu as pltpu
+    with pltpu.force_tpu_interpret_mode():   # a rehearsal, not a timing
+        measure(args)
+
+
+def measure(args) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from intellillm_tpu.ops.attention import decode_attention_reference
+    from intellillm_tpu.ops.pallas import paged_attention as pa
+    from intellillm_tpu.ops.pallas import ragged_paged_attention as rpa
+    from intellillm_tpu.ops.ragged_attention import (
+        ragged_fused_attention_reference)
+
+    hkv, g = (int(x) for x in args.heads.split("x"))
+    d, bs = 128, 16
+    dtype = jnp.dtype(args.pool_dtype)
+    with open(os.path.join(CHECKOUT, "benchmarks", "chip", "traffic",
+                           args.traffic + ".json")) as f:
+        traffic = json.load(f)
+    rows_d, rows_r, w, layers = 128, 512, args.width, args.layers
+    if args.tiny:
+        rows_d, rows_r, layers = 8, 16, 2
+    rng = np.random.default_rng(args.seed)
+    dec_ctx, pre_ctx, seq_of = contexts(traffic, max(rows_d, rows_r),
+                                        args.seed)
+    dec_ctx, pre_ctx, seq_of = (dec_ctx[:rows_d], pre_ctx[:rows_r],
+                                seq_of[:rows_r])
+    if args.full_width:
+        dec_ctx = np.full_like(dec_ctx, w * bs)
+    dec_ctx = np.minimum(dec_ctx, w * bs)
+    pre_ctx = np.minimum(pre_ctx, w * bs)
+
+    nb = max(args.blocks, rows_d * w + 1) if args.full_width else args.blocks
+    pool = lambda: jnp.asarray(
+        rng.standard_normal((nb, hkv, bs, d), np.float32) * 0.5
+    ).astype(dtype)
+    k_cache, v_cache = pool(), pool()
+
+    def tables_for(owner, n_rows):
+        """Rows of one sequence share a table; pages are distinct across
+        sequences; entries past a sequence's pages are 0."""
+        t = np.zeros((n_rows, w), np.int32)
+        perm = rng.permutation(nb - 1) + 1
+        at, by_seq = 0, {}
+        for r in range(n_rows):
+            key = owner[r]
+            if key < 0:
+                continue
+            if key not in by_seq:
+                by_seq[key] = perm[at:at + w]
+                at = at + w if at + 2 * w <= len(perm) else 0
+            t[r] = by_seq[key]
+        return t
+
+    result = {"root": args.root or ".", "heads": args.heads,
+              "pool_dtype": str(dtype), "width": w,
+              "full_width": bool(args.full_width),
+
+              "device": jax.devices()[0].device_kind,
+              "platform": jax.devices()[0].platform}
+    scale = d ** -0.5
+
+    # ---- decode ----------------------------------------------------------
+    q = jnp.asarray(rng.standard_normal((rows_d, 1, hkv * g, d),
+                                        np.float32)).astype(jnp.bfloat16)
+    tables = jnp.asarray(tables_for(np.arange(rows_d), rows_d))
+    ctx = jnp.asarray(dec_ctx)
+    out, lse = pa.paged_attention(q, k_cache, v_cache, tables, ctx, scale,
+                                  return_lse=True)
+    ref, ref_lse = decode_attention_reference(
+        q, k_cache, v_cache, tables, ctx, scale, return_lse=True)
+    result["decode_max_err"] = float(jnp.max(jnp.abs(
+        out.astype(jnp.float32) - ref.astype(jnp.float32))))
+    result["decode_lse_max_err"] = float(jnp.max(jnp.abs(lse - ref_lse)))
+    result["decode_ctx_mean"] = float(dec_ctx.mean())
+
+    @jax.jit
+    def decode_layers(q, k_cache, v_cache, tables, ctx):
+        def layer(_, q):
+            return pa.paged_attention(q, k_cache, v_cache, tables, ctx, scale)
+        return jax.lax.fori_loop(0, layers, layer, q)
+
+    # ---- ragged ----------------------------------------------------------
+    qr = jnp.asarray(rng.standard_normal((rows_r, 1, hkv * g, d),
+                                         np.float32)).astype(jnp.bfloat16)
+    k_new = jnp.asarray(rng.standard_normal((rows_r, hkv, d), np.float32)
+                        * 0.5).astype(dtype)
+    v_new = jnp.asarray(rng.standard_normal((rows_r, hkv, d), np.float32)
+                        * 0.5).astype(dtype)
+    tables_r = tables_for(seq_of, rows_r)
+    slots = np.where(
+        pre_ctx > 0,
+        tables_r[np.arange(rows_r), np.maximum(pre_ctx - 1, 0) // bs] * bs
+        + np.maximum(pre_ctx - 1, 0) % bs, -1).astype(np.int32)
+    tables_r, slots, ctx_r = (jnp.asarray(tables_r), jnp.asarray(slots),
+                              jnp.asarray(pre_ctx))
+    out_r, kc, vc = rpa.ragged_paged_attention(
+        qr, k_new, v_new, jnp.copy(k_cache), jnp.copy(v_cache), slots,
+        tables_r, ctx_r, scale)
+    ref_r, kc_ref, vc_ref = ragged_fused_attention_reference(
+        qr, k_new, v_new, k_cache, v_cache, slots, tables_r, ctx_r, scale)
+    result["ragged_max_err"] = float(jnp.max(jnp.abs(
+        out_r.astype(jnp.float32) - ref_r.astype(jnp.float32))))
+    result["ragged_pool_equal"] = bool(
+        jnp.array_equal(kc.astype(jnp.float32), kc_ref.astype(jnp.float32))
+        and jnp.array_equal(vc.astype(jnp.float32),
+                            vc_ref.astype(jnp.float32)))
+    result["ragged_ctx_mean_live"] = float(pre_ctx[pre_ctx > 0].mean())
+    del kc, vc, kc_ref, vc_ref
+
+    @functools.partial(jax.jit, donate_argnums=(3, 4))
+    def ragged_layers(qr, k_new, v_new, k_cache, v_cache, slots, tables, ctx):
+        def layer(_, carry):
+            q, kc, vc = carry
+            return rpa.ragged_paged_attention(q, k_new, v_new, kc, vc, slots,
+                                              tables, ctx, scale)
+        return jax.lax.fori_loop(0, layers, layer, (qr, k_cache, v_cache))
+
+    def run_decode():
+        return decode_layers(q, k_cache, v_cache, tables, ctx)
+
+    state = {"kc": jnp.copy(k_cache), "vc": jnp.copy(v_cache)}
+
+    def run_ragged():
+        out, state["kc"], state["vc"] = ragged_layers(
+            qr, k_new, v_new, state["kc"], state["vc"], slots, tables_r,
+            ctx_r)
+        return out
+
+    for name, fn, marker in (("decode", run_decode, "_paged_attention_call"),
+                             ("ragged", run_ragged, "_ragged_call")):
+        for _ in range(2):
+            jax.block_until_ready(fn())
+        t0 = time.perf_counter()
+        for _ in range(args.repeats):
+            o = fn()
+        jax.block_until_ready(o)
+        host = (time.perf_counter() - t0) / (args.repeats * layers)
+        result[f"{name}_host_us_per_call"] = host * 1e6
+        with tempfile.TemporaryDirectory() as tmp:
+            with jax.profiler.trace(tmp):
+                for _ in range(args.repeats):
+                    o = fn()
+                jax.block_until_ready(o)
+            dev = kernel_device_us(tmp, marker)
+        if dev is not None:
+            result[f"{name}_device_us_per_call"] = dev["us_per_call"]
+            result[f"{name}_device_calls"] = dev["calls"]
+    print(json.dumps(result))
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(result, f)
+
+
+def kernel_device_us(trace_dir: str, marker: str):
+    """Mean device duration of the events on the TPU's op line whose name
+    holds `marker`; None where the trace has no device plane (a CPU run)."""
+    from jax.profiler import ProfileData
+    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if not paths:
+        return None
+    total, calls = 0, 0
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if not plane.name.startswith("/device:TPU:"):
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for ev in line.events:
+                if marker in ev.name:
+                    total += ev.duration_ns
+                    calls += 1
+    if not calls:
+        return None
+    return {"us_per_call": total / calls / 1e3, "calls": calls}
+
+
+if __name__ == "__main__":
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--root", default="",
+                        help="checkout to import intellillm_tpu from")
+    parser.add_argument("--heads", default="8x4",
+                        help="KV heads x query heads a KV head (8x4 Mistral, "
+                             "16x1 DeepSeek-MoE)")
+    parser.add_argument("--pool-dtype", default="float8_e5m2")
+    parser.add_argument("--traffic", default="decode-closed")
+    parser.add_argument("--width", type=int, default=64,
+                        help="block-table width")
+    parser.add_argument("--blocks", type=int, default=6535)
+    parser.add_argument("--full-width", action="store_true",
+                        help="every decode row fills its table")
+    parser.add_argument("--layers", type=int, default=32,
+                        help="kernel calls chained in one program")
+    parser.add_argument("--repeats", type=int, default=5)
+    parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--tiny", action="store_true",
+                        help="CPU rehearsal sizes")
+    parser.add_argument("--out", default="")
+    main(parser.parse_args())

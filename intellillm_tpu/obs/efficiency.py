@@ -128,6 +128,12 @@ class _EfficiencyMetrics:
             "Context lengths the attention kernel walks, summed over the "
             "live rows and substeps of the dispatched programs.",
             ["program"])
+        self.counter_dispatch_walked_tokens = Counter(
+            "intellillm_dispatch_walked_tokens_total",
+            "Tokens of paged context the attention kernel's walk moves "
+            "for the same rows and substeps (whole pages, by the "
+            "kernel's own rule): over the context tokens, what the walk "
+            "fetches beyond what the rows hold.", ["program"])
         self.counter_moe_assignments = Counter(
             "intellillm_moe_assignments_total",
             "Token x expert choices of an expert-parallel share's router, "
@@ -147,6 +153,7 @@ class _EfficiencyMetrics:
                 self.counter_dispatch.labels(program, device)
             self.counter_dispatch_row_steps.labels(program)
             self.counter_dispatch_context_tokens.labels(program)
+            self.counter_dispatch_walked_tokens.labels(program)
 
     @classmethod
     def reset_for_testing(cls) -> None:
@@ -249,11 +256,13 @@ class EfficiencyTracker:
         self._tokens: Dict[str, Dict[str, int]] = {
             phase: {kind: 0 for kind in TOKEN_KINDS} for phase in PHASES}
         self._dispatches: Dict[str, int] = {phase: 0 for phase in PHASES}
-        # program -> device dispatches by device state, row-steps and
-        # context tokens (the counts a kernel's bytes are computed from).
+        # program -> device dispatches by device state, row-steps,
+        # context tokens and walked tokens (the counts a kernel's bytes
+        # are computed from).
         self._programs: Dict[str, Dict[str, int]] = {
             program: dict.fromkeys(
-                DEVICE_STATES + ("row_steps", "context_tokens"), 0)
+                DEVICE_STATES + ("row_steps", "context_tokens",
+                                 "walked_tokens"), 0)
             for program in PROGRAMS}
         # (phase, axis) -> [sum of fill ratios, observations]
         self._fill: Dict[Tuple[str, str], List[float]] = {}
@@ -361,7 +370,8 @@ class EfficiencyTracker:
                         program: Optional[str] = None,
                         device_idle: bool = False,
                         row_steps: int = 0,
-                        context_tokens: int = 0) -> None:
+                        context_tokens: int = 0,
+                        walked_tokens: int = 0) -> None:
         """Account one device dispatch. Extents are pre-padding vs
         post-padding; token counts are what the device actually
         computes (prefill: rows x padded len; decode: rows x substeps).
@@ -371,7 +381,9 @@ class EfficiencyTracker:
         program on one of the two calls only. `device_idle`: nothing
         dispatched earlier was still running. `row_steps` / `context_tokens`:
         live rows x substeps, and the context lengths attention walks
-        summed over them."""
+        summed over them. `walked_tokens`: what the kernel's page walk
+        moves for those same row-substeps (`walk_extent` of the kernel
+        module: whole pages)."""
         if not self.enabled:
             return
         real_tokens = int(real_tokens)
@@ -411,6 +423,7 @@ class EfficiencyTracker:
                 counts[device] += 1
                 counts["row_steps"] += int(row_steps)
                 counts["context_tokens"] += int(context_tokens)
+                counts["walked_tokens"] += int(walked_tokens)
         if self._metrics is not None:
             m = self._metrics
             if program is not None:
@@ -418,6 +431,8 @@ class EfficiencyTracker:
                 m.counter_dispatch_row_steps.labels(program).inc(row_steps)
                 m.counter_dispatch_context_tokens.labels(program).inc(
                     context_tokens)
+                m.counter_dispatch_walked_tokens.labels(program).inc(
+                    walked_tokens)
             m.counter_tokens.labels("real", phase).inc(real_tokens)
             m.counter_tokens.labels("pad", phase).inc(pad_tokens)
             for axis, ratio in fills:

@@ -42,6 +42,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -51,6 +52,18 @@ from intellillm_tpu.ops.pallas.ragged_paged_attention import (
     _SMEM_TABLE_BYTES)
 
 _NEG_INF = -1e30
+
+
+def walk_extent(ctx, block_size: int, w: int, xp=np):
+    """The latent walk's rule, for `_walk` (`xp=jnp`) and the host's
+    counter: whole groups of `_largest_divisor(w, 16)` pages whatever the
+    row holds, one at least. These are the fixed groups that
+    `paged_attention.walk_extent` replaced in the per-head kernels; at
+    1,280 B a token they cost this model little (PERF.md section 7).
+    Returns (pages moved, tokens multiplied)."""
+    group = _largest_divisor(w, 16) * block_size
+    groups = xp.maximum((ctx + (group - 1)) // group, 1)
+    return groups * (group // block_size), groups * group
 
 
 def _group_copies(pool_ref, buf_ref, sem, tables_ref, b, g, buf, *,
@@ -72,7 +85,8 @@ def _walk(pool_ref, tables_ref, buf_idx_ref, init_ref, q_ref, kbuf, ksem,
     """Online softmax of row b's H heads over pool positions `< limit`,
     into the scratch accumulators. Returns the query block as loaded."""
     bk = pages_per_group * block_size
-    num_groups = jnp.maximum(lax.div(limit + bk - 1, bk), 1)
+    num_groups = lax.div(walk_extent(limit, block_size, w_max, xp=jnp)[0],
+                         pages_per_group)
 
     copies = functools.partial(_group_copies, pool_ref, kbuf, ksem,
                                tables_ref, pages_per_group=pages_per_group,

@@ -42,9 +42,33 @@ twin was deleted, see the selection history below):
 - Besides the attended output, the kernel emits the per-head logsumexp so
   fused multi-step decode can merge pool-part and stage-part attention.
 
+- The walk follows the row, not the table (`walk_extent`, the one rule
+  both kernels and the host's counter share): a row moves the
+  ceil(ctx / block_size) pages it holds and multiplies them up to the
+  next sub-group of 4 pages. Until PR 27 every group moved and
+  multiplied all 16 pages whatever the context (a pad row one whole
+  group): at the serving cells' 16-976-token contexts under 64-block
+  tables that was 1.37x the tokens a decode row attends to and 2.4x a
+  prefill-chunk row's. What the chip said while this was built (v5e,
+  128 rows x 8 KV heads x 4, fp8; `benchmarks/kernels/
+  benchmark_paged_walk.py`): the kernel is bound by its instruction
+  stream, not by bytes (copies alone run at 715 GB/s and hide behind the
+  dots); a conditional region costs ~27 ns taken or not, about what
+  three page copies cost to issue, a loop iteration ~5 ns; and an
+  update of any size pays the QK -> softmax -> PV chain's latency once.
+  So: a row's full groups run as static pieces (one wait a pool for
+  the whole group's bytes), its tail's copies go out in a loop (a
+  conditional a page, or a run of pages for each bit of the count, both
+  measured slower than moving all 16), and the tail is multiplied as
+  ONE piece sized by a tree of two-way conditionals (a chain of
+  sub-group updates measured 30-70 % slower than the fixed group). A
+  page that is not moved leaves in VMEM what was there, so V starts out
+  zeroed: 0 x NaN is NaN.
+
 The ragged mixed-batch sibling (ops/pallas/ragged_paged_attention.py)
-reuses this module's `_group_copies` DMA walk and adds the fused
-cache-write + in-flight-token handling the flat mixed dispatch needs.
+reuses this module's `_walk` (the DMA walk and the online softmax) and
+adds the fused cache-write + in-flight-token handling the flat mixed
+dispatch needs.
 
 Numerics: f32 accumulation regardless of cache dtype.
 """
@@ -55,6 +79,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -62,26 +87,11 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _group_copies(k_hbm_ref, v_hbm_ref, k_buf, v_buf, k_sem, v_sem,
-                  tables_ref, b, hb, g, buf, *, heads_per_block,
-                  pages_per_group, w_max):
-    """The async copies moving page-group g of sequence b / kv-head block
-    hb into VMEM buffer `buf`. Identical descriptor lists are built at
-    start and wait time (a DMA is identified by its (src, dst, sem))."""
-    copies = []
-    h0 = hb * heads_per_block
-    for j in range(pages_per_group):
-        idx = jnp.minimum(g * pages_per_group + j, w_max - 1)
-        page = tables_ref[b * w_max + idx]
-        # Chained single-axis dynamic slices: Mosaic supports dynamic
-        # indexing one (leading) axis at a time.
-        copies.append(pltpu.make_async_copy(
-            k_hbm_ref.at[page].at[pl.ds(h0, heads_per_block)],
-            k_buf.at[buf, j], k_sem.at[buf]))
-        copies.append(pltpu.make_async_copy(
-            v_hbm_ref.at[page].at[pl.ds(h0, heads_per_block)],
-            v_buf.at[buf, j], v_sem.at[buf]))
-    return copies
+# Pages of a sub-group: a row's tail is multiplied up to the last
+# sub-group that holds one of its pages.
+_SUB_PAGES = 4
+# A column of another head's, as a token position no context reaches.
+_FAR = 1 << 30
 
 
 def _largest_divisor(n: int, cap: int) -> int:
@@ -89,6 +99,253 @@ def _largest_divisor(n: int, cap: int) -> int:
         if n % p == 0:
             return p
     return 1
+
+
+def _pages_per_group(w: int, page_bytes: int) -> int:
+    """Pages of one DMA group, a divisor of the table's width: 16 at
+    most, 32 where a page (one head block's slab) is 16 KiB or less.
+    Every piece of the walk pays a fixed price (the QK -> softmax -> PV
+    chain's latency, its conditionals), so a short row is best one
+    piece; the cap is what the f32 copies of a group leave of VMEM."""
+    return _largest_divisor(w, 32 if page_bytes <= 16 * 1024 else 16)
+
+
+def _sub_pages(pages_per_group: int) -> int:
+    if pages_per_group % _SUB_PAGES:
+        return pages_per_group
+    return _SUB_PAGES
+
+
+def walk_extent(ctx, block_size: int, w: int, sub_pages: int = 1, xp=np):
+    """THE rule of the page walk, shared by both paged kernels (`xp=jnp`,
+    on SMEM scalars) and by the host's counter (numpy, vectorised): a row
+    whose walk must see `ctx` tokens (its context for decode, its context
+    less its own token for the ragged kernel) moves the first
+    ceil(ctx / block_size) pages of its `w`-wide table, none for a pad
+    row, and multiplies those pages rounded up to a whole sub-group.
+    Returns (pages moved, tokens multiplied)."""
+    pages = xp.clip((ctx + (block_size - 1)) // block_size, 0, w)
+    subs = (pages + (sub_pages - 1)) // sub_pages
+    return pages, subs * (sub_pages * block_size)
+
+
+def _switch(index, lo: int, hi: int, branch) -> None:
+    """Run `branch(i)` for the i in lo..hi that `index` equals, through a
+    tree of two-way conditionals (each costs about as much as issuing a
+    page's copies, so as few as decide it)."""
+    if lo == hi:
+        branch(lo)
+        return
+    mid = (lo + hi) // 2
+    lax.cond(index <= mid, lambda: _switch(index, lo, mid, branch),
+             lambda: _switch(index, mid + 1, hi, branch))
+
+
+def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
+          slopes_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf, k_sem, v_sem,
+          m_scr, l_scr, acc_scr, code_scr, *, held_back: int,
+          batch_size: int, num_head_blocks: int, heads_per_block: int,
+          num_groups_g: int, pages_per_group: int, block_size: int,
+          scale: float, w_max: int):
+    """Online softmax of this grid step's (row, kv-head block) over the
+    row's paged context less its last `held_back` tokens, into the scratch
+    accumulators. Returns the scaled query block [HP*G, D] in f32.
+
+    The context streams through the VMEM double buffer in groups of
+    `pages_per_group` pages: each group's copies are issued while the
+    group before it is multiplied, and a step's last group issues the
+    first group of the NEXT grid step, so the DMA pipeline never drains.
+    `walk_extent` says how far the walk goes. The row's full groups come
+    first, each moved and multiplied as one static piece; then its tail,
+    the pages of its last group that it holds, moved page by page and
+    multiplied in one piece up to the last sub-group that holds one. A
+    row with nothing to walk (a pad row) moves and multiplies nothing.
+    Copies are started and waited for by the same rule on the same row's
+    context, so what is waited for is what was started."""
+    b = pl.program_id(0)
+    hb = pl.program_id(1)
+    hp, g_sz, ppg, bs = (heads_per_block, num_groups_g, pages_per_group,
+                         block_size)
+    sub = _sub_pages(ppg)
+    pools = ((k_hbm_ref, k_buf, k_sem), (v_hbm_ref, v_buf, v_sem))
+
+    def walk_pages(row):
+        return walk_extent(context_lens_ref[row] - held_back, bs, w_max,
+                           xp=jnp)[0]
+
+    limit = context_lens_ref[b] - held_back
+    pages = walk_pages(b)
+    full_groups = lax.div(pages, ppg)
+    tail = pages - full_groups * ppg
+
+    def start_page(row, hb_, g, j, buf):
+        page = tables_ref[row * w_max + g * ppg + j]
+        # Chained single-axis dynamic slices: Mosaic supports dynamic
+        # indexing one (leading) axis at a time.
+        for hbm, vmem, sem in pools:
+            pltpu.make_async_copy(
+                hbm.at[page].at[pl.ds(hb_ * hp, hp)], vmem.at[buf, j],
+                sem.at[buf]).start()
+
+    def start_full(row, hb_, g, buf):
+        for j in range(ppg):
+            start_page(row, hb_, g, j, buf)
+
+    def start_tail(row, hb_, g, n, buf):
+        def one(j, carry):
+            start_page(row, hb_, g, j, buf)
+            return carry
+        lax.fori_loop(0, n, one, 0)
+
+    # A DMA semaphore counts bytes, so a wait names only how many: the
+    # buffer itself stands in for the source.
+    def wait_full(buf):
+        for _, vmem, sem in pools:
+            pltpu.make_async_copy(vmem.at[buf], vmem.at[buf],
+                                  sem.at[buf]).wait()
+
+    def wait_tail(n, buf):
+        def one(j, carry):
+            for _, vmem, sem in pools:
+                pltpu.make_async_copy(vmem.at[buf, j], vmem.at[buf, j],
+                                      sem.at[buf]).wait()
+            return carry
+        lax.fori_loop(0, n, one, 0)
+
+    def start_first_group(row, hb_, buf):
+        n = jnp.minimum(walk_pages(row), ppg)
+        lax.cond(n == ppg, lambda: start_full(row, hb_, 0, buf),
+                 lambda: start_tail(row, hb_, 0, n, buf))
+
+    # Very first grid step starts its own group 0; afterwards every step's
+    # group 0 was prefetched by its predecessor. It also lays out, once,
+    # what every step reads back: the position code of the flat score
+    # layout, and a V buffer that is finite. A page the walk does not move
+    # leaves in VMEM what was there: the mask zeroes its probabilities,
+    # but 0 x NaN in the PV dot is NaN, so V starts out finite and from
+    # then on holds only pages that some row owns. (K needs nothing: a
+    # masked score is selected away, never multiplied.)
+    @pl.when(init_ref[0] == 1)
+    def _first():
+        # The KV buffer flattens page-major: flat column c =
+        # (page*HP + head)*BS + tok, so head(c) = (c // BS) % HP and the
+        # token's index within the group is page(c)*BS + tok(c). Columns
+        # of other heads than the row's are junk: coded out of reach.
+        rows_i = lax.broadcasted_iota(jnp.int32, code_scr.shape, 0)
+        cols_i = lax.broadcasted_iota(jnp.int32, code_scr.shape, 1)
+        col_head = lax.rem(lax.div(cols_i, bs), hp)
+        col_tok = lax.div(cols_i, hp * bs) * bs + lax.rem(cols_i, bs)
+        code_scr[...] = jnp.where(lax.div(rows_i, g_sz) == col_head,
+                                  col_tok, _FAR)
+        zero = jnp.zeros(v_buf.shape[2:], jnp.float32).astype(v_buf.dtype)
+        for i in range(v_buf.shape[0]):
+            for j in range(ppg):
+                v_buf[i, j] = zero
+        start_first_group(b, hb, 0)
+    init_ref[0] = 0
+    start_buf = buf_idx_ref[0]
+
+    # Successor grid point (head-block fastest, then batch).
+    wrap = hb + 1 == num_head_blocks
+    nhb = jnp.where(wrap, 0, hb + 1)
+    nb = jnp.where(wrap, b + 1, b)
+
+    def start_successor(buf):
+        # By the SUCCESSOR's context. In the ragged kernel this is issued
+        # before the successor's own cache write: safe only because its
+        # own token is held back from its walk.
+        @pl.when(nb < batch_size)
+        def _():
+            start_first_group(nb, nhb, buf)
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    q_flat = (q_ref[0].astype(jnp.float32) *
+              scale).reshape(hp * g_sz, -1)              # [HP*G, D]
+    slope = slopes_ref[:, :, 0].reshape(hp * g_sz, 1)
+    query_pos_f = (context_lens_ref[b] - 1).astype(jnp.float32)
+
+    def update(g, buf, npg: int):
+        """Fold the first `npg` pages of group g, held in buffer `buf`,
+        into the accumulators."""
+        ncols = npg * hp * bs
+        # Token position of each FLAT column within the full sequence
+        # (out of reach for a column of another head's).
+        token_pos = g * (ppg * bs) + code_scr[:, :ncols]  # [HP*G, NC]
+        mask = token_pos < limit
+
+        # ONE flat dot for all HP heads: [HP*G, D] x [NPG*HP*BS, D]^T. The
+        # cross-head scores are junk (masked out); the extra FLOPs are
+        # ~2 MXU tiles — far cheaper than HP separate small dots or a
+        # (Mosaic-hostile) batched dot.
+        d = k_buf.shape[-1]
+        k = k_buf[buf, :npg].reshape(ncols, d).astype(jnp.float32)
+        v = v_buf[buf, :npg].reshape(ncols, d).astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q_flat, k, (((1, ), (1, )), ((), ())),
+            preferred_element_type=jnp.float32)          # [HP*G, NC]
+        # ALiBi: score += slope * (key_pos - query_pos).
+        s = s + slope * (token_pos.astype(jnp.float32) - query_pos_f)
+
+        m_prev = m_scr[:, 0][:, None]                    # [HP*G, 1]
+        m_cur = jnp.max(jnp.where(mask, s, _NEG_INF), axis=1,
+                        keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        # Mask AFTER the exp: with nothing valid m_new == s == -inf-ish
+        # and exp(0) would otherwise contribute 1s; the mask also zeroes
+        # the cross-head columns so pv below stays block-diagonal.
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)     # [HP*G, NC]
+
+        l_new = l_scr[:, 0][:, None] * alpha + jnp.sum(p, axis=1,
+                                                       keepdims=True)
+        pv = jax.lax.dot_general(
+            p, v, (((1, ), (0, )), ((), ())),
+            preferred_element_type=jnp.float32)          # [HP*G, D]
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        m_scr[...] = jnp.broadcast_to(m_new, (hp * g_sz, 128))
+        l_scr[...] = jnp.broadcast_to(l_new, (hp * g_sz, 128))
+
+    def full_group(g, carry):
+        buf = lax.rem(start_buf + g, 2)
+        nxt = 1 - buf
+
+        @pl.when(g + 1 < full_groups)
+        def _next_full_group():
+            start_full(b, hb, g + 1, nxt)
+
+        @pl.when(g + 1 == full_groups)
+        def _after_the_full_groups():
+            lax.cond(tail > 0,
+                     lambda: start_tail(b, hb, full_groups, tail, nxt),
+                     lambda: start_successor(nxt))
+
+        wait_full(buf)
+        update(g, buf, ppg)
+        return carry
+
+    lax.fori_loop(0, full_groups, full_group, 0)
+    tail_buf = lax.rem(start_buf + full_groups, 2)
+
+    def tail_group():
+        start_successor(1 - tail_buf)
+        wait_tail(tail, tail_buf)
+        # One piece whatever its size: a chain of smaller updates would
+        # pay the QK -> softmax -> PV latency once a link.
+        _switch(lax.div(tail + sub - 1, sub), 1, ppg // sub,
+                lambda t: update(full_groups, tail_buf, t * sub))
+
+    def no_tail():
+        @pl.when(full_groups == 0)      # nothing held: nothing in flight
+        def _():
+            start_successor(tail_buf)
+
+    lax.cond(tail > 0, tail_group, no_tail)
+    buf_idx_ref[0] = lax.rem(start_buf + full_groups
+                             + (tail > 0).astype(jnp.int32), 2)
+    return q_flat
 
 
 def _decode_kernel(
@@ -113,120 +370,17 @@ def _decode_kernel(
     m_scr,              # [HP * G, 128] f32 running max
     l_scr,              # [HP * G, 128] f32 running denominator
     acc_scr,            # [HP * G, D] f32 running numerator
+    code_scr,           # [HP * G, P * HP * BS] i32 position code
     *,
-    batch_size: int,
-    num_head_blocks: int,
     heads_per_block: int,
     num_groups_g: int,
-    pages_per_group: int,
-    block_size: int,
-    scale: float,
-    w_max: int,
+    **walk_params,
 ):
-    b = pl.program_id(0)
-    hb = pl.program_id(1)
-    ctx = context_lens_ref[b]
-    bk = pages_per_group * block_size
-    num_groups = jnp.maximum(lax.div(ctx + bk - 1, bk), 1)
     hp, g_sz = heads_per_block, num_groups_g
-
-    def copies(b_, hb_, g_, buf_):
-        return _group_copies(k_hbm_ref, v_hbm_ref, k_buf, v_buf, k_sem,
-                             v_sem, tables_ref, b_, hb_, g_, buf_,
-                             heads_per_block=hp,
-                             pages_per_group=pages_per_group, w_max=w_max)
-
-    # Very first grid step starts its own group 0; afterwards every step's
-    # group 0 was prefetched by its predecessor.
-    @pl.when(init_ref[0] == 1)
-    def _first():
-        for c in copies(b, hb, 0, 0):
-            c.start()
-    init_ref[0] = 0
-    start_buf = buf_idx_ref[0]
-
-    # Successor grid point (head-block fastest, then batch).
-    wrap = hb + 1 == num_head_blocks
-    nhb = jnp.where(wrap, 0, hb + 1)
-    nb = jnp.where(wrap, b + 1, b)
-    has_next = nb < batch_size
-
-    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q_flat = (q_ref[0].astype(jnp.float32) *
-              scale).reshape(hp * g_sz, -1)              # [HP*G, D]
-    # Static masks for the flat [HP*G, P*HP*BS] score layout. The KV
-    # buffer flattens page-major: flat column c = (page*HP + head)*BS +
-    # tok, so head(c) = (c // BS) % HP and the in-sequence token index is
-    # page(c)*BS + tok(c).
-    ncols = pages_per_group * hp * block_size
-    rows_i = jax.lax.broadcasted_iota(jnp.int32, (hp * g_sz, ncols), 0)
-    cols_i = jax.lax.broadcasted_iota(jnp.int32, (hp * g_sz, ncols), 1)
-    col_head = lax.rem(lax.div(cols_i, block_size), hp)
-    block_mask = lax.div(rows_i, g_sz) == col_head
-    col_tok = (lax.div(cols_i, hp * block_size) * block_size +
-               lax.rem(cols_i, block_size))              # [HP*G, NC]
-
-    def body(g, carry):
-        buf = lax.rem(start_buf + g, 2)
-        nxt = lax.rem(buf + 1, 2)
-
-        @pl.when(g + 1 < num_groups)
-        def _prefetch_own():
-            for c in copies(b, hb, g + 1, nxt):
-                c.start()
-
-        @pl.when((g + 1 == num_groups) & has_next)
-        def _prefetch_successor():
-            for c in copies(nb, nhb, 0, nxt):
-                c.start()
-
-        for c in copies(b, hb, g, buf):
-            c.wait()
-
-        # Token position of each FLAT column within the full sequence.
-        token_pos = g * bk + col_tok                     # [HP*G, NC]
-        mask = block_mask & (token_pos < ctx)
-        pos_f = token_pos.astype(jnp.float32)
-        ctx_f = (ctx - 1).astype(jnp.float32)
-
-        # ONE flat dot for all HP heads: [HP*G, D] x [P*HP*BS, D]^T. The
-        # cross-head scores are junk (masked by block_mask below); the
-        # extra FLOPs are ~2 MXU tiles — far cheaper than HP separate
-        # small dots or a (Mosaic-hostile) batched dot.
-        k = k_buf[buf].reshape(-1, k_buf.shape[-1]).astype(jnp.float32)
-        v = v_buf[buf].reshape(-1, v_buf.shape[-1]).astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q_flat, k, (((1, ), (1, )), ((), ())),
-            preferred_element_type=jnp.float32)          # [HP*G, HP*PBS]
-        # ALiBi: score += slope * (key_pos - query_pos).
-        slope = slopes_ref[:, :, 0].reshape(hp * g_sz, 1)
-        s = s + slope * (pos_f - ctx_f)
-
-        m_prev = m_scr[:, 0][:, None]                    # [HP*G, 1]
-        m_cur = jnp.max(jnp.where(mask, s, _NEG_INF), axis=1,
-                        keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        # Mask AFTER the exp: with a fully-invalid group m_new == s ==
-        # -inf-ish and exp(0) would otherwise contribute 1s; the mask also
-        # zeroes the cross-head columns so pv below stays block-diagonal.
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)     # [HP*G, HP*PBS]
-
-        l_new = l_scr[:, 0][:, None] * alpha + jnp.sum(p, axis=1,
-                                                       keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1, ), (0, )), ((), ())),
-            preferred_element_type=jnp.float32)          # [HP*G, D]
-        acc_scr[...] = acc_scr[...] * alpha + pv
-        m_scr[...] = jnp.broadcast_to(m_new, (hp * g_sz, 128))
-        l_scr[...] = jnp.broadcast_to(l_new, (hp * g_sz, 128))
-        return carry
-
-    lax.fori_loop(0, num_groups, body, 0, unroll=False)
-    buf_idx_ref[0] = lax.rem(start_buf + num_groups, 2)
+    _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
+          slopes_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf, k_sem, v_sem,
+          m_scr, l_scr, acc_scr, code_scr, held_back=0, heads_per_block=hp,
+          num_groups_g=g_sz, **walk_params)
 
     l = l_scr[:, 0][:, None]                             # [HP*G, 1]
     m = m_scr[:, 0][:, None]
@@ -258,8 +412,8 @@ def _paged_attention_call(q_grouped, slopes, k_cache, v_cache, block_tables,
     b, hkv, g, d = q_grouped.shape
     nb, _, bs, _ = k_cache.shape
     w = block_tables.shape[1]
-    ppg = _largest_divisor(w, 16)
     hp = _largest_divisor(hkv, _default_hp(k_cache))
+    ppg = _pages_per_group(w, hp * bs * d * k_cache.dtype.itemsize)
 
     # <8 sublanes in the q block: hint a f32 <1x128> layout (a bf16 <8x128>
     # memref would be mis-tiled for tiny G).
@@ -286,6 +440,7 @@ def _paged_attention_call(q_grouped, slopes, k_cache, v_cache, block_tables,
             pltpu.VMEM((hp * g, 128), jnp.float32),
             pltpu.VMEM((hp * g, 128), jnp.float32),
             pltpu.VMEM((hp * g, d), jnp.float32),
+            pltpu.VMEM((hp * g, ppg * hp * bs), jnp.int32),
         ],
     )
 

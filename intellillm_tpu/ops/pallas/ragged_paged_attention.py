@@ -14,8 +14,8 @@ block) handles both. Per grid step the kernel
    `[HP, BS, D]` slab (see `_page_slab_copies`), and
 2. walks the row's paged prior context with the same double-buffered
    multi-page DMA groups and flat-dot online softmax as
-   `ops/pallas/paged_attention.py` (whose `_group_copies` walk it
-   reuses).
+   `ops/pallas/paged_attention.py` (whose `_walk` it calls: the pages
+   moved and multiplied follow the row's context, `walk_extent`).
 
 Write-before-read ordering across rows relies on the sequential grid
 (`dimension_semantics=("arbitrary", "arbitrary")`): chunk rows of the
@@ -26,12 +26,14 @@ i's K/V because row i's write-back completed inside row i's grid step
 The one hazard is the cross-step prefetch: the last page group of each
 step prefetches the NEXT step's group 0 — *before* that step's own
 cache write. The kernel therefore never reads a row's own token back
-from HBM: the HBM walk is masked to `pos < ctx - 1` and the self-token
-score/value come straight from the VMEM K/V input block, merged into
-the online-softmax accumulators after the walk. (The in-flight prefetch
-may still copy the raced bytes; they are masked out of the math. The
-slab write-back rewrites the page's other rows with the bytes they
-already hold, so a prefetch racing it reads the same values either way.)
+from HBM: the HBM walk is held to `pos < ctx - 1` (it moves the pages
+of that prior context only) and the self-token score/value come straight
+from the VMEM K/V input block, merged into the online-softmax
+accumulators after the walk. (The in-flight prefetch may still copy the
+raced bytes, where the prior context shares the token's page; they are
+masked out of the math. The slab write-back rewrites the page's other
+rows with the bytes they already hold, so a prefetch racing it reads
+the same values either way.)
 
 Numerics contract: callers pass `k_new`/`v_new` already cast to the
 cache dtype — the reference path reads the cache *after* the write, so
@@ -55,8 +57,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from intellillm_tpu.ops.pallas.paged_attention import (_default_hp,
-                                                       _group_copies,
-                                                       _largest_divisor)
+                                                       _largest_divisor,
+                                                       _pages_per_group,
+                                                       _walk)
 
 _NEG_INF = -1e30
 # SMEM the flattened block tables of one launch may take (of 1 MiB on
@@ -107,35 +110,38 @@ def _ragged_kernel(
     vw_buf,
     kw_sem,             # write-DMA semaphores (scalar)
     vw_sem,
+    slab_ref,           # [2] i32 SMEM: the (page, head block) kw/vw_buf hold
     m_scr,              # [HP * G, 128] f32
     l_scr,
     acc_scr,            # [HP * G, D] f32
+    code_scr,           # [HP * G, P * HP * BS] i32 position code
     *,
-    batch_size: int,
-    num_head_blocks: int,
     heads_per_block: int,
     num_groups_g: int,
-    pages_per_group: int,
     block_size: int,
-    scale: float,
-    w_max: int,
+    **walk_params,
 ):
     del k_out_ref, v_out_ref  # in-place aliases of the HBM inputs
     b = pl.program_id(0)
     hb = pl.program_id(1)
     ctx = context_lens_ref[b]
     slot = slots_ref[b]
-    bk = pages_per_group * block_size
     hp, g_sz = heads_per_block, num_groups_g
-    # The HBM walk covers the prior context only (pos < ctx - 1); the
-    # row's own token is merged from VMEM after the walk.
-    num_groups = jnp.maximum(lax.div((ctx - 1) + bk - 1, bk), 1)
 
     # 1. Write this row's K/V before anything downstream can read it:
     #    fetch the slot's [HP, BS, D] page slab, replace row `off` in
     #    VMEM, write the slab back. Serial within the (sequential) grid
     #    step, so the next row of the same sequence reads this row's
-    #    token. Pad rows (slot < 0) skip it entirely.
+    #    token. The fetch is skipped where the buffers already hold that
+    #    slab as the pool has it, because the last write went to it too
+    #    (a prefill chunk's rows fill one page 16 times over): one DMA
+    #    round trip a row instead of two. Pad rows (slot < 0) skip the
+    #    write entirely.
+    @pl.when(init_ref[0] == 1)       # first grid step: they hold nothing
+    def _no_slab_yet():
+        slab_ref[0] = -1
+        slab_ref[1] = -1
+
     @pl.when(slot >= 0)
     def _write():
         page = lax.div(slot, block_size)
@@ -143,10 +149,15 @@ def _ragged_kernel(
         slab = functools.partial(
             _page_slab_copies, k_hbm_ref, v_hbm_ref, kw_buf, vw_buf,
             kw_sem, vw_sem, page, hb * hp, heads_per_block=hp)
-        for c in slab(to_hbm=False):
-            c.start()
-        for c in slab(to_hbm=False):
-            c.wait()
+
+        @pl.when((slab_ref[0] != page) | (slab_ref[1] != hb))
+        def _fetch():
+            for c in slab(to_hbm=False):
+                c.start()
+            for c in slab(to_hbm=False):
+                c.wait()
+        slab_ref[0] = page
+        slab_ref[1] = hb
         is_row = lax.broadcasted_iota(jnp.int32, kw_buf.shape, 1) == off
         for new_ref, buf in ((k_new_ref, kw_buf), (v_new_ref, vw_buf)):
             # Select in f32: exact for bf16 and fp8 values, and avoids
@@ -160,91 +171,16 @@ def _ragged_kernel(
         for c in slab(to_hbm=True):
             c.wait()
 
-    def copies(b_, hb_, g_, buf_):
-        return _group_copies(k_hbm_ref, v_hbm_ref, k_buf, v_buf, k_sem,
-                             v_sem, tables_ref, b_, hb_, g_, buf_,
-                             heads_per_block=hp,
-                             pages_per_group=pages_per_group, w_max=w_max)
+    # 2. Walk the prior context only (the row's last token is held back
+    #    and merged from VMEM below).
+    q_flat = _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref,
+                   q_ref, slopes_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf,
+                   k_sem, v_sem, m_scr, l_scr, acc_scr, code_scr,
+                   held_back=1,
+                   heads_per_block=hp, num_groups_g=g_sz,
+                   block_size=block_size, **walk_params)
 
-    @pl.when(init_ref[0] == 1)
-    def _first():
-        for c in copies(b, hb, 0, 0):
-            c.start()
-    init_ref[0] = 0
-    start_buf = buf_idx_ref[0]
-
-    wrap = hb + 1 == num_head_blocks
-    nhb = jnp.where(wrap, 0, hb + 1)
-    nb = jnp.where(wrap, b + 1, b)
-    has_next = nb < batch_size
-
-    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q_flat = (q_ref[0].astype(jnp.float32) *
-              scale).reshape(hp * g_sz, -1)              # [HP*G, D]
-    ncols = pages_per_group * hp * block_size
-    rows_i = jax.lax.broadcasted_iota(jnp.int32, (hp * g_sz, ncols), 0)
-    cols_i = jax.lax.broadcasted_iota(jnp.int32, (hp * g_sz, ncols), 1)
-    col_head = lax.rem(lax.div(cols_i, block_size), hp)
-    block_mask = lax.div(rows_i, g_sz) == col_head
-    col_tok = (lax.div(cols_i, hp * block_size) * block_size +
-               lax.rem(cols_i, block_size))
-
-    def body(g, carry):
-        buf = lax.rem(start_buf + g, 2)
-        nxt = lax.rem(buf + 1, 2)
-
-        @pl.when(g + 1 < num_groups)
-        def _prefetch_own():
-            for c in copies(b, hb, g + 1, nxt):
-                c.start()
-
-        @pl.when((g + 1 == num_groups) & has_next)
-        def _prefetch_successor():
-            # Issued before the successor's own cache write — safe only
-            # because the successor's self-token is masked from its walk.
-            for c in copies(nb, nhb, 0, nxt):
-                c.start()
-
-        for c in copies(b, hb, g, buf):
-            c.wait()
-
-        token_pos = g * bk + col_tok                     # [HP*G, NC]
-        mask = block_mask & (token_pos < ctx - 1)
-        pos_f = token_pos.astype(jnp.float32)
-        ctx_f = (ctx - 1).astype(jnp.float32)
-
-        k = k_buf[buf].reshape(-1, k_buf.shape[-1]).astype(jnp.float32)
-        v = v_buf[buf].reshape(-1, v_buf.shape[-1]).astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q_flat, k, (((1, ), (1, )), ((), ())),
-            preferred_element_type=jnp.float32)          # [HP*G, HP*PBS]
-        slope = slopes_ref[:, :, 0].reshape(hp * g_sz, 1)
-        s = s + slope * (pos_f - ctx_f)
-
-        m_prev = m_scr[:, 0][:, None]
-        m_cur = jnp.max(jnp.where(mask, s, _NEG_INF), axis=1,
-                        keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-
-        l_new = l_scr[:, 0][:, None] * alpha + jnp.sum(p, axis=1,
-                                                       keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1, ), (0, )), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha + pv
-        m_scr[...] = jnp.broadcast_to(m_new, (hp * g_sz, 128))
-        l_scr[...] = jnp.broadcast_to(l_new, (hp * g_sz, 128))
-        return carry
-
-    lax.fori_loop(0, num_groups, body, 0, unroll=False)
-    buf_idx_ref[0] = lax.rem(start_buf + num_groups, 2)
-
-    # 2. Merge the self token (pos = ctx - 1) from the VMEM input block.
+    # 3. Merge the self token (pos = ctx - 1) from the VMEM input block.
     #    k_new/v_new are already in the cache dtype, so the f32 upcast
     #    here matches a reference read of the just-written cache line.
     #    ALiBi bias is slope * (pos - query_pos) = 0 for the self token.
@@ -276,8 +212,8 @@ def _ragged_call(q_grouped, slopes, k_new, v_new, k_cache, v_cache,
     b, hkv, g, d = q_grouped.shape
     nb, _, bs, _ = k_cache.shape
     w = block_tables.shape[1]
-    ppg = _largest_divisor(w, 16)
     hp = _largest_divisor(hkv, _default_hp(k_cache))
+    ppg = _pages_per_group(w, hp * bs * d * k_cache.dtype.itemsize)
     q_kernel_dtype = q_grouped.dtype if g % 8 == 0 else jnp.float32
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -305,9 +241,11 @@ def _ragged_call(q_grouped, slopes, k_new, v_new, k_cache, v_cache,
             pltpu.VMEM((hp, bs, d), v_cache.dtype),
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
+            pltpu.SMEM((2, ), jnp.int32),
             pltpu.VMEM((hp * g, 128), jnp.float32),
             pltpu.VMEM((hp * g, 128), jnp.float32),
             pltpu.VMEM((hp * g, d), jnp.float32),
+            pltpu.VMEM((hp * g, ppg * hp * bs), jnp.int32),
         ],
     )
 

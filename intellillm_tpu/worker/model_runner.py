@@ -212,6 +212,13 @@ class ModelRunner:
 
         self.block_size = cache_config.block_size
         self.sliding_window = model_config.get_sliding_window()
+        # The page walk's rule of the attention kernels this model runs
+        # (what the walked-tokens counter reports).
+        if model_config.get_latent_cache_width():
+            from intellillm_tpu.ops.pallas.mla_attention import walk_extent
+        else:
+            from intellillm_tpu.ops.pallas.paged_attention import walk_extent
+        self._walk_extent = walk_extent
         from intellillm_tpu.layers.attention import model_uses_alibi
         self._uses_alibi = model_uses_alibi(model)
         self.vocab_size = model_config.get_vocab_size()
@@ -740,6 +747,30 @@ class ModelRunner:
 
     # --- batch prep -------------------------------------------------------
 
+    def _walked_tokens(self, ctxs, w: int, held_back: int = 0) -> int:
+        """Tokens the attention kernel's page walk moves for rows of
+        contexts `ctxs` (a sliding window's worth of them) under `w`-wide
+        tables, the last `held_back` tokens of each left out of it."""
+        ctx = np.asarray(ctxs, np.int64)
+        if self.sliding_window:
+            ctx = np.minimum(ctx, self.sliding_window)
+        pages, _ = self._walk_extent(ctx - held_back, self.block_size, w)
+        return int(pages.sum()) * self.block_size
+
+    def _decode_walked_tokens(self, ctx: np.ndarray, num_steps: int,
+                              w: int) -> int:
+        """The same over a K-step decode program (`_decode_fn`): every
+        substep of a staging chunk walks the pool as the chunk found it,
+        the context less the row's newest token plus the chunks
+        committed before."""
+        chunk = self.decode_chunk if self.decode_chunk > 0 else num_steps
+        return sum(
+            min(chunk, num_steps - base) * self._walked_tokens(
+                np.where(ctx > 0,
+                         np.minimum(ctx - 1 + base, self.max_model_len), 0),
+                w)
+            for base in range(0, num_steps, chunk))
+
     def _prepare_decode(
         self,
         seq_group_metadata_list: List[SequenceGroupMetadata],
@@ -772,7 +803,7 @@ class ModelRunner:
                   "context_lens": context_lens, "block_tables": block_tables}
         arrays["_eff"] = {
             "real_rows": len(rows),
-            "context_tokens": sum(ctxs),
+            "ctxs": np.asarray(ctxs, np.int64),
             "width_real": max(len(t) for t in tables),
             "width_padded": w,
         }
@@ -1001,8 +1032,10 @@ class ModelRunner:
             width_padded=eff_info["width_padded"],
             program=program, device_idle=device_idle,
             row_steps=real_rows * num_steps,
-            context_tokens=(eff_info["context_tokens"] * num_steps
-                            + real_rows * num_steps * (num_steps - 1) // 2))
+            context_tokens=(int(eff_info["ctxs"].sum()) * num_steps
+                            + real_rows * num_steps * (num_steps - 1) // 2),
+            walked_tokens=self._decode_walked_tokens(
+                eff_info["ctxs"], num_steps, eff_info["width_padded"]))
 
         # ONE device→host transfer for everything, performed by
         # InflightStep.finalize() — immediately on the eager path, or
@@ -1227,8 +1260,11 @@ class ModelRunner:
         # (one program, every row one token) is counted once, with
         # whichever side reports first.
         pad_rows = padded_n - len(rows)
+        # The walk sees a row's context less the row's own token.
         dispatch = dict(program="mixed", device_idle=device_idle,
-                        row_steps=len(rows), context_tokens=sum(ctxs))
+                        row_steps=len(rows), context_tokens=sum(ctxs),
+                        walked_tokens=self._walked_tokens(ctxs, w,
+                                                          held_back=1))
         if n_chunk_groups:
             self._efficiency.record_dispatch(
                 "prefill", n_chunk_groups, n_chunk_groups,
@@ -1336,7 +1372,8 @@ class ModelRunner:
             program="decode_cont", device_idle=device_idle,
             row_steps=live_rows * num_steps,
             context_tokens=(int(ctx.sum()) * num_steps
-                            + live_rows * num_steps * (num_steps - 1) // 2))
+                            + live_rows * num_steps * (num_steps - 1) // 2),
+            walked_tokens=self._decode_walked_tokens(ctx, num_steps, w))
 
         step = InflightStep(self, packed, cont.metas, cont.rows, num_steps,
                             num_steps, st.logprob_k, False, num_steps)

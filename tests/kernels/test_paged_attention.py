@@ -169,3 +169,133 @@ def test_paged_v4_flag_is_inert_and_warns():
                                      d**-0.5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=5e-3, atol=5e-3)
+
+
+# --- the walk's edges ------------------------------------------------------
+
+WALK_BS, WALK_W = 16, 32            # two 16-page groups of 256 tokens
+WALK_EDGES = (0, 1, WALK_BS - 1, WALK_BS, WALK_BS + 1, 255, 256, 257,
+              WALK_BS * WALK_W)
+WALK_POOLS = {"bf16": jnp.bfloat16, "fp8_e5m2": jnp.float8_e5m2}
+
+
+def _edge_batch(rng, ctx, hkv, dtype, poison=None):
+    """Rows alternating every edge context with `ctx`, so that the
+    cross-step prefetch meets `ctx` as predecessor and as successor of
+    each. Every row owns distinct pages; with `poison`, every page that
+    is not among a row's first ceil(ctx / bs) table entries holds it,
+    and each table's tail points at such pages."""
+    bs, w, d = WALK_BS, WALK_W, 128
+    ctxs = np.asarray([c for edge in WALK_EDGES for c in (edge, ctx)],
+                      np.int32)
+    b = len(ctxs)
+    nb = b * w + 8
+    k = rng.normal(size=(nb, hkv, bs, d)).astype(np.float32)
+    v = rng.normal(size=(nb, hkv, bs, d)).astype(np.float32)
+    tables = rng.permutation(nb)[:b * w].reshape(b, w).astype(np.int32)
+    if poison is not None:
+        owned = np.zeros(nb, bool)
+        for row, c in enumerate(ctxs):
+            owned[tables[row, :-(-int(c) // bs)]] = True
+        k[~owned] = poison
+        v[~owned] = poison
+    return (jnp.asarray(k).astype(dtype), jnp.asarray(v).astype(dtype),
+            jnp.asarray(tables), jnp.asarray(ctxs))
+
+
+def _finite_pool(cache):
+    """What the reference may read: the same pool with the poison out."""
+    c = cache.astype(jnp.float32)
+    return jnp.where(jnp.isfinite(c), c, 0.0).astype(cache.dtype)
+
+
+def _assert_rows_match(out, lse, ref, ref_lse, ctxs):
+    """Live rows equal the oracle; a pad row attends to nothing: zeros,
+    and a logsumexp that vanishes in any merge."""
+    out, lse, live = np.asarray(out), np.asarray(lse), np.asarray(ctxs) > 0
+    assert np.isfinite(out).all()
+    tol = _oracle_tol(use_alibi=False)
+    np.testing.assert_allclose(out[live], np.asarray(ref)[live],
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(lse[live], np.asarray(ref_lse)[live],
+                               rtol=tol, atol=tol)
+    assert (out[~live] == 0).all() and (lse[~live] <= -1e29).all()
+
+
+@requires_tpu
+@pytest.mark.parametrize("hq,hkv", [(8, 8), (8, 2)])
+@pytest.mark.parametrize("pool", sorted(WALK_POOLS))
+@pytest.mark.parametrize("ctx", WALK_EDGES)
+def test_paged_attention_walk_edges(ctx, pool, hq, hkv):
+    """Output and logsumexp at the edges of the walk's rule: a pad row,
+    one token, a page less one / exactly / plus one, a group less one /
+    exactly / plus one, the whole table."""
+    rng = np.random.default_rng(11)
+    k_cache, v_cache, tables, ctxs = _edge_batch(rng, ctx, hkv,
+                                                 WALK_POOLS[pool])
+    q = jnp.asarray(rng.normal(size=(len(ctxs), 1, hq, 128)
+                               ).astype(np.float32))
+    out, lse = jax.block_until_ready(paged_attention(
+        q, k_cache, v_cache, tables, ctxs, 128**-0.5, return_lse=True))
+    ref, ref_lse = decode_attention_reference(
+        q, k_cache, v_cache, tables, ctxs, 128**-0.5, return_lse=True)
+    _assert_rows_match(out, lse, ref, ref_lse, ctxs)
+
+
+@requires_tpu
+@pytest.mark.parametrize("poison", [np.nan, np.inf], ids=["nan", "inf"])
+@pytest.mark.parametrize("pool", sorted(WALK_POOLS))
+def test_paged_attention_moves_no_page_a_row_does_not_hold(pool, poison):
+    """Every page outside a row's first ceil(ctx / bs) table entries is
+    NaN or Inf, the tables' tails point at them, and the output is
+    finite and right: the walk moved none of them (a moved page's V
+    reaches the PV dot, where 0 x NaN is NaN, whatever the mask says)."""
+    rng = np.random.default_rng(12)
+    k_cache, v_cache, tables, ctxs = _edge_batch(
+        rng, 40, 2, WALK_POOLS[pool], poison=poison)
+    q = jnp.asarray(rng.normal(size=(len(ctxs), 1, 8, 128)
+                               ).astype(np.float32))
+    out, lse = jax.block_until_ready(paged_attention(
+        q, k_cache, v_cache, tables, ctxs, 128**-0.5, return_lse=True))
+    ref, ref_lse = decode_attention_reference(
+        q, _finite_pool(k_cache), _finite_pool(v_cache), tables, ctxs,
+        128**-0.5, return_lse=True)
+    _assert_rows_match(out, lse, ref, ref_lse, ctxs)
+
+
+def test_walk_extent_against_a_count_over_the_traffic():
+    """The rule, against a count by pages, over every context the
+    benchmark's `decode-closed` traffic sends through the two kernels
+    (64-block tables): what the walk moves is within 5 % of what decode
+    rows attend to and within 10 % for prefill-chunk rows, where the
+    fixed 16-page groups it replaced moved 1.35x and 2.4x."""
+    import json
+    import os
+    import sys
+    chip = os.path.join(os.path.dirname(__file__), "..", "..", "benchmarks",
+                        "chip")
+    sys.path.insert(0, chip)
+    from harness.loadgen import length_pairs
+
+    from intellillm_tpu.ops.pallas.paged_attention import walk_extent
+    with open(os.path.join(chip, "traffic", "decode-closed.json")) as f:
+        traffic = json.load(f)
+    pairs = length_pairs(traffic, traffic["block"])
+    bs, w = 16, 64
+    # a decode row attends to p + j + 1 tokens, all but its newest in the
+    # pool; prefill row c of a prompt to c, all but its own in the pool
+    decode = np.asarray([p + j for p, o in pairs for j in range(o)])
+    prefill = np.asarray([c - 1 for p, _ in pairs for c in range(1, p + 1)])
+    for walk, bound, old_ratio in ((decode, 1.05, 1.3), (prefill, 1.10, 2.2)):
+        pages, multiplied = walk_extent(walk, bs, w, sub_pages=4)
+        counted = (np.arange(w)[None, :] * bs < walk[:, None]).sum(axis=1)
+        np.testing.assert_array_equal(pages, counted)
+        assert (multiplied >= pages * bs).all()
+        assert (multiplied < pages * bs + 4 * bs).all()
+        attended = (walk + 1).sum()
+        assert pages.sum() * bs / attended <= bound
+        fixed_groups = np.maximum(-(-walk // 256), 1) * 256
+        assert fixed_groups.sum() / attended >= old_ratio
+    # a pad row moves nothing; a context past the table is held to it
+    assert walk_extent(np.asarray([0, -1, 5000]), bs, w)[0].tolist() == [
+        0, 0, w]

@@ -224,39 +224,47 @@ def test_record_dispatch_clamps_and_attributes_buckets(tracker):
 def test_record_dispatch_counts_programs_device_state_and_context(tracker):
     """The dispatch boundary's three more facts: which program, whether
     the device had run dry, and the rows x substeps / context tokens the
-    attention kernel walks (the counts its bytes are computed from)."""
+    attention kernel walks (the counts its bytes are computed from),
+    with the tokens its page walk moves for them."""
     tracker.record_dispatch("decode", 3, 4, real_tokens=24, padded_tokens=32,
                             program="decode_fused", device_idle=True,
-                            row_steps=24, context_tokens=24 * 100 + 84)
+                            row_steps=24, context_tokens=24 * 100 + 84,
+                            walked_tokens=24 * 112)
     tracker.record_dispatch("decode", 3, 4, real_tokens=24, padded_tokens=32,
                             program="decode_cont", device_idle=False,
-                            row_steps=24, context_tokens=24 * 108 + 84)
+                            row_steps=24, context_tokens=24 * 108 + 84,
+                            walked_tokens=24 * 112)
     # a mixed step reports its two sides separately; the dispatch is
     # named, and so counted, on one of the calls only
     tracker.record_dispatch("prefill", 1, 1, real_tokens=8, padded_tokens=8,
                             program="mixed", device_idle=False,
-                            row_steps=10, context_tokens=300)
+                            row_steps=10, context_tokens=300,
+                            walked_tokens=320)
     tracker.record_dispatch("decode", 2, 8, real_tokens=2, padded_tokens=8)
     programs = tracker.snapshot()["programs"]
     assert programs["decode_fused"] == {"idle": 1, "busy": 0,
                                         "row_steps": 24,
-                                        "context_tokens": 2484}
+                                        "context_tokens": 2484,
+                                        "walked_tokens": 2688}
     assert programs["decode_cont"] == {"idle": 0, "busy": 1,
                                        "row_steps": 24,
-                                       "context_tokens": 2676}
+                                       "context_tokens": 2676,
+                                       "walked_tokens": 2688}
     assert programs["mixed"] == {"idle": 0, "busy": 1, "row_steps": 10,
-                                 "context_tokens": 300}
+                                 "context_tokens": 300,
+                                 "walked_tokens": 320}
     assert tracker.snapshot()["dispatches"] == {"prefill": 1, "decode": 3}
 
 
 def test_dispatch_program_counters_skip_warmup_and_exist_at_zero(tracker):
     from prometheus_client import REGISTRY
-    zero = {"idle": 0, "busy": 0, "row_steps": 0, "context_tokens": 0}
+    zero = {"idle": 0, "busy": 0, "row_steps": 0, "context_tokens": 0,
+            "walked_tokens": 0}
     with tracker.warmup():
         tracker.record_dispatch("decode", 1, 16, real_tokens=8,
                                 padded_tokens=128, program="decode_fused",
                                 device_idle=True, row_steps=8,
-                                context_tokens=64)
+                                context_tokens=64, walked_tokens=64)
     assert tracker.snapshot()["programs"] == {
         "mixed": zero, "decode_fused": zero, "decode_cont": zero}
     # Both `device` children of every program are exported from the first
@@ -267,6 +275,7 @@ def test_dispatch_program_counters_skip_warmup_and_exist_at_zero(tracker):
                 "intellillm_dispatch_total",
                 {"program": program, "device": device}) is not None
         for name in ("intellillm_dispatch_row_steps_total",
-                     "intellillm_dispatch_context_tokens_total"):
+                     "intellillm_dispatch_context_tokens_total",
+                     "intellillm_dispatch_walked_tokens_total"):
             assert REGISTRY.get_sample_value(
                 name, {"program": program}) is not None

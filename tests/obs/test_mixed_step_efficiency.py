@@ -95,6 +95,14 @@ def test_mixed_steps_attribute_tokens_exactly_once(tiny_opt_dir, trackers):
     expected_ctx = sum(n * (n + 1) // 2 for n in prompt_lens) + sum(
         n + j for n in prompt_lens for j in range(1, MAX_TOKENS))
     assert mixed["context_tokens"] == expected_ctx
+    # ... and what the kernel's page walk moves for those rows: whole
+    # pages of the context less the row's own token.
+    bs = engine.cache_config.block_size
+    pages = lambda ctx: -(-(ctx - 1) // bs)
+    assert mixed["walked_tokens"] == bs * (
+        sum(pages(c) for n in prompt_lens for c in range(1, n + 1))
+        + sum(pages(n + j) for n in prompt_lens
+              for j in range(1, MAX_TOKENS)))
     # the serial step() fetches each step before it dispatches the next
     assert mixed["busy"] == 0 or engine.pipeline_enabled
 
@@ -173,3 +181,12 @@ def test_fused_decode_programs_count_rows_steps_and_context(tiny_opt_dir,
     # so no decode program can have walked less than that
     first = sum(4 * (n + 1) + 6 for n in prompt_lens)
     assert sum(d["context_tokens"] for d in decode) >= first
+    # the page walk moves whole pages of what the pool holds when the
+    # K=4 program starts: the context a substep attends to, less its own
+    # and the staged tokens (at most 4), rounded up to a page
+    bs = engine.cache_config.block_size
+    for d in decode:
+        assert d["walked_tokens"] % bs == 0
+        assert (d["context_tokens"] - 4 * d["row_steps"]
+                <= d["walked_tokens"]
+                < d["context_tokens"] + bs * d["row_steps"])

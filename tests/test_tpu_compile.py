@@ -80,46 +80,62 @@ def compile_for_chip(compiled_for_chip):
         fn, *specs, donate=donate).as_text()
 
 
-def _pool(dtype):
-    return ((BLOCKS, HEADS, BLOCK_SIZE, HEAD_DIM), dtype)
+def _pool(dtype, hkv=HEADS):
+    return ((BLOCKS, hkv, BLOCK_SIZE, HEAD_DIM), dtype)
 
 
+# (rows, table width, query heads, KV heads): Llama-2-7B at bs=96, and
+# the decode programs of the benchmark's two per-head cells (128 rows x
+# 64-block tables: Mistral-7B's 8 KV heads x 4, DeepSeek-MoE's 16 x 1).
+DECODE_SHAPES = {"llama7b-96x32": (96, 32, HEADS, HEADS),
+                 "mistral7b-128x64": (128, 64, 32, 8),
+                 "dsmoe16b-128x64": (128, 64, 16, 16)}
+
+
+@pytest.mark.parametrize("shape", sorted(DECODE_SHAPES))
 @pytest.mark.parametrize("pool", sorted(POOL_DTYPES))
-def test_paged_attention_compiles(compile_for_chip, pool):
-    """The decode kernel of decode_fused/decode_cont at bs=96."""
+def test_paged_attention_compiles(compile_for_chip, pool, shape):
+    """The decode kernel of decode_fused/decode_cont."""
     from intellillm_tpu.ops.pallas.paged_attention import paged_attention
-    b, w, dtype = 96, 32, POOL_DTYPES[pool]
+    (b, w, hq, hkv), dtype = DECODE_SHAPES[shape], POOL_DTYPES[pool]
 
     def fn(q, k_cache, v_cache, tables, ctx):
         return paged_attention(q, k_cache, v_cache, tables, ctx,
                                HEAD_DIM ** -0.5, return_lse=True)
 
     text = compile_for_chip(
-        fn, ((b, 1, HEADS, HEAD_DIM), jnp.bfloat16), _pool(dtype),
-        _pool(dtype), ((b, w), jnp.int32), ((b, ), jnp.int32))
+        fn, ((b, 1, hq, HEAD_DIM), jnp.bfloat16), _pool(dtype, hkv),
+        _pool(dtype, hkv), ((b, w), jnp.int32), ((b, ), jnp.int32))
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("rows,width", [(256, 32), (2048, 256)])
+# (rows, table width, query heads, KV heads). (2048, 256) is the default
+# token budget with max_model_len 4096 tables; 512 x 64 is the cells'
+# largest `mixed` step.
+RAGGED_SHAPES = {"llama7b-256x32": (256, 32, HEADS, HEADS),
+                 "llama7b-2048x256": (2048, 256, HEADS, HEADS),
+                 "mistral7b-512x64": (512, 64, 32, 8),
+                 "dsmoe16b-512x64": (512, 64, 16, 16)}
+
+
+@pytest.mark.parametrize("shape", sorted(RAGGED_SHAPES))
 @pytest.mark.parametrize("pool", sorted(POOL_DTYPES))
-def test_ragged_paged_attention_compiles(compile_for_chip, pool, rows,
-                                         width):
+def test_ragged_paged_attention_compiles(compile_for_chip, pool, shape):
     """What the mixed seam selects on a TPU: the fused cache-write +
-    attend kernel. (2048, 256) is the default token budget with
-    max_model_len 4096 tables — more SMEM than one launch may take, so
+    attend kernel. (2048, 256) takes more SMEM than one launch may, so
     the seam must have split it."""
     from intellillm_tpu.ops.pallas.ragged_paged_attention import (
         ragged_paged_attention)
-    dtype = POOL_DTYPES[pool]
+    (rows, width, hq, hkv), dtype = RAGGED_SHAPES[shape], POOL_DTYPES[pool]
 
     def fn(q, k_new, v_new, k_cache, v_cache, slots, tables, ctx):
         return ragged_paged_attention(q, k_new, v_new, k_cache, v_cache,
                                       slots, tables, ctx, HEAD_DIM ** -0.5)
 
     text = compile_for_chip(
-        fn, ((rows, 1, HEADS, HEAD_DIM), jnp.bfloat16),
-        ((rows, HEADS, HEAD_DIM), dtype), ((rows, HEADS, HEAD_DIM), dtype),
-        _pool(dtype), _pool(dtype), ((rows, ), jnp.int32),
+        fn, ((rows, 1, hq, HEAD_DIM), jnp.bfloat16),
+        ((rows, hkv, HEAD_DIM), dtype), ((rows, hkv, HEAD_DIM), dtype),
+        _pool(dtype, hkv), _pool(dtype, hkv), ((rows, ), jnp.int32),
         ((rows, width), jnp.int32), ((rows, ), jnp.int32), donate=(3, 4))
     assert "tpu_custom_call" in text
 
