@@ -1607,12 +1607,6 @@ def make_arg_parser() -> argparse.ArgumentParser:
     p.add_argument("--predictor-path", type=str, default=None,
                    help="pass --predictor-path to the server "
                         "(length-predictor checkpoint)")
-    p.add_argument("--enable-chunked-prefill", action="store_true",
-                   default=True,
-                   help="DEPRECATED no-op, kept for script "
-                        "compatibility: chunked prefill is the server "
-                        "default; use --disable-chunked-prefill to turn "
-                        "it off")
     p.add_argument("--disable-chunked-prefill", action="store_true",
                    help="pass --disable-chunked-prefill to the server "
                         "(whole-prompt single-chunk admission)")

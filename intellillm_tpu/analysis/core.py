@@ -201,7 +201,7 @@ DEFAULT_SEED_FLAGS = frozenset({
     "--num-speculative-tokens", "--pipeline-parallel-size", "--port",
     "--quantization", "--response-role", "--revision",
     "--scheduling-policy", "--seed", "--served-model-name",
-    "--sp-prefill-threshold", "--speculative-model", "--swap-space",
+    "--speculative-model", "--swap-space",
     "--tensor-parallel-size", "--tokenizer", "--tokenizer-mode",
     "--trust-remote-code", "--api-key",
 })

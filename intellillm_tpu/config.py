@@ -259,7 +259,6 @@ class ParallelConfig:
         pipeline_parallel_size: int = 1,
         max_parallel_loading_workers: Optional[int] = None,
         disable_custom_collectives: bool = False,
-        sp_prefill_threshold: Optional[int] = None,
     ) -> None:
         self.tensor_parallel_size = tensor_parallel_size
         self.data_parallel_size = data_parallel_size
@@ -268,20 +267,6 @@ class ParallelConfig:
         # XLA owns ICI collectives; kept for CLI parity with the reference's
         # --disable-custom-all-reduce (subsumed by jax.lax.psum).
         self.disable_custom_collectives = disable_custom_collectives
-        # Sequence-parallel prefill: accepted but currently INERT. The
-        # ring/ulysses attention ops (ops/ring_attention.py) remain, but
-        # their engine hook rode the legacy whole-prompt prefill path,
-        # which the mixed token-budget dispatch replaced — prompts now
-        # prefill as budget-sized chunks, which bounds per-step prefill
-        # latency without sequence sharding. Re-wiring SP under the mixed
-        # dispatch is tracked in ROADMAP.md.
-        self.sp_prefill_threshold = sp_prefill_threshold
-        if sp_prefill_threshold is not None:
-            logger.warning(
-                "sp_prefill_threshold=%d is currently inert: "
-                "sequence-parallel prefill was tied to the removed "
-                "whole-prompt prefill path; prompts prefill as chunked "
-                "mixed-dispatch rows instead.", sp_prefill_threshold)
         self.world_size = (tensor_parallel_size * data_parallel_size *
                            pipeline_parallel_size)
         self._verify_args()
@@ -368,9 +353,9 @@ class SchedulerConfig:
                 and not self.enable_chunked_prefill):
             raise ValueError(
                 f"max_num_batched_tokens ({self.max_num_batched_tokens}) must "
-                f"be >= max_model_len ({self.max_model_len}). Enable chunked "
-                "prefill (--enable-chunked-prefill) to use a per-step token "
-                "budget smaller than the longest admissible prompt.")
+                f"be >= max_model_len ({self.max_model_len}) with chunked "
+                "prefill off (--disable-chunked-prefill): only a chunked "
+                "prompt can be longer than the per-step token budget.")
         if (self.max_num_batched_tokens < self.max_num_seqs
                 and not self.enable_chunked_prefill):
             # Chunked admission seats every runnable decode before the

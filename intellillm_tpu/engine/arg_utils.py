@@ -7,17 +7,11 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import warnings
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
 from intellillm_tpu.config import (CacheConfig, LoRAConfig, ModelConfig,
                                    ParallelConfig, SchedulerConfig)
-
-# CLI sentinel for the deprecated --enable-chunked-prefill flag: the
-# store_true default is also True, so a plain bool cannot tell "user
-# typed the flag" (warn) from "default" (silent).
-_CHUNKED_CLI_SENTINEL = "__explicit_cli__"
 
 
 @dataclass
@@ -32,7 +26,6 @@ class EngineArgs:
     tensor_parallel_size: int = 1
     data_parallel_size: int = 1
     pipeline_parallel_size: int = 1
-    sp_prefill_threshold: Optional[int] = None
     # KV cache
     block_size: int = 16
     hbm_utilization: float = 0.90
@@ -93,14 +86,6 @@ class EngineArgs:
     def __post_init__(self) -> None:
         if self.tokenizer is None:
             self.tokenizer = self.model
-        if self.enable_chunked_prefill == _CHUNKED_CLI_SENTINEL:
-            warnings.warn(
-                "--enable-chunked-prefill is deprecated and a no-op: "
-                "chunked prefill has been the default since the mixed "
-                "token-budget dispatch landed. Drop the flag, or use "
-                "--disable-chunked-prefill to turn chunking off.",
-                DeprecationWarning, stacklevel=2)
-            self.enable_chunked_prefill = True
 
     @staticmethod
     def add_cli_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
@@ -118,10 +103,6 @@ class EngineArgs:
                             default=1)
         parser.add_argument("--pipeline-parallel-size", "-pp", type=int,
                             default=1)
-        parser.add_argument("--sp-prefill-threshold", type=int, default=None,
-                            help="prompts >= this many tokens prefill with "
-                            "the sequence dim sharded over the mesh 'data' "
-                            "axis (ring attention); None disables")
         parser.add_argument("--block-size", type=int, default=16,
                             choices=[8, 16, 32, 64, 128])
         parser.add_argument("--hbm-utilization", "--gpu-memory-utilization",
@@ -160,26 +141,17 @@ class EngineArgs:
                             "KV export; 'decode' imports prefilled KV and "
                             "runs pure decode; 'mixed' (default) does both "
                             "(see docs/routing.md)")
-        parser.add_argument("--enable-chunked-prefill", action="store_const",
-                            const=_CHUNKED_CLI_SENTINEL, default=True,
-                            help="DEPRECATED no-op (emits a "
-                            "DeprecationWarning): chunked prefill is on by "
-                            "default; use --disable-chunked-prefill to turn "
-                            "it off. (default: on) split long prompts into "
-                            "token-budget-sized chunks and piggyback them "
-                            "onto decode batches (mixed steps); running "
-                            "decodes are admitted first, so a long prompt "
-                            "never stalls generation. "
-                            "--max-num-batched-tokens is the per-step "
-                            "compute budget (default 512), not a "
-                            "prompt-length ceiling")
         parser.add_argument("--disable-chunked-prefill", action="store_true",
-                            help="one-release escape hatch: admit each "
-                            "prompt as a single whole-prompt chunk instead "
-                            "of splitting it (prompts must then fit "
-                            "--max-num-batched-tokens whole). Execution "
-                            "still uses the mixed dispatch — the legacy "
-                            "homogeneous prefill path is gone")
+                            help="admit each prompt as a single "
+                            "whole-prompt chunk (it must then fit "
+                            "--max-num-batched-tokens whole). By default a "
+                            "long prompt is split into token-budget-sized "
+                            "chunks that ride along with decode rows in "
+                            "mixed steps: running decodes are admitted "
+                            "first, so a long prompt never stalls "
+                            "generation, and --max-num-batched-tokens is "
+                            "the per-step compute budget (default 512), "
+                            "not a prompt-length ceiling")
         parser.add_argument("--disable-tenant-fairness", action="store_true",
                             help="turn off the per-tenant weighted "
                             "admission caps (seat + prefill-chunk-token "
@@ -290,7 +262,6 @@ class EngineArgs:
             tensor_parallel_size=self.tensor_parallel_size,
             data_parallel_size=self.data_parallel_size,
             pipeline_parallel_size=self.pipeline_parallel_size,
-            sp_prefill_threshold=self.sp_prefill_threshold,
         )
         scheduler_config = SchedulerConfig(
             max_num_batched_tokens=self.max_num_batched_tokens,

@@ -62,10 +62,6 @@ class LatentAttention:
         attn_metadata: AttentionMetadata,
     ):
         """Returns (o^ [B, L, H, kv_lora_rank], kv_cache)."""
-        if attn_metadata.is_prompt:
-            raise NotImplementedError(
-                "latent attention serves the mixed and decode programs "
-                "only (no runner path builds a prompt-phase batch)")
         mesh = attn_metadata.mesh
         if mesh is not None and mesh.devices.size > 1:
             raise NotImplementedError(

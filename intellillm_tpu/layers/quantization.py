@@ -181,15 +181,15 @@ def qmatmul(x: jnp.ndarray, w: Union[jnp.ndarray, QuantizedWeight]
     if not is_quantized(w):
         return x @ w
     if "q4lut" in w:
-        from intellillm_tpu.ops.dispatch import use_pallas
+        from intellillm_tpu.ops.dispatch import use_kernel
         from intellillm_tpu.ops.pallas import quant_matmul as _qmm
-        if use_pallas() and _qmm.supports_lut(w):
+        if use_kernel("int4_lut", _qmm.supports_lut(w)):
             return _qmm.quant_matmul_int4_lut(x, w)
         return x @ _dequant_int4lut(w, x.dtype)
     if "q4" in w:
-        from intellillm_tpu.ops.dispatch import use_pallas
+        from intellillm_tpu.ops.dispatch import use_kernel
         from intellillm_tpu.ops.pallas import quant_matmul as _qmm
-        if use_pallas() and _qmm.supports(w):
+        if use_kernel("int4", _qmm.supports(w)):
             # Pallas kernel: packed bytes stream HBM→VMEM, dequant feeds
             # the MXU in-tile. It reserves ZERO temp HBM, where the XLA
             # path's buffer plan reserves ~6x the packed bytes (measured

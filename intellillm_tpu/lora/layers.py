@@ -6,9 +6,9 @@ Role parity: reference punica kernels (`csrc/punica/bgmv/bgmv_impl.cuh`,
 
 - Pallas BGMV kernel (ops/pallas/bgmv.py) on TPU: the adapter stacks stay
   VMEM-resident and each row's adapter is picked by a dynamic VMEM index
-  — no gathered [B, Din, R] copy in HBM per step. Gated by
-  `use_pallas_kernel("bgmv")` (INTELLILLM_PALLAS_BGMV) and
-  `bgmv_supported` (128-aligned dims, VMEM budget).
+  — no gathered [B, Din, R] copy in HBM per step. Taken where
+  `use_kernel("bgmv", bgmv_supported(...))` (128-aligned dims, VMEM
+  budget) says so.
 - jnp reference elsewhere: the per-row adapter slab is gathered from the
   stacked tensors and contracted with two einsums — XLA maps the
   [B, Din, R] x [B, R, Dout] chain onto the MXU directly.
@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from intellillm_tpu.ops.dispatch import use_pallas_kernel
+from intellillm_tpu.ops.dispatch import use_kernel
 
 
 def lora_delta(
@@ -35,7 +35,7 @@ def lora_delta(
     directly onto the base projection output.
     """
     from intellillm_tpu.ops.pallas.bgmv import bgmv, bgmv_supported
-    if use_pallas_kernel("bgmv") and bgmv_supported(x, a_stack, b_stack):
+    if use_kernel("bgmv", bgmv_supported(x, a_stack, b_stack)):
         return bgmv(x, a_stack, b_stack, row_slots)
     a_sel = a_stack[row_slots]                     # [B, Din, R]
     b_sel = b_stack[row_slots]                     # [B, R, Dout]

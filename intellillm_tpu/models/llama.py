@@ -80,7 +80,6 @@ class LlamaForCausalLM:
             head_size=self.head_size,
             scale=self.head_size**-0.5,
             num_kv_heads=self.num_kv_heads,
-            sliding_window=getattr(cfg, "sliding_window", None),
         )
 
     def __call__(

@@ -1,22 +1,24 @@
 """Attention ops over the paged KV pool — reference (pure jnp) impls.
 
 Role parity:
-- prefill: reference used xformers `memory_efficient_attention_forward`
-  with a BlockDiagonalCausalMask (`vllm/model_executor/layers/attention.py:151-161`).
-  Here: batched padded causal attention; XLA fuses the softmax chain. A
-  Pallas flash kernel (ops/pallas/flash_attention.py) takes over on TPU for
-  long sequences.
 - decode: reference `ops.paged_attention_v1/v2` CUDA kernels
   (`csrc/attention/attention_kernels.cu`). Here: block-table gather +
-  masked attention (correct everywhere, used for tests/CPU), with the
-  Pallas paged-attention kernel (ops/pallas/paged_attention.py) as the TPU
-  fast path.
+  masked attention (correct everywhere, used for tests/CPU), the oracle
+  of the Pallas kernels behind the seams of `ops/ragged_attention.py` and
+  `ops/mla_attention.py`; the staged part and the merge of the K-step
+  decode.
+- `prefill_attention_reference`: dense causal attention over a padded
+  prompt batch (the reference's xformers call,
+  `vllm/model_executor/layers/attention.py:151-161`). No serving path
+  runs it: a prompt is served as chunk rows through the fused seam. It
+  is the independent answer the tests hold that path to.
 
 GQA/MQA is handled by reshaping queries to [.., kv_heads, group, ..] rather
 than materializing repeated KV heads (reference expands heads instead,
 attention.py:106-120 — wasteful on HBM bandwidth).
-ALiBi biases (attention.py:196-227) and sliding windows (:131-133) are
-supported in both phases.
+ALiBi biases (attention.py:196-227) are supported throughout; a sliding
+window is the runner's (a ring of blocks and a clamped context), never a
+mask inside attention, and only the dense oracle takes one.
 """
 from __future__ import annotations
 
@@ -82,93 +84,6 @@ def prefill_attention_reference(
     # Fully-masked (padded) query rows softmax to NaN; zero them.
     probs = jnp.where(full_mask.any(axis=-1, keepdims=True), probs, 0.0)
     out = jnp.einsum("bhglm,bmhd->blhgd", probs, v.astype(probs.dtype))
-    return out.reshape(b, l, hq, d).astype(q.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "sliding_window"))
-def context_attention_reference(
-    q: jnp.ndarray,             # [B, L, Hq, D] — the new (suffix) tokens
-    k_new: jnp.ndarray,         # [B, L, Hkv, D]
-    v_new: jnp.ndarray,         # [B, L, Hkv, D]
-    k_cache: jnp.ndarray,       # [num_blocks, Hkv, bs, D] — holds the prefix
-    v_cache: jnp.ndarray,
-    block_tables: jnp.ndarray,  # [B, max_blocks] int32
-    prefix_lens: jnp.ndarray,   # [B] int32 — cached prefix length per seq
-    new_lens: jnp.ndarray,      # [B] int32 — actual new-token count
-    scale: float,
-    alibi_slopes: Optional[jnp.ndarray] = None,
-    sliding_window: Optional[int] = None,
-) -> jnp.ndarray:
-    """Prefill attention when part of the context is already cached (prefix
-    caching / chunked prefill). Role parity: the reference's 728-line Triton
-    `context_attention_fwd` (`layers/triton_kernel/prefix_prefill.py`).
-
-    Each new token attends to [cached prefix ++ causal new tokens].
-    """
-    from intellillm_tpu.ops.kv_cache import gather_kv_for_attention
-
-    b, l, hq, d = q.shape
-    hkv = k_new.shape[2]
-    nb, _, bs, _ = k_cache.shape
-    max_blocks = block_tables.shape[1]
-    max_ctx = max_blocks * bs
-
-    # Gather prefix KV: [B, max_ctx, Hkv, D]
-    k_pre = gather_kv_for_attention(k_cache, block_tables)
-    v_pre = gather_kv_for_attention(v_cache, block_tables)
-
-    qg = _grouped_query_reshape(q, hkv) * scale
-
-    # Prefix scores: every new token sees all valid prefix positions.
-    s_pre = jnp.einsum("blhgd,bmhd->bhglm", qg, k_pre,
-                       preferred_element_type=jnp.float32)
-    pre_pos = jnp.arange(max_ctx)[None, :]
-    pre_valid = pre_pos < prefix_lens[:, None]           # [B, max_ctx]
-    q_pos = jnp.arange(l)[None, :]
-    q_valid = q_pos < new_lens[:, None]                  # [B, L]
-    mask_pre = (q_valid[:, None, None, :, None] &
-                pre_valid[:, None, None, None, :])
-    if sliding_window is not None:
-        # Query's absolute position is prefix_len + i; prefix key's is its
-        # slot index. Same window semantics as the non-prefix prefill path.
-        abs_q_w = prefix_lens[:, None] + q_pos                # [B, L]
-        in_window = (pre_pos[:, None, :] >
-                     abs_q_w[:, :, None] - sliding_window)    # [B, L, M]
-        mask_pre &= in_window[:, None, None, :, :]
-    s_pre = jnp.where(mask_pre, s_pre, _NEG_INF)
-
-    # New-token scores: causal within the suffix.
-    s_new = jnp.einsum("blhgd,bmhd->bhglm", qg, k_new,
-                       preferred_element_type=jnp.float32)
-    causal = (jnp.arange(l)[:, None] >= jnp.arange(l)[None, :])
-    mask_new = (causal[None, None, None, :, :] &
-                q_valid[:, None, None, :, None] &
-                q_valid[:, None, None, None, :])
-    if sliding_window is not None:
-        # Both absolute positions share the prefix offset, so the window
-        # check reduces to suffix-relative indices.
-        new_window = (jnp.arange(l)[None, :] >
-                      jnp.arange(l)[:, None] - sliding_window)
-        mask_new &= new_window[None, None, None, :, :]
-    s_new = jnp.where(mask_new, s_new, _NEG_INF)
-
-    if alibi_slopes is not None:
-        slopes = alibi_slopes.reshape(hkv, hq // hkv)
-        abs_q = prefix_lens[:, None] + jnp.arange(l)[None, :]     # [B, L]
-        dist_pre = abs_q[:, :, None] - pre_pos[:, None, :]        # [B, L, M]
-        s_pre = s_pre - (slopes[None, :, :, None, None] *
-                         dist_pre[:, None, None, :, :])
-        dist_new = (jnp.arange(l)[:, None] - jnp.arange(l)[None, :])
-        s_new = s_new - (slopes[None, :, :, None, None] *
-                         dist_new[None, None, None].astype(jnp.float32))
-
-    scores = jnp.concatenate([s_pre, s_new], axis=-1)
-    any_valid = jnp.concatenate(
-        [mask_pre, mask_new], axis=-1).any(axis=-1, keepdims=True)
-    probs = jax.nn.softmax(scores, axis=-1)
-    probs = jnp.where(any_valid, probs, 0.0)
-    v_all = jnp.concatenate([v_pre, v_new], axis=1).astype(probs.dtype)
-    out = jnp.einsum("bhglm,bmhd->blhgd", probs, v_all)
     return out.reshape(b, l, hq, d).astype(q.dtype)
 
 

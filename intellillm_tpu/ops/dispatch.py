@@ -1,21 +1,19 @@
 """Kernel dispatch policy: Pallas fast paths vs jnp reference impls.
 
 The reference picks CUDA kernel V1 vs V2 by context length heuristics
-(`attention.py:230-302`); here the choice is Pallas-vs-jnp by backend, with
-an env/programmatic override for tests and debugging.
+(`attention.py:230-302`); here every kernel sits behind a *seam* (a
+function in `ops/`, `lora/layers.py` or `layers/quantization.py` that
+owns one Pallas kernel and its jnp oracle), and a seam asks one question:
+`use_kernel(name, supported)`. The answer is the backend's (Pallas on a
+TPU, the oracle elsewhere; `set_use_pallas` / INTELLILLM_USE_PALLAS turn
+every seam at once, to isolate a kernel fault) AND whether the shapes at
+hand are ones the kernel supports, which the seam works out from what it
+is given (head size a multiple of 128, `bgmv_supported`, ...). The
+label of the path *taken* goes to intellillm_kernel_dispatch_total{path}
+(`pallas:<name>` / `reference:<name>`).
 
-Two layers of selection (both trace-time, so the counter moves in
-lockstep with XLA compiles and the jit bucket keys never change):
-
-- `use_pallas()` — the backend-level gate (INTELLILLM_USE_PALLAS or
-  default-on-TPU). Used by the prefill flash and decode paged kernels.
-- `use_pallas_kernel(name)` — the backend gate AND a per-kernel
-  INTELLILLM_PALLAS_<NAME> flag (default on), so one hot-path kernel can
-  be reverted to its jnp reference without losing the others. Used by
-  the ragged fused cache-write+attend kernel ("ragged") and the
-  batched-LoRA BGMV kernel ("bgmv"); see docs/kernels.md. A kernel with
-  no flag of its own (the latent-attention seams, "mla") follows the
-  backend gate alone and still records its own label.
+The choice is made while tracing, so the counter moves in lockstep with
+XLA compiles and the jit bucket keys never change.
 """
 from __future__ import annotations
 
@@ -26,15 +24,6 @@ import jax
 
 _FORCE: Optional[bool] = None
 
-# Per-kernel opt-out flags for `use_pallas_kernel`. Every entry is a
-# bounded label of intellillm_kernel_dispatch_total{path} (as
-# "pallas:<name>" / "reference:<name>") — adding one here means
-# documenting it in docs/kernels.md (flag-docs lint enforces this).
-_KERNEL_FLAGS: Dict[str, str] = {
-    "ragged": "INTELLILLM_PALLAS_RAGGED",
-    "bgmv": "INTELLILLM_PALLAS_BGMV",
-}
-
 
 def set_use_pallas(force: Optional[bool]) -> None:
     """Force Pallas kernels on/off (None = auto by backend)."""
@@ -42,38 +31,30 @@ def set_use_pallas(force: Optional[bool]) -> None:
     _FORCE = force
 
 
-def use_pallas() -> bool:
-    result = _resolve_use_pallas()
-    # Dispatch decisions happen at trace time, so the counter moves in
-    # lockstep with XLA compiles (intellillm_kernel_dispatch_total).
+def use_kernel(kernel: str, supported: bool = True) -> bool:
+    """Whether the seam `kernel` runs its Pallas kernel on the shapes at
+    hand (`supported`: the seam's own test of them). Records the path
+    taken; nothing else writes that record."""
+    result = _resolve_use_pallas() and bool(supported)
     from intellillm_tpu.obs import record_kernel_dispatch
-    record_kernel_dispatch("pallas" if result else "reference")
-    return result
-
-
-def use_pallas_kernel(kernel: str) -> bool:
-    """Per-kernel selection: the backend gate AND the kernel's own
-    INTELLILLM_PALLAS_* flag (unset/empty counts as enabled)."""
-    result = _resolve_use_pallas() and _kernel_flag(kernel) is not False
-    from intellillm_tpu.obs import record_kernel_dispatch
-    record_kernel_dispatch(
-        ("pallas:" if result else "reference:") + kernel)
+    record_kernel_dispatch(("pallas:" if result else "reference:") + kernel)
     return result
 
 
 def kernel_selection() -> Dict[str, object]:
     """Trace-time selection snapshot (no metrics side effects) for
-    `/debug/kernels` and the warm-up stats: which path each kernel seam
-    would take if a program were traced right now."""
+    `/debug/kernels` and the warm-up stats: which path the seams would
+    take, shapes permitting, if a program were traced right now. `ragged`
+    and `bgmv` repeat `use_pallas`: readers of the snapshot ask for them
+    by name."""
     base = _resolve_use_pallas()
-    sel: Dict[str, object] = {
+    return {
         "use_pallas": base,
         "forced": _FORCE is not None,
         "backend": jax.default_backend(),
+        "ragged": base,
+        "bgmv": base,
     }
-    for kernel in _KERNEL_FLAGS:
-        sel[kernel] = base and _kernel_flag(kernel) is not False
-    return sel
 
 
 def shard_over_heads(fn, mesh, num_kv_heads: int, in_head_dims,
@@ -103,21 +84,6 @@ def shard_over_heads(fn, mesh, num_kv_heads: int, in_head_dims,
         in_specs=tuple(spec(d) for d in in_head_dims),
         out_specs=tuple(spec(d) for d in out_head_dims),
         check_vma=False)
-
-
-def _kernel_flag(kernel: str) -> Optional[bool]:
-    from intellillm_tpu.utils import parse_env_flag
-    env = _KERNEL_FLAGS.get(kernel)
-    if env is None:
-        return None
-    raw = os.environ.get(env)
-    flag = parse_env_flag(raw)
-    if flag is None and raw is not None and raw.strip():
-        import warnings
-        warnings.warn(
-            f"{env}={raw!r} not recognized "
-            "(use 0/1/true/false/on/off/yes/no); treating as enabled")
-    return flag
 
 
 def _resolve_use_pallas() -> bool:

@@ -4,8 +4,8 @@ The absorbed form (`layers/mla_attention.py`) makes attention over a
 latent cache multi-query attention with one shared key a token: H query
 heads of width `Dk` against the stored vector `[c_kv | k_rope | 0-pad]`,
 the value being that vector's first `Dv` lanes. Two seams, selected at
-trace time by the backend gate (`use_pallas_kernel("mla")`, dispatch labels
-`pallas:mla` / `reference:mla`):
+trace time (`use_kernel("mla", ...)`, dispatch labels `pallas:mla` /
+`reference:mla`):
 
 - `mla_fused_attention`: the `mixed` program — write every row's new
   latent vector into the pool, then attend causally over it (decode rows
@@ -16,6 +16,8 @@ trace time by the backend gate (`use_pallas_kernel("mla")`, dispatch labels
 The jnp references compose the per-head references (`ops/attention.py`)
 with the pool given as both K and V and one kv head: they are the oracle
 the Pallas kernels (`ops/pallas/mla_attention.py`) are held to.
+`walk_extent` is those kernels' page-walk rule, here for the runner's
+walked-tokens counter.
 """
 from __future__ import annotations
 
@@ -24,25 +26,18 @@ from typing import Tuple
 import jax.numpy as jnp
 
 from intellillm_tpu.ops.attention import decode_attention_reference
-from intellillm_tpu.ops.dispatch import use_pallas_kernel
+from intellillm_tpu.ops.dispatch import use_kernel
 from intellillm_tpu.ops.kv_cache import scatter_rows
+from intellillm_tpu.ops.pallas.mla_attention import (  # noqa: F401
+    mla_paged_attention, mla_ragged_paged_attention, walk_extent)
 
 
 def _use_kernel(pool: jnp.ndarray, v_width: int) -> bool:
-    """Which path this seam takes, recorded under the label of the path
-    taken. Mosaic DMA windows are whole 128-lane tiles: with the kernels
-    selected, widths that are not raise (the stored width always is,
-    `ModelConfig.get_latent_cache_width`; `kv_lora_rank` must be) rather
-    than fall to the reference under the kernels' label."""
-    if not use_pallas_kernel("mla"):
-        return False
-    if pool.shape[-1] % 128 or v_width % 128:
-        raise ValueError(
-            "the latent-attention kernels need whole 128-lane tiles: "
-            f"stored width {pool.shape[-1]}, kv_lora_rank {v_width}. Run "
-            "this configuration with INTELLILLM_USE_PALLAS=0 (the jnp "
-            "reference) or give it kernels.")
-    return True
+    """Mosaic DMA windows are whole 128-lane tiles: the stored width
+    always is (`ModelConfig.get_latent_cache_width`); a `kv_lora_rank`
+    that is not serves from the reference, under its label."""
+    return use_kernel("mla", pool.shape[-1] % 128 == 0
+                      and v_width % 128 == 0)
 
 
 def mla_fused_attention(
@@ -57,8 +52,6 @@ def mla_fused_attention(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (attended latent [B, H, v_width], pool)."""
     if _use_kernel(pool, v_width):
-        from intellillm_tpu.ops.pallas.mla_attention import (
-            mla_ragged_paged_attention)
         return mla_ragged_paged_attention(
             q, new.astype(pool.dtype), pool, slot_mapping, block_tables,
             context_lens, scale, v_width)
@@ -85,8 +78,6 @@ def mla_pool_attention(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (attended latent [B, H, v_width], logsumexp [B, H])."""
     if _use_kernel(pool, v_width):
-        from intellillm_tpu.ops.pallas.mla_attention import (
-            mla_paged_attention)
         return mla_paged_attention(q, pool, block_tables, context_lens,
                                    scale, v_width)
     return mla_pool_attention_reference(q, pool, block_tables,

@@ -5,10 +5,12 @@ kernels in `csrc/`:
   head-block-vectorized kernel; the old v3/v4 twin modules are one now)
 - ragged_paged_attention.py — fused cache-write + causal paged attention
   over the flat mixed batch (decode + prefill-chunk rows in one grid)
-- flash_attention.py — blockwise-causal prefill flash attention
+- mla_attention.py — the same two kernels over a latent (MLA) pool
 - bgmv.py — batched-LoRA gather-matmul (Punica BGMV equivalent)
 - quant_matmul.py — int4 weight-dequant matmuls
 
-Kernel selection lives in ops/dispatch.py; every kernel keeps a jnp
-reference twin (see docs/kernels.md for the contract and flags).
+Each kernel is called by one seam (ops/ragged_attention.py,
+ops/mla_attention.py, lora/layers.py, layers/quantization.py), which asks
+ops/dispatch.use_kernel and keeps the kernel's jnp oracle (see
+docs/kernels.md for the contract and the one override).
 """

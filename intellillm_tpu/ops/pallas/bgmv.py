@@ -23,8 +23,7 @@ Slot 0 is the pinned all-zero adapter, so no-LoRA rows get an exact
 +0.0 delta — same guarantee as the gather path, bit-for-bit.
 
 Selection: `lora/layers.lora_delta` gates on
-`use_pallas_kernel("bgmv")` AND `bgmv_supported(...)`; see
-docs/kernels.md (INTELLILLM_PALLAS_BGMV).
+`use_kernel("bgmv", bgmv_supported(...))`; see docs/kernels.md.
 """
 from __future__ import annotations
 

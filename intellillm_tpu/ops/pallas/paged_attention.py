@@ -398,11 +398,8 @@ def _default_hp(k_cache) -> int:
     bf16 KV: hp cap 8 -> 1487, 16 -> 1603, 32 -> 1551 tok/s/chip (32
     pays a quadratically growing junk-column score dot); fp8 KV:
     16 -> 1811, 32 -> 1836 (half-size pages tip the balance toward
-    fewer, larger DMAs). Default 16, 32 for 1-byte caches;
-    INTELLILLM_PAGED_HP overrides for experiments."""
-    import os
-    default = 32 if k_cache.dtype.itemsize == 1 else 16
-    return int(os.environ.get("INTELLILLM_PAGED_HP", default))
+    fewer, larger DMAs). So: 16, and 32 for 1-byte caches."""
+    return 32 if k_cache.dtype.itemsize == 1 else 16
 
 
 @functools.partial(
@@ -488,31 +485,12 @@ def paged_attention(
     return_lse: bool = False,
 ):
     """Decode-phase paged attention. Returns [B, 1, Hq, D] (and, with
-    return_lse, the per-head logsumexp [B, Hq] for attention merging)."""
-    import os
-
-    from intellillm_tpu.utils import parse_env_flag
-    raw = os.environ.get("INTELLILLM_PAGED_V4")
-    if raw is not None and raw.strip():
-        # The v3 twin this flag used to select was folded away; warn once
-        # per call site so stale launch configs surface instead of
-        # silently running a kernel the operator thinks they disabled.
-        import warnings
-        if parse_env_flag(raw) is False:
-            warnings.warn(
-                "INTELLILLM_PAGED_V4=0 no longer selects a v3 kernel — "
-                "the v3/v4 pair was consolidated into one paged-attention "
-                "kernel. Use INTELLILLM_USE_PALLAS=0 for the jnp "
-                "reference path.")
+    return_lse, the per-head logsumexp [B, Hq] for attention merging).
+    The head size must be whole 128-lane tiles (Mosaic DMA windows are
+    128-aligned in the minor dimension): the seam
+    (`ops/ragged_attention.py` `paged_pool_attention`) sends other head
+    sizes to the jnp reference."""
     b, one, hq, d = q.shape
-    if d % 128 != 0:
-        # Mosaic DMA windows must be 128-aligned in the minor dimension, so
-        # head sizes like 64/80 cannot be sliced out of the HBM pool; use
-        # the jnp gather reference (these are the small-model head sizes).
-        from intellillm_tpu.ops.attention import decode_attention_reference
-        return decode_attention_reference(q, k_cache, v_cache, block_tables,
-                                          context_lens, scale, alibi_slopes,
-                                          return_lse=return_lse)
     hkv = k_cache.shape[1]
     g = hq // hkv
     q_grouped = q.reshape(b, hkv, g, d)
@@ -528,7 +506,3 @@ def paged_attention(
         return out, lse.reshape(b, hq)
     return out
 
-
-# Import-compat alias for callers of the pre-consolidation twin module's
-# entry point (the kernels are one and the same now).
-paged_attention_v4 = paged_attention

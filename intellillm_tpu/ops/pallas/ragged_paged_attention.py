@@ -41,8 +41,8 @@ the self-token must see post-cast (e.g. fp8-quantized) values, and DMAs
 cannot cast. The caches are updated in place via `input_output_aliases`
 (indices count the scalar-prefetch operands).
 
-Selection: `ops/ragged_attention.ragged_fused_attention` gates on
-`use_pallas_kernel("ragged")` and `head_size % 128 == 0`; everything
+Selection: `ops/ragged_attention.ragged_fused_attention` asks
+`use_kernel("ragged", head_size % 128 == 0)`; everything
 else takes the jnp reference composition (reshape_and_cache then
 decode_attention_reference), which is the golden-pinned oracle.
 """

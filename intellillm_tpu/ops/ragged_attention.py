@@ -1,15 +1,22 @@
-"""Fused cache-write + attend seam for the mixed token-budget dispatch.
+"""Seams of per-head attention over the paged KV pool.
 
-`layers/attention.py` calls `ragged_fused_attention` for every
-non-prompt (mixed/decode) step. The selection is trace-time
-(`use_pallas_kernel("ragged")`), so the jit bucket keys never change
-and the single `mixed` executable is preserved; on CPU — and on TPU for
-head sizes that fail the 128-lane DMA alignment — the reference path
-composes exactly the same primitives in exactly the same order as the
-pre-fusion incumbent (`reshape_and_cache` scatter, then
-`decode_attention_reference` gather), so greedy outputs are
-bit-identical by construction. That composition is the golden oracle
-the Pallas kernel is pinned against.
+`layers/attention.py` calls these and nothing below them. Each asks
+`ops/dispatch.use_kernel` once, with the one thing the kernels need of
+the shapes (a head size of whole 128-lane tiles: Mosaic DMA windows
+cannot slice 64 or 80 lanes out of the HBM pool), and runs its Pallas
+kernel or its jnp oracle; the choice is trace-time, so the jit bucket
+keys never change.
+
+- `ragged_fused_attention` (label `ragged`): every row of a `mixed` step.
+  The oracle composes the `reshape_and_cache` scatter and the
+  `decode_attention_reference` gather, the pair the kernel fuses; the
+  kernel is pinned against that composition.
+- `paged_pool_attention` (label `paged`): the pool part of a K-step
+  decode substep, with the logsumexp the layer merges the staged tokens'
+  part by.
+
+`walk_extent` is the page walk's rule of both kernels, here for the
+runner's walked-tokens counter.
 """
 from __future__ import annotations
 
@@ -18,9 +25,12 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 
 from intellillm_tpu.ops.attention import decode_attention_reference
-from intellillm_tpu.ops.dispatch import (shard_over_heads,
-                                         use_pallas_kernel)
+from intellillm_tpu.ops.dispatch import shard_over_heads, use_kernel
 from intellillm_tpu.ops.kv_cache import reshape_and_cache
+from intellillm_tpu.ops.pallas.paged_attention import (  # noqa: F401
+    paged_attention, walk_extent)
+from intellillm_tpu.ops.pallas.ragged_paged_attention import (
+    ragged_paged_attention)
 
 Arrays3 = Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]
 
@@ -42,11 +52,7 @@ def ragged_fused_attention(
     it (decode rows and prefill-chunk rows alike — chunk rows just carry
     `context_lens = position + 1`). Returns (out, k_cache, v_cache).
     `mesh` (multi-chip runs) shards the kernel over kv heads."""
-    d = q.shape[-1]
-    if use_pallas_kernel("ragged") and d % 128 == 0:
-        from intellillm_tpu.ops.pallas.ragged_paged_attention import (
-            ragged_paged_attention)
-
+    if use_kernel("ragged", q.shape[-1] % 128 == 0):
         def kernel(q, k_new, v_new, k_cache, v_cache, slots, tables, ctx,
                    slopes):
             return ragged_paged_attention(q, k_new, v_new, k_cache, v_cache,
@@ -78,3 +84,33 @@ def ragged_fused_attention_reference(
     out = decode_attention_reference(q, k_cache, v_cache, block_tables,
                                      context_lens, scale, alibi_slopes)
     return out, k_cache, v_cache
+
+
+def paged_pool_attention(
+    q: jnp.ndarray,             # [B, 1, Hq, D]
+    k_cache: jnp.ndarray,       # [NB, Hkv, BS, D], read-only
+    v_cache: jnp.ndarray,
+    block_tables: jnp.ndarray,  # [B, W] i32
+    context_lens: jnp.ndarray,  # [B] i32 tokens in the pool
+    scale: float,
+    alibi_slopes: Optional[jnp.ndarray] = None,
+    mesh=None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Attention of one query token a row over what the pool holds of its
+    sequence. Returns (out [B, 1, Hq, D], logsumexp [B, Hq]). `mesh`
+    (multi-chip runs) shards the kernel over kv heads."""
+    if use_kernel("paged", q.shape[-1] % 128 == 0):
+        def kernel(q, k_cache, v_cache, tables, ctx, slopes):
+            return paged_attention(q, k_cache, v_cache, tables, ctx, scale,
+                                   slopes, return_lse=True)
+
+        return shard_over_heads(
+            kernel, mesh, k_cache.shape[1],
+            in_head_dims=(2, 1, 1, None, None,
+                          None if alibi_slopes is None else 0),
+            out_head_dims=(2, 1))(
+                q, k_cache, v_cache, block_tables, context_lens,
+                alibi_slopes)
+    return decode_attention_reference(q, k_cache, v_cache, block_tables,
+                                      context_lens, scale, alibi_slopes,
+                                      return_lse=True)

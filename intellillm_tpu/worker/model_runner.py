@@ -215,9 +215,9 @@ class ModelRunner:
         # The page walk's rule of the attention kernels this model runs
         # (what the walked-tokens counter reports).
         if model_config.get_latent_cache_width():
-            from intellillm_tpu.ops.pallas.mla_attention import walk_extent
+            from intellillm_tpu.ops.mla_attention import walk_extent
         else:
-            from intellillm_tpu.ops.pallas.paged_attention import walk_extent
+            from intellillm_tpu.ops.ragged_attention import walk_extent
         self._walk_extent = walk_extent
         from intellillm_tpu.layers.attention import model_uses_alibi
         self._uses_alibi = model_uses_alibi(model)
@@ -599,7 +599,6 @@ class ModelRunner:
                 pos_k = jnp.minimum(base_pos + chunk_base + k,
                                     self.max_model_len - 1)
                 meta = AttentionMetadata(
-                    is_prompt=False,
                     slot_mapping=None,
                     context_lens=pool_ctx,
                     block_tables=block_tables,
@@ -714,7 +713,6 @@ class ModelRunner:
                                     axis=1)[:, 0] * bs + pos % bs)
         slot = jnp.where(context_lens > 0, slot, nb * bs)
         meta = AttentionMetadata(
-            is_prompt=False,
             slot_mapping=slot[:, None],
             context_lens=ctx,
             block_tables=block_tables,

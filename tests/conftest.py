@@ -130,6 +130,17 @@ EXAMPLE_PROMPTS = [
 
 
 @pytest.fixture
+def force_pallas():
+    """`set_use_pallas`, put back to the backend's own choice afterwards.
+    Forced on under the CPU backend, a seam still serves a shape its
+    kernel does not support (every tiny model's head size) from its
+    oracle."""
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    yield set_use_pallas
+    set_use_pallas(None)
+
+
+@pytest.fixture
 def example_prompts():
     return list(EXAMPLE_PROMPTS)
 

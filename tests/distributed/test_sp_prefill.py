@@ -1,13 +1,9 @@
 """Multi-device prefill correctness through the SERVING engine.
 
-Sequence-parallel (ring / Ulysses) prefill was tied to the removed
-whole-prompt homogeneous prefill program; prompts now prefill as
-chunked mixed-dispatch rows on every topology, and
-`--sp-prefill-threshold` is accepted but inert (config.py logs the
-warning). What must still hold — and what these tests pin — is output
-equality: long prompts prefilled under tensor/data-parallel meshes must
-produce greedy tokens identical to a single-device run, and the SP ops
-must never be silently routed to (they would desync the paged KV pool).
+Prompts prefill as chunked mixed-dispatch rows on every topology. What
+must hold is output equality: long prompts prefilled under a
+tensor x data-parallel mesh produce greedy tokens identical to a
+single-device run.
 """
 import jax
 import pytest
@@ -39,54 +35,7 @@ def test_multi_device_prefill_matches_single_device(tiny_llama_dir):
            for o in _llm(tiny_llama_dir).generate(prompts, params)]
 
     llm = _llm(tiny_llama_dir, tensor_parallel_size=2,
-               data_parallel_size=4, sp_prefill_threshold=48,
-               max_paddings=40)
+               data_parallel_size=4, max_paddings=40)
     got = [o.outputs[0].token_ids for o in llm.generate(prompts, params)]
-
-    assert got == ref
-
-
-@requires_8_devices
-def test_sp_threshold_is_inert_and_ring_never_engaged(tiny_llama_dir):
-    """--sp-prefill-threshold must not route ANY prompt through the ring
-    path (it would bypass the paged mixed dispatch): the op stays
-    uncalled even for prompts past the threshold."""
-    import intellillm_tpu.ops.ring_attention as ring_mod
-    calls = {"n": 0}
-    orig = ring_mod.ring_attention
-
-    def counting(*a, **kw):
-        calls["n"] += 1
-        return orig(*a, **kw)
-
-    ring_mod.ring_attention = counting
-    try:
-        llm = _llm(tiny_llama_dir, data_parallel_size=4,
-                   sp_prefill_threshold=64)
-        long_prompt = " ".join(["the cat runs fast and the dog"] * 12)
-        llm.generate(["hello my name is", long_prompt],
-                     SamplingParams(temperature=0.0, max_tokens=4))
-    finally:
-        ring_mod.ring_attention = orig
-    assert calls["n"] == 0
-
-
-@requires_8_devices
-def test_multi_device_prefill_ulysses_env_matches_single_device(
-        tiny_llama_dir, monkeypatch):
-    """INTELLILLM_SP_MODE=ulysses (now a no-op for serving) must not
-    change outputs: the dp=2 run still matches single-device exactly.
-    (tiny-llama has 2 kv heads — dp=2 keeps heads dividing the axis.)"""
-    monkeypatch.setenv("INTELLILLM_SP_MODE", "ulysses")
-    long_prompt = " ".join(["the cat runs fast and the dog is slow"] * 12)
-    params = SamplingParams(temperature=0.0, max_tokens=12)
-
-    ref = [o.outputs[0].token_ids
-           for o in _llm(tiny_llama_dir).generate([long_prompt], params)]
-
-    llm = _llm(tiny_llama_dir, data_parallel_size=2,
-               sp_prefill_threshold=48, max_paddings=40)
-    got = [o.outputs[0].token_ids
-           for o in llm.generate([long_prompt], params)]
 
     assert got == ref

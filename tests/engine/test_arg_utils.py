@@ -1,9 +1,7 @@
-"""CLI argument plumbing (engine/arg_utils.py): the deprecated no-op
---enable-chunked-prefill warns and still resolves to chunking ON, the
---replica-role flag round-trips into SchedulerConfig, and the silent
-default path stays silent."""
+"""CLI argument plumbing (engine/arg_utils.py): chunked prefill is on
+unless --disable-chunked-prefill says otherwise, and the --replica-role
+flag round-trips into SchedulerConfig."""
 import argparse
-import warnings
 
 import pytest
 
@@ -15,29 +13,13 @@ def _parse(argv):
     return parser.parse_args(argv)
 
 
-def test_enable_chunked_prefill_flag_warns_and_stays_on():
-    args = _parse(["--model", "m", "--enable-chunked-prefill"])
-    with pytest.warns(DeprecationWarning, match="no-op"):
-        engine_args = EngineArgs.from_cli_args(args)
-    # The sentinel never leaks: the flag resolves back to the default.
-    assert engine_args.enable_chunked_prefill is True
-    assert engine_args.disable_chunked_prefill is False
-
-
-def test_no_warning_without_the_flag():
-    args = _parse(["--model", "m"])
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        engine_args = EngineArgs.from_cli_args(args)
-    assert engine_args.enable_chunked_prefill is True
-
-
-def test_disable_chunked_prefill_still_works():
-    args = _parse(["--model", "m", "--disable-chunked-prefill"])
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        engine_args = EngineArgs.from_cli_args(args)
-    assert engine_args.disable_chunked_prefill is True
+@pytest.mark.parametrize("argv,chunked", [([], True),
+                                          (["--disable-chunked-prefill"],
+                                           False)])
+def test_chunked_prefill_is_on_unless_disabled(argv, chunked):
+    engine_args = EngineArgs.from_cli_args(_parse(["--model", "m"] + argv))
+    assert (engine_args.enable_chunked_prefill
+            and not engine_args.disable_chunked_prefill) is chunked
 
 
 @pytest.mark.parametrize("role", ["mixed", "prefill", "decode"])

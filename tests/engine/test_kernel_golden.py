@@ -1,13 +1,15 @@
-"""Golden invariance across the kernel-selection flags.
+"""Golden invariance across the kernel selection.
 
-The `INTELLILLM_PALLAS_*` flags choose a path at trace time inside the
-same jit programs — flipping them must not change greedy outputs
-anywhere the reference runs (on CPU both settings resolve to the same
-reference composition, so outputs are bit-identical BY CONSTRUCTION and
-this pins the construction), and must not change the executable count
-or bucketing (the zero-new-executables acceptance criterion, checked
-via CompileTracker deltas). On TPU the same tests compare the Pallas
-kernels against the reference for real.
+`set_use_pallas` (what INTELLILLM_USE_PALLAS sets from outside) chooses a
+path at trace time inside the same jit programs — flipping it must not
+change greedy outputs anywhere the reference runs (these tiny models'
+head sizes and adapter widths are ones no kernel supports, so forced on
+or off every seam resolves to the same reference composition: outputs
+are bit-identical BY CONSTRUCTION and this pins the construction), and
+must not change the executable count or bucketing (the
+zero-new-executables acceptance criterion, checked via CompileTracker
+deltas). On TPU the same tests compare the Pallas kernels against the
+reference for real.
 
 The workload is deliberately a MIXED batch: several prompts admitted
 together with a small token budget, so steps interleave decode rows
@@ -35,9 +37,8 @@ def _greedy(llm, prompts, max_tokens=8):
     return [tuple(o.outputs[0].token_ids) for o in outs]
 
 
-def _run_flagged(model_dir, prompts, monkeypatch, ragged, bgmv):
-    monkeypatch.setenv("INTELLILLM_PALLAS_RAGGED", ragged)
-    monkeypatch.setenv("INTELLILLM_PALLAS_BGMV", bgmv)
+def _run_forced(model_dir, prompts, force_pallas, on):
+    force_pallas(on)
     before = get_compile_tracker().snapshot()
     llm = _build(model_dir)
     tokens = _greedy(llm, prompts)
@@ -56,15 +57,15 @@ def _run_flagged(model_dir, prompts, monkeypatch, ragged, bgmv):
 
 def test_mixed_greedy_identical_across_kernel_flags(tiny_llama_dir,
                                                     example_prompts,
-                                                    monkeypatch):
+                                                    force_pallas):
     """Flag flip: identical greedy tokens AND identical per-program
     compile deltas on the same mixed workload (chunked prefill + decode
     rows interleaved under a 16-token budget)."""
     prompts = example_prompts[:4]
-    tok_off, _, mixed_off = _run_flagged(
-        tiny_llama_dir, prompts, monkeypatch, "0", "0")
-    tok_on, compiles_on, mixed_on = _run_flagged(
-        tiny_llama_dir, prompts, monkeypatch, "1", "1")
+    tok_off, _, mixed_off = _run_forced(
+        tiny_llama_dir, prompts, force_pallas, False)
+    tok_on, compiles_on, mixed_on = _run_forced(
+        tiny_llama_dir, prompts, force_pallas, True)
     assert tok_on == tok_off
     # Both runs must actually exercise the mixed hot path. (Earlier
     # tests in the same process may have warmed the identical buckets —
@@ -82,8 +83,8 @@ def test_mixed_greedy_identical_across_kernel_flags(tiny_llama_dir,
 
 def test_lora_mixed_batch_identical_across_bgmv_flag(tmp_path_factory,
                                                      example_prompts,
-                                                     monkeypatch):
-    """Adapter rows and no-adapter rows in the same batch, BGMV flag off
+                                                     force_pallas):
+    """Adapter rows and no-adapter rows in the same batch, kernels off
     vs on: identical outputs (slot-0 rows ride the exact +0.0 guarantee
     on either path)."""
     pytest.importorskip("safetensors")
@@ -107,8 +108,8 @@ def test_lora_mixed_batch_identical_across_bgmv_flag(tmp_path_factory,
         base, safe_serialization=True)
     adapter = make_adapter(str(root / "ad"), seed=11, rank=4, alpha=8.0)
 
-    def run(flag):
-        monkeypatch.setenv("INTELLILLM_PALLAS_BGMV", flag)
+    def run(on):
+        force_pallas(on)
         llm = _build(base, enable_lora=True, max_loras=2, max_lora_rank=8,
                      max_model_len=64)
         params = SamplingParams(temperature=0.0, max_tokens=6)
@@ -123,4 +124,4 @@ def test_lora_mixed_batch_identical_across_bgmv_flag(tmp_path_factory,
         del llm
         return toks
 
-    assert run("0") == run("1")
+    assert run(False) == run(True)

@@ -143,34 +143,6 @@ def test_paged_attention_bf16_cache_wide_table():
                                rtol=2e-2, atol=2e-2)
 
 
-@requires_tpu
-def test_paged_v4_flag_is_inert_and_warns():
-    """INTELLILLM_PAGED_V4=0 used to select the deleted v3 twin; it must
-    now warn (stale launch configs surface) and still run the kernel."""
-    import os
-    rng = np.random.default_rng(5)
-    b, hq, hkv, d, nb, bs, w = 2, 4, 2, 128, 32, 16, 4
-    k_cache, v_cache = make_cache(rng, nb, hkv, bs, d, np.float32)
-    q = jnp.asarray(rng.normal(size=(b, 1, hq, d)).astype(np.float32))
-    tables = jnp.asarray(
-        rng.permutation(nb)[:b * w].reshape(b, w).astype(np.int32))
-    ctx = jnp.asarray(np.asarray([5, 40], np.int32))
-
-    env = dict(os.environ)
-    try:
-        os.environ["INTELLILLM_PAGED_V4"] = "0"
-        with pytest.warns(UserWarning, match="consolidated"):
-            out = paged_attention(q, k_cache, v_cache, tables, ctx,
-                                  d**-0.5)
-    finally:
-        os.environ.clear()
-        os.environ.update(env)
-    ref = decode_attention_reference(q, k_cache, v_cache, tables, ctx,
-                                     d**-0.5)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=5e-3, atol=5e-3)
-
-
 # --- the walk's edges ------------------------------------------------------
 
 WALK_BS, WALK_W = 16, 32            # two 16-page groups of 256 tokens

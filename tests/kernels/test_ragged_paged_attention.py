@@ -287,3 +287,104 @@ def test_ragged_slab_fetch_is_skipped_only_for_the_page_just_written(pool):
                                   np.asarray(kc_r.astype(jnp.float32)))
     np.testing.assert_array_equal(np.asarray(vc_k.astype(jnp.float32)),
                                   np.asarray(vc_r.astype(jnp.float32)))
+
+
+# --- a whole prompt as chunk rows against dense causal attention ----------
+#
+# Everything above holds the kernel to the scatter + gather composition.
+# This holds the *seam* (kernel and composition alike) to an answer that
+# shares nothing with either: dense causal attention over the prompt.
+
+PROMPT_LEN, PROMPT_BS, PROMPT_W = 45, 16, 8
+# How the prompt reaches the seam: the chunks (position ranges), a call
+# each, and how many leading tokens are in the pool before the first.
+PROMPT_ROUTES = {
+    "one_chunk": (0, [(0, 45)]),
+    "two_chunks_split_in_a_block": (0, [(0, 21), (21, 45)]),
+    "three_chunks": (0, [(0, 16), (16, 37), (37, 45)]),
+    "cached_prefix_of_two_blocks": (32, [(32, 45)]),
+}
+# The seam's two paths: the composition at a head size no kernel serves,
+# the Pallas kernel (interpret mode here) at one it does.
+PROMPT_PATHS = {"oracle_d64": (64, None), "kernel_d128": (128, True)}
+
+
+@requires_tpu
+@pytest.mark.parametrize("path", sorted(PROMPT_PATHS))
+@pytest.mark.parametrize("alibi", [False, True], ids=["plain", "alibi"])
+@pytest.mark.parametrize("hq,hkv", [(8, 8), (8, 2)])
+@pytest.mark.parametrize("route", sorted(PROMPT_ROUTES))
+def test_prompt_as_chunk_rows_equals_dense_causal_attention(
+        route, hq, hkv, alibi, path, force_pallas):
+    from intellillm_tpu.layers.alibi import get_alibi_slopes
+    from intellillm_tpu.obs import get_compile_tracker
+    from intellillm_tpu.ops.attention import prefill_attention_reference
+    from intellillm_tpu.ops.ragged_attention import ragged_fused_attention
+    d, force = PROMPT_PATHS[path]
+    cached, chunks = PROMPT_ROUTES[route]
+    n, bs, w, nb = PROMPT_LEN, PROMPT_BS, PROMPT_W, 64
+    rng = np.random.default_rng(11)
+    q = rng.normal(size=(n, hq, d)).astype(np.float32)
+    k = rng.normal(size=(n, hkv, d)).astype(np.float32)
+    v = rng.normal(size=(n, hkv, d)).astype(np.float32)
+    slopes = (jnp.asarray(get_alibi_slopes(hq), jnp.float32)
+              if alibi else None)
+    scale = d**-0.5
+
+    # A pool full of another tenant's values, so a read past what the
+    # prompt wrote shows; the prompt's table, a decode row of another
+    # sequence and a pad row ride along in every call.
+    k_pool = rng.normal(size=(nb, hkv, bs, d)).astype(np.float32)
+    v_pool = rng.normal(size=(nb, hkv, bs, d)).astype(np.float32)
+    tables = rng.permutation(nb)[:2 * w].reshape(2, w).astype(np.int32)
+    table, other = tables
+    pos = np.arange(n)
+    slot_of = table[pos // bs] * bs + pos % bs
+    for p in range(cached):           # the prefix another request cached
+        k_pool[table[p // bs], :, p % bs] = k[p]
+        v_pool[table[p // bs], :, p % bs] = v[p]
+    k_pool, v_pool = jnp.asarray(k_pool), jnp.asarray(v_pool)
+
+    force_pallas(force)
+    label = ("pallas" if force else "reference") + ":ragged"
+    before = get_compile_tracker().snapshot()["kernel_dispatch"].get(label, 0)
+    out = np.zeros((n, hq, d), np.float32)
+    other_ctx = 20
+    for lo, hi in chunks:
+        rows = hi - lo
+        extra = rng.normal(size=(2, hq, d)).astype(np.float32)
+        extra_kv = rng.normal(size=(2, 2, hkv, d)).astype(np.float32)
+        other_slot = other[(other_ctx - 1) // bs] * bs + (other_ctx - 1) % bs
+        got, k_pool, v_pool = ragged_fused_attention(
+            jnp.asarray(np.concatenate([q[lo:hi], extra]))[:, None],
+            jnp.asarray(np.concatenate([k[lo:hi], extra_kv[0]])),
+            jnp.asarray(np.concatenate([v[lo:hi], extra_kv[1]])),
+            k_pool, v_pool,
+            jnp.asarray(np.concatenate([slot_of[lo:hi], [other_slot, -1]]),
+                        jnp.int32),
+            jnp.asarray(np.concatenate(
+                [np.tile(table, (rows, 1)), other[None],
+                 np.zeros((1, w), np.int32)])),
+            jnp.asarray(np.concatenate([pos[lo:hi] + 1, [other_ctx, 0]]),
+                        jnp.int32),
+            scale, slopes)
+        # Interpret mode: let the kernel's callbacks finish before the
+        # next dispatch (see _run_both).
+        jax.block_until_ready((got, k_pool, v_pool))
+        out[lo:hi] = np.asarray(got)[:rows, 0]
+        other_ctx += 1
+    after = get_compile_tracker().snapshot()["kernel_dispatch"].get(label, 0)
+    assert after - before == len(chunks), "the seam took the other path"
+
+    dense = prefill_attention_reference(
+        jnp.asarray(q)[None], jnp.asarray(k)[None], jnp.asarray(v)[None],
+        jnp.asarray([n], jnp.int32), scale, None, slopes)
+    first = chunks[0][0]
+    tol = 5e-3 if jax.default_backend() == "tpu" else 2e-3
+    np.testing.assert_allclose(out[first:], np.asarray(dense)[0, first:],
+                               rtol=tol, atol=tol)
+    # The prompt's K/V are in its pages, position by position.
+    np.testing.assert_array_equal(
+        np.asarray(k_pool)[table[pos // bs], :, pos % bs], k)
+    np.testing.assert_array_equal(
+        np.asarray(v_pool)[table[pos // bs], :, pos % bs], v)

@@ -107,25 +107,6 @@ def test_serve_bench_fleet_smoke():
     assert alerts["page_firing"] is False
 
 
-def test_sp_prefill_bench_smoke():
-    """sp_prefill_bench emits one JSON line per (mode, length) on the CPU
-    backend (flash under interpret mode, ring on the virtual mesh)."""
-    import json
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        " --xla_force_host_platform_device_count=8")
-    r = subprocess.run(
-        [sys.executable, "benchmarks/sp_prefill_bench.py", "--size",
-         "tiny", "--lengths", "256", "--modes", "flash,ring"],
-        env=env, capture_output=True, text=True, timeout=420)
-    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
-    lines = [json.loads(x) for x in r.stdout.splitlines()
-             if x.startswith("{")]
-    assert len(lines) == 2
-    assert all(x["value"] > 0 for x in lines)
-
-
 def test_spec_bench_modes_build():
     """spec_bench's engine configuration (draft + force-accept env)
     drives bench.py end to end on CPU."""

@@ -164,15 +164,31 @@ def test_mixed_seam_selects_the_kernel(compile_for_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("seq_len", [512, 2048])
-def test_flash_attention_compiles(compile_for_chip, seq_len):
-    from intellillm_tpu.ops.pallas.flash_attention import flash_attention
+def test_staged_decode_selects_the_kernel(compile_for_chip):
+    """`layers/attention.py`'s K-step decode substep with Pallas forced on
+    (what a TPU resolves to): the pool part is the paged kernel, reached
+    through its seam."""
+    from intellillm_tpu.layers.attention import (AttentionMetadata,
+                                                 PagedAttention)
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    attn = PagedAttention(HEADS, HEAD_DIM, HEAD_DIM ** -0.5)
+    rows, width, chunk, dtype = 128, 64, 8, jnp.float8_e5m2
 
-    def fn(q, k, v, ctx):
-        return flash_attention(q, k, v, ctx, HEAD_DIM ** -0.5)
+    def fn(q, k, v, k_pool, v_pool, k_stage, v_stage, tables, ctx, idx):
+        meta = AttentionMetadata(context_lens=ctx, block_tables=tables,
+                                 staged=True, stage_index=idx)
+        return attn(q, k, v, (k_pool, v_pool, k_stage, v_stage), meta)[0]
 
-    qkv = ((1, seq_len, HEADS, HEAD_DIM), jnp.bfloat16)
-    text = compile_for_chip(fn, qkv, qkv, qkv, ((1, ), jnp.int32))
+    qkv = ((rows, 1, HEADS, HEAD_DIM), jnp.bfloat16)
+    stage = ((rows, chunk, HEADS, HEAD_DIM), jnp.bfloat16)
+    set_use_pallas(True)
+    try:
+        text = compile_for_chip(
+            fn, qkv, qkv, qkv, _pool(dtype), _pool(dtype), stage, stage,
+            ((rows, width), jnp.int32), ((rows, ), jnp.int32),
+            ((), jnp.int32))
+    finally:
+        set_use_pallas(None)
     assert "tpu_custom_call" in text
 
 
@@ -347,14 +363,13 @@ def test_mla_seams_select_the_kernels(compile_for_chip):
     b, w, c = 128, 64, 8
 
     def mixed(q, new, pool, slots, tables, ctx):
-        meta = AttentionMetadata(is_prompt=False, slot_mapping=slots,
-                                 context_lens=ctx, block_tables=tables)
+        meta = AttentionMetadata(slot_mapping=slots, context_lens=ctx,
+                                 block_tables=tables)
         return attn(q, new, (pool, ), meta)
 
     def staged(q, new, pool, stage, tables, ctx, k):
-        meta = AttentionMetadata(is_prompt=False, context_lens=ctx,
-                                 block_tables=tables, staged=True,
-                                 stage_index=k)
+        meta = AttentionMetadata(context_lens=ctx, block_tables=tables,
+                                 staged=True, stage_index=k)
         return attn(q, new, (pool, stage), meta)
 
     dispatch.set_use_pallas(True)

@@ -36,13 +36,12 @@ def draft_llama_dir(tmp_path_factory):
 
 def test_k_transitions_reuse_warm_executables(tiny_llama_dir,
                                               draft_llama_dir,
-                                              monkeypatch):
-    # Run the whole ladder with the Pallas hot-path kernels selected:
-    # the INTELLILLM_PALLAS_* flags are trace-time choices inside the
-    # same programs, so the K-ladder executable count and the warm-reuse
-    # guarantee must be identical to the flags-off default.
-    monkeypatch.setenv("INTELLILLM_PALLAS_RAGGED", "1")
-    monkeypatch.setenv("INTELLILLM_PALLAS_BGMV", "1")
+                                              monkeypatch, force_pallas):
+    # Run the whole ladder with the Pallas kernels selected: the
+    # selection is a trace-time choice inside the same programs, so the
+    # K-ladder executable count and the warm-reuse guarantee must be
+    # identical to the default.
+    force_pallas(True)
     k_min, k_max = 1, 3
     llm = LLM(model=tiny_llama_dir, dtype="float32",
               num_device_blocks_override=128, max_model_len=128,

@@ -80,18 +80,17 @@ def test_warm_up_default_is_two_mixed_executables(monkeypatch,
 
 @pytest.mark.parametrize("num_decode_steps", [1, 4])
 def test_warm_up_count_invariant_under_kernel_flags(monkeypatch,
+                                                    force_pallas,
                                                     num_decode_steps):
-    """Selecting the Pallas hot-path kernels (INTELLILLM_PALLAS_RAGGED /
-    INTELLILLM_PALLAS_BGMV) must not change the default warm-up: the
-    flags pick a code path at trace time INSIDE the two mixed
+    """Selecting the Pallas kernels must not change the default warm-up:
+    the selection picks a code path at trace time INSIDE the two mixed
     executables, so the count stays exactly 2 and no extra program
     appears. (On this tiny model head size is 16, so the attention
     seam falls back to the reference body — which is precisely the
-    invariance being pinned: flag state must not leak into bucketing.)
-    The stats must also carry the trace-time kernel_selection snapshot
-    that /health/detail and bench read."""
-    monkeypatch.setenv("INTELLILLM_PALLAS_RAGGED", "1")
-    monkeypatch.setenv("INTELLILLM_PALLAS_BGMV", "1")
+    invariance being pinned: the selection must not leak into
+    bucketing.) The stats must also carry the trace-time
+    kernel_selection snapshot that /health/detail and bench read."""
+    force_pallas(True)
     worker = _make_worker(num_decode_steps)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     n = worker.warm_up_model()
