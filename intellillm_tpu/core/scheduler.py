@@ -71,6 +71,7 @@ class SchedulerOutputs:
         num_prefill_tokens: int = 0,
         num_mixed_decode_tokens: int = 0,
         spec_plan: Optional[Set[str]] = None,
+        blocked: Optional[str] = None,
     ) -> None:
         self.scheduled_seq_groups = scheduled_seq_groups
         self.prompt_run = prompt_run
@@ -93,6 +94,10 @@ class SchedulerOutputs:
         # this round (per-row eligibility — the rest of the batch decodes
         # one plain token). None on non-speculative engines.
         self.spec_plan = spec_plan
+        # Why a prefill-only pass stopped admitting, when it did (the
+        # reason given to the decision log): kv_watermark, needs_chunking,
+        # max_seqs, token_budget, padding. None otherwise.
+        self.blocked = blocked
         assert not (blocks_to_swap_in and blocks_to_swap_out)
 
     @property
@@ -412,6 +417,7 @@ class Scheduler:
         blocks_to_swap_out: Dict[int, int] = {}
         blocks_to_copy: Dict[int, List[int]] = {}
         ignored_seq_groups: List[SequenceGroup] = []
+        blocked: Optional[str] = None
 
         now = time.monotonic()
 
@@ -476,9 +482,9 @@ class Scheduler:
 
                 can_allocate = self.block_manager.can_allocate(seq_group)
                 if can_allocate == AllocStatus.LATER:
+                    blocked = "kv_watermark"
                     self._decisions.pass_blocked(
-                        "kv_watermark",
-                        self.block_manager.kv_pressure_detail())
+                        blocked, self.block_manager.kv_pressure_detail())
                     break
                 if can_allocate == AllocStatus.NEVER:
                     logger.warning(
@@ -518,21 +524,25 @@ class Scheduler:
                 if new_tokens > self._max_chunk_size:
                     # Sliding-window cap: this prompt needs real chunking —
                     # leave it for a serial chunked pass.
-                    self._decisions.pass_blocked("token_budget",
-                                                 "needs_chunking")
+                    blocked = "needs_chunking"
+                    self._decisions.pass_blocked("token_budget", blocked)
                     break
 
                 # Flat token accounting: the runner flattens prompt rows
                 # into one (token_budget,)-bucketed batch, so the budget
                 # caps the SUM of chunk tokens, not batch x max-len.
                 if num_batched_tokens + new_tokens > self._prefill_token_budget:
+                    # Alone over the budget, it too needs chunking.
+                    blocked = ("token_budget" if num_batched_tokens
+                               else "needs_chunking")
                     self._decisions.pass_blocked("token_budget")
                     break
 
                 num_new_seqs = seq_group.get_max_num_running_seqs()
                 if (num_curr_seqs + num_new_seqs
                         > self.scheduler_config.max_num_seqs):
-                    self._decisions.pass_blocked("max_seqs")
+                    blocked = "max_seqs"
+                    self._decisions.pass_blocked(blocked)
                     break
 
                 # Padding waste counted against the *bucketed* flat shape
@@ -543,7 +553,8 @@ class Scheduler:
                 num_paddings = (
                     pad_to_bucket(total, self._mixed_token_buckets) - total)
                 if scheduled and num_paddings > self.scheduler_config.max_paddings:
-                    self._decisions.pass_blocked("padding")
+                    blocked = "padding"
+                    self._decisions.pass_blocked(blocked)
                     break
                 num_batched_tokens = total
 
@@ -580,6 +591,7 @@ class Scheduler:
                     ignored_seq_groups=ignored_seq_groups,
                     chunked_prefills=chunks,
                     num_prefill_tokens=num_batched_tokens,
+                    blocked=blocked,
                 )
 
         if prefill_only:
@@ -591,7 +603,7 @@ class Scheduler:
                 scheduled_seq_groups=[], prompt_run=True,
                 num_batched_tokens=0, blocks_to_swap_in={},
                 blocks_to_swap_out={}, blocks_to_copy={},
-                ignored_seq_groups=[])
+                ignored_seq_groups=[], blocked=blocked)
 
         # Decode step. Highest-priority groups keep their blocks; the
         # lowest-priority running groups get preempted when memory runs out.
@@ -1078,32 +1090,38 @@ class Scheduler:
 
             seq_group_metadata_list: List[SequenceGroupMetadata] = []
             for seq_group in scheduler_outputs.scheduled_seq_groups:
-                seq_data: Dict[int, SequenceData] = {}
-                block_tables: Dict[int, List[int]] = {}
-                for seq in seq_group.get_seqs(status=SequenceStatus.RUNNING):
-                    seq_data[seq.seq_id] = seq.data
-                    block_tables[seq.seq_id] = (
-                        self.block_manager.get_block_table(seq))
                 chunk = None
                 if scheduler_outputs.chunked_prefills:
                     chunk = scheduler_outputs.chunked_prefills.get(
                         seq_group.request_id)
-                seq_group_metadata_list.append(
-                    SequenceGroupMetadata(
-                        request_id=seq_group.request_id,
-                        is_prompt=(True if chunk is not None
-                                   else scheduler_outputs.prompt_run),
-                        seq_data=seq_data,
-                        sampling_params=seq_group.sampling_params,
-                        block_tables=block_tables,
-                        lora_request=seq_group.lora_request,
-                        prefix=seq_group.prefix,
-                        token_chunk_size=(chunk[1] if chunk is not None
-                                          else None),
-                        num_computed_tokens=(chunk[0] if chunk is not None
-                                             else 0),
-                    ))
+                seq_group_metadata_list.append(self.group_metadata(
+                    seq_group, chunk, scheduler_outputs.prompt_run))
         return seq_group_metadata_list, scheduler_outputs
+
+    def group_metadata(
+        self, seq_group: SequenceGroup,
+        chunk: Optional[Tuple[int, int, bool]] = None,
+        prompt_run: bool = False,
+    ) -> SequenceGroupMetadata:
+        """The runner's payload for one group's running sequences: a
+        decode entry, or (with `chunk` / `prompt_run`) a prefill one."""
+        seq_data: Dict[int, SequenceData] = {}
+        block_tables: Dict[int, List[int]] = {}
+        for seq in seq_group.get_seqs(status=SequenceStatus.RUNNING):
+            seq_data[seq.seq_id] = seq.data
+            block_tables[seq.seq_id] = (
+                self.block_manager.get_block_table(seq))
+        return SequenceGroupMetadata(
+            request_id=seq_group.request_id,
+            is_prompt=True if chunk is not None else prompt_run,
+            seq_data=seq_data,
+            sampling_params=seq_group.sampling_params,
+            block_tables=block_tables,
+            lora_request=seq_group.lora_request,
+            prefix=seq_group.prefix,
+            token_chunk_size=chunk[1] if chunk is not None else None,
+            num_computed_tokens=chunk[0] if chunk is not None else 0,
+        )
 
     def fork_seq(self, parent_seq: Sequence, child_seq: Sequence) -> None:
         self.block_manager.fork(parent_seq, child_seq)
@@ -1134,14 +1152,11 @@ class Scheduler:
             if seq is not None:
                 self.block_manager.free(seq)
 
-    def can_continue_decode(self) -> bool:
-        """Whether the current decode batch may be extended in place (same
-        rows, host state lagging) without a fresh scheduling pass: nothing
-        waiting for admission, nothing swapped out awaiting swap-in, and
-        no resident sequence mid-prefill (its next chunk needs a fresh
-        mixed scheduling pass)."""
-        return (not self.waiting and not self.swapped
-                and not any(self._is_prefilling(sg) for sg in self.running))
+    def mid_prefill(self) -> bool:
+        """Whether a resident sequence is part-way through its prompt:
+        its next chunk needs a fresh mixed scheduling pass, so the
+        running decode batch may not be continued past it."""
+        return any(self._is_prefilling(sg) for sg in self.running)
 
     # --- internals -------------------------------------------------------
 

@@ -16,7 +16,9 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Dict, Iterable, List, Optional, Tuple, Union
+
+import numpy as np
+from typing import Dict, Iterable, List, Optional, Set, Tuple, Union
 
 from intellillm_tpu.config import (CacheConfig, LoRAConfig, ModelConfig,
                                    ParallelConfig, SchedulerConfig)
@@ -45,6 +47,22 @@ from intellillm_tpu.worker.worker import Worker
 logger = init_logger(__name__)
 
 _LOG_STATS_INTERVAL = 5.0  # seconds
+
+# step_pipelined: prefill-only steps that may be in flight per decode step
+# in flight. One throttles admission: a ~410-token prompt step a 94 ms
+# decode period admitted 4.3k prompt tokens/s where 128 closed-loop
+# clients ask ~6k, 28 % of the seats stood empty and out_tok_s fell 6.5 %
+# (PERF.md section 6, PR 29, chip run p29a); two kept every seat taken.
+_PROMPT_STEPS_PER_DECODE_STEP = 2
+# step_pipelined: continuations in a row whose rows would fit a smaller
+# batch bucket before the loop drains to re-bucket them. A drain costs
+# about one host pass with the device dry; a half-empty bucket costs a
+# share of every step.
+_REBUCKET_AFTER_CONTS = 4
+# Why a prefill-only scheduling pass admitted nothing
+# (SchedulerOutputs.blocked) -> the cause of the drain it asks for.
+_ADMISSION_DRAINS = {"kv_watermark": "memory",
+                     "needs_chunking": "chunked_prefill"}
 
 
 class LLMEngine:
@@ -214,19 +232,24 @@ class LLMEngine:
         # Speculative decoding owns its own dispatch pattern (draft +
         # teacher-forced verify per step) — no pipelined continuations.
         # Chunked mode pipelines too: steady-state decode runs the fused
-        # continuation programs, and mixed steps (any sequence mid-
-        # prefill) force a fresh schedule via can_continue_decode.
+        # continuation programs, and a sequence mid-prefill over several
+        # chunks forces a fresh (mixed) schedule.
         self.pipeline_enabled = (pipeline_enabled_env()
                                  and speculative_config is None)
         self._pipeline_depth = max(
             1, int(_os.environ.get("INTELLILLM_PIPELINE_DEPTH", "2")))
         self._inflight: deque = deque()
         self._pending_outputs: List[RequestOutput] = []
-        # Joiner tracking: prompts admitted mid-pipeline produce sequences
-        # that only join decode at the next fresh schedule; conts past
-        # them are capped (see _cont_budget_ok).
-        self._joiners_pending = False
-        self._conts_past_prompt = 0
+        # Sequences that have been given a row of a decode batch (fresh
+        # or by continuation) and are not known to be finished: a running
+        # sequence outside this set is a joiner (see _dispatch_cont).
+        self._decode_members: Set[int] = set()
+        # Why the pipeline is draining to a fresh schedule, if it is
+        # (intellillm_pipeline_drains_total{cause}).
+        self._drain_cause: Optional[str] = None
+        # Consecutive continuations whose rows would have fitted a
+        # smaller batch bucket than the one in flight.
+        self._roomy_conts = 0
 
         # Stall watchdog (obs/watchdog.py): heartbeat at every step
         # boundary; the monitor thread uses these callbacks to decide
@@ -816,23 +839,39 @@ class LLMEngine:
     # step() is strictly serial: schedule → dispatch → fetch → process.
     # The fetch and host post-processing (detokenize, stop checks,
     # streaming) serialize with device compute, so the chip idles for
-    # the host's share of every step (how large that share is on the
-    # chip: not measured).
-    # step_pipelined() keeps up to `depth` device steps dispatched but
-    # unfetched:
+    # the host's share of every step.
+    # step_pipelined() keeps up to `depth` DECODE steps dispatched but
+    # unfetched, and finalizes the oldest step in flight:
     #   - decode→decode: a continuation program slices its input tokens
     #     from the previous step's ON-DEVICE packed output, so the host
     #     never needs step N's results to dispatch step N+1 (the host's
     #     view of sequence state intentionally trails the device);
     #   - prompt admission chains on the in-flight cache futures (XLA
-    #     executes enqueued programs in order), so a new request never
-    #     waits for the pipeline to drain before its prefill starts;
-    #   - anything that needs a coherent host view (swap, preemption,
-    #     beam, penalties, K=1 batches) drains the pipeline first.
+    #     executes enqueued programs in order) as a prefill-only step,
+    #     at most _PROMPT_STEPS_PER_DECODE_STEP of them per decode step
+    #     in flight, so a new request never waits for the pipeline to
+    #     drain before its prefill starts and a decode step is always
+    #     queued behind a prompt step; a prompt that only waits for a
+    #     seat does not stop decode;
+    #   - an admitted sequence JOINS the running decode batch at the next
+    #     continuation: each continuation is dispatched from a re-snapshot
+    #     of the row set (_dispatch_cont) in which rows the host knows to
+    #     be done (max_tokens / max_model_len covered by dispatched steps,
+    #     or seen finished) give up their slot and sequences whose
+    #     prefill has been fetched take free slots of the compiled batch
+    #     bucket, their first token passed from the host;
+    #   - what needs a coherent host view drains the pipeline to a full
+    #     schedule(), counted by cause: no decode step in flight (idle),
+    #     swap / preemption / a refused block growth (memory), a joiner
+    #     the fused program excludes or a K=1 batch (params), a sequence
+    #     mid-prefill over several chunks (chunked_prefill), more rows
+    #     than the batch bucket in flight was compiled for, or few enough
+    #     for a smaller one _REBUCKET_AFTER_CONTS times running (bucket).
     # KV pages referenced by in-flight steps are free-guarded in the
     # scheduler: a sequence finishing host-side mid-pipeline stays a
-    # "zombie" row (its outputs are overshoot, discarded) and its pages
-    # are released only once the last referencing step is fetched.
+    # "zombie" row of the steps already dispatched (its outputs are
+    # overshoot, discarded) and its pages are released only once the
+    # last referencing step is fetched.
 
     def has_inflight(self) -> bool:
         return bool(self._inflight)
@@ -842,9 +881,8 @@ class LLMEngine:
         as the pipeline depth allows, then fetches + processes the oldest
         in-flight step. Returns [] only when fully idle."""
         self._tracer.begin_step()
-        while len(self._inflight) < self._pipeline_depth:
-            if not self._pipeline_dispatch_one():
-                break
+        while self._pipeline_dispatch_one():
+            pass
         if not self._inflight:
             pending, self._pending_outputs = self._pending_outputs, []
             return pending
@@ -856,10 +894,25 @@ class LLMEngine:
             outs.extend(self._finalize_one())
         return outs
 
+    def _drain(self, cause: str) -> bool:
+        """Nothing more may be dispatched until the pipeline is empty and
+        a full schedule() has run; the first cause of a drain names it."""
+        if self._drain_cause is None:
+            self._drain_cause = cause
+        return False
+
     def _pipeline_dispatch_one(self) -> bool:
+        """Dispatch one more step if the pipeline has room for it; False
+        when the next thing to do is to finalize the oldest step."""
         sched = self.scheduler
-        # New prompts admit immediately, chained behind in-flight steps.
-        if sched.waiting and not sched.swapped:
+        seed = self._newest_decode_inflight()
+        decodes = sum(s.cont_state is not None for s in self._inflight)
+        others = len(self._inflight) - decodes
+        # New prompts admit immediately, chained behind in-flight steps,
+        # a bounded number per decode step in flight, so that a burst of
+        # prompts cannot hold decode back by more than that.
+        if (sched.waiting and not sched.swapped
+                and others < (decodes or 1) * _PROMPT_STEPS_PER_DECODE_STEP):
             metas, so = sched.schedule(prefill_only=True)
             if so.ignored_seq_groups and not metas:
                 # Rejected without device work (over-long prompts):
@@ -871,18 +924,34 @@ class LLMEngine:
             if metas:
                 self._dispatch(metas, so)
                 return True
-            if self._inflight:
-                return False  # memory-blocked: drain, then full schedule
-        elif (self._inflight and sched.running
-                and sched.can_continue_decode()
-                and self._cont_budget_ok()):
-            if self._dispatch_cont():
-                return True
-            return False  # out of blocks for in-place growth: drain
-        if self._inflight:
+            if self._inflight and so.blocked in _ADMISSION_DRAINS:
+                # What only a full scheduling pass on an empty pipeline
+                # resolves. Seats, adapter slots or a tenant's share
+                # running out is not: decode goes on, and a later
+                # prefill-only pass admits when a request has finished.
+                return self._drain(_ADMISSION_DRAINS[so.blocked])
+        if not self._inflight:
+            return self._dispatch_fresh()
+        if seed is None:
+            # Only prompt steps (start, idle engine) in flight, or a step
+            # of a program that cannot be continued (_dispatch named it).
+            return self._drain("idle")
+        if self._inflight[0].cont_state is None:
+            # A prompt step is the oldest in flight and a decode step is
+            # queued behind it: fetch it first, so that its sequences
+            # join the continuation dispatched next.
             return False
-        # Pipeline empty: full scheduling pass (may swap/preempt).
-        metas, so = sched.schedule()
+        if decodes >= self._pipeline_depth:
+            return False
+        if sched.swapped:
+            return self._drain("memory")
+        if sched.mid_prefill():
+            return self._drain("chunked_prefill")
+        return self._dispatch_cont(seed)
+
+    def _dispatch_fresh(self) -> bool:
+        """Pipeline empty: full scheduling pass (may swap/preempt)."""
+        metas, so = self.scheduler.schedule()
         if so.is_empty() and not metas:
             if so.ignored_seq_groups:
                 self._pending_outputs.extend(
@@ -915,87 +984,187 @@ class LLMEngine:
         )
         seq_ids = [sid for m in metas for sid in m.seq_data]
         self.scheduler.guard_seqs(seq_ids)
+        groups = scheduler_outputs.scheduled_seq_groups
+        if not scheduler_outputs.prompt_run:
+            # A fresh schedule merged every running sequence into this
+            # step's decode rows, after a drain.
+            rows = {sid for m in metas if m.token_chunk_size is None
+                    for sid in m.seq_data}
+            self._efficiency.record_decode_join(
+                "fresh", len(rows - self._decode_members))
+            self._decode_members = rows
+            self._efficiency.record_pipeline_drain(
+                self._drain_cause or "idle")
+            self._drain_cause = None
+            self._roomy_conts = 0
+            if step.cont_state is None:
+                # No program continues this step: the scheduler clamped
+                # the batch to K=1, or a sequence is mid-prefill.
+                self._drain("chunked_prefill" if scheduler_outputs.is_mixed
+                            else "params")
         if step.cont_state is not None:
-            step.cont_state.groups = scheduler_outputs.scheduled_seq_groups
+            owner = {seq.seq_id: (seq, meta, group)
+                     for meta, group in zip(metas, groups)
+                     for seq in group.get_seqs()}
+            step.cont_state.owners = [owner[sid]
+                                      for _, sid in step.cont_state.rows]
         step._pipeline_seq_ids = seq_ids
         step._pipeline_sched = scheduler_outputs
-        if scheduler_outputs.prompt_run:
-            self._joiners_pending = True
-            self._conts_past_prompt = 0
-        else:
-            # A fresh decode schedule merged every running sequence.
-            self._joiners_pending = False
-            self._conts_past_prompt = 0
         self._inflight.append(step)
 
     def _newest_decode_inflight(self):
         """The newest in-flight entry that can seed a continuation — it
         need not be the pipeline tail: prompt admissions interleave, and
         a continuation chained PAST a prefill is legal (the prefill
-        touches disjoint pages, and the cont's row snapshot predates the
-        new sequences, which join at the next fresh schedule)."""
+        touches disjoint pages)."""
         for step in reversed(self._inflight):
             if step.cont_state is not None:
                 return step
         return None
 
-    def _cont_budget_ok(self) -> bool:
-        """At most one continuation may be dispatched past un-merged
-        prompt admissions: freshly admitted sequences have their first
-        token (from prefill) but join decode only at the next fresh
-        schedule — unbounded conts would starve their TPOT."""
-        if self._newest_decode_inflight() is None:
-            return False
-        if not self._joiners_pending:
-            return True
-        return self._conts_past_prompt < 1
+    @staticmethod
+    def _fused_excludes(sp: SamplingParams) -> bool:
+        """Sampling the fused K>1 decode program does not take: such a
+        sequence enters decode through a fresh schedule (which forks
+        best_of groups copy-on-write, or clamps the batch to K=1)."""
+        return bool(sp.best_of > 1 or sp.use_beam_search
+                    or sp.presence_penalty or sp.frequency_penalty
+                    or sp.repetition_penalty != 1.0
+                    or sp.logits_processors)
 
-    def _dispatch_cont(self) -> bool:
-        prev = self._newest_decode_inflight()
-        cont = prev.cont_state
-        k = cont.num_steps
-        lag = cont.steps_dispatched
-        mml = self.model_config.max_model_len
-        bm = self.scheduler.block_manager
-        # A continuation is pure overshoot if every row's token budget is
-        # already covered by the dispatched-but-unfetched steps — the
-        # host KNOWS max_tokens and the model-length cap even though it
-        # hasn't seen the tokens yet (EOS/stops stay unpredictable; those
-        # rows still justify speculative continuation). The offline shape
-        # max_tokens == K would otherwise waste an entire fused call per
-        # batch.
-        any_needed = False
-        for i in range(len(cont.rows)):
-            ctx_i = int(cont.ctx0[i])
-            if ctx_i == 0:
-                continue
-            mt = cont.row_params[i].max_tokens
-            if ((mt is None or cont.out_lens0[i] + lag < mt)
-                    and ctx_i + lag < mml):
-                any_needed = True
-                break
-        if not any_needed:
+    def _dispatch_cont(self, prev) -> bool:
+        """Continue the newest decode step `prev` by one fused step over a
+        re-snapshot of its row set."""
+        with self._tracer.span("schedule"):
+            plan = self._resnapshot(prev)
+        if plan is None:
             return False
-        targets = [(sid, min(int(cont.ctx0[i]) + lag + k - 1, mml))
-                   for i, (_, sid) in enumerate(cont.rows)]
-        if not bm.can_grow_all(targets):
-            return False
-        tables = [bm.grow_to(sid, target) for sid, target in targets]
-        step = self.worker.execute_decode_cont(cont, lag, tables,
+        cont, tables, join_tokens, groups, joined = plan
+        step = self.worker.execute_decode_cont(cont, tables, join_tokens,
                                                prev.packed, prev.t1)
-        cont.steps_dispatched += k
-        if self._joiners_pending:
-            self._conts_past_prompt += 1
+        self._decode_members.update(joined)
+        self._efficiency.record_decode_join("cont", len(joined))
+        self._drain_cause = None
         seq_ids = [sid for _, sid in cont.rows]
         self.scheduler.guard_seqs(seq_ids)
         step._pipeline_seq_ids = seq_ids
         step._pipeline_sched = SchedulerOutputs(
-            scheduled_seq_groups=cont.groups, prompt_run=False,
-            num_batched_tokens=len(cont.rows), blocks_to_swap_in={},
+            scheduled_seq_groups=groups, prompt_run=False,
+            num_batched_tokens=len(seq_ids), blocks_to_swap_in={},
             blocks_to_swap_out={}, blocks_to_copy={},
-            ignored_seq_groups=[], num_decode_steps=k)
+            ignored_seq_groups=[], num_decode_steps=cont.num_steps)
         self._inflight.append(step)
         return True
+
+    def _resnapshot(self, prev) -> Optional[tuple]:
+        """The row set of the step that continues `prev`, computed
+        numerically: the host has not seen the tokens of the steps in
+        flight, but it knows every row's budget. None when no
+        continuation may be dispatched now (a drain names why, if the
+        reason is one)."""
+        from intellillm_tpu.worker.model_runner import (PAD_PARAMS,
+                                                         DecodeContState)
+        sched = self.scheduler
+        pc = prev.cont_state
+        k = pc.num_steps
+        mml = self.model_config.max_model_len
+        b = pc.ctx0.shape[0]
+
+        # Rows kept: those the dispatched steps do not finish for sure.
+        # max_tokens and the model-length cap are known ahead of the
+        # tokens; EOS / stop strings are not, and a row that may have
+        # stopped stays (a zombie) until a finalize has shown it.
+        ctx = np.zeros(b, np.int32)
+        out_lens = np.zeros(b, np.int64)
+        owners: List[Optional[tuple]] = [None] * b   # (seq, meta, group)
+        params = [PAD_PARAMS] * b
+        loras = None if pc.row_loras is None else [None] * b
+        for slot, owner in zip(pc.slots, pc.owners):
+            if owner[0].is_finished():
+                continue
+            mt = pc.row_params[slot].max_tokens
+            if ((mt is not None and pc.out_lens0[slot] + k >= mt)
+                    or pc.ctx0[slot] + k > mml):
+                continue
+            ctx[slot] = pc.ctx0[slot] + k
+            out_lens[slot] = pc.out_lens0[slot] + k
+            owners[slot] = owner
+            params[slot] = pc.row_params[slot]
+            if loras is not None:
+                loras[slot] = pc.row_loras[slot]
+
+        # Joiners: running sequences no decode batch has taken yet, whose
+        # prefill step has been fetched (its token is on the host). One
+        # whose prefill is still in flight joins a later step.
+        prefilling = {sid for step in self._inflight
+                      if step.cont_state is None
+                      for sid in step._pipeline_seq_ids}
+        alive: Set[int] = set()
+        joiners: List[Tuple[Sequence, SequenceGroup]] = []
+        for group in sched.running:
+            for seq in group.get_seqs(status=SequenceStatus.RUNNING):
+                alive.add(seq.seq_id)
+                if (seq.seq_id in self._decode_members
+                        or seq.seq_id in prefilling):
+                    continue
+                if self._fused_excludes(group.sampling_params):
+                    self._drain("params")
+                    return None
+                joiners.append((seq, group))
+        free = np.flatnonzero(ctx == 0)
+        if len(joiners) > len(free):
+            self._drain("bucket")
+            return None
+        join_tokens = np.full(b, -1, np.int64)
+        for slot, (seq, group) in zip(free, joiners):
+            ctx[slot] = seq.get_len()
+            out_lens[slot] = seq.get_output_len()
+            owners[slot] = (seq, sched.group_metadata(group), group)
+            params[slot] = group.sampling_params
+            if loras is not None:
+                loras[slot] = group.lora_request
+            join_tokens[slot] = seq.get_last_token_id()
+        slots = np.flatnonzero(ctx)
+        if not len(slots):
+            # Every row's budget is covered by the steps in flight and
+            # nobody waits to join: a continuation would be pure
+            # overshoot (the offline shape max_tokens == K would waste a
+            # whole fused call per batch).
+            return None
+        # A continuation inherits the batch bucket in flight. When the
+        # rows have fitted a smaller one for a few snapshots running
+        # (load fell), a fresh schedule re-buckets them.
+        if self.worker.model_runner.decode_batch_bucket(len(slots)) < b:
+            self._roomy_conts += 1
+            if self._roomy_conts >= _REBUCKET_AFTER_CONTS:
+                self._drain("bucket")
+                return None
+        else:
+            self._roomy_conts = 0
+
+        bm = sched.block_manager
+        targets = [(owners[slot][0].seq_id, min(int(ctx[slot]) + k - 1, mml))
+                   for slot in slots]
+        if not bm.can_grow_all(targets):
+            self._drain("memory")
+            return None
+        tables = [bm.grow_to(sid, target) for sid, target in targets]
+
+        # One metadata entry per group holding a row, in slot order.
+        cont = DecodeContState([], [], slots, ctx, out_lens, params, loras,
+                               k)
+        cont.owners = [owners[slot] for slot in slots]
+        groups: List[SequenceGroup] = []
+        listed: Set[str] = set()
+        for seq, meta, group in cont.owners:
+            cont.rows.append((group.request_id, seq.seq_id))
+            if group.request_id not in listed:
+                listed.add(group.request_id)
+                cont.metas.append(meta)
+                groups.append(group)
+        self._decode_members &= alive
+        return (cont, tables, join_tokens, groups,
+                [seq.seq_id for seq, _ in joiners])
 
     def _finalize_one(self) -> List[RequestOutput]:
         step = self._inflight.popleft()

@@ -62,6 +62,10 @@ PHASES = ("prefill", "decode")
 # device was doing when the host dispatched one.
 PROGRAMS = ("mixed", "decode_fused", "decode_cont")
 DEVICE_STATES = ("idle", "busy")
+# How a row entered a decode batch, and why the step pipeline drained to
+# a fresh schedule (engine/llm_engine.py step_pipelined).
+JOIN_VIAS = ("cont", "fresh")
+DRAIN_CAUSES = ("memory", "params", "chunked_prefill", "bucket", "idle")
 TOKEN_KINDS = ("real", "pad")
 AXES = ("batch", "len", "block_width")
 _DEFAULT_MFU_WINDOW = 64
@@ -142,6 +146,18 @@ class _EfficiencyMetrics:
             "than its router has outputs counts here; here / (here + "
             "absent) is held experts / router width in expectation.",
             ["held"])
+        self.counter_decode_join_rows = Counter(
+            "intellillm_decode_join_rows_total",
+            "Rows that entered a decode batch, by how (via: cont = took a "
+            "free slot of the running batch at a continuation; fresh = "
+            "merged by a full schedule after the pipeline drained).",
+            ["via"])
+        self.counter_pipeline_drains = Counter(
+            "intellillm_pipeline_drains_total",
+            "Full scheduling passes that dispatched decode rows with the "
+            "step pipeline empty, by what emptied it (cause: memory | "
+            "params | chunked_prefill | bucket | idle = no decode step was "
+            "in flight to continue).", ["cause"])
         # Pre-create the label children so the series exist (at 0) from
         # the first scrape, before any dispatch happens: a ratio over a
         # series that never appeared reads as "no answer", not as 0.
@@ -154,6 +170,10 @@ class _EfficiencyMetrics:
             self.counter_dispatch_row_steps.labels(program)
             self.counter_dispatch_context_tokens.labels(program)
             self.counter_dispatch_walked_tokens.labels(program)
+        for via in JOIN_VIAS:
+            self.counter_decode_join_rows.labels(via)
+        for cause in DRAIN_CAUSES:
+            self.counter_pipeline_drains.labels(cause)
 
     @classmethod
     def reset_for_testing(cls) -> None:
@@ -264,6 +284,9 @@ class EfficiencyTracker:
                 DEVICE_STATES + ("row_steps", "context_tokens",
                                  "walked_tokens"), 0)
             for program in PROGRAMS}
+        self._decode_joins: Dict[str, int] = dict.fromkeys(JOIN_VIAS, 0)
+        self._pipeline_drains: Dict[str, int] = dict.fromkeys(
+            DRAIN_CAUSES, 0)
         # (phase, axis) -> [sum of fill ratios, observations]
         self._fill: Dict[Tuple[str, str], List[float]] = {}
         # (phase, batch_bucket, inner_bucket) -> cumulative waste row;
@@ -438,6 +461,27 @@ class EfficiencyTracker:
             for axis, ratio in fills:
                 m.hist_fill_ratio.labels(phase, axis).observe(ratio)
 
+    def record_decode_join(self, via: str, rows: int) -> None:
+        """`rows` sequences were given a row of a decode batch for the
+        first time: by a continuation's re-snapshot (`cont`) or by a
+        full schedule after a drain (`fresh`)."""
+        if not self.enabled or not rows:
+            return
+        with self._lock:
+            self._decode_joins[via] += int(rows)
+        if self._metrics is not None:
+            self._metrics.counter_decode_join_rows.labels(via).inc(rows)
+
+    def record_pipeline_drain(self, cause: str) -> None:
+        """A full scheduling pass dispatched decode rows: the pipeline
+        had drained for `cause`."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self._pipeline_drains[cause] += 1
+        if self._metrics is not None:
+            self._metrics.counter_pipeline_drains.labels(cause).inc()
+
     def record_moe_assignments(self, here: int, total: int) -> None:
         """Token x expert choices of a finished step: `here` went to
         experts this chip holds, `total - here` to experts held
@@ -552,6 +596,8 @@ class EfficiencyTracker:
                 "dispatches": dict(self._dispatches),
                 "programs": {program: dict(counts) for program, counts
                              in self._programs.items()},
+                "decode_join_rows": dict(self._decode_joins),
+                "pipeline_drains": dict(self._pipeline_drains),
                 "co_batched_steps": self._co_batched_steps,
                 "warmup_excluded_dispatches": self._warmup_excluded,
                 "top_waste": buckets[:top_n],

@@ -10,7 +10,9 @@ where the work happens:
                     loop thread)
     handoff         run_in_executor call -> step entered with the KV lock
                     held: executor queueing + lock wait
-    schedule        scheduler pass + metadata build (core/scheduler.py)
+    schedule        scheduler pass + metadata build (core/scheduler.py),
+                    and the row re-snapshot of a decode continuation
+                    (llm_engine._resnapshot)
     prepare_inputs  host batch prep + sampling tensors (model_runner)
     execute         jit dispatch of the device step (model_runner)
     fetch_wait      the blocking device->host copies of a step's results:

@@ -65,29 +65,38 @@ _SEED_STRIDE = np.uint32(0x9E3779B9)  # per-substep seed fold
 
 
 class DecodeContState:
-    """Row snapshot of a fused decode batch, enabling in-place continuation
-    steps whose input tokens come from the PREVIOUS step's on-device
-    output (pipelined decode: dispatch step N+1 before fetching step N,
-    hiding the device→host fetch latency behind device compute).
+    """Row snapshot of ONE dispatched fused decode step, from which the
+    engine derives the next continuation's snapshot (pipelined decode:
+    dispatch step N+1 before fetching step N, hiding the device→host
+    fetch latency behind device compute).
 
     Host sequence state lags the device by the un-fetched steps, so the
-    snapshot carries everything a continuation needs numerically:
-    context lengths / output lengths at the fresh dispatch, row order,
-    params. Rows whose sequence finishes host-side mid-pipeline stay in
-    the batch as zombies (their outputs are overshoot, discarded by the
-    engine; their KV pages are free-guarded by the scheduler)."""
+    snapshot carries everything the next step needs numerically: context
+    and output lengths at THIS step's start, the slot each row holds in
+    the padded batch, params. The engine re-snapshots per continuation
+    (engine/llm_engine.py `_dispatch_cont`): rows the host knows to be
+    done give up their slot, admitted sequences take free slots, and a
+    row that may have stopped on a token the host has not seen stays as
+    a zombie (its outputs are overshoot, discarded by the engine; its KV
+    pages are free-guarded by the scheduler)."""
 
-    def __init__(self, metas, rows, ctx0, out_lens0, row_params, row_loras,
-                 num_steps):
-        self.metas = metas              # original metadata list (reused)
-        self.rows = rows                # [(request_id, seq_id)] row order
-        self.ctx0 = ctx0                # np [B] padded ctx at fresh prep
-        self.out_lens0 = out_lens0      # per live row output len at prep
-        self.row_params = row_params
-        self.row_loras = row_loras
+    def __init__(self, metas, rows, slots, ctx0, out_lens0, row_params,
+                 row_loras, num_steps):
+        self.metas = metas              # one per group holding a live row
+        self.rows = rows                # [(request_id, seq_id)] by slot
+        self.slots = slots              # np [len(rows)]: each row's slot
+        self.ctx0 = ctx0                # np [B] ctx at this step's start
+        self.out_lens0 = out_lens0      # np [B] output len at its start
+        self.row_params = row_params    # per slot (PAD_PARAMS when empty)
+        self.row_loras = row_loras      # per slot, or None (no adapters)
         self.num_steps = num_steps      # K of the fused program
-        self.groups = None              # engine fills: scheduled groups
-        self.steps_dispatched = num_steps  # device steps since fresh prep
+        self.owners = None              # engine fills, per row:
+        #                                 (Sequence, its meta, its group)
+
+
+# What an empty slot of a continuation samples with: greedy, so that an
+# all-greedy batch keeps the no-Gumbel program (SamplingTensors.build).
+PAD_PARAMS = SamplingParams(temperature=0.0)
 
 
 class InflightStep:
@@ -500,17 +509,22 @@ class ModelRunner:
                          do_random=do_random)
         return out + (fetched, plp_out, num_stats)
 
-    def _decode_cont_fn(self, params, kv_caches, prev_packed, positions,
-                        block_tables, context_lens, temperatures, top_ks,
-                        top_ps, min_ps, seeds, pres_pen, freq_pen, rep_pen,
-                        prompt_tokens, output_tokens, lora=None, *,
-                        prev_t1, num_steps, logprob_k, do_topk, do_topp,
-                        do_minp, do_penalties, do_random=True):
-        """Continuation of a fused decode: input tokens = the last substep's
-        samples from the previous step's packed output (column prev_t1-1 of
-        the _pack layout), so the host never needs the previous step's
-        results to keep the device busy."""
-        token_ids = prev_packed[:, prev_t1 - 1:prev_t1]
+    def _decode_cont_fn(self, params, kv_caches, prev_packed, join_tokens,
+                        join_mask, positions, block_tables, context_lens,
+                        temperatures, top_ks, top_ps, min_ps, seeds,
+                        pres_pen, freq_pen, rep_pen, prompt_tokens,
+                        output_tokens, lora=None, *, prev_t1, num_steps,
+                        logprob_k, do_topk, do_topp, do_minp, do_penalties,
+                        do_random=True):
+        """Continuation of a fused decode: a row's input token is the last
+        substep's sample from the previous step's packed output (column
+        prev_t1-1 of the _pack layout), so the host never needs the
+        previous step's results to keep the device busy. A row that joins
+        the batch at this step (`join_mask` [B, 1]) reads its prefill's
+        token from `join_tokens` [B, 1] instead; a plain continuation is
+        the all-false mask of the same program."""
+        token_ids = jnp.where(join_mask, join_tokens,
+                              prev_packed[:, prev_t1 - 1:prev_t1])
         return self._decode_fn(
             params, kv_caches, token_ids, positions, block_tables,
             context_lens, temperatures, top_ks, top_ps, min_ps, seeds,
@@ -769,6 +783,10 @@ class ModelRunner:
                 w)
             for base in range(0, num_steps, chunk))
 
+    def decode_batch_bucket(self, rows: int) -> int:
+        """The padded batch a fresh decode step of `rows` rows runs at."""
+        return pad_to_bucket(rows, self.mixed_token_buckets)
+
     def _prepare_decode(
         self,
         seq_group_metadata_list: List[SequenceGroupMetadata],
@@ -789,7 +807,7 @@ class ModelRunner:
                 ctxs.append(n)
                 tables.append(list(meta.block_tables[seq_id]))
 
-        b = pad_to_bucket(len(rows), self.mixed_token_buckets)
+        b = self.decode_batch_bucket(len(rows))
         w = pad_to_bucket(max(max(len(t) for t in tables),
                               _MIN_BLOCK_TABLE_WIDTH),
                           self.mixed_token_buckets)
@@ -1045,10 +1063,15 @@ class ModelRunner:
                   if proc_rows else None),
             numerics=num_stats_dev)
         if num_steps > 1:
+            pad = padded_n - len(rows)
+            out_lens0 = np.zeros(padded_n, np.int64)
+            out_lens0[:len(rows)] = row_out_lens
             step.cont_state = DecodeContState(
-                seq_group_metadata_list, rows,
-                arrays["context_lens"].copy(), row_out_lens, row_params,
-                row_loras, num_steps)
+                seq_group_metadata_list, rows, np.arange(len(rows)),
+                arrays["context_lens"].copy(), out_lens0,
+                row_params + [PAD_PARAMS] * pad,
+                None if row_loras is None else row_loras + [None] * pad,
+                num_steps)
         if defer_fetch:
             return step, new_caches
         return step.finalize(), new_caches
@@ -1297,50 +1320,49 @@ class ModelRunner:
     def execute_decode_cont(
         self,
         cont: DecodeContState,
-        lag: int,
         tables: List[List[int]],
+        join_tokens: np.ndarray,
         prev_packed,
         prev_t1: int,
         kv_caches,
         defer_fetch: bool = True,
     ) -> Tuple[Any, Any]:
-        """Dispatch a continuation step of a fused decode batch: same rows,
-        input tokens sliced on device from `prev_packed`, context lengths
-        advanced numerically by `lag` (the device steps since the fresh
-        prep — the host sequence state is allowed to trail). `tables` are
-        the per-row block tables already grown by the scheduler to cover
-        this step's writes."""
+        """Dispatch a continuation step of a fused decode batch. `cont` is
+        this step's own snapshot (contexts advanced numerically by the
+        engine: the host sequence state is allowed to trail). A row's
+        input token is sliced on device from `prev_packed`, except where
+        `join_tokens` [B] holds one (>= 0): a row that joins at this
+        step. `tables` are the rows' block tables, in `cont.rows` order,
+        already grown by the scheduler to cover this step's writes."""
         num_steps = cont.num_steps
         with self._tracer.span("prepare_inputs"):
             b = cont.ctx0.shape[0]
-            mml = self.max_model_len
-            ctx = np.where(cont.ctx0 > 0,
-                           np.minimum(cont.ctx0 + lag, mml),
-                           0).astype(np.int32)
+            ctx = cont.ctx0.astype(np.int32)
             positions = np.maximum(ctx - 1, 0).astype(np.int32)[:, None]
             w = pad_to_bucket(max(max((len(t) for t in tables), default=1),
                                   _MIN_BLOCK_TABLE_WIDTH),
                               self.mixed_token_buckets)
             block_tables = np.zeros((b, w), np.int32)
-            for i, t in enumerate(tables):
-                block_tables[i, :len(t)] = t
+            for slot, t in zip(cont.slots, tables):
+                block_tables[slot, :len(t)] = t
+            join_mask = (join_tokens >= 0)[:, None]
+            join_tokens = np.maximum(join_tokens, 0).astype(np.int32)[:, None]
 
             # Seeds advance exactly as a fresh (caught-up) dispatch would
             # compute them, so pipelined sampling streams match
             # unpipelined.
             meta_by_req = {m.request_id: m for m in cont.metas}
-            row_seeds = [
-                self._row_seed(sid, cont.out_lens0[i] + lag,
-                               cont.row_params[i],
-                               meta_by_req[req_id].seq_data[sid])
-                for i, (req_id, sid) in enumerate(cont.rows)]
+            row_seeds = [0] * b
+            for slot, (req_id, sid) in zip(cont.slots, cont.rows):
+                row_seeds[slot] = self._row_seed(
+                    sid, int(cont.out_lens0[slot]), cont.row_params[slot],
+                    meta_by_req[req_id].seq_data[sid])
 
             lora_state, eff_vocab = self._activate_lora(cont.row_loras, b)
             st = SamplingTensors.build(cont.row_params, row_seeds, None,
                                        eff_vocab, b)
-            # The scheduler only emits K>1 fused batches for penalty-free,
-            # processor-free, non-beam rows — which is also what makes the
-            # continuation legal in the first place.
+            # The engine only continues penalty-free, processor-free,
+            # non-beam rows: what the fused K>1 program takes.
             assert not st.do_penalties, (
                 "decode continuation dispatched for a penalty-bearing batch")
 
@@ -1349,18 +1371,21 @@ class ModelRunner:
         flags = dict(logprob_k=st.logprob_k, do_topk=st.do_topk,
                      do_topp=st.do_topp, do_minp=st.do_minp,
                      do_penalties=False, do_random=st.do_random)
+        # A joined continuation is the same executable as a plain one:
+        # the join arrays are always passed, their shapes follow `b`.
         bucket = (b, w, prev_t1, num_steps, lora_state is not None,
                   tuple(sorted(flags.items())))
         device_idle = self._device_idle()
         with self._tracer.span("execute"):
             packed, new_caches = self._guarded_call(
                 "decode_cont", bucket, self._jit_decode_cont,
-                self.params, kv_caches, prev_packed, place(positions),
-                place(block_tables), place(ctx), *sampling_args, lora_state,
+                self.params, kv_caches, prev_packed, place(join_tokens),
+                place(join_mask), place(positions), place(block_tables),
+                place(ctx), *sampling_args, lora_state,
                 prev_t1=prev_t1, num_steps=num_steps, **flags)
         self._last_packed = packed
 
-        live_rows = int((cont.ctx0 > 0).sum())
+        live_rows = len(cont.rows)
         self._efficiency.record_dispatch(
             "decode", live_rows, b,
             real_tokens=live_rows * num_steps,
@@ -1374,7 +1399,8 @@ class ModelRunner:
             walked_tokens=self._decode_walked_tokens(ctx, num_steps, w))
 
         step = InflightStep(self, packed, cont.metas, cont.rows, num_steps,
-                            num_steps, st.logprob_k, False, num_steps)
+                            num_steps, st.logprob_k, False, num_steps,
+                            emit=(cont.slots, cont.rows))
         step.cont_state = cont
         if defer_fetch:
             return step, new_caches

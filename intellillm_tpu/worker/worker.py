@@ -381,11 +381,14 @@ class Worker:
                             # shapes, tokens sliced from the previous
                             # step's packed output (which the fused
                             # warm-up call above just produced with
-                            # exactly the runtime shape/dtype).
+                            # exactly the runtime shape/dtype), and the
+                            # joiners' token / mask columns.
                             packed, caches = runner._jit_decode_cont(
                                 self.params,
                                 self.cache_engine.device_cache,
-                                packed, *args[1:], prev_t1=k,
+                                packed, zeros_i,
+                                place(np.zeros((b, 1), np.bool_)),
+                                *args[1:], prev_t1=k,
                                 num_steps=k, **flags)
                             self.cache_engine.device_cache = caches
                             n += 1
@@ -437,12 +440,12 @@ class Worker:
         self.cache_engine.device_cache = new_caches
         return outputs
 
-    def execute_decode_cont(self, cont, lag: int, tables, prev_packed,
+    def execute_decode_cont(self, cont, tables, join_tokens, prev_packed,
                             prev_t1: int):
         """Dispatch a pipelined decode continuation (no swaps/copies — the
         engine only continues batches with no pending block ops)."""
         step, new_caches = self.model_runner.execute_decode_cont(
-            cont, lag, tables, prev_packed, prev_t1,
+            cont, tables, join_tokens, prev_packed, prev_t1,
             self.cache_engine.device_cache, defer_fetch=True)
         self.cache_engine.device_cache = new_caches
         return step

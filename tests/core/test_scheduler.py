@@ -252,3 +252,30 @@ def test_preemption_victim_prices_with_p90_when_available():
     metas, _ = s.schedule()
     assert [m.request_id for m in metas] == ["0", "1"]
     assert g3.get_seqs()[0].status == SequenceStatus.WAITING
+
+
+@pytest.mark.parametrize("case, expected", [
+    ("seats", "max_seqs"),
+    ("pool", "kv_watermark"),
+    ("long_prompt", "needs_chunking"),
+    ("admits", None),
+])
+def test_prefill_only_pass_says_why_it_stopped(case, expected):
+    """A prefill-only pass (the pipelined loop's admission) reports why it
+    stopped admitting in `SchedulerOutputs.blocked`: the loop drains for a
+    full pool or a prompt that needs chunking, not for full seats."""
+    cache_config = CacheConfig(block_size=4, swap_space_gib=0.001)
+    cache_config.num_device_blocks = 7 if case == "pool" else 64
+    cache_config.num_cpu_blocks = 8
+    s = Scheduler(SchedulerConfig(
+        max_num_batched_tokens=16, max_model_len=64, max_paddings=256,
+        max_num_seqs=1 if case == "seats" else 8,
+        enable_chunked_prefill=True), cache_config)
+    first, _ = add_request(s, "0", 16 if case == "pool" else 6)
+    metas, out = s.schedule(prefill_only=True)
+    assert [m.request_id for m in metas] == ["0"] and out.blocked is None
+    append_token(first)
+    add_request(s, "1", {"pool": 16, "long_prompt": 24}.get(case, 6))
+    metas, out = s.schedule(prefill_only=True)
+    assert out.blocked == expected
+    assert bool(metas) == (expected is None)

@@ -7,10 +7,58 @@ Outputs must match the serial step() loop token-for-token: greedy results
 are schedule-independent, and sampling seeds are keyed per
 (sequence, output-position) so pipelining cannot change random streams
 either.
+
+An admitted sequence joins the running decode batch at the next
+continuation (a re-snapshot of the row set: rows the host knows to be
+done give up their slot, joiners take free ones); what needs a coherent
+host view still drains to a fresh schedule, counted by cause.
 """
 import pytest
 
 from intellillm_tpu import LLM, SamplingParams
+from intellillm_tpu.engine.llm_engine import _PROMPT_STEPS_PER_DECODE_STEP
+from intellillm_tpu.obs import get_efficiency_tracker
+
+
+def _params(sampling, i, max_tokens):
+    """Greedy, sampling seeded by the engine, or by the request."""
+    if sampling == "greedy":
+        return SamplingParams(temperature=0.0, max_tokens=max_tokens,
+                              ignore_eos=True)
+    return SamplingParams(temperature=0.8, top_p=0.9, max_tokens=max_tokens,
+                          ignore_eos=True,
+                          seed=1000 + i if sampling == "request_seed"
+                          else None)
+
+
+SAMPLINGS = ("greedy", "engine_seed", "request_seed")
+
+
+def _run_arrivals(llm, requests, observe=None, behind_decode=False):
+    """One request before the first call and one more ahead of every
+    later call (with `behind_decode`, of every call that finds a decode
+    step in flight): admissions interleave with decode in every period."""
+    engine = llm.llm_engine
+    outs = []
+    pending = list(requests)
+    calls = 0
+    engine.add_request(*pending.pop(0))
+    while (engine.has_unfinished_requests() or engine.has_inflight()
+           or pending):
+        if pending and (not behind_decode
+                        or engine._newest_decode_inflight() is not None):
+            engine.add_request(*pending.pop(0))
+        outs.extend(engine.step_pipelined())
+        if observe is not None:
+            observe(engine)
+        calls += 1
+        assert calls < 2000, "pipelined engine made no progress"
+    return _collect(outs)
+
+
+def _dispatches(program):
+    counts = get_efficiency_tracker().snapshot()["programs"][program]
+    return counts["idle"] + counts["busy"]
 
 
 def _build(model_dir, **kw):
@@ -75,37 +123,85 @@ def test_pipelined_matches_serial_greedy(tiny_llama_dir, example_prompts):
     assert all(r[0][2] == "length" for r in got.values())
 
 
-def test_pipelined_staggered_admission(tiny_llama_dir, example_prompts):
-    """Requests added mid-decode are admitted via prefill chaining; the
-    final outputs still match the serial loop."""
-    reqs = [(str(i), p, SamplingParams(temperature=0.0, max_tokens=20,
-                                       ignore_eos=True))
+@pytest.mark.parametrize("sampling", SAMPLINGS)
+def test_pipelined_staggered_admission(tiny_llama_dir, example_prompts,
+                                       sampling):
+    """Requests added mid-decode are admitted via prefill chaining and
+    join the running batch; the final outputs still match the serial
+    loop."""
+    reqs = [(str(i), p, _params(sampling, i, 20))
             for i, p in enumerate(example_prompts)]
     ref = _run_serial(_build(tiny_llama_dir), reqs)
     got = _run_pipelined(_build(tiny_llama_dir), reqs, stagger_after=2)
     assert got == ref
 
 
-def test_pipelined_stops_make_zombie_rows(tiny_opt_dir, example_prompts):
-    """A sequence hitting a stop mid-pipeline becomes a zombie row (its
-    in-flight overshoot is discarded, its KV pages deferred-freed); the
-    surviving sequences finish with serial-identical outputs."""
-    probe = _run_serial(
-        _build(tiny_opt_dir),
-        [("p", example_prompts[0],
-          SamplingParams(temperature=0.0, max_tokens=4))])
-    first_word = probe["p"][0][1].strip().split()[0]
-    params = [
-        SamplingParams(temperature=0.0, max_tokens=32, stop=[first_word]),
-        SamplingParams(temperature=0.0, max_tokens=32, ignore_eos=True),
-        SamplingParams(temperature=0.0, max_tokens=32, ignore_eos=True),
-    ]
-    reqs = [(str(i), p, sp)
-            for i, (p, sp) in enumerate(zip(example_prompts, params))]
-    ref = _run_serial(_build(tiny_opt_dir), reqs)
-    got = _run_pipelined(_build(tiny_opt_dir), reqs)
-    assert got == ref
-    assert ref["0"][0][2] == "stop"          # the zombie actually stopped
+def _second_step_stop(toks, text12, kind):
+    """Stop criteria that first match inside the second fused step
+    (output positions 10..17 at K=8) of a greedy run whose first 32
+    tokens are `toks` and whose first 12 detokenize to `text12`, or None:
+    a stop token id that is new there, or the stop string that is all of
+    the text up to the 12th token (so it matches when that token has
+    been detokenized, not before)."""
+    if kind == "string":
+        return dict(stop=[text12.strip()]) if text12.strip() else None
+    return next((dict(stop_token_ids=[toks[j]]) for j in range(10, 16)
+                 if toks[j] not in toks[:j]), None)
+
+
+@pytest.mark.parametrize("model, kind", [("tiny_opt", "string"),
+                                         ("tiny_llama", "token_ids")])
+def test_pipelined_stops_make_zombie_rows(request, example_prompts, model,
+                                          kind):
+    """A sequence hitting a stop mid-pipeline becomes a zombie row of the
+    steps already dispatched: their overshoot is discarded (a stop string
+    is seen only after detokenizing, and the text is cut with overshoot
+    in flight), its KV pages are freed only after the last step that
+    references them, and the next re-snapshot drops it. The surviving
+    sequences finish with serial-identical tokens, text and reason. Runs
+    on learned positions (OPT) and on rotary ones, K=8 fused."""
+    model_dir = request.getfixturevalue(model + "_dir")
+    free = [(str(i), p, _params("greedy", i, 32))
+            for i, p in enumerate(example_prompts[:3])]
+    probe = _run_serial(_build(model_dir), free)
+    short = _run_serial(_build(model_dir),
+                        [(r, p, _params("greedy", 0, 12)) for r, p, _ in free])
+    # Stopping there ends that request in the second fused step, with
+    # later steps already dispatched.
+    rid, stop = next(
+        (rid, stop) for rid, ((toks, _, _), ) in sorted(probe.items())
+        if (stop := _second_step_stop(toks, short[rid][0][1], kind)))
+    reqs = [(r, p, SamplingParams(temperature=0.0, max_tokens=32, **stop)
+             if r == rid else sp) for r, p, sp in free]
+    ref = _run_serial(_build(model_dir), reqs)
+    assert ref[rid][0][2] == "stop" and 9 < len(ref[rid][0][0]) < 18
+
+    llm = _build(model_dir)
+    engine = llm.llm_engine
+    for req in reqs:
+        engine.add_request(*req)
+    (zombie, ) = next(g for g in engine.scheduler.waiting
+                      if g.request_id == rid).get_seqs()
+    tables = engine.scheduler.block_manager.block_tables
+    held_while_referenced = 0
+    outs = []
+    while engine.has_unfinished_requests() or engine.has_inflight():
+        outs.extend(engine.step_pipelined())
+        if not zombie.is_finished():
+            continue
+        referenced = any(zombie.seq_id in step._pipeline_seq_ids
+                         for step in engine._inflight)
+        # Pages stay while a dispatched step may still write them, and
+        # go with the last such step.
+        assert (zombie.seq_id in tables) == referenced
+        held_while_referenced += referenced
+        # A step dispatched after the stop was seen has no row for it.
+        newest = engine._newest_decode_inflight()
+        if newest is not None and held_while_referenced > 1:
+            assert zombie.seq_id not in [
+                sid for _, sid in newest.cont_state.rows]
+    assert held_while_referenced >= 1
+    assert _collect(outs) == ref
 
 
 def test_pipelined_random_sampling_matches(tiny_llama_dir, example_prompts):
@@ -131,6 +227,30 @@ def test_pipelined_best_of_groups(tiny_llama_dir, example_prompts):
     assert got == ref
 
 
+@pytest.mark.parametrize("joiner", [
+    dict(temperature=0.0, presence_penalty=0.5),
+    dict(temperature=0.0, repetition_penalty=1.3),
+    dict(temperature=0.7, best_of=2, n=2),
+], ids=["presence_penalty", "repetition_penalty", "best_of"])
+def test_excluded_joiner_drains_to_fresh_schedule(tiny_llama_dir,
+                                                  example_prompts, joiner):
+    """A sequence whose sampling the fused program does not take cannot
+    join by continuation: the pipeline drains (cause `params`) and a
+    full schedule merges it (copy-on-write forks, or a K=1 batch)."""
+    reqs = [("0", example_prompts[0], _params("greedy", 0, 48)),
+            ("1", example_prompts[1], _params("greedy", 1, 48)),
+            ("2", example_prompts[2],
+             SamplingParams(max_tokens=12, ignore_eos=True, **joiner))]
+    ref = _run_serial(_build(tiny_llama_dir), reqs)
+    get_efficiency_tracker().reset_for_testing()
+    got = _run_pipelined(_build(tiny_llama_dir), reqs, stagger_after=2)
+    assert got == ref
+    snap = get_efficiency_tracker().snapshot()
+    assert snap["pipeline_drains"]["params"] >= 1
+    assert snap["decode_join_rows"]["cont"] == 0
+    assert snap["decode_join_rows"]["fresh"] >= 3     # 2 + the joiner's rows
+
+
 def test_pipelined_tight_pool_drains(tiny_llama_dir):
     """When in-place growth runs out of free blocks the pipeline drains to
     a full scheduling pass (which may preempt) instead of corrupting the
@@ -152,31 +272,222 @@ def test_pipelined_tight_pool_drains(tiny_llama_dir):
     assert len(done["0"][0][0]) >= 16
 
 
-def test_pipelined_continuous_arrivals(tiny_llama_dir, example_prompts):
+def test_pipelined_tight_pool_preempts_a_joined_batch(tiny_llama_dir):
+    """Two sequences that outgrow the pool together: the continuation's
+    block growth is refused, the pipeline drains (cause `memory`), the
+    full schedule preempts one, and both finish with the serial loop's
+    tokens."""
+    kw = dict(num_device_blocks_override=12, max_num_seqs=4)
+    sp = SamplingParams(temperature=0.0, max_tokens=56, ignore_eos=True)
+    prompts = {"0": [2, 3, 4, 5] * 12, "1": [6, 7, 8, 9] * 12}   # 48 each
+
+    def run(pipelined):
+        llm = _build(tiny_llama_dir, **kw)
+        engine = llm.llm_engine
+        engine.add_request("0", None, sp, prompt_token_ids=prompts["0"])
+        outs = []
+        calls = 0
+        while (engine.has_unfinished_requests() or engine.has_inflight()
+               or calls < 2):
+            outs.extend(engine.step_pipelined() if pipelined
+                        else engine.step())
+            calls += 1
+            if calls == 2:
+                engine.add_request("1", None, sp,
+                                   prompt_token_ids=prompts["1"])
+            assert calls < 400
+        return _collect(outs)
+
+    ref = run(False)
+    get_efficiency_tracker().reset_for_testing()
+    got = run(True)
+    assert {k: v[0][0] for k, v in got.items()} == {
+        k: v[0][0] for k, v in ref.items()}
+    assert all(len(v[0][0]) == 56 for v in got.values())
+    snap = get_efficiency_tracker().snapshot()
+    assert snap["pipeline_drains"]["memory"] >= 1
+
+
+@pytest.mark.parametrize("behind_decode", [False, True])
+@pytest.mark.parametrize("sampling", SAMPLINGS)
+def test_pipelined_continuous_arrivals(tiny_llama_dir, example_prompts,
+                                       sampling, behind_decode):
     """High-rate pattern: a new request arrives on (almost) every call,
-    so prompt admissions interleave with decode continuations chained
-    PAST them (the _cont_budget_ok path). Outputs must still match the
-    serial loop exactly."""
+    so prompt admissions interleave with decode continuations and every
+    admitted sequence joins the running batch. Outputs must still match
+    the serial loop exactly."""
     prompts = (example_prompts * 3)[:10]
-    reqs = [(str(i), p, SamplingParams(temperature=0.0, max_tokens=16,
-                                       ignore_eos=True))
+    reqs = [(str(i), p, _params(sampling, i, 16 + 3 * i))
             for i, p in enumerate(prompts)]
     ref = _run_serial(_build(tiny_llama_dir, max_num_seqs=12), reqs)
+    got = _run_arrivals(_build(tiny_llama_dir, max_num_seqs=12), reqs,
+                        behind_decode=behind_decode)
+    assert got == ref
 
-    llm = _build(tiny_llama_dir, max_num_seqs=12)
+
+def test_joiners_enter_by_continuation_not_by_fresh_schedule(
+        tiny_llama_dir, example_prompts, monkeypatch):
+    """The structure of the loop under continuous arrivals with a roomy
+    pool: ONE `decode_fused` (the first batch, from an idle engine), and
+    every later sequence takes a slot of the running batch at a
+    continuation. No drain but the first schedule's; the depth counts
+    decode steps, a prompt step rides between them."""
+    prompts = (example_prompts * 4)[:12]
+    reqs = [(str(i), p, _params("greedy", i, 40))
+            for i, p in enumerate(prompts)]
+    ref = _run_serial(_build(tiny_llama_dir, max_num_seqs=16), reqs)
+    get_efficiency_tracker().reset_for_testing()
+    llm = _build(tiny_llama_dir, max_num_seqs=16)
     engine = llm.llm_engine
-    outs = []
-    pending = list(reqs)
-    calls = 0
-    engine.add_request(*pending.pop(0))
-    while (engine.has_unfinished_requests() or engine.has_inflight()
-           or pending):
-        if pending:
-            engine.add_request(*pending.pop(0))
-        outs.extend(engine.step_pipelined())
-        calls += 1
-        assert calls < 2000
-    assert _collect(outs) == ref
+    depth = engine._pipeline_depth
+    seen = set()          # (decode steps, other steps) in flight
+    finalize = engine._finalize_one
+
+    def observing():
+        decodes = sum(s.cont_state is not None for s in engine._inflight)
+        seen.add((decodes, len(engine._inflight) - decodes))
+        return finalize()
+
+    monkeypatch.setattr(engine, "_finalize_one", observing)
+    got = _run_arrivals(llm, reqs, behind_decode=True)
+    assert got == ref
+    assert _dispatches("decode_fused") == 1
+    assert _dispatches("decode_cont") >= 5
+    snap = get_efficiency_tracker().snapshot()
+    assert snap["decode_join_rows"] == {"cont": len(reqs) - 1, "fresh": 1}
+    assert snap["pipeline_drains"] == {
+        "memory": 0, "params": 0, "chunked_prefill": 0, "bucket": 0,
+        "idle": 1}
+    # `depth` decode steps in flight with a prompt step beside them, and
+    # never more than the stated number of prompt steps per decode step.
+    assert max(d for d, _ in seen) == depth
+    assert (depth, 1) in seen
+    assert all(o <= _PROMPT_STEPS_PER_DECODE_STEP * max(d, 1)
+               for d, o in seen)
+
+
+def test_joiner_reuses_the_slot_of_a_row_done_by_max_tokens(
+        tiny_llama_dir, example_prompts, monkeypatch):
+    """A row whose max_tokens the dispatched steps cover gives up its
+    slot without the host having seen its tokens; the next joiner takes
+    it, the other rows keep theirs."""
+    reqs = [("short", example_prompts[0], _params("greedy", 0, 9)),
+            ("long", example_prompts[1], _params("greedy", 1, 64)),
+            ("late", example_prompts[2], _params("greedy", 2, 24))]
+    ref = _run_serial(_build(tiny_llama_dir), reqs)
+    llm = _build(tiny_llama_dir)
+    engine = llm.llm_engine
+    snapshots = []
+    orig = engine.worker.execute_decode_cont
+
+    def recording(cont, *a, **kw):
+        snapshots.append({rid: int(slot)
+                          for (rid, _), slot in zip(cont.rows, cont.slots)})
+        return orig(cont, *a, **kw)
+
+    monkeypatch.setattr(engine.worker, "execute_decode_cont", recording)
+    got = _run_pipelined(llm, reqs, stagger_after=2)
+    assert got == ref
+    # 1 prefill token + one K=8 step cover max_tokens=9: the first
+    # continuation already runs without the short row.
+    assert snapshots[0] == {"long": 1}
+    joined = next(s for s in snapshots if "late" in s)
+    assert joined == {"late": 0, "long": 1}
+
+
+def test_batch_bucket_follows_the_load_both_ways(tiny_llama_dir,
+                                                example_prompts,
+                                                monkeypatch):
+    """A continuation inherits the batch bucket in flight. More joiners
+    than it has free slots drain (cause `bucket`) to a larger one; when
+    the load has fallen and the rows have fitted the next smaller bucket
+    for a few snapshots running, the loop drains (`bucket`) again and the
+    fresh schedule re-buckets them, instead of running the peak's bucket
+    until the engine idles."""
+    prompts = (example_prompts * 6)[:20]
+    reqs = [(str(i), p, _params("greedy", i, 96 if i < 3 else 10))
+            for i, p in enumerate(prompts)]
+    kw = dict(max_num_seqs=24, num_device_blocks_override=256)
+    ref = _run_serial(_build(tiny_llama_dir, **kw), reqs)
+    get_efficiency_tracker().reset_for_testing()
+    llm = _build(tiny_llama_dir, **kw)
+    engine = llm.llm_engine
+    buckets = []          # (batch bucket, live rows) of every decode step
+    orig = engine.worker.execute_decode_cont
+
+    def recording(cont, *a, **kw):
+        buckets.append((cont.ctx0.shape[0], len(cont.rows)))
+        return orig(cont, *a, **kw)
+
+    monkeypatch.setattr(engine.worker, "execute_decode_cont", recording)
+    # 3 long requests first (bucket 16), then 17 short ones at once.
+    got = _run_pipelined(llm, reqs, stagger_after=3)
+    assert got == ref
+    assert max(b for b, _ in buckets) == 32
+    peak = max(i for i, (b, _) in enumerate(buckets) if b == 32)
+    after = buckets[peak + 1:]
+    assert after and all(b == 16 for b, _ in after)
+    # Back at 16 at the fourth roomy snapshot, not at the end of the run.
+    roomy = [n for b, n in buckets if b == 32 and n <= 16]
+    assert len(roomy) == 3 and len(after) >= 2
+    assert get_efficiency_tracker().snapshot()["pipeline_drains"][
+        "bucket"] == 2
+
+
+def test_full_seats_do_not_drain(tiny_llama_dir, example_prompts):
+    """More requests than seats (`max_num_seqs`), the queue forming with
+    decode in flight: a prompt that waits for a seat is not a reason to
+    drain. Decode continues, a finished row frees a seat, and the next
+    prefill-only pass admits the head of the queue, which joins by
+    continuation."""
+    prompts = (example_prompts * 3)[:10]
+    # Seats free one at a time, with a decode step always in flight.
+    reqs = [(str(i), p, _params("greedy", i, (96, 64, 40, 16)[i] if i < 4
+                                else 16))
+            for i, p in enumerate(prompts)]
+    ref = _run_serial(_build(tiny_llama_dir, max_num_seqs=4), reqs)
+    get_efficiency_tracker().reset_for_testing()
+    got = _run_pipelined(_build(tiny_llama_dir, max_num_seqs=4), reqs,
+                         stagger_after=4)
+    assert got == ref
+    snap = get_efficiency_tracker().snapshot()
+    assert snap["pipeline_drains"] == {
+        "memory": 0, "params": 0, "chunked_prefill": 0, "bucket": 0,
+        "idle": 1}
+    assert snap["decode_join_rows"] == {"cont": 6, "fresh": 4}
+    assert _dispatches("decode_fused") == 1
+
+
+def test_prompt_over_the_budget_drains_as_chunked_prefill(tiny_llama_dir,
+                                                          example_prompts):
+    """A prompt longer than one prefill-only step may carry needs the
+    chunked pass, on an empty pipeline: the scheduler says so
+    (`SchedulerOutputs.blocked`) and the drain is counted by that."""
+    kw = dict(max_num_batched_tokens=32, max_model_len=128)
+    long_prompt = [2, 3, 4, 5, 6] * 16                       # 80 tokens
+    sp = _params("greedy", 0, 40)
+
+    def run(pipelined):
+        engine = _build(tiny_llama_dir, **kw).llm_engine
+        engine.add_request("0", example_prompts[0], sp)
+        outs = []
+        calls = 0
+        while (engine.has_unfinished_requests() or engine.has_inflight()
+               or calls < 3):
+            outs.extend(engine.step_pipelined() if pipelined
+                        else engine.step())
+            calls += 1
+            if calls == 3:
+                engine.add_request("1", None, sp,
+                                   prompt_token_ids=long_prompt)
+            assert calls < 400
+        return _collect(outs)
+
+    ref = run(False)
+    get_efficiency_tracker().reset_for_testing()
+    assert run(True) == ref
+    drains = get_efficiency_tracker().snapshot()["pipeline_drains"]
+    assert drains["chunked_prefill"] >= 1 and drains["memory"] == 0
 
 
 def test_no_overshoot_cont_when_budgets_exhausted(tiny_llama_dir,

@@ -256,6 +256,50 @@ def test_record_dispatch_counts_programs_device_state_and_context(tracker):
     assert tracker.snapshot()["dispatches"] == {"prefill": 1, "decode": 3}
 
 
+@pytest.mark.parametrize("via,rows", [("cont", 3), ("fresh", 5)])
+def test_decode_join_rows_count_by_how_the_row_entered(tracker, via, rows):
+    """intellillm_decode_join_rows_total{via}: rows that took a slot of
+    the running batch at a continuation, or were merged by a fresh
+    schedule; the other child stays where it was."""
+    from prometheus_client import REGISTRY
+    other = {"cont": "fresh", "fresh": "cont"}[via]
+
+    def sample(v):
+        return REGISTRY.get_sample_value(
+            "intellillm_decode_join_rows_total", {"via": v})
+
+    before = {v: sample(v) for v in (via, other)}
+    assert None not in before.values()      # both children from scrape 1
+    tracker.record_decode_join(via, rows)
+    tracker.record_decode_join(via, 0)
+    assert tracker.snapshot()["decode_join_rows"] == {via: rows, other: 0}
+    assert sample(via) - before[via] == rows
+    assert sample(other) == before[other]
+
+
+@pytest.mark.parametrize("cause", ["memory", "params", "chunked_prefill",
+                                   "bucket", "idle"])
+def test_pipeline_drains_count_by_cause(tracker, cause):
+    """intellillm_pipeline_drains_total{cause}: every cause is exported
+    at 0 before the first drain, and a drain counts under its own."""
+    from prometheus_client import REGISTRY
+    from intellillm_tpu.obs.efficiency import DRAIN_CAUSES
+
+    def sample(c):
+        return REGISTRY.get_sample_value(
+            "intellillm_pipeline_drains_total", {"cause": c})
+
+    before = {c: sample(c) for c in DRAIN_CAUSES}
+    assert None not in before.values()
+    assert tracker.snapshot()["pipeline_drains"] == dict.fromkeys(
+        DRAIN_CAUSES, 0)
+    tracker.record_pipeline_drain(cause)
+    drains = tracker.snapshot()["pipeline_drains"]
+    assert drains.pop(cause) == 1 and not any(drains.values())
+    assert {c: sample(c) - before[c] for c in DRAIN_CAUSES} == {
+        c: int(c == cause) for c in DRAIN_CAUSES}
+
+
 def test_dispatch_program_counters_skip_warmup_and_exist_at_zero(tracker):
     from prometheus_client import REGISTRY
     zero = {"idle": 0, "busy": 0, "row_steps": 0, "context_tokens": 0,

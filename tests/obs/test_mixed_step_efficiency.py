@@ -190,3 +190,69 @@ def test_fused_decode_programs_count_rows_steps_and_context(tiny_opt_dir,
         assert (d["context_tokens"] - 4 * d["row_steps"]
                 <= d["walked_tokens"]
                 < d["context_tokens"] + bs * d["row_steps"])
+
+
+def test_joined_continuation_counts_the_joiners_rows_and_contexts(
+        tiny_opt_dir, trackers):
+    """A `decode_cont` dispatch reports the re-snapshot it ran: a row
+    that joined at it counts with its own context (prompt + 1), a row
+    whose budget the earlier steps cover no longer counts, so
+    context_tokens / row_steps stays the mean context a decode
+    row-substep reads."""
+    eff, _ = trackers
+    k = 4
+    llm = LLM(model=tiny_opt_dir, dtype="float32",
+              num_device_blocks_override=128, max_model_len=128,
+              max_num_seqs=8, max_paddings=512, num_decode_steps=k)
+    engine = llm.llm_engine
+    if not engine.pipeline_enabled:
+        pytest.skip("pipelined stepping is off")
+    eff.reset_for_testing()
+    prompt_lens = {str(i): len(engine.tokenizer.encode(p))
+                   for i, p in enumerate(PROMPTS)}
+    n_out = {"0": 30, "1": 1 + k, "2": 18, "3": 22}
+    runner = engine.worker.model_runner
+    snapshots = []
+    dispatch = runner.execute_decode_cont
+
+    def recording(cont, *args, **kwargs):
+        snapshots.append([rid for rid, _ in cont.rows])
+        return dispatch(cont, *args, **kwargs)
+
+    runner.execute_decode_cont = recording
+    pending = list(enumerate(PROMPTS))
+    while (engine.has_unfinished_requests() or engine.has_inflight()
+           or pending):
+        # "0" and "1" start together; the others arrive behind decode.
+        if pending and (len(pending) > 2
+                        or engine._newest_decode_inflight() is not None):
+            i, prompt = pending.pop(0)
+            engine.add_request(str(i), prompt, SamplingParams(
+                temperature=0.0, max_tokens=n_out[str(i)], ignore_eos=True))
+        engine.step_pipelined()
+
+    snap = eff.snapshot()
+    assert snap["decode_join_rows"] == {"cont": 2, "fresh": 2}
+    assert snap["programs"]["decode_fused"]["idle"] == 1
+    # "1" is done after the fresh step (1 + K tokens): no continuation
+    # has a row for it; the joiners appear.
+    assert all("1" not in rows for rows in snapshots)
+    assert any("2" in rows for rows in snapshots)
+    # Every continuation's row-substeps and contexts, from the requests
+    # alone: a row's j-th decode step starts at prompt + 1 + K * j.
+    steps_before = {"0": 1, "1": 1, "2": 0, "3": 0}    # the fresh step
+    row_steps = context = 0
+    for rows in snapshots:
+        for rid in rows:
+            ctx = prompt_lens[rid] + 1 + k * steps_before[rid]
+            row_steps += k
+            context += k * ctx + k * (k - 1) // 2
+            steps_before[rid] += 1
+    cont = snap["programs"]["decode_cont"]
+    assert (cont["row_steps"], cont["context_tokens"]) == (row_steps,
+                                                           context)
+    bs = engine.cache_config.block_size
+    assert cont["walked_tokens"] % bs == 0
+    assert (cont["context_tokens"] - k * cont["row_steps"]
+            <= cont["walked_tokens"]
+            < cont["context_tokens"] + bs * cont["row_steps"])

@@ -387,3 +387,149 @@ def test_mla_seams_select_the_kernels(compile_for_chip):
         assert "tpu_custom_call" in text
     finally:
         dispatch.set_use_pallas(None)
+
+
+# --- the continuation program of the benchmark's three cells ---------------
+
+# configuration file -> layers kept here (a dense and, where the model has
+# them, a MoE layer: every kind of layer at its published width).
+CELL_CONFIGS = {"mistral-7b-v0.2": 2, "deepseek-moe-16b-l5": 2,
+                "ax-k1-ep16-l7": 2}
+CELL_ROWS, CELL_TABLE_WIDTH, CELL_K, CELL_BLOCKS = 128, 64, 8, 9000
+
+
+@pytest.mark.parametrize("config", sorted(CELL_CONFIGS))
+def test_decode_cont_takes_joiners_at_no_more_temporaries(
+        compiled_for_chip, one_chip, tmp_path, config):
+    """`decode_cont` as the cells dispatch it (128 rows x 64-block tables,
+    K=8, the cell's dtype, quantization and pool dtype, published widths)
+    compiles for the described v5e with the joiners' token and mask
+    columns, holds both paged kernels, and needs no more temporaries
+    than the program it replaces (input tokens sliced from the previous
+    step's packed output alone)."""
+    import json
+
+    from intellillm_tpu.config import (CacheConfig, ModelConfig,
+                                       ParallelConfig, SchedulerConfig)
+    from intellillm_tpu.models import get_model_class
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    from intellillm_tpu.utils import STR_DTYPE_TO_JNP
+    from intellillm_tpu.worker.model_runner import ModelRunner
+
+    from chipbench import CHIP_DIR      # puts the harness on sys.path
+    from harness.server import hf_config_of
+
+    with open(os.path.join(CHIP_DIR, "configs", config + ".json")) as f:
+        cell = json.load(f)
+    serve = cell["serve"]
+    layers = CELL_CONFIGS[config]
+    hf = hf_config_of(cell, rehearsal=False)
+    hf["num_hidden_layers"] = layers
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    model_config = ModelConfig(
+        model=str(tmp_path), dtype=serve["dtype"], load_format="dummy",
+        max_model_len=serve["max_model_len"],
+        quantization=serve["quantization"])
+    model = get_model_class(model_config.hf_config.architectures)(
+        model_config)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: model.init_random_params(0)))
+    set_use_pallas(True)        # jax.default_backend() is "cpu" here
+    try:
+        runner = ModelRunner(
+            model, None, model_config,
+            SchedulerConfig(max_num_batched_tokens=512,
+                            max_num_seqs=CELL_ROWS,
+                            max_model_len=serve["max_model_len"],
+                            enable_chunked_prefill=True),
+            CacheConfig(cache_dtype=serve["kv_cache_dtype"],
+                        num_device_blocks_override=CELL_BLOCKS,
+                        swap_space_gib=0.01),
+            ParallelConfig())
+        pools, heads, width = model_config.get_cache_row_shape()
+        pool_dtype = jnp.dtype(STR_DTYPE_TO_JNP[
+            serve["dtype"] if serve["kv_cache_dtype"] == "auto"
+            else serve["kv_cache_dtype"]])
+        b, w, k = CELL_ROWS, CELL_TABLE_WIDTH, CELL_K
+
+        def sds(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        kv = [tuple(sds(pool_dtype, CELL_BLOCKS, heads, 16, width)
+                    for _ in range(pools)) for _ in range(layers)]
+        # _pack's layout at logprob_k = 1, with an expert-parallel
+        # share's two counter columns.
+        share = 2 if getattr(model, "moe_choices_per_token", 0) else 0
+        packed = sds(jnp.int32, b, 4 * k + share)
+        i32, f32 = jnp.int32, jnp.float32
+        rest = (sds(i32, b, 1), sds(i32, b, w), sds(i32, b), sds(f32, b),
+                sds(i32, b), sds(f32, b), sds(f32, b), sds(jnp.uint32, b),
+                sds(f32, b), sds(f32, b), sds(f32, b), None, None)
+        flags = dict(prev_t1=k, num_steps=k, logprob_k=1, do_topk=False,
+                     do_topp=False, do_minp=False, do_penalties=False,
+                     do_random=False)
+
+        def sliced_only(params, kv_caches, prev_packed, *rest, prev_t1,
+                        **flags):
+            return runner._decode_fn(
+                params, kv_caches, prev_packed[:, prev_t1 - 1:prev_t1],
+                *rest, **flags)
+
+        # `compiled_for_chip` is asked for its side: the persistent
+        # compilation cache is off while these two compile.
+        assert not jax.config.jax_enable_compilation_cache
+        joined = runner._jit_decode_cont.lower(
+            params, kv, packed, sds(i32, b, 1), sds(jnp.bool_, b, 1),
+            *rest, **flags).compile()
+        before = jax.jit(
+            sliced_only, static_argnames=tuple(flags),
+            donate_argnames=("kv_caches", )).lower(
+                params, kv, packed, *rest, **flags).compile()
+    finally:
+        set_use_pallas(None)
+    assert joined.as_text().count("tpu_custom_call") >= 2
+    temp = joined.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.01 * before.memory_analysis().temp_size_in_bytes
+
+
+def test_joined_continuation_is_the_plain_one_s_executable(
+        tiny_llama_dir, example_prompts):
+    """The runner's bucket key of a continuation that takes joiners equals
+    a plain one's, and jit holds one `decode_cont` executable for both."""
+    from intellillm_tpu import LLM, SamplingParams
+    from intellillm_tpu.obs import get_compile_tracker
+
+    tracker = get_compile_tracker()
+    tracker.reset_for_testing()
+    llm = LLM(model=tiny_llama_dir, dtype="float32",
+              num_device_blocks_override=128, max_model_len=128,
+              max_num_seqs=8, max_paddings=512, swap_space=0.01,
+              num_decode_steps=8)
+    engine = llm.llm_engine
+    runner = engine.worker.model_runner
+    joins = []
+    dispatch = runner.execute_decode_cont
+
+    def recording(cont, tables, join_tokens, *args, **kwargs):
+        joins.append(int((join_tokens >= 0).sum()))
+        return dispatch(cont, tables, join_tokens, *args, **kwargs)
+
+    runner.execute_decode_cont = recording
+    sp = SamplingParams(temperature=0.0, max_tokens=40, ignore_eos=True)
+    pending = list(enumerate(example_prompts))
+    while engine.has_unfinished_requests() or engine.has_inflight() \
+            or pending:
+        if pending and (len(pending) == len(example_prompts)
+                        or engine._newest_decode_inflight() is not None):
+            i, prompt = pending.pop(0)
+            engine.add_request(str(i), prompt, sp)
+        engine.step_pipelined()
+    assert 0 in joins and max(joins) >= 1     # plain and joined, both ran
+    snap = tracker.snapshot()
+    assert snap["compiles"]["decode_cont"] == 1
+    assert runner._jit_decode_cont._cache_size() == 1
