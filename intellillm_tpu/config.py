@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass, field
-from typing import Optional, Tuple, Union
+from typing import List, Optional, Tuple, Union
 
 from intellillm_tpu.logger import init_logger
 from intellillm_tpu.transformers_utils.config import get_hf_config
@@ -148,7 +148,44 @@ class ModelConfig:
         latent = self.get_latent_cache_width()
         if latent:
             return 1, 1, latent
-        return 2, self.get_total_num_kv_heads(), self.get_head_size()
+        # A config may state a stored head width wider than the head (the
+        # head zero-padded to whole 128-lane tiles, which is what the
+        # device pads a narrower pool row to anyway and what the Pallas
+        # kernels' DMA windows need): `layers/attention.py` pads q, k, v
+        # up to the pool's width and cuts the output back.
+        width = getattr(self.hf_config, "kv_cache_head_width", None)
+        return (2, self.get_total_num_kv_heads(),
+                width or self.get_head_size())
+
+    def get_layer_cache_kinds(self) -> List[str]:
+        """What each layer of the stack caches, told by mechanism: "kv"
+        (a paged pool of `get_cache_row_shape`, attention of either kind)
+        or "state" (a fixed-size per-sequence state of
+        `get_state_row_shape`, one slot a running sequence, not paged). A
+        config that lists `layer_types` and a short-convolution cache
+        length (`conv_L_cache`) has "state" where its type is not an
+        attention; every other config is "kv" throughout."""
+        n = self.get_num_layers()
+        types = getattr(self.hf_config, "layer_types", None)
+        if not types or not getattr(self.hf_config, "conv_L_cache", None):
+            return ["kv"] * n
+        return ["kv" if "attention" in t else "state" for t in types[:n]]
+
+    def get_state_row_shape(self) -> Optional[Tuple[int, int]]:
+        """(taps, width) of what one sequence keeps in one "state" layer:
+        the last `conv_L_cache - 1` gated inputs of a short convolution,
+        `hidden_size` channels each. None for a model without such
+        layers."""
+        if "state" not in self.get_layer_cache_kinds():
+            return None
+        return self.hf_config.conv_L_cache - 1, self.hf_config.hidden_size
+
+    def get_num_kv_layers(self) -> int:
+        """Layers with a paged KV (or latent) pool."""
+        return self.get_layer_cache_kinds().count("kv")
+
+    def get_num_state_layers(self) -> int:
+        return self.get_layer_cache_kinds().count("state")
 
     def get_head_size(self) -> int:
         if hasattr(self.hf_config, "head_dim") and self.hf_config.head_dim:
@@ -230,6 +267,11 @@ class CacheConfig:
         # Filled after profiling / engine init.
         self.num_device_blocks: Optional[int] = None
         self.num_cpu_blocks: Optional[int] = None
+        # Per-sequence state slots (models with "state" layers,
+        # `ModelConfig.get_layer_cache_kinds`): one a running sequence on
+        # the device, and as many on the host for swapped ones. 0 for
+        # every other model.
+        self.num_state_slots: int = 0
 
     def _verify_args(self) -> None:
         if self.hbm_utilization > 1.0 or self.hbm_utilization <= 0:

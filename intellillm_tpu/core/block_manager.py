@@ -8,6 +8,19 @@ block numbers index the HBM pool arrays held by the worker's CacheEngine;
 this module never touches device memory itself — it emits block-op plans
 (swap-in / swap-out / copy dicts) that the worker executes.
 
+Per-sequence state slots (models with "state" layers,
+`ModelConfig.get_layer_cache_kinds`): beside its block table a sequence
+holds ONE slot of the worker's state pools, taken with the table at
+admission (an admission that finds blocks but no slot waits), moved to a
+host slot and back with a swap, copied at a fork, given back when the
+sequence is freed. A slot is given back at once even while the blocks'
+release waits for steps in flight (`release_state_slot`): the device runs
+its programs in order, so whatever a step in flight still writes there
+lands before the next owner's first step, and that step starts at
+position 0, which reads no state. The moves are recorded in
+`state_ops` for the worker to run before the next device step. With
+`num_state_slots` 0 (every other model) none of this does anything.
+
 Honesty note: the refcounted free-list / CoW / swap bookkeeping is a
 deliberate close port of the reference's host-side block manager (pure
 bookkeeping, SURVEY §7.4). Additions that have no reference analogue:
@@ -72,6 +85,7 @@ class BlockSpaceManager:
         num_cpu_blocks: int,
         watermark: float = 0.01,
         sliding_window: Optional[int] = None,
+        num_state_slots: int = 0,
     ) -> None:
         self.block_size = block_size
         self.num_total_device_blocks = num_device_blocks
@@ -92,6 +106,67 @@ class BlockSpaceManager:
                                             num_cpu_blocks)
         # seq_id -> physical block table
         self.block_tables: Dict[int, BlockTable] = {}
+
+        # seq_id -> state slot, on the device (running) or on the host
+        # (swapped); as many host slots as device slots.
+        self.num_state_slots = num_state_slots
+        self.state_slots: Dict[int, int] = {}
+        self.cpu_state_slots: Dict[int, int] = {}
+        self._free_state_slots = list(range(num_state_slots))[::-1]
+        self._free_cpu_state_slots = list(range(num_state_slots))[::-1]
+        # ("swap_out" | "swap_in" | "fork", source slot, destination slot)
+        # in the order made. The engine hands the worker this very list
+        # (never rebound, only appended to and cleared): the worker runs
+        # and clears it before its next device step.
+        self.state_ops: List[Tuple[str, int, int]] = []
+        from intellillm_tpu.obs import get_efficiency_tracker
+        self._efficiency = get_efficiency_tracker()
+        self._report_state_slots()
+
+    # --- per-sequence state slots ------------------------------------------
+
+    def _report_state_slots(self, event: Optional[str] = None) -> None:
+        if self.num_state_slots:
+            self._efficiency.record_state_slots(
+                self.num_state_slots - len(self._free_state_slots),
+                len(self._free_state_slots), event)
+
+    def can_allocate_state(self, seq_group: SequenceGroup) -> bool:
+        """Whether every waiting sequence of the group finds a slot."""
+        if not self.num_state_slots:
+            return True
+        return (seq_group.num_seqs(status=SequenceStatus.WAITING)
+                <= len(self._free_state_slots))
+
+    def _take_state_slot(self, seq_id: int, event: str) -> int:
+        if not self._free_state_slots:
+            raise RuntimeError(
+                "no free state slot (one a running sequence: the "
+                "scheduler's max_num_seqs bounds them)")
+        slot = self._free_state_slots.pop()
+        self.state_slots[seq_id] = slot
+        self._report_state_slots(event)
+        return slot
+
+    def release_state_slot(self, seq: Sequence) -> None:
+        """Give the sequence's slot back (device or host); nothing if it
+        holds none."""
+        slot = self.state_slots.pop(seq.seq_id, None)
+        if slot is not None:
+            self._free_state_slots.append(slot)
+            self._report_state_slots("free")
+        slot = self.cpu_state_slots.pop(seq.seq_id, None)
+        if slot is not None:
+            self._free_cpu_state_slots.append(slot)
+            self._report_state_slots("free")
+
+    def get_state_slot(self, seq_id: int) -> Optional[int]:
+        """The device slot of a running sequence (None: the model keeps
+        no state)."""
+        if not self.num_state_slots:
+            return None
+        return self.state_slots[seq_id]
+
 
     # --- admission -------------------------------------------------------
 
@@ -150,6 +225,8 @@ class BlockSpaceManager:
 
         for seq in seq_group.get_seqs(status=SequenceStatus.WAITING):
             self.block_tables[seq.seq_id] = block_table.copy()
+            if self.num_state_slots:
+                self._take_state_slot(seq.seq_id, "alloc")
 
     # --- prefix import (disaggregated KV transfer) ------------------------
 
@@ -252,6 +329,10 @@ class BlockSpaceManager:
         self.block_tables[child_seq.seq_id] = src_block_table.copy()
         for block in src_block_table:
             block.ref_count += 1
+        if self.num_state_slots:
+            self.state_ops.append(
+                ("fork", self.state_slots[parent_seq.seq_id],
+                 self._take_state_slot(child_seq.seq_id, "fork")))
 
     # --- swap ------------------------------------------------------------
 
@@ -274,6 +355,8 @@ class BlockSpaceManager:
         # swap-in, which may need a CoW block plus the blocks covering the
         # lookahead tokens (same budget as can_append_slots).
         blocks_per_seq = 1 + (num_slots - 1) // self.block_size + 1
+        if self.num_state_slots and num_swapped > len(self._free_state_slots):
+            return False
         return (len(blocks) + num_swapped * blocks_per_seq
                 <= num_free - self.watermark_blocks)
 
@@ -292,12 +375,22 @@ class BlockSpaceManager:
                 new_block_table.append(device_block)
                 self.cpu_allocator.free(cpu_block)
             self.block_tables[seq.seq_id] = new_block_table
+            if self.num_state_slots:
+                host = self.cpu_state_slots.pop(seq.seq_id)
+                self._free_cpu_state_slots.append(host)
+                self.state_ops.append(
+                    ("swap_in", host,
+                     self._take_state_slot(seq.seq_id, "swap_in")))
         return {
             cpu.block_number: dev.block_number
             for cpu, dev in mapping.items()
         }
 
     def can_swap_out(self, seq_group: SequenceGroup) -> bool:
+        if self.num_state_slots and (
+                seq_group.num_seqs(status=SequenceStatus.RUNNING)
+                > len(self._free_cpu_state_slots)):
+            return False
         return (len(self._get_physical_blocks(seq_group))
                 <= self.cpu_allocator.get_num_free_blocks())
 
@@ -316,6 +409,13 @@ class BlockSpaceManager:
                 new_block_table.append(cpu_block)
                 self.device_allocator.free(device_block)
             self.block_tables[seq.seq_id] = new_block_table
+            if self.num_state_slots:
+                slot = self.state_slots.pop(seq.seq_id)
+                host = self._free_cpu_state_slots.pop()
+                self.cpu_state_slots[seq.seq_id] = host
+                self._free_state_slots.append(slot)
+                self.state_ops.append(("swap_out", slot, host))
+                self._report_state_slots("swap_out")
         return {
             dev.block_number: cpu.block_number
             for dev, cpu in mapping.items()
@@ -331,6 +431,7 @@ class BlockSpaceManager:
                 self.cpu_allocator.free(block)
 
     def free(self, seq: Sequence) -> None:
+        self.release_state_slot(seq)
         if seq.seq_id not in self.block_tables:
             return  # already freed or never allocated
         self._free_block_table(self.block_tables[seq.seq_id])
@@ -340,6 +441,12 @@ class BlockSpaceManager:
         for block_table in self.block_tables.values():
             self._free_block_table(block_table)
         self.block_tables.clear()
+        self.state_slots.clear()
+        self.cpu_state_slots.clear()
+        self._free_state_slots = list(range(self.num_state_slots))[::-1]
+        self._free_cpu_state_slots = list(range(self.num_state_slots))[::-1]
+        self.state_ops.clear()
+        self._report_state_slots()
 
     def get_block_table(self, seq: Sequence) -> List[int]:
         return [b.block_number for b in self.block_tables[seq.seq_id]]

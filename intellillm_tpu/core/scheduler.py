@@ -269,6 +269,7 @@ class Scheduler:
             num_device_blocks=cache_config.num_device_blocks,
             num_cpu_blocks=cache_config.num_cpu_blocks,
             sliding_window=cache_config.sliding_window,
+            num_state_slots=cache_config.num_state_slots,
         )
         self.prefix_pool = PrefixPool(cache_config.block_size)
 
@@ -485,6 +486,13 @@ class Scheduler:
                     blocked = "kv_watermark"
                     self._decisions.pass_blocked(
                         blocked, self.block_manager.kv_pressure_detail())
+                    break
+                if (can_allocate == AllocStatus.OK and not
+                        self.block_manager.can_allocate_state(seq_group)):
+                    # Blocks but no state slot: like seats, a later pass
+                    # admits when a sequence has ended (no drain).
+                    blocked = "state_slots"
+                    self._decisions.pass_blocked(blocked)
                     break
                 if can_allocate == AllocStatus.NEVER:
                     logger.warning(
@@ -971,6 +979,10 @@ class Scheduler:
                         "kv_watermark",
                         self.block_manager.kv_pressure_detail())
                     break
+                if (can_allocate == AllocStatus.OK and not
+                        self.block_manager.can_allocate_state(seq_group)):
+                    self._decisions.pass_blocked("state_slots")
+                    break
                 if can_allocate == AllocStatus.NEVER:
                     logger.warning(
                         "Input prompt (%d tokens) cannot be allocated even "
@@ -1111,6 +1123,10 @@ class Scheduler:
             seq_data[seq.seq_id] = seq.data
             block_tables[seq.seq_id] = (
                 self.block_manager.get_block_table(seq))
+        state_slots = None
+        if self.block_manager.num_state_slots:
+            state_slots = {sid: self.block_manager.get_state_slot(sid)
+                           for sid in seq_data}
         return SequenceGroupMetadata(
             request_id=seq_group.request_id,
             is_prompt=True if chunk is not None else prompt_run,
@@ -1121,6 +1137,7 @@ class Scheduler:
             prefix=seq_group.prefix,
             token_chunk_size=chunk[1] if chunk is not None else None,
             num_computed_tokens=chunk[0] if chunk is not None else 0,
+            state_slots=state_slots,
         )
 
     def fork_seq(self, parent_seq: Sequence, child_seq: Sequence) -> None:
@@ -1129,6 +1146,9 @@ class Scheduler:
     def free_seq(self, seq: Sequence) -> None:
         if self._free_guard.get(seq.seq_id, 0) > 0:
             self._deferred_free[seq.seq_id] = seq
+            # The state slot does not wait for the steps in flight
+            # (core/block_manager.py says why that is safe).
+            self.block_manager.release_state_slot(seq)
             return
         self.block_manager.free(seq)
 

@@ -118,6 +118,14 @@ class LLMEngine:
             self.length_predictor)
 
         self.speculative_config = speculative_config
+        # A model with per-sequence state layers (a short convolution's
+        # taps: `ModelConfig.get_layer_cache_kinds`) takes a state slot a
+        # running sequence, and refuses what would need more of the state
+        # than its newest value.
+        self._stateful = model_config.get_num_state_layers() > 0
+        if self._stateful:
+            self._refuse_for_state_model(speculative_config)
+            cache_config.num_state_slots = scheduler_config.max_num_seqs
         if speculative_config is not None:
             # One engine decode "step" = K draft proposals + the bonus
             # token; the scheduler must reserve K+1 KV slots per pass.
@@ -216,6 +224,11 @@ class LLMEngine:
         self._device_telemetry.attach()
 
         self.scheduler = Scheduler(scheduler_config, cache_config, lora_config)
+        # The block manager's per-sequence state moves (swap, fork) reach
+        # the worker through this one list: the manager appends in the
+        # order it decides them, the worker runs and clears them before
+        # its next device step, whichever call dispatches it.
+        self.worker.state_ops = self.scheduler.block_manager.state_ops
         # Per-row speculative scheduling: eligible decode rows reserve
         # K+1 slots and join SchedulerOutputs.spec_plan.
         self.scheduler.spec_decode_enabled = speculative_config is not None
@@ -470,6 +483,31 @@ class LLMEngine:
 
     # --- init ------------------------------------------------------------
 
+    def _refuse_for_state_model(self, speculative_config) -> None:
+        """Start-up refusals of a model with "state" layers, each naming
+        the mechanism it lacks."""
+        if speculative_config is not None:
+            raise NotImplementedError(
+                "Speculative decoding is not supported for a model with "
+                "per-sequence convolution state: a rejected draft token "
+                "has already advanced the state, and the state pool keeps "
+                "no earlier value to roll back to.")
+        role = self.scheduler_config.replica_role
+        if role != "mixed":
+            raise NotImplementedError(
+                f"replica_role={role!r} (KV export/import, "
+                f"worker/kv_transfer.py) is not supported for a model with "
+                f"per-sequence convolution state: the IKV1 payload carries "
+                f"K/V blocks of a prompt prefix and no state snapshot at "
+                f"the prefix's end.")
+
+    _PREFIX_REFUSAL = (
+        "Prefix reuse (prefix_pos, prefix.py and the prefix pool) is not "
+        "supported for a model with per-sequence convolution state: a "
+        "request that skips a cached prefix needs the state as it stood at "
+        "the prefix's end, and the pool keeps K/V blocks only (it would "
+        "need state snapshots).")
+
     def _init_tokenizer(self, **kwargs) -> None:
         self.tokenizer = TokenizerGroup(
             self.model_config.tokenizer,
@@ -495,6 +533,7 @@ class LLMEngine:
         cc = self.cache_config
         from intellillm_tpu.worker.cache_engine import CacheEngine
         mc = self.model_config
+        state_slot_bytes = CacheEngine.get_state_slot_size(mc)
         self._boot.set_info("cache", {
             "num_device_blocks": cc.num_device_blocks,
             "num_cpu_blocks": cc.num_cpu_blocks,
@@ -506,10 +545,21 @@ class LLMEngine:
             "kind": "latent" if mc.get_latent_cache_width() else "kv",
             "pools_per_layer": mc.get_cache_row_shape()[0],
             # Device bytes one token takes in one layer's cache (lane
-            # padding included): block bytes / block size / layers.
+            # padding included): block bytes / block size / layers with
+            # a pool.
             "bytes_per_token_per_layer": CacheEngine.get_cache_block_size(
                 cc.block_size, cc.cache_dtype, mc, self.parallel_config)
-            // (cc.block_size * mc.get_num_layers()),
+            // (cc.block_size * max(mc.get_num_kv_layers(), 1)),
+            # Layers by what they cache, and the per-sequence state beside
+            # the paged pools (0 slots for a model without state layers).
+            "kv_layers": mc.get_num_kv_layers(),
+            "state_layers": mc.get_num_state_layers(),
+            "num_state_slots": cc.num_state_slots,
+            "state_slot_bytes": state_slot_bytes,
+            # intellillm_cache_bytes{kind}: the device pools' sizes.
+            "bytes": self._efficiency.record_cache_bytes(
+                kv=self._kv_block_bytes * cc.num_device_blocks,
+                state=state_slot_bytes * cc.num_state_slots),
         })
         from intellillm_tpu.utils import runtime_facts
         self._boot.set_info("runtime", runtime_facts())
@@ -612,6 +662,8 @@ class LLMEngine:
                        lora_request)
 
         prefix = None
+        if prefix_pos is not None and self._stateful:
+            raise NotImplementedError(self._PREFIX_REFUSAL)
         if (prefix_pos is not None
                 and sampling_params.prompt_logprobs is not None):
             # Cached-prefix positions have no hidden states in the prefill.
@@ -1149,11 +1201,13 @@ class LLMEngine:
             self._drain("memory")
             return None
         tables = [bm.grow_to(sid, target) for sid, target in targets]
+        state_slots = [bm.get_state_slot(sid) for sid, _ in targets]
 
         # One metadata entry per group holding a row, in slot order.
         cont = DecodeContState([], [], slots, ctx, out_lens, params, loras,
                                k)
         cont.owners = [owners[slot] for slot in slots]
+        cont.state_slots = state_slots if self._stateful else None
         groups: List[SequenceGroup] = []
         listed: Set[str] = set()
         for seq, meta, group in cont.owners:

@@ -54,6 +54,10 @@ class AttentionMetadata:
     # Static: the runner's device mesh, so the Pallas seams can run per
     # kv-head shard when it spans chips (ops/dispatch.shard_over_heads).
     mesh: Optional[object] = struct.field(pytree_node=False, default=None)
+    # [B] the state slot whose sequence each row continues (models with
+    # "state" layers, layers/short_conv.py); a slot past the pool for a
+    # pad row, as slot_mapping does. None for every other model.
+    state_slots: Optional[jnp.ndarray] = None
 
 
 class PagedAttention:
@@ -84,6 +88,22 @@ class PagedAttention:
         kv_cache,             # KVCache, or (kp, vp, k_stage, v_stage) staged
         attn_metadata: AttentionMetadata,
     ):
+        # A pool whose rows are wider than the head (the head padded to
+        # whole 128-lane tiles, `ModelConfig.get_cache_row_shape`): q, k
+        # and v take the pool's width with zeros, which add nothing to a
+        # score and come back as zeros cut off the output, so the seams
+        # and their kernels see a head of whole tiles.
+        head = query.shape[-1]
+        pad = kv_cache[0].shape[-1] - head
+        if pad:
+            query, key, value = (
+                jnp.pad(x, ((0, 0), ) * (x.ndim - 1) + ((0, pad), ))
+                for x in (query, key, value))
+        out, kv_cache = self._attend(query, key, value, kv_cache,
+                                     attn_metadata)
+        return (out[..., :head] if pad else out), kv_cache
+
+    def _attend(self, query, key, value, kv_cache, attn_metadata):
         if attn_metadata.staged:
             return self._staged_decode(query, key, value, kv_cache,
                                        attn_metadata)

@@ -61,8 +61,13 @@ class Routing:
       `n_group` equal consecutive groups, a group's score is its largest
       expert score, only experts of the best `topk_group` groups may be
       chosen. 1 / 1 = no limit.
-    - `renormalize`: chosen weights divided by their sum; `scale`: then
-      multiplied by it (`routed_scaling_factor`).
+    - `renormalize`: chosen weights divided by their sum (floored at
+      1e-9), or by their sum plus `sum_eps` where a family adds an epsilon
+      to it; `scale`: then multiplied by it (`routed_scaling_factor`).
+    - `select_bias`: the layer passes a per-expert bias to `_route`; it is
+      added to the scores for the CHOICE of experts (after the group
+      limit's own scores, which use it too) and is no part of their
+      weights.
     - `held_start`: expert-parallel share. The stack's N experts are the
       router's outputs `[held_start, held_start + N)`. Routing, the choice
       of top-k and the normalisation are over ALL of the router's outputs;
@@ -75,15 +80,18 @@ class Routing:
     topk_group: int = 1
     scale: float = 1.0
     held_start: int = 0
+    select_bias: bool = False
+    sum_eps: float = 0.0
 
 
 _SOFTMAX_TOP_K = Routing()
 
 
 def _route(x: jnp.ndarray, gate_w: jnp.ndarray, top_k: int,
-           routing: Routing = _SOFTMAX_TOP_K):
+           routing: Routing = _SOFTMAX_TOP_K, select_bias=None):
     """(weights [T, K] f32, experts [T, K] i32) over the router's whole
-    width."""
+    width. `select_bias` [N] is the selection bias of a routing that has
+    one (`routing.select_bias`)."""
     router_logits = (x.astype(jnp.float32) @ gate_w.astype(jnp.float32))
     if routing.scoring == "softmax":
         weights = jax.nn.softmax(router_logits, axis=-1)      # [T, N]
@@ -92,21 +100,25 @@ def _route(x: jnp.ndarray, gate_w: jnp.ndarray, top_k: int,
     else:
         raise ValueError(f"unknown router scoring {routing.scoring!r}")
     choose_from = weights
+    if routing.select_bias:
+        choose_from = weights + select_bias.astype(jnp.float32)
     if routing.n_group > 1:
         t, n = weights.shape
         per = n // routing.n_group
-        group_score = weights.reshape(t, routing.n_group, per).max(-1)
+        group_score = choose_from.reshape(t, routing.n_group, per).max(-1)
         _, best = jax.lax.top_k(group_score, routing.topk_group)
         keep = jnp.zeros((t, routing.n_group), bool).at[
             jnp.arange(t)[:, None], best].set(True)
         # Scores are positive (sigmoid, softmax): -1 never wins a top-k
         # against an expert of a kept group.
-        choose_from = jnp.where(jnp.repeat(keep, per, axis=1), weights,
-                                -1.0)
+        choose_from = jnp.where(jnp.repeat(keep, per, axis=1), choose_from,
+                                -jnp.inf if routing.select_bias else -1.0)
     _, topi = jax.lax.top_k(choose_from, top_k)               # [T, K]
     topw = jnp.take_along_axis(weights, topi, axis=1)
     if routing.renormalize:
-        topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+        total = topw.sum(-1, keepdims=True)
+        topw = topw / (total + routing.sum_eps if routing.sum_eps
+                       else jnp.maximum(total, 1e-9))
     if routing.scale != 1.0:
         topw = topw * routing.scale
     return topw, topi

@@ -20,6 +20,7 @@ from intellillm_tpu.models.gpt_neox import GPTNeoXForCausalLM
 from intellillm_tpu.models.gptj import GPTJForCausalLM
 from intellillm_tpu.models.decilm import DeciLMForCausalLM
 from intellillm_tpu.models.internlm import InternLMForCausalLM
+from intellillm_tpu.models.lfm2_moe import Lfm2MoeForCausalLM
 from intellillm_tpu.models.llama import LlamaForCausalLM
 from intellillm_tpu.models.mixtral import MixtralForCausalLM
 from intellillm_tpu.models.mpt import MPTForCausalLM
@@ -65,6 +66,7 @@ _MODEL_REGISTRY: Dict[str, Type] = {
     "ChatGLMForConditionalGeneration": ChatGLMForCausalLM,
     "DeepseekForCausalLM": DeepseekForCausalLM,
     "AXK1ForCausalLM": AXK1ForCausalLM,          # latent attention + EP share
+    "Lfm2MoeForCausalLM": Lfm2MoeForCausalLM,    # short-conv state + GQA
 }
 
 

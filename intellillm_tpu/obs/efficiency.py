@@ -67,6 +67,10 @@ DEVICE_STATES = ("idle", "busy")
 JOIN_VIAS = ("cont", "fresh")
 DRAIN_CAUSES = ("memory", "params", "chunked_prefill", "bucket", "idle")
 TOKEN_KINDS = ("real", "pad")
+# What happens to a per-sequence state slot (core/block_manager.py), and
+# the device cache pools by kind.
+STATE_SLOT_EVENTS = ("alloc", "free", "swap_out", "swap_in", "fork")
+CACHE_KINDS = ("kv", "state")
 AXES = ("batch", "len", "block_width")
 _DEFAULT_MFU_WINDOW = 64
 _FILL_BUCKETS = (0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9,
@@ -158,6 +162,31 @@ class _EfficiencyMetrics:
             "step pipeline empty, by what emptied it (cause: memory | "
             "params | chunked_prefill | bucket | idle = no decode step was "
             "in flight to continue).", ["cause"])
+        self.gauge_state_slots = Gauge(
+            "intellillm_state_slots",
+            "Per-sequence state slots of a model with state layers (a "
+            "short convolution's taps), on the device (state: used | "
+            "free). One slot a running sequence; 0 / 0 for every other "
+            "model.", ["state"])
+        self.counter_state_slot_events = Counter(
+            "intellillm_state_slot_events_total",
+            "What the block manager did with state slots (event: alloc = "
+            "an admission took one; free = a sequence ended, was aborted "
+            "or was preempted by recompute; swap_out / swap_in = moved to "
+            "or from a host slot with the sequence's blocks; fork = a "
+            "child sequence took one and the parent's state was copied).",
+            ["event"])
+        self.gauge_cache_bytes = Gauge(
+            "intellillm_cache_bytes",
+            "Device bytes of the cache pools (kind: kv = the paged K/V or "
+            "latent pools of the layers that have one; state = the "
+            "per-sequence state pools).", ["kind"])
+        for state in ("used", "free"):
+            self.gauge_state_slots.labels(state).set(0)
+        for event in STATE_SLOT_EVENTS:
+            self.counter_state_slot_events.labels(event)
+        for kind in CACHE_KINDS:
+            self.gauge_cache_bytes.labels(kind).set(0)
         # Pre-create the label children so the series exist (at 0) from
         # the first scrape, before any dispatch happens: a ratio over a
         # series that never appeared reads as "no answer", not as 0.
@@ -481,6 +510,27 @@ class EfficiencyTracker:
             self._pipeline_drains[cause] += 1
         if self._metrics is not None:
             self._metrics.counter_pipeline_drains.labels(cause).inc()
+
+    def record_state_slots(self, used: int, free: int,
+                           event: Optional[str] = None) -> None:
+        """The block manager's state slots after a change, and the change
+        (one of STATE_SLOT_EVENTS) if there was one."""
+        if not self.enabled or self._metrics is None:
+            return
+        m = self._metrics
+        m.gauge_state_slots.labels("used").set(used)
+        m.gauge_state_slots.labels("free").set(free)
+        if event is not None:
+            m.counter_state_slot_events.labels(event).inc()
+
+    def record_cache_bytes(self, kv: int, state: int) -> Dict[str, int]:
+        """Sizes of the device cache pools, once at engine init; returned
+        for `/health/detail`."""
+        sizes = {"kv": int(kv), "state": int(state)}
+        if self.enabled and self._metrics is not None:
+            for kind, n in sizes.items():
+                self._metrics.gauge_cache_bytes.labels(kind).set(n)
+        return sizes
 
     def record_moe_assignments(self, here: int, total: int) -> None:
         """Token x expert choices of a finished step: `here` went to

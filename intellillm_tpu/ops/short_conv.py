@@ -1,0 +1,48 @@
+"""Seam of the gated short convolution's body (label `short_conv`).
+
+`layers/short_conv.py` calls this and nothing below it; it asks
+`ops/dispatch.use_kernel` once and runs the Pallas kernel
+(`ops/pallas/short_conv.py`, `_short_conv_call` in the device trace) or
+the jnp oracle below, the same arithmetic: float32 inside, `g` rounded to
+the activations' dtype before the filter reads it (a later step reads it
+from the state at that precision).
+"""
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax.numpy as jnp
+
+from intellillm_tpu.ops.dispatch import use_kernel
+from intellillm_tpu.ops.pallas.short_conv import (short_conv,
+                                                  short_conv_supported)
+
+
+def gated_input(bcx: jnp.ndarray) -> jnp.ndarray:
+    """g = B * x of an in-projection `[B | C | x]`, as the state stores
+    it."""
+    e = bcx.shape[-1] // 3
+    f32 = jnp.float32
+    return (bcx[..., :e].astype(f32) * bcx[..., 2 * e:].astype(f32)).astype(
+        bcx.dtype)
+
+
+def short_conv_body(bcx: jnp.ndarray, p1: jnp.ndarray, p2: jnp.ndarray,
+                    w: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(y, g) [T, E] of the in-projection `bcx` [T, 3E], the gated inputs
+    one (`p1`) and two (`p2`) positions back, and the filter `w` [3, E]
+    (tap 0 the oldest)."""
+    if use_kernel("short_conv", short_conv_supported(bcx)):
+        return short_conv(bcx, p1, p2, w)
+    return short_conv_body_reference(bcx, p1, p2, w)
+
+
+def short_conv_body_reference(bcx, p1, p2, w):
+    e = bcx.shape[-1] // 3
+    f32 = jnp.float32
+    g = gated_input(bcx)
+    w = w.astype(f32)
+    conv = (w[0] * p2.astype(f32) + w[1] * p1.astype(f32)
+            + w[2] * g.astype(f32))
+    y = (bcx[..., e:2 * e].astype(f32) * conv).astype(bcx.dtype)
+    return y, g

@@ -435,6 +435,9 @@ class SequenceGroupMetadata:
     # a whole-phase (legacy homogeneous) entry.
     token_chunk_size: Optional[int] = None
     num_computed_tokens: int = 0
+    # seq_id -> the sequence's slot in the per-sequence state pools
+    # (models with "state" layers); None for every other model.
+    state_slots: Optional[Dict[int, int]] = None
 
     @property
     def lora_int_id(self) -> int:

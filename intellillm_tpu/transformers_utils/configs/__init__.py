@@ -13,6 +13,7 @@ from intellillm_tpu.transformers_utils.configs.chatglm import ChatGLMConfig
 from intellillm_tpu.transformers_utils.configs.decilm import DeciLMConfig
 from intellillm_tpu.transformers_utils.configs.deepseek import DeepseekConfig
 from intellillm_tpu.transformers_utils.configs.internlm import InternLMConfig
+from intellillm_tpu.transformers_utils.configs.lfm2_moe import Lfm2MoeConfig
 from intellillm_tpu.transformers_utils.configs.qwen import QWenConfig
 from intellillm_tpu.transformers_utils.configs.yi import YiConfig
 
@@ -24,6 +25,7 @@ _CONFIG_REGISTRY = {
     "deci": DeciLMConfig,
     "deepseek": DeepseekConfig,
     "internlm": InternLMConfig,
+    "lfm2_moe": Lfm2MoeConfig,
     "qwen": QWenConfig,
     "Yi": YiConfig,
     "yi": YiConfig,
@@ -31,6 +33,7 @@ _CONFIG_REGISTRY = {
 
 __all__ = [
     "AquilaConfig", "AXK1Config", "BaichuanConfig", "ChatGLMConfig", "DeciLMConfig",
-    "DeepseekConfig", "InternLMConfig", "QWenConfig", "YiConfig",
+    "DeepseekConfig", "InternLMConfig", "Lfm2MoeConfig", "QWenConfig",
+    "YiConfig",
     "_CONFIG_REGISTRY",
 ]

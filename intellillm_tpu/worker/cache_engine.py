@@ -17,6 +17,14 @@ TPU redesign:
   V. Every block op below maps over the tuple, so swap, copy and the KV
   audit's swap checksums work on both; KV export/import speaks (K, V) on
   the wire and refuses the latent kind with a message.
+- Layers are described one by one (`ModelConfig.get_layer_cache_kinds`):
+  a "kv" layer has the pools above; a "state" layer (a short convolution)
+  has ONE array `[num_state_slots, taps * width]` (a slot's taps side by
+  side, oldest first: whole lane tiles, no padded sublanes), a slot a
+  running sequence, not paged. `device_cache[i]` is layer i's tuple either way.
+  Block ops touch the "kv" layers, the state ops (`swap_state_out`,
+  `swap_state_in`, `copy_state`) the "state" layers, by slot. Models
+  whose layers are all "kv" have no state pool and run no state op.
 - Swaps are jax device↔host transfers (no CUDA streams/events; JAX's async
   dispatch overlaps them with compute until the arrays are consumed).
 - Copies (CoW) are fused gather/scatter updates executed functionally; the
@@ -54,7 +62,16 @@ class CacheEngine:
         self.model_config = model_config
         self.parallel_config = parallel_config
 
-        self.num_layers = model_config.get_num_layers()
+        # One entry a layer: "kv" (paged pools) or "state" (slots).
+        self.layer_kinds = model_config.get_layer_cache_kinds()
+        self.num_layers = len(self.layer_kinds)
+        self.kv_layers = [i for i, kind in enumerate(self.layer_kinds)
+                          if kind == "kv"]
+        self.state_layers = [i for i, kind in enumerate(self.layer_kinds)
+                             if kind == "state"]
+        self.state_row_shape = model_config.get_state_row_shape()
+        self.num_state_slots = (cache_config.num_state_slots
+                                if self.state_layers else 0)
         # What a token stores in a layer (`get_cache_row_shape`): 2 pools
         # (K, V) of kv heads x head size, or the 1 latent pool of one
         # "head" of the stored latent width. The head count is the full
@@ -62,7 +79,9 @@ class CacheEngine:
         # over the mesh "model" axis by the head dim.
         self.num_pools, self.num_kv_heads, self.head_size = \
             model_config.get_cache_row_shape()
-        self.latent = model_config.get_latent_cache_width() is not None
+        # What the IKV1 wire format cannot carry, if anything.
+        self.cache_kind = ("latent" if model_config.get_latent_cache_width()
+                           else "state" if self.state_layers else "kv")
 
         self.block_size = cache_config.block_size
         self.num_device_blocks = cache_config.num_device_blocks
@@ -77,6 +96,7 @@ class CacheEngine:
         self.device_cache: List[KVCache] = self._allocate_device_cache()
         self.cpu_cache: List[Tuple[np.ndarray, ...]] = \
             self._allocate_cpu_cache()
+        self.state_slot_bytes = self.get_state_slot_size(model_config)
 
         # Byte sizes for the obs swap accounting: swaps move host↔device
         # payload (unpadded logical bytes); CoW copies move on-device
@@ -102,10 +122,21 @@ class CacheEngine:
         return (num_blocks, self.num_kv_heads, self.block_size,
                 self.head_size)
 
+    def _state_shape(self) -> Tuple[int, ...]:
+        # [slots, taps * width]; the same number of slots on the host.
+        taps, width = self.state_row_shape
+        return (self.num_state_slots, taps * width)
+
     def _allocate_device_cache(self) -> List[KVCache]:
         shape = self._block_shape(self.num_device_blocks)
         caches = []
-        for _ in range(self.num_layers):
+        for kind in self.layer_kinds:
+            if kind == "state":
+                # The state keeps the activations' dtype whatever the KV
+                # pool's (`state_dtype`).
+                caches.append((jnp.zeros(self._state_shape(),
+                                         dtype=self.state_dtype), ))
+                continue
             pools = []
             for _ in range(self.num_pools):
                 pool = jnp.zeros(shape, dtype=self.dtype)
@@ -115,24 +146,34 @@ class CacheEngine:
             caches.append(tuple(pools))
         return caches
 
+    @property
+    def state_dtype(self):
+        return jnp.dtype(STR_DTYPE_TO_JNP[self.model_config.dtype])
+
+    @staticmethod
+    def _host_dtype(dtype) -> np.dtype:
+        if dtype in (jnp.float32, jnp.float16):
+            return np.dtype(dtype.name)
+        # bf16 / fp8 swap pools keep the device dtype bit-for-bit via
+        # ml_dtypes so swap in/out is lossless.
+        import ml_dtypes
+        return np.dtype(getattr(ml_dtypes, dtype.name))
+
     def _allocate_cpu_cache(self):
         shape = self._block_shape(self.num_cpu_blocks)
-        if self.dtype in (jnp.float32, jnp.float16):
-            np_dtype = np.dtype(self.dtype.name)
-        else:
-            # bf16 / fp8 swap pools keep the device dtype bit-for-bit via
-            # ml_dtypes so swap in/out is lossless.
-            import ml_dtypes
-            np_dtype = np.dtype(getattr(ml_dtypes, self.dtype.name))
-        return [tuple(np.zeros(shape, dtype=np_dtype)
+        np_dtype = self._host_dtype(self.dtype)
+        return [(np.zeros(self._state_shape(),
+                          dtype=self._host_dtype(self.state_dtype)), )
+                if kind == "state" else
+                tuple(np.zeros(shape, dtype=np_dtype)
                       for _ in range(self.num_pools))
-                for _ in range(self.num_layers)]
+                for kind in self.layer_kinds]
 
     # --- block-op execution ---------------------------------------------
 
     def swap_in(self, src_to_dst: Dict[int, int]) -> None:
         audit = self._kv_audit
-        for i in range(self.num_layers):
+        for i in self.kv_layers:
             if audit.enabled:
                 # Verify sampled host blocks BEFORE they re-enter the
                 # device pool: a bit that flipped while the block sat
@@ -152,7 +193,7 @@ class CacheEngine:
 
     def swap_out(self, src_to_dst: Dict[int, int]) -> None:
         audit = self._kv_audit
-        for i in range(self.num_layers):
+        for i in self.kv_layers:
             for dev, cpu in zip(self.device_cache[i], self.cpu_cache[i]):
                 swap_blocks(dev, cpu, src_to_dst, direction="out")
             if audit.enabled:
@@ -180,7 +221,7 @@ class CacheEngine:
         of the fixed swap pool, so exports never contend with scheduler
         swap plans for CPU block numbers.
         """
-        self._refuse_latent("export")
+        self._refuse_kv_transfer("export")
         src_to_dst = {int(b): j for j, b in enumerate(block_numbers)}
         shape = self._block_shape(len(block_numbers))
         np_dtype = self.cpu_cache[0][0].dtype if self.cpu_cache else \
@@ -208,7 +249,7 @@ class CacheEngine:
                       block_numbers: List[int]) -> None:
         """Scatter a KV handoff payload into device blocks (inverse of
         export_blocks; staging slot j → device block j's target)."""
-        self._refuse_latent("import")
+        self._refuse_kv_transfer("import")
         if len(layers) != self.num_layers:
             raise ValueError(f"payload has {len(layers)} layers, cache has "
                              f"{self.num_layers}")
@@ -227,19 +268,69 @@ class CacheEngine:
         self._telemetry.record_swap("in", len(block_numbers),
                                     self.logical_block_bytes)
 
-    def _refuse_latent(self, what: str) -> None:
-        if self.latent:
+    # What KV export/import cannot carry, by cache kind.
+    _KV_TRANSFER_REFUSALS = {
+        "latent": "this model caches one latent pool a layer",
+        "state": "this model keeps a per-sequence convolution state "
+                 "beside its K/V pools, which a prefix of blocks does not "
+                 "hold (it would need a state snapshot at the prefix's end)",
+    }
+
+    def _refuse_kv_transfer(self, what: str) -> None:
+        why = self._KV_TRANSFER_REFUSALS.get(self.cache_kind)
+        if why:
             raise NotImplementedError(
                 f"KV {what} (worker/kv_transfer.py) carries per-layer K and "
-                f"V slabs; this model caches one latent pool a layer, which "
-                f"the IKV1 wire format cannot describe. Disaggregated "
-                f"prefill/decode is not supported for it yet.")
+                f"V slabs; {why}, which the IKV1 wire format cannot "
+                f"describe. Disaggregated prefill/decode is not supported "
+                f"for it yet.")
+
+    def _copy_rows(self, layers: List[int],
+                   src_to_dsts: Dict[int, List[int]]) -> None:
+        """Rows (blocks, or slots) src -> dsts in every pool of `layers`."""
+        copied = copy_blocks([self.device_cache[i] for i in layers],
+                             src_to_dsts)
+        for i, pools in zip(layers, copied):
+            self.device_cache[i] = pools
 
     def copy(self, src_to_dsts: Dict[int, List[int]]) -> None:
-        self.device_cache = copy_blocks(self.device_cache, src_to_dsts)
+        self._copy_rows(self.kv_layers, src_to_dsts)
         self._telemetry.record_swap(
             "copy", sum(len(dsts) for dsts in src_to_dsts.values()),
             self.device_block_bytes)
+
+    # --- per-sequence state ops (by slot) ---------------------------------
+
+    def swap_state_out(self, src_to_dst: Dict[int, int]) -> None:
+        """Device slot -> host slot, every "state" layer."""
+        for i in self.state_layers:
+            swap_blocks(self.device_cache[i][0], self.cpu_cache[i][0],
+                        src_to_dst, direction="out")
+
+    def swap_state_in(self, src_to_dst: Dict[int, int]) -> None:
+        """Host slot -> device slot, every "state" layer."""
+        for i in self.state_layers:
+            self.device_cache[i] = (swap_blocks(
+                self.cpu_cache[i][0], self.device_cache[i][0], src_to_dst,
+                direction="in"), )
+
+    def copy_state(self, src_to_dsts: Dict[int, List[int]]) -> None:
+        """A fork: the parent's slot copied to each child's."""
+        self._copy_rows(self.state_layers, src_to_dsts)
+
+    def execute_state_ops(self, ops: List[Tuple[str, int, int]]) -> None:
+        """The block manager's state moves, in the order it made them:
+        ("swap_out" | "swap_in" | "fork", source slot, destination
+        slot)."""
+        for op, src, dst in ops:
+            if op == "swap_out":
+                self.swap_state_out({src: dst})
+            elif op == "swap_in":
+                self.swap_state_in({src: dst})
+            elif op == "fork":
+                self.copy_state({src: [dst]})
+            else:
+                raise ValueError(f"unknown state op {op!r}")
 
     # --- sizing ----------------------------------------------------------
 
@@ -265,7 +356,7 @@ class CacheEngine:
         """
         num_pools, num_kv_heads, head_size = \
             model_config.get_cache_row_shape()
-        num_layers = model_config.get_num_layers()
+        num_layers = model_config.get_num_kv_layers()
         if cache_dtype == "auto":
             cache_dtype = model_config.dtype
         itemsize = jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]).itemsize
@@ -293,9 +384,23 @@ class CacheEngine:
         TPU tiling."""
         num_pools, num_kv_heads, head_size = \
             model_config.get_cache_row_shape()
-        num_layers = model_config.get_num_layers()
+        num_layers = model_config.get_num_kv_layers()
         if cache_dtype == "auto":
             cache_dtype = model_config.dtype
         itemsize = jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]).itemsize
         return (num_pools * num_layers * num_kv_heads * block_size *
                 head_size * itemsize)
+
+    @staticmethod
+    def get_state_slot_size(model_config: ModelConfig) -> int:
+        """Bytes of one state slot across the "state" layers (0 for a
+        model without them), on the device: a slot's row padded to whole
+        lanes. The state is stored in the model's dtype: the cache dtype
+        quantises K/V only."""
+        row = model_config.get_state_row_shape()
+        if row is None:
+            return 0
+        taps, width = row
+        itemsize = jnp.dtype(STR_DTYPE_TO_JNP[model_config.dtype]).itemsize
+        return (model_config.get_num_state_layers()
+                * (-(-taps * width // 128) * 128) * itemsize)

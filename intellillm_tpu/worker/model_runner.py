@@ -92,6 +92,9 @@ class DecodeContState:
         self.num_steps = num_steps      # K of the fused program
         self.owners = None              # engine fills, per row:
         #                                 (Sequence, its meta, its group)
+        self.state_slots = None         # per row (`rows` order): the
+        #                                 sequence's state slot, for a
+        #                                 model with state layers
 
 
 # What an empty slot of a continuation samples with: greedy, so that an
@@ -228,6 +231,14 @@ class ModelRunner:
         else:
             from intellillm_tpu.ops.ragged_attention import walk_extent
         self._walk_extent = walk_extent
+        # What each layer caches (`ModelConfig.get_layer_cache_kinds`).
+        # A model with "state" layers gives every row of a step the state
+        # slot of its sequence (`AttentionMetadata.state_slots`); the
+        # programs of every other model take no such argument.
+        self.layer_kinds = model_config.get_layer_cache_kinds()
+        self.stateful = "state" in self.layer_kinds
+        self._kv0 = self.layer_kinds.index("kv")
+        self.num_state_slots = cache_config.num_state_slots
         from intellillm_tpu.layers.attention import model_uses_alibi
         self._uses_alibi = model_uses_alibi(model)
         self.vocab_size = model_config.get_vocab_size()
@@ -318,7 +329,19 @@ class ModelRunner:
             sds(f32, b), sds(f32, b), sds(u32, b), sds(f32, b), sds(f32, b),
             sds(f32, b), None, None, logprob_k=logprob_k, do_topk=False,
             do_topp=False, do_minp=False, do_penalties=False,
-            do_random=do_random)
+            do_random=do_random,
+            **({"state_slots": sds(i32, b)} if self.stateful else {}))
+
+    def state_slot_kwargs(self, row_slots, padded_n: int) -> Dict[str, Any]:
+        """The `state_slots` argument of a step program: `row_slots` (one
+        a live row, in row order) padded with a slot past the pool, as
+        `slot_mapping` pads. Empty for a model without state layers, whose
+        programs are then called exactly as before."""
+        if not self.stateful:
+            return {}
+        slots = np.full(padded_n, self.num_state_slots, np.int32)
+        slots[:len(row_slots)] = row_slots
+        return {"state_slots": self._place_batch_array(slots)}
 
     def _device_idle(self) -> bool:
         """Whether nothing dispatched earlier is still running on the
@@ -513,9 +536,9 @@ class ModelRunner:
                         join_mask, positions, block_tables, context_lens,
                         temperatures, top_ks, top_ps, min_ps, seeds,
                         pres_pen, freq_pen, rep_pen, prompt_tokens,
-                        output_tokens, lora=None, *, prev_t1, num_steps,
-                        logprob_k, do_topk, do_topp, do_minp, do_penalties,
-                        do_random=True):
+                        output_tokens, lora=None, state_slots=None, *,
+                        prev_t1, num_steps, logprob_k, do_topk, do_topp,
+                        do_minp, do_penalties, do_random=True):
         """Continuation of a fused decode: a row's input token is the last
         substep's sample from the previous step's packed output (column
         prev_t1-1 of the _pack layout), so the host never needs the
@@ -531,7 +554,8 @@ class ModelRunner:
             pres_pen, freq_pen, rep_pen, prompt_tokens, output_tokens,
             lora, num_steps=num_steps, logprob_k=logprob_k,
             do_topk=do_topk, do_topp=do_topp, do_minp=do_minp,
-            do_penalties=do_penalties, do_random=do_random)
+            do_penalties=do_penalties, do_random=do_random,
+            state_slots=state_slots)
 
     def _decode_teacher_fn(self, params, kv_caches, teacher_tokens,
                            positions, block_tables, context_lens,
@@ -562,7 +586,7 @@ class ModelRunner:
                    min_ps, seeds, pres_pen, freq_pen, rep_pen, prompt_tokens,
                    output_tokens, lora=None, *, num_steps, logprob_k,
                    do_topk, do_topp, do_minp, do_penalties,
-                   do_random=True, teacher_tokens=None):
+                   do_random=True, teacher_tokens=None, state_slots=None):
         """K fused decode iterations (staged, chunked).
 
         The paged pool stays loop-invariant (read-only) through each scan —
@@ -586,9 +610,12 @@ class ModelRunner:
         b = token_ids.shape[0]
         base_pos = positions[:, 0]              # [B] = n-1
         base_ctx = context_lens                 # [B] = n (0 for pad rows)
-        hkv = kv_caches[0][0].shape[1]
-        d = kv_caches[0][0].shape[3]
-        cache_dtype = kv_caches[0][0].dtype
+        kinds = self.layer_kinds
+        kv0 = kv_caches[self._kv0][0]
+        hkv, d, cache_dtype = kv0.shape[1], kv0.shape[3], kv0.dtype
+        state_stages = {
+            i: pools[0].at[state_slots].get(mode="fill", fill_value=0)
+            for i, pools in enumerate(kv_caches) if kinds[i] == "state"}
 
         # Chunk schedule: full chunks plus a shorter tail when K is not a
         # multiple (e.g. K=40, C=16 → [16, 16, 8]). decode_chunk <= 0
@@ -619,6 +646,7 @@ class ModelRunner:
                     staged=True,
                     stage_index=k,
                     mesh=self.mesh,
+                    state_slots=state_slots,
                 )
                 # A layer's cache is (K, V) or the 1-tuple (latent pool,);
                 # staged, the layer gets its pools then their stages.
@@ -657,8 +685,10 @@ class ModelRunner:
                 base_ctx > 0,
                 jnp.minimum(base_ctx - 1 + chunk_base, self.max_model_len),
                 0)
-            stages = [tuple(jnp.zeros((b, csize, hkv, d), cache_dtype)
-                            for _ in pools) for pools in cur_caches]
+            stages = [(state_stages[i], ) if kinds[i] == "state" else
+                      tuple(jnp.zeros((b, csize, hkv, d), cache_dtype)
+                            for _ in pools)
+                      for i, pools in enumerate(cur_caches)]
             (cur_tokens, stages), ys = jax.lax.scan(
                 make_substep(pool_ctx, cur_caches, chunk_base),
                 (cur_tokens, stages),
@@ -674,13 +704,22 @@ class ModelRunner:
             n_valid = jnp.where(
                 base_ctx > 0,
                 jnp.clip(self.max_model_len - start, 0, csize), 0)
+            state_stages = {i: stages[i][0] for i in state_stages}
             cur_caches = [
+                pools if kinds[i] == "state" else
                 commit_staged_chunk(stage, pools, start, n_valid,
                                     block_tables)
-                for pools, stage in zip(cur_caches, stages)]
+                for i, (pools, stage) in enumerate(zip(cur_caches, stages))]
             chunk_base += csize
 
         new_caches = cur_caches
+        if state_stages:
+            dst = jnp.where(base_ctx > 0, state_slots,
+                            self.num_state_slots)
+            new_caches = [
+                (pools[0].at[dst].set(state_stages[i], mode="drop"), )
+                if kinds[i] == "state" else pools
+                for i, pools in enumerate(cur_caches)]
         # [K, B, ...] per ys leaf, chunks concatenated along the step axis.
         ys = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0),
                           *ys_chunks) if len(ys_chunks) > 1 else ys_chunks[0]
@@ -699,7 +738,7 @@ class ModelRunner:
                           top_ps, min_ps, seeds, pres_pen, freq_pen, rep_pen,
                           prompt_tokens, output_tokens, lora=None,
                           fetch_indices=None, plp_targets=None,
-                          numerics_inject=None, *,
+                          numerics_inject=None, state_slots=None, *,
                           num_samples=1, plp_k=0, do_numerics=False,
                           logprob_k, do_topk, do_topp, do_minp,
                           do_penalties, do_random=True):
@@ -717,7 +756,7 @@ class ModelRunner:
         b = token_ids.shape[0]
         pos = positions[:, 0]
         ctx = context_lens
-        nb = kv_caches[0][0].shape[0]
+        nb = kv_caches[self._kv0][0].shape[0]
 
         li = pos // bs
         if wb is not None:
@@ -731,6 +770,7 @@ class ModelRunner:
             context_lens=ctx,
             block_tables=block_tables,
             mesh=self.mesh,
+            state_slots=state_slots,
         )
         hidden, new_caches, held = self._call_model(
             params, token_ids, pos[:, None], kv_caches, meta, lora)
@@ -796,6 +836,7 @@ class ModelRunner:
         poss: List[int] = []
         ctxs: List[int] = []
         tables: List[List[int]] = []
+        row_slots: List[int] = []
 
         for meta in seq_group_metadata_list:
             assert not meta.is_prompt
@@ -806,6 +847,8 @@ class ModelRunner:
                 poss.append(n - 1)
                 ctxs.append(n)
                 tables.append(list(meta.block_tables[seq_id]))
+                if self.stateful:
+                    row_slots.append(meta.state_slots[seq_id])
 
         b = self.decode_batch_bucket(len(rows))
         w = pad_to_bucket(max(max(len(t) for t in tables),
@@ -817,6 +860,7 @@ class ModelRunner:
 
         arrays = {"token_ids": token_ids, "positions": positions,
                   "context_lens": context_lens, "block_tables": block_tables}
+        arrays["_state"] = self.state_slot_kwargs(row_slots, b)
         arrays["_eff"] = {
             "real_rows": len(rows),
             "ctxs": np.asarray(ctxs, np.int64),
@@ -924,6 +968,7 @@ class ModelRunner:
             arrays, rows = self._prepare_decode(seq_group_metadata_list)
 
             eff_info = arrays.pop("_eff")
+            state_kwargs = arrays.pop("_state")
             padded_n = arrays["token_ids"].shape[0]
 
             # Per-row sampling params / seeds / token histories.
@@ -1015,7 +1060,7 @@ class ModelRunner:
                     program, bucket, self._jit_decode_single,
                     *decode_args,
                     place(fetch_indices) if fetch_indices is not None
-                    else None, **common, **numerics_kwargs)
+                    else None, **common, **numerics_kwargs, **state_kwargs)
             result = list(result)
             packed = result.pop(0)
             fetched = result.pop(0) if proc_rows else None
@@ -1033,7 +1078,8 @@ class ModelRunner:
             with self._tracer.span("execute"):
                 packed, new_caches = self._guarded_call(
                     program, bucket, self._jit_decode,
-                    *decode_args, num_steps=num_steps, **common)
+                    *decode_args, num_steps=num_steps, **common,
+                    **state_kwargs)
         self._last_packed = packed
         t1 = t2 = num_steps
 
@@ -1072,6 +1118,10 @@ class ModelRunner:
                 row_params + [PAD_PARAMS] * pad,
                 None if row_loras is None else row_loras + [None] * pad,
                 num_steps)
+            if self.stateful:
+                step.cont_state.state_slots = [
+                    meta_by_req[req_id].state_slots[seq_id]
+                    for req_id, seq_id in rows]
         if defer_fetch:
             return step, new_caches
         return step.finalize(), new_caches
@@ -1116,6 +1166,7 @@ class ModelRunner:
             row_seeds: List[int] = []
             row_tokens: List[Tuple[np.ndarray, np.ndarray]] = []
             row_loras_src: List[Any] = []
+            row_slots: List[int] = []
             # Flat-row emission subset: all decode rows; only the LAST
             # row of a FINAL chunk (mid-prompt rows' samples are
             # meaningless).
@@ -1161,6 +1212,8 @@ class ModelRunner:
                         row_seeds.append(seed)
                         row_tokens.append(views)
                         row_loras_src.append(meta.lora_request)
+                        if self.stateful:
+                            row_slots.append(meta.state_slots[seq_id])
                         if want_plp and pos + 1 < n_prompt:
                             plp_jobs.append((len(rows) - 1,
                                              sp.prompt_logprobs, data,
@@ -1190,6 +1243,8 @@ class ModelRunner:
                                            sp, data))
                         row_tokens.append(data.token_views())
                         row_loras_src.append(meta.lora_request)
+                        if self.stateful:
+                            row_slots.append(meta.state_slots[seq_id])
                         emit_idx.append(len(rows) - 1)
                         emit_rows.append((meta.request_id, seq_id))
                         n_decode_rows += 1
@@ -1235,6 +1290,7 @@ class ModelRunner:
                 do_penalties=st.do_penalties, do_random=st.do_random,
             )
             sampling_args = self._sampling_args_device(st, padded_n)
+            state_kwargs = self.state_slot_kwargs(row_slots, padded_n)
 
         # Numerics sentinels: when OFF the dispatch binds exactly as the
         # pre-sentinel code did (no extra kwargs → identical jit cache
@@ -1265,7 +1321,7 @@ class ModelRunner:
                 place(fetch_indices) if fetch_indices is not None else None,
                 place(plp_targets) if plp_k else None,
                 num_samples=num_samples, plp_k=plp_k, **common,
-                **numerics_kwargs)
+                **numerics_kwargs, **state_kwargs)
         result = list(result)
         packed = result.pop(0)
         plp_dev = result.pop(0) if plp_k else None
@@ -1368,6 +1424,13 @@ class ModelRunner:
 
             place = self._place_batch_array
             sampling_args = self._sampling_args_device(st, b)
+            state_kwargs = {}
+            if self.stateful:
+                # By batch slot, not by row order: a continuation's rows
+                # keep the seats they had.
+                slots_arr = np.full(b, self.num_state_slots, np.int32)
+                slots_arr[cont.slots] = cont.state_slots
+                state_kwargs = {"state_slots": place(slots_arr)}
         flags = dict(logprob_k=st.logprob_k, do_topk=st.do_topk,
                      do_topp=st.do_topp, do_minp=st.do_minp,
                      do_penalties=False, do_random=st.do_random)
@@ -1382,7 +1445,8 @@ class ModelRunner:
                 self.params, kv_caches, prev_packed, place(join_tokens),
                 place(join_mask), place(positions), place(block_tables),
                 place(ctx), *sampling_args, lora_state,
-                prev_t1=prev_t1, num_steps=num_steps, **flags)
+                prev_t1=prev_t1, num_steps=num_steps, **flags,
+                **state_kwargs)
         self._last_packed = packed
 
         live_rows = len(cont.rows)
@@ -1421,6 +1485,7 @@ class ModelRunner:
         with self._tracer.span("prepare_inputs"):
             arrays, rows = self._prepare_decode(seq_group_metadata_list)
         eff_info = arrays.pop("_eff")
+        arrays.pop("_state")    # speculative decoding refuses state models
         padded_n = arrays["token_ids"].shape[0]
         teacher = np.zeros((padded_n, num_steps), np.int32)
         for i, toks in enumerate(teacher_rows):

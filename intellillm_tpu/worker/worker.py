@@ -59,6 +59,10 @@ class Worker:
         self.params = None
         self.model_runner: Optional[ModelRunner] = None
         self.cache_engine: Optional[CacheEngine] = None
+        # Per-sequence state moves waiting for the next device step: the
+        # engine binds this to the block manager's own list
+        # (core/block_manager.py `state_ops`).
+        self.state_ops: List[Tuple[str, int, int]] = []
         # Whether warm-up should compile the pipelined-continuation
         # program (SpecDecodeWorker disables it: spec mode never
         # pipelines, and warms its own teacher/draft programs instead).
@@ -144,12 +148,18 @@ class Worker:
         from intellillm_tpu.utils import STR_DTYPE_TO_JNP as _M
         stage_dtype = (self.model_config.dtype
                        if cache_dtype == "auto" else cache_dtype)
-        stage_bytes = (pools * self.model_config.get_num_layers() *
+        stage_bytes = (pools * self.model_config.get_num_kv_layers() *
                        self.scheduler_config.max_num_seqs * k_steps *
                        nkv * row_width *
                        _jnp.dtype(_M[stage_dtype]).itemsize)
         temp_bytes += stage_bytes + int(0.10 * weights_bytes)
-        available = int(total * hbm_utilization) - weights_bytes - temp_bytes
+        # Per-sequence state pools (0 for a model without state layers):
+        # a fixed size beside the blocks, and once more as the decode
+        # programs' stage of the rows' own states.
+        state_bytes = 2 * (CacheEngine.get_state_slot_size(self.model_config)
+                           * self.scheduler_config.max_num_seqs)
+        available = (int(total * hbm_utilization) - weights_bytes
+                     - temp_bytes - state_bytes)
         num_device_blocks = max(available // block_bytes_per_chip, 0)
         logger.info(
             "Memory profile (per chip): total=%.2fGiB weights=%.2fGiB "
@@ -180,7 +190,6 @@ class Worker:
 
         from intellillm_tpu.utils import STR_DTYPE_TO_JNP
         pools, nkv, hs = self.model_config.get_cache_row_shape()
-        nl = self.model_config.get_num_layers()
         cache_dtype = (self.model_config.dtype
                        if self.cache_config.cache_dtype == "auto" else
                        self.cache_config.cache_dtype)
@@ -188,7 +197,14 @@ class Worker:
         cache_shape = jax.ShapeDtypeStruct(
             (dummy_blocks, nkv, self.cache_config.block_size, hs),
             jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]))
-        kv_struct = [(cache_shape, ) * pools for _ in range(nl)]
+        state_row = self.model_config.get_state_row_shape()
+        state_shape = state_row and jax.ShapeDtypeStruct(
+            (self.scheduler_config.max_num_seqs,
+             state_row[0] * state_row[1]),
+            jnp.dtype(STR_DTYPE_TO_JNP[self.model_config.dtype]))
+        kv_struct = [(state_shape, ) if kind == "state"
+                     else (cache_shape, ) * pools
+                     for kind in self.model_config.get_layer_cache_kinds()]
 
         lowered = runner.lower_mixed_step(kv_struct, b, w, logprob_k=8)
         return int(lowered.compile().memory_analysis().temp_size_in_bytes)
@@ -226,6 +242,10 @@ class Worker:
             logical = CacheEngine.get_logical_cache_block_size(
                 cc.block_size, cc.cache_dtype, self.model_config)
             ledger["cpu_swap_pool"] = logical * (cc.num_cpu_blocks or 0)
+            state = (CacheEngine.get_state_slot_size(self.model_config)
+                     * getattr(cc, "num_state_slots", 0))
+            if state:
+                ledger["state_pool"] = state
         return ledger
 
     def warm_up_model(self):
@@ -343,10 +363,12 @@ class Worker:
                     do_numerics=True,
                     numerics_inject=place(np.zeros(b, np.float32)))
                     if num_on else {})
+                # A model with state layers: every row a pad row's slot.
+                state_kwargs = runner.state_slot_kwargs([], b)
                 for flags in flag_variants:
                     result = runner._jit_decode_single(
                         self.params, self.cache_engine.device_cache,
-                        *args, **flags, **numerics_kwargs)
+                        *args, **flags, **numerics_kwargs, **state_kwargs)
                     # (packed, [sentinel panel,] caches) — the panel
                     # rides along only under --enable-numerics.
                     packed, caches = result[0], result[-1]
@@ -365,7 +387,8 @@ class Worker:
                         result = runner._jit_decode_single(
                             self.params,
                             self.cache_engine.device_cache,
-                            *fargs, **flags, **numerics_kwargs)
+                            *fargs, **flags, **numerics_kwargs,
+                            **state_kwargs)
                         packed, caches = result[0], result[-1]
                         self.cache_engine.device_cache = caches
                         n += 1
@@ -373,7 +396,7 @@ class Worker:
                     if full and k > 1:
                         packed, caches = runner._jit_decode(
                             self.params, self.cache_engine.device_cache,
-                            *args, num_steps=k, **flags)
+                            *args, num_steps=k, **flags, **state_kwargs)
                         self.cache_engine.device_cache = caches
                         n += 1
                         if self.warm_cont_program:
@@ -389,7 +412,7 @@ class Worker:
                                 packed, zeros_i,
                                 place(np.zeros((b, 1), np.bool_)),
                                 *args[1:], prev_t1=k,
-                                num_steps=k, **flags)
+                                num_steps=k, **flags, **state_kwargs)
                             self.cache_engine.device_cache = caches
                             n += 1
                     # lint: allow(host-sync) reason=warm-up runs before serving; blocking here ensures executables are resident and the logged compile wall-time is honest
@@ -421,9 +444,11 @@ class Worker:
         """Returns one SamplerOutput per fused decode substep (length 1 for
         prompt runs and unfused decodes). With `defer_fetch`, returns the
         dispatched-but-unfetched InflightStep instead (pipelined path)."""
-        if blocks_to_swap_out or blocks_to_swap_in or blocks_to_copy:
+        if (blocks_to_swap_out or blocks_to_swap_in or blocks_to_copy
+                or self.state_ops):
             from intellillm_tpu.obs import get_step_tracer
             with get_step_tracer().span("swap_copy"):
+                self._run_state_ops()
                 if blocks_to_swap_out:
                     self.cache_engine.swap_out(blocks_to_swap_out)
                 if blocks_to_swap_in:
@@ -440,10 +465,17 @@ class Worker:
         self.cache_engine.device_cache = new_caches
         return outputs
 
+    def _run_state_ops(self) -> None:
+        if self.state_ops:
+            ops = list(self.state_ops)
+            self.state_ops.clear()
+            self.cache_engine.execute_state_ops(ops)
+
     def execute_decode_cont(self, cont, tables, join_tokens, prev_packed,
                             prev_t1: int):
         """Dispatch a pipelined decode continuation (no swaps/copies — the
         engine only continues batches with no pending block ops)."""
+        self._run_state_ops()
         step, new_caches = self.model_runner.execute_decode_cont(
             cont, tables, join_tokens, prev_packed, prev_t1,
             self.cache_engine.device_cache, defer_fetch=True)

@@ -147,7 +147,9 @@ def test_ledger_math_against_fake_param_tree_and_cache_sizing():
     model_config = SimpleNamespace(
         dtype="float32",
         get_cache_row_shape=lambda: (2, 4, 16),
-        get_num_layers=lambda: 2)
+        get_num_layers=lambda: 2,
+        get_num_kv_layers=lambda: 2,
+        get_state_row_shape=lambda: None)
     w = Worker.__new__(Worker)
     w.params = params
     w.model_config = model_config

@@ -533,3 +533,87 @@ def test_joined_continuation_is_the_plain_one_s_executable(
     snap = tracker.snapshot()
     assert snap["compiles"]["decode_cont"] == 1
     assert runner._jit_decode_cont._cache_size() == 1
+
+
+# --- a hybrid stack: state layers, 64-wide heads stored at 128 lanes --------
+
+@pytest.mark.parametrize("program", ["mixed", "decode_cont"])
+def test_state_model_programs_compile(compiled_for_chip, one_chip, tmp_path,
+                                      program):
+    """`lfm2-8b-a1b-l14`'s programs as its cell dispatches them (`mixed`
+    at 512 rows, `decode_cont` at 128 rows x 64-block tables, K=8, bf16,
+    published widths; depth cut to the two dense conv layers + one period)
+    compile for the described v5e with the state slots' argument, and hold
+    the attention kernel (a 64-wide head through the 128-lane pool) and
+    the short-convolution kernel."""
+    import json
+
+    from intellillm_tpu.config import (CacheConfig, ModelConfig,
+                                       ParallelConfig, SchedulerConfig)
+    from intellillm_tpu.models import get_model_class
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    from intellillm_tpu.worker.model_runner import ModelRunner
+
+    from chipbench import CHIP_DIR      # puts the harness on sys.path
+    from harness.server import hf_config_of
+
+    with open(os.path.join(CHIP_DIR, "configs",
+                           "lfm2-8b-a1b-l14.json")) as f:
+        cell = json.load(f)
+    serve = cell["serve"]
+    hf = hf_config_of(cell, rehearsal=False)
+    hf["num_hidden_layers"] = layers = 6
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    model_config = ModelConfig(
+        model=str(tmp_path), dtype=serve["dtype"], load_format="dummy",
+        max_model_len=serve["max_model_len"])
+    kinds = model_config.get_layer_cache_kinds()
+    assert kinds == ["state", "state", "kv", "state", "state", "state"]
+    assert model_config.get_cache_row_shape() == (2, 8, 128)
+    model = get_model_class(model_config.hf_config.architectures)(
+        model_config)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init_random_params(0)))
+    cache_config = CacheConfig(num_device_blocks_override=CELL_BLOCKS,
+                               swap_space_gib=0.01)
+    cache_config.num_state_slots = CELL_ROWS
+    set_use_pallas(True)        # jax.default_backend() is "cpu" here
+    try:
+        runner = ModelRunner(
+            model, params, model_config,
+            SchedulerConfig(max_num_batched_tokens=512,
+                            max_num_seqs=CELL_ROWS,
+                            max_model_len=serve["max_model_len"],
+                            enable_chunked_prefill=True),
+            cache_config, ParallelConfig())
+
+        def sds(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        bf16, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+        kv = [(sds(bf16, CELL_ROWS, 2 * 2048), ) if kind == "state" else
+              (sds(bf16, CELL_BLOCKS, 8, 16, 128), ) * 2 for kind in kinds]
+        assert not jax.config.jax_enable_compilation_cache
+        if program == "mixed":
+            compiled = runner.lower_mixed_step(
+                kv, 512, CELL_TABLE_WIDTH, do_random=False).compile()
+        else:
+            b, w, k = CELL_ROWS, CELL_TABLE_WIDTH, CELL_K
+            rest = (sds(i32, b, 1), sds(i32, b, w), sds(i32, b),
+                    sds(f32, b), sds(i32, b), sds(f32, b), sds(f32, b),
+                    sds(jnp.uint32, b), sds(f32, b), sds(f32, b),
+                    sds(f32, b), None, None)
+            compiled = runner._jit_decode_cont.lower(
+                params, kv, sds(i32, b, 4 * k), sds(i32, b, 1),
+                sds(jnp.bool_, b, 1), *rest, state_slots=sds(i32, b),
+                prev_t1=k, num_steps=k, logprob_k=1, do_topk=False,
+                do_topp=False, do_minp=False, do_penalties=False,
+                do_random=False).compile()
+    finally:
+        set_use_pallas(None)
+    text = compiled.as_text()
+    assert "_short_conv_call" in text
+    assert text.count("tpu_custom_call") >= 2
+    # One layer of each kind plus the head stay far inside the chip.
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024**3
