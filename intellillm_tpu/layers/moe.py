@@ -2,23 +2,28 @@
 
 Role parity: reference `vllm/model_executor/layers/fused_moe.py` (Triton
 grouped-GEMM over experts + CUDA `moe_align_block_size`,
-`csrc/moe_align_block_size_kernels.cu`). TPU redesign, two paths:
+`csrc/moe_align_block_size_kernels.cu`). TPU redesign, two forms of one
+sum (every routed choice computed, none dropped or clipped; bf16 products,
+float32 accumulation and combine):
 
-- `moe_ffn_grouped` — sort-based ragged grouped matmul with STATIC shapes
-  (the XLA-friendly equivalent of `moe_align_block_size` + grouped GEMM):
-  flatten the (token, k) assignments, stable-sort by expert, pad each
-  expert's group up to a block multiple, then scan over fixed-size token
-  blocks, each of which gathers exactly one expert's weights. Per-token
-  FLOPs are proportional to top_k (plus at most one padding block per
-  expert), not num_experts. No token dropping: the padded buffer is sized
-  T*K + N*block, an upper bound on the sum of per-expert padded groups.
-- `moe_ffn_dense` — every expert over every token, combined by routing
-  weights. For tiny decode batches (T*K << N*block) this is the faster
-  path: the step is bound by reading all expert weights from HBM either
-  way, and dense avoids the sort/scatter entirely.
+- `moe_ffn_dense` — every held expert over every row, combined by the
+  routing weights, in chunks of at most 256 rows. Its arithmetic
+  intensity is one FLOP a weight byte a row, and the v5e's ridge is
+  197 TFLOP/s / 819 GB/s = 240: a step of up to 256 rows is bound by
+  reading the stacks, which no form avoids once every expert is chosen
+  by some row, and dense does it with no sort and no gather.
+- `moe_ffn_grouped` — the routed rows only. The token-choices are placed
+  in expert order (a count and a prefix sum: the keys are expert ids), the
+  rows gathered once, and `ops/grouped_ffn.grouped_expert_ffn` runs gate,
+  up, activation and down over tiles of 128 sorted rows, each tile
+  multiplied by the one or two experts whose rows it holds: no padding
+  block an expert, an expert's weights read about once. The choice's
+  weight is applied to the float32 output row and a token's rows summed
+  in float32.
 
-`moe_ffn` dispatches between them by comparing each path's FLOP model
-(dense: N*T products; grouped: T*K plus up to one padding block/expert).
+`moe_ffn` takes the dense form for rows that fit one dense chunk and the
+grouped form for more (`DENSE_ROWS`): a second chunk reads every stack a
+second time and multiplies N/K times the routed rows.
 
 Stored layout of the expert stacks: all three are `[N, I, D]` (experts,
 inner, hidden), so `w1` (gate) and `w3` (up) are the checkpoint's own
@@ -43,11 +48,27 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from intellillm_tpu.ops.grouped_ffn import ROW_TILE, grouped_expert_ffn
 from intellillm_tpu.utils import cdiv
 
 # Rows of one bf16 sublane tile: a chunk of the dense path is padded to a
 # multiple of it and by no more.
 _ROW_TILE = 16
+
+# The most rows a step may have and stay dense: one dense chunk. Up to here
+# the dense form stands at its weight read (the chip's ridge is 240 rows); a
+# second chunk reads every stack again and multiplies N/K times the routed
+# rows. On the chip (benchmarks/kernels/benchmark_grouped_ffn.py, PERF.md
+# section 6): at 256 rows dense is ahead by 8 % (32 experts top-4) and 14 %
+# (12 held of 192, top-8) and 3 % behind at 64 experts top-6; at 384 and
+# 512 rows grouped is ahead by 27 to 44 % at all three.
+DENSE_ROWS = 256
+
+
+def grouped_rows(t: int) -> bool:
+    """Whether a step of `t` rows takes the grouped form: the rule of
+    `moe_ffn`, by the rows alone (the runner's counter asks it too)."""
+    return t > DENSE_ROWS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,7 +183,7 @@ def moe_ffn_dense(
     w2: jnp.ndarray,       # [N, I, D]  (down proj per expert)
     w3: jnp.ndarray,       # [N, I, D]  (up proj per expert)
     top_k: int,
-    chunk_size: int = 256,
+    chunk_size: int = DENSE_ROWS,
     routing: Routing = _SOFTMAX_TOP_K,
     routed=None,
 ) -> jnp.ndarray:
@@ -216,10 +237,15 @@ def moe_ffn_grouped(
     w2: jnp.ndarray,       # [N, I, D]
     w3: jnp.ndarray,       # [N, I, D]
     top_k: int,
-    block: int = 512,
     routing: Routing = _SOFTMAX_TOP_K,
     routed=None,
+    tile: int = ROW_TILE,
+    mesh=None,
 ) -> jnp.ndarray:
+    """`tile`: rows a tile of the grouped kernels' walk (the sorted rows
+    are padded to a whole number of them, once, not an expert). `mesh`:
+    the runner's device mesh; where it spans chips each runs the kernels
+    on its share of the stacks' inner dimension (`_over_inner_shards`)."""
     t, d = x.shape
     n = w1.shape[0]
     tk = t * top_k
@@ -231,59 +257,84 @@ def moe_ffn_grouped(
 
     # A choice of an expert this stack does not hold (a share: the router
     # is wider than the stack) goes to a group of its own past the last
-    # held expert, index n, which sorts last, which no block computes and
-    # whose weight is 0. A stack that holds every expert has no such row.
+    # held expert, index n, which sorts last, which nothing computes and
+    # whose rows the combine leaves out. A stack that holds every expert has
+    # no such row.
     local = topi.reshape(-1) - routing.held_start              # [T*K]
     here = (local >= 0) & (local < n)
     flat_e = jnp.where(here, local, n)
-    flat_w = jnp.where(here, topw.reshape(-1), 0.0)
-    sort_idx = jnp.argsort(flat_e, stable=True)                # [T*K]
-    sorted_e = flat_e[sort_idx]
-    token_idx = sort_idx // top_k                              # source token
+    flat_w = topw.reshape(-1)
 
-    counts = jnp.bincount(flat_e, length=n).astype(jnp.int32)  # held only
-    padded = cdiv(counts, block) * block                       # [N]
-    pad_cum = jnp.cumsum(padded)
-    starts = pad_cum - padded                                  # [N] slot base
-    grp_cum = jnp.cumsum(counts)
-    grp_start = grp_cum - counts                               # [N] in sorted
-    # Static upper bound on sum of padded group sizes (block multiple).
-    s = (cdiv(tk, block) + n) * block
-    nb = s // block
-    # Absent rows get a slot past the buffer: the scatter drops them, the
-    # gather below clamps, and their weight is 0.
-    safe_e = jnp.minimum(sorted_e, n - 1)
-    pos_in_grp = jnp.arange(tk) - grp_start[safe_e]
-    slot = jnp.where(sorted_e < n, starts[safe_e] + pos_in_grp, s)
-    xb = jnp.zeros((s, d), x.dtype).at[slot].set(x[token_idx], mode="drop")
+    # Place the choices in expert order, stably, by counting: a choice's
+    # place is where its expert's group starts plus how many earlier
+    # choices went to the same expert.
+    earlier, counts = _earlier_choices_of_the_same(flat_e, n + 1)
+    place = (jnp.cumsum(counts) - counts)[flat_e] + earlier    # [T*K]
+    m = cdiv(tk, tile) * tile
+    token_of = jnp.zeros((m, ), jnp.int32).at[place].set(
+        jnp.arange(tk, dtype=jnp.int32) // top_k)
+    xs = x[token_of]                                           # [M, D]
 
-    # Expert owning each block; blocks past the last padded group get a
-    # clipped id and compute on zeros (their output is never gathered).
-    blk_expert = jnp.clip(
-        jnp.searchsorted(pad_cum, jnp.arange(nb) * block, side="right"),
-        0, n - 1)
-
-    def body(carry, inp):
-        xc, e = inp                                            # [B, D], []
-        w1e = jax.lax.dynamic_index_in_dim(w1, e, 0, keepdims=False)
-        w3e = jax.lax.dynamic_index_in_dim(w3, e, 0, keepdims=False)
-        w2e = jax.lax.dynamic_index_in_dim(w2, e, 0, keepdims=False)
-        h1 = jnp.einsum("bd,id->bi", xc, w1e,
-                        preferred_element_type=jnp.float32)
-        h3 = jnp.einsum("bd,id->bi", xc, w3e,
-                        preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(h1) * h3).astype(x.dtype)
-        return carry, jnp.dot(h, w2e, preferred_element_type=jnp.float32)
+    def experts(xs, w1, w2, w3, sizes, place, flat_w, here):
+        out = grouped_expert_ffn(xs, w1, w2, w3, sizes, tile)  # [M, D] f32
+        # Rows of the last group (and of the pad) are as the device found
+        # them: select, do not multiply by 0.
+        rows = jnp.where(here[:, None], out[place] * flat_w[:, None], 0.0)
+        return rows.reshape(t, top_k, d).sum(1)
 
     with jax.named_scope("moe_experts"):
-        _, out_blocks = jax.lax.scan(body, None,
-                                     (xb.reshape(nb, block, d), blk_expert))
-    out = out_blocks.reshape(s, d)                             # f32
-
-    contrib = (out[jnp.minimum(slot, s - 1)]
-               * flat_w[sort_idx][:, None])                    # [T*K, D]
-    y = jnp.zeros((t, d), jnp.float32).at[token_idx].add(contrib)
+        y = _over_inner_shards(experts, mesh, w1.shape[1])(
+            xs, w1, w2, w3, counts[:n], place, flat_w, here)
     return y.astype(x.dtype)
+
+
+_COUNT_BLOCK = 128
+
+
+def _earlier_choices_of_the_same(flat_e: jnp.ndarray, groups: int):
+    """([C] i32: how many choices before choice c went to c's own group,
+    [groups] i32: choices a group). A running count in two levels, since
+    XLA's own cumsum over [C, groups] is a reduce-window that costs a tenth
+    of the kernels' time: inside a block of 128 choices a strictly lower
+    triangular matmul (0/1 operands, float32 sums: exact), across blocks
+    the running sum of the blocks' totals."""
+    c = flat_e.shape[0]
+    blocks = cdiv(c, _COUNT_BLOCK)
+    chose = (flat_e[:, None] == jnp.arange(groups)).astype(jnp.bfloat16)
+    chose = jnp.pad(chose, ((0, blocks * _COUNT_BLOCK - c), (0, 0))).reshape(
+        blocks, _COUNT_BLOCK, groups)
+    at = jnp.arange(_COUNT_BLOCK)
+    before_in_block = (at[:, None] > at[None, :]).astype(jnp.bfloat16)
+    within = jnp.einsum("rc,bcg->brg", before_in_block, chose,
+                        preferred_element_type=jnp.float32)
+    totals = chose.astype(jnp.float32).sum(1)                  # [B, G]
+    before = jnp.cumsum(totals, axis=0) - totals
+    earlier = ((within + before[:, None, :]) * chose).sum(-1)
+    return (earlier.reshape(-1)[:c].astype(jnp.int32),
+            totals.sum(0).astype(jnp.int32))
+
+
+def _over_inner_shards(experts, mesh, inner: int):
+    """Mosaic kernels cannot be partitioned by the compiler
+    (`ops/dispatch.shard_over_heads` says the same of attention): on a
+    mesh that spans chips each chip runs `experts` on its share of the
+    stacks' inner dimension, the axis the models shard them on, and the
+    float32 partial sums are added across the "model" axis. An inner
+    width the axis does not divide runs whole on every chip."""
+    if mesh is None or mesh.devices.size == 1:
+        return experts
+    from jax.sharding import PartitionSpec as P
+    axis = "model" if inner % mesh.shape["model"] == 0 else None
+    stack = P(None, axis, None)
+
+    def body(*args):
+        y = experts(*args)
+        return jax.lax.psum(y, axis) if axis else y
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(), stack, stack, stack, P(), P(), P(), P()),
+        out_specs=P(), check_vma=False)
 
 
 def moe_ffn(
@@ -293,18 +344,18 @@ def moe_ffn(
     w2: jnp.ndarray,
     w3: jnp.ndarray,
     top_k: int,
-    block: int = 512,
     routing: Routing = _SOFTMAX_TOP_K,
     routed=None,
+    mesh=None,
 ) -> jnp.ndarray:
     # All three stacks are [N, I, D] (module docstring): w1/w3 contract on
     # their last axis, so no loop around this call makes XLA re-lay them.
     # QuantMixtral (reference mixtral_quant.py): expert stacks arrive as
     # packed int4 — HBM holds only the packed bytes, [N, in/2, out] as the
     # checkpoint packs them; the dense stack is a transient dequant feeding
-    # the expert einsums (XLA fuses the affine into the dot producers where
-    # profitable), which for w1/w3 ([N, D, I] unpacked) is handed over
-    # with its last two axes swapped.
+    # the expert matmuls of either form (XLA fuses the affine into the dot
+    # producers where profitable), which for w1/w3 ([N, D, I] unpacked) is
+    # handed over with its last two axes swapped.
     from intellillm_tpu.layers.quantization import (dequant_int4_stack,
                                                     is_quantized)
     if is_quantized(w1):
@@ -313,25 +364,8 @@ def moe_ffn(
         w2 = dequant_int4_stack(w2, x.dtype)
     if is_quantized(w3):
         w3 = dequant_int4_stack(w3, x.dtype).swapaxes(1, 2)
-    t = x.shape[0]
-    n = w1.shape[0]
-    # Dense runs n*t token-expert rows; grouped runs the routed rows
-    # plus up to one padding block per expert: t*top_k + n*block worst
-    # case. Switch at row parity. Fetch-synced v5e device timing (n=8,
-    # top_k=2, e=2048, inter=4096, block=512) showed grouped at or ahead
-    # of dense from a few hundred tokens (4.6 vs 16.0 ms at t=256,
-    # 6.6 vs 8.9 ms at t=2048, 8.1 vs 13.9 ms at t=4096) and tied within
-    # noise below, so the old 2x-FLOP-win margin (crossover ~2k tokens)
-    # left prefill-sized batches on the slow dense path. The n*block
-    # padding term must stay in the inequality: many-expert models
-    # (DeepSeek n=64) pay n padding blocks on the grouped path, which
-    # dominates at small t. The routed rows are those routed HERE: a
-    # stack that holds n of the router's n_router experts is sent
-    # t*top_k*n/n_router of the choices in expectation (all of them when
-    # it holds every expert, as Mixtral and DeepSeek-MoE do).
-    n_router = gate_w.shape[-1]
-    if t * top_k * n + n * block * n_router <= n * t * n_router:
-        return moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, block=block,
-                               routing=routing, routed=routed)
+    if grouped_rows(x.shape[0]):
+        return moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k,
+                               routing=routing, routed=routed, mesh=mesh)
     return moe_ffn_dense(x, gate_w, w1, w2, w3, top_k, routing=routing,
                          routed=routed)

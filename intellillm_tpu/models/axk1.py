@@ -192,7 +192,8 @@ class AXK1ForCausalLM:
             routed = _route(flat, lp["gate_router"], self.top_k,
                             routing=self.routing)
         out = moe_ffn(flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
-                      self.top_k, routing=self.routing, routed=routed)
+                      self.top_k, routing=self.routing, routed=routed,
+                      mesh=attn_metadata.mesh)
         held = None
         if self.moe_choices_per_token:
             held = held_assignments(routed[1], self.n_held,

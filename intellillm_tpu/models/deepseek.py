@@ -72,7 +72,8 @@ class DeepseekForCausalLM(LlamaForCausalLM):
                                          self.rms_eps)
         flat = h.reshape(b * l, e)
         out = moe_ffn(flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
-                      self.top_k, routing=self.routing)
+                      self.top_k, routing=self.routing,
+                      mesh=attn_metadata.mesh)
         if self.n_shared:
             with jax.named_scope("moe_shared"):
                 gate = flat @ lp["shared_gate"]

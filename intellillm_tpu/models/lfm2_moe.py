@@ -161,7 +161,8 @@ class Lfm2MoeForCausalLM:
                             routing=self.routing,
                             select_bias=lp.get("expert_bias"))
         out = moe_ffn(flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
-                      self.top_k, routing=self.routing, routed=routed)
+                      self.top_k, routing=self.routing, routed=routed,
+                      mesh=attn_metadata.mesh)
         return out.reshape(b, l, e), residual, cache
 
     def compute_logits(self, params: Params, hidden, lora=None):

@@ -73,7 +73,7 @@ class MixtralForCausalLM(LlamaForCausalLM):
                                          self.rms_eps)
         flat = h.reshape(b * l, e)
         moe_out = moe_ffn(flat, lp["gate_router"], lp["w1"], lp["w2"],
-                          lp["w3"], self.top_k)
+                          lp["w3"], self.top_k, mesh=attn_metadata.mesh)
         return moe_out.reshape(b, l, e), residual, kv_cache
 
     def partition_specs(self):

@@ -65,6 +65,8 @@ DEVICE_STATES = ("idle", "busy")
 # How a row entered a decode batch, and why the step pipeline drained to
 # a fresh schedule (engine/llm_engine.py step_pipelined).
 JOIN_VIAS = ("cont", "fresh")
+# The two forms of the expert FFN (layers/moe.py moe_ffn).
+MOE_FFN_PATHS = ("dense", "grouped")
 DRAIN_CAUSES = ("memory", "params", "chunked_prefill", "bucket", "idle")
 TOKEN_KINDS = ("real", "pad")
 # What happens to a per-sequence state slot (core/block_manager.py), and
@@ -150,6 +152,14 @@ class _EfficiencyMetrics:
             "than its router has outputs counts here; here / (here + "
             "absent) is held experts / router width in expectation.",
             ["held"])
+        self.counter_moe_ffn_rows = Counter(
+            "intellillm_moe_ffn_rows_total",
+            "Token-rows (pad rows included) x substeps that the dispatched "
+            "programs of a model with expert layers sent through each "
+            "form of the expert FFN (path: dense = every held expert over "
+            "every row, a step of up to 256 rows; grouped = the routed "
+            "rows only, a step of more), by layers/moe.py's own rule.",
+            ["path"])
         self.counter_decode_join_rows = Counter(
             "intellillm_decode_join_rows_total",
             "Rows that entered a decode batch, by how (via: cont = took a "
@@ -183,6 +193,8 @@ class _EfficiencyMetrics:
             "per-sequence state pools).", ["kind"])
         for state in ("used", "free"):
             self.gauge_state_slots.labels(state).set(0)
+        for path in MOE_FFN_PATHS:
+            self.counter_moe_ffn_rows.labels(path)
         for event in STATE_SLOT_EVENTS:
             self.counter_state_slot_events.labels(event)
         for kind in CACHE_KINDS:
@@ -314,6 +326,7 @@ class EfficiencyTracker:
                                  "walked_tokens"), 0)
             for program in PROGRAMS}
         self._decode_joins: Dict[str, int] = dict.fromkeys(JOIN_VIAS, 0)
+        self._moe_ffn_rows: Dict[str, int] = dict.fromkeys(MOE_FFN_PATHS, 0)
         self._pipeline_drains: Dict[str, int] = dict.fromkeys(
             DRAIN_CAUSES, 0)
         # (phase, axis) -> [sum of fill ratios, observations]
@@ -490,6 +503,23 @@ class EfficiencyTracker:
             for axis, ratio in fills:
                 m.hist_fill_ratio.labels(phase, axis).observe(ratio)
 
+    def record_moe_ffn_rows(self, rows: int, substeps: int = 1) -> None:
+        """A dispatched program of `rows` token-rows (as padded: what the
+        device multiplies) ran its expert layers `substeps` times; the
+        form is the one `layers/moe.py` `moe_ffn` takes at that many
+        rows."""
+        if not self.enabled:
+            return
+        from intellillm_tpu.layers.moe import grouped_rows
+        path = "grouped" if grouped_rows(rows) else "dense"
+        with self._lock:
+            if self._warmup_depth > 0:
+                return
+            self._moe_ffn_rows[path] += int(rows) * int(substeps)
+        if self._metrics is not None:
+            self._metrics.counter_moe_ffn_rows.labels(path).inc(
+                int(rows) * int(substeps))
+
     def record_decode_join(self, via: str, rows: int) -> None:
         """`rows` sequences were given a row of a decode batch for the
         first time: by a continuation's re-snapshot (`cont`) or by a
@@ -647,6 +677,7 @@ class EfficiencyTracker:
                 "programs": {program: dict(counts) for program, counts
                              in self._programs.items()},
                 "decode_join_rows": dict(self._decode_joins),
+                "moe_ffn_rows": dict(self._moe_ffn_rows),
                 "pipeline_drains": dict(self._pipeline_drains),
                 "co_batched_steps": self._co_batched_steps,
                 "warmup_excluded_dispatches": self._warmup_excluded,

@@ -212,6 +212,11 @@ class ModelRunner:
         self.parallel_config = parallel_config
         self.mesh = mesh
         self._dp = (mesh.shape.get("data", 1) if mesh is not None else 1)
+        # Whether a program runs expert layers (layers/moe.py moe_ffn): its
+        # rows then count in intellillm_moe_ffn_rows_total{path}.
+        layers = params.get("layers", ()) if isinstance(params, dict) else ()
+        self._has_expert_layers = any(
+            isinstance(lp, dict) and "gate_router" in lp for lp in layers)
         self._tracer = get_step_tracer()
         self._compile_tracker = get_compile_tracker()
         self._efficiency = get_efficiency_tracker()
@@ -342,6 +347,12 @@ class ModelRunner:
         slots = np.full(padded_n, self.num_state_slots, np.int32)
         slots[:len(row_slots)] = row_slots
         return {"state_slots": self._place_batch_array(slots)}
+
+    def _record_moe_ffn_rows(self, rows: int, substeps: int = 1) -> None:
+        """`rows` x `substeps` of a dispatched program went through the
+        expert layers, if the model has any."""
+        if self._has_expert_layers:
+            self._efficiency.record_moe_ffn_rows(rows, substeps)
 
     def _device_idle(self) -> bool:
         """Whether nothing dispatched earlier is still running on the
@@ -1098,6 +1109,7 @@ class ModelRunner:
                             + real_rows * num_steps * (num_steps - 1) // 2),
             walked_tokens=self._decode_walked_tokens(
                 eff_info["ctxs"], num_steps, eff_info["width_padded"]))
+        self._record_moe_ffn_rows(padded_n, num_steps)
 
         # ONE device→host transfer for everything, performed by
         # InflightStep.finalize() — immediately on the eager path, or
@@ -1359,6 +1371,7 @@ class ModelRunner:
                 padded_tokens=padded_n - n_chunk_tokens,
                 width_real=max(len(t) for t in tables),
                 width_padded=w, **dispatch)
+        self._record_moe_ffn_rows(padded_n)
 
         step = InflightStep(
             self, packed, seq_group_metadata_list, rows, num_samples, 1,
@@ -1461,6 +1474,7 @@ class ModelRunner:
             context_tokens=(int(ctx.sum()) * num_steps
                             + live_rows * num_steps * (num_steps - 1) // 2),
             walked_tokens=self._decode_walked_tokens(ctx, num_steps, w))
+        self._record_moe_ffn_rows(b, num_steps)
 
         step = InflightStep(self, packed, cont.metas, cont.rows, num_steps,
                             num_steps, st.logprob_k, False, num_steps,
@@ -1526,6 +1540,7 @@ class ModelRunner:
             padded_tokens=padded_n * num_steps,
             width_real=eff_info["width_real"],
             width_padded=eff_info["width_padded"])
+        self._record_moe_ffn_rows(padded_n, num_steps)
         step = InflightStep(self, packed, seq_group_metadata_list, rows,
                             num_steps, num_steps, st.logprob_k, False,
                             num_steps)

@@ -1,5 +1,6 @@
-"""Grouped (ragged) and dense MoE vs a plain per-token reference, vs each
-other, and vs the HF Mixtral block.
+"""Grouped and dense MoE vs a plain per-token reference, vs each other,
+and vs the HF Mixtral block; the grouped form through its jnp oracle and
+through the Pallas kernels (interpret mode here: conftest.py).
 
 Reference test role: `tests/kernels/test_moe.py` (Triton fused_moe vs HF
 MixtralSparseMoeBlock).
@@ -12,8 +13,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from intellillm_tpu.layers.moe import (Routing, dense_chunking, moe_ffn,
-                                       moe_ffn_dense, moe_ffn_grouped)
+from intellillm_tpu.layers import moe
+from intellillm_tpu.layers.moe import (Routing, _route, dense_chunking,
+                                       moe_ffn, moe_ffn_dense,
+                                       moe_ffn_grouped)
 
 
 def _rand_weights(key, n, d, i, dtype=jnp.float32):
@@ -25,23 +28,43 @@ def _rand_weights(key, n, d, i, dtype=jnp.float32):
     return gate_w, w1, w2, w3
 
 
-def _per_token_reference(x, gate_w, w1, w2, w3, top_k, renormalize):
-    """One token and one selected expert at a time, float64 numpy: softmax
-    over all experts, top-k (ties to the lower index, as lax.top_k), then
-    down(silu(gate(x)) * up(x)) weighted by the routing weight. Shares
-    nothing with either path under test."""
+def _per_token_reference(x, gate_w, w1, w2, w3, top_k, routing,
+                         select_bias=None):
+    """One token and one selected expert at a time, float64 numpy: the
+    scores, the group limit, top-k (ties to the lower index, as
+    lax.top_k), the weights' normalisation and scale, then
+    down(silu(gate(x)) * up(x)) weighted by the routing weight, for the
+    chosen experts the stack holds. Shares nothing with either form under
+    test."""
     x, gate_w, w1, w2, w3 = (np.asarray(a, np.float64)
                              for a in (x, gate_w, w1, w2, w3))
+    n = w1.shape[0]
     out = np.zeros_like(x)
     for ti, row in enumerate(x):
         logits = row @ gate_w
-        probs = np.exp(logits - logits.max())
-        probs /= probs.sum()
-        chosen = np.argsort(-probs, kind="stable")[:top_k]
-        weights = probs[chosen]
-        if renormalize:
-            weights = weights / weights.sum()
-        for e, wt in zip(chosen, weights):
+        if routing.scoring == "softmax":
+            scores = np.exp(logits - logits.max())
+            scores /= scores.sum()
+        else:
+            scores = 1.0 / (1.0 + np.exp(-logits))
+        choose = scores.copy()
+        if routing.select_bias:
+            choose = choose + np.asarray(select_bias, np.float64)
+        if routing.n_group > 1:
+            per = len(choose) // routing.n_group
+            best = np.argsort(-choose.reshape(routing.n_group, per).max(-1),
+                              kind="stable")[:routing.topk_group]
+            kept = np.zeros(routing.n_group, bool)
+            kept[best] = True
+            choose = np.where(np.repeat(kept, per), choose, -np.inf)
+        chosen = np.argsort(-choose, kind="stable")[:top_k]
+        weights = scores[chosen]
+        if routing.renormalize:
+            weights = weights / (weights.sum() + routing.sum_eps)
+        weights = weights * routing.scale
+        for e, wt in zip(chosen - routing.held_start, weights):
+            if not 0 <= e < n:
+                continue                           # held on another chip
             gate = w1[e] @ row                                 # [I]
             up = w3[e] @ row
             h = gate / (1.0 + np.exp(-gate)) * up
@@ -60,16 +83,63 @@ def test_both_paths_match_per_token_reference(t, renormalize, n, top_k):
     d, i = 16, 24
     gate_w, w1, w2, w3 = _rand_weights(key, n, d, i)
     x = jax.random.normal(jax.random.fold_in(key, 1), (t, d), jnp.float32)
-    ref = _per_token_reference(x, gate_w, w1, w2, w3, top_k, renormalize)
-
     routing = Routing(renormalize=renormalize)
+    ref = _per_token_reference(x, gate_w, w1, w2, w3, top_k, routing)
+
     dense = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k, routing=routing)
-    grouped = moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, block=16,
+    grouped = moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, tile=16,
                               routing=routing)
     assert dense.shape == grouped.shape == (t, d)
     np.testing.assert_allclose(np.asarray(dense), ref, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(np.asarray(grouped), ref, rtol=2e-4,
                                atol=2e-5)
+
+
+# (held experts, router width, top-k, Routing) of the three MoE cells and of
+# Mixtral; A.X-K1's share sits in the middle of its router's range.
+CELL_ROUTINGS = {
+    "dsmoe16b": (64, 64, 6, Routing(renormalize=False)),
+    "axk1-share": (12, 192, 8, Routing(scoring="sigmoid", n_group=8,
+                                       topk_group=4, scale=2.5,
+                                       held_start=24)),
+    "lfm2moe": (32, 32, 4, Routing(scoring="sigmoid", select_bias=True,
+                                   sum_eps=1e-6)),
+    "mixtral": (8, 8, 2, Routing()),
+}
+
+
+@pytest.mark.parametrize("t", [257, 384, 512])
+@pytest.mark.parametrize("kernel", [False, True], ids=["jnp", "pallas"])
+@pytest.mark.parametrize("cell", sorted(CELL_ROUTINGS))
+def test_grouped_form_at_the_cells_routing(cell, kernel, t, force_pallas):
+    """The grouped form, through the oracle and through the kernels, at
+    the routing settings the cells run, on the grouped side of the rule.
+    One held expert gets every row (its group spans many row tiles), one
+    gets none, the others' groups start and end inside tiles of 32 rows,
+    and T*K is no whole number of tiles except at 512 (pad rows)."""
+    n, n_router, top_k, routing = CELL_ROUTINGS[cell]
+    d, i = 128, 128
+    key = jax.random.PRNGKey(sum(map(ord, cell)) + t)
+    _, w1, w2, w3 = _rand_weights(key, n, d, i)
+    gate_w = jax.random.normal(key, (d, n_router), jnp.float32) * 0.1
+    x = jax.random.normal(jax.random.fold_in(key, 1), (t, d), jnp.float32)
+    # A constant feature drives the logits of two held experts: `hot` is
+    # every row's first choice, `cold` is nobody's.
+    hot, cold = routing.held_start + 1, routing.held_start + 2
+    x = x.at[:, 0].set(1.0)
+    gate_w = gate_w.at[0, hot].set(30.0).at[0, cold].set(-30.0)
+    bias = (jax.random.normal(jax.random.fold_in(key, 2), (n_router, ))
+            * 0.05 if routing.select_bias else None)
+    ref = _per_token_reference(x, gate_w, w1, w2, w3, top_k, routing, bias)
+
+    routed = _route(x, gate_w, top_k, routing, select_bias=bias)
+    chosen = np.asarray(routed[1])
+    assert (chosen == hot).any(-1).all() and not (chosen == cold).any()
+    force_pallas(kernel)
+    out = jax.block_until_ready(moe_ffn_grouped(
+        x, gate_w, w1, w2, w3, top_k, routing=routing, routed=routed,
+        tile=32))
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4, atol=2e-5)
 
 
 def test_dense_chunk_is_sized_to_the_rows_present():
@@ -99,21 +169,22 @@ def test_dense_chunk_is_sized_to_the_rows_present():
 
 @pytest.mark.parametrize("t", [1, 7, 64, 300])
 @pytest.mark.parametrize("n,top_k", [(8, 2), (4, 1), (4, 4)])
-@pytest.mark.parametrize("block", [8, 32])
-def test_grouped_matches_dense(t, n, top_k, block):
+@pytest.mark.parametrize("tile", [8, 32])
+def test_grouped_matches_dense(t, n, top_k, tile):
     key = jax.random.PRNGKey(42)
     d, i = 16, 32
     gate_w, w1, w2, w3 = _rand_weights(key, n, d, i)
     x = jax.random.normal(jax.random.fold_in(key, 1), (t, d), jnp.float32)
 
     ref = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k)
-    out = moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, block=block)
+    out = moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, tile=tile)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
 
 def test_grouped_skewed_routing():
-    """All tokens route to one expert — exercises group padding bounds."""
+    """All tokens route to two experts: groups of many tiles beside
+    groups of none."""
     n, top_k, d, i, t = 8, 2, 16, 32, 96
     key = jax.random.PRNGKey(0)
     gate_w, w1, w2, w3 = _rand_weights(key, n, d, i)
@@ -122,7 +193,7 @@ def test_grouped_skewed_routing():
     x = jax.random.normal(jax.random.fold_in(key, 2), (t, d), jnp.float32)
 
     ref = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k)
-    out = moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, block=16)
+    out = moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, tile=16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
@@ -154,20 +225,54 @@ def test_moe_matches_hf_mixtral_block():
     x = jnp.asarray(x_t[0].numpy())
 
     out_d = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k)
-    out_g = moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, block=16)
+    out_g = moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, tile=16)
     np.testing.assert_allclose(np.asarray(out_d), ref, rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(out_g), ref, rtol=2e-4, atol=2e-4)
 
 
-def test_dispatcher_picks_paths():
-    """moe_ffn output is identical regardless of which path it picks."""
-    n, top_k, d, i = 4, 2, 16, 32
-    key = jax.random.PRNGKey(7)
-    gate_w, w1, w2, w3 = _rand_weights(key, n, d, i)
-    for t in (3, 600):
-        x = jax.random.normal(jax.random.fold_in(key, t), (t, d),
+@pytest.mark.parametrize("rows,path", [(128, "dense"), (256, "dense"),
+                                       (257, "grouped"), (512, "grouped")])
+@pytest.mark.parametrize("n,n_router,top_k", [(8, 8, 2), (12, 192, 8),
+                                              (32, 32, 4), (64, 64, 6)])
+def test_dispatcher_picks_paths(monkeypatch, n, n_router, top_k, rows, path):
+    """The rule is the rows alone: what fits one dense chunk stays dense,
+    more goes grouped, whatever the experts held or routed over; and
+    `moe_ffn` gives what the dense form gives on either side."""
+    took = []
+    for name in ("dense", "grouped"):
+        def spy(*a, name=name, form=getattr(moe, "moe_ffn_" + name), **kw):
+            took.append(name)
+            return form(*a, **kw)
+        monkeypatch.setattr(moe, "moe_ffn_" + name, spy)
+    d, i = 16, 32
+    key = jax.random.PRNGKey(7 + n)
+    _, w1, w2, w3 = _rand_weights(key, n, d, i)
+    gate_w = jax.random.normal(key, (d, n_router), jnp.float32) * 0.1
+    x = jax.random.normal(jax.random.fold_in(key, rows), (rows, d),
+                          jnp.float32)
+    out = moe.moe_ffn(x, gate_w, w1, w2, w3, top_k)
+    assert took == [path]
+    assert moe.grouped_rows(rows) == (path == "grouped")
+    ref = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_grouped_form_over_a_mesh_sums_the_inner_shards():
+    """On a mesh that spans chips each chip runs the grouped form on its
+    share of the stacks' inner dimension and the float32 partial sums are
+    added (an inner width the axis does not divide runs whole)."""
+    from jax.sharding import Mesh
+    n, top_k, d, t = 8, 2, 16, 300
+    key = jax.random.PRNGKey(3)
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(1, 4),
+                ("data", "model"))
+    for i in (32, 24 + 6):
+        gate_w, w1, w2, w3 = _rand_weights(key, n, d, i)
+        x = jax.random.normal(jax.random.fold_in(key, i), (t, d),
                               jnp.float32)
-        ref = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k)
-        out = moe_ffn(x, gate_w, w1, w2, w3, top_k, block=64)
+        ref = moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k, tile=16)
+        out = jax.jit(lambda *a: moe_ffn_grouped(
+            *a, top_k, tile=16, mesh=mesh))(x, gate_w, w1, w2, w3)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-4, atol=2e-4)
+                                   rtol=2e-4, atol=2e-5)

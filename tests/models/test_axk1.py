@@ -189,6 +189,29 @@ def test_engine_matches_reference_float32(ckpt, which, ep):
     assert worst_dlogprob(cfg, weights, prompts, outs, ep) < F32_TOL
 
 
+@pytest.mark.parametrize("which,ep", [("full", (None, 0)),
+                                      ("share", (4, 1))])
+def test_a_step_over_256_rows_takes_the_grouped_experts(ckpt, which, ep):
+    """Three prompts of 100, 100 and 90 tokens under a 512-token budget
+    make one `mixed` step of 290 rows: over `layers/moe.py` `DENSE_ROWS`,
+    so the expert layers run the grouped form (for the share: the choices
+    of experts held elsewhere in a last group that nothing computes) and
+    still meet the float32 reference."""
+    from intellillm_tpu.obs import get_compile_tracker
+
+    def traces():
+        seen = get_compile_tracker().snapshot()["kernel_dispatch"]
+        return sum(v for k, v in seen.items() if k.endswith(":grouped_ffn"))
+
+    cfg, weights, dirs = ckpt
+    before = traces()
+    llm = make_llm(dirs[which], max_num_batched_tokens=512)
+    prompts = prompts_of(cfg, (100, 100, 90), seed=3)
+    outs = generate(llm, prompts, [greedy(10)] * 3)
+    assert traces() > before
+    assert worst_dlogprob(cfg, weights, prompts, outs, ep) < F32_TOL
+
+
 def test_engine_matches_reference_bfloat16(ckpt):
     """bf16 weights and latents, float32 reference on the same bf16-rounded
     weights: every activation is rounded to 8 bits of mantissa after each
@@ -356,7 +379,7 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch, rows, path):
     total = jnp.zeros_like(x)
     for rank in range(4):
         w1, w2, w3 = stacks_of(w, p, range(rank * 4, rank * 4 + 4))
-        part = moe.moe_ffn(x, gate, w1, w2, w3, 4, block=16,
+        part = moe.moe_ffn(x, gate, w1, w2, w3, 4,
                            routing=routing_of(cfg, rank * 4))
         want = ref.moe(cfg, w, p, x, ep_size=4, ep_rank=rank, shared=False)
         np.testing.assert_allclose(np.asarray(part), np.asarray(want),
@@ -378,21 +401,23 @@ def test_held_assignments_count_the_choices_here():
 
 @pytest.mark.parametrize("name,widths,rows,path", [
     ("deepseek-moe-16b", (64, 64, 6), 128, "dense"),
-    ("deepseek-moe-16b", (64, 64, 6), 512, "dense"),
+    ("deepseek-moe-16b", (64, 64, 6), 256, "dense"),
+    ("deepseek-moe-16b", (64, 64, 6), 512, "grouped"),
     ("mixtral-8x7b", (8, 8, 2), 128, "dense"),
-    ("mixtral-8x7b", (8, 8, 2), 512, "dense"),
+    ("mixtral-8x7b", (8, 8, 2), 512, "grouped"),
     ("mixtral-8x7b", (8, 8, 2), 2048, "grouped"),
-    # 12 of 192 held: 128 x 8 x 12/192 = 64 routed rows here in
-    # expectation; the crossover counts those, not all 1,024 choices.
+    # 12 of 192 held: a 512-row step routes 512 x 8 x 12/192 = 256 rows
+    # here in expectation, where two dense chunks multiply 12 x 512.
     ("ax-k1 share", (12, 192, 8), 128, "dense"),
-    ("ax-k1 share", (12, 192, 8), 512, "dense"),
-    ("ax-k1 share", (12, 192, 8), 1024, "grouped"),
+    ("ax-k1 share", (12, 192, 8), 256, "dense"),
+    ("ax-k1 share", (12, 192, 8), 512, "grouped"),
     ("ax-k1 share", (12, 192, 8), 4096, "grouped"),
 ])
-def test_moe_crossover_by_rows_routed_here(monkeypatch, name, widths, rows,
-                                           path):
-    """The two models the benchmark already has take the paths they took
-    before the router's width and the stack's N were told apart."""
+def test_moe_form_by_the_rows_of_the_step(monkeypatch, name, widths, rows,
+                                          path):
+    """The models the benchmark has, at the rows their programs have:
+    a decode step and a `mixed` step of up to 256 rows stay dense, a
+    `mixed` step of more goes grouped (layers/moe.py `DENSE_ROWS`)."""
     from intellillm_tpu.layers import moe
     n, n_router, top_k = widths
     took = []

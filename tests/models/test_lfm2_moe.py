@@ -180,6 +180,32 @@ def test_engine_matches_reference_float32(ckpt):
     assert not bm.state_slots and len(bm._free_state_slots) == 8
 
 
+def grouped_ffn_traces():
+    from intellillm_tpu.obs import get_compile_tracker
+    seen = get_compile_tracker().snapshot()["kernel_dispatch"]
+    return sum(v for k, v in seen.items() if k.endswith(":grouped_ffn"))
+
+
+def test_a_step_over_256_rows_takes_the_grouped_experts(ckpt):
+    """Three prompts of 100, 100 and 90 tokens under a 512-token budget
+    make one `mixed` step of 290 rows (a 512-row program): over
+    `layers/moe.py` `DENSE_ROWS`, so its expert layers run the grouped
+    form, with the selection bias and the sum's epsilon, and still meet
+    the float32 reference; the decode steps after it stay dense."""
+    from intellillm_tpu.obs.efficiency import get_efficiency_tracker
+    cfg, weights, d = ckpt
+    before = grouped_ffn_traces()
+    rows_before = get_efficiency_tracker().snapshot()["moe_ffn_rows"]
+    llm = make_llm(d, max_num_batched_tokens=512)
+    prompts = prompts_of(cfg, (100, 100, 90), seed=3)
+    outs = generate(llm, prompts, [greedy(10)] * 3)
+    assert grouped_ffn_traces() > before
+    assert worst_dlogprob(cfg, weights, prompts, outs) < F32_TOL
+    rows = get_efficiency_tracker().snapshot()["moe_ffn_rows"]
+    assert rows["grouped"] - rows_before["grouped"] == 512
+    assert rows["dense"] > rows_before["dense"]
+
+
 def test_engine_matches_reference_bfloat16(ckpt):
     """bf16 weights, K/V and convolution state, float32 reference on the
     same bf16-rounded weights: bf16's own distance at this size."""

@@ -186,6 +186,51 @@ def test_tp2_equals_tp1(moe_checkpoint, single_chip_tokens):
     assert _greedy(d, list(EXAMPLE_PROMPTS), tp=2) == single_chip_tokens
 
 
+@pytest.mark.parametrize("tp", [1, 2])
+def test_a_step_over_256_rows_equals_the_dense_form(moe_checkpoint,
+                                                    monkeypatch, tp):
+    """Three 100-token prompts under a 512-token budget make one step of
+    300 rows, over `layers/moe.py` `DENSE_ROWS`: its expert layers run the
+    grouped form (at tp=2 each chip on its half of the inner dimension,
+    the partial sums added), and every logprob is the one the dense form
+    gives at that many rows (the float32 reference both families have
+    here), to 1e-4."""
+    from intellillm_tpu import LLM, SamplingParams
+    from intellillm_tpu.layers import moe
+    _, d, _, _ = moe_checkpoint
+    rng = np.random.default_rng(31)
+    prompts = [rng.integers(3, 100, size=100).tolist() for _ in range(3)]
+    forms = []
+    for name in ("dense", "grouped"):
+        def spy(*a, name=name, form=getattr(moe, "moe_ffn_" + name), **kw):
+            forms.append(name)
+            return form(*a, **kw)
+        monkeypatch.setattr(moe, "moe_ffn_" + name, spy)
+
+    def logprobs(tp):
+        llm = LLM(model=d, dtype="float32", num_device_blocks_override=128,
+                  max_model_len=128, max_num_seqs=8, swap_space=0.01,
+                  max_num_batched_tokens=512, max_paddings=512,
+                  tensor_parallel_size=tp)
+        outs = llm.generate(
+            prompt_token_ids=prompts,
+            sampling_params=SamplingParams(temperature=0.0, max_tokens=6,
+                                           ignore_eos=True, logprobs=5))
+        return [{tok: float(getattr(v, "logprob", v))
+                 for tok, v in step.items()}
+                for o in outs for step in o.outputs[0].logprobs]
+
+    got = logprobs(tp)
+    assert "grouped" in forms
+    del forms[:]
+    monkeypatch.setattr(moe, "DENSE_ROWS", 1 << 30)
+    want = logprobs(1)
+    assert forms and set(forms) == {"dense"}
+    assert len(got) == len(want) == 18
+    for g, w in zip(got, want):
+        assert g.keys() == w.keys()
+        assert max(abs(g[t] - w[t]) for t in g) < 1e-4
+
 
 def _fingerprint(stack_ndi):
     """A position-sensitive float64 sum over an [N, D, I] array."""

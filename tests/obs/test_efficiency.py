@@ -300,6 +300,37 @@ def test_pipeline_drains_count_by_cause(tracker, cause):
         c: int(c == cause) for c in DRAIN_CAUSES}
 
 
+@pytest.mark.parametrize("rows,substeps,path", [
+    (128, 8, "dense"), (256, 1, "dense"), (257, 1, "grouped"),
+    (512, 1, "grouped")])
+def test_moe_ffn_rows_count_by_the_form_the_rows_take(tracker, rows,
+                                                      substeps, path):
+    """intellillm_moe_ffn_rows_total{path}: a dispatched program's rows x
+    substeps, under the form `layers/moe.py` `moe_ffn` takes at that many
+    rows (the rule is asked, not copied); both children from the first
+    scrape; warm-up dispatches are not counted."""
+    from prometheus_client import REGISTRY
+    from intellillm_tpu.layers.moe import grouped_rows
+    from intellillm_tpu.obs.efficiency import MOE_FFN_PATHS
+    assert grouped_rows(rows) == (path == "grouped")
+
+    def sample(p):
+        return REGISTRY.get_sample_value(
+            "intellillm_moe_ffn_rows_total", {"path": p})
+
+    before = {p: sample(p) for p in MOE_FFN_PATHS}
+    assert None not in before.values()
+    with tracker.warmup():
+        tracker.record_moe_ffn_rows(rows, substeps)
+    assert tracker.snapshot()["moe_ffn_rows"] == dict.fromkeys(
+        MOE_FFN_PATHS, 0)
+    tracker.record_moe_ffn_rows(rows, substeps)
+    counted = tracker.snapshot()["moe_ffn_rows"]
+    assert counted.pop(path) == rows * substeps and not any(counted.values())
+    assert {p: sample(p) - before[p] for p in MOE_FFN_PATHS} == {
+        p: rows * substeps * (p == path) for p in MOE_FFN_PATHS}
+
+
 def test_dispatch_program_counters_skip_warmup_and_exist_at_zero(tracker):
     from prometheus_client import REGISTRY
     zero = {"idle": 0, "busy": 0, "row_steps": 0, "context_tokens": 0,

@@ -105,12 +105,26 @@ def int4_lut(rng, supported):
     return lambda: qmatmul(x, w)
 
 
+def grouped_ffn(rng, supported):
+    from intellillm_tpu.ops.grouped_ffn import grouped_expert_ffn
+    # Whole 128-lane tiles of hidden width are the kernels'; 64 lanes are
+    # not. Four experts over 64 sorted rows in tiles of 16: one with no
+    # row, groups that start inside a tile, 8 rows past the last group
+    # that are nobody's (the two paths need not agree on them).
+    d, i, n, m = (128 if supported else 64), 128, 4, 64
+    xs = _normal(rng, m, d)
+    w1, w2, w3 = (_normal(rng, n, i, d) * 0.1 for _ in range(3))
+    sizes = jnp.asarray(np.asarray([20, 0, 30, 6], np.int32))
+    return lambda: grouped_expert_ffn(xs, w1, w2, w3, sizes, 16)[:56]
+
+
 # seam -> (the label's name, (rtol, atol) of the kernel against the oracle;
 # the int4 pair's is tests/kernels/test_quant_matmul.py's, bf16 products)
 SEAMS = {paged: ("paged", (2e-3, 2e-3)), ragged: ("ragged", (2e-3, 2e-3)),
          mla_fused: ("mla", (2e-3, 2e-3)), mla_pool: ("mla", (2e-3, 2e-3)),
          bgmv: ("bgmv", (2e-4, 2e-4)), int4: ("int4", (0.02, 0.15)),
-         int4_lut: ("int4_lut", (0.02, 0.15))}
+         int4_lut: ("int4_lut", (0.02, 0.15)),
+         grouped_ffn: ("grouped_ffn", (2e-4, 2e-4))}
 CASES = {"forced_on_supported": (True, True, "pallas"),
          "forced_on_unsupported": (True, False, "reference"),
          "forced_off": (False, True, "reference")}

@@ -10,10 +10,11 @@ never compiled was the default for every prompt token.
 Each kernel test lowers one kernel at Llama-2-7B widths (32 heads of 128,
 hidden 4096, inter 11008) for a described v5e device and asserts that
 `tpu_custom_call` is in the compiled text — a seam that resolved to a
-reference cannot pass as a kernel. The MoE tests at the end hold the
-compiled expert FFN (plain XLA, no kernel) to the stored layout of the
-expert stacks: no copy of a stack in any program. A compile that passes is
-a compile, never a chip run.
+reference cannot pass as a kernel. The MoE tests hold the compiled expert
+FFN (the dense form: plain XLA; the grouped form: two Pallas kernels behind
+`ops/grouped_ffn.py`) to the stored layout of the expert stacks: no copy of
+a stack in any program. A compile that passes is a compile, never a chip
+run.
 
 This file lives at the top of tests/ on purpose: tests/kernels/conftest.py
 wraps every test there in interpret mode, under which nothing reaches
@@ -234,17 +235,19 @@ def test_quant_matmul_int4_lut_compiles(compile_for_chip):
 
 # --- MoE expert FFN: no copy of an expert stack in any program -----------
 
-# (experts, hidden, expert inner, top-k) as published.
+# (experts held, hidden, expert inner, top-k) as published.
 DSMOE16B = (64, 2048, 1408, 6)
 MIXTRAL_8X7B = (8, 4096, 14336, 2)
+AXK1_SHARE = (12, 7168, 2048, 8)          # 12 of the router's 192
+LFM2_8B_A1B = (32, 2048, 1792, 4)
 
 
-def _moe_specs(rows, widths):
+def _moe_specs(rows, widths, n_router=None):
     """x, router, and the three stacks in their stored layout [N, I, D]."""
     n, d, i, _ = widths
     stack = ((n, i, d), jnp.bfloat16)
-    return (((rows, d), jnp.bfloat16), ((d, n), jnp.float32), stack, stack,
-            stack)
+    return (((rows, d), jnp.bfloat16), ((d, n_router or n), jnp.float32),
+            stack, stack, stack)
 
 
 def _stack_sized_copies(text, widths):
@@ -260,22 +263,25 @@ def _stack_sized_copies(text, widths):
     return found
 
 
-@pytest.mark.parametrize("rows,loop", [(128, 0), (128, 8), (512, 0)],
+@pytest.mark.parametrize("rows,loop", [(128, 0), (128, 8), (256, 0),
+                                       (512, 0)],
                          ids=["decode-128", "decode-128-in-loop-of-8",
-                              "prefill-512-two-chunks"])
+                              "prefill-256", "dense-512-two-chunks"])
 def test_moe_dense_copies_no_expert_stack(compiled_for_chip, rows, loop):
-    """`moe_ffn` at DeepSeek-MoE-16B widths takes the dense path at these
-    row counts. Alone at 128 rows XLA never copied; inside a loop (the
-    K=8 loop of decode_fused/decode_cont, the chunk `while` of a 512-row
-    prefill step) its layout assignment wants w1/w3 with D minor-most and,
+    """The dense form at DeepSeek-MoE-16B widths (`moe_ffn` takes it up to
+    256 rows; 512 rows in two chunks is what it was before the grouped
+    form took them). Alone at 128 rows XLA never copied; inside a loop
+    (the K=8 loop of decode_fused/decode_cont, the chunk `while` of two
+    chunks) its layout assignment wants w1/w3 with D minor-most and,
     handed [N, D, I], copied both 369 MB stacks in front of the loop
     (0.74 GB of temporaries a layer)."""
-    from intellillm_tpu.layers.moe import Routing, moe_ffn
+    from intellillm_tpu.layers.moe import Routing, moe_ffn, moe_ffn_dense
     top_k = DSMOE16B[3]
+    form = moe_ffn if rows <= 256 else moe_ffn_dense
 
     def ffn(x, gate_w, w1, w2, w3):
-        return moe_ffn(x, gate_w, w1, w2, w3, top_k,
-                       routing=Routing(renormalize=False))
+        return form(x, gate_w, w1, w2, w3, top_k,
+                    routing=Routing(renormalize=False))
 
     def looped(x, *weights):
         return jax.lax.fori_loop(0, loop, lambda _, h: ffn(h, *weights), x)
@@ -286,18 +292,63 @@ def test_moe_dense_copies_no_expert_stack(compiled_for_chip, rows, loop):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
-def test_moe_grouped_copies_no_expert_stack(compiled_for_chip):
-    """The grouped path at Mixtral-8x7B widths, a 2048-row prefill: each
-    block's dots read one expert's slab, and no stack is copied."""
-    from intellillm_tpu.layers.moe import moe_ffn_grouped
-    top_k = MIXTRAL_8X7B[3]
+# name -> (widths, router width, rows, Routing settings, most bytes of
+# temporaries): the three MoE cells' 512-row `mixed` programs and a
+# 2048-row Mixtral-8x7B prefill. The temporaries are the sorted rows
+# [T*K, D], the activation [T*K, I] and the float32 output [T*K, D]; for
+# the share T*K is all 4,096 choices, of which 1 in 16 is held here.
+GROUPED_PROGRAMS = {
+    "dsmoe16b-512": (DSMOE16B, 64, 512, dict(renormalize=False), 0.06e9),
+    "axk1-share-512": (AXK1_SHARE, 192, 512,
+                       dict(scoring="sigmoid", n_group=8, topk_group=4,
+                            scale=2.5), 0.3e9),
+    "lfm2moe-512": (LFM2_8B_A1B, 32, 512,
+                    dict(scoring="sigmoid", sum_eps=1e-6), 0.06e9),
+    "mixtral8x7b-2048": (MIXTRAL_8X7B, 8, 2048, {}, 0.25e9),
+}
+
+
+@pytest.mark.parametrize("program", sorted(GROUPED_PROGRAMS))
+def test_moe_grouped_copies_no_expert_stack(compiled_for_chip, force_pallas,
+                                            program):
+    """`moe_ffn` over 256 rows takes the grouped form: its two kernels
+    compile for the chip at the cells' widths (a 7168-wide hidden, a
+    1408-wide inner: 11 lane tiles), read the stacks where they are stored
+    (no copy of one) and leave the temporaries stated above."""
+    from intellillm_tpu.layers.moe import Routing, moe_ffn
+    widths, n_router, rows, settings, most = GROUPED_PROGRAMS[program]
+    force_pallas(True)
 
     def ffn(x, gate_w, w1, w2, w3):
-        return moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k)
+        return moe_ffn(x, gate_w, w1, w2, w3, widths[3],
+                       routing=Routing(**settings))
 
-    compiled = compiled_for_chip(ffn, *_moe_specs(2048, MIXTRAL_8X7B))
-    assert _stack_sized_copies(compiled.as_text(), MIXTRAL_8X7B) == []
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.25e9
+    compiled = compiled_for_chip(ffn, *_moe_specs(rows, widths, n_router))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert _stack_sized_copies(text, widths) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < most
+
+
+@pytest.mark.parametrize("rows", [128, 256])
+@pytest.mark.parametrize("widths,n_router,settings", [
+    (w, r, s) for w, r, _, s, _ in GROUPED_PROGRAMS.values()],
+    ids=[name.rsplit("-", 1)[0] for name in GROUPED_PROGRAMS])
+def test_moe_up_to_256_rows_is_the_dense_program(rows, widths, n_router,
+                                                 settings):
+    """A decode step (128 rows) and a `mixed` step of up to 256 rows lower
+    to the text `moe_ffn_dense` lowers to, operation for operation: the
+    grouped form adds nothing to the programs it does not take
+    (`moe_ffn_dense` itself is the parent's, untouched)."""
+    from intellillm_tpu.layers.moe import Routing, moe_ffn, moe_ffn_dense
+    routing = Routing(**settings)
+    args = [jax.ShapeDtypeStruct(shape, dtype)
+            for shape, dtype in _moe_specs(rows, widths, n_router)]
+    texts = [jax.jit(lambda *a, form=form: form(
+        *a, widths[3], routing=routing)).lower(*args).as_text()
+        for form in (moe_ffn, moe_ffn_dense)]
+    assert texts[0] == texts[1]
+    assert "ragged_dot" not in texts[0] and "custom_call" not in texts[0]
 
 
 # A.X-K1's latent attention, as the cell `axk1-ep16-decode-closed` runs it:
