@@ -280,10 +280,11 @@ def phase_serve(args, expect_tpu: bool) -> None:
         r_a2 = http_json(comp, greedy)
         # B: greedy and streamed, long enough to still be decoding when C
         # arrives. C: seeded sampling, a prompt longer than the token
-        # budget, sent from B's first token. A prompt that fits the
-        # budget is admitted by a prefill-only step while decode stays in
-        # flight; one that does not forces a chunked pass, whose second
-        # chunk shares its step with B's decode row.
+        # budget, sent from B's first token. It is admitted by
+        # prefill-only steps, a chunk a step, while B's decode stays in
+        # flight, and joins B's batch by continuation once its final
+        # chunk has been fetched. (D and D2, sent to an idle engine, take
+        # the chunked pass of a fresh schedule: `chunked_prefill` drains.)
         p_b = prompt_ids(args.seed + 2, 24, vocab)
         p_c = prompt_ids(args.seed + 3, cfg["long_prompt"], vocab)
         sampled = {**common, "prompt": p_c, "max_tokens": 16,
@@ -385,10 +386,16 @@ def phase_serve(args, expect_tpu: bool) -> None:
                       1 for s in relower if s is not None),
                   "relower_s": round(sum(s or 0.0 for s in relower), 1)},
               "co_batched_steps": eff["co_batched_steps"],
+              "decode_join_rows": eff["decode_join_rows"],
+              "pipeline_drains": eff["pipeline_drains"],
               "dispatches": eff["dispatches"],
               "memory_stats": mem})
-        check(eff["co_batched_steps"] >= 1,
-              "no step mixed prefill rows with decode rows")
+        check(eff["decode_join_rows"]["cont"] >= 1,
+              "the chunked prompt did not join the decode batch by "
+              "continuation")
+        check(eff["pipeline_drains"]["chunked_prefill"] >= 1,
+              "D and D2 (an idle engine, no decode step in flight) did "
+              "not take the chunked pass of a fresh schedule")
         for program in ("mixed", "decode_fused", "decode_cont"):
             check(compiles["compiles"].get(program, 0) >= 1,
                   f"program {program} never ran: {compiles['compiles']}")
@@ -445,8 +452,9 @@ def build_small_engine(size: str, seed: int, tp: int = 1):
 
 def greedy_tokens(engine, size: str, seed: int) -> dict:
     """Prompts of different lengths added together, the last longer than
-    the token budget (so its later chunks share steps with the others'
-    decode rows), greedy, through the engine's normal stepping loop."""
+    the token budget (so it runs chunk by chunk, beside the others' decode
+    steps or, on a fresh schedule, in a step with their decode rows),
+    greedy, through the engine's normal stepping loop."""
     from intellillm_tpu.sampling_params import SamplingParams
 
     cfg = SIZES[size]
@@ -577,6 +585,10 @@ def phase_parity(args) -> None:
           "first_tokens_on": {k: v[:6] for k, v in sorted(toks_on.items())},
           "kernels_on_s": round(t1 - t0, 1),
           "kernels_off_s": round(time.monotonic() - t1, 1)})
+    # The prompts arrive together at an idle engine, so the long one's
+    # chunks share the fresh schedule's steps with the others' decode rows.
+    check(get_efficiency_tracker().snapshot()["co_batched_steps"] >= 1,
+          "no step mixed prefill rows with decode rows")
     if toks_on != toks_off:
         explain_divergence(toks_on, top_on, toks_off, top_off)
         fail("greedy tokens differ, kernels on vs off")

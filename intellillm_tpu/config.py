@@ -8,6 +8,7 @@ pool instead of torch CUDA allocations.
 """
 from __future__ import annotations
 
+import math
 import os
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple, Union
@@ -159,17 +160,54 @@ class ModelConfig:
 
     def get_layer_cache_kinds(self) -> List[str]:
         """What each layer of the stack caches, told by mechanism: "kv"
-        (a paged pool of `get_cache_row_shape`, attention of either kind)
-        or "state" (a fixed-size per-sequence state of
-        `get_state_row_shape`, one slot a running sequence, not paged). A
-        config that lists `layer_types` and a short-convolution cache
-        length (`conv_L_cache`) has "state" where its type is not an
-        attention; every other config is "kv" throughout."""
+        (a paged pool of `get_cache_row_shape`, attention of either kind,
+        the model's primary cache group), "kv_window" (the same pool rows,
+        but the layer attends to the last `get_window_group_size()`
+        tokens only and its blocks are a group of their own, beside
+        layers that keep the whole context) or "state" (a fixed-size
+        per-sequence state of `get_state_row_shape`, one slot a running
+        sequence, not paged). A config that lists `layer_types` and a
+        short-convolution cache length (`conv_L_cache`) has "state" where
+        its type is not an attention; one that lists a
+        `sliding_window_layout` of ones and zeros and a
+        `sliding_window_size` has "kv_window" where the layout says 1,
+        unless it says 1 everywhere (then the window is the whole
+        model's, `get_sliding_window`, and there is one group); every
+        other config is "kv" throughout."""
         n = self.get_num_layers()
+        layout = self._window_layout()
+        if layout is not None and not all(layout):
+            return ["kv_window" if w else "kv" for w in layout]
         types = getattr(self.hf_config, "layer_types", None)
         if not types or not getattr(self.hf_config, "conv_L_cache", None):
             return ["kv"] * n
         return ["kv" if "attention" in t else "state" for t in types[:n]]
+
+    def _window_layout(self) -> Optional[List[int]]:
+        """Per layer, whether it has the sliding window, for a config
+        that says so layer by layer; None for every other."""
+        layout = getattr(self.hf_config, "sliding_window_layout", None)
+        size = getattr(self.hf_config, "sliding_window_size", None)
+        if not layout or not size or not any(layout):
+            return None
+        return [int(bool(w)) for w in layout[:self.get_num_layers()]]
+
+    def get_window_group_size(self) -> Optional[int]:
+        """The window, in tokens, of the "kv_window" layers; None for a
+        model without such a group."""
+        if "kv_window" not in self.get_layer_cache_kinds():
+            return None
+        return int(self.hf_config.sliding_window_size)
+
+    def get_window_group_share(self, ahead_tokens: int) -> float:
+        """Blocks of the window group a block of the primary group: what a
+        sequence holds of the one (the window and what a step writes
+        ahead of it) over what it holds of the other at `max_model_len`.
+        0 without a window group."""
+        window = self.get_window_group_size()
+        if window is None:
+            return 0.0
+        return min(1.0, (window + ahead_tokens) / max(self.max_model_len, 1))
 
     def get_state_row_shape(self) -> Optional[Tuple[int, int]]:
         """(taps, width) of what one sequence keeps in one "state" layer:
@@ -181,8 +219,12 @@ class ModelConfig:
         return self.hf_config.conv_L_cache - 1, self.hf_config.hidden_size
 
     def get_num_kv_layers(self) -> int:
-        """Layers with a paged KV (or latent) pool."""
+        """Layers with a paged KV (or latent) pool of the primary group."""
         return self.get_layer_cache_kinds().count("kv")
+
+    def get_num_window_layers(self) -> int:
+        """Layers whose pool belongs to the window group."""
+        return self.get_layer_cache_kinds().count("kv_window")
 
     def get_num_state_layers(self) -> int:
         return self.get_layer_cache_kinds().count("state")
@@ -234,6 +276,12 @@ class ModelConfig:
         return self.hf_config.vocab_size
 
     def get_sliding_window(self) -> Optional[int]:
+        """The window every layer has (the primary group is then
+        windowed); None where no layer or only some have one
+        (`get_window_group_size`)."""
+        layout = self._window_layout()
+        if layout is not None and all(layout):
+            return int(self.hf_config.sliding_window_size)
         return getattr(self.hf_config, "sliding_window", None)
 
 
@@ -255,13 +303,17 @@ class CacheConfig:
         cache_dtype: str = "auto",
         num_device_blocks_override: Optional[int] = None,
         sliding_window: Optional[int] = None,
+        window_group_size: Optional[int] = None,
     ) -> None:
         self.block_size = block_size
         self.hbm_utilization = hbm_utilization
         self.swap_space_bytes = int(swap_space_gib * _GiB)
         self.cache_dtype = cache_dtype
         self.num_device_blocks_override = num_device_blocks_override
+        # The window every layer has, or (`window_group_size`) the window
+        # of the layers that have one beside layers that have none.
         self.sliding_window = sliding_window
+        self.window_group_size = window_group_size
         self._verify_args()
 
         # Filled after profiling / engine init.
@@ -272,6 +324,25 @@ class CacheConfig:
         # the device, and as many on the host for swapped ones. 0 for
         # every other model.
         self.num_state_slots: int = 0
+        # The window group's pools (models with "kv_window" layers): its
+        # blocks on the device and on the host, derived from the primary
+        # group's by `ModelConfig.get_window_group_share` of
+        # `window_ahead_tokens` (the most tokens a step writes ahead of
+        # its first query; the engine sets it from the scheduler's
+        # limits). 0 for every other model.
+        self.window_ahead_tokens: int = 0
+        self.num_window_device_blocks: int = 0
+        self.num_window_cpu_blocks: int = 0
+
+    def size_window_group(self, model_config: "ModelConfig") -> None:
+        """Fill the window group's block counts from the primary
+        group's."""
+        share = model_config.get_window_group_share(self.window_ahead_tokens)
+        if share:
+            self.num_window_device_blocks = math.ceil(
+                self.num_device_blocks * share)
+            self.num_window_cpu_blocks = math.ceil(
+                (self.num_cpu_blocks or 0) * share)
 
     def _verify_args(self) -> None:
         if self.hbm_utilization > 1.0 or self.hbm_utilization <= 0:

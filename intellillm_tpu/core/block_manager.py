@@ -3,10 +3,30 @@
 Role parity: reference `vllm/core/block_manager.py` (BlockAllocator :10,
 AllocStatus :54, BlockSpaceManager :68): logical→physical block maps,
 refcounted free lists per device, copy-on-write forking, host↔HBM swap
-planning, sliding-window block rings, allocation watermark. The physical
+planning, sliding-window groups, allocation watermark. The physical
 block numbers index the HBM pool arrays held by the worker's CacheEngine;
 this module never touches device memory itself — it emits block-op plans
 (swap-in / swap-out / copy dicts) that the worker executes.
+
+Cache groups (`ModelConfig.get_layer_cache_kinds`): a sequence holds one
+block table a group. The FULL group ("kv" layers) grows with the context.
+A WINDOW group ("kv_window" layers, a sliding window of `window` tokens)
+has a table indexed by logical block like the full one, but holds only
+the blocks a coming step can still see: before a step whose first query
+stands at position p, every block wholly below p - window + 1 is given
+back to the group's free list (`recycled` counts them) and its table
+entry goes stale (reported as block 0; the attention kernels never walk
+below the window's first page). So a sequence holds at most
+window / block_size + (a chunk or a K-step ahead) / block_size + 2 window
+blocks however long it grows, and a step in flight that still reads a
+block given back reads it before its next owner's first write: the
+device runs its programs in order. The two groups have pools (and block
+numbers) of their own: the primary group's swap and copy plans are
+returned to the scheduler as ever, the second group's are recorded in
+`state_ops` ("w_swap_out" | "w_swap_in" | "w_copy", source block,
+destination block) for the worker to run before the next device step. A
+model whose every layer has the window (`sliding_window`: the old
+whole-model ring's case) has the window group as its only group.
 
 Per-sequence state slots (models with "state" layers,
 `ModelConfig.get_layer_cache_kinds`): beside its block table a sequence
@@ -75,6 +95,107 @@ class AllocStatus(enum.Enum):
     NEVER = enum.auto()     # can never fit; reject the request
 
 
+class _Group:
+    """One cache group's pools and tables: device and host allocators, a
+    table a sequence (entries by logical block; None where a windowed
+    group gave the block back)."""
+
+    def __init__(self, block_size: int, num_device_blocks: int,
+                 num_cpu_blocks: int, watermark: float,
+                 window: Optional[int] = None, ahead: int = 0) -> None:
+        self.block_size = block_size
+        self.num_device_blocks = num_device_blocks
+        self.watermark_blocks = int(watermark * num_device_blocks)
+        self.device = BlockAllocator(Device.DEVICE, block_size,
+                                     num_device_blocks)
+        self.cpu = BlockAllocator(Device.CPU, block_size, num_cpu_blocks)
+        self.tables: Dict[int, List[Optional[PhysicalTokenBlock]]] = {}
+        self.window = window
+        # The most blocks a sequence holds: the window, what a step
+        # writes ahead of its first query, and the two partly used blocks
+        # at the ends.
+        self.cap: Optional[int] = None
+        if window is not None:
+            self.cap = -(-(window + ahead) // block_size) + 2
+        # Blocks given back because they fell out of the window.
+        self.recycled = 0
+
+    def blocks_for(self, num_tokens: int) -> int:
+        n = -(-num_tokens // self.block_size)
+        return n if self.cap is None else min(n, self.cap)
+
+    def first_live_block(self, first_pos: int) -> int:
+        """Blocks below it hold nothing a query at `first_pos` or later
+        sees."""
+        if self.window is None:
+            return 0
+        return max(first_pos - self.window + 1, 0) // self.block_size
+
+    def shortfall(self, seq_id: int, first_pos: int, num_tokens: int) -> int:
+        """Blocks `advance` would take from the free list, net of those it
+        gives back first."""
+        table = self.tables[seq_id]
+        need = -(-num_tokens // self.block_size) - len(table)
+        dead = sum(b is not None and b.ref_count == 1
+                   for b in table[:self.first_live_block(first_pos)])
+        return max(need, 0) - dead
+
+    def advance(self, seq_id: int, first_pos: int, num_tokens: int) -> int:
+        """Give back what a step from `first_pos` on no longer sees, then
+        cover `num_tokens` tokens. Returns the blocks given back."""
+        table = self.tables[seq_id]
+        gone = 0
+        for i in range(min(self.first_live_block(first_pos), len(table))):
+            if table[i] is not None:
+                self.device.free(table[i])
+                table[i] = None
+                gone += 1
+        self.recycled += gone
+        while len(table) * self.block_size < num_tokens:
+            table.append(self.device.allocate())
+        return gone
+
+    def live(self, seq_ids) -> List[PhysicalTokenBlock]:
+        blocks: Set[PhysicalTokenBlock] = set()
+        for sid in seq_ids:
+            blocks.update(b for b in self.tables[sid] if b is not None)
+        return list(blocks)
+
+    def move(self, seq_ids, src: BlockAllocator,
+             dst: BlockAllocator) -> Dict[int, int]:
+        """Swap plan of the sequences' blocks from `src`'s pool to
+        `dst`'s: {source block number: destination block number}."""
+        mapping: Dict[PhysicalTokenBlock, PhysicalTokenBlock] = {}
+        for sid in seq_ids:
+            new_table: List[Optional[PhysicalTokenBlock]] = []
+            for block in self.tables[sid]:
+                if block is None:
+                    new_table.append(None)
+                    continue
+                if block in mapping:
+                    other = mapping[block]
+                    other.ref_count += 1
+                else:
+                    other = dst.allocate()
+                    mapping[block] = other
+                new_table.append(other)
+                src.free(block)
+            self.tables[sid] = new_table
+        return {a.block_number: b.block_number for a, b in mapping.items()}
+
+    def free_table(self, seq_id: int) -> None:
+        table = self.tables.pop(seq_id, None)
+        for block in set(table or ()):
+            if block is None:
+                continue
+            (self.device if block.device == Device.DEVICE
+             else self.cpu).free(block)
+
+    def table_numbers(self, seq_id: int) -> List[int]:
+        return [0 if b is None else b.block_number
+                for b in self.tables[seq_id]]
+
+
 class BlockSpaceManager:
     """Maps sequences' logical blocks onto the physical HBM/CPU pools."""
 
@@ -86,26 +207,35 @@ class BlockSpaceManager:
         watermark: float = 0.01,
         sliding_window: Optional[int] = None,
         num_state_slots: int = 0,
+        window_group: Optional[Tuple[int, int, int]] = None,
+        window_ahead: int = 0,
     ) -> None:
+        """`sliding_window`: every layer has it (the primary group is
+        windowed). `window_group` (window tokens, device blocks, host
+        blocks): some layers have one, beside full ones: a second group.
+        `window_ahead`: the most tokens a step writes past its first
+        query (a prefill chunk, a K-step decode)."""
         self.block_size = block_size
         self.num_total_device_blocks = num_device_blocks
         self.num_total_cpu_blocks = num_cpu_blocks
-
-        self.block_sliding_window: Optional[int] = None
-        if sliding_window is not None:
-            assert sliding_window % block_size == 0, (sliding_window, block_size)
-            self.block_sliding_window = sliding_window // block_size
-
-        self.watermark = watermark
         assert watermark >= 0.0
-        self.watermark_blocks = int(watermark * num_device_blocks)
+        self.watermark = watermark
 
-        self.device_allocator = BlockAllocator(Device.DEVICE, block_size,
-                                               num_device_blocks)
-        self.cpu_allocator = BlockAllocator(Device.CPU, block_size,
-                                            num_cpu_blocks)
-        # seq_id -> physical block table
-        self.block_tables: Dict[int, BlockTable] = {}
+        primary = _Group(block_size, num_device_blocks, num_cpu_blocks,
+                         watermark, sliding_window, window_ahead)
+        self.groups: List[_Group] = [primary]
+        self.window_group: Optional[_Group] = None
+        if window_group is not None:
+            assert sliding_window is None, "one window a model"
+            window, win_device, win_cpu = window_group
+            self.window_group = _Group(block_size, win_device, win_cpu,
+                                       watermark, window, window_ahead)
+            self.groups.append(self.window_group)
+        self.watermark_blocks = primary.watermark_blocks
+        self.device_allocator = primary.device
+        self.cpu_allocator = primary.cpu
+        # seq_id -> physical block table of the primary group
+        self.block_tables = primary.tables
 
         # seq_id -> state slot, on the device (running) or on the host
         # (swapped); as many host slots as device slots.
@@ -114,14 +244,62 @@ class BlockSpaceManager:
         self.cpu_state_slots: Dict[int, int] = {}
         self._free_state_slots = list(range(num_state_slots))[::-1]
         self._free_cpu_state_slots = list(range(num_state_slots))[::-1]
+        # Moves beside the primary group's plans, in the order made:
         # ("swap_out" | "swap_in" | "fork", source slot, destination slot)
-        # in the order made. The engine hands the worker this very list
-        # (never rebound, only appended to and cleared): the worker runs
-        # and clears it before its next device step.
+        # of state slots, ("w_swap_out" | "w_swap_in" | "w_copy", source
+        # block, destination block) of the second group's blocks. The
+        # engine hands the worker this very list (never rebound, only
+        # appended to and cleared): the worker runs and clears it before
+        # its next device step.
         self.state_ops: List[Tuple[str, int, int]] = []
         from intellillm_tpu.obs import get_efficiency_tracker
         self._efficiency = get_efficiency_tracker()
         self._report_state_slots()
+
+    # --- windowed groups ---------------------------------------------------
+
+    def _windowed(self) -> Optional[_Group]:
+        for g in self.groups:
+            if g.window is not None:
+                return g
+        return None
+
+    @property
+    def window_blocks_recycled(self) -> int:
+        g = self._windowed()
+        return g.recycled if g else 0
+
+    def _advance_group(self, g: _Group, seq_id: int, first_pos: int,
+                       num_tokens: int) -> None:
+        gone = g.advance(seq_id, first_pos, num_tokens)
+        if gone:
+            self._efficiency.record_window_blocks("recycled", gone)
+
+    def _advance(self, seq_id: int, first_pos: int, num_tokens: int) -> None:
+        for g in self.groups:
+            self._advance_group(g, seq_id, first_pos, num_tokens)
+
+    def advance_for_chunk(self, seq: Sequence, start: int, size: int) -> None:
+        """Before a prefill chunk of positions [start, start + size):
+        a windowed group gives back what the chunk cannot see and covers
+        what admission reserved of the sequence from there on (`cap`
+        blocks from the first it still sees, or up to the sequence's end):
+        it takes no more than it has just given back, so a chunk never
+        finds the pool empty, whatever the chunks' sizes. (A group that
+        keeps everything holds the whole prompt since admission.)"""
+        g = self._windowed()
+        if g is None:
+            return
+        reserved = (g.first_live_block(start) + g.cap) * self.block_size
+        assert start + size <= reserved, (start, size, reserved)
+        self._advance_group(g, seq.seq_id, start,
+                            min(seq.get_len(), reserved))
+
+    def get_window_block_table(self, seq: Sequence) -> Optional[List[int]]:
+        """The second group's table (None: the model has one group)."""
+        if self.window_group is None:
+            return None
+        return self.window_group.table_numbers(seq.seq_id)
 
     # --- per-sequence state slots ------------------------------------------
 
@@ -167,64 +345,56 @@ class BlockSpaceManager:
             return None
         return self.state_slots[seq_id]
 
-
     # --- admission -------------------------------------------------------
 
     def can_allocate(self, seq_group: SequenceGroup) -> AllocStatus:
         # All WAITING seqs in a group share the prompt, hence one table.
         seq = seq_group.get_seqs(status=SequenceStatus.WAITING)[0]
-        num_required = seq.num_logical_blocks()
-
-        if seq_group.prefix is not None and seq_group.prefix.allocated:
-            num_required -= seq_group.prefix.get_num_blocks()
-
-        if self.block_sliding_window is not None:
-            num_required = min(num_required, self.block_sliding_window)
-
-        num_free = self.device_allocator.get_num_free_blocks()
-        if self.num_total_device_blocks - num_required < self.watermark_blocks:
-            return AllocStatus.NEVER
-        if num_free - num_required >= self.watermark_blocks:
-            return AllocStatus.OK
-        return AllocStatus.LATER
+        verdict = AllocStatus.OK
+        for g in self.groups:
+            num_required = g.blocks_for(seq.get_len())
+            if (g is self.groups[0] and seq_group.prefix is not None
+                    and seq_group.prefix.allocated):
+                num_required -= seq_group.prefix.get_num_blocks()
+            if g.num_device_blocks - num_required < g.watermark_blocks:
+                return AllocStatus.NEVER
+            if (g.device.get_num_free_blocks() - num_required
+                    < g.watermark_blocks):
+                verdict = AllocStatus.LATER
+        return verdict
 
     def allocate(self, seq_group: SequenceGroup) -> None:
         seq = seq_group.get_seqs(status=SequenceStatus.WAITING)[0]
-        num_prompt_blocks = seq.num_logical_blocks()
-
-        block_table: BlockTable = []
-        prefix_block_table: BlockTable = []
-        num_prefix_blocks = 0
-
-        prefix = seq_group.prefix
-        if prefix is not None and prefix.allocated:
-            # Reuse already-computed prefix blocks (+1 ref each).
-            num_prefix_blocks = prefix.get_num_blocks()
-            for block in prefix.block_table:
-                block.ref_count += seq_group.num_seqs()
-                block_table.append(block)
-
-        for logical_idx in range(num_prefix_blocks, num_prompt_blocks):
-            if (self.block_sliding_window is not None
-                    and logical_idx >= self.block_sliding_window):
-                # Ring reuse: positions beyond the window wrap onto old blocks.
-                block = block_table[logical_idx % self.block_sliding_window]
-            else:
-                block = self.device_allocator.allocate()
+        waiting = seq_group.get_seqs(status=SequenceStatus.WAITING)
+        tables = []
+        for g in self.groups:
+            block_table: List[Optional[PhysicalTokenBlock]] = []
+            num_prefix_blocks = 0
+            prefix = seq_group.prefix if g is self.groups[0] else None
+            if prefix is not None and prefix.allocated:
+                # Reuse already-computed prefix blocks (+1 ref each).
+                num_prefix_blocks = prefix.get_num_blocks()
+                for block in prefix.block_table:
+                    block.ref_count += seq_group.num_seqs()
+                    block_table.append(block)
+            # A windowed group holds the prompt's head only: the chunks
+            # move it on (`advance_for_chunk`).
+            for _ in range(num_prefix_blocks, g.blocks_for(seq.get_len())):
+                block = g.device.allocate()
                 # All seqs of the group share the full prompt.
                 block.ref_count = seq_group.num_seqs()
-            block_table.append(block)
+                block_table.append(block)
+            if prefix is not None and not prefix.allocated:
+                # First group to bring this prefix in: pin its blocks.
+                prefix_block_table = block_table[:prefix.get_num_blocks()]
+                for block in prefix_block_table:
+                    block.ref_count += 1
+                prefix.set_block_table(prefix_block_table)
+            tables.append(block_table)
 
-        if prefix is not None and not prefix.allocated:
-            # First group to bring this prefix in: pin its blocks.
-            num_prefix_blocks = prefix.get_num_blocks()
-            prefix_block_table = block_table[:num_prefix_blocks]
-            for block in prefix_block_table:
-                block.ref_count += 1
-            prefix.set_block_table(prefix_block_table)
-
-        for seq in seq_group.get_seqs(status=SequenceStatus.WAITING):
-            self.block_tables[seq.seq_id] = block_table.copy()
+        for seq in waiting:
+            for g, block_table in zip(self.groups, tables):
+                g.tables[seq.seq_id] = block_table.copy()
             if self.num_state_slots:
                 self._take_state_slot(seq.seq_id, "alloc")
 
@@ -243,15 +413,20 @@ class BlockSpaceManager:
 
     # --- decode growth ---------------------------------------------------
 
+    def _blocks_per_append(self, num_slots: int) -> int:
+        """A CoW block plus the blocks covering `num_slots` lookahead
+        tokens: what a sequence may take from a group for one step."""
+        return 1 + (num_slots - 1) // self.block_size + 1
+
     def can_append_slots(self, seq_group: SequenceGroup,
                          num_slots: int = 1) -> bool:
         """Conservative check: every running seq may need a CoW block plus
         the blocks covering `num_slots` lookahead tokens (multi-step
-        decode reserves K slots per scheduling pass)."""
-        num_free = self.device_allocator.get_num_free_blocks()
+        decode reserves K slots per scheduling pass), in every group."""
         num_seqs = seq_group.num_seqs(status=SequenceStatus.RUNNING)
-        blocks_per_seq = 1 + (num_slots - 1) // self.block_size + 1
-        return num_seqs * blocks_per_seq <= num_free
+        need = num_seqs * self._blocks_per_append(num_slots)
+        return all(need <= g.device.get_num_free_blocks()
+                   for g in self.groups)
 
     def append_slots(self, seq: Sequence,
                      num_slots: int = 1) -> List[Tuple[int, int]]:
@@ -259,76 +434,74 @@ class BlockSpaceManager:
         positions (positions len-1 .. len+num_slots-2 get written by the
         fused decode steps).
 
-        Returns [(src, dst)] copy-on-write pairs (shared trailing block).
+        Returns [(src, dst)] copy-on-write pairs of the primary group
+        (shared trailing block); the second group's go to `state_ops`.
         """
-        block_table = self.block_tables[seq.seq_id]
+        first_pos = seq.get_len() - 1
         total_tokens = seq.get_len() + num_slots - 1
-        blocks_needed = (total_tokens + self.block_size - 1) // self.block_size
-
         cows: List[Tuple[int, int]] = []
-        # CoW the current last block only when shared AND actually written
-        # this step (the first write position falls inside it); writes to
-        # fresh blocks never need a copy.
-        first_write_block = (seq.get_len() - 1) // self.block_size
-        if block_table and first_write_block < len(block_table):
-            last_block = block_table[-1]
-            assert last_block.device == Device.DEVICE
-            if last_block.ref_count > 1:
-                new_block = self.device_allocator.allocate()
-                block_table[-1] = new_block
-                self.device_allocator.free(last_block)
-                cows.append((last_block.block_number, new_block.block_number))
-
-        while len(block_table) < blocks_needed:
-            if (self.block_sliding_window
-                    and len(block_table) >= self.block_sliding_window):
-                block_table.append(
-                    block_table[len(block_table) % self.block_sliding_window])
-            else:
-                block_table.append(self.device_allocator.allocate())
+        for g in self.groups:
+            block_table = g.tables[seq.seq_id]
+            # CoW the current last block only when shared AND actually
+            # written this step (the first write position falls inside
+            # it); writes to fresh blocks never need a copy.
+            first_write_block = first_pos // self.block_size
+            if block_table and first_write_block < len(block_table):
+                last_block = block_table[-1]
+                assert last_block.device == Device.DEVICE
+                if last_block.ref_count > 1:
+                    new_block = g.device.allocate()
+                    block_table[-1] = new_block
+                    g.device.free(last_block)
+                    pair = (last_block.block_number, new_block.block_number)
+                    if g is self.groups[0]:
+                        cows.append(pair)
+                    else:
+                        self.state_ops.append(("w_copy", ) + pair)
+        self._advance(seq.seq_id, first_pos, total_tokens)
         return cows
 
-    def can_grow_all(self, targets: List[Tuple[int, int]]) -> bool:
+    def can_grow_all(self, targets: List[Tuple[int, int]],
+                     first_positions: Optional[List[int]] = None) -> bool:
         """Whether `grow_to` would succeed for EVERY (seq_id, target_len)
         pair without dipping below the watermark — the shortfalls sum, so
         a per-row check would over-admit. Used by the pipelined decode
         continuation, whose host sequence lengths lag the device by the
         in-flight fused steps — targets are explicit token counts, not
-        `seq.get_len()`."""
-        total_short = 0
-        for seq_id, target_len in targets:
-            block_table = self.block_tables.get(seq_id)
-            if block_table is None:
+        `seq.get_len()`. `first_positions`: the rows' first query
+        positions (what a windowed group may give back first)."""
+        if first_positions is None:
+            first_positions = [0] * len(targets)
+        for g in self.groups:
+            total_short = 0
+            for (seq_id, target_len), first in zip(targets, first_positions):
+                if seq_id not in g.tables:
+                    return False
+                total_short += g.shortfall(seq_id, first, target_len)
+            if total_short > (g.device.get_num_free_blocks()
+                              - g.watermark_blocks):
                 return False
-            needed = (target_len + self.block_size - 1) // self.block_size
-            if self.block_sliding_window is not None:
-                needed = min(needed, self.block_sliding_window)
-            total_short += max(0, needed - len(block_table))
-        return total_short <= (self.device_allocator.get_num_free_blocks()
-                               - self.watermark_blocks)
+        return True
 
-    def grow_to(self, seq_id: int, target_len: int) -> List[int]:
-        """Extend a sequence's block table to cover `target_len` tokens and
-        return the block-number table. No copy-on-write handling: the
-        continuation path only runs for sequences whose trailing block is
-        private (the first post-prefill decode step, which goes through
-        `append_slots`, resolves any fork sharing)."""
-        block_table = self.block_tables[seq_id]
-        needed = (target_len + self.block_size - 1) // self.block_size
-        while len(block_table) < needed:
-            if (self.block_sliding_window
-                    and len(block_table) >= self.block_sliding_window):
-                block_table.append(
-                    block_table[len(block_table) % self.block_sliding_window])
-            else:
-                block_table.append(self.device_allocator.allocate())
-        return [b.block_number for b in block_table]
+    def grow_to(self, seq_id: int, target_len: int,
+                first_pos: int = 0) -> List[int]:
+        """Extend a sequence's block tables to cover `target_len` tokens
+        (a windowed group first gives back what a step from `first_pos`
+        on cannot see) and return the primary block-number table. No
+        copy-on-write handling: the continuation path only runs for
+        sequences whose trailing block is private (the first post-prefill
+        decode step, which goes through `append_slots`, resolves any fork
+        sharing)."""
+        self._advance(seq_id, first_pos, target_len)
+        return self.groups[0].table_numbers(seq_id)
 
     def fork(self, parent_seq: Sequence, child_seq: Sequence) -> None:
-        src_block_table = self.block_tables[parent_seq.seq_id]
-        self.block_tables[child_seq.seq_id] = src_block_table.copy()
-        for block in src_block_table:
-            block.ref_count += 1
+        for g in self.groups:
+            src_block_table = g.tables[parent_seq.seq_id]
+            g.tables[child_seq.seq_id] = src_block_table.copy()
+            for block in src_block_table:
+                if block is not None:
+                    block.ref_count += 1
         if self.num_state_slots:
             self.state_ops.append(
                 ("fork", self.state_slots[parent_seq.seq_id],
@@ -336,111 +509,88 @@ class BlockSpaceManager:
 
     # --- swap ------------------------------------------------------------
 
-    def _get_physical_blocks(
-            self, seq_group: SequenceGroup) -> List[PhysicalTokenBlock]:
-        blocks: Set[PhysicalTokenBlock] = set()
-        for seq in seq_group.get_seqs():
-            if seq.is_finished():
-                continue
-            blocks.update(self.block_tables[seq.seq_id])
-        return list(blocks)
+    @staticmethod
+    def _unfinished_ids(seq_group: SequenceGroup) -> List[int]:
+        return [seq.seq_id for seq in seq_group.get_seqs()
+                if not seq.is_finished()]
 
     def can_swap_in(self, seq_group: SequenceGroup,
                     num_slots: int = 1) -> bool:
-        blocks = self._get_physical_blocks(seq_group)
         num_swapped = seq_group.num_seqs(status=SequenceStatus.SWAPPED)
-        num_free = self.device_allocator.get_num_free_blocks()
         # Headroom per seq for the imminent append: with multi-step decode
         # the scheduler reserves `num_slots` lookahead slots right after the
         # swap-in, which may need a CoW block plus the blocks covering the
         # lookahead tokens (same budget as can_append_slots).
-        blocks_per_seq = 1 + (num_slots - 1) // self.block_size + 1
+        headroom = num_swapped * self._blocks_per_append(num_slots)
         if self.num_state_slots and num_swapped > len(self._free_state_slots):
             return False
-        return (len(blocks) + num_swapped * blocks_per_seq
-                <= num_free - self.watermark_blocks)
+        ids = self._unfinished_ids(seq_group)
+        return all(
+            len(g.live(ids)) + headroom
+            <= g.device.get_num_free_blocks() - g.watermark_blocks
+            for g in self.groups)
+
+    def _record_window_moves(self, op: str, mapping: Dict[int, int]) -> None:
+        self.state_ops.extend((op, src, dst) for src, dst in mapping.items())
 
     def swap_in(self, seq_group: SequenceGroup) -> Dict[int, int]:
-        """Plan CPU→HBM block moves; returns {cpu_block_no: device_block_no}."""
-        mapping: Dict[PhysicalTokenBlock, PhysicalTokenBlock] = {}
-        for seq in seq_group.get_seqs(status=SequenceStatus.SWAPPED):
-            new_block_table: BlockTable = []
-            for cpu_block in self.block_tables[seq.seq_id]:
-                if cpu_block in mapping:
-                    device_block = mapping[cpu_block]
-                    device_block.ref_count += 1
-                else:
-                    device_block = self.device_allocator.allocate()
-                    mapping[cpu_block] = device_block
-                new_block_table.append(device_block)
-                self.cpu_allocator.free(cpu_block)
-            self.block_tables[seq.seq_id] = new_block_table
-            if self.num_state_slots:
-                host = self.cpu_state_slots.pop(seq.seq_id)
+        """Plan CPU→HBM block moves; returns {cpu_block_no: device_block_no}
+        of the primary group."""
+        ids = [seq.seq_id
+               for seq in seq_group.get_seqs(status=SequenceStatus.SWAPPED)]
+        primary, *others = self.groups
+        mapping = primary.move(ids, primary.cpu, primary.device)
+        for g in others:
+            self._record_window_moves("w_swap_in",
+                                      g.move(ids, g.cpu, g.device))
+        if self.num_state_slots:
+            for sid in ids:
+                host = self.cpu_state_slots.pop(sid)
                 self._free_cpu_state_slots.append(host)
                 self.state_ops.append(
-                    ("swap_in", host,
-                     self._take_state_slot(seq.seq_id, "swap_in")))
-        return {
-            cpu.block_number: dev.block_number
-            for cpu, dev in mapping.items()
-        }
+                    ("swap_in", host, self._take_state_slot(sid, "swap_in")))
+        return mapping
 
     def can_swap_out(self, seq_group: SequenceGroup) -> bool:
         if self.num_state_slots and (
                 seq_group.num_seqs(status=SequenceStatus.RUNNING)
                 > len(self._free_cpu_state_slots)):
             return False
-        return (len(self._get_physical_blocks(seq_group))
-                <= self.cpu_allocator.get_num_free_blocks())
+        ids = self._unfinished_ids(seq_group)
+        return all(len(g.live(ids)) <= g.cpu.get_num_free_blocks()
+                   for g in self.groups)
 
     def swap_out(self, seq_group: SequenceGroup) -> Dict[int, int]:
-        """Plan HBM→CPU block moves; returns {device_block_no: cpu_block_no}."""
-        mapping: Dict[PhysicalTokenBlock, PhysicalTokenBlock] = {}
-        for seq in seq_group.get_seqs(status=SequenceStatus.RUNNING):
-            new_block_table: BlockTable = []
-            for device_block in self.block_tables[seq.seq_id]:
-                if device_block in mapping:
-                    cpu_block = mapping[device_block]
-                    cpu_block.ref_count += 1
-                else:
-                    cpu_block = self.cpu_allocator.allocate()
-                    mapping[device_block] = cpu_block
-                new_block_table.append(cpu_block)
-                self.device_allocator.free(device_block)
-            self.block_tables[seq.seq_id] = new_block_table
-            if self.num_state_slots:
-                slot = self.state_slots.pop(seq.seq_id)
+        """Plan HBM→CPU block moves; returns {device_block_no: cpu_block_no}
+        of the primary group."""
+        ids = [seq.seq_id
+               for seq in seq_group.get_seqs(status=SequenceStatus.RUNNING)]
+        primary, *others = self.groups
+        mapping = primary.move(ids, primary.device, primary.cpu)
+        for g in others:
+            self._record_window_moves("w_swap_out",
+                                      g.move(ids, g.device, g.cpu))
+        if self.num_state_slots:
+            for sid in ids:
+                slot = self.state_slots.pop(sid)
                 host = self._free_cpu_state_slots.pop()
-                self.cpu_state_slots[seq.seq_id] = host
+                self.cpu_state_slots[sid] = host
                 self._free_state_slots.append(slot)
                 self.state_ops.append(("swap_out", slot, host))
                 self._report_state_slots("swap_out")
-        return {
-            dev.block_number: cpu.block_number
-            for dev, cpu in mapping.items()
-        }
+        return mapping
 
     # --- free ------------------------------------------------------------
 
-    def _free_block_table(self, block_table: BlockTable) -> None:
-        for block in set(block_table):
-            if block.device == Device.DEVICE:
-                self.device_allocator.free(block)
-            else:
-                self.cpu_allocator.free(block)
-
     def free(self, seq: Sequence) -> None:
         self.release_state_slot(seq)
-        if seq.seq_id not in self.block_tables:
-            return  # already freed or never allocated
-        self._free_block_table(self.block_tables[seq.seq_id])
-        del self.block_tables[seq.seq_id]
+        for g in self.groups:
+            g.free_table(seq.seq_id)  # nothing if freed or never allocated
 
     def reset(self) -> None:
-        for block_table in self.block_tables.values():
-            self._free_block_table(block_table)
-        self.block_tables.clear()
+        for g in self.groups:
+            for seq_id in list(g.tables):
+                g.free_table(seq_id)
         self.state_slots.clear()
         self.cpu_state_slots.clear()
         self._free_state_slots = list(range(self.num_state_slots))[::-1]
@@ -449,7 +599,7 @@ class BlockSpaceManager:
         self._report_state_slots()
 
     def get_block_table(self, seq: Sequence) -> List[int]:
-        return [b.block_number for b in self.block_tables[seq.seq_id]]
+        return self.groups[0].table_numbers(seq.seq_id)
 
     def get_num_free_device_blocks(self) -> int:
         return self.device_allocator.get_num_free_blocks()
@@ -460,6 +610,11 @@ class BlockSpaceManager:
     def kv_pressure_detail(self) -> str:
         """Compact free-vs-watermark snapshot for scheduler decision
         events: what the watermark check saw when it said LATER."""
-        return (f"free={self.device_allocator.get_num_free_blocks()}"
-                f"/{self.num_total_device_blocks}"
-                f",watermark={self.watermark_blocks}")
+        detail = (f"free={self.device_allocator.get_num_free_blocks()}"
+                  f"/{self.num_total_device_blocks}"
+                  f",watermark={self.watermark_blocks}")
+        if self.window_group is not None:
+            g = self.window_group
+            detail += (f",window_free={g.device.get_num_free_blocks()}"
+                       f"/{g.num_device_blocks}")
+        return detail

@@ -236,12 +236,9 @@ class Scheduler:
         else:
             # --disable-chunked-prefill escape hatch: prompts still run
             # as (single-chunk) mixed rows, so they must fit the step
-            # budget whole — and the attention window on sliding-window
-            # models (a longer chunk would reuse ring slots in one step).
+            # budget whole.
             self.prompt_limit = min(scheduler_config.max_model_len,
-                                    scheduler_config.max_num_batched_tokens,
-                                    cache_config.sliding_window
-                                    or scheduler_config.max_model_len)
+                                    scheduler_config.max_num_batched_tokens)
         self._prefill_token_budget = scheduler_config.max_num_batched_tokens
 
         # Bucketed-shape mirror of the runner's mixed (token_budget,)
@@ -255,11 +252,6 @@ class Scheduler:
             max(scheduler_config.max_num_batched_tokens,
                 scheduler_config.max_num_seqs, max_blocks, 16),
             start=16)
-        # Sliding-window models: a chunk longer than the window would let
-        # two positions of one dispatch share a ring slot — cap chunks at
-        # the window (the ring layout is exact per step below it).
-        self._max_chunk_size = (cache_config.sliding_window
-                                or scheduler_config.max_model_len)
 
         self.policy: Policy = PolicyFactory.get_policy(
             scheduler_config.policy,
@@ -270,6 +262,12 @@ class Scheduler:
             num_cpu_blocks=cache_config.num_cpu_blocks,
             sliding_window=cache_config.sliding_window,
             num_state_slots=cache_config.num_state_slots,
+            window_group=(
+                (cache_config.window_group_size,
+                 cache_config.num_window_device_blocks,
+                 cache_config.num_window_cpu_blocks)
+                if cache_config.num_window_device_blocks else None),
+            window_ahead=cache_config.window_ahead_tokens,
         )
         self.prefix_pool = PrefixPool(cache_config.block_size)
 
@@ -413,7 +411,8 @@ class Scheduler:
 
     # --- the scheduling pass --------------------------------------------
 
-    def _schedule(self, prefill_only: bool = False) -> SchedulerOutputs:
+    def _schedule(self, prefill_only: bool = False,
+                  chunk_long_prompts: bool = False) -> SchedulerOutputs:
         blocks_to_swap_in: Dict[int, int] = {}
         blocks_to_swap_out: Dict[int, int] = {}
         blocks_to_copy: Dict[int, List[int]] = {}
@@ -436,6 +435,10 @@ class Scheduler:
                             for sg in list(self.running)
                             + list(self.swapped)))):
             return self._chunked_pass(now)
+        if (self.scheduler_config.enable_chunked_prefill and prefill_only
+                and chunk_long_prompts and not self.swapped
+                and self._pipelined_chunking_due()):
+            return self._chunked_pass(now, prefill_only=True)
 
         # Prompt admission: runs for --disable-chunked-prefill mode and
         # for pipelined prefill-only passes. Prompts still execute as
@@ -529,13 +532,6 @@ class Scheduler:
                 if prefix is not None and prefix.computed:
                     start = min(prefix.get_length(), num_prompt_tokens - 1)
                 new_tokens = num_prompt_tokens - start
-                if new_tokens > self._max_chunk_size:
-                    # Sliding-window cap: this prompt needs real chunking —
-                    # leave it for a serial chunked pass.
-                    blocked = "needs_chunking"
-                    self._decisions.pass_blocked("token_budget", blocked)
-                    break
-
                 # Flat token accounting: the runner flattens prompt rows
                 # into one (token_budget,)-bucketed batch, so the budget
                 # caps the SUM of chunk tokens, not batch x max-len.
@@ -673,8 +669,10 @@ class Scheduler:
         self.running = running
 
         # Swap in previously swapped-out groups while there's room.
-        self.swapped = deque(self.policy.sort_by_priority(now, self.swapped))
-        if not preempted:
+        if not prefill_only:
+            self.swapped = deque(
+                self.policy.sort_by_priority(now, self.swapped))
+        if not preempted and not prefill_only:
             num_curr_seqs = sum(sg.get_max_num_running_seqs()
                                 for sg in self.running)
             curr_loras = self._running_loras()
@@ -749,11 +747,18 @@ class Scheduler:
         return any(not s.data.prefill_complete
                    for s in seq_group.get_unfinished_seqs())
 
-    def _chunked_pass(self, now: float) -> SchedulerOutputs:
+    def _chunked_pass(self, now: float,
+                      prefill_only: bool = False) -> SchedulerOutputs:
         """One mixed step: admit every runnable decode first (preempting
         as needed), then spend the remaining token-budget slack on prefill
         chunks — continuing in-flight chunked prefills before admitting
-        new prompts (Sarathi-Serve style decode-maximal batching)."""
+        new prompts (Sarathi-Serve style decode-maximal batching).
+
+        `prefill_only` (a pipelined pass, device steps still unfetched):
+        the chunks alone, with the whole budget and no decode row, no
+        preemption and no swap planning: the decode batch goes on by
+        continuation beside them, and a sequence joins it once its final
+        chunk has been fetched."""
         blocks_to_swap_in: Dict[int, int] = {}
         blocks_to_swap_out: Dict[int, int] = {}
         blocks_to_copy: Dict[int, List[int]] = {}
@@ -764,7 +769,9 @@ class Scheduler:
         # Pass 1: decodes. Mid-prefill groups pass straight through — their
         # prompt blocks were fully allocated at admission, and they emit no
         # token this step, so no slot growth either.
-        self.running = deque(self.policy.sort_by_priority(now, self.running))
+        if not prefill_only:
+            self.running = deque(
+                self.policy.sort_by_priority(now, self.running))
         running: Deque[SequenceGroup] = deque()
         decode_groups: List[SequenceGroup] = []
         prefilling_groups: List[SequenceGroup] = []
@@ -779,6 +786,10 @@ class Scheduler:
         spec_requests: Optional[Set[str]] = None
         if self.spec_decode_enabled:
             spec_requests = set()
+        if prefill_only:
+            prefilling_groups = [sg for sg in self.running
+                                 if self._is_prefilling(sg)]
+            running, self.running = self.running, deque()
         while self.running:
             seq_group = self.running.popleft()
             if self._is_prefilling(seq_group):
@@ -820,8 +831,10 @@ class Scheduler:
 
         # Pass 2: swap-in (decode-ready groups join the batch, mid-prefill
         # groups resume chunking where their KV left off).
-        self.swapped = deque(self.policy.sort_by_priority(now, self.swapped))
-        if not preempted:
+        if not prefill_only:
+            self.swapped = deque(
+                self.policy.sort_by_priority(now, self.swapped))
+        if not preempted and not prefill_only:
             num_curr_seqs = sum(sg.get_max_num_running_seqs()
                                 for sg in self.running)
             curr_loras = self._running_loras()
@@ -916,7 +929,7 @@ class Scheduler:
                 break
             seq = seq_group.get_seqs(status=SequenceStatus.RUNNING)[0]
             remaining = seq.data.get_num_uncomputed_tokens()
-            want = min(remaining, slack, self._max_chunk_size)
+            want = min(remaining, slack)
             size = fairness.allowed_chunk(seq_group, want)
             if size <= 0:
                 # Tenant's chunk share for this step is spent; the group
@@ -1035,7 +1048,7 @@ class Scheduler:
                         "router's KV handoff missed for %s",
                         num_prompt_tokens, seq_group.request_id)
                 remaining = num_prompt_tokens - start
-                want = min(remaining, slack, self._max_chunk_size)
+                want = min(remaining, slack)
                 size = fairness.allowed_chunk(seq_group, want)
                 final = size == remaining
                 if not final:
@@ -1072,7 +1085,7 @@ class Scheduler:
         num_prefill_tokens = sum(size for _, size, _ in chunks.values())
         return SchedulerOutputs(
             scheduled_seq_groups=decode_groups + chunk_groups,
-            prompt_run=False,
+            prompt_run=prefill_only,
             num_batched_tokens=decode_rows + num_prefill_tokens,
             blocks_to_swap_in=blocks_to_swap_in,
             blocks_to_swap_out=blocks_to_swap_out,
@@ -1087,15 +1100,22 @@ class Scheduler:
         )
 
     def schedule(
-        self, prefill_only: bool = False,
+        self, prefill_only: bool = False, chunk_long_prompts: bool = False,
     ) -> Tuple[List[SequenceGroupMetadata], SchedulerOutputs]:
+        """`prefill_only`: a pipelined pass, prompts alone.
+        `chunk_long_prompts` (with it): a prompt longer than the token
+        budget is admitted and continued a chunk a pass (the engine asks
+        so while a decode step is in flight); without, such a prompt
+        blocks the pass ("needs_chunking") and waits for a full one."""
         with self._tracer.span("schedule"):
             # Decision-log pass bracket: verdict sites inside _schedule
             # report what blocked admission; end_pass charges every
             # still-waiting request the elapsed wall time to the cause
             # observed this pass (see obs/decisions.py).
             self._decisions.begin_pass()
-            scheduler_outputs = self._schedule(prefill_only=prefill_only)
+            scheduler_outputs = self._schedule(
+                prefill_only=prefill_only,
+                chunk_long_prompts=chunk_long_prompts)
             self._decisions.end_pass(
                 [sg.request_id for sg in self.waiting],
                 [sg.request_id for sg in self.swapped])
@@ -1119,10 +1139,18 @@ class Scheduler:
         decode entry, or (with `chunk` / `prompt_run`) a prefill one."""
         seq_data: Dict[int, SequenceData] = {}
         block_tables: Dict[int, List[int]] = {}
+        window_tables: Optional[Dict[int, List[int]]] = (
+            None if self.block_manager.window_group is None else {})
         for seq in seq_group.get_seqs(status=SequenceStatus.RUNNING):
             seq_data[seq.seq_id] = seq.data
+            if chunk is not None:
+                # A windowed cache group moves on with the prompt.
+                self.block_manager.advance_for_chunk(seq, chunk[0], chunk[1])
             block_tables[seq.seq_id] = (
                 self.block_manager.get_block_table(seq))
+            if window_tables is not None:
+                window_tables[seq.seq_id] = (
+                    self.block_manager.get_window_block_table(seq))
         state_slots = None
         if self.block_manager.num_state_slots:
             state_slots = {sid: self.block_manager.get_state_slot(sid)
@@ -1138,6 +1166,7 @@ class Scheduler:
             token_chunk_size=chunk[1] if chunk is not None else None,
             num_computed_tokens=chunk[0] if chunk is not None else 0,
             state_slots=state_slots,
+            window_block_tables=window_tables,
         )
 
     def fork_seq(self, parent_seq: Sequence, child_seq: Sequence) -> None:
@@ -1171,6 +1200,19 @@ class Scheduler:
             seq = self._deferred_free.pop(sid, None)
             if seq is not None:
                 self.block_manager.free(seq)
+
+    def _pipelined_chunking_due(self) -> bool:
+        """Whether a pipelined (prefill-only) pass has chunks to run: a
+        resident sequence is part-way through its prompt, or the next
+        waiting prompt is longer than a step's token budget. (A prompt
+        that fits a step whole is admitted whole, as ever.)"""
+        if self.mid_prefill():
+            return True
+        if not self.waiting:
+            return False
+        seq = self.waiting[0].get_seqs(status=SequenceStatus.WAITING)[0]
+        return (self.prompt_limit >= seq.get_len()
+                > self._prefill_token_budget)
 
     def mid_prefill(self) -> bool:
         """Whether a resident sequence is part-way through its prompt:

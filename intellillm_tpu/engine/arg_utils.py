@@ -257,6 +257,7 @@ class EngineArgs:
             cache_dtype=self.kv_cache_dtype,
             num_device_blocks_override=self.num_device_blocks_override,
             sliding_window=model_config.get_sliding_window(),
+            window_group_size=model_config.get_window_group_size(),
         )
         parallel_config = ParallelConfig(
             tensor_parallel_size=self.tensor_parallel_size,

@@ -126,6 +126,19 @@ class LLMEngine:
         if self._stateful:
             self._refuse_for_state_model(speculative_config)
             cache_config.num_state_slots = scheduler_config.max_num_seqs
+        # A model with a sliding window, on every layer or on some
+        # (`get_sliding_window` / `get_window_group_size`): its windowed
+        # cache group keeps the last `window` tokens' blocks only, and
+        # what would need the blocks it gave back is refused.
+        self._window = (model_config.get_sliding_window()
+                        or model_config.get_window_group_size())
+        if self._window:
+            self._refuse_for_window_model(speculative_config)
+            # The most tokens a step writes ahead of its first query: a
+            # prefill chunk or a K-step decode.
+            cache_config.window_ahead_tokens = max(
+                scheduler_config.max_num_batched_tokens,
+                scheduler_config.num_decode_steps)
         if speculative_config is not None:
             # One engine decode "step" = K draft proposals + the bonus
             # token; the scheduler must reserve K+1 KV slots per pass.
@@ -150,25 +163,28 @@ class LLMEngine:
             self.worker.load_model()
 
         # Fused multi-step decode is incompatible with ALiBi (bias needs
-        # the true query position per substep) and sliding window (exact
-        # window semantics need the ring layout). Clamp K HERE so the
+        # the true query position per substep). Clamp K HERE so the
         # scheduler budgets lookahead slots consistently with what the
         # runner will actually execute — deciding only in the runner would
-        # make the scheduler reserve blocks that are never consumed.
-        from intellillm_tpu.layers.attention import model_uses_alibi
-        if scheduler_config.num_decode_steps > 1 and (
-                model_config.get_sliding_window() is not None
-                or model_uses_alibi(self.worker.model)):
+        # make the scheduler reserve blocks that are never consumed. (A
+        # sliding window is no reason: the staged decode moves the
+        # window's lower edge a token a substep.)
+        from intellillm_tpu.layers.attention import (model_attention_windows,
+                                                     model_uses_alibi)
+        if self._window and not model_attention_windows(self.worker.model):
+            raise NotImplementedError(
+                f"The config gives a sliding window of {self._window} "
+                f"tokens, but {type(self.worker.model).__name__} builds no "
+                f"attention layer with a window: the windowed cache group "
+                f"would give back blocks its layers still read.")
+        if scheduler_config.num_decode_steps > 1 and model_uses_alibi(
+                self.worker.model):
             if speculative_config is not None:
                 raise ValueError(
                     "Speculative decoding needs the fused multi-step "
-                    "decode program, which sliding-window/ALiBi models "
-                    "cannot use.")
-            logger.info(
-                "Clamping num_decode_steps %d -> 1 (model uses %s).",
-                scheduler_config.num_decode_steps,
-                "sliding window" if model_config.get_sliding_window()
-                is not None else "ALiBi")
+                    "decode program, which ALiBi models cannot use.")
+            logger.info("Clamping num_decode_steps %d -> 1 (model uses "
+                        "ALiBi).", scheduler_config.num_decode_steps)
             scheduler_config.num_decode_steps = 1
 
         # Chunked prefill + speculative decoding compose since the
@@ -246,7 +262,7 @@ class LLMEngine:
         # teacher-forced verify per step) — no pipelined continuations.
         # Chunked mode pipelines too: steady-state decode runs the fused
         # continuation programs, and a sequence mid-prefill over several
-        # chunks forces a fresh (mixed) schedule.
+        # chunks gets them as prompt steps beside the continuations.
         self.pipeline_enabled = (pipeline_enabled_env()
                                  and speculative_config is None)
         self._pipeline_depth = max(
@@ -501,6 +517,31 @@ class LLMEngine:
                 f"K/V blocks of a prompt prefix and no state snapshot at "
                 f"the prefix's end.")
 
+    def _refuse_for_window_model(self, speculative_config) -> None:
+        """Start-up refusals of a model with a sliding-window cache group,
+        each naming the mechanism it lacks."""
+        if speculative_config is not None:
+            raise NotImplementedError(
+                "Speculative decoding is not supported for a model with a "
+                "sliding-window cache group: the teacher-forced decode "
+                "program takes one block table a row and no window, and a "
+                "draft model's pool has no window group.")
+        role = self.scheduler_config.replica_role
+        if role != "mixed":
+            raise NotImplementedError(
+                f"replica_role={role!r} (KV export/import, "
+                f"worker/kv_transfer.py) is not supported for a model with "
+                f"a sliding-window cache group: the IKV1 payload carries "
+                f"one table of blocks a prefix, and the window group has "
+                f"given back the blocks of a prefix longer than the window.")
+
+    _WINDOW_PREFIX_REFUSAL = (
+        "Prefix reuse (prefix_pos, prefix.py and the prefix pool) is not "
+        "supported for a model with a sliding-window cache group: the "
+        "window group gives back the blocks of a prefix longer than the "
+        "window, so a later request could not attend to them (it would "
+        "need the pool to pin a window's worth of blocks a prefix).")
+
     _PREFIX_REFUSAL = (
         "Prefix reuse (prefix_pos, prefix.py and the prefix pool) is not "
         "supported for a model with per-sequence convolution state: a "
@@ -534,6 +575,9 @@ class LLMEngine:
         from intellillm_tpu.worker.cache_engine import CacheEngine
         mc = self.model_config
         state_slot_bytes = CacheEngine.get_state_slot_size(mc)
+        # One layer's share of a block (either group: the rows are alike).
+        layer_block_bytes = CacheEngine.get_layer_block_size(
+            cc.block_size, cc.cache_dtype, mc)
         self._boot.set_info("cache", {
             "num_device_blocks": cc.num_device_blocks,
             "num_cpu_blocks": cc.num_cpu_blocks,
@@ -547,9 +591,7 @@ class LLMEngine:
             # Device bytes one token takes in one layer's cache (lane
             # padding included): block bytes / block size / layers with
             # a pool.
-            "bytes_per_token_per_layer": CacheEngine.get_cache_block_size(
-                cc.block_size, cc.cache_dtype, mc, self.parallel_config)
-            // (cc.block_size * max(mc.get_num_kv_layers(), 1)),
+            "bytes_per_token_per_layer": layer_block_bytes // cc.block_size,
             # Layers by what they cache, and the per-sequence state beside
             # the paged pools (0 slots for a model without state layers).
             "kv_layers": mc.get_num_kv_layers(),
@@ -558,8 +600,25 @@ class LLMEngine:
             "state_slot_bytes": state_slot_bytes,
             # intellillm_cache_bytes{kind}: the device pools' sizes.
             "bytes": self._efficiency.record_cache_bytes(
-                kv=self._kv_block_bytes * cc.num_device_blocks,
+                kv=(layer_block_bytes * mc.get_num_kv_layers()
+                    * cc.num_device_blocks),
+                kv_window=(layer_block_bytes * mc.get_num_window_layers()
+                           * cc.num_window_device_blocks),
                 state=state_slot_bytes * cc.num_state_slots),
+            # The cache groups: layers, blocks and tokens each holds. A
+            # window group (layers with a sliding window beside layers
+            # that keep the whole context) has pools of its own.
+            "groups": {
+                "kv": {"layers": mc.get_num_kv_layers(),
+                       "window": mc.get_sliding_window(),
+                       "num_device_blocks": cc.num_device_blocks,
+                       "tokens": cc.num_device_blocks * cc.block_size},
+                "kv_window": {
+                    "layers": mc.get_num_window_layers(),
+                    "window": mc.get_window_group_size(),
+                    "num_device_blocks": cc.num_window_device_blocks,
+                    "tokens": cc.num_window_device_blocks * cc.block_size},
+            },
         })
         from intellillm_tpu.utils import runtime_facts
         self._boot.set_info("runtime", runtime_facts())
@@ -572,7 +631,8 @@ class LLMEngine:
             # (get_cache_block_size reports lane-padded DEVICE bytes).
             from intellillm_tpu.worker.cache_engine import CacheEngine
             logical = CacheEngine.get_logical_cache_block_size(
-                cc.block_size, cc.cache_dtype, self.model_config)
+                cc.block_size, cc.cache_dtype, self.model_config,
+                cc.window_ahead_tokens)
             num_cpu = max(int(cc.swap_space_bytes // logical), 1)
         else:
             num_device, num_cpu = self.worker.profile_num_available_blocks(
@@ -594,17 +654,23 @@ class LLMEngine:
                 "decrease max_model_len.")
         cc.num_device_blocks = num_device
         cc.num_cpu_blocks = num_cpu
+        cc.size_window_group(self.model_config)
         logger.info("KV cache: %d device blocks, %d CPU (swap) blocks",
                     num_device, num_cpu)
+        if cc.num_window_device_blocks:
+            logger.info("Window group: %d device blocks, %d CPU blocks",
+                        cc.num_window_device_blocks,
+                        cc.num_window_cpu_blocks)
         self.worker.init_cache_engine(cc)
         # Per-block byte sizes for the absolute used/total figures in
         # Stats (physical device bytes; unpadded host bytes for swap).
         from intellillm_tpu.worker.cache_engine import CacheEngine
         self._kv_block_bytes = CacheEngine.get_cache_block_size(
             cc.block_size, cc.cache_dtype, self.model_config,
-            self.parallel_config)
+            self.parallel_config, cc.window_ahead_tokens)
         self._cpu_block_bytes = CacheEngine.get_logical_cache_block_size(
-            cc.block_size, cc.cache_dtype, self.model_config)
+            cc.block_size, cc.cache_dtype, self.model_config,
+            cc.window_ahead_tokens)
 
     @classmethod
     def from_engine_args(cls, engine_args: EngineArgs,
@@ -652,7 +718,7 @@ class LLMEngine:
             # capable replica and needs the complete output.
             if (prefix_pos is None
                     and sampling_params.prompt_logprobs is None
-                    and self.model_config.get_sliding_window() is None):
+                    and not self._window):
                 aligned = ((len(prompt_token_ids) - 1) // block_size
                            ) * block_size
                 if aligned > 0:
@@ -670,15 +736,8 @@ class LLMEngine:
             raise ValueError(
                 "prompt_logprobs cannot be combined with prefix_pos.")
         if prefix_pos is not None:
-            if self.model_config.get_sliding_window() is not None:
-                # The ring block layout stores only the last `window` tokens
-                # at wrapped slot indices, so cached-prefix attention cannot
-                # recover absolute key positions once the prefix exceeds the
-                # window. Same restriction as the reference (prefix caching
-                # + sliding window unsupported).
-                raise ValueError(
-                    "Prefix caching (prefix_pos) is not supported for "
-                    "sliding-window models.")
+            if self._window:
+                raise NotImplementedError(self._WINDOW_PREFIX_REFUSAL)
             prefix = self.scheduler.prefix_pool.add_or_get_prefix(
                 prompt_token_ids[:prefix_pos],
                 lora_request.lora_int_id if lora_request else 0)
@@ -915,10 +974,16 @@ class LLMEngine:
     #   - what needs a coherent host view drains the pipeline to a full
     #     schedule(), counted by cause: no decode step in flight (idle),
     #     swap / preemption / a refused block growth (memory), a joiner
-    #     the fused program excludes or a K=1 batch (params), a sequence
-    #     mid-prefill over several chunks (chunked_prefill), more rows
+    #     the fused program excludes or a K=1 batch (params), more rows
     #     than the batch bucket in flight was compiled for, or few enough
-    #     for a smaller one _REBUCKET_AFTER_CONTS times running (bucket).
+    #     for a smaller one _REBUCKET_AFTER_CONTS times running (bucket);
+    #   - a prompt longer than a step's token budget runs its chunks as
+    #     prompt steps too (schedule(prefill_only=True) hands out the
+    #     next chunk of every sequence part-way through its prompt), so a
+    #     long prompt does not drain a pipeline that has a decode step in
+    #     flight; with none in flight (an idle start, after a drain) the
+    #     fresh schedule runs its chunks in mixed steps with decode rows
+    #     at K=1 and counts a chunked_prefill drain, as ever.
     # KV pages referenced by in-flight steps are free-guarded in the
     # scheduler: a sequence finishing host-side mid-pipeline stays a
     # "zombie" row of the steps already dispatched (its outputs are
@@ -963,9 +1028,20 @@ class LLMEngine:
         # New prompts admit immediately, chained behind in-flight steps,
         # a bounded number per decode step in flight, so that a burst of
         # prompts cannot hold decode back by more than that.
-        if (sched.waiting and not sched.swapped
+        # A prompt longer than the budget runs its chunks as prompt steps
+        # only beside a decode step in flight. With none (an idle start, a
+        # drained pipeline) no continuation can be dispatched either (it
+        # needs a decode step to continue, and a fresh schedule an empty
+        # pipeline), so chunk steps of their own would hold every running
+        # sequence still until the last waiting prompt's last chunk: the
+        # fresh schedule below puts the chunks in steps with their decode
+        # rows instead, as it does under the serial loop.
+        chunks = decodes > 0
+        if ((sched.waiting or (chunks and sched.mid_prefill()))
+                and not sched.swapped
                 and others < (decodes or 1) * _PROMPT_STEPS_PER_DECODE_STEP):
-            metas, so = sched.schedule(prefill_only=True)
+            metas, so = sched.schedule(prefill_only=True,
+                                       chunk_long_prompts=chunks)
             if so.ignored_seq_groups and not metas:
                 # Rejected without device work (over-long prompts):
                 # surface their outputs with the next batch returned.
@@ -997,8 +1073,9 @@ class LLMEngine:
             return False
         if sched.swapped:
             return self._drain("memory")
-        if sched.mid_prefill():
-            return self._drain("chunked_prefill")
+        # A sequence part-way through its prompt is no row of the decode
+        # batch: its chunks run as prompt steps beside the continuations
+        # (the pass above) and it joins once its final chunk is fetched.
         return self._dispatch_cont(seed)
 
     def _dispatch_fresh(self) -> bool:
@@ -1157,7 +1234,8 @@ class LLMEngine:
             for seq in group.get_seqs(status=SequenceStatus.RUNNING):
                 alive.add(seq.seq_id)
                 if (seq.seq_id in self._decode_members
-                        or seq.seq_id in prefilling):
+                        or seq.seq_id in prefilling
+                        or not seq.data.prefill_complete):
                     continue
                 if self._fused_excludes(group.sampling_params):
                     self._drain("params")
@@ -1197,10 +1275,14 @@ class LLMEngine:
         bm = sched.block_manager
         targets = [(owners[slot][0].seq_id, min(int(ctx[slot]) + k - 1, mml))
                    for slot in slots]
-        if not bm.can_grow_all(targets):
+        # A row's first query of this step stands at its context's last
+        # position: what a windowed cache group may give back first.
+        firsts = [max(int(ctx[slot]) - 1, 0) for slot in slots]
+        if not bm.can_grow_all(targets, firsts):
             self._drain("memory")
             return None
-        tables = [bm.grow_to(sid, target) for sid, target in targets]
+        tables = [bm.grow_to(sid, target, first)
+                  for (sid, target), first in zip(targets, firsts)]
         state_slots = [bm.get_state_slot(sid) for sid, _ in targets]
 
         # One metadata entry per group holding a row, in slot order.
@@ -1208,6 +1290,9 @@ class LLMEngine:
                                k)
         cont.owners = [owners[slot] for slot in slots]
         cont.state_slots = state_slots if self._stateful else None
+        if bm.window_group is not None:
+            cont.window_tables = [bm.window_group.table_numbers(sid)
+                                  for sid, _ in targets]
         groups: List[SequenceGroup] = []
         listed: Set[str] = set()
         for seq, meta, group in cont.owners:

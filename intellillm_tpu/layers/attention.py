@@ -58,6 +58,13 @@ class AttentionMetadata:
     # "state" layers, layers/short_conv.py); a slot past the pool for a
     # pad row, as slot_mapping does. None for every other model.
     state_slots: Optional[jnp.ndarray] = None
+    # The window group's table and slots (a model with "kv_window" layers
+    # beside "kv" ones, `ModelConfig.get_layer_cache_kinds`): a layer
+    # with a sliding window reads these instead of `block_tables` /
+    # `slot_mapping`. None for every other model, one whose every layer
+    # has the window included (its one group is the windowed one).
+    window_block_tables: Optional[jnp.ndarray] = None
+    window_slot_mapping: Optional[jnp.ndarray] = None
 
 
 class PagedAttention:
@@ -71,7 +78,11 @@ class PagedAttention:
         scale: float,
         num_kv_heads: Optional[int] = None,
         alibi_slopes=None,
+        sliding_window: Optional[int] = None,
     ) -> None:
+        """`sliding_window`: a query attends to its last `sliding_window`
+        tokens, its own included (None: to its whole context)."""
+        self.sliding_window = sliding_window
         self.num_heads = num_heads
         self.head_size = head_size
         self.scale = scale
@@ -115,13 +126,29 @@ class PagedAttention:
         # per sequence, one block table per row.
         b, l, _, d = query.shape
         k_cache, v_cache = kv_cache
+        tables, slots = self._group_tables(attn_metadata)
         out, k_cache, v_cache = ragged_fused_attention(
             query, key.reshape(b * l, self.num_kv_heads, d),
             value.reshape(b * l, self.num_kv_heads, d), k_cache, v_cache,
-            attn_metadata.slot_mapping.reshape(-1),
-            attn_metadata.block_tables, attn_metadata.context_lens,
-            self.scale, self.alibi_slopes, mesh=attn_metadata.mesh)
+            slots.reshape(-1), tables, attn_metadata.context_lens,
+            self.scale, self.alibi_slopes, mesh=attn_metadata.mesh,
+            **self._window_kwargs())
         return out, (k_cache, v_cache)
+
+    def _group_tables(self, attn_metadata):
+        """(block tables, slot mapping) of this layer's cache group."""
+        if (self.sliding_window is not None
+                and attn_metadata.window_block_tables is not None):
+            return (attn_metadata.window_block_tables,
+                    attn_metadata.window_slot_mapping)
+        return attn_metadata.block_tables, attn_metadata.slot_mapping
+
+    def _window_kwargs(self, **more):
+        """The seams' window arguments; none for a layer without one, so
+        that its calls are what they were."""
+        if self.sliding_window is None:
+            return {}
+        return dict(window=self.sliding_window, **more)
 
     def _staged_decode(self, query, key, value, kv_cache, attn_metadata):
         """Fused multi-step decode: pool is read-only; the substep's K/V go
@@ -137,14 +164,39 @@ class PagedAttention:
         v_stage = jax.lax.dynamic_update_slice_in_dim(
             v_stage, value.astype(v_stage.dtype), k_idx, axis=1)
 
+        # Under a window the substep's query stands k + 1 positions past
+        # the pool's last token, so the window's lower edge moves a token
+        # a substep; the stage's tokens (at most a staging chunk, which
+        # the runner holds to the window) are all inside it.
         out_pool, lse_pool = paged_pool_attention(
-            query, k_pool, v_pool, attn_metadata.block_tables,
+            query, k_pool, v_pool, self._group_tables(attn_metadata)[0],
             attn_metadata.context_lens, self.scale, self.alibi_slopes,
-            mesh=attn_metadata.mesh)
+            mesh=attn_metadata.mesh,
+            **self._window_kwargs(query_ahead=k_idx + 1))
         out_stage, lse_stage = staged_decode_attention(
             query, k_stage, v_stage, k_idx, self.scale)
         out = merge_attention_parts(out_pool, lse_pool, out_stage, lse_stage)
         return out, (k_pool, v_pool, k_stage, v_stage)
+
+
+def _any_attention_layer(model, said) -> bool:
+    """Whether `said(layer)` holds of any PagedAttention in the model."""
+    seen = set()
+
+    def walk(obj, depth) -> bool:
+        if id(obj) in seen or depth > 4:
+            return False
+        seen.add(id(obj))
+        if isinstance(obj, PagedAttention):
+            return said(obj)
+        if isinstance(obj, (list, tuple)):
+            return any(walk(v, depth + 1) for v in obj)
+        d = getattr(obj, "__dict__", None)
+        if not isinstance(d, dict):
+            return False
+        return any(walk(v, depth + 1) for v in d.values())
+
+    return walk(model, 0)
 
 
 def model_uses_alibi(model) -> bool:
@@ -154,19 +206,14 @@ def model_uses_alibi(model) -> bool:
     ALiBi model family cannot silently miss the fused multi-step decode
     clamp: the engine forces K=1 for ALiBi models because the staged scan
     holds context_lens constant across substeps."""
-    seen = set()
+    return _any_attention_layer(
+        model, lambda layer: layer.alibi_slopes is not None)
 
-    def walk(obj, depth) -> bool:
-        if id(obj) in seen or depth > 4:
-            return False
-        seen.add(id(obj))
-        if isinstance(obj, PagedAttention):
-            return obj.alibi_slopes is not None
-        if isinstance(obj, (list, tuple)):
-            return any(walk(v, depth + 1) for v in obj)
-        d = getattr(obj, "__dict__", None)
-        if not isinstance(d, dict):
-            return False
-        return any(walk(v, depth + 1) for v in d.values())
 
-    return walk(model, 0)
+def model_attention_windows(model) -> bool:
+    """True if any PagedAttention layer in the model has a sliding window:
+    a config that gives one is served only by a model that builds its
+    layers with it (the windowed cache group gives back the blocks such
+    a layer no longer reads)."""
+    return _any_attention_layer(
+        model, lambda layer: layer.sliding_window is not None)

@@ -35,6 +35,10 @@ every `mixed` step and for the life of the decode programs
 (tests/test_tpu_compile.py `test_moe_dense_copies_no_expert_stack` holds
 the compiled text to no such copy).
 
+The gate's activation is a parameter of all three (`activation`: "silu",
+the default and what every family but one uses, or "relu": a ReGLU expert,
+`relu(gate) * up`), static, so that "silu" traces what it always did.
+
 Routing is a parameter (`Routing`): the default matches HF Mixtral
 (softmax over ALL experts → top-k → renormalize the selected weights);
 sigmoid scores, group-limited selection, a scaling factor and an
@@ -48,7 +52,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from intellillm_tpu.ops.grouped_ffn import ROW_TILE, grouped_expert_ffn
+from intellillm_tpu.ops.grouped_ffn import (ROW_TILE, gate_activation,
+                                            grouped_expert_ffn)
 from intellillm_tpu.utils import cdiv
 
 # Rows of one bf16 sublane tile: a chunk of the dense path is padded to a
@@ -186,12 +191,14 @@ def moe_ffn_dense(
     chunk_size: int = DENSE_ROWS,
     routing: Routing = _SOFTMAX_TOP_K,
     routed=None,
+    activation: str = "silu",
 ) -> jnp.ndarray:
     """`chunk_size` is the most rows a chunk may hold (it bounds the
     [N, C, I] activations); the rows present decide the rest. `routed` is
     a (weights, experts) pair the caller already has from `_route`."""
     t, d = x.shape
     n = w1.shape[0]
+    act = gate_activation(activation)
 
     if routed is None:
         with jax.named_scope("moe_router"):
@@ -217,7 +224,7 @@ def moe_ffn_dense(
                         preferred_element_type=jnp.float32)
         h3 = jnp.einsum("td,nid->nti", xc, w3,
                         preferred_element_type=jnp.float32)
-        h = jax.nn.silu(h1) * h3
+        h = act(h1) * h3
         out = jnp.einsum("nti,nid->ntd", h.astype(x.dtype), w2,
                          preferred_element_type=jnp.float32)   # [N, C, D]
         mixed = jnp.einsum("ntd,tn->td", out, cc)
@@ -241,6 +248,7 @@ def moe_ffn_grouped(
     routed=None,
     tile: int = ROW_TILE,
     mesh=None,
+    activation: str = "silu",
 ) -> jnp.ndarray:
     """`tile`: rows a tile of the grouped kernels' walk (the sorted rows
     are padded to a whole number of them, once, not an expert). `mesh`:
@@ -276,7 +284,8 @@ def moe_ffn_grouped(
     xs = x[token_of]                                           # [M, D]
 
     def experts(xs, w1, w2, w3, sizes, place, flat_w, here):
-        out = grouped_expert_ffn(xs, w1, w2, w3, sizes, tile)  # [M, D] f32
+        out = grouped_expert_ffn(xs, w1, w2, w3, sizes, tile,
+                                 activation=activation)    # [M, D] f32
         # Rows of the last group (and of the pad) are as the device found
         # them: select, do not multiply by 0.
         rows = jnp.where(here[:, None], out[place] * flat_w[:, None], 0.0)
@@ -347,6 +356,7 @@ def moe_ffn(
     routing: Routing = _SOFTMAX_TOP_K,
     routed=None,
     mesh=None,
+    activation: str = "silu",
 ) -> jnp.ndarray:
     # All three stacks are [N, I, D] (module docstring): w1/w3 contract on
     # their last axis, so no loop around this call makes XLA re-lay them.
@@ -366,6 +376,7 @@ def moe_ffn(
         w3 = dequant_int4_stack(w3, x.dtype).swapaxes(1, 2)
     if grouped_rows(x.shape[0]):
         return moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k,
-                               routing=routing, routed=routed, mesh=mesh)
+                               routing=routing, routed=routed, mesh=mesh,
+                               activation=activation)
     return moe_ffn_dense(x, gate_w, w1, w2, w3, top_k, routing=routing,
-                         routed=routed)
+                         routed=routed, activation=activation)

@@ -28,6 +28,7 @@ from intellillm_tpu.models.opt import OPTForCausalLM
 from intellillm_tpu.models.phi import PhiForCausalLM
 from intellillm_tpu.models.qwen import QWenLMHeadModel
 from intellillm_tpu.models.qwen2 import Qwen2ForCausalLM
+from intellillm_tpu.models.smallthinker import SmallThinkerForCausalLM
 from intellillm_tpu.models.stablelm import StableLMForCausalLM
 
 _MODEL_REGISTRY: Dict[str, Type] = {
@@ -67,6 +68,8 @@ _MODEL_REGISTRY: Dict[str, Type] = {
     "DeepseekForCausalLM": DeepseekForCausalLM,
     "AXK1ForCausalLM": AXK1ForCausalLM,          # latent attention + EP share
     "Lfm2MoeForCausalLM": Lfm2MoeForCausalLM,    # short-conv state + GQA
+    # window + full attention layers (two cache groups), ReGLU experts
+    "SmallThinkerForCausalLM": SmallThinkerForCausalLM,
 }
 
 

@@ -80,6 +80,8 @@ class LlamaForCausalLM:
             head_size=self.head_size,
             scale=self.head_size**-0.5,
             num_kv_heads=self.num_kv_heads,
+            # Mistral's (every layer's) window; None for the others.
+            sliding_window=model_config.get_sliding_window(),
         )
 
     def __call__(

@@ -72,7 +72,10 @@ TOKEN_KINDS = ("real", "pad")
 # What happens to a per-sequence state slot (core/block_manager.py), and
 # the device cache pools by kind.
 STATE_SLOT_EVENTS = ("alloc", "free", "swap_out", "swap_in", "fork")
-CACHE_KINDS = ("kv", "state")
+CACHE_KINDS = ("kv", "kv_window", "state")
+# Layers by how far their attention sees (the attended-tokens counter).
+ATTENTION_REACHES = ("full", "window")
+WINDOW_BLOCK_EVENTS = ("recycled", )
 AXES = ("batch", "len", "block_width")
 _DEFAULT_MFU_WINDOW = 64
 _FILL_BUCKETS = (0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9,
@@ -144,6 +147,19 @@ class _EfficiencyMetrics:
             "for the same rows and substeps (whole pages, by the "
             "kernel's own rule): over the context tokens, what the walk "
             "fetches beyond what the rows hold.", ["program"])
+        self.counter_dispatch_attended_tokens = Counter(
+            "intellillm_dispatch_attended_tokens_total",
+            "Tokens a row attends to in ONE layer of a kind (layers: full "
+            "= its whole context; window = what a sliding window leaves "
+            "of it), summed over the same live rows and substeps as the "
+            "context tokens. A kind the model has no layer of stays 0.",
+            ["program", "layers"])
+        self.counter_window_blocks = Counter(
+            "intellillm_window_blocks_total",
+            "What the block manager did with blocks of a sliding-window "
+            "cache group (event: recycled = given back to the group's "
+            "free list because every token in it fell out of the "
+            "window).", ["event"])
         self.counter_moe_assignments = Counter(
             "intellillm_moe_assignments_total",
             "Token x expert choices of an expert-parallel share's router, "
@@ -189,8 +205,10 @@ class _EfficiencyMetrics:
         self.gauge_cache_bytes = Gauge(
             "intellillm_cache_bytes",
             "Device bytes of the cache pools (kind: kv = the paged K/V or "
-            "latent pools of the layers that have one; state = the "
-            "per-sequence state pools).", ["kind"])
+            "latent pools of the layers that keep the whole context; "
+            "kv_window = those of the sliding-window layers, where they "
+            "are a group of their own; state = the per-sequence state "
+            "pools).", ["kind"])
         for state in ("used", "free"):
             self.gauge_state_slots.labels(state).set(0)
         for path in MOE_FFN_PATHS:
@@ -211,6 +229,10 @@ class _EfficiencyMetrics:
             self.counter_dispatch_row_steps.labels(program)
             self.counter_dispatch_context_tokens.labels(program)
             self.counter_dispatch_walked_tokens.labels(program)
+            for reach in ATTENTION_REACHES:
+                self.counter_dispatch_attended_tokens.labels(program, reach)
+        for event in WINDOW_BLOCK_EVENTS:
+            self.counter_window_blocks.labels(event)
         for via in JOIN_VIAS:
             self.counter_decode_join_rows.labels(via)
         for cause in DRAIN_CAUSES:
@@ -436,7 +458,9 @@ class EfficiencyTracker:
                         device_idle: bool = False,
                         row_steps: int = 0,
                         context_tokens: int = 0,
-                        walked_tokens: int = 0) -> None:
+                        walked_tokens: int = 0,
+                        attended_tokens: Optional[Dict[str, int]] = None
+                        ) -> None:
         """Account one device dispatch. Extents are pre-padding vs
         post-padding; token counts are what the device actually
         computes (prefill: rows x padded len; decode: rows x substeps).
@@ -448,7 +472,9 @@ class EfficiencyTracker:
         live rows x substeps, and the context lengths attention walks
         summed over them. `walked_tokens`: what the kernel's page walk
         moves for those same row-substeps (`walk_extent` of the kernel
-        module: whole pages)."""
+        module: whole pages; where the model has two cache groups, the
+        mean over its layers). `attended_tokens`: by ATTENTION_REACHES,
+        what those row-substeps attend to in one layer of that kind."""
         if not self.enabled:
             return
         real_tokens = int(real_tokens)
@@ -498,6 +524,9 @@ class EfficiencyTracker:
                     context_tokens)
                 m.counter_dispatch_walked_tokens.labels(program).inc(
                     walked_tokens)
+                for reach, n in (attended_tokens or {}).items():
+                    m.counter_dispatch_attended_tokens.labels(
+                        program, reach).inc(n)
             m.counter_tokens.labels("real", phase).inc(real_tokens)
             m.counter_tokens.labels("pad", phase).inc(pad_tokens)
             for axis, ratio in fills:
@@ -553,10 +582,18 @@ class EfficiencyTracker:
         if event is not None:
             m.counter_state_slot_events.labels(event).inc()
 
-    def record_cache_bytes(self, kv: int, state: int) -> Dict[str, int]:
+    def record_window_blocks(self, event: str, blocks: int) -> None:
+        """Blocks of a sliding-window cache group, by what happened to
+        them (one of WINDOW_BLOCK_EVENTS)."""
+        if self.enabled and self._metrics is not None:
+            self._metrics.counter_window_blocks.labels(event).inc(blocks)
+
+    def record_cache_bytes(self, kv: int, state: int,
+                           kv_window: int = 0) -> Dict[str, int]:
         """Sizes of the device cache pools, once at engine init; returned
         for `/health/detail`."""
-        sizes = {"kv": int(kv), "state": int(state)}
+        sizes = {"kv": int(kv), "kv_window": int(kv_window),
+                 "state": int(state)}
         if self.enabled and self._metrics is not None:
             for kind, n in sizes.items():
                 self._metrics.gauge_cache_bytes.labels(kind).set(n)

@@ -17,7 +17,8 @@ GQA/MQA is handled by reshaping queries to [.., kv_heads, group, ..] rather
 than materializing repeated KV heads (reference expands heads instead,
 attention.py:106-120 — wasteful on HBM bandwidth).
 ALiBi biases (attention.py:196-227) are supported throughout; a sliding
-window is the runner's (a ring of blocks and a clamped context), never a
+window reaches the paged references as each row's first visible position
+(`lo`), and a stale table entry below it is gathered and masked, never a
 mask inside attention, and only the dense oracle takes one.
 """
 from __future__ import annotations
@@ -97,8 +98,10 @@ def decode_attention_reference(
     scale: float,
     alibi_slopes: Optional[jnp.ndarray] = None,
     return_lse: bool = False,
+    lo: Optional[jnp.ndarray] = None,
 ):
-    """Single-token decode attention via block-table gather.
+    """Single-token decode attention via block-table gather. `lo` [B]
+    (a sliding window): positions below it are not attended to.
 
     Correct-everywhere baseline for the Pallas paged-attention kernel; used
     directly on CPU (tests) and as the numerics oracle in kernel tests.
@@ -121,6 +124,8 @@ def decode_attention_reference(
 
     pos = jnp.arange(max_ctx)[None, :]
     valid = pos < context_lens[:, None]        # [B, max_ctx]
+    if lo is not None:
+        valid &= pos >= lo[:, None]
 
     if alibi_slopes is not None:
         slopes = alibi_slopes.reshape(hkv, hq // hkv)

@@ -100,13 +100,19 @@ _ON_LAST_AXES = (((1, ), (1, )), ((), ()))      # a . b^T
 
 
 def _gate_up_kernel(offsets, expert_of, tile_of, x_ref, w1_ref, w3_ref,
-                    h_ref):
+                    h_ref, *, activation: str = "silu"):
     x = x_ref[...]
     h1 = jax.lax.dot_general(x, w1_ref[...], _ON_LAST_AXES,
                              preferred_element_type=jnp.float32)
     h3 = jax.lax.dot_general(x, w3_ref[...], _ON_LAST_AXES,
                              preferred_element_type=jnp.float32)
-    h = (h1 * jax.nn.sigmoid(h1) * h3).astype(h_ref.dtype)
+    if activation == "silu":
+        gate = h1 * jax.nn.sigmoid(h1)
+    elif activation == "relu":
+        gate = jnp.maximum(h1, 0.0)
+    else:
+        raise ValueError(f"unknown expert activation {activation!r}")
+    h = (gate * h3).astype(h_ref.dtype)
     own = _own_rows((offsets, expert_of, tile_of), *h_ref.shape)
     # Another expert's visit of this tile wrote the other rows: keep them.
     h_ref[...] = jnp.where(own, h, h_ref[...])
@@ -149,15 +155,17 @@ def _rows_at_column(c, v, offsets, expert_of, tile_of):
     return tile_of[v], c
 
 
-@functools.partial(jax.jit, static_argnames=("tile", ))
-def _grouped_gate_up_call(meta, visits, xs, w1, w3, *, tile):
+@functools.partial(jax.jit, static_argnames=("tile", "activation"))
+def _grouped_gate_up_call(meta, visits, xs, w1, w3, *, tile,
+                          activation="silu"):
     m, d = xs.shape
     n, i, _ = w1.shape
     ti = _column_tile(i, d, 2, w1.dtype.itemsize)
     weight = pl.BlockSpec((None, ti, d),
                           lambda c, v, off, e, t: (e[v], c, 0))
     return pl.pallas_call(
-        _gate_up_kernel,
+        (_gate_up_kernel if activation == "silu" else
+         functools.partial(_gate_up_kernel, activation=activation)),
         out_shape=jax.ShapeDtypeStruct((m, i), xs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -166,7 +174,8 @@ def _grouped_gate_up_call(meta, visits, xs, w1, w3, *, tile):
             grid=(i // ti, visits)),
         compiler_params=_COMPILER_PARAMS,
         cost_estimate=pl.CostEstimate(
-            flops=4 * m * d * i, transcendentals=m * i,
+            flops=4 * m * d * i,
+            transcendentals=m * i if activation == "silu" else 0,
             bytes_accessed=(2 * n * i * d + m * d * (i // ti) + m * i)
             * xs.dtype.itemsize),
     )(*meta, xs, w1, w3)
@@ -195,10 +204,12 @@ def _grouped_down_call(meta, visits, h, w2, *, tile):
     )(*meta, h, w2)
 
 
-def grouped_ffn(xs, w1, w2, w3, group_sizes, tile: int):
+def grouped_ffn(xs, w1, w2, w3, group_sizes, tile: int,
+                activation: str = "silu"):
     """[M, D] float32: the expert FFN of rows sorted by expert. Callers
     check `grouped_ffn_supported` first."""
     meta, visits = visit_metadata(group_sizes.astype(jnp.int32),
                                   xs.shape[0], tile)
-    h = _grouped_gate_up_call(meta, visits, xs, w1, w3, tile=tile)
+    h = _grouped_gate_up_call(meta, visits, xs, w1, w3, tile=tile,
+                              activation=activation)
     return _grouped_down_call(meta, visits, h, w2, tile=tile)

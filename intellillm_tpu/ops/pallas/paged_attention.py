@@ -116,15 +116,22 @@ def _sub_pages(pages_per_group: int) -> int:
     return _SUB_PAGES
 
 
-def walk_extent(ctx, block_size: int, w: int, sub_pages: int = 1, xp=np):
+def walk_extent(ctx, block_size: int, w: int, sub_pages: int = 1, xp=np,
+                lo=None):
     """THE rule of the page walk, shared by both paged kernels (`xp=jnp`,
     on SMEM scalars) and by the host's counter (numpy, vectorised): a row
     whose walk must see `ctx` tokens (its context for decode, its context
     less its own token for the ragged kernel) moves the first
     ceil(ctx / block_size) pages of its `w`-wide table, none for a pad
     row, and multiplies those pages rounded up to a whole sub-group.
+    Under a sliding window the row sees no position below `lo` (its
+    first visible one): the walk starts at the page that holds `lo`
+    (`lo // block_size`, the edge page whole: the mask is exact inside
+    it) and moves nothing below it.
     Returns (pages moved, tokens multiplied)."""
     pages = xp.clip((ctx + (block_size - 1)) // block_size, 0, w)
+    if lo is not None:
+        pages = xp.maximum(pages - lo // block_size, 0)
     subs = (pages + (sub_pages - 1)) // sub_pages
     return pages, subs * (sub_pages * block_size)
 
@@ -146,7 +153,7 @@ def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
           m_scr, l_scr, acc_scr, code_scr, *, held_back: int,
           batch_size: int, num_head_blocks: int, heads_per_block: int,
           num_groups_g: int, pages_per_group: int, block_size: int,
-          scale: float, w_max: int):
+          scale: float, w_max: int, windowed: bool = False):
     """Online softmax of this grid step's (row, kv-head block) over the
     row's paged context less its last `held_back` tokens, into the scratch
     accumulators. Returns the scaled query block [HP*G, D] in f32.
@@ -161,7 +168,14 @@ def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
     multiplied in one piece up to the last sub-group that holds one. A
     row with nothing to walk (a pad row) moves and multiplies nothing.
     Copies are started and waited for by the same rule on the same row's
-    context, so what is waited for is what was started."""
+    context, so what is waited for is what was started.
+
+    `windowed`: `context_lens_ref` is [2B], the rows' contexts and then
+    their first visible positions `lo` (a sliding window). A row's walk
+    starts at table entry `lo // block_size` (its page 0 is that entry:
+    groups, tail and prefetch count from there) and positions below `lo`
+    in that first page are masked. Not windowed, nothing here differs
+    from what it was."""
     b = pl.program_id(0)
     hb = pl.program_id(1)
     hp, g_sz, ppg, bs = (heads_per_block, num_groups_g, pages_per_group,
@@ -169,9 +183,14 @@ def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
     sub = _sub_pages(ppg)
     pools = ((k_hbm_ref, k_buf, k_sem), (v_hbm_ref, v_buf, v_sem))
 
+    def first_page(row):
+        return lax.div(context_lens_ref[batch_size + row], bs)
+
     def walk_pages(row):
         return walk_extent(context_lens_ref[row] - held_back, bs, w_max,
-                           xp=jnp)[0]
+                           xp=jnp,
+                           lo=(context_lens_ref[batch_size + row]
+                               if windowed else None))[0]
 
     limit = context_lens_ref[b] - held_back
     pages = walk_pages(b)
@@ -179,7 +198,10 @@ def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
     tail = pages - full_groups * ppg
 
     def start_page(row, hb_, g, j, buf):
-        page = tables_ref[row * w_max + g * ppg + j]
+        entry = row * w_max + g * ppg + j
+        if windowed:
+            entry = entry + first_page(row)
+        page = tables_ref[entry]
         # Chained single-axis dynamic slices: Mosaic supports dynamic
         # indexing one (leading) axis at a time.
         for hbm, vmem, sem in pools:
@@ -274,7 +296,12 @@ def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
         # Token position of each FLAT column within the full sequence
         # (out of reach for a column of another head's).
         token_pos = g * (ppg * bs) + code_scr[:, :ncols]  # [HP*G, NC]
-        mask = token_pos < limit
+        if windowed:
+            token_pos = token_pos + first_page(b) * bs
+            mask = ((token_pos < limit)
+                    & (token_pos >= context_lens_ref[batch_size + b]))
+        else:
+            mask = token_pos < limit
 
         # ONE flat dot for all HP heads: [HP*G, D] x [NPG*HP*BS, D]^T. The
         # cross-head scores are junk (masked out); the extra FLOPs are
@@ -402,10 +429,11 @@ def _default_hp(k_cache) -> int:
     return 32 if k_cache.dtype.itemsize == 1 else 16
 
 
-@functools.partial(
-    jax.jit, static_argnames=("scale_static", ))
-def _paged_attention_call(q_grouped, slopes, k_cache, v_cache, block_tables,
-                          context_lens, *, scale_static: float):
+def _paged_attention_launch(q_grouped, slopes, k_cache, v_cache,
+                            block_tables, context_lens, *,
+                            scale_static: float, windowed: bool):
+    """`context_lens` [B], or windowed [2B]: the contexts, then the rows'
+    first visible positions."""
     b, hkv, g, d = q_grouped.shape
     nb, _, bs, _ = k_cache.shape
     w = block_tables.shape[1]
@@ -451,6 +479,7 @@ def _paged_attention_call(q_grouped, slopes, k_cache, v_cache, block_tables,
         block_size=bs,
         scale=scale_static,
         w_max=w,
+        **({"windowed": True} if windowed else {}),
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -474,6 +503,29 @@ def _paged_attention_call(q_grouped, slopes, k_cache, v_cache, block_tables,
     return out.astype(q_grouped.dtype), lse[..., 0]
 
 
+@functools.partial(
+    jax.jit, static_argnames=("scale_static", ))
+def _paged_attention_call(q_grouped, slopes, k_cache, v_cache, block_tables,
+                          context_lens, *, scale_static: float):
+    return _paged_attention_launch(q_grouped, slopes, k_cache, v_cache,
+                                   block_tables, context_lens,
+                                   scale_static=scale_static, windowed=False)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale_static", ))
+def _paged_attention_call_window(q_grouped, slopes, k_cache, v_cache,
+                                 block_tables, context_lens, lo, *,
+                                 scale_static: float):
+    """The same launch under a sliding window (`lo` [B]: each row's first
+    visible position); a name of its own so that a trace tells the
+    windowed calls apart."""
+    return _paged_attention_launch(
+        q_grouped, slopes, k_cache, v_cache, block_tables,
+        jnp.concatenate([context_lens, lo]), scale_static=scale_static,
+        windowed=True)
+
+
 def paged_attention(
     q: jnp.ndarray,             # [B, 1, Hq, D]
     k_cache: jnp.ndarray,       # [NB, Hkv, BS, D]
@@ -483,9 +535,12 @@ def paged_attention(
     scale: float,
     alibi_slopes: Optional[jnp.ndarray] = None,
     return_lse: bool = False,
+    lo: Optional[jnp.ndarray] = None,
 ):
     """Decode-phase paged attention. Returns [B, 1, Hq, D] (and, with
     return_lse, the per-head logsumexp [B, Hq] for attention merging).
+    `lo` [B] i32 (a sliding window): a row attends to positions
+    `lo <= pos < context_lens` only, and walks no page below `lo`'s.
     The head size must be whole 128-lane tiles (Mosaic DMA windows are
     128-aligned in the minor dimension): the seam
     (`ops/ragged_attention.py` `paged_pool_attention`) sends other head
@@ -498,9 +553,15 @@ def paged_attention(
         slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(hkv, g)
     else:
         slopes = jnp.zeros((hkv, g), jnp.float32)
-    out, lse = _paged_attention_call(q_grouped, slopes, k_cache, v_cache,
-                                     block_tables, context_lens,
-                                     scale_static=float(scale))
+    if lo is None:
+        out, lse = _paged_attention_call(q_grouped, slopes, k_cache, v_cache,
+                                         block_tables, context_lens,
+                                         scale_static=float(scale))
+    else:
+        out, lse = _paged_attention_call_window(
+            q_grouped, slopes, k_cache, v_cache, block_tables,
+            context_lens.astype(jnp.int32), lo.astype(jnp.int32),
+            scale_static=float(scale))
     out = out.reshape(b, 1, hq, d)
     if return_lse:
         return out, lse.reshape(b, hq)

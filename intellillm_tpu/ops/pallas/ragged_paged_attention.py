@@ -205,10 +205,11 @@ def _ragged_kernel(
     o_ref[0] = o.reshape(hp, g_sz, -1).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale_static", ))
-def _ragged_call(q_grouped, slopes, k_new, v_new, k_cache, v_cache,
-                 slot_mapping, block_tables, context_lens, *,
-                 scale_static: float):
+def _ragged_launch(q_grouped, slopes, k_new, v_new, k_cache, v_cache,
+                   slot_mapping, block_tables, context_lens, *,
+                   scale_static: float, windowed: bool):
+    """`context_lens` [B], or windowed [2B]: the contexts, then the rows'
+    first visible positions (`_walk` says how it walks then)."""
     b, hkv, g, d = q_grouped.shape
     nb, _, bs, _ = k_cache.shape
     w = block_tables.shape[1]
@@ -259,6 +260,7 @@ def _ragged_call(q_grouped, slopes, k_new, v_new, k_cache, v_cache,
         block_size=bs,
         scale=scale_static,
         w_max=w,
+        **({"windowed": True} if windowed else {}),
     )
     out, k_cache, v_cache = pl.pallas_call(
         kernel,
@@ -291,6 +293,28 @@ def _ragged_call(q_grouped, slopes, k_new, v_new, k_cache, v_cache,
     return out.astype(q_grouped.dtype), k_cache, v_cache
 
 
+@functools.partial(jax.jit, static_argnames=("scale_static", ))
+def _ragged_call(q_grouped, slopes, k_new, v_new, k_cache, v_cache,
+                 slot_mapping, block_tables, context_lens, *,
+                 scale_static: float):
+    return _ragged_launch(q_grouped, slopes, k_new, v_new, k_cache, v_cache,
+                          slot_mapping, block_tables, context_lens,
+                          scale_static=scale_static, windowed=False)
+
+
+@functools.partial(jax.jit, static_argnames=("scale_static", ))
+def _ragged_call_window(q_grouped, slopes, k_new, v_new, k_cache, v_cache,
+                        slot_mapping, block_tables, context_lens, lo, *,
+                        scale_static: float):
+    """The same launch under a sliding window (`lo` [B]: each row's first
+    visible position); a name of its own so that a trace tells the
+    windowed calls apart."""
+    return _ragged_launch(q_grouped, slopes, k_new, v_new, k_cache, v_cache,
+                          slot_mapping, block_tables,
+                          jnp.concatenate([context_lens, lo]),
+                          scale_static=scale_static, windowed=True)
+
+
 def ragged_paged_attention(
     q: jnp.ndarray,             # [B, 1, Hq, D] flat mixed batch
     k_new: jnp.ndarray,         # [B, Hkv, D] — MUST be cache dtype
@@ -302,10 +326,13 @@ def ragged_paged_attention(
     context_lens: jnp.ndarray,  # [B] i32, counting the new token
     scale: float,
     alibi_slopes=None,
+    lo=None,
 ):
     """Fused cache-write + causal paged attention over the flat mixed
     batch. Returns (out [B, 1, Hq, D], k_cache, v_cache) with the caches
-    updated in place (donated/aliased)."""
+    updated in place (donated/aliased). `lo` [B] i32 (a sliding window):
+    a row attends to positions `lo <= pos < context_lens` only and walks
+    no page below `lo`'s."""
     b, one, hq, d = q.shape
     hkv = k_cache.shape[1]
     g = hq // hkv
@@ -337,10 +364,16 @@ def ragged_paged_attention(
     outs = []
     for r0 in range(0, b, rows):
         rs = slice(r0, r0 + rows)
-        out, k_cache, v_cache = _ragged_call(
-            q_grouped[rs], slopes, k_new[rs], v_new[rs], k_cache, v_cache,
-            slots[rs], block_tables[rs], ctx[rs],
-            scale_static=float(scale))
+        if lo is None:
+            out, k_cache, v_cache = _ragged_call(
+                q_grouped[rs], slopes, k_new[rs], v_new[rs], k_cache,
+                v_cache, slots[rs], block_tables[rs], ctx[rs],
+                scale_static=float(scale))
+        else:
+            out, k_cache, v_cache = _ragged_call_window(
+                q_grouped[rs], slopes, k_new[rs], v_new[rs], k_cache,
+                v_cache, slots[rs], block_tables[rs], ctx[rs],
+                lo.astype(jnp.int32)[rs], scale_static=float(scale))
         outs.append(out)
     out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
     return out.reshape(b, 1, hq, d), k_cache, v_cache

@@ -17,6 +17,17 @@ keys never change.
 
 `walk_extent` is the page walk's rule of both kernels, here for the
 runner's walked-tokens counter.
+
+A sliding window is a static argument of both seams (`window`, tokens):
+a row whose query stands at position p attends to positions
+`p - window < j <= p`, so its first visible position is
+`window_lo(context + ahead, window)`, which the kernels walk from
+(`ops/pallas/paged_attention.py` `_walk`; in the device trace the
+windowed launches are `_ragged_call_window` and
+`_paged_attention_call_window`). The block table is indexed by logical
+block as ever; entries below the window's first page may be stale
+(`core/block_manager.py`) and are never read by the kernels. With
+`window=None` the programs are what they were.
 """
 from __future__ import annotations
 
@@ -35,6 +46,12 @@ from intellillm_tpu.ops.pallas.ragged_paged_attention import (
 Arrays3 = Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]
 
 
+def window_lo(query_pos_plus_one, window: int):
+    """First position a query at `query_pos_plus_one - 1` sees under a
+    window of `window` tokens (works on numpy and jnp alike)."""
+    return (query_pos_plus_one - window).clip(0)
+
+
 def ragged_fused_attention(
     q: jnp.ndarray,             # [B, 1, Hq, D] flat mixed batch
     k_new: jnp.ndarray,         # [B, Hkv, D] new K per row (model dtype)
@@ -47,16 +64,20 @@ def ragged_fused_attention(
     scale: float,
     alibi_slopes: Optional[jnp.ndarray] = None,
     mesh=None,
+    window: Optional[int] = None,
 ) -> Arrays3:
     """Write each row's K/V into the paged pool and causally attend over
     it (decode rows and prefill-chunk rows alike — chunk rows just carry
     `context_lens = position + 1`). Returns (out, k_cache, v_cache).
-    `mesh` (multi-chip runs) shards the kernel over kv heads."""
+    `mesh` (multi-chip runs) shards the kernel over kv heads. `window`:
+    a row attends to its last `window` tokens, its own included."""
+    lo = None if window is None else window_lo(context_lens, window)
     if use_kernel("ragged", q.shape[-1] % 128 == 0):
         def kernel(q, k_new, v_new, k_cache, v_cache, slots, tables, ctx,
-                   slopes):
+                   slopes, *lo):
             return ragged_paged_attention(q, k_new, v_new, k_cache, v_cache,
-                                          slots, tables, ctx, scale, slopes)
+                                          slots, tables, ctx, scale, slopes,
+                                          *lo)
 
         # The kernel's DMAs cannot cast, and its self-token read must
         # match a reference read of the just-written cache line — cast
@@ -64,25 +85,27 @@ def ragged_fused_attention(
         return shard_over_heads(
             kernel, mesh, k_cache.shape[1],
             in_head_dims=(2, 1, 1, 1, 1, None, None, None,
-                          None if alibi_slopes is None else 0),
+                          None if alibi_slopes is None else 0)
+            + (() if lo is None else (None, )),
             out_head_dims=(2, 1, 1))(
                 q, k_new.astype(k_cache.dtype), v_new.astype(v_cache.dtype),
                 k_cache, v_cache, slot_mapping, block_tables, context_lens,
-                alibi_slopes)
+                alibi_slopes, *(() if lo is None else (lo, )))
     return ragged_fused_attention_reference(
         q, k_new, v_new, k_cache, v_cache, slot_mapping, block_tables,
-        context_lens, scale, alibi_slopes)
+        context_lens, scale, alibi_slopes, lo=lo)
 
 
 def ragged_fused_attention_reference(
     q, k_new, v_new, k_cache, v_cache, slot_mapping, block_tables,
-    context_lens, scale, alibi_slopes=None) -> Arrays3:
+    context_lens, scale, alibi_slopes=None, lo=None) -> Arrays3:
     """The incumbent composition, verbatim: scatter pass then paged
     gather-attention. Bit-equal to the pre-fusion hot path."""
     k_cache, v_cache = reshape_and_cache(k_new, v_new, k_cache, v_cache,
                                          slot_mapping)
     out = decode_attention_reference(q, k_cache, v_cache, block_tables,
-                                     context_lens, scale, alibi_slopes)
+                                     context_lens, scale, alibi_slopes,
+                                     lo=lo)
     return out, k_cache, v_cache
 
 
@@ -95,22 +118,30 @@ def paged_pool_attention(
     scale: float,
     alibi_slopes: Optional[jnp.ndarray] = None,
     mesh=None,
+    window: Optional[int] = None,
+    query_ahead=0,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Attention of one query token a row over what the pool holds of its
     sequence. Returns (out [B, 1, Hq, D], logsumexp [B, Hq]). `mesh`
-    (multi-chip runs) shards the kernel over kv heads."""
+    (multi-chip runs) shards the kernel over kv heads. `window`: the
+    query, which stands `query_ahead` positions past the pool's last
+    token (a staged decode's substep k: k + 1), attends to its last
+    `window` tokens; those of them the pool holds are this call's."""
+    lo = (None if window is None
+          else window_lo(context_lens + query_ahead, window))
     if use_kernel("paged", q.shape[-1] % 128 == 0):
-        def kernel(q, k_cache, v_cache, tables, ctx, slopes):
+        def kernel(q, k_cache, v_cache, tables, ctx, slopes, lo=None):
             return paged_attention(q, k_cache, v_cache, tables, ctx, scale,
-                                   slopes, return_lse=True)
+                                   slopes, return_lse=True, lo=lo)
 
         return shard_over_heads(
             kernel, mesh, k_cache.shape[1],
             in_head_dims=(2, 1, 1, None, None,
-                          None if alibi_slopes is None else 0),
+                          None if alibi_slopes is None else 0)
+            + (() if lo is None else (None, )),
             out_head_dims=(2, 1))(
                 q, k_cache, v_cache, block_tables, context_lens,
-                alibi_slopes)
+                alibi_slopes, *(() if lo is None else (lo, )))
     return decode_attention_reference(q, k_cache, v_cache, block_tables,
                                       context_lens, scale, alibi_slopes,
-                                      return_lse=True)
+                                      return_lse=True, lo=lo)

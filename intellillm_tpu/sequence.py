@@ -438,6 +438,9 @@ class SequenceGroupMetadata:
     # seq_id -> the sequence's slot in the per-sequence state pools
     # (models with "state" layers); None for every other model.
     state_slots: Optional[Dict[int, int]] = None
+    # seq_id -> the window group's block table (models with "kv_window"
+    # layers beside "kv" ones); None for every other model.
+    window_block_tables: Optional[Dict[int, List[int]]] = None
 
     @property
     def lora_int_id(self) -> int:

@@ -15,6 +15,8 @@ from intellillm_tpu.transformers_utils.configs.deepseek import DeepseekConfig
 from intellillm_tpu.transformers_utils.configs.internlm import InternLMConfig
 from intellillm_tpu.transformers_utils.configs.lfm2_moe import Lfm2MoeConfig
 from intellillm_tpu.transformers_utils.configs.qwen import QWenConfig
+from intellillm_tpu.transformers_utils.configs.smallthinker import (
+    SmallThinkerConfig)
 from intellillm_tpu.transformers_utils.configs.yi import YiConfig
 
 _CONFIG_REGISTRY = {
@@ -27,6 +29,7 @@ _CONFIG_REGISTRY = {
     "internlm": InternLMConfig,
     "lfm2_moe": Lfm2MoeConfig,
     "qwen": QWenConfig,
+    "smallthinker": SmallThinkerConfig,
     "Yi": YiConfig,
     "yi": YiConfig,
 }
@@ -34,6 +37,6 @@ _CONFIG_REGISTRY = {
 __all__ = [
     "AquilaConfig", "AXK1Config", "BaichuanConfig", "ChatGLMConfig", "DeciLMConfig",
     "DeepseekConfig", "InternLMConfig", "Lfm2MoeConfig", "QWenConfig",
-    "YiConfig",
+    "SmallThinkerConfig", "YiConfig",
     "_CONFIG_REGISTRY",
 ]

@@ -22,6 +22,11 @@ TPU redesign:
   has ONE array `[num_state_slots, taps * width]` (a slot's taps side by
   side, oldest first: whole lane tiles, no padded sublanes), a slot a
   running sequence, not paged. `device_cache[i]` is layer i's tuple either way.
+  A "kv_window" layer (a sliding window beside layers that keep the whole
+  context) has the same pools with the window group's own number of
+  blocks (`CacheConfig.num_window_device_blocks`), and the window group's
+  block ops (`swap_in` / `swap_out` / `copy` with `window=True`) touch
+  those layers alone.
   Block ops touch the "kv" layers, the state ops (`swap_state_out`,
   `swap_state_in`, `copy_state`) the "state" layers, by slot. Models
   whose layers are all "kv" have no state pool and run no state op.
@@ -67,6 +72,8 @@ class CacheEngine:
         self.num_layers = len(self.layer_kinds)
         self.kv_layers = [i for i, kind in enumerate(self.layer_kinds)
                           if kind == "kv"]
+        self.window_layers = [i for i, kind in enumerate(self.layer_kinds)
+                              if kind == "kv_window"]
         self.state_layers = [i for i, kind in enumerate(self.layer_kinds)
                              if kind == "state"]
         self.state_row_shape = model_config.get_state_row_shape()
@@ -80,12 +87,18 @@ class CacheEngine:
         self.num_pools, self.num_kv_heads, self.head_size = \
             model_config.get_cache_row_shape()
         # What the IKV1 wire format cannot carry, if anything.
-        self.cache_kind = ("latent" if model_config.get_latent_cache_width()
-                           else "state" if self.state_layers else "kv")
+        self.cache_kind = (
+            "latent" if model_config.get_latent_cache_width()
+            else "state" if self.state_layers
+            else "window" if (self.window_layers
+                              or model_config.get_sliding_window())
+            else "kv")
 
         self.block_size = cache_config.block_size
         self.num_device_blocks = cache_config.num_device_blocks
         self.num_cpu_blocks = cache_config.num_cpu_blocks
+        self.num_window_device_blocks = cache_config.num_window_device_blocks
+        self.num_window_cpu_blocks = cache_config.num_window_cpu_blocks
 
         if cache_config.cache_dtype == "auto":
             self.dtype = jnp.dtype(STR_DTYPE_TO_JNP[model_config.dtype])
@@ -103,9 +116,10 @@ class CacheEngine:
         # (lane-padded physical) bytes.
         self.device_block_bytes = self.get_cache_block_size(
             self.block_size, cache_config.cache_dtype, model_config,
-            parallel_config)
+            parallel_config, cache_config.window_ahead_tokens)
         self.logical_block_bytes = self.get_logical_cache_block_size(
-            self.block_size, cache_config.cache_dtype, model_config)
+            self.block_size, cache_config.cache_dtype, model_config,
+            cache_config.window_ahead_tokens)
         from intellillm_tpu.obs.device_telemetry import get_device_telemetry
         self._telemetry = get_device_telemetry()
         # KV integrity audit (obs/numerics.py): sampled blake2b
@@ -128,9 +142,11 @@ class CacheEngine:
         return (self.num_state_slots, taps * width)
 
     def _allocate_device_cache(self) -> List[KVCache]:
-        shape = self._block_shape(self.num_device_blocks)
         caches = []
         for kind in self.layer_kinds:
+            shape = self._block_shape(self.num_window_device_blocks
+                                      if kind == "kv_window"
+                                      else self.num_device_blocks)
             if kind == "state":
                 # The state keeps the activations' dtype whatever the KV
                 # pool's (`state_dtype`).
@@ -160,20 +176,25 @@ class CacheEngine:
         return np.dtype(getattr(ml_dtypes, dtype.name))
 
     def _allocate_cpu_cache(self):
-        shape = self._block_shape(self.num_cpu_blocks)
         np_dtype = self._host_dtype(self.dtype)
         return [(np.zeros(self._state_shape(),
                           dtype=self._host_dtype(self.state_dtype)), )
                 if kind == "state" else
-                tuple(np.zeros(shape, dtype=np_dtype)
+                tuple(np.zeros(self._block_shape(
+                    self.num_window_cpu_blocks if kind == "kv_window"
+                    else self.num_cpu_blocks), dtype=np_dtype)
                       for _ in range(self.num_pools))
                 for kind in self.layer_kinds]
 
     # --- block-op execution ---------------------------------------------
 
-    def swap_in(self, src_to_dst: Dict[int, int]) -> None:
+    def _group_layers(self, window: bool) -> List[int]:
+        return self.window_layers if window else self.kv_layers
+
+    def swap_in(self, src_to_dst: Dict[int, int],
+                window: bool = False) -> None:
         audit = self._kv_audit
-        for i in self.kv_layers:
+        for i in self._group_layers(window):
             if audit.enabled:
                 # Verify sampled host blocks BEFORE they re-enter the
                 # device pool: a bit that flipped while the block sat
@@ -191,9 +212,10 @@ class CacheEngine:
         self._telemetry.record_swap("in", len(src_to_dst),
                                     self.logical_block_bytes)
 
-    def swap_out(self, src_to_dst: Dict[int, int]) -> None:
+    def swap_out(self, src_to_dst: Dict[int, int],
+                 window: bool = False) -> None:
         audit = self._kv_audit
-        for i in self.kv_layers:
+        for i in self._group_layers(window):
             for dev, cpu in zip(self.device_cache[i], self.cpu_cache[i]):
                 swap_blocks(dev, cpu, src_to_dst, direction="out")
             if audit.enabled:
@@ -274,6 +296,9 @@ class CacheEngine:
         "state": "this model keeps a per-sequence convolution state "
                  "beside its K/V pools, which a prefix of blocks does not "
                  "hold (it would need a state snapshot at the prefix's end)",
+        "window": "this model's sliding-window cache group has given back "
+                  "the blocks of a prefix longer than its window (and may "
+                  "hold a table of its own beside the full layers')",
     }
 
     def _refuse_kv_transfer(self, what: str) -> None:
@@ -293,8 +318,9 @@ class CacheEngine:
         for i, pools in zip(layers, copied):
             self.device_cache[i] = pools
 
-    def copy(self, src_to_dsts: Dict[int, List[int]]) -> None:
-        self._copy_rows(self.kv_layers, src_to_dsts)
+    def copy(self, src_to_dsts: Dict[int, List[int]],
+             window: bool = False) -> None:
+        self._copy_rows(self._group_layers(window), src_to_dsts)
         self._telemetry.record_swap(
             "copy", sum(len(dsts) for dsts in src_to_dsts.values()),
             self.device_block_bytes)
@@ -319,18 +345,45 @@ class CacheEngine:
         self._copy_rows(self.state_layers, src_to_dsts)
 
     def execute_state_ops(self, ops: List[Tuple[str, int, int]]) -> None:
-        """The block manager's state moves, in the order it made them:
-        ("swap_out" | "swap_in" | "fork", source slot, destination
-        slot)."""
+        """The block manager's moves beside the primary group's plans, in
+        the order it made them: ("swap_out" | "swap_in" | "fork", source
+        slot, destination slot) of state slots, ("w_swap_out" |
+        "w_swap_in" | "w_copy", source block, destination block) of the
+        window group's blocks. Consecutive moves of one kind run as one
+        call (a sequence's whole window table is one swap a layer, not
+        one a block); a move that reads what an earlier one of the run
+        wrote starts a new call."""
+        kind, run, written = None, {}, set()
         for op, src, dst in ops:
-            if op == "swap_out":
-                self.swap_state_out({src: dst})
-            elif op == "swap_in":
-                self.swap_state_in({src: dst})
-            elif op == "fork":
-                self.copy_state({src: [dst]})
+            if run and (op != kind or src in written):
+                self._execute_state_run(kind, run)
+                run, written = {}, set()
+            kind = op
+            run.setdefault(src, []).append(dst)
+            written.add(dst)
+        if run:
+            self._execute_state_run(kind, run)
+
+    def _execute_state_run(self, op: str,
+                           src_to_dsts: Dict[int, List[int]]) -> None:
+        if op in ("w_copy", "fork"):
+            if op == "fork":
+                self.copy_state(src_to_dsts)
             else:
-                raise ValueError(f"unknown state op {op!r}")
+                self.copy(src_to_dsts, window=True)
+            return
+        # A swap moves a source to one place.
+        src_to_dst = {src: dst for src, (dst, ) in src_to_dsts.items()}
+        if op == "w_swap_out":
+            self.swap_out(src_to_dst, window=True)
+        elif op == "w_swap_in":
+            self.swap_in(src_to_dst, window=True)
+        elif op == "swap_out":
+            self.swap_state_out(src_to_dst)
+        elif op == "swap_in":
+            self.swap_state_in(src_to_dst)
+        else:
+            raise ValueError(f"unknown state op {op!r}")
 
     # --- sizing ----------------------------------------------------------
 
@@ -340,9 +393,13 @@ class CacheEngine:
         cache_dtype: str,
         model_config: ModelConfig,
         parallel_config: ParallelConfig,
+        window_ahead: int = 0,
     ) -> int:
-        """PHYSICAL bytes per block across all layers (K + V, or the one
-        latent pool), whole model.
+        """PHYSICAL bytes per block of the primary group across all layers
+        (K + V, or the one latent pool), whole model. Where the model has
+        a window group too, a primary block brings
+        `get_window_group_share(window_ahead)` of a block in each of the
+        window layers, counted here: the two groups are sized as one.
 
         TPU HBM arrays are tiled: the pool layout [NB, H, BS, D] pads the
         minor dim to the 128-lane width. For D=128 models physical ==
@@ -354,9 +411,20 @@ class CacheEngine:
         bytes — sizing the pool by logical bytes made the memory profile
         allocate past HBM and OOM at engine init.
         """
+        layer_block = CacheEngine.get_layer_block_size(
+            block_size, cache_dtype, model_config)
+        window_blocks = (model_config.get_num_window_layers() *
+                         model_config.get_window_group_share(window_ahead))
+        return (model_config.get_num_kv_layers() * layer_block
+                + int(window_blocks * layer_block))
+
+    @staticmethod
+    def get_layer_block_size(block_size: int, cache_dtype: str,
+                             model_config: ModelConfig) -> int:
+        """PHYSICAL bytes of one block in ONE layer's pools (either
+        group: the rows are alike)."""
         num_pools, num_kv_heads, head_size = \
             model_config.get_cache_row_shape()
-        num_layers = model_config.get_num_kv_layers()
         if cache_dtype == "auto":
             cache_dtype = model_config.dtype
         itemsize = jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]).itemsize
@@ -369,27 +437,31 @@ class CacheEngine:
             # OOMs at init (e.g. head_size 256 with block_size 8).
             sublane = {4: 8, 2: 16, 1: 32}.get(itemsize, 8)
             eff_block_size = -(-block_size // sublane) * sublane
-        return (num_pools * num_layers * num_kv_heads * eff_block_size *
-                lanes * itemsize)
+        return num_pools * num_kv_heads * eff_block_size * lanes * itemsize
 
     @staticmethod
     def get_logical_cache_block_size(
         block_size: int,
         cache_dtype: str,
         model_config: ModelConfig,
+        window_ahead: int = 0,
     ) -> int:
         """Unpadded bytes per block across all layers (K + V, or the
         latent pool at its stored width: the host pool holds the same
         zero-padded rows) — sizes the host (numpy) swap pool, which has no
-        TPU tiling."""
+        TPU tiling. The window group's share is counted as in
+        `get_cache_block_size`."""
         num_pools, num_kv_heads, head_size = \
             model_config.get_cache_row_shape()
         num_layers = model_config.get_num_kv_layers()
         if cache_dtype == "auto":
             cache_dtype = model_config.dtype
         itemsize = jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]).itemsize
-        return (num_pools * num_layers * num_kv_heads * block_size *
-                head_size * itemsize)
+        layer_block = (num_pools * num_kv_heads * block_size * head_size *
+                       itemsize)
+        window_blocks = (model_config.get_num_window_layers() *
+                         model_config.get_window_group_share(window_ahead))
+        return num_layers * layer_block + int(window_blocks * layer_block)
 
     @staticmethod
     def get_state_slot_size(model_config: ModelConfig) -> int:

@@ -95,6 +95,9 @@ class DecodeContState:
         self.state_slots = None         # per row (`rows` order): the
         #                                 sequence's state slot, for a
         #                                 model with state layers
+        self.window_tables = None       # per row (`rows` order): the
+        #                                 window group's block table, for
+        #                                 a model with two cache groups
 
 
 # What an empty slot of a continuation samples with: greedy, so that an
@@ -243,6 +246,18 @@ class ModelRunner:
         self.layer_kinds = model_config.get_layer_cache_kinds()
         self.stateful = "state" in self.layer_kinds
         self._kv0 = self.layer_kinds.index("kv")
+        # A model with "kv_window" layers beside "kv" ones hands every
+        # step program the window group's block tables too
+        # (`window_tables`); the programs of every other model take no
+        # such argument. `_window` is the window of whichever layers have
+        # one (every layer's, or the window group's).
+        self.window_group = model_config.get_window_group_size()
+        self._window = self.sliding_window or self.window_group
+        self._layers_by_reach = (
+            (self.layer_kinds.count("kv"),
+             self.layer_kinds.count("kv_window")) if self.window_group
+            else (0, 1) if self.sliding_window
+            else (1, 0))
         self.num_state_slots = cache_config.num_state_slots
         from intellillm_tpu.layers.attention import model_uses_alibi
         self._uses_alibi = model_uses_alibi(model)
@@ -260,6 +275,12 @@ class ModelRunner:
             logger.warning("INTELLILLM_DECODE_CHUNK=%r is not an integer; "
                            "using the default (16)", raw_chunk)
             self.decode_chunk = 16
+        if self._window and 0 < self._window < max(self.decode_chunk, 1):
+            raise ValueError(
+                f"a sliding window of {self._window} tokens is narrower "
+                f"than the K-step decode's staging chunk "
+                f"({self.decode_chunk}): the staged tokens would not all "
+                f"be inside it")
 
         # ONE bucket family: decode rows + prefill-chunk rows flatten into
         # a single (token_budget,)-bucketed batch, and block-table widths
@@ -335,7 +356,28 @@ class ModelRunner:
             sds(f32, b), None, None, logprob_k=logprob_k, do_topk=False,
             do_topp=False, do_minp=False, do_penalties=False,
             do_random=do_random,
-            **({"state_slots": sds(i32, b)} if self.stateful else {}))
+            **({"state_slots": sds(i32, b)} if self.stateful else {}),
+            **({"window_tables": sds(i32, b, w)} if self.window_group
+               else {}))
+
+    def window_table_kwargs(self, tables, rows, padded_n: int,
+                            w: int) -> Dict[str, Any]:
+        """The `window_tables` argument of a step program: the window
+        group's block table of each live row (`tables`, at batch rows
+        `rows`), padded like the primary tables. Empty for a model
+        without a window group, whose programs are then called exactly
+        as before."""
+        if not self.window_group:
+            return {}
+        arr = np.zeros((padded_n, w), np.int32)
+        # A chunk's rows all carry their sequence's one table (the same
+        # list object): write it once for all of them.
+        rows_of: Dict[int, Tuple[Any, List[int]]] = {}
+        for row, t in zip(rows, tables):
+            rows_of.setdefault(id(t), (t, []))[1].append(row)
+        for t, its_rows in rows_of.values():
+            arr[its_rows, :len(t)] = np.asarray(t, np.int32)
+        return {"window_tables": self._place_batch_array(arr)}
 
     def state_slot_kwargs(self, row_slots, padded_n: int) -> Dict[str, Any]:
         """The `state_slots` argument of a step program: `row_slots` (one
@@ -547,7 +589,8 @@ class ModelRunner:
                         join_mask, positions, block_tables, context_lens,
                         temperatures, top_ks, top_ps, min_ps, seeds,
                         pres_pen, freq_pen, rep_pen, prompt_tokens,
-                        output_tokens, lora=None, state_slots=None, *,
+                        output_tokens, lora=None, state_slots=None,
+                        window_tables=None, *,
                         prev_t1, num_steps, logprob_k, do_topk, do_topp,
                         do_minp, do_penalties, do_random=True):
         """Continuation of a fused decode: a row's input token is the last
@@ -566,7 +609,7 @@ class ModelRunner:
             lora, num_steps=num_steps, logprob_k=logprob_k,
             do_topk=do_topk, do_topp=do_topp, do_minp=do_minp,
             do_penalties=do_penalties, do_random=do_random,
-            state_slots=state_slots)
+            state_slots=state_slots, window_tables=window_tables)
 
     def _decode_teacher_fn(self, params, kv_caches, teacher_tokens,
                            positions, block_tables, context_lens,
@@ -597,7 +640,8 @@ class ModelRunner:
                    min_ps, seeds, pres_pen, freq_pen, rep_pen, prompt_tokens,
                    output_tokens, lora=None, *, num_steps, logprob_k,
                    do_topk, do_topp, do_minp, do_penalties,
-                   do_random=True, teacher_tokens=None, state_slots=None):
+                   do_random=True, teacher_tokens=None, state_slots=None,
+                   window_tables=None):
         """K fused decode iterations (staged, chunked).
 
         The paged pool stays loop-invariant (read-only) through each scan —
@@ -615,9 +659,13 @@ class ModelRunner:
         them in place — no double buffering), advance the pool context,
         repeat. Stage traffic drops K/C-fold; the extra scatters write the
         same total bytes as the single post-scan scatter did.
+
+        A layer with a sliding window runs the same way: its pool part
+        sees what the window leaves of the pool (the lower edge a token
+        further every substep, `layers/attention.py`), its stage is
+        committed through its own group's table (`window_tables`, where
+        the model has two groups).
         """
-        assert self.sliding_window is None, (
-            "sliding-window models use the unstaged single-step decode")
         b = token_ids.shape[0]
         base_pos = positions[:, 0]              # [B] = n-1
         base_ctx = context_lens                 # [B] = n (0 for pad rows)
@@ -658,6 +706,7 @@ class ModelRunner:
                     stage_index=k,
                     mesh=self.mesh,
                     state_slots=state_slots,
+                    window_block_tables=window_tables,
                 )
                 # A layer's cache is (K, V) or the 1-tuple (latent pool,);
                 # staged, the layer gets its pools then their stages.
@@ -718,8 +767,10 @@ class ModelRunner:
             state_stages = {i: stages[i][0] for i in state_stages}
             cur_caches = [
                 pools if kinds[i] == "state" else
-                commit_staged_chunk(stage, pools, start, n_valid,
-                                    block_tables)
+                commit_staged_chunk(
+                    stage, pools, start, n_valid,
+                    window_tables if kinds[i] == "kv_window"
+                    else block_tables)
                 for i, (pools, stage) in enumerate(zip(cur_caches, stages))]
             chunk_base += csize
 
@@ -749,39 +800,47 @@ class ModelRunner:
                           top_ps, min_ps, seeds, pres_pen, freq_pen, rep_pen,
                           prompt_tokens, output_tokens, lora=None,
                           fetch_indices=None, plp_targets=None,
-                          numerics_inject=None, state_slots=None, *,
+                          numerics_inject=None, state_slots=None,
+                          window_tables=None, *,
                           num_samples=1, plp_k=0, do_numerics=False,
                           logprob_k, do_topk, do_topp, do_minp,
                           do_penalties, do_random=True):
         """Unstaged single-step program — THE mixed dispatch: writes KV to
         the pool before attention, so decode rows and prefill-chunk rows
-        run side by side in one flat batch. Also exact for sliding-window
-        models (ring layout) and used whenever K == 1.
+        run side by side in one flat batch. Also used whenever K == 1.
+        `window_tables`: the window group's tables, for a model with two
+        cache groups (a row's token goes to the same logical block and
+        offset in either).
 
         num_samples > 1 serves final-chunk `best_of` fan-out (every row
         draws num_samples gumbel streams; a row's sample 0 is bit-equal
         to its num_samples=1 draw, so co-batched decode rows are
         unaffected). plp_k > 0 adds the per-row prompt-logprob panel."""
         bs = self.block_size
-        wb = (self.sliding_window // bs) if self.sliding_window else None
         b = token_ids.shape[0]
         pos = positions[:, 0]
-        ctx = context_lens
-        nb = kv_caches[self._kv0][0].shape[0]
 
-        li = pos // bs
-        if wb is not None:
-            li = li % wb
-            ctx = jnp.minimum(ctx, self.sliding_window)
-        slot = (jnp.take_along_axis(block_tables, li[:, None],
-                                    axis=1)[:, 0] * bs + pos % bs)
-        slot = jnp.where(context_lens > 0, slot, nb * bs)
+        def slots_of(tables, pool):
+            # A pad row's slot is one past the pool.
+            slot = (jnp.take_along_axis(tables, (pos // bs)[:, None],
+                                        axis=1)[:, 0] * bs + pos % bs)
+            return jnp.where(context_lens > 0, slot,
+                             pool.shape[0] * bs)[:, None]
+
+        window_kwargs = {}
+        if window_tables is not None:
+            window_kwargs = dict(
+                window_block_tables=window_tables,
+                window_slot_mapping=slots_of(
+                    window_tables,
+                    kv_caches[self.layer_kinds.index("kv_window")][0]))
         meta = AttentionMetadata(
-            slot_mapping=slot[:, None],
-            context_lens=ctx,
+            slot_mapping=slots_of(block_tables, kv_caches[self._kv0][0]),
+            context_lens=context_lens,
             block_tables=block_tables,
             mesh=self.mesh,
             state_slots=state_slots,
+            **window_kwargs,
         )
         hidden, new_caches, held = self._call_model(
             params, token_ids, pos[:, None], kv_caches, meta, lora)
@@ -810,29 +869,61 @@ class ModelRunner:
 
     # --- batch prep -------------------------------------------------------
 
-    def _walked_tokens(self, ctxs, w: int, held_back: int = 0) -> int:
-        """Tokens the attention kernel's page walk moves for rows of
-        contexts `ctxs` (a sliding window's worth of them) under `w`-wide
-        tables, the last `held_back` tokens of each left out of it."""
-        ctx = np.asarray(ctxs, np.int64)
-        if self.sliding_window:
-            ctx = np.minimum(ctx, self.sliding_window)
-        pages, _ = self._walk_extent(ctx - held_back, self.block_size, w)
-        return int(pages.sum()) * self.block_size
+    def _walked_tokens(self, seen, query_ends, w: int) -> int:
+        """Tokens the attention kernel's page walk moves for rows that
+        must see `seen` tokens of their contexts under `w`-wide tables,
+        a layer: the mean over the model's paged layers, of which those
+        with a sliding window walk from the page of the first position a
+        query that ends at `query_ends` (its position + 1) sees."""
+        seen = np.asarray(seen, np.int64)
+        full_layers, window_layers = self._layers_by_reach
+        walked = 0
+        if full_layers:
+            pages, _ = self._walk_extent(seen, self.block_size, w)
+            walked += full_layers * int(pages.sum())
+        if window_layers:
+            lo = np.clip(np.asarray(query_ends, np.int64) - self._window,
+                         0, None)
+            pages, _ = self._walk_extent(seen, self.block_size, w, lo=lo)
+            walked += window_layers * int(pages.sum())
+        return walked * self.block_size // (full_layers + window_layers)
 
-    def _decode_walked_tokens(self, ctx: np.ndarray, num_steps: int,
-                              w: int) -> int:
-        """The same over a K-step decode program (`_decode_fn`): every
-        substep of a staging chunk walks the pool as the chunk found it,
-        the context less the row's newest token plus the chunks
-        committed before."""
+    def _attended_tokens(self, query_ends, context_tokens: int
+                         ) -> Optional[Dict[str, int]]:
+        """What queries ending at `query_ends` attend to in one layer of
+        each reach (`intellillm_dispatch_attended_tokens_total`); None
+        for a model without a window, which counts context tokens only."""
+        if not self._window:
+            return None
+        full_layers, _ = self._layers_by_reach
+        ends = np.asarray(query_ends, np.int64)
+        return {"full": context_tokens if full_layers else 0,
+                "window": int(np.minimum(ends, self._window).sum())}
+
+    def _decode_counts(self, ctx: np.ndarray, num_steps: int, w: int):
+        """(walked tokens, attended tokens) of a K-step decode program
+        (`_decode_fn`): every substep of a staging chunk walks the pool
+        as the chunk found it, the context less the row's newest token
+        plus the chunks committed before; substep k's query ends at the
+        context + k."""
         chunk = self.decode_chunk if self.decode_chunk > 0 else num_steps
-        return sum(
-            min(chunk, num_steps - base) * self._walked_tokens(
-                np.where(ctx > 0,
-                         np.minimum(ctx - 1 + base, self.max_model_len), 0),
-                w)
-            for base in range(0, num_steps, chunk))
+        live = ctx > 0
+        walked = 0
+        for base in range(0, num_steps, chunk):
+            pool = np.where(live, np.minimum(ctx - 1 + base,
+                                             self.max_model_len), 0)
+            substeps = min(chunk, num_steps - base)
+            if not self._window:
+                walked += substeps * self._walked_tokens(pool, None, w)
+                continue
+            k = np.arange(substeps)[:, None]
+            walked += self._walked_tokens(
+                np.broadcast_to(pool, (substeps, ) + pool.shape),
+                pool[None, :] + k + 1, w)
+        if not self._window:
+            return walked, None
+        ends = (ctx[live][None, :] + np.arange(num_steps)[:, None])
+        return walked, self._attended_tokens(ends, int(ends.sum()))
 
     def decode_batch_bucket(self, rows: int) -> int:
         """The padded batch a fresh decode step of `rows` rows runs at."""
@@ -847,6 +938,7 @@ class ModelRunner:
         poss: List[int] = []
         ctxs: List[int] = []
         tables: List[List[int]] = []
+        window_tables: List[List[int]] = []
         row_slots: List[int] = []
 
         for meta in seq_group_metadata_list:
@@ -858,6 +950,8 @@ class ModelRunner:
                 poss.append(n - 1)
                 ctxs.append(n)
                 tables.append(list(meta.block_tables[seq_id]))
+                if self.window_group:
+                    window_tables.append(meta.window_block_tables[seq_id])
                 if self.stateful:
                     row_slots.append(meta.state_slots[seq_id])
 
@@ -871,7 +965,11 @@ class ModelRunner:
 
         arrays = {"token_ids": token_ids, "positions": positions,
                   "context_lens": context_lens, "block_tables": block_tables}
-        arrays["_state"] = self.state_slot_kwargs(row_slots, b)
+        arrays["_state"] = {
+            **self.state_slot_kwargs(row_slots, b),
+            **self.window_table_kwargs(window_tables, range(len(rows)), b,
+                                       w)}
+        arrays["_window_tables"] = window_tables
         arrays["_eff"] = {
             "real_rows": len(rows),
             "ctxs": np.asarray(ctxs, np.int64),
@@ -980,6 +1078,7 @@ class ModelRunner:
 
             eff_info = arrays.pop("_eff")
             state_kwargs = arrays.pop("_state")
+            row_window_tables = arrays.pop("_window_tables")
             padded_n = arrays["token_ids"].shape[0]
 
             # Per-row sampling params / seeds / token histories.
@@ -1025,14 +1124,12 @@ class ModelRunner:
             sampling_args = self._sampling_args_device(st, padded_n)
 
         num_steps = num_decode_steps
-        # The engine clamps num_decode_steps to 1 at init for sliding
-        # window (window semantics need the ring layout) and ALiBi
-        # (bias needs the true query position per substep); the staged
-        # decode program would be silently wrong for both.
-        assert num_steps == 1 or (self.sliding_window is None
-                                  and not self._uses_alibi), (
-            "fused multi-step decode requested for a sliding-window or "
-            "ALiBi model; the engine should have clamped K to 1")
+        # The engine clamps num_decode_steps to 1 at init for ALiBi (the
+        # bias needs the true query position per substep): the staged
+        # decode program would be silently wrong for it.
+        assert num_steps == 1 or not self._uses_alibi, (
+            "fused multi-step decode requested for an ALiBi model; the "
+            "engine should have clamped K to 1")
         decode_args = (
             self.params, kv_caches,
             place(arrays["token_ids"]), place(arrays["positions"]),
@@ -1097,6 +1194,17 @@ class ModelRunner:
         # Each substep computes one token per row, pad rows included; a
         # row's substep j walks a context one token longer than j - 1's.
         real_rows = eff_info["real_rows"]
+        if num_steps == 1:
+            # The single-step program walks a row's context less its own
+            # token, as every `mixed` step does.
+            walked = self._walked_tokens(eff_info["ctxs"] - 1,
+                                         eff_info["ctxs"],
+                                         eff_info["width_padded"])
+            attended = self._attended_tokens(eff_info["ctxs"],
+                                             int(eff_info["ctxs"].sum()))
+        else:
+            walked, attended = self._decode_counts(
+                eff_info["ctxs"], num_steps, eff_info["width_padded"])
         self._efficiency.record_dispatch(
             "decode", real_rows, padded_n,
             real_tokens=real_rows * num_steps,
@@ -1107,8 +1215,7 @@ class ModelRunner:
             row_steps=real_rows * num_steps,
             context_tokens=(int(eff_info["ctxs"].sum()) * num_steps
                             + real_rows * num_steps * (num_steps - 1) // 2),
-            walked_tokens=self._decode_walked_tokens(
-                eff_info["ctxs"], num_steps, eff_info["width_padded"]))
+            walked_tokens=walked, attended_tokens=attended)
         self._record_moe_ffn_rows(padded_n, num_steps)
 
         # ONE device→host transfer for everything, performed by
@@ -1134,6 +1241,8 @@ class ModelRunner:
                 step.cont_state.state_slots = [
                     meta_by_req[req_id].state_slots[seq_id]
                     for req_id, seq_id in rows]
+            if self.window_group:
+                step.cont_state.window_tables = row_window_tables
         if defer_fetch:
             return step, new_caches
         return step.finalize(), new_caches
@@ -1179,6 +1288,7 @@ class ModelRunner:
             row_tokens: List[Tuple[np.ndarray, np.ndarray]] = []
             row_loras_src: List[Any] = []
             row_slots: List[int] = []
+            window_tables: List[List[int]] = []
             # Flat-row emission subset: all decode rows; only the LAST
             # row of a FINAL chunk (mid-prompt rows' samples are
             # meaningless).
@@ -1205,6 +1315,9 @@ class ModelRunner:
                     final = start + size == data.get_len()
                     all_ids = data.get_token_ids()
                     table = list(meta.block_tables[seq_id])
+                    if self.window_group:
+                        window_tables += (
+                            [meta.window_block_tables[seq_id]] * size)
                     # Same (seed, penalty-window) a whole-prompt prefill
                     # of this prompt would use, so the final chunk's
                     # sample reproduces legacy output exactly.
@@ -1249,6 +1362,9 @@ class ModelRunner:
                         poss.append(n - 1)
                         ctxs.append(n)
                         tables.append(list(meta.block_tables[seq_id]))
+                        if self.window_group:
+                            window_tables.append(
+                                meta.window_block_tables[seq_id])
                         row_params.append(sp)
                         row_seeds.append(
                             self._row_seed(seq_id, data.get_output_len(),
@@ -1302,7 +1418,10 @@ class ModelRunner:
                 do_penalties=st.do_penalties, do_random=st.do_random,
             )
             sampling_args = self._sampling_args_device(st, padded_n)
-            state_kwargs = self.state_slot_kwargs(row_slots, padded_n)
+            state_kwargs = {
+                **self.state_slot_kwargs(row_slots, padded_n),
+                **self.window_table_kwargs(window_tables, range(len(rows)),
+                                           padded_n, w)}
 
         # Numerics sentinels: when OFF the dispatch binds exactly as the
         # pre-sentinel code did (no extra kwargs → identical jit cache
@@ -1350,10 +1469,13 @@ class ModelRunner:
         # whichever side reports first.
         pad_rows = padded_n - len(rows)
         # The walk sees a row's context less the row's own token.
+        ctx_arr = np.fromiter(ctxs, np.int64, len(ctxs))
         dispatch = dict(program="mixed", device_idle=device_idle,
                         row_steps=len(rows), context_tokens=sum(ctxs),
-                        walked_tokens=self._walked_tokens(ctxs, w,
-                                                          held_back=1))
+                        walked_tokens=self._walked_tokens(ctx_arr - 1,
+                                                          ctx_arr, w),
+                        attended_tokens=self._attended_tokens(
+                            ctx_arr, int(ctx_arr.sum())))
         if n_chunk_groups:
             self._efficiency.record_dispatch(
                 "prefill", n_chunk_groups, n_chunk_groups,
@@ -1444,6 +1566,8 @@ class ModelRunner:
                 slots_arr = np.full(b, self.num_state_slots, np.int32)
                 slots_arr[cont.slots] = cont.state_slots
                 state_kwargs = {"state_slots": place(slots_arr)}
+            state_kwargs.update(self.window_table_kwargs(
+                cont.window_tables or (), cont.slots, b, w))
         flags = dict(logprob_k=st.logprob_k, do_topk=st.do_topk,
                      do_topp=st.do_topp, do_minp=st.do_minp,
                      do_penalties=False, do_random=st.do_random)
@@ -1463,6 +1587,8 @@ class ModelRunner:
         self._last_packed = packed
 
         live_rows = len(cont.rows)
+        walked, attended = self._decode_counts(ctx.astype(np.int64),
+                                               num_steps, w)
         self._efficiency.record_dispatch(
             "decode", live_rows, b,
             real_tokens=live_rows * num_steps,
@@ -1473,7 +1599,7 @@ class ModelRunner:
             row_steps=live_rows * num_steps,
             context_tokens=(int(ctx.sum()) * num_steps
                             + live_rows * num_steps * (num_steps - 1) // 2),
-            walked_tokens=self._decode_walked_tokens(ctx, num_steps, w))
+            walked_tokens=walked, attended_tokens=attended)
         self._record_moe_ffn_rows(b, num_steps)
 
         step = InflightStep(self, packed, cont.metas, cont.rows, num_steps,
@@ -1499,7 +1625,9 @@ class ModelRunner:
         with self._tracer.span("prepare_inputs"):
             arrays, rows = self._prepare_decode(seq_group_metadata_list)
         eff_info = arrays.pop("_eff")
-        arrays.pop("_state")    # speculative decoding refuses state models
+        # Speculative decoding refuses state and window models.
+        arrays.pop("_state")
+        arrays.pop("_window_tables")
         padded_n = arrays["token_ids"].shape[0]
         teacher = np.zeros((padded_n, num_steps), np.int32)
         for i, toks in enumerate(teacher_rows):

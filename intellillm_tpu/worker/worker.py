@@ -59,9 +59,9 @@ class Worker:
         self.params = None
         self.model_runner: Optional[ModelRunner] = None
         self.cache_engine: Optional[CacheEngine] = None
-        # Per-sequence state moves waiting for the next device step: the
-        # engine binds this to the block manager's own list
-        # (core/block_manager.py `state_ops`).
+        # Per-sequence state moves, and the window group's block moves,
+        # waiting for the next device step: the engine binds this to the
+        # block manager's own list (core/block_manager.py `state_ops`).
         self.state_ops: List[Tuple[str, int, int]] = []
         # Whether warm-up should compile the pipelined-continuation
         # program (SpecDecodeWorker disables it: spec mode never
@@ -111,12 +111,16 @@ class Worker:
         the compiled executable) instead of running a dummy forward and
         sampling the CUDA allocator.
         """
+        # A block of the primary group, with the window group's share of
+        # one where the model has two groups (both are sized as one).
+        ahead = self.cache_config.window_ahead_tokens
         block_bytes = CacheEngine.get_cache_block_size(
-            block_size, cache_dtype, self.model_config, self.parallel_config)
+            block_size, cache_dtype, self.model_config, self.parallel_config,
+            ahead)
         # The host swap pool is plain numpy (unpadded): size it by logical
         # bytes, not the lane-padded device bytes.
         logical_block_bytes = CacheEngine.get_logical_cache_block_size(
-            block_size, cache_dtype, self.model_config)
+            block_size, cache_dtype, self.model_config, ahead)
         num_cpu_blocks = int(cpu_swap_space // logical_block_bytes)
 
         # Everything is accounted per chip: params and the KV pool are
@@ -148,7 +152,8 @@ class Worker:
         from intellillm_tpu.utils import STR_DTYPE_TO_JNP as _M
         stage_dtype = (self.model_config.dtype
                        if cache_dtype == "auto" else cache_dtype)
-        stage_bytes = (pools * self.model_config.get_num_kv_layers() *
+        stage_bytes = (pools * (self.model_config.get_num_kv_layers()
+                                + self.model_config.get_num_window_layers()) *
                        self.scheduler_config.max_num_seqs * k_steps *
                        nkv * row_width *
                        _jnp.dtype(_M[stage_dtype]).itemsize)
@@ -231,7 +236,7 @@ class Worker:
         if self.cache_engine is not None and cc.num_device_blocks:
             block_bytes = CacheEngine.get_cache_block_size(
                 cc.block_size, cc.cache_dtype, self.model_config,
-                self.parallel_config)
+                self.parallel_config, getattr(cc, "window_ahead_tokens", 0))
             # Same per-chip division as the memory profile: the pool
             # shards by kv-head over "model" only when divisible.
             tp = self.parallel_config.tensor_parallel_size
@@ -240,7 +245,8 @@ class Worker:
                 block_bytes //= tp
             ledger["kv_pool"] = block_bytes * cc.num_device_blocks
             logical = CacheEngine.get_logical_cache_block_size(
-                cc.block_size, cc.cache_dtype, self.model_config)
+                cc.block_size, cc.cache_dtype, self.model_config,
+                getattr(cc, "window_ahead_tokens", 0))
             ledger["cpu_swap_pool"] = logical * (cc.num_cpu_blocks or 0)
             state = (CacheEngine.get_state_slot_size(self.model_config)
                      * getattr(cc, "num_state_slots", 0))
@@ -363,8 +369,11 @@ class Worker:
                     do_numerics=True,
                     numerics_inject=place(np.zeros(b, np.float32)))
                     if num_on else {})
-                # A model with state layers: every row a pad row's slot.
-                state_kwargs = runner.state_slot_kwargs([], b)
+                # A model with state layers: every row a pad row's slot;
+                # one with a window group: its tables, all zeros too.
+                state_kwargs = {
+                    **runner.state_slot_kwargs([], b),
+                    **runner.window_table_kwargs((), (), b, w)}
                 for flags in flag_variants:
                     result = runner._jit_decode_single(
                         self.params, self.cache_engine.device_cache,

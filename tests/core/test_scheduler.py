@@ -258,12 +258,16 @@ def test_preemption_victim_prices_with_p90_when_available():
     ("seats", "max_seqs"),
     ("pool", "kv_watermark"),
     ("long_prompt", "needs_chunking"),
+    ("long_prompt_beside_decode", None),
     ("admits", None),
 ])
 def test_prefill_only_pass_says_why_it_stopped(case, expected):
     """A prefill-only pass (the pipelined loop's admission) reports why it
     stopped admitting in `SchedulerOutputs.blocked`: the loop drains for a
-    full pool or a prompt that needs chunking, not for full seats."""
+    full pool or a prompt that needs chunking, not for full seats; asked
+    to chunk (the engine does while a decode step is in flight), the pass
+    admits a prompt longer than the budget with its first chunk and goes
+    on chunk by chunk."""
     cache_config = CacheConfig(block_size=4, swap_space_gib=0.001)
     cache_config.num_device_blocks = 7 if case == "pool" else 64
     cache_config.num_cpu_blocks = 8
@@ -275,7 +279,17 @@ def test_prefill_only_pass_says_why_it_stopped(case, expected):
     metas, out = s.schedule(prefill_only=True)
     assert [m.request_id for m in metas] == ["0"] and out.blocked is None
     append_token(first)
-    add_request(s, "1", {"pool": 16, "long_prompt": 24}.get(case, 6))
-    metas, out = s.schedule(prefill_only=True)
+    beside = case == "long_prompt_beside_decode"
+    add_request(s, "1", 24 if case.startswith("long_prompt") else
+                {"pool": 16}.get(case, 6))
+    metas, out = s.schedule(prefill_only=True, chunk_long_prompts=beside)
     assert out.blocked == expected
     assert bool(metas) == (expected is None)
+    if beside:
+        assert out.prompt_run and out.chunked_prefills == {"1": (0, 16, False)}
+        assert s.mid_prefill()
+        metas, out = s.schedule(prefill_only=True, chunk_long_prompts=True)
+        # the rest of it, alone: no decode row rides a prefill-only pass
+        assert [m.request_id for m in metas] == ["1"]
+        assert out.chunked_prefills == {"1": (16, 8, True)}
+        assert not s.mid_prefill()

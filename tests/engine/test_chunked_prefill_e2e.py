@@ -48,8 +48,8 @@ def test_chunked_on_matches_legacy_greedy(tiny_opt_dir):
     mixed_steps = {"n": 0, "split": 0}
     orig = sched_mod.Scheduler._chunked_pass
 
-    def spy(self, now):
-        out = orig(self, now)
+    def spy(self, now, **kw):
+        out = orig(self, now, **kw)
         mixed_steps["n"] += 1
         if any(start > 0 for start, _, _ in out.chunked_prefills.values()):
             mixed_steps["split"] += 1

@@ -458,11 +458,12 @@ def test_full_seats_do_not_drain(tiny_llama_dir, example_prompts):
     assert _dispatches("decode_fused") == 1
 
 
-def test_prompt_over_the_budget_drains_as_chunked_prefill(tiny_llama_dir,
-                                                          example_prompts):
-    """A prompt longer than one prefill-only step may carry needs the
-    chunked pass, on an empty pipeline: the scheduler says so
-    (`SchedulerOutputs.blocked`) and the drain is counted by that."""
+def test_prompt_over_the_budget_is_chunked_beside_the_decode_batch(
+        tiny_llama_dir, example_prompts):
+    """A prompt longer than one prefill-only step may carry runs its
+    chunks as prompt steps of their own, chained behind the decode steps
+    in flight: no drain, and its sequence joins the running batch by
+    continuation once its final chunk is fetched."""
     kw = dict(max_num_batched_tokens=32, max_model_len=128)
     long_prompt = [2, 3, 4, 5, 6] * 16                       # 80 tokens
     sp = _params("greedy", 0, 40)
@@ -486,8 +487,41 @@ def test_prompt_over_the_budget_drains_as_chunked_prefill(tiny_llama_dir,
     ref = run(False)
     get_efficiency_tracker().reset_for_testing()
     assert run(True) == ref
-    drains = get_efficiency_tracker().snapshot()["pipeline_drains"]
-    assert drains["chunked_prefill"] >= 1 and drains["memory"] == 0
+    snap = get_efficiency_tracker().snapshot()
+    assert snap["pipeline_drains"] == {
+        "memory": 0, "params": 0, "chunked_prefill": 0, "bucket": 0,
+        "idle": 1}
+    assert snap["decode_join_rows"] == {"cont": 1, "fresh": 1}
+
+
+def test_prompt_over_the_budget_on_an_idle_pipeline_is_co_batched(
+        tiny_llama_dir, example_prompts):
+    """With no decode step in flight (requests that arrive together at an
+    idle engine) a prompt over the budget is not chunked by prefill-only
+    passes: the fresh schedule's chunked pass puts its chunks in steps
+    with the other sequences' decode rows, which then move a token a step
+    and do not stand still while the chunks run alone."""
+    kw = dict(max_num_batched_tokens=32, max_model_len=128)
+    long_prompt = [2, 3, 4, 5, 6] * 16                       # 80 tokens
+    sp = _params("greedy", 0, 24)
+
+    def run(pipelined):
+        engine = _build(tiny_llama_dir, **kw).llm_engine
+        engine.add_request("0", example_prompts[0], sp)
+        engine.add_request("1", None, sp, prompt_token_ids=long_prompt)
+        outs = []
+        while engine.has_unfinished_requests() or engine.has_inflight():
+            outs.extend(engine.step_pipelined() if pipelined
+                        else engine.step())
+        return _collect(outs)
+
+    ref = run(False)
+    get_efficiency_tracker().reset_for_testing()
+    assert run(True) == ref
+    snap = get_efficiency_tracker().snapshot()
+    assert snap["co_batched_steps"] >= 1
+    assert snap["pipeline_drains"]["chunked_prefill"] >= 1
+    assert snap["decode_join_rows"]["cont"] == 0
 
 
 def test_no_overshoot_cont_when_budgets_exhausted(tiny_llama_dir,

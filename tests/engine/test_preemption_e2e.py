@@ -107,8 +107,20 @@ _LONG_PROMPTS = [
 ]
 
 
+# Both engine loops. The serial one ("0"): every step is a full schedule.
+# The pipelined one ("1", the default): a long prompt's chunks run as
+# prompt steps beside a decode step in flight, with no preemption and no
+# swap planning of their own; a pool that runs short drains the pipeline
+# to a full schedule, which preempts groups part-way through their
+# prompts as the serial loop does (11 preemptions, 10 of them mid-chunk,
+# on either loop at this size).
+_LOOPS = pytest.mark.parametrize("pipeline", ["0", "1"])
+
+
+@_LOOPS
 def test_chunked_recompute_preemption_preserves_greedy(tiny_opt_dir,
-                                                       monkeypatch):
+                                                       monkeypatch,
+                                                       pipeline):
     """Chunked prefill + tight pool: recompute preemption must hit at
     least one PARTIALLY-prefilled group (num_computed_tokens mid-prompt),
     and the re-chunked re-prefill must reproduce the unpressured chunked
@@ -136,6 +148,7 @@ def test_chunked_recompute_preemption_preserves_greedy(tiny_opt_dir,
 
     monkeypatch.setattr(sched_mod.Scheduler, "_preempt_by_recompute",
                         counting)
+    monkeypatch.setenv("INTELLILLM_PIPELINE", pipeline)
     tight = _generate(_llm(tiny_opt_dir, 10, **chunked_kw),
                       _LONG_PROMPTS, params)
 
@@ -147,8 +160,9 @@ def test_chunked_recompute_preemption_preserves_greedy(tiny_opt_dir,
             f"prompt {i} diverged under mid-chunk recompute preemption")
 
 
+@_LOOPS
 def test_chunked_swap_preemption_preserves_greedy(tiny_opt_dir,
-                                                  monkeypatch):
+                                                  monkeypatch, pipeline):
     """Force SWAP preemption (instead of the single-seq recompute
     default) under chunked prefill: a swapped-out mid-chunk group keeps
     its num_computed_tokens, and swap-in must resume chunking exactly
@@ -191,6 +205,7 @@ def test_chunked_swap_preemption_preserves_greedy(tiny_opt_dir,
     monkeypatch.setattr(ce.CacheEngine, "swap_out", counting_out)
     monkeypatch.setattr(ce.CacheEngine, "swap_in", counting_in)
 
+    monkeypatch.setenv("INTELLILLM_PIPELINE", pipeline)
     tight = _generate(_llm(tiny_opt_dir, 10, **chunked_kw),
                       _LONG_PROMPTS, params)
 
@@ -202,3 +217,78 @@ def test_chunked_swap_preemption_preserves_greedy(tiny_opt_dir,
     for i, (r, t) in enumerate(zip(roomy, tight)):
         assert r.outputs[0].token_ids == t.outputs[0].token_ids, (
             f"prompt {i} diverged under mid-chunk swap preemption")
+
+
+def _generate_late(llm, early, late, params_early, params_late, after):
+    """`early` requests first; `late` ones added once `after` pipelined
+    steps have run, so they arrive beside a decode step in flight."""
+    engine = llm.llm_engine
+    assert engine.pipeline_enabled
+    for i, (p, sp) in enumerate(zip(early, params_early)):
+        engine.add_request(str(i), p, sp)
+    outs, steps, added = {}, 0, False
+    while (engine.has_unfinished_requests() or engine.has_inflight()
+           or not added):
+        if steps == after:
+            for j, (p, sp) in enumerate(zip(late, params_late)):
+                engine.add_request(str(len(early) + j), p, sp)
+            added = True
+        for o in engine.step_pipelined():
+            if o.finished:
+                outs[o.request_id] = o
+        steps += 1
+    return [outs[str(i)] for i in range(len(early) + len(late))]
+
+
+@pytest.mark.parametrize("mode", ["recompute", "swap"])
+def test_chunk_steps_beside_decode_under_a_tight_pool(tiny_opt_dir,
+                                                      monkeypatch, mode):
+    """The pipelined loop's own chunk path under memory pressure: prompts
+    over the token budget arrive while a K-step decode is in flight, so
+    their chunks run as prompt steps beside it
+    (`_chunked_pass(prefill_only=True)`, which neither preempts nor plans
+    a swap); the pool then runs short, the pipeline drains, and the full
+    schedule preempts. The tokens must be the roomy run's."""
+    from intellillm_tpu.core import scheduler as sched_mod
+
+    early = _LONG_PROMPTS[2:]
+    late = _LONG_PROMPTS[:2]
+    p_early = [SamplingParams(temperature=0.0, max_tokens=40,
+                              ignore_eos=True) for _ in early]
+    p_late = [SamplingParams(temperature=0.0, max_tokens=24,
+                             ignore_eos=True) for _ in late]
+    kw = dict(enable_chunked_prefill=True, max_num_batched_tokens=16)
+
+    roomy = _generate_late(_llm(tiny_opt_dir, 128, **kw), early, late,
+                           p_early, p_late, after=8)
+
+    seen = {"chunk_steps": 0, "preempted": 0}
+    orig_pass = sched_mod.Scheduler._chunked_pass
+    orig_preempt = sched_mod.Scheduler._preempt
+
+    def counting_pass(self, now, prefill_only=False):
+        out = orig_pass(self, now, prefill_only=prefill_only)
+        if prefill_only and out.scheduled_seq_groups:
+            seen["chunk_steps"] += 1
+        return out
+
+    def counting_preempt(self, seq_group, blocks_to_swap_out,
+                         preemption_mode=None):
+        seen["preempted"] += 1
+        if mode == "swap":
+            preemption_mode = sched_mod.PreemptionMode.SWAP
+        return orig_preempt(self, seq_group, blocks_to_swap_out,
+                            preemption_mode)
+
+    monkeypatch.setattr(sched_mod.Scheduler, "_chunked_pass", counting_pass)
+    monkeypatch.setattr(sched_mod.Scheduler, "_preempt", counting_preempt)
+    tight = _generate_late(_llm(tiny_opt_dir, 14, **kw), early, late,
+                           p_early, p_late, after=8)
+
+    assert seen["chunk_steps"] > 0, (
+        f"no chunk ran as a prompt step beside the decode batch — {seen}")
+    assert seen["preempted"] > 0, (
+        f"pool was sized to run short after the late prompts — {seen}")
+    for i, (r, t) in enumerate(zip(roomy, tight)):
+        assert r.outputs[0].token_ids == t.outputs[0].token_ids, (
+            f"prompt {i} diverged ({mode}) — {seen}")

@@ -149,6 +149,8 @@ def test_ledger_math_against_fake_param_tree_and_cache_sizing():
         get_cache_row_shape=lambda: (2, 4, 16),
         get_num_layers=lambda: 2,
         get_num_kv_layers=lambda: 2,
+        get_num_window_layers=lambda: 0,
+        get_window_group_share=lambda ahead: 0.0,
         get_state_row_shape=lambda: None)
     w = Worker.__new__(Worker)
     w.params = params

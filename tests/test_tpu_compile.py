@@ -141,6 +141,50 @@ def test_ragged_paged_attention_compiles(compile_for_chip, pool, shape):
     assert "tpu_custom_call" in text
 
 
+# smallthinker-21ba3b-l8's launches under its 4,096-token window: the
+# cell's 512-row chunk and its 16-row decode step, both at the widest table
+# (8,192 tokens of 16-token blocks), 28 query heads over 4 KV heads.
+@pytest.mark.parametrize("windowed", [False, True])
+@pytest.mark.parametrize("rows", [16, 512])
+def test_window_kernels_compile(compile_for_chip, rows, windowed):
+    """Both paged kernels with a row's first visible position (`lo`): the
+    walk starts at `lo`'s page and the programs are named apart."""
+    from intellillm_tpu.ops.pallas.paged_attention import paged_attention
+    from intellillm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    width, hq, hkv, dtype = 512, 28, 4, jnp.bfloat16
+    lo = (((rows, ), jnp.int32), ) if windowed else ()
+
+    def ragged(q, k_new, v_new, k_cache, v_cache, slots, tables, ctx, *lo):
+        return ragged_paged_attention(q, k_new, v_new, k_cache, v_cache,
+                                      slots, tables, ctx, HEAD_DIM ** -0.5,
+                                      None, *lo)
+
+    def paged(q, k_cache, v_cache, tables, ctx, *lo):
+        return paged_attention(q, k_cache, v_cache, tables, ctx,
+                               HEAD_DIM ** -0.5, return_lse=True,
+                               lo=lo[0] if lo else None)
+
+    text = compile_for_chip(
+        ragged, ((rows, 1, hq, HEAD_DIM), jnp.bfloat16),
+        ((rows, hkv, HEAD_DIM), dtype), ((rows, hkv, HEAD_DIM), dtype),
+        _pool(dtype, hkv), _pool(dtype, hkv), ((rows, ), jnp.int32),
+        ((rows, width), jnp.int32), ((rows, ), jnp.int32), *lo,
+        donate=(3, 4))
+    assert "tpu_custom_call" in text
+    assert ("_ragged_call_window" in text) == windowed
+    # The decode kernel takes a whole batch's tables into SMEM in one
+    # launch (1 MiB on the chip): 128 rows x 512 blocks fit, 512 do not.
+    rows = min(rows, 128)
+    lo = (((rows, ), jnp.int32), ) if windowed else ()
+    text = compile_for_chip(
+        paged, ((rows, 1, hq, HEAD_DIM), jnp.bfloat16), _pool(dtype, hkv),
+        _pool(dtype, hkv), ((rows, width), jnp.int32),
+        ((rows, ), jnp.int32), *lo)
+    assert "tpu_custom_call" in text
+    assert ("_paged_attention_call_window" in text) == windowed
+
+
 def test_mixed_seam_selects_the_kernel(compile_for_chip):
     """The seam itself (ops/ragged_attention), told it is on a TPU,
     resolves to the Pallas kernel and not to the reference."""
@@ -667,4 +711,99 @@ def test_state_model_programs_compile(compiled_for_chip, one_chip, tmp_path,
     assert "_short_conv_call" in text
     assert text.count("tpu_custom_call") >= 2
     # One layer of each kind plus the head stay far inside the chip.
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024**3
+
+
+# --- window and full layers: two cache groups a step ------------------------
+
+@pytest.mark.parametrize("program", ["mixed", "decode_cont"])
+def test_window_group_model_programs_compile(compiled_for_chip, one_chip,
+                                             tmp_path, program):
+    """`smallthinker-21ba3b-l8`'s programs as its cell dispatches them
+    (`mixed` at 512 rows, `decode_cont` at 16 rows, both at 512-block
+    tables of an 8,192-token model length, K=8, bf16, published widths;
+    depth cut to one period: a full layer and three window layers) compile
+    for the described v5e with the window group's tables, and hold the
+    windowed and the unwindowed attention kernels side by side."""
+    import json
+
+    from intellillm_tpu.config import (CacheConfig, ModelConfig,
+                                       ParallelConfig, SchedulerConfig)
+    from intellillm_tpu.models import get_model_class
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    from intellillm_tpu.worker.model_runner import ModelRunner
+
+    from chipbench import CHIP_DIR      # puts the harness on sys.path
+    from harness.server import hf_config_of
+
+    with open(os.path.join(CHIP_DIR, "configs",
+                           "smallthinker-21ba3b-l8.json")) as f:
+        cell = json.load(f)
+    serve = cell["serve"]
+    hf = hf_config_of(cell, rehearsal=False)
+    hf["num_hidden_layers"] = 4
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    model_config = ModelConfig(
+        model=str(tmp_path), dtype=serve["dtype"], load_format="dummy",
+        max_model_len=serve["max_model_len"])
+    kinds = model_config.get_layer_cache_kinds()
+    assert kinds == ["kv", "kv_window", "kv_window", "kv_window"]
+    assert model_config.get_window_group_size() == 4096
+    assert model_config.get_cache_row_shape() == (2, 4, 128)
+    model = get_model_class(model_config.hf_config.architectures)(
+        model_config)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init_random_params(0)))
+    blocks, rows, width, k = 16000, 16, 512, CELL_K
+    cache_config = CacheConfig(
+        num_device_blocks_override=blocks, swap_space_gib=0.01,
+        window_group_size=model_config.get_window_group_size())
+    cache_config.window_ahead_tokens = 512
+    cache_config.num_device_blocks = blocks
+    cache_config.size_window_group(model_config)
+    assert cache_config.num_window_device_blocks == 9000
+    set_use_pallas(True)        # jax.default_backend() is "cpu" here
+    try:
+        runner = ModelRunner(
+            model, params, model_config,
+            SchedulerConfig(max_num_batched_tokens=512, max_num_seqs=rows,
+                            max_model_len=serve["max_model_len"],
+                            enable_chunked_prefill=True),
+            cache_config, ParallelConfig())
+
+        def sds(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        bf16, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+        kv = [(sds(bf16, 9000 if kind == "kv_window" else blocks, 4, 16,
+                   128), ) * 2 for kind in kinds]
+        assert not jax.config.jax_enable_compilation_cache
+        if program == "mixed":
+            compiled = runner.lower_mixed_step(
+                kv, 512, width, do_random=False).compile()
+        else:
+            b, w = rows, width
+            rest = (sds(i32, b, 1), sds(i32, b, w), sds(i32, b),
+                    sds(f32, b), sds(i32, b), sds(f32, b), sds(f32, b),
+                    sds(jnp.uint32, b), sds(f32, b), sds(f32, b),
+                    sds(f32, b), None, None)
+            compiled = runner._jit_decode_cont.lower(
+                params, kv, sds(i32, b, 4 * k), sds(i32, b, 1),
+                sds(jnp.bool_, b, 1), *rest, window_tables=sds(i32, b, w),
+                prev_t1=k, num_steps=k, logprob_k=1, do_topk=False,
+                do_topp=False, do_minp=False, do_penalties=False,
+                do_random=False).compile()
+    finally:
+        set_use_pallas(None)
+    text = compiled.as_text()
+    kernel, windowed = (("_ragged_call", "_ragged_call_window")
+                        if program == "mixed" else
+                        ("_paged_attention_call",
+                         "_paged_attention_call_window"))
+    assert windowed in text and re.search(kernel + r"(?!_window)\b", text)
+    if program == "mixed":
+        # 512 rows take the grouped (ReLU) expert kernels.
+        assert "_grouped_gate_up_call" in text
+    # One period plus the whole vocabulary's head stay inside the chip.
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024**3

@@ -87,6 +87,50 @@ def test_state_ops_move_slots_and_leave_blocks_alone():
         ce.execute_state_ops([("shuffle", 0, 1)])
 
 
+@pytest.mark.parametrize("ops, calls", [
+    # consecutive moves of one kind are one call
+    ([("swap_out", 0, 0), ("swap_out", 1, 1), ("swap_out", 2, 3)],
+     [("swap_state_out", {0: 0, 1: 1, 2: 3})]),
+    # a fork to several children is one source with its destinations
+    ([("fork", 1, 2), ("fork", 1, 3)], [("copy_state", {1: [2, 3]})]),
+    # another kind, or a source an earlier move of the run wrote, starts
+    # a new call: the order the block manager made them in holds
+    ([("fork", 1, 3), ("fork", 3, 0), ("swap_out", 0, 2), ("swap_in", 1, 1)],
+     [("copy_state", {1: [3]}), ("copy_state", {3: [0]}),
+      ("swap_state_out", {0: 2}), ("swap_state_in", {1: 1})]),
+    ([("w_swap_out", 4, 0), ("w_swap_out", 5, 1), ("w_copy", 2, 6),
+      ("w_swap_in", 0, 7)],
+     [("swap_out", {4: 0, 5: 1}), ("copy", {2: [6]}), ("swap_in", {0: 7})]),
+])
+def test_state_ops_run_as_one_call_a_run(monkeypatch, ops, calls):
+    ce = engine()
+    seen = []
+    for name in ("swap_state_out", "swap_state_in", "copy_state",
+                 "swap_out", "swap_in", "copy"):
+        monkeypatch.setattr(
+            ce, name,
+            lambda mapping, window=None, name=name: seen.append(
+                (name, mapping) if window is None
+                else (name, mapping, window)))
+    ce.execute_state_ops(ops)
+    assert seen == [c if "state" in c[0] else c + (True, )
+                    for c in calls]
+
+
+def test_a_fork_of_a_fork_in_one_list_copies_the_copy():
+    ce = engine()
+    rng = np.random.default_rng(1)
+    for i in ce.state_layers:
+        ce.device_cache[i] = (jnp.asarray(
+            rng.normal(size=(4, 128)).astype(np.float32)), )
+    before = [np.asarray(ce.device_cache[i][0]) for i in ce.state_layers]
+    ce.execute_state_ops([("fork", 1, 3), ("fork", 3, 0)])
+    for i, was in zip(ce.state_layers, before):
+        now = np.asarray(ce.device_cache[i][0])
+        np.testing.assert_array_equal(now[3], was[1])
+        np.testing.assert_array_equal(now[0], was[1])
+
+
 def test_models_the_repo_had_describe_every_layer_alike():
     from transformers import LlamaConfig
     hf = LlamaConfig(vocab_size=64, hidden_size=64, intermediate_size=128,
