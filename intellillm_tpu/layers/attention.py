@@ -65,6 +65,12 @@ class AttentionMetadata:
     # has the window included (its one group is the windowed one).
     window_block_tables: Optional[jnp.ndarray] = None
     window_slot_mapping: Optional[jnp.ndarray] = None
+    # [B] i32, a `mixed` step: 0 for a row that continues the row before
+    # it (the next position of the same sequence: a prompt chunk's rows
+    # after its first), 1 for every other row. The ragged seam's kernel
+    # walks a run's paged context once a tile of its rows. None: no row
+    # is known to continue another.
+    run_starts: Optional[jnp.ndarray] = None
 
 
 class PagedAttention:
@@ -132,7 +138,7 @@ class PagedAttention:
             value.reshape(b * l, self.num_kv_heads, d), k_cache, v_cache,
             slots.reshape(-1), tables, attn_metadata.context_lens,
             self.scale, self.alibi_slopes, mesh=attn_metadata.mesh,
-            **self._window_kwargs())
+            run_starts=attn_metadata.run_starts, **self._window_kwargs())
         return out, (k_cache, v_cache)
 
     def _group_tables(self, attn_metadata):

@@ -76,6 +76,8 @@ CACHE_KINDS = ("kv", "kv_window", "state")
 # Layers by how far their attention sees (the attended-tokens counter).
 ATTENTION_REACHES = ("full", "window")
 WINDOW_BLOCK_EVENTS = ("recycled", )
+# A `mixed` row by the tile of rows that walked its context with it.
+RAGGED_TILES = ("one", "many")
 AXES = ("batch", "len", "block_width")
 _DEFAULT_MFU_WINDOW = 64
 _FILL_BUCKETS = (0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9,
@@ -154,6 +156,14 @@ class _EfficiencyMetrics:
             "of it), summed over the same live rows and substeps as the "
             "context tokens. A kind the model has no layer of stays 0.",
             ["program", "layers"])
+        self.counter_mixed_tile_rows = Counter(
+            "intellillm_mixed_tile_rows_total",
+            "Live rows of the `mixed` programs by the tile the ragged "
+            "attention kernel ran them in (tile: one = the row walked "
+            "its paged context alone, as a decode row does; many = rows "
+            "of one prompt chunk that walked it once together). "
+            "many / (one + many) is how often the tiling engages.",
+            ["tile"])
         self.counter_window_blocks = Counter(
             "intellillm_window_blocks_total",
             "What the block manager did with blocks of a sliding-window "
@@ -231,6 +241,8 @@ class _EfficiencyMetrics:
             self.counter_dispatch_walked_tokens.labels(program)
             for reach in ATTENTION_REACHES:
                 self.counter_dispatch_attended_tokens.labels(program, reach)
+        for tile in RAGGED_TILES:
+            self.counter_mixed_tile_rows.labels(tile)
         for event in WINDOW_BLOCK_EVENTS:
             self.counter_window_blocks.labels(event)
         for via in JOIN_VIAS:
@@ -349,6 +361,8 @@ class EfficiencyTracker:
             for program in PROGRAMS}
         self._decode_joins: Dict[str, int] = dict.fromkeys(JOIN_VIAS, 0)
         self._moe_ffn_rows: Dict[str, int] = dict.fromkeys(MOE_FFN_PATHS, 0)
+        self._mixed_tile_rows: Dict[str, int] = dict.fromkeys(
+            RAGGED_TILES, 0)
         self._pipeline_drains: Dict[str, int] = dict.fromkeys(
             DRAIN_CAUSES, 0)
         # (phase, axis) -> [sum of fill ratios, observations]
@@ -459,7 +473,8 @@ class EfficiencyTracker:
                         row_steps: int = 0,
                         context_tokens: int = 0,
                         walked_tokens: int = 0,
-                        attended_tokens: Optional[Dict[str, int]] = None
+                        attended_tokens: Optional[Dict[str, int]] = None,
+                        tile_rows: Optional[Dict[str, int]] = None
                         ) -> None:
         """Account one device dispatch. Extents are pre-padding vs
         post-padding; token counts are what the device actually
@@ -474,7 +489,9 @@ class EfficiencyTracker:
         moves for those same row-substeps (`walk_extent` of the kernel
         module: whole pages; where the model has two cache groups, the
         mean over its layers). `attended_tokens`: by ATTENTION_REACHES,
-        what those row-substeps attend to in one layer of that kind."""
+        what those row-substeps attend to in one layer of that kind.
+        `tile_rows`: by RAGGED_TILES, a `mixed` step's live rows by the
+        tile the ragged kernel ran them in."""
         if not self.enabled:
             return
         real_tokens = int(real_tokens)
@@ -515,6 +532,8 @@ class EfficiencyTracker:
                 counts["row_steps"] += int(row_steps)
                 counts["context_tokens"] += int(context_tokens)
                 counts["walked_tokens"] += int(walked_tokens)
+                for tile, n in (tile_rows or {}).items():
+                    self._mixed_tile_rows[tile] += int(n)
         if self._metrics is not None:
             m = self._metrics
             if program is not None:
@@ -527,6 +546,8 @@ class EfficiencyTracker:
                 for reach, n in (attended_tokens or {}).items():
                     m.counter_dispatch_attended_tokens.labels(
                         program, reach).inc(n)
+                for tile, n in (tile_rows or {}).items():
+                    m.counter_mixed_tile_rows.labels(tile).inc(n)
             m.counter_tokens.labels("real", phase).inc(real_tokens)
             m.counter_tokens.labels("pad", phase).inc(pad_tokens)
             for axis, ratio in fills:
@@ -715,6 +736,7 @@ class EfficiencyTracker:
                              in self._programs.items()},
                 "decode_join_rows": dict(self._decode_joins),
                 "moe_ffn_rows": dict(self._moe_ffn_rows),
+                "mixed_tile_rows": dict(self._mixed_tile_rows),
                 "pipeline_drains": dict(self._pipeline_drains),
                 "co_batched_steps": self._co_batched_steps,
                 "warmup_excluded_dispatches": self._warmup_excluded,

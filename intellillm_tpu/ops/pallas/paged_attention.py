@@ -66,9 +66,10 @@ twin was deleted, see the selection history below):
   zeroed: 0 x NaN is NaN.
 
 The ragged mixed-batch sibling (ops/pallas/ragged_paged_attention.py)
-reuses this module's `_walk` (the DMA walk and the online softmax) and
-adds the fused cache-write + in-flight-token handling the flat mixed
-dispatch needs.
+reuses this module's `_page_walker` (the DMA walk) and `_row_update`
+(the flat-dot online softmax of one query token a row) and adds the
+fused cache-write, the in-flight-token handling and the tiles of many
+rows the flat mixed dispatch needs.
 
 Numerics: f32 accumulation regardless of cache dtype.
 """
@@ -148,39 +149,38 @@ def _switch(index, lo: int, hi: int, branch) -> None:
              lambda: _switch(index, mid + 1, hi, branch))
 
 
-def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
-          slopes_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf, k_sem, v_sem,
-          m_scr, l_scr, acc_scr, code_scr, *, held_back: int,
-          batch_size: int, num_head_blocks: int, heads_per_block: int,
-          num_groups_g: int, pages_per_group: int, block_size: int,
-          scale: float, w_max: int, windowed: bool = False):
-    """Online softmax of this grid step's (row, kv-head block) over the
-    row's paged context less its last `held_back` tokens, into the scratch
-    accumulators. Returns the scaled query block [HP*G, D] in f32.
+def _page_walker(context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf,
+                 v_buf, k_sem, v_sem, *, held_back: int, batch_size: int,
+                 heads_per_block: int, pages_per_group: int,
+                 block_size: int, w_max: int, windowed: bool = False):
+    """The DMA side of the page walk, for one launch's refs: returns
+    (`first_page`, `start_first_group`, `walk`).
 
-    The context streams through the VMEM double buffer in groups of
-    `pages_per_group` pages: each group's copies are issued while the
-    group before it is multiplied, and a step's last group issues the
-    first group of the NEXT grid step, so the DMA pipeline never drains.
-    `walk_extent` says how far the walk goes. The row's full groups come
-    first, each moved and multiplied as one static piece; then its tail,
-    the pages of its last group that it holds, moved page by page and
-    multiplied in one piece up to the last sub-group that holds one. A
-    row with nothing to walk (a pad row) moves and multiplies nothing.
-    Copies are started and waited for by the same rule on the same row's
-    context, so what is waited for is what was started.
+    `walk(row, hb, start_buf, start_successor, update, sub)` streams the
+    pages `row` holds of its context less its last `held_back` tokens
+    (kv-head block `hb`) through the VMEM double buffer in groups of
+    `pages_per_group` pages and calls `update(g, buf, npg)` on each piece
+    (the first `npg` pages of group g, in buffer `buf`); it returns the
+    buffer the next walk's group 0 is in. Each group's copies are issued
+    while the group before it is multiplied, and a walk's last group
+    calls `start_successor(buf)`, which issues the first group of the
+    NEXT walk (`start_first_group(row, hb, buf)`), so the DMA pipeline
+    never drains. `walk_extent` says how far the walk goes. The row's
+    full groups come first, each moved and multiplied as one static
+    piece; then its tail, the pages of its last group that it holds,
+    moved page by page and multiplied in one piece up to the last
+    `sub`-page sub-group that holds one. A row with nothing to walk (a
+    pad row) moves and multiplies nothing. Copies are started and waited
+    for by the same rule on the same row's context, so what is waited
+    for is what was started.
 
     `windowed`: `context_lens_ref` is [2B], the rows' contexts and then
     their first visible positions `lo` (a sliding window). A row's walk
-    starts at table entry `lo // block_size` (its page 0 is that entry:
-    groups, tail and prefetch count from there) and positions below `lo`
-    in that first page are masked. Not windowed, nothing here differs
-    from what it was."""
-    b = pl.program_id(0)
-    hb = pl.program_id(1)
-    hp, g_sz, ppg, bs = (heads_per_block, num_groups_g, pages_per_group,
-                         block_size)
-    sub = _sub_pages(ppg)
+    starts at table entry `first_page(row) = lo // block_size` (its page
+    0 is that entry: groups, tail and prefetch count from there);
+    `update` masks the positions below `lo` in that first page. Not
+    windowed, nothing here differs from what it was."""
+    hp, ppg, bs = heads_per_block, pages_per_group, block_size
     pools = ((k_hbm_ref, k_buf, k_sem), (v_hbm_ref, v_buf, v_sem))
 
     def first_page(row):
@@ -191,11 +191,6 @@ def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
                            xp=jnp,
                            lo=(context_lens_ref[batch_size + row]
                                if windowed else None))[0]
-
-    limit = context_lens_ref[b] - held_back
-    pages = walk_pages(b)
-    full_groups = lax.div(pages, ppg)
-    tail = pages - full_groups * ppg
 
     def start_page(row, hb_, g, j, buf):
         entry = row * w_max + g * ppg + j
@@ -239,67 +234,99 @@ def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
         lax.cond(n == ppg, lambda: start_full(row, hb_, 0, buf),
                  lambda: start_tail(row, hb_, 0, n, buf))
 
-    # Very first grid step starts its own group 0; afterwards every step's
-    # group 0 was prefetched by its predecessor. It also lays out, once,
-    # what every step reads back: the position code of the flat score
-    # layout, and a V buffer that is finite. A page the walk does not move
-    # leaves in VMEM what was there: the mask zeroes its probabilities,
-    # but 0 x NaN in the PV dot is NaN, so V starts out finite and from
-    # then on holds only pages that some row owns. (K needs nothing: a
-    # masked score is selected away, never multiplied.)
-    @pl.when(init_ref[0] == 1)
-    def _first():
-        # The KV buffer flattens page-major: flat column c =
-        # (page*HP + head)*BS + tok, so head(c) = (c // BS) % HP and the
-        # token's index within the group is page(c)*BS + tok(c). Columns
-        # of other heads than the row's are junk: coded out of reach.
-        rows_i = lax.broadcasted_iota(jnp.int32, code_scr.shape, 0)
-        cols_i = lax.broadcasted_iota(jnp.int32, code_scr.shape, 1)
-        col_head = lax.rem(lax.div(cols_i, bs), hp)
-        col_tok = lax.div(cols_i, hp * bs) * bs + lax.rem(cols_i, bs)
-        code_scr[...] = jnp.where(lax.div(rows_i, g_sz) == col_head,
-                                  col_tok, _FAR)
-        zero = jnp.zeros(v_buf.shape[2:], jnp.float32).astype(v_buf.dtype)
-        for i in range(v_buf.shape[0]):
-            for j in range(ppg):
-                v_buf[i, j] = zero
-        start_first_group(b, hb, 0)
-    init_ref[0] = 0
-    start_buf = buf_idx_ref[0]
+    def walk(row, hb, start_buf, start_successor, update, sub):
+        pages = walk_pages(row)
+        full_groups = lax.div(pages, ppg)
+        tail = pages - full_groups * ppg
 
-    # Successor grid point (head-block fastest, then batch).
-    wrap = hb + 1 == num_head_blocks
-    nhb = jnp.where(wrap, 0, hb + 1)
-    nb = jnp.where(wrap, b + 1, b)
+        def full_group(g, carry):
+            buf = lax.rem(start_buf + g, 2)
+            nxt = 1 - buf
 
-    def start_successor(buf):
-        # By the SUCCESSOR's context. In the ragged kernel this is issued
-        # before the successor's own cache write: safe only because its
-        # own token is held back from its walk.
-        @pl.when(nb < batch_size)
-        def _():
-            start_first_group(nb, nhb, buf)
+            @pl.when(g + 1 < full_groups)
+            def _next_full_group():
+                start_full(row, hb, g + 1, nxt)
 
-    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
+            @pl.when(g + 1 == full_groups)
+            def _after_the_full_groups():
+                lax.cond(tail > 0,
+                         lambda: start_tail(row, hb, full_groups, tail, nxt),
+                         lambda: start_successor(nxt))
 
-    q_flat = (q_ref[0].astype(jnp.float32) *
-              scale).reshape(hp * g_sz, -1)              # [HP*G, D]
-    slope = slopes_ref[:, :, 0].reshape(hp * g_sz, 1)
-    query_pos_f = (context_lens_ref[b] - 1).astype(jnp.float32)
+            wait_full(buf)
+            update(g, buf, ppg)
+            return carry
+
+        lax.fori_loop(0, full_groups, full_group, 0)
+        tail_buf = lax.rem(start_buf + full_groups, 2)
+
+        def tail_group():
+            start_successor(1 - tail_buf)
+            wait_tail(tail, tail_buf)
+            # One piece whatever its size: a chain of smaller updates
+            # would pay the QK -> softmax -> PV latency once a link.
+            _switch(lax.div(tail + sub - 1, sub), 1, ppg // sub,
+                    lambda t: update(full_groups, tail_buf, t * sub))
+
+        def no_tail():
+            @pl.when(full_groups == 0)  # nothing held: nothing in flight
+            def _():
+                start_successor(tail_buf)
+
+        lax.cond(tail > 0, tail_group, no_tail)
+        return lax.rem(start_buf + full_groups
+                       + (tail > 0).astype(jnp.int32), 2)
+
+    return first_page, start_first_group, walk
+
+
+def _position_code(code_scr, *, heads_per_block: int, num_groups_g: int,
+                   block_size: int) -> None:
+    """Lay out the position code of the flat score layout, once a launch.
+    The KV buffer flattens page-major: flat column c = (page*HP + head)*BS
+    + tok, so head(c) = (c // BS) % HP and the token's index within the
+    group is page(c)*BS + tok(c). Columns of other heads than the row's
+    are junk: coded out of reach."""
+    hp, bs = heads_per_block, block_size
+    rows_i = lax.broadcasted_iota(jnp.int32, code_scr.shape, 0)
+    cols_i = lax.broadcasted_iota(jnp.int32, code_scr.shape, 1)
+    col_head = lax.rem(lax.div(cols_i, bs), hp)
+    col_tok = lax.div(cols_i, hp * bs) * bs + lax.rem(cols_i, bs)
+    code_scr[...] = jnp.where(lax.div(rows_i, num_groups_g) == col_head,
+                              col_tok, _FAR)
+
+
+def _zero_pages(v_buf) -> None:
+    """A page the walk does not move leaves in VMEM what was there: the
+    mask zeroes its probabilities, but 0 x NaN in the PV dot is NaN, so V
+    starts out finite and from then on holds only pages that some row
+    owns. (K needs nothing: a masked score is selected away, never
+    multiplied.)"""
+    zero = jnp.zeros(v_buf.shape[2:], jnp.float32).astype(v_buf.dtype)
+    for i in range(v_buf.shape[0]):
+        for j in range(v_buf.shape[1]):
+            v_buf[i, j] = zero
+
+
+def _row_update(q_flat, slope, query_pos_f, limit, lo, k_buf, v_buf, m_scr,
+                l_scr, acc_scr, code_scr, *, first_token, heads_per_block:
+                int, pages_per_group: int, block_size: int):
+    """`update(g, buf, npg)` of one query token a row: folds the first
+    `npg` pages of group g, held in buffer `buf`, into the row's
+    accumulators. `q_flat` [HP*G, D] f32 (scaled), `slope` [HP*G, 1];
+    the row sees positions `lo <= pos < limit` (`lo` None: from 0) and
+    its walk's page 0 starts at position `first_token`."""
+    hp, ppg, bs = heads_per_block, pages_per_group, block_size
+    rows = q_flat.shape[0]
 
     def update(g, buf, npg: int):
-        """Fold the first `npg` pages of group g, held in buffer `buf`,
-        into the accumulators."""
         ncols = npg * hp * bs
         # Token position of each FLAT column within the full sequence
         # (out of reach for a column of another head's).
         token_pos = g * (ppg * bs) + code_scr[:, :ncols]  # [HP*G, NC]
-        if windowed:
-            token_pos = token_pos + first_page(b) * bs
-            mask = ((token_pos < limit)
-                    & (token_pos >= context_lens_ref[batch_size + b]))
+        if lo is not None:
+            token_pos = token_pos + first_token
+            mask = (token_pos < limit) & (token_pos >= lo)
         else:
             mask = token_pos < limit
 
@@ -332,47 +359,69 @@ def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
             p, v, (((1, ), (0, )), ((), ())),
             preferred_element_type=jnp.float32)          # [HP*G, D]
         acc_scr[...] = acc_scr[...] * alpha + pv
-        m_scr[...] = jnp.broadcast_to(m_new, (hp * g_sz, 128))
-        l_scr[...] = jnp.broadcast_to(l_new, (hp * g_sz, 128))
+        m_scr[...] = jnp.broadcast_to(m_new, (rows, 128))
+        l_scr[...] = jnp.broadcast_to(l_new, (rows, 128))
 
-    def full_group(g, carry):
-        buf = lax.rem(start_buf + g, 2)
-        nxt = 1 - buf
+    return update
 
-        @pl.when(g + 1 < full_groups)
-        def _next_full_group():
-            start_full(b, hb, g + 1, nxt)
 
-        @pl.when(g + 1 == full_groups)
-        def _after_the_full_groups():
-            lax.cond(tail > 0,
-                     lambda: start_tail(b, hb, full_groups, tail, nxt),
-                     lambda: start_successor(nxt))
+def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
+          slopes_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf, k_sem, v_sem,
+          m_scr, l_scr, acc_scr, code_scr, *, batch_size: int,
+          num_head_blocks: int, heads_per_block: int, num_groups_g: int,
+          pages_per_group: int, block_size: int, scale: float, w_max: int,
+          windowed: bool = False):
+    """Online softmax of this grid step's (row, kv-head block) over the
+    row's paged context, into the scratch accumulators (`_page_walker`
+    moves the pages, `_row_update` multiplies them). A step's last group
+    issues the first group of the NEXT grid step."""
+    b = pl.program_id(0)
+    hb = pl.program_id(1)
+    hp, g_sz, ppg, bs = (heads_per_block, num_groups_g, pages_per_group,
+                         block_size)
+    first_page, start_first_group, walk = _page_walker(
+        context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf,
+        k_sem, v_sem, held_back=0, batch_size=batch_size,
+        heads_per_block=hp, pages_per_group=ppg, block_size=bs,
+        w_max=w_max, windowed=windowed)
 
-        wait_full(buf)
-        update(g, buf, ppg)
-        return carry
+    # Very first grid step starts its own group 0; afterwards every step's
+    # group 0 was prefetched by its predecessor. It also lays out, once,
+    # what every step reads back: the position code of the flat score
+    # layout, and a V buffer that is finite.
+    @pl.when(init_ref[0] == 1)
+    def _first():
+        _position_code(code_scr, heads_per_block=hp, num_groups_g=g_sz,
+                       block_size=bs)
+        _zero_pages(v_buf)
+        start_first_group(b, hb, 0)
+    init_ref[0] = 0
 
-    lax.fori_loop(0, full_groups, full_group, 0)
-    tail_buf = lax.rem(start_buf + full_groups, 2)
+    # Successor grid point (head-block fastest, then batch).
+    wrap = hb + 1 == num_head_blocks
+    nhb = jnp.where(wrap, 0, hb + 1)
+    nb = jnp.where(wrap, b + 1, b)
 
-    def tail_group():
-        start_successor(1 - tail_buf)
-        wait_tail(tail, tail_buf)
-        # One piece whatever its size: a chain of smaller updates would
-        # pay the QK -> softmax -> PV latency once a link.
-        _switch(lax.div(tail + sub - 1, sub), 1, ppg // sub,
-                lambda t: update(full_groups, tail_buf, t * sub))
-
-    def no_tail():
-        @pl.when(full_groups == 0)      # nothing held: nothing in flight
+    def start_successor(buf):
+        @pl.when(nb < batch_size)
         def _():
-            start_successor(tail_buf)
+            start_first_group(nb, nhb, buf)
 
-    lax.cond(tail > 0, tail_group, no_tail)
-    buf_idx_ref[0] = lax.rem(start_buf + full_groups
-                             + (tail > 0).astype(jnp.int32), 2)
-    return q_flat
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    q_flat = (q_ref[0].astype(jnp.float32) *
+              scale).reshape(hp * g_sz, -1)              # [HP*G, D]
+    update = _row_update(
+        q_flat, slopes_ref[:, :, 0].reshape(hp * g_sz, 1),
+        (context_lens_ref[b] - 1).astype(jnp.float32), context_lens_ref[b],
+        context_lens_ref[batch_size + b] if windowed else None,
+        k_buf, v_buf, m_scr, l_scr, acc_scr, code_scr,
+        first_token=first_page(b) * bs if windowed else 0,
+        heads_per_block=hp, pages_per_group=ppg, block_size=bs)
+    buf_idx_ref[0] = walk(b, hb, buf_idx_ref[0], start_successor, update,
+                          _sub_pages(ppg))
 
 
 def _decode_kernel(
@@ -406,7 +455,7 @@ def _decode_kernel(
     hp, g_sz = heads_per_block, num_groups_g
     _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
           slopes_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf, k_sem, v_sem,
-          m_scr, l_scr, acc_scr, code_scr, held_back=0, heads_per_block=hp,
+          m_scr, l_scr, acc_scr, code_scr, heads_per_block=hp,
           num_groups_g=g_sz, **walk_params)
 
     l = l_scr[:, 0][:, None]                             # [HP*G, 1]

@@ -193,6 +193,14 @@ class InflightStep:
                                    self.is_prompt, self.num_steps)
 
 
+def _padded_run_starts(run_starts, padded_n: int) -> np.ndarray:
+    """`run_starts` of a step's live rows made up to the bucket: a pad
+    row continues nothing."""
+    starts = np.ones(padded_n, np.int32)
+    starts[:len(run_starts)] = run_starts
+    return starts
+
+
 class ModelRunner:
 
     def __init__(
@@ -239,6 +247,13 @@ class ModelRunner:
         else:
             from intellillm_tpu.ops.ragged_attention import walk_extent
         self._walk_extent = walk_extent
+        # Query heads a KV head: with the table width, what the ragged
+        # kernel sizes its tiles of rows by (`_mixed_walkers`). None: the
+        # latent kernels walk a row at a time.
+        self._queries_per_kv = (
+            None if model_config.get_latent_cache_width()
+            else model_config.get_num_attention_heads()
+            // model_config.get_total_num_kv_heads())
         # What each layer caches (`ModelConfig.get_layer_cache_kinds`).
         # A model with "state" layers gives every row of a step the state
         # slot of its sequence (`AttentionMetadata.state_slots`); the
@@ -355,7 +370,7 @@ class ModelRunner:
             sds(f32, b), sds(f32, b), sds(u32, b), sds(f32, b), sds(f32, b),
             sds(f32, b), None, None, logprob_k=logprob_k, do_topk=False,
             do_topp=False, do_minp=False, do_penalties=False,
-            do_random=do_random,
+            do_random=do_random, run_starts=sds(i32, b),
             **({"state_slots": sds(i32, b)} if self.stateful else {}),
             **({"window_tables": sds(i32, b, w)} if self.window_group
                else {}))
@@ -389,6 +404,15 @@ class ModelRunner:
         slots = np.full(padded_n, self.num_state_slots, np.int32)
         slots[:len(row_slots)] = row_slots
         return {"state_slots": self._place_batch_array(slots)}
+
+    def run_start_kwargs(self, run_starts, padded_n: int) -> Dict[str, Any]:
+        """The `run_starts` argument of the `mixed` program: 0 for a row
+        that continues the row before it (a prompt chunk's rows after its
+        first), 1 for every other row, pad rows included. `run_starts`
+        says it of the live rows, in row order; what it leaves out is 1
+        (a step of decode rows hands in none)."""
+        return {"run_starts": self._place_batch_array(
+            _padded_run_starts(run_starts, padded_n))}
 
     def _record_moe_ffn_rows(self, rows: int, substeps: int = 1) -> None:
         """`rows` x `substeps` of a dispatched program went through the
@@ -801,7 +825,7 @@ class ModelRunner:
                           prompt_tokens, output_tokens, lora=None,
                           fetch_indices=None, plp_targets=None,
                           numerics_inject=None, state_slots=None,
-                          window_tables=None, *,
+                          window_tables=None, run_starts=None, *,
                           num_samples=1, plp_k=0, do_numerics=False,
                           logprob_k, do_topk, do_topp, do_minp,
                           do_penalties, do_random=True):
@@ -810,7 +834,8 @@ class ModelRunner:
         run side by side in one flat batch. Also used whenever K == 1.
         `window_tables`: the window group's tables, for a model with two
         cache groups (a row's token goes to the same logical block and
-        offset in either).
+        offset in either). `run_starts` [B]: 0 for a row that continues
+        the row before it (`AttentionMetadata.run_starts`).
 
         num_samples > 1 serves final-chunk `best_of` fan-out (every row
         draws num_samples gumbel streams; a row's sample 0 is bit-equal
@@ -840,6 +865,7 @@ class ModelRunner:
             block_tables=block_tables,
             mesh=self.mesh,
             state_slots=state_slots,
+            run_starts=run_starts,
             **window_kwargs,
         )
         hidden, new_caches, held = self._call_model(
@@ -869,24 +895,45 @@ class ModelRunner:
 
     # --- batch prep -------------------------------------------------------
 
-    def _walked_tokens(self, seen, query_ends, w: int) -> int:
+    def _walked_tokens(self, seen, query_ends, w: int,
+                       walkers=None) -> int:
         """Tokens the attention kernel's page walk moves for rows that
         must see `seen` tokens of their contexts under `w`-wide tables,
         a layer: the mean over the model's paged layers, of which those
         with a sliding window walk from the page of the first position a
-        query that ends at `query_ends` (its position + 1) sees."""
+        query that ends at `query_ends` (its position + 1) sees.
+        `walkers` (a `mixed` step, `_mixed_walkers`): the rows that walk;
+        the others ride in a walker's tile and move nothing."""
         seen = np.asarray(seen, np.int64)
         full_layers, window_layers = self._layers_by_reach
         walked = 0
+
+        def moved(pages) -> int:
+            return int((pages if walkers is None else pages[walkers]).sum())
         if full_layers:
             pages, _ = self._walk_extent(seen, self.block_size, w)
-            walked += full_layers * int(pages.sum())
+            walked += full_layers * moved(pages)
         if window_layers:
             lo = np.clip(np.asarray(query_ends, np.int64) - self._window,
                          0, None)
             pages, _ = self._walk_extent(seen, self.block_size, w, lo=lo)
-            walked += window_layers * int(pages.sum())
+            walked += window_layers * moved(pages)
         return walked * self.block_size // (full_layers + window_layers)
+
+    def _mixed_walkers(self, run_starts, padded_n: int, w: int):
+        """(which of a `mixed` step's live rows walk their context, the
+        live rows by the tile they run in): the ragged kernel walks a
+        run's context once a tile of its rows (`ops/ragged_attention.py`
+        `ragged_walkers`), the latent kernels once a row (None)."""
+        n = len(run_starts)
+        if self._queries_per_kv is None:
+            return None, {"one": n}
+        from intellillm_tpu.ops.ragged_attention import ragged_walkers
+        walkers, alone = ragged_walkers(
+            _padded_run_starts(run_starts, padded_n),
+            np.arange(padded_n) < n, w, self._queries_per_kv)
+        alone = int(alone[:n].sum())
+        return walkers[:n], {"one": alone, "many": n - alone}
 
     def _attended_tokens(self, query_ends, context_tokens: int
                          ) -> Optional[Dict[str, int]]:
@@ -1168,7 +1215,8 @@ class ModelRunner:
                     program, bucket, self._jit_decode_single,
                     *decode_args,
                     place(fetch_indices) if fetch_indices is not None
-                    else None, **common, **numerics_kwargs, **state_kwargs)
+                    else None, **common, **numerics_kwargs, **state_kwargs,
+                    **self.run_start_kwargs((), padded_n))
             result = list(result)
             packed = result.pop(0)
             fetched = result.pop(0) if proc_rows else None
@@ -1202,9 +1250,11 @@ class ModelRunner:
                                          eff_info["width_padded"])
             attended = self._attended_tokens(eff_info["ctxs"],
                                              int(eff_info["ctxs"].sum()))
+            tile_rows = {"one": real_rows}
         else:
             walked, attended = self._decode_counts(
                 eff_info["ctxs"], num_steps, eff_info["width_padded"])
+            tile_rows = None
         self._efficiency.record_dispatch(
             "decode", real_rows, padded_n,
             real_tokens=real_rows * num_steps,
@@ -1215,7 +1265,8 @@ class ModelRunner:
             row_steps=real_rows * num_steps,
             context_tokens=(int(eff_info["ctxs"].sum()) * num_steps
                             + real_rows * num_steps * (num_steps - 1) // 2),
-            walked_tokens=walked, attended_tokens=attended)
+            walked_tokens=walked, attended_tokens=attended,
+            tile_rows=tile_rows)
         self._record_moe_ffn_rows(padded_n, num_steps)
 
         # ONE device→host transfer for everything, performed by
@@ -1304,6 +1355,9 @@ class ModelRunner:
             n_chunk_tokens = 0
             n_chunk_groups = 0
             n_decode_rows = 0
+            # 0 for a row that continues the row before it: a chunk's
+            # rows after its first (`AttentionMetadata.run_starts`).
+            run_starts: List[int] = []
 
             for meta in seq_group_metadata_list:
                 sp = meta.sampling_params
@@ -1344,6 +1398,7 @@ class ModelRunner:
                                              sp.prompt_logprobs, data,
                                              int(all_ids[pos + 1]), pos + 1))
                             plp_k = max(plp_k, sp.prompt_logprobs, 1)
+                    run_starts += [1] + [0] * (size - 1)
                     n_chunk_tokens += size
                     n_chunk_groups += 1
                     if final:
@@ -1375,6 +1430,7 @@ class ModelRunner:
                             row_slots.append(meta.state_slots[seq_id])
                         emit_idx.append(len(rows) - 1)
                         emit_rows.append((meta.request_id, seq_id))
+                        run_starts.append(1)
                         n_decode_rows += 1
 
             num_samples = pad_to_bucket(num_samples, _SAMPLE_BUCKETS)
@@ -1421,7 +1477,8 @@ class ModelRunner:
             state_kwargs = {
                 **self.state_slot_kwargs(row_slots, padded_n),
                 **self.window_table_kwargs(window_tables, range(len(rows)),
-                                           padded_n, w)}
+                                           padded_n, w),
+                **self.run_start_kwargs(run_starts, padded_n)}
 
         # Numerics sentinels: when OFF the dispatch binds exactly as the
         # pre-sentinel code did (no extra kwargs → identical jit cache
@@ -1470,12 +1527,14 @@ class ModelRunner:
         pad_rows = padded_n - len(rows)
         # The walk sees a row's context less the row's own token.
         ctx_arr = np.fromiter(ctxs, np.int64, len(ctxs))
+        walkers, tile_rows = self._mixed_walkers(run_starts, padded_n, w)
         dispatch = dict(program="mixed", device_idle=device_idle,
                         row_steps=len(rows), context_tokens=sum(ctxs),
-                        walked_tokens=self._walked_tokens(ctx_arr - 1,
-                                                          ctx_arr, w),
+                        walked_tokens=self._walked_tokens(
+                            ctx_arr - 1, ctx_arr, w, walkers),
                         attended_tokens=self._attended_tokens(
-                            ctx_arr, int(ctx_arr.sum())))
+                            ctx_arr, int(ctx_arr.sum())),
+                        tile_rows=tile_rows)
         if n_chunk_groups:
             self._efficiency.record_dispatch(
                 "prefill", n_chunk_groups, n_chunk_groups,

@@ -374,10 +374,12 @@ class Worker:
                 state_kwargs = {
                     **runner.state_slot_kwargs([], b),
                     **runner.window_table_kwargs((), (), b, w)}
+                run_starts = runner.run_start_kwargs((), b)
                 for flags in flag_variants:
                     result = runner._jit_decode_single(
                         self.params, self.cache_engine.device_cache,
-                        *args, **flags, **numerics_kwargs, **state_kwargs)
+                        *args, **flags, **numerics_kwargs, **state_kwargs,
+                        **run_starts)
                     # (packed, [sentinel panel,] caches) — the panel
                     # rides along only under --enable-numerics.
                     packed, caches = result[0], result[-1]
@@ -397,7 +399,7 @@ class Worker:
                             self.params,
                             self.cache_engine.device_cache,
                             *fargs, **flags, **numerics_kwargs,
-                            **state_kwargs)
+                            **state_kwargs, **run_starts)
                         packed, caches = result[0], result[-1]
                         self.cache_engine.device_cache = caches
                         n += 1

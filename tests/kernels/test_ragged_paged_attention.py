@@ -388,3 +388,36 @@ def test_prompt_as_chunk_rows_equals_dense_causal_attention(
         np.asarray(k_pool)[table[pos // bs], :, pos % bs], k)
     np.testing.assert_array_equal(
         np.asarray(v_pool)[table[pos // bs], :, pos % bs], v)
+
+
+@requires_tpu
+@pytest.mark.parametrize("pool", [None, jnp.bfloat16])
+@pytest.mark.parametrize("hq,hkv", [(8, 8), (8, 2), (28, 4)])
+def test_ragged_chunk_run_as_one_tile(hq, hkv, pool):
+    """The mixed batch with its chunk run (rows 3..5) handed in as a run:
+    `run_starts` says rows 4 and 5 continue the row before them, the
+    kernel writes and walks them as one tile between tiles of one, and
+    output and pool equal the oracle's. (tests/kernels/test_ragged_tiles.py
+    has the runs that cross tiles and pages.)"""
+    from intellillm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    rng = np.random.default_rng(6)
+    d = 128
+    q, k_new, v_new, k, v, slots, tables, ctx = _mixed_batch(
+        rng, hq=hq, hkv=hkv, d=d)
+    if pool is not None:
+        k, v = k.astype(pool), v.astype(pool)
+    starts = jnp.asarray([1, 1, 1, 1, 0, 0, 1], jnp.int32)
+    got = jax.block_until_ready(ragged_paged_attention(
+        q, k_new.astype(k.dtype), v_new.astype(v.dtype), k, v, slots,
+        tables, ctx, d**-0.5, run_starts=starts))
+    want = ragged_fused_attention_reference(
+        q, k_new, v_new, k, v, slots, tables, ctx, d**-0.5)
+    tol = 2e-2 if pool is not None else 2e-3
+    np.testing.assert_allclose(np.asarray(got[0], np.float32),
+                               np.asarray(want[0], np.float32),
+                               rtol=tol, atol=tol)
+    for pool_got, pool_want in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(
+            np.asarray(pool_got.astype(jnp.float32)),
+            np.asarray(pool_want.astype(jnp.float32)))

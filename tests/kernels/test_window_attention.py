@@ -188,3 +188,147 @@ def test_walk_extent_with_a_window(window):
     # a pad row moves nothing, with a window as without
     assert walk_extent(np.asarray([0, -1]), bs, w,
                        lo=np.asarray([0, 0]))[0].tolist() == [0, 0]
+
+
+# --- a chunk's tile across the window's edge --------------------------------
+
+def _chunk_rows(rng, start, n, hq, hkv, poison=True):
+    """`n` rows of one sequence from position `start`, after one decode
+    row of another; entries of the chunk's table below its first row's
+    window point at pages of NaN."""
+    ctx = np.asarray([40] + list(range(start + 1, start + n + 1)), np.int32)
+    b = len(ctx)
+    lo = np.clip(ctx - WINDOW, 0, None)
+    below = np.concatenate([lo[:1], np.full(n, lo[1])]) // BS
+    k, v, tables = _pool(rng, b, hkv,
+                         poison_below=below if poison else None)
+    tables[2:] = tables[1]
+    slots = (tables[np.arange(b), (ctx - 1) // BS] * BS
+             + (ctx - 1) % BS).astype(np.int32)
+    starts = np.asarray([1, 1] + [0] * (n - 1), np.int32)
+    q = jnp.asarray(rng.normal(size=(b, 1, hq, D)).astype(np.float32))
+    k_new = jnp.asarray(rng.normal(size=(b, hkv, D)).astype(np.float32))
+    v_new = jnp.asarray(rng.normal(size=(b, hkv, D)).astype(np.float32))
+    return (q, k_new, v_new, k, v, jnp.asarray(slots), jnp.asarray(tables),
+            jnp.asarray(ctx)), jnp.asarray(lo), jnp.asarray(starts)
+
+
+# First position of a 23-row chunk (one tile of 24 rows with the decode
+# row before it): every row inside the window (lo 0 throughout); rows on
+# both sides of the edge, the first row whose `lo` leaves page 0 among
+# them (position 115: lo 16); every row beyond the window from a page's
+# middle; from a page's first token, many groups in.
+WINDOW_CHUNK_STARTS = (30, 90, 104, 200, 384)
+
+
+@requires_tpu
+@pytest.mark.parametrize("hq,hkv", [(28, 4), (8, 2)])
+@pytest.mark.parametrize("start", WINDOW_CHUNK_STARTS)
+def test_ragged_window_tile_matches_reference(start, hq, hkv):
+    """The rows of one tile under a window: each row's own `lo` masks the
+    walk and the tile's own keys, the walk starts at the page of the
+    first row's `lo` and reads no page below it (those hold NaN)."""
+    from intellillm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    rng = np.random.default_rng(21)
+    (q, k_new, v_new, k, v, slots, tables, ctx), lo, starts = _chunk_rows(
+        rng, start, 23, hq, hkv)
+    got = jax.block_until_ready(ragged_paged_attention(
+        q, k_new, v_new, k, v, slots, tables, ctx, D**-0.5, lo=lo,
+        run_starts=starts))
+    want = ragged_fused_attention_reference(
+        q, k_new, v_new, _finite(k), _finite(v), slots, tables, ctx,
+        D**-0.5, lo=lo)
+    assert np.isfinite(np.asarray(got[0])).all()
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=_tol(), atol=_tol())
+    for pool_got, pool_want in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(np.asarray(_finite(pool_got)),
+                                      np.asarray(pool_want))
+
+
+@requires_tpu
+def test_ragged_window_tiles_equal_rows_alone():
+    """What `run_starts` changes is how often the context is walked, not
+    what a row attends to: the same step with every row a tile of one."""
+    from intellillm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    rng = np.random.default_rng(22)
+    args, lo, starts = _chunk_rows(rng, 104, 23, 8, 2, poison=False)
+    tiled = jax.block_until_ready(ragged_paged_attention(
+        *args, D**-0.5, lo=lo, run_starts=starts))
+    alone = jax.block_until_ready(ragged_paged_attention(
+        *args, D**-0.5, lo=lo))
+    np.testing.assert_allclose(np.asarray(tiled[0]), np.asarray(alone[0]),
+                               rtol=1e-5, atol=1e-5)
+    for a, b in zip(tiled[1:], alone[1:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@requires_tpu
+@pytest.mark.parametrize("window", [None, WINDOW])
+def test_seam_tiles_heads_padded_to_a_lane_tile(window):
+    """Heads of 64 in a pool of 128 lanes (`PagedAttention` pads q, k and
+    v with zeros), a chunk's rows as tiles through the seam: equal to the
+    jnp composition at the head's own width."""
+    from intellillm_tpu.layers.attention import (AttentionMetadata,
+                                                 PagedAttention)
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    rng = np.random.default_rng(23)
+    hq, hkv, head = 8, 2, 64
+    (q, k_new, v_new, k, v, slots, tables, ctx), _, starts = _chunk_rows(
+        rng, 104, 23, hq, hkv, poison=False)
+    b = q.shape[0]
+    layer = PagedAttention(hq, head, head**-0.5, hkv, sliding_window=window)
+    meta = AttentionMetadata(slot_mapping=slots[:, None], context_lens=ctx,
+                             block_tables=tables, run_starts=starts)
+    narrow = (q[..., :head].reshape(b, 1, hq, head),
+              k_new[..., :head].reshape(b, 1, hkv, head),
+              v_new[..., :head].reshape(b, 1, hkv, head))
+    outs = {}
+    for pallas in (True, False):
+        set_use_pallas(pallas)
+        try:
+            out, pools = layer(*narrow, (k, v), meta)
+            outs[pallas] = jax.block_until_ready((out, ) + tuple(pools))
+        finally:
+            set_use_pallas(None)
+    assert outs[True][0].shape == (b, 1, hq, head)
+    np.testing.assert_allclose(np.asarray(outs[True][0]),
+                               np.asarray(outs[False][0]),
+                               rtol=_tol(), atol=_tol())
+    for got, want in zip(outs[True][1:], outs[False][1:]):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@requires_tpu
+@pytest.mark.parametrize("known_runs", [True, False])
+@pytest.mark.parametrize("window", [None, WINDOW])
+def test_seam_tiles_on_a_two_device_mesh(window, known_runs):
+    """`shard_over_heads`: each device runs the tiles on its half of the
+    KV heads; `run_starts` is row metadata, replicated (or None: no row
+    is known to continue another, and every row walks alone)."""
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    from jax.sharding import Mesh
+    if len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2),
+                ("data", "model"))
+    rng = np.random.default_rng(24)
+    (q, k_new, v_new, k, v, slots, tables, ctx), lo, starts = _chunk_rows(
+        rng, 104, 23, 8, 2, poison=False)
+    set_use_pallas(True)
+    try:
+        got = jax.block_until_ready(ragged_fused_attention(
+            q, k_new, v_new, k, v, slots, tables, ctx, D**-0.5, mesh=mesh,
+            window=window, run_starts=starts if known_runs else None))
+    finally:
+        set_use_pallas(None)
+    want = ragged_fused_attention_reference(
+        q, k_new, v_new, k, v, slots, tables, ctx, D**-0.5,
+        lo=None if window is None else lo)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=_tol(), atol=_tol())
+    for pool_got, pool_want in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(np.asarray(pool_got),
+                                      np.asarray(pool_want))

@@ -331,6 +331,38 @@ def test_moe_ffn_rows_count_by_the_form_the_rows_take(tracker, rows,
         p: rows * substeps * (p == path) for p in MOE_FFN_PATHS}
 
 
+@pytest.mark.parametrize("tiles", [{"one": 128}, {"one": 3, "many": 509},
+                                   {"many": 512}])
+def test_mixed_tile_rows_count_by_the_tile_a_row_ran_in(tracker, tiles):
+    """intellillm_mixed_tile_rows_total{tile}: a `mixed` step's live rows
+    by whether the ragged kernel walked their context alone or a tile of
+    a chunk's rows together; both children from the first scrape; warm-up
+    dispatches are not counted."""
+    from prometheus_client import REGISTRY
+    from intellillm_tpu.obs.efficiency import RAGGED_TILES
+
+    def sample(t):
+        return REGISTRY.get_sample_value(
+            "intellillm_mixed_tile_rows_total", {"tile": t})
+
+    def dispatch():
+        n = sum(tiles.values())
+        tracker.record_dispatch("prefill", 1, 1, real_tokens=n,
+                                padded_tokens=n, program="mixed",
+                                row_steps=n, tile_rows=tiles)
+
+    before = {t: sample(t) for t in RAGGED_TILES}
+    assert None not in before.values()
+    with tracker.warmup():
+        dispatch()
+    assert tracker.snapshot()["mixed_tile_rows"] == dict.fromkeys(
+        RAGGED_TILES, 0)
+    dispatch()
+    want = {t: tiles.get(t, 0) for t in RAGGED_TILES}
+    assert tracker.snapshot()["mixed_tile_rows"] == want
+    assert {t: sample(t) - before[t] for t in RAGGED_TILES} == want
+
+
 def test_dispatch_program_counters_skip_warmup_and_exist_at_zero(tracker):
     from prometheus_client import REGISTRY
     zero = {"idle": 0, "busy": 0, "row_steps": 0, "context_tokens": 0,

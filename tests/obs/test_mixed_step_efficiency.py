@@ -97,12 +97,16 @@ def test_mixed_steps_attribute_tokens_exactly_once(tiny_opt_dir, trackers):
     assert mixed["context_tokens"] == expected_ctx
     # ... and what the kernel's page walk moves for those rows: whole
     # pages of the context less the row's own token.
+    # (No chunk fills a whole span of rows at this budget, so every row is
+    # a tile of one: tests/worker/test_mixed_run_starts.py holds the rule.)
     bs = engine.cache_config.block_size
     pages = lambda ctx: -(-(ctx - 1) // bs)
     assert mixed["walked_tokens"] == bs * (
         sum(pages(c) for n in prompt_lens for c in range(1, n + 1))
         + sum(pages(n + j) for n in prompt_lens
               for j in range(1, MAX_TOKENS)))
+    tiles = snap["mixed_tile_rows"]
+    assert tiles == {"one": mixed["row_steps"], "many": 0}
     # the serial step() fetches each step before it dispatches the next
     assert mixed["busy"] == 0 or engine.pipeline_enabled
 

@@ -185,6 +185,46 @@ def test_window_kernels_compile(compile_for_chip, rows, windowed):
     assert ("_paged_attention_call_window" in text) == windowed
 
 
+# The `mixed` chunk step of the cells that run the ragged kernel, with the
+# rows' runs handed in: (table width, query heads, KV heads, pool, window).
+# (The A.X-K1 cell's prompt steps run the latent kernel.)
+TILED_CELLS = {
+    "mistral7b": (64, 32, 8, jnp.float8_e5m2, False),
+    "dsmoe16b": (64, 16, 16, jnp.float8_e5m2, False),
+    "lfm2moe-heads-of-64-stored-128": (64, 32, 8, jnp.bfloat16, False),
+    "smallthinker-full": (512, 28, 4, jnp.bfloat16, False),
+    "smallthinker-window": (512, 28, 4, jnp.bfloat16, True),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(TILED_CELLS))
+def test_tiled_ragged_kernel_compiles_at_the_cells_shapes(compile_for_chip,
+                                                          cell):
+    """512 rows with `run_starts`: one program whatever the runs are (the
+    split into tiles is data), named as the attention shares read it."""
+    from intellillm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    width, hq, hkv, dtype, windowed = TILED_CELLS[cell]
+    rows = 512
+    lo = (((rows, ), jnp.int32), ) if windowed else ()
+
+    def fn(q, k_new, v_new, k_cache, v_cache, slots, tables, ctx, starts,
+           *lo):
+        return ragged_paged_attention(
+            q, k_new, v_new, k_cache, v_cache, slots, tables, ctx,
+            HEAD_DIM ** -0.5, None, *lo, run_starts=starts)
+
+    text = compile_for_chip(
+        fn, ((rows, 1, hq, HEAD_DIM), jnp.bfloat16),
+        ((rows, hkv, HEAD_DIM), dtype), ((rows, hkv, HEAD_DIM), dtype),
+        _pool(dtype, hkv), _pool(dtype, hkv), ((rows, ), jnp.int32),
+        ((rows, width), jnp.int32), ((rows, ), jnp.int32),
+        ((rows, ), jnp.int32), *lo, donate=(3, 4))
+    assert "tpu_custom_call" in text
+    assert ("_ragged_call_window" in text) == windowed
+    assert re.search(r"_ragged_call(?!_window)\b", text) or windowed
+
+
 def test_mixed_seam_selects_the_kernel(compile_for_chip):
     """The seam itself (ops/ragged_attention), told it is on a TPU,
     resolves to the Pallas kernel and not to the reference."""
