@@ -53,6 +53,11 @@ class Stats:
     # Loop gap before this step: previous end_step() -> this begin_step(),
     # None when the engine had no work pending then (or tracing is off).
     step_gap: Optional[float] = None
+    # The device timeline's drain (obs.tracing): seconds each program ran
+    # on the device and seconds the device sat dry under each host phase,
+    # retired since the previous step's drain. Empty when tracing is off.
+    device_busy: Dict[str, float] = field(default_factory=dict)
+    device_idle: Dict[str, float] = field(default_factory=dict)
 
 
 def _fmt_bytes(n: float) -> str:
@@ -131,6 +136,17 @@ class _Metrics:
             labelnames,
             buckets=[0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                      0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5])
+        self.counter_device_busy = Counter(
+            "intellillm_device_busy_seconds_total",
+            "Seconds a step program ran on the device, on the host's "
+            "clock with no profiler (obs.tracing device timeline).",
+            list(labelnames) + ["program"])
+        self.counter_device_idle = Counter(
+            "intellillm_device_idle_seconds_total",
+            "Seconds the device had no dispatched step left to run while "
+            "the engine had work, by the host phase (or loop_gap, "
+            "unannotated) that passed meanwhile (obs.tracing).",
+            list(labelnames) + ["phase"])
 
     @classmethod
     def reset_for_testing(cls) -> None:
@@ -168,7 +184,18 @@ class StatLogger:
         self.step_seconds: float = 0.0
         self.gap_seconds: float = 0.0
         self.num_steps: int = 0
+        self.device_busy_seconds: float = 0.0
+        self.device_idle_seconds: Dict[str, float] = {}
         self.metrics = _Metrics(list(labels.keys())) if _PROMETHEUS else None
+        if self.metrics is not None:
+            # A window's delta must find every series at its start.
+            from intellillm_tpu.obs.kernels import KNOWN_PROGRAMS
+            from intellillm_tpu.obs.tracing import IDLE_PHASES
+            lv = self.labels.values()
+            for program in KNOWN_PROGRAMS:
+                self.metrics.counter_device_busy.labels(*lv, program)
+            for phase in IDLE_PHASES:
+                self.metrics.counter_device_idle.labels(*lv, phase)
 
     def _throughput(self, tracked: List[int], now: float) -> float:
         elapsed = now - self.last_local_log
@@ -201,6 +228,10 @@ class StatLogger:
                 m.histogram_step_time.labels(*lv).observe(stats.step_time)
             if stats.step_gap is not None:
                 m.histogram_step_gap.labels(*lv).observe(stats.step_gap)
+            for program, secs in stats.device_busy.items():
+                m.counter_device_busy.labels(*lv, program).inc(secs)
+            for phase, secs in stats.device_idle.items():
+                m.counter_device_idle.labels(*lv, phase).inc(secs)
 
         self.num_prompt_tokens.append(stats.num_prompt_tokens)
         self.num_generation_tokens.append(stats.num_generation_tokens)
@@ -211,6 +242,10 @@ class StatLogger:
             self.step_seconds += stats.step_time
             self.gap_seconds += stats.step_gap or 0.0
             self.num_steps += 1
+        self.device_busy_seconds += sum(stats.device_busy.values())
+        for phase, secs in stats.device_idle.items():
+            self.device_idle_seconds[phase] = (
+                self.device_idle_seconds.get(phase, 0.0) + secs)
 
         if stats.now - self.last_local_log > self.local_interval:
             prefill_tps, decode_tps, mfu_str, pad_str = \
@@ -249,11 +284,21 @@ class StatLogger:
                     p, self.phase_seconds[p] / self.num_steps * 1e3)
                     for p in ordered]
                 parts.append("other %.1fms" % (other / self.num_steps * 1e3))
+                idle = sum(self.device_idle_seconds.values())
+                device = ""
+                if period > 0.0 and (idle or self.device_busy_seconds):
+                    device = "; dev busy %.1f%% idle %.1f%%" % (
+                        self.device_busy_seconds / period * 100,
+                        idle / period * 100)
+                    if idle:
+                        device += " (%s)" % max(
+                            self.device_idle_seconds,
+                            key=self.device_idle_seconds.get)
                 logger.info("Step breakdown over %d steps (avg/step): %s; "
-                            "step %.1fms + loop gap %.1fms",
+                            "step %.1fms + loop gap %.1fms%s",
                             self.num_steps, ", ".join(parts),
                             self.step_seconds / self.num_steps * 1e3,
-                            self.gap_seconds / self.num_steps * 1e3)
+                            self.gap_seconds / self.num_steps * 1e3, device)
             self._log_slo_summary()
             self.num_prompt_tokens = []
             self.num_generation_tokens = []
@@ -261,6 +306,8 @@ class StatLogger:
             self.step_seconds = 0.0
             self.gap_seconds = 0.0
             self.num_steps = 0
+            self.device_busy_seconds = 0.0
+            self.device_idle_seconds = {}
             self.last_local_log = stats.now
 
     def _efficiency_interval(self, now: float):

@@ -110,7 +110,8 @@ from intellillm_tpu.obs import (EVENTS, explain_request, get_alert_manager,
                                 get_efficiency_tracker,
                                 get_flight_recorder, get_kernel_ledger,
                                 get_metrics_history, get_slo_tracker,
-                                get_watchdog, get_workload_log)
+                                get_step_tracer, get_watchdog,
+                                get_workload_log)
 from intellillm_tpu.prediction import get_prediction_service
 from intellillm_tpu.worker.spec_decode.metrics import get_spec_stats
 
@@ -328,6 +329,9 @@ def add_debug_routes(app: web.Application,
                 top_n=4, include_buckets=False),
             # Compact: the per-executable table lives at /debug/kernels.
             "kernels": get_kernel_ledger().health_block(),
+            # Device seconds by program and dry seconds by host phase
+            # since start, profiler off (obs/tracing.py).
+            "device_clock": get_step_tracer().device_totals(),
             "live_requests": len(get_flight_recorder().live_request_ids()),
             # Fleet contention ledger: deferred seconds by cause +
             # decision counts (per-request decomposition at
@@ -455,20 +459,40 @@ def add_debug_routes(app: web.Application,
             shutil.rmtree(tmpdir, ignore_errors=True)
             return web.json_response(
                 {"error": "a trace is already running"}, status=409)
+        # Whole steps on the program's own clock (obs/tracing.py's device
+        # timeline, read at step boundaries), to hold against the trace's
+        # account: the two windows differ by up to a step at each end.
+        def clock_now():
+            programs = get_efficiency_tracker().snapshot(
+                top_n=0, include_buckets=False)["programs"]
+            return {**get_step_tracer().device_totals(),
+                    "dispatches": {name: c["idle"] + c["busy"]
+                                   for name, c in programs.items()}}
+        clock_start = clock_now()
         loop = asyncio.get_event_loop()
         try:
             observed = await loop.run_in_executor(
                 None, wait_for_steps, ledger, steps)
+            clock_end = clock_now()
             await loop.run_in_executor(None, engine.stop_profile)
             reduced = await loop.run_in_executor(None, capture_profile,
                                                  tmpdir)
         finally:
             shutil.rmtree(tmpdir, ignore_errors=True)
         block = ledger.merge_profile(reduced, steps=observed, top=top)
+        clock = {key: {name: v - clock_start[key].get(name, 0)
+                       for name, v in clock_end[key].items()}
+                 for key in ("busy_by_program", "idle_by_phase",
+                             "dispatches")}
+        # Busy and idle tile the loop's period: the seconds between the
+        # two step boundaries.
+        clock["window_s"] = (sum(clock["busy_by_program"].values())
+                             + sum(clock["idle_by_phase"].values()))
         return web.json_response({
             "steps_requested": steps,
             "steps_observed": observed,
             "profile": block,
+            "clock": clock,
         })
 
     app.router.add_get("/metrics", metrics)

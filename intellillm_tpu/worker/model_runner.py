@@ -147,13 +147,13 @@ class InflightStep:
             # a 1-token prompt's final chunk) — only finals to assemble.
             host_plp = None
             if plp_dev is not None:
-                with r._tracer.span("fetch_wait"):
+                with r._tracer.span("fetch_wait", waits_on=self.packed):
                     # lint: allow(host-sync) reason=the designed single D2H point for prompt logprobs: the panel must reach the host to be attached to request output
                     host_plp = np.asarray(plp_dev)
             r._attach_prompt_logprobs(host_plp, plp_k, jobs, finals)
         # The host waits for the device here and nowhere else in the step:
         # its own phase, so that `sample` is host work only.
-        with r._tracer.span("fetch_wait"):
+        with r._tracer.span("fetch_wait", waits_on=self.packed):
             # lint: allow(host-sync) reason=the one intentional fetch per step: sampled ids must cross to the host here so the engine can emit tokens; everything upstream stays async
             packed = np.array(self.packed) if self.proc else np.asarray(
                 self.packed)
@@ -232,9 +232,6 @@ class ModelRunner:
         self._compile_tracker = get_compile_tracker()
         self._efficiency = get_efficiency_tracker()
         self._kernel_ledger = get_kernel_ledger()
-        # Output of the newest dispatch: XLA runs programs in order, so
-        # its readiness says whether the device still has work queued.
-        self._last_packed = None
         from intellillm_tpu.obs import get_numerics_tracker
         self._numerics = get_numerics_tracker()
 
@@ -419,12 +416,6 @@ class ModelRunner:
         expert layers, if the model has any."""
         if self._has_expert_layers:
             self._efficiency.record_moe_ffn_rows(rows, substeps)
-
-    def _device_idle(self) -> bool:
-        """Whether nothing dispatched earlier is still running on the
-        device: one non-blocking readiness query, never a wait."""
-        last = self._last_packed
-        return last is None or last.is_ready()
 
     def _guarded_call(self, program, key, fn, /, *args, **kwargs):
         """Every jitted dispatch goes through here: compile tracking
@@ -1185,7 +1176,7 @@ class ModelRunner:
         fetched = None
         num_stats_dev = None
         program = "mixed" if num_steps == 1 else "decode_fused"
-        device_idle = self._device_idle()
+        device_idle = self._tracer.device_dry()
         if num_steps == 1:
             # Numerics sentinels (obs/numerics.py): opt-in extra device
             # output. When OFF the call binds exactly as pre-sentinel
@@ -1217,6 +1208,7 @@ class ModelRunner:
                     place(fetch_indices) if fetch_indices is not None
                     else None, **common, **numerics_kwargs, **state_kwargs,
                     **self.run_start_kwargs((), padded_n))
+                self._tracer.note_dispatch(program, result[0], bucket)
             result = list(result)
             packed = result.pop(0)
             fetched = result.pop(0) if proc_rows else None
@@ -1236,7 +1228,7 @@ class ModelRunner:
                     program, bucket, self._jit_decode,
                     *decode_args, num_steps=num_steps, **common,
                     **state_kwargs)
-        self._last_packed = packed
+                self._tracer.note_dispatch(program, packed, bucket)
         t1 = t2 = num_steps
 
         # Each substep computes one token per row, pad rows included; a
@@ -1498,7 +1490,7 @@ class ModelRunner:
                   tuple(sorted(common.items())))
         if num_on:
             bucket = bucket + ("numerics", )
-        device_idle = self._device_idle()
+        device_idle = self._tracer.device_dry()
         with self._tracer.span("execute"):
             result = self._guarded_call(
                 "mixed", bucket, self._jit_decode_single,
@@ -1510,13 +1502,13 @@ class ModelRunner:
                 place(plp_targets) if plp_k else None,
                 num_samples=num_samples, plp_k=plp_k, **common,
                 **numerics_kwargs, **state_kwargs)
+            self._tracer.note_dispatch("mixed", result[0], bucket)
         result = list(result)
         packed = result.pop(0)
         plp_dev = result.pop(0) if plp_k else None
         fetched = result.pop(0) if proc_rows else None
         num_stats_dev = result.pop(0) if num_on else None
         new_caches = result.pop(0)
-        self._last_packed = packed
 
         # Per-phase efficiency attribution: each real token is counted
         # exactly once under its own phase; the flat batch's bucket
@@ -1634,7 +1626,7 @@ class ModelRunner:
         # the join arrays are always passed, their shapes follow `b`.
         bucket = (b, w, prev_t1, num_steps, lora_state is not None,
                   tuple(sorted(flags.items())))
-        device_idle = self._device_idle()
+        device_idle = self._tracer.device_dry()
         with self._tracer.span("execute"):
             packed, new_caches = self._guarded_call(
                 "decode_cont", bucket, self._jit_decode_cont,
@@ -1643,7 +1635,7 @@ class ModelRunner:
                 place(ctx), *sampling_args, lora_state,
                 prev_t1=prev_t1, num_steps=num_steps, **flags,
                 **state_kwargs)
-        self._last_packed = packed
+            self._tracer.note_dispatch("decode_cont", packed, bucket)
 
         live_rows = len(cont.rows)
         walked, attended = self._decode_counts(ctx.astype(np.int64),
@@ -1721,6 +1713,8 @@ class ModelRunner:
                 place(arrays["positions"]), place(arrays["block_tables"]),
                 place(arrays["context_lens"]), *sampling_args, lora_state,
                 num_steps=num_steps, **flags)
+            self._tracer.note_dispatch("decode_teacher", packed,
+                                        bucket)
         self._efficiency.record_dispatch(
             "decode", eff_info["real_rows"], padded_n,
             real_tokens=eff_info["real_rows"] * num_steps,

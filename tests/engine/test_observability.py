@@ -9,7 +9,8 @@ obs primitives in isolation (tests/obs/ covers those).
 import pytest
 
 from intellillm_tpu import LLM, SamplingParams
-from intellillm_tpu.engine.metrics import _Metrics, _PROMETHEUS
+from intellillm_tpu.engine import metrics as metrics_mod
+from intellillm_tpu.engine.metrics import _PROMETHEUS
 from intellillm_tpu.obs import (get_compile_tracker, get_flight_recorder,
                                 get_slo_tracker, get_step_tracer)
 
@@ -22,10 +23,12 @@ def fresh_obs():
     get_compile_tracker().reset_for_testing()
     get_flight_recorder().reset_for_testing()
     get_slo_tracker().reset_for_testing()
-    _Metrics.reset_for_testing()
+    # (looked up now: tests/obs/test_metrics_no_prometheus.py reloads the
+    # module, and the class imported before that is not the engine's)
+    metrics_mod._Metrics.reset_for_testing()
     yield
     get_slo_tracker().reset_for_testing()
-    _Metrics.reset_for_testing()
+    metrics_mod._Metrics.reset_for_testing()
 
 
 def _registry_value(name: str, label_filter=None) -> float:
@@ -136,6 +139,57 @@ def test_async_engine_accounts_for_the_whole_loop_period(tiny_opt_dir,
     for phase in ("fetch_wait", "process_outputs", "stats", "stream_out",
                   "admit", "handoff", "handback"):
         assert by_phase[phase] > 0.0, f"{phase} never recorded"
+
+
+@pytest.mark.skipif(not _PROMETHEUS, reason="needs prometheus_client")
+def test_engine_run_exports_the_device_timeline(tiny_opt_dir, fresh_obs):
+    """A real run through the runner's dispatch sites: every program's
+    device seconds and the dry seconds by phase reach /metrics and tile
+    the loop's period."""
+    from intellillm_tpu.obs.tracing import IDLE_PHASES
+
+    # (the efficiency tracker's counters outlive an engine: a delta)
+    dispatched_before = _registry_value("intellillm_dispatch_total")
+    llm = LLM(model=tiny_opt_dir, dtype="float32",
+              num_device_blocks_override=128, max_model_len=128,
+              max_num_seqs=8, max_paddings=512, swap_space=0.01,
+              disable_log_stats=False)
+    engine = llm.llm_engine
+    # every child exists at 0 before the first step
+    assert _registry_value("intellillm_device_busy_seconds_total") == 0.0
+    from prometheus_client import REGISTRY
+    phases = {s.labels["phase"] for m in REGISTRY.collect()
+              for s in m.samples
+              if s.name == "intellillm_device_idle_seconds_total"}
+    assert phases == set(IDLE_PHASES)
+
+    params = SamplingParams(temperature=0.0, max_tokens=24, ignore_eos=True)
+    for i, prompt in enumerate(["hello my name is",
+                                "the capital of france is"]):
+        engine.add_request(str(i), prompt, params)
+    llm._run_engine(use_tqdm=False)
+
+    busy = _registry_value("intellillm_device_busy_seconds_total")
+    idle = _registry_value("intellillm_device_idle_seconds_total")
+    period = (_registry_value("intellillm_step_time_seconds_sum")
+              + _registry_value("intellillm_step_gap_seconds_sum"))
+    for program in ("mixed", "decode_fused", "decode_cont"):
+        assert _registry_value("intellillm_device_busy_seconds_total",
+                               {"program": program}) > 0.0, program
+    # The device was dry at least until the first dispatch returned.
+    assert _registry_value("intellillm_device_idle_seconds_total",
+                           {"phase": "execute"}) > 0.0
+    # Busy and idle tile the loop's period: every step but the last was
+    # retired inside it, and no second is counted twice. (The arithmetic
+    # is held exactly, on a scripted clock, in tests/obs/test_tracing.py.)
+    assert 0.5 * period < busy + idle <= period * (1 + 1e-6)
+    totals = get_step_tracer().device_totals()
+    assert sum(totals["busy_by_program"].values()) == pytest.approx(busy)
+    assert sum(totals["idle_by_phase"].values()) == pytest.approx(idle)
+    # the tracer was told of every dispatch the efficiency tracker counted
+    assert _registry_value("intellillm_dispatch_total") > dispatched_before
+    # nothing is left behind once the engine waits for traffic
+    assert not get_step_tracer()._pending
 
 
 def test_compile_counters_once_per_bucket(tiny_opt_dir, fresh_obs):

@@ -334,6 +334,104 @@ def test_profiler_capture_runs_against_a_fake_engine(monkeypatch, tmp_path):
         ledger.reset_for_testing()
 
 
+def test_profiler_capture_returns_the_programs_own_clock_beside_the_traces(
+        monkeypatch):
+    """Two clocks, one capture: a loop of annotated steps runs (on the
+    CPU backend) through the process's tracer while the endpoint, through
+    the real `LLMEngine.start_profile`, traces some of them. The `clock`
+    block is the device timeline's account of those steps; its busy + dry
+    seconds tile its window, which is the trace's."""
+    import threading
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    from aiohttp import web
+
+    from intellillm_tpu.engine.llm_engine import LLMEngine
+    from intellillm_tpu.entrypoints.debug_routes import add_debug_routes
+    from intellillm_tpu.obs import (get_efficiency_tracker,
+                                    get_kernel_ledger, get_step_tracer)
+
+    monkeypatch.setenv("INTELLILLM_PROFILER_CAPTURE_TIMEOUT_S", "20")
+    ledger = get_kernel_ledger()
+    ledger.reset_for_testing()
+    efficiency = get_efficiency_tracker()
+    tracer = get_step_tracer()
+    monkeypatch.setattr(tracer, "enabled", True)
+    tracer.reset_for_testing()
+    fn = jax.jit(lambda x: (x @ x).sum())
+    x = jnp.ones((384, 384))
+    fn(x).block_until_ready()
+    stop = threading.Event()
+
+    def loop():
+        while not stop.is_set():
+            tracer.begin_step()
+            with tracer.span("schedule"):
+                time.sleep(0.004)
+            dry = tracer.device_dry()
+            with tracer.span("execute"):
+                y = fn(x)
+                tracer.note_dispatch("mixed", y)
+            efficiency.record_dispatch(
+                "decode", 1, 1, real_tokens=1, padded_tokens=1,
+                program="mixed", device_idle=dry)
+            with tracer.span("sample"):
+                with tracer.span("fetch_wait", waits_on=y):
+                    y.block_until_ready()
+            step_time = tracer.end_step(work_pending=True)[1]
+            ledger.record_step(step_time)
+
+    engine = object.__new__(LLMEngine)
+    engine._tracer = tracer
+    app = web.Application()
+    engines = [engine]
+    add_debug_routes(app, lambda: engines[0], enable_profiling=True)
+    thread = threading.Thread(target=loop)
+    thread.start()
+    try:
+        async def scenario(client):
+            resp = await client.post("/debug/profiler/capture",
+                                     params={"steps": "64"})
+            assert resp.status == 200
+            data = await resp.json()
+            assert data["steps_observed"] >= 64
+            profile, clock = data["profile"], data["clock"]
+            assert profile["annotations"]["execute"] >= 64
+            assert clock["dispatches"]["mixed"] >= 64
+            busy = sum(clock["busy_by_program"].values())
+            idle = sum(clock["idle_by_phase"].values())
+            assert set(clock["busy_by_program"]) == {"mixed"} and busy > 0
+            # the timeline is read at step boundaries, and busy + idle
+            # is the time between them; the trace's window is the same to
+            # a step at either end
+            assert busy + idle == clock["window_s"]
+            assert clock["window_s"] == pytest.approx(profile["window_s"],
+                                                      rel=0.1)
+            # both accounts name the sleep under `schedule` as where the
+            # device waits
+            by_clock, by_trace = clock["idle_by_phase"], profile[
+                "idle_by_phase"]
+            assert max(by_clock, key=by_clock.get) == "schedule"
+            assert max(by_trace, key=by_trace.get) == "schedule"
+            # /health/detail shows the totals since start (the blocks
+            # that need no engine come before it asks for one)
+            engines[0] = None
+            resp = await client.get("/health/detail")
+            totals = (await resp.json())["device_clock"]
+            assert totals["busy_by_program"]["mixed"] >= busy
+            assert totals["idle_by_phase"]["schedule"] >= by_clock[
+                "schedule"]
+
+        _run(app, scenario)
+    finally:
+        stop.set()
+        thread.join()
+        tracer.reset_for_testing()
+        ledger.reset_for_testing()
+
+
 @pytest.mark.skipif(not _PROMETHEUS, reason="needs prometheus_client")
 def test_both_servers_serve_metrics_from_shared_handler():
     """/metrics comes from ONE handler in debug_routes — the demo server

@@ -337,14 +337,17 @@ def test_reduce_profile_names_the_return_crossing_and_needs_device_ops():
 
 @pytest.fixture(scope="module")
 def cpu_trace_dir(tmp_path_factory):
-    """A real jax.profiler trace on the CPU backend: three 'steps', each
-    annotated by a StepTracer under the profile flag — the loop thread
-    admits, another thread schedules, dispatches and waits."""
+    """A real jax.profiler trace on the CPU backend, started and stopped
+    through `LLMEngine.start_profile` / `stop_profile` (the one place the
+    server's traces start): three 'steps', each annotated by a StepTracer
+    under the profile flag — the loop thread admits, another thread
+    schedules, dispatches and waits."""
     import threading
 
     import jax
     import jax.numpy as jnp
 
+    from intellillm_tpu.engine.llm_engine import LLMEngine
     from intellillm_tpu.obs.tracing import StepTracer
 
     logdir = str(tmp_path_factory.mktemp("cpu-trace"))
@@ -366,8 +369,9 @@ def cpu_trace_dir(tmp_path_factory):
         tracer.end_step(work_pending=True)
         tracer.begin_crossing()
 
-    jax.profiler.start_trace(logdir)
-    tracer.set_profiling(True)
+    engine = object.__new__(LLMEngine)
+    engine._tracer = tracer
+    assert engine.start_profile(logdir) == logdir
     try:
         for _ in range(3):
             with tracer.span("admit"):
@@ -380,8 +384,7 @@ def cpu_trace_dir(tmp_path_factory):
             with tracer.span("stream_out"):
                 time.sleep(0.001)
     finally:
-        tracer.set_profiling(False)
-        jax.profiler.stop_trace()
+        engine.stop_profile()
     return logdir
 
 
@@ -416,6 +419,26 @@ def test_cpu_profile_carries_the_annotations_and_every_gap_has_a_phase(
     # and the two thread crossings are named, not left unannotated
     assert idle.get("handoff", 0.0) > 0 and idle.get("handback", 0.0) > 0
     assert capture_profile(cpu_trace_dir)["busy_s"] == r["busy_s"]
+
+
+def test_a_trace_started_by_the_engine_has_no_python_tracer_events(
+        cpu_trace_dir):
+    """`start_profile` turns jax's Python tracer off (under it the host
+    runs 1.5-3x slower, and a trace shows idle the program does not
+    have) and keeps the host tracer that records the annotations. Read
+    raw, not through `load_profile`, which would filter them out."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(cpu_trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    names = [ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events]
+    assert sum(n.startswith("intellillm/") for n in names) >= 3 * 6
+    # the Python tracer names a frame `$<file>:<line> <function>`
+    assert not [n for n in names if n.startswith("$")][:5]
+    assert not [n for n in names if ".py:" in n][:5]
 
 
 def test_load_profile_missing_or_corrupt_is_none(tmp_path):

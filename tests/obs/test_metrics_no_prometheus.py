@@ -391,3 +391,39 @@ def test_spec_acceptance_rate_optional():
     assert stats.spec_acceptance_rate is None
     assert stats.step_phase_times == {}
     assert stats.step_time == 0.0
+
+
+@pytest.mark.parametrize("busy, idle, field", [
+    ({"decode_cont": 0.006}, {"prepare_inputs": 0.0007, "sample": 0.0003},
+     "dev busy 60.0% idle 10.0% (prepare_inputs)"),
+    ({"mixed": 0.01}, {}, "dev busy 100.0% idle 0.0%"),
+    ({}, {}, None),             # tracing off: the line is as it was
+])
+def test_step_breakdown_line_gains_the_device_timeline(monkeypatch, busy,
+                                                       idle, field):
+    """`dev busy a% idle b% (top phase)`: the timeline's seconds over the
+    interval's period (step time + loop gap), and the phase under which
+    most of the dry time passed."""
+    lines = []
+    monkeypatch.setattr(metrics_mod.logger, "info",
+                        lambda msg, *args: lines.append(msg % args))
+    stat_logger = metrics_mod.StatLogger(local_interval=0.0,
+                                         labels={"model_name": "m"})
+    stat_logger.last_local_log = 999.0
+    stats = _stats(metrics_mod, now=1000.0)
+    stats.step_gap = 0.003          # period 10 ms
+    stats.device_busy, stats.device_idle = busy, idle
+    try:
+        stat_logger.log(stats)
+    finally:
+        if metrics_mod._PROMETHEUS:
+            metrics_mod._Metrics.reset_for_testing()
+    line, = [ln for ln in lines if "Step breakdown" in ln]
+    if field is None:
+        assert "dev busy" not in line
+        assert line.endswith("step 7.0ms + loop gap 3.0ms")
+    else:
+        assert line.endswith("step 7.0ms + loop gap 3.0ms; " + field)
+    # the interval's accumulators start again
+    assert stat_logger.device_busy_seconds == 0.0
+    assert stat_logger.device_idle_seconds == {}
