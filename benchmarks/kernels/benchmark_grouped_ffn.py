@@ -1,11 +1,15 @@
 """Device time a call of the dense and the grouped expert FFN, side by side.
 
-Times `layers/moe.py` `moe_ffn_dense` and `moe_ffn_grouped` at the three
+Times `layers/moe.py` `moe_ffn_dense` and `moe_ffn_grouped` at the four
 MoE configurations' widths and routing (DeepSeek-MoE-16B, the A.X-K1 share
-of 12 held experts, LFM2-8B-A1B) at 128 / 256 / 384 / 512 rows: one expert
-layer-step, routing included, chained `--layers` times in one program. The
-grouped form is first held to the dense one on the same input. `moe_ffn`'s
-rule (`DENSE_ROWS`) is set from this table. `--root DIR` imports the
+of 12 held experts, LFM2-8B-A1B, SmallThinker-21BA3B) at 128 / 256 / 384 /
+512 rows (`--rows 1,4,8,16,32,64,128` for the few-row side of the rule):
+one expert layer-step, routing included, chained `--layers` times in one
+program. The grouped form is first held to the dense one on the same
+input; beside the times stand the held experts the first layer-step's rows
+chose (`experts_chosen`: the grouped kernels' visits where the sorted rows
+fit one tile) and the bytes of expert weights either form reads. `moe_ffn`'s
+rule (`DENSE_ROWS`, `CHOSEN_SHARE_CUT`) is set from this table. `--root DIR` imports the
 package from another checkout (the parent's, unpacked beside this one), so
 one chip call times both:
 
@@ -33,7 +37,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 CHECKOUT = os.path.join(HERE, "..", "..")
 
 # name -> (held experts, router width, hidden, expert inner, top-k,
-# Routing settings) as the cells run them.
+# Routing settings[, gate activation]) as the cells run them.
 CONFIGS = {
     "dsmoe16b": (64, 64, 2048, 1408, 6, dict(renormalize=False)),
     "axk1-ep16": (12, 192, 7168, 2048, 8,
@@ -41,6 +45,7 @@ CONFIGS = {
                        scale=2.5, held_start=0)),
     "lfm2moe": (32, 32, 2048, 1792, 4,
                 dict(scoring="sigmoid", sum_eps=1e-6)),
+    "smallthinker": (64, 64, 2560, 768, 6, {}, "relu"),
 }
 
 
@@ -63,7 +68,8 @@ def measure(args) -> None:
               "device": jax.devices()[0].device_kind,
               "platform": jax.devices()[0].platform, "table": []}
     for name in args.configs.split(","):
-        n, n_router, d, i, top_k, settings = CONFIGS[name]
+        n, n_router, d, i, top_k, settings, *activation = CONFIGS[name]
+        activation = dict(activation=activation[0]) if activation else {}
         if args.tiny:
             d, i = 128, 128
         routing = moe.Routing(**settings)
@@ -83,7 +89,8 @@ def measure(args) -> None:
             # constants of a 1 GB executable.
             def program(x, *weights):
                 def layer(_, x):
-                    out = form(x, *weights, top_k, routing=routing)
+                    out = form(x, *weights, top_k, routing=routing,
+                               **activation)
                     # The next layer-step depends on this one, routes its
                     # rows a little differently and keeps their size.
                     return (x + 0.01 * out).astype(x.dtype)
@@ -95,9 +102,18 @@ def measure(args) -> None:
                                   (rows, d), jnp.float32).astype(jnp.bfloat16)
             one = {f: jax.block_until_ready(jax.jit(
                 lambda x, *weights, f=f: forms[f](
-                    x, *weights, top_k, routing=routing))(x, *weights))
+                    x, *weights, top_k, routing=routing,
+                    **activation))(x, *weights))
                 .astype(jnp.float32) for f in forms}
-            line = {"config": name, "rows": rows,
+            local = (moe._route(x, gate_w, top_k, routing)[1]
+                     - routing.held_start)
+            chosen = int(jnp.unique(
+                local[(local >= 0) & (local < n)]).size)
+            expert_bytes = 3 * i * d * w1.dtype.itemsize
+            line = {"config": name, "rows": rows, "experts_held": n,
+                    "experts_chosen": chosen,
+                    "dense_weight_bytes": n * expert_bytes,
+                    "grouped_weight_bytes": chosen * expert_bytes,
                     "grouped_max_abs_diff": float(jnp.max(jnp.abs(
                         one["grouped"] - one["dense"]))),
                     "dense_max_abs": float(jnp.max(jnp.abs(one["dense"])))}

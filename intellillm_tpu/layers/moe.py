@@ -21,9 +21,13 @@ float32 accumulation and combine):
   weight is applied to the float32 output row and a token's rows summed
   in float32.
 
-`moe_ffn` takes the dense form for rows that fit one dense chunk and the
-grouped form for more (`DENSE_ROWS`): a second chunk reads every stack a
-second time and multiplies N/K times the routed rows.
+`moe_ffn` picks the form by the shapes it is handed (`grouped_rows`): the
+grouped form for more rows than one dense chunk holds (`DENSE_ROWS`: a
+second chunk reads every stack a second time and multiplies N/K times the
+routed rows), and for so few rows that a good share of the experts is
+chosen by none of them (`CHOSEN_SHARE_CUT`: the grouped kernels never copy
+the weights of an expert without a row, the dense form reads every stack
+whole); the dense form between the two.
 
 Stored layout of the expert stacks: all three are `[N, I, D]` (experts,
 inner, hidden), so `w1` (gate) and `w3` (up) are the checkpoint's own
@@ -69,11 +73,44 @@ _ROW_TILE = 16
 # 512 rows grouped is ahead by 27 to 44 % at all three.
 DENSE_ROWS = 256
 
+# The expected share of the router's experts that some row of a step
+# chooses, under which a step of up to DENSE_ROWS rows takes the grouped
+# form all the same: the dense form reads every held stack, the grouped
+# kernels only the experts that have a row, at 1.09 to 1.12 times those
+# bytes' time at 819 GB/s plus ~16 us of sort, gather and combine. On the
+# chip (benchmarks/kernels/benchmark_grouped_ffn.py --rows
+# 1,4,8,16,32,64,128; PERF.md section 6, PR 38), us a layer-step dense |
+# grouped (held experts chosen):
+#   64 ReGLU experts of 768 x 2560, top-6: 1 row 1009 | 118 (6), 4 rows
+#     1005 | 289 (17), 8 rows 1005 | 616 (38), 16 rows 1005 | 790 (49),
+#     32 rows 1007 | 992 (61), 64 rows 1010 | 1065, 128 rows 1013 | 1114;
+#   64 experts of 1408 x 2048, top-6: 1 row 1473 | 165, 16 rows 1471 |
+#     1038 (44), 32 rows 1472 | 1470 (62), 128 rows 1480 | 1605;
+#   32 experts of 1792 x 2048, top-4: 8 rows 937 | 668 (22), 16 rows 937 |
+#     814 (27), 32 rows 938 | 962 (32), 128 rows 944 | 1053;
+#   12 held of 192, 2048 x 7168, top-8 in 4 of 8 groups: 16 rows 1415 |
+#     759 (6), 32 rows 1424 | 1212 (10), 64 rows 1433 | 1412 (11), 128
+#     rows 1454 | 1598 (12).
+# The forms cross where ~0.96 of the held experts are in fact chosen
+# (grouped / dense = the share chosen + 0.02 to 0.04); the cut stands under
+# that by what a step's count spreads about its expectation, so that the
+# grouped form is taken where it is ahead by 7 % and more.
+CHOSEN_SHARE_CUT = 0.90
 
-def grouped_rows(t: int) -> bool:
+
+def chosen_share(t: int, top_k: int, router_width: int) -> float:
+    """The share of the router's experts that `t` rows choosing `top_k`
+    of `router_width` each are expected to choose between them, were the
+    choices uniform and independent (a held share sees the same share of
+    its own experts)."""
+    return 1.0 - (1.0 - top_k / router_width) ** t
+
+
+def grouped_rows(t: int, top_k: int, router_width: int) -> bool:
     """Whether a step of `t` rows takes the grouped form: the rule of
-    `moe_ffn`, by the rows alone (the runner's counter asks it too)."""
-    return t > DENSE_ROWS
+    `moe_ffn`, by the shapes alone (the runner's counter asks it too)."""
+    return (t > DENSE_ROWS
+            or chosen_share(t, top_k, router_width) < CHOSEN_SHARE_CUT)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -249,11 +286,15 @@ def moe_ffn_grouped(
     tile: int = ROW_TILE,
     mesh=None,
     activation: str = "silu",
-) -> jnp.ndarray:
+    count_visited: bool = False,
+):
     """`tile`: rows a tile of the grouped kernels' walk (the sorted rows
     are padded to a whole number of them, once, not an expert). `mesh`:
     the runner's device mesh; where it spans chips each runs the kernels
-    on its share of the stacks' inner dimension (`_over_inner_shards`)."""
+    on its share of the stacks' inner dimension (`_over_inner_shards`).
+    `count_visited`: also return how many held experts have a row (i32
+    scalar): the experts whose weights the kernels read, since an expert
+    without a row gets no visit (`visit_metadata`)."""
     t, d = x.shape
     n = w1.shape[0]
     tk = t * top_k
@@ -294,7 +335,10 @@ def moe_ffn_grouped(
     with jax.named_scope("moe_experts"):
         y = _over_inner_shards(experts, mesh, w1.shape[1])(
             xs, w1, w2, w3, counts[:n], place, flat_w, here)
-    return y.astype(x.dtype)
+    y = y.astype(x.dtype)
+    if count_visited:
+        return y, (counts[:n] > 0).sum().astype(jnp.int32)
+    return y
 
 
 _COUNT_BLOCK = 128
@@ -357,7 +401,12 @@ def moe_ffn(
     routed=None,
     mesh=None,
     activation: str = "silu",
-) -> jnp.ndarray:
+    count_visited: bool = False,
+):
+    """The expert FFN in the form `grouped_rows` picks for these shapes.
+    `count_visited`: also return how many held experts the step read (i32
+    scalar): those with a row in the grouped form, all of them in the
+    dense one (intellillm_moe_expert_visits_total)."""
     # All three stacks are [N, I, D] (module docstring): w1/w3 contract on
     # their last axis, so no loop around this call makes XLA re-lay them.
     # QuantMixtral (reference mixtral_quant.py): expert stacks arrive as
@@ -374,9 +423,11 @@ def moe_ffn(
         w2 = dequant_int4_stack(w2, x.dtype)
     if is_quantized(w3):
         w3 = dequant_int4_stack(w3, x.dtype).swapaxes(1, 2)
-    if grouped_rows(x.shape[0]):
+    if grouped_rows(x.shape[0], top_k, gate_w.shape[1]):
         return moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k,
                                routing=routing, routed=routed, mesh=mesh,
-                               activation=activation)
-    return moe_ffn_dense(x, gate_w, w1, w2, w3, top_k, routing=routing,
-                         routed=routed, activation=activation)
+                               activation=activation,
+                               count_visited=count_visited)
+    y = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k, routing=routing,
+                      routed=routed, activation=activation)
+    return (y, jnp.int32(w1.shape[0])) if count_visited else y

@@ -59,6 +59,9 @@ class AXK1ForCausalLM:
 
     supports_lora = False
     supported_quantization = ()
+    # A call returns {"expert_visits": ...} beside its result
+    # (worker/model_runner.py `_call_model`).
+    counts_expert_visits = True
 
     def __init__(self, model_config: ModelConfig) -> None:
         cfg = model_config.hf_config
@@ -101,6 +104,7 @@ class AXK1ForCausalLM:
             scale=float(cfg.routed_scaling_factor),
             held_start=self.ep_rank * self.n_held)
         n_moe = sum(self._is_moe_layer(i) for i in range(self.num_layers))
+        self.experts_held = self.n_held * n_moe
         # A share reports how many of each token's choices it holds
         # (worker/model_runner.py `_pack`); the whole model has nothing
         # to report.
@@ -138,17 +142,21 @@ class AXK1ForCausalLM:
         residual = None
         new_caches = []
         held = None
+        visited = jnp.int32(0)
         for i in range(self.num_layers):
-            h, residual, cache, held_i = self._layer(
+            h, residual, cache, held_i, visited_i = self._layer(
                 params["layers"][i], h, residual, kv_caches[i],
                 attn_metadata, positions)
             new_caches.append(cache)
+            visited += visited_i
             if held_i is not None:
                 held = held_i if held is None else held + held_i
         h, _ = fused_add_rms_norm(h, residual, params["norm"], self.rms_eps)
+        counters = {"expert_visits": jnp.stack(
+            [visited, jnp.int32(self.experts_held)])}
         if self.moe_choices_per_token:
-            return h, new_caches, held
-        return h, new_caches
+            counters["held"] = held
+        return h, new_caches, counters
 
     def _attention(self, lp, h, kv_cache, attn_metadata, positions):
         b, l, _ = h.shape
@@ -186,14 +194,15 @@ class AXK1ForCausalLM:
                                          self.rms_eps)
         if "w1" not in lp:
             h = (self.act(h @ lp["gate"]) * (h @ lp["up"])) @ lp["down"]
-            return h, residual, kv_cache, None
+            return h, residual, kv_cache, None, 0
         flat = h.reshape(b * l, e)
         with jax.named_scope("moe_router"):
             routed = _route(flat, lp["gate_router"], self.top_k,
                             routing=self.routing)
-        out = moe_ffn(flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
-                      self.top_k, routing=self.routing, routed=routed,
-                      mesh=attn_metadata.mesh)
+        out, visited = moe_ffn(
+            flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
+            self.top_k, routing=self.routing, routed=routed,
+            mesh=attn_metadata.mesh, count_visited=True)
         held = None
         if self.moe_choices_per_token:
             held = held_assignments(routed[1], self.n_held,
@@ -202,7 +211,7 @@ class AXK1ForCausalLM:
             with jax.named_scope("moe_shared"):
                 out = out + (self.act(flat @ lp["shared_gate"]) *
                              (flat @ lp["shared_up"])) @ lp["shared_down"]
-        return out.reshape(b, l, e), residual, kv_cache, held
+        return out.reshape(b, l, e), residual, kv_cache, held, visited
 
     def compute_logits(self, params: Params, hidden, lora=None):
         return hidden @ params["lm_head"]

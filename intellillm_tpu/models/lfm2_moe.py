@@ -59,6 +59,9 @@ class Lfm2MoeForCausalLM:
 
     supports_lora = False
     supported_quantization = ()
+    # A call returns {"expert_visits": ...} beside its result
+    # (worker/model_runner.py `_call_model`).
+    counts_expert_visits = True
 
     def __init__(self, model_config: ModelConfig) -> None:
         cfg = model_config.hf_config
@@ -113,14 +116,19 @@ class Lfm2MoeForCausalLM:
         h = params["embed_tokens"][input_ids]
         residual = None
         new_caches = []
+        visited = jnp.int32(0)
         for i in range(self.num_layers):
-            h, residual, cache = self._layer(
+            h, residual, cache, visited_i = self._layer(
                 params["layers"][i], self.layer_kinds[i], h, residual,
                 kv_caches[i], attn_metadata, positions)
             new_caches.append(cache)
+            visited += visited_i
         h, _ = fused_add_rms_norm(h, residual, params["embedding_norm"],
                                   self.eps)
-        return h, new_caches
+        held = self.num_experts * sum(
+            self._is_moe_layer(i) for i in range(self.num_layers))
+        return h, new_caches, {
+            "expert_visits": jnp.stack([visited, jnp.int32(held)])}
 
     def _attention(self, lp, h, kv_cache, attn_metadata, positions):
         b, l, _ = h.shape
@@ -154,16 +162,17 @@ class Lfm2MoeForCausalLM:
                                          self.eps)
         if "w1" not in lp:
             h = (jax.nn.silu(h @ lp["gate"]) * (h @ lp["up"])) @ lp["down"]
-            return h, residual, cache
+            return h, residual, cache, 0
         flat = h.reshape(b * l, e)
         with jax.named_scope("moe_router"):
             routed = _route(flat, lp["gate_router"], self.top_k,
                             routing=self.routing,
                             select_bias=lp.get("expert_bias"))
-        out = moe_ffn(flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
-                      self.top_k, routing=self.routing, routed=routed,
-                      mesh=attn_metadata.mesh)
-        return out.reshape(b, l, e), residual, cache
+        out, visited = moe_ffn(
+            flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
+            self.top_k, routing=self.routing, routed=routed,
+            mesh=attn_metadata.mesh, count_visited=True)
+        return out.reshape(b, l, e), residual, cache, visited
 
     def compute_logits(self, params: Params, hidden, lora=None):
         # Logits leave the matmul in float32 (the sampler works in float32

@@ -54,6 +54,9 @@ class SmallThinkerForCausalLM:
 
     supports_lora = False
     supported_quantization = ()
+    # A call returns {"expert_visits": ...} beside its result
+    # (worker/model_runner.py `_call_model`).
+    counts_expert_visits = True
 
     def __init__(self, model_config: ModelConfig) -> None:
         cfg = model_config.hf_config
@@ -106,13 +109,17 @@ class SmallThinkerForCausalLM:
         h = params["embed_tokens"][input_ids]
         residual = None
         new_caches = []
+        visited = jnp.int32(0)
         for i in range(self.num_layers):
-            h, residual, cache = self._layer(
+            h, residual, cache, visited_i = self._layer(
                 params["layers"][i], i, h, residual, kv_caches[i],
                 attn_metadata, positions)
             new_caches.append(cache)
+            visited += visited_i
         h, _ = fused_add_rms_norm(h, residual, params["norm"], self.eps)
-        return h, new_caches
+        held = self.num_layers * self.num_experts
+        return h, new_caches, {
+            "expert_visits": jnp.stack([visited, jnp.int32(held)])}
 
     def _layer(self, lp, i, h, residual, cache, attn_metadata, positions):
         b, l, e = h.shape
@@ -137,11 +144,11 @@ class SmallThinkerForCausalLM:
         h = out.reshape(b, l, self.num_heads * d) @ lp["o"]
         h, residual = fused_add_rms_norm(h, residual, lp["post_attn_norm"],
                                          self.eps)
-        out = moe_ffn(h.reshape(b * l, e), lp["gate_router"], lp["w1"],
-                      lp["w2"], lp["w3"], self.top_k, routing=self.routing,
-                      routed=routed, mesh=attn_metadata.mesh,
-                      activation="relu")
-        return out.reshape(b, l, e), residual, cache
+        out, visited = moe_ffn(
+            h.reshape(b * l, e), lp["gate_router"], lp["w1"], lp["w2"],
+            lp["w3"], self.top_k, routing=self.routing, routed=routed,
+            mesh=attn_metadata.mesh, activation="relu", count_visited=True)
+        return out.reshape(b, l, e), residual, cache, visited
 
     def compute_logits(self, params: Params, hidden, lora=None):
         # Logits leave the matmul in float32 (models/lfm2_moe.py says why).

@@ -67,6 +67,8 @@ DEVICE_STATES = ("idle", "busy")
 JOIN_VIAS = ("cont", "fresh")
 # The two forms of the expert FFN (layers/moe.py moe_ffn).
 MOE_FFN_PATHS = ("dense", "grouped")
+# Whether a held expert's weights were read by a layer-substep.
+EXPERT_VISITED = ("yes", "no")
 DRAIN_CAUSES = ("memory", "params", "chunked_prefill", "bucket", "idle")
 TOKEN_KINDS = ("real", "pad")
 # What happens to a per-sequence state slot (core/block_manager.py), and
@@ -183,9 +185,19 @@ class _EfficiencyMetrics:
             "Token-rows (pad rows included) x substeps that the dispatched "
             "programs of a model with expert layers sent through each "
             "form of the expert FFN (path: dense = every held expert over "
-            "every row, a step of up to 256 rows; grouped = the routed "
-            "rows only, a step of more), by layers/moe.py's own rule.",
+            "every row; grouped = the routed rows only: a step of more "
+            "than 256 rows, or of so few that a good share of the experts "
+            "is chosen by none), by layers/moe.py's own rule.",
             ["path"])
+        self.counter_moe_expert_visits = Counter(
+            "intellillm_moe_expert_visits_total",
+            "Held experts x expert layers x substeps of the finished steps "
+            "of a model that reports them, by whether the layer-substep "
+            "read the expert's weights (visited: yes = some row chose it, "
+            "or the form was the dense one, which reads every stack; no = "
+            "the grouped form and no row chose it). yes / (yes + no) is "
+            "the share of the held experts' bytes a step read.",
+            ["program", "visited"])
         self.counter_decode_join_rows = Counter(
             "intellillm_decode_join_rows_total",
             "Rows that entered a decode batch, by how (via: cont = took a "
@@ -223,6 +235,9 @@ class _EfficiencyMetrics:
             self.gauge_state_slots.labels(state).set(0)
         for path in MOE_FFN_PATHS:
             self.counter_moe_ffn_rows.labels(path)
+        for program in PROGRAMS:
+            for visited in EXPERT_VISITED:
+                self.counter_moe_expert_visits.labels(program, visited)
         for event in STATE_SLOT_EVENTS:
             self.counter_state_slot_events.labels(event)
         for kind in CACHE_KINDS:
@@ -361,6 +376,9 @@ class EfficiencyTracker:
             for program in PROGRAMS}
         self._decode_joins: Dict[str, int] = dict.fromkeys(JOIN_VIAS, 0)
         self._moe_ffn_rows: Dict[str, int] = dict.fromkeys(MOE_FFN_PATHS, 0)
+        self._moe_expert_visits: Dict[str, Dict[str, int]] = {
+            program: dict.fromkeys(EXPERT_VISITED, 0)
+            for program in PROGRAMS}
         self._mixed_tile_rows: Dict[str, int] = dict.fromkeys(
             RAGGED_TILES, 0)
         self._pipeline_drains: Dict[str, int] = dict.fromkeys(
@@ -553,15 +571,17 @@ class EfficiencyTracker:
             for axis, ratio in fills:
                 m.hist_fill_ratio.labels(phase, axis).observe(ratio)
 
-    def record_moe_ffn_rows(self, rows: int, substeps: int = 1) -> None:
+    def record_moe_ffn_rows(self, rows: int, substeps: int, top_k: int,
+                            router_width: int) -> None:
         """A dispatched program of `rows` token-rows (as padded: what the
         device multiplies) ran its expert layers `substeps` times; the
-        form is the one `layers/moe.py` `moe_ffn` takes at that many
-        rows."""
+        form is the one `layers/moe.py` `moe_ffn` takes at those shapes
+        (rows, and the model's `top_k` of `router_width`)."""
         if not self.enabled:
             return
         from intellillm_tpu.layers.moe import grouped_rows
-        path = "grouped" if grouped_rows(rows) else "dense"
+        path = ("grouped" if grouped_rows(rows, top_k, router_width)
+                else "dense")
         with self._lock:
             if self._warmup_depth > 0:
                 return
@@ -634,6 +654,25 @@ class EfficiencyTracker:
         m.counter_moe_assignments.labels("here").inc(int(here))
         m.counter_moe_assignments.labels("absent").inc(
             max(int(total) - int(here), 0))
+
+    def record_moe_expert_visits(self, program: str, visited: int,
+                                 held: int) -> None:
+        """A finished step of `program` ran `held` (expert, layer,
+        substep) triples over the experts this chip holds and read the
+        weights of `visited` of them. The numbers ride the step's packed
+        device->host fetch (worker/model_runner.py `_pack`)."""
+        if not self.enabled or program not in self._moe_expert_visits:
+            return
+        counts = {"yes": int(visited), "no": max(int(held - visited), 0)}
+        with self._lock:
+            if self._warmup_depth > 0:
+                return
+            for key, n in counts.items():
+                self._moe_expert_visits[program][key] += n
+        if self._metrics is not None:
+            for key, n in counts.items():
+                self._metrics.counter_moe_expert_visits.labels(
+                    program, key).inc(n)
 
     def record_step(self, step_time: float) -> Optional[float]:
         """Engine step boundary: fold the real tokens dispatched since
@@ -736,6 +775,9 @@ class EfficiencyTracker:
                              in self._programs.items()},
                 "decode_join_rows": dict(self._decode_joins),
                 "moe_ffn_rows": dict(self._moe_ffn_rows),
+                "moe_expert_visits": {
+                    program: dict(counts) for program, counts
+                    in self._moe_expert_visits.items()},
                 "mixed_tile_rows": dict(self._mixed_tile_rows),
                 "pipeline_drains": dict(self._pipeline_drains),
                 "co_batched_steps": self._co_batched_steps,

@@ -113,8 +113,9 @@ class InflightStep:
 
     def __init__(self, runner, packed, metas, rows, t1, t2, logprob_k,
                  is_prompt, num_steps, proc=None, mixed_plp=None, emit=None,
-                 numerics=None):
+                 numerics=None, program=""):
         self.runner = runner
+        self.program = program          # the dispatched program's name
         self.packed = packed            # device array (also the cont input)
         self.metas = metas
         self.rows = rows
@@ -159,11 +160,19 @@ class InflightStep:
                 self.packed)
         sampled, sampled_lp, topk_ids, topk_lp = r._unpack(
             packed, self.t1, self.t2, self.logprob_k)
+        # The counter columns `_pack` appended, from the end: the expert
+        # visits of a model that counts them, before them the two of an
+        # expert-parallel share.
+        end = packed.shape[1]
+        if getattr(r.model, "counts_expert_visits", False):
+            end -= 2
+            r._efficiency.record_moe_expert_visits(
+                self.program, int(packed[0, end]), int(packed[0, end + 1]))
         choices = getattr(r.model, "moe_choices_per_token", 0)
         if choices:
-            # The two columns `_pack` appended for an expert-parallel share.
             r._efficiency.record_moe_assignments(
-                int(packed[:, -2].sum()), int(packed[:, -1].sum()) * choices)
+                int(packed[:, end - 2].sum()),
+                int(packed[:, end - 1].sum()) * choices)
         if self.proc:
             proc_rows, fetched, row_params, row_tokens, row_seeds = self.proc
             r._resample_processor_rows(
@@ -223,11 +232,14 @@ class ModelRunner:
         self.parallel_config = parallel_config
         self.mesh = mesh
         self._dp = (mesh.shape.get("data", 1) if mesh is not None else 1)
-        # Whether a program runs expert layers (layers/moe.py moe_ffn): its
-        # rows then count in intellillm_moe_ffn_rows_total{path}.
+        # The router's width where a program runs expert layers
+        # (layers/moe.py moe_ffn), else 0: its rows then count in
+        # intellillm_moe_ffn_rows_total{path}, by the form that rows,
+        # the model's `top_k` and this width give.
         layers = params.get("layers", ()) if isinstance(params, dict) else ()
-        self._has_expert_layers = any(
-            isinstance(lp, dict) and "gate_router" in lp for lp in layers)
+        self._router_width = next(
+            (lp["gate_router"].shape[1] for lp in layers
+             if isinstance(lp, dict) and "gate_router" in lp), 0)
         self._tracer = get_step_tracer()
         self._compile_tracker = get_compile_tracker()
         self._efficiency = get_efficiency_tracker()
@@ -414,8 +426,9 @@ class ModelRunner:
     def _record_moe_ffn_rows(self, rows: int, substeps: int = 1) -> None:
         """`rows` x `substeps` of a dispatched program went through the
         expert layers, if the model has any."""
-        if self._has_expert_layers:
-            self._efficiency.record_moe_ffn_rows(rows, substeps)
+        if self._router_width:
+            self._efficiency.record_moe_ffn_rows(
+                rows, substeps, self.model.top_k, self._router_width)
 
     def _guarded_call(self, program, key, fn, /, *args, **kwargs):
         """Every jitted dispatch goes through here: compile tracking
@@ -445,7 +458,7 @@ class ModelRunner:
     # --- packing helpers --------------------------------------------------
 
     @staticmethod
-    def _pack(sampled, sampled_lp, topk_ids, topk_lp, moe_held=None,
+    def _pack(sampled, sampled_lp, topk_ids, topk_lp, counters=None,
               live=None, substeps=1):
         """[B,T1] i32, [B,T1] f32, [B,T2,Kt] i32, [B,T2,Kt] f32 →
         single [B, 2*T1 + 2*T2*Kt] int32 for a 1-fetch D2H.
@@ -454,11 +467,15 @@ class ModelRunner:
         bitcast to f32 are denormals, which TPU ops flush to zero — the
         reverse direction is safe.
 
-        An expert-parallel share (`moe_held` [B]: a row's expert choices
-        held on this chip, summed over layers and substeps) appends two
-        columns after everything `_unpack` reads: the held choices of
-        live rows and the substeps of live rows (0 for pad rows), so
-        intellillm_moe_assignments_total rides this same fetch.
+        A model's `counters` (`_call_model`), summed over layers and
+        substeps, append columns after everything `_unpack` reads, so
+        they ride this same fetch. "held" [B] (an expert-parallel share:
+        a row's expert choices held on this chip): the held choices of
+        live rows and the substeps of live rows (0 for pad rows), for
+        intellillm_moe_assignments_total. Then "expert_visits" [2] (held
+        experts whose weights were read, held experts, both x layers x
+        substeps), in row 0 of two columns of their own, for
+        intellillm_moe_expert_visits_total.
         """
         b = sampled.shape[0]
         parts = [
@@ -467,9 +484,13 @@ class ModelRunner:
             topk_ids.reshape(b, -1),
             jax.lax.bitcast_convert_type(topk_lp, jnp.int32).reshape(b, -1),
         ]
-        if moe_held is not None:
-            parts += [jnp.where(live, moe_held, 0)[:, None],
+        counters = counters or {}
+        if "held" in counters:
+            parts += [jnp.where(live, counters["held"], 0)[:, None],
                       jnp.where(live, substeps, 0).astype(jnp.int32)[:, None]]
+        if "expert_visits" in counters:
+            parts.append(jnp.zeros((b, 2), jnp.int32).at[0].set(
+                counters["expert_visits"]))
         return jnp.concatenate(parts, axis=-1)
 
     @staticmethod
@@ -493,9 +514,16 @@ class ModelRunner:
         else:
             out = self.model(params, token_ids, positions, kv_caches,
                              attn_metadata, lora=lora)
-        # An expert-parallel share also returns, per token, how many of
-        # its expert choices this chip holds ([B, L] i32); None otherwise.
-        return out if len(out) == 3 else out + (None, )
+        # A model may return counters of the call beside its result, each
+        # summed over its layers: "held" ([B, L] i32, an expert-parallel
+        # share: how many of a token's expert choices this chip holds; L
+        # is 1 in every program) and "expert_visits" ([2] i32: held
+        # experts whose weights a layer read, held experts).
+        hidden, caches, *rest = out
+        counters = dict(rest[0]) if rest else {}
+        if "held" in counters:
+            counters["held"] = counters["held"][:, 0]
+        return hidden, caches, counters
 
     # --- jitted step functions -------------------------------------------
 
@@ -727,7 +755,7 @@ class ModelRunner:
                 # staged, the layer gets its pools then their stages.
                 caches4 = [tuple(pools) + tuple(stage)
                            for pools, stage in zip(cur_caches, stages)]
-                hidden, caches4, held = self._call_model(
+                hidden, caches4, counters = self._call_model(
                     params, cur_tokens[:, None], pos_k[:, None], caches4,
                     meta, lora)
                 stages = [tuple(c[len(c) // 2:]) for c in caches4]
@@ -742,9 +770,7 @@ class ModelRunner:
                     do_minp=do_minp, do_penalties=do_penalties,
                     do_random=do_random)
                 next_tokens = sampled[:, 0]
-                ys = (next_tokens, lp[:, 0], tk_ids, tk_lp)
-                if held is not None:
-                    ys += (held[:, 0], )
+                ys = (next_tokens, lp[:, 0], tk_ids, tk_lp, counters)
                 return (next_tokens, stages), ys
             return substep
 
@@ -806,7 +832,7 @@ class ModelRunner:
                             jnp.swapaxes(lp_k, 0, 1),
                             jnp.swapaxes(tk_ids_k, 0, 1),
                             jnp.swapaxes(tk_lp_k, 0, 1),
-                            moe_held=(ys[4].sum(0) if len(ys) > 4 else None),
+                            counters=jax.tree.map(lambda a: a.sum(0), ys[4]),
                             live=base_ctx > 0, substeps=num_steps)
         return packed, new_caches
 
@@ -859,7 +885,7 @@ class ModelRunner:
             run_starts=run_starts,
             **window_kwargs,
         )
-        hidden, new_caches, held = self._call_model(
+        hidden, new_caches, counters = self._call_model(
             params, token_ids, pos[:, None], kv_caches, meta, lora)
         (sampled, lp, tk_ids, tk_lp, fetched, plp_out,
          num_stats) = self._compute_logits_and_sample(
@@ -873,8 +899,7 @@ class ModelRunner:
             numerics_inject=numerics_inject)
         packed = self._pack(sampled, lp, tk_ids[:, None, :],
                             tk_lp[:, None, :],
-                            moe_held=None if held is None else held[:, 0],
-                            live=context_lens > 0)
+                            counters=counters, live=context_lens > 0)
         extras = ()
         if plp_out is not None:
             extras += (plp_out, )
@@ -1269,7 +1294,7 @@ class ModelRunner:
             st.logprob_k, False, num_steps,
             proc=((proc_rows, fetched, row_params, row_tokens, row_seeds)
                   if proc_rows else None),
-            numerics=num_stats_dev)
+            numerics=num_stats_dev, program=program)
         if num_steps > 1:
             pad = padded_n - len(rows)
             out_lens0 = np.zeros(padded_n, np.int64)
@@ -1553,7 +1578,7 @@ class ModelRunner:
                   if proc_rows else None),
             mixed_plp=((plp_dev, plp_k, plp_jobs, plp_finals)
                        if (plp_jobs or plp_finals) else None),
-            emit=(emit_idx, emit_rows),
+            emit=(emit_idx, emit_rows), program="mixed",
             numerics=num_stats_dev)
         if defer_fetch:
             return step, new_caches
@@ -1655,7 +1680,8 @@ class ModelRunner:
 
         step = InflightStep(self, packed, cont.metas, cont.rows, num_steps,
                             num_steps, st.logprob_k, False, num_steps,
-                            emit=(cont.slots, cont.rows))
+                            emit=(cont.slots, cont.rows),
+                            program="decode_cont")
         step.cont_state = cont
         if defer_fetch:
             return step, new_caches

@@ -235,9 +235,9 @@ def test_moe_matches_hf_mixtral_block():
 @pytest.mark.parametrize("n,n_router,top_k", [(8, 8, 2), (12, 192, 8),
                                               (32, 32, 4), (64, 64, 6)])
 def test_dispatcher_picks_paths(monkeypatch, n, n_router, top_k, rows, path):
-    """The rule is the rows alone: what fits one dense chunk stays dense,
-    more goes grouped, whatever the experts held or routed over; and
-    `moe_ffn` gives what the dense form gives on either side."""
+    """From 128 rows on every expert is chosen by some row at these
+    routings: what fits one dense chunk stays dense, more goes grouped;
+    and `moe_ffn` gives what the dense form gives on either side."""
     took = []
     for name in ("dense", "grouped"):
         def spy(*a, name=name, form=getattr(moe, "moe_ffn_" + name), **kw):
@@ -252,7 +252,7 @@ def test_dispatcher_picks_paths(monkeypatch, n, n_router, top_k, rows, path):
                           jnp.float32)
     out = moe.moe_ffn(x, gate_w, w1, w2, w3, top_k)
     assert took == [path]
-    assert moe.grouped_rows(rows) == (path == "grouped")
+    assert moe.grouped_rows(rows, top_k, n_router) == (path == "grouped")
     ref = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
@@ -276,3 +276,111 @@ def test_grouped_form_over_a_mesh_sums_the_inner_shards():
             *a, top_k, tile=16, mesh=mesh))(x, gate_w, w1, w2, w3)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-5)
+
+
+# (top-k, router width) of the configurations that run `moe_ffn`, and the
+# rows from which a step of up to DENSE_ROWS is dense: below them a good
+# share of the experts is chosen by no row (CHOSEN_SHARE_CUT).
+RULE_CONFIGS = {"dsmoe16b": (6, 64, 32), "smallthinker": (6, 64, 32),
+                "lfm2moe": (4, 32, 32), "axk1-share": (8, 192, 64),
+                "mixtral": (2, 8, 16)}
+
+
+@pytest.mark.parametrize("rows", [1, 4, 8, 16, 32, 64, 128, 256, 512])
+@pytest.mark.parametrize("config", sorted(RULE_CONFIGS))
+def test_the_rule_as_a_table(config, rows):
+    """`grouped_rows` at the decode and `mixed` buckets the cells reach and
+    at a lone request's rows: grouped under `dense_from` rows (16 decode
+    rows of top-6 of 64 are expected to choose 0.79 of the experts, 16 of
+    top-4 of 32 0.88, 32 of top-8 of 192 0.74, 32 of top-6 of 64 0.96) and
+    over 256."""
+    top_k, width, dense_from = RULE_CONFIGS[config]
+    assert moe.grouped_rows(rows, top_k, width) == (
+        rows < dense_from or rows > moe.DENSE_ROWS)
+    share = moe.chosen_share(rows, top_k, width)
+    assert 0.0 < share <= 1.0
+    if rows <= moe.DENSE_ROWS:
+        assert (share < moe.CHOSEN_SHARE_CUT) == (rows < dense_from)
+
+
+# (held, router width, top-k, Routing, activation): ReGLU and SiLU
+# experts of 64 top-6, a share of a group-limited router, and 32 top-4
+# chosen with a selection bias.
+FEW_ROW_CELLS = {
+    "reglu-64x6": (64, 64, 6, Routing(), "relu"),
+    "silu-64x6": (64, 64, 6, Routing(renormalize=False), "silu"),
+    "share-12-of-192x8": (12, 192, 8, Routing(
+        scoring="sigmoid", n_group=8, topk_group=4, scale=2.5,
+        held_start=24), "silu"),
+    "bias-32x4": (32, 32, 4, Routing(scoring="sigmoid", select_bias=True,
+                                     sum_eps=1e-6), "silu"),
+}
+
+
+@pytest.mark.parametrize("t", [1, 4, 13, 16])
+@pytest.mark.parametrize("kernel", [False, True], ids=["jnp", "pallas"])
+@pytest.mark.parametrize("cell", sorted(FEW_ROW_CELLS))
+def test_few_rows_equal_the_dense_form(cell, kernel, t, force_pallas):
+    """`moe_ffn` at a decode step's few rows (the grouped form, by the
+    rule) gives what the dense form gives at the same rows, and reads the
+    experts that have a row and no other: the count it hands back is the
+    number of held experts some row chose, which is the kernels' number
+    of visits (the sorted rows are one tile of 128)."""
+    from intellillm_tpu.ops.pallas.grouped_ffn import visit_metadata
+    n, n_router, top_k, routing, activation = FEW_ROW_CELLS[cell]
+    d, i = 128, 128
+    key = jax.random.PRNGKey(sum(map(ord, cell)) + t)
+    _, w1, w2, w3 = _rand_weights(key, n, d, i)
+    gate_w = jax.random.normal(key, (d, n_router), jnp.float32) * 0.1
+    x = jax.random.normal(jax.random.fold_in(key, 1), (t, d), jnp.float32)
+    bias = (jax.random.normal(jax.random.fold_in(key, 2), (n_router, ))
+            * 0.05 if routing.select_bias else None)
+    routed = _route(x, gate_w, top_k, routing, select_bias=bias)
+    ref = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k, routing=routing,
+                        routed=routed, activation=activation)
+    force_pallas(kernel)
+    out, visited = jax.block_until_ready(moe_ffn(
+        x, gate_w, w1, w2, w3, top_k, routing=routing, routed=routed,
+        activation=activation, count_visited=True))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
+    local = np.asarray(routed[1]) - routing.held_start
+    sizes = np.bincount(local[(local >= 0) & (local < n)], minlength=n)
+    assert moe.grouped_rows(t, top_k, n_router)
+    assert int(visited) == (sizes > 0).sum() < n
+    (_, expert_of, _), visits = visit_metadata(
+        jnp.asarray(sizes, jnp.int32), 128, 128)
+    assert int(visits) == int(visited)
+    # No visit goes to an expert without a row.
+    assert set(np.asarray(expert_of)[:int(visits)]) == set(
+        np.flatnonzero(sizes))
+
+
+@pytest.mark.parametrize("activation", ["silu", "relu"])
+def test_kernels_at_one_tile_of_a_decode_step(activation):
+    """The two kernels at what 16 decode rows of top-6 of 64 give them:
+    M = one tile of 128 sorted rows of which 96 are live, 46 experts with
+    one to four rows each and 18 with none, against the jnp oracle."""
+    from intellillm_tpu.ops.grouped_ffn import grouped_expert_ffn_reference
+    from intellillm_tpu.ops.pallas.grouped_ffn import (grouped_ffn,
+                                                       visit_metadata)
+    n, d, i, m, live = 64, 128, 256, 128, 96
+    rng = np.random.default_rng(5)
+    sizes = np.zeros(n, np.int32)
+    chosen = rng.choice(n, size=46, replace=False)
+    sizes[chosen] = 1
+    for e in rng.choice(chosen, size=live - 46):
+        sizes[e] += 1
+    assert sizes.sum() == live and (sizes == 0).sum() == 18
+    assert sizes.max() <= 6
+    key = jax.random.PRNGKey(11)
+    _, w1, w2, w3 = _rand_weights(key, n, d, i)
+    xs = jax.random.normal(jax.random.fold_in(key, 1), (m, d), jnp.float32)
+    sizes = jnp.asarray(sizes)
+    _, visits = visit_metadata(sizes, m, 128)
+    assert int(visits) == 46
+    out = jax.block_until_ready(grouped_ffn(xs, w1, w2, w3, sizes, 128,
+                                            activation))
+    ref = grouped_expert_ffn_reference(xs, w1, w2, w3, sizes, activation)
+    np.testing.assert_allclose(np.asarray(out)[:live],
+                               np.asarray(ref)[:live], rtol=2e-4, atol=2e-5)

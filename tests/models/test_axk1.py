@@ -356,13 +356,16 @@ def stacks_of(w, prefix, experts):
     return stack("gate"), stack("down", t=True), stack("up")
 
 
-@pytest.mark.parametrize("rows,path", [(8, "dense"), (600, "grouped")])
+@pytest.mark.parametrize("rows,path", [(8, "grouped"), (64, "dense"),
+                                       (600, "grouped")])
 def test_the_shares_add_up_to_the_uncut_layer(monkeypatch, rows, path):
     """THE SHARE TEST. Four shares of a 16-expert layer, each through
     `moe_ffn` holding 4 experts of a 16-wide router: their routed parts,
     plus the shared expert counted once, add up to what the uncut
     reference gives for the whole layer; each share alone equals the
-    reference given the same share. On both of `moe_ffn`'s paths."""
+    reference given the same share. On both of `moe_ffn`'s paths: 8 rows
+    of top-4 of 16 leave experts unchosen (grouped), 64 do not (dense),
+    600 are more than one dense chunk (grouped)."""
     from intellillm_tpu.layers import moe
     cfg = dict(TINY)
     p = "model.layers.1.mlp."
@@ -400,6 +403,12 @@ def test_held_assignments_count_the_choices_here():
 
 
 @pytest.mark.parametrize("name,widths,rows,path", [
+    # So few rows that experts go unchosen (`CHOSEN_SHARE_CUT`).
+    ("deepseek-moe-16b", (64, 64, 6), 16, "grouped"),
+    ("deepseek-moe-16b", (64, 64, 6), 32, "dense"),
+    ("mixtral-8x7b", (8, 8, 2), 16, "dense"),
+    ("ax-k1 share", (12, 192, 8), 32, "grouped"),
+    ("ax-k1 share", (12, 192, 8), 64, "dense"),
     ("deepseek-moe-16b", (64, 64, 6), 128, "dense"),
     ("deepseek-moe-16b", (64, 64, 6), 256, "dense"),
     ("deepseek-moe-16b", (64, 64, 6), 512, "grouped"),
@@ -416,8 +425,10 @@ def test_held_assignments_count_the_choices_here():
 def test_moe_form_by_the_rows_of_the_step(monkeypatch, name, widths, rows,
                                           path):
     """The models the benchmark has, at the rows their programs have:
-    a decode step and a `mixed` step of up to 256 rows stay dense, a
-    `mixed` step of more goes grouped (layers/moe.py `DENSE_ROWS`)."""
+    a 128-row decode step and a `mixed` step of up to 256 rows stay
+    dense, a `mixed` step of more goes grouped (layers/moe.py
+    `DENSE_ROWS`), and so does a step of so few rows that experts go
+    unchosen."""
     from intellillm_tpu.layers import moe
     n, n_router, top_k = widths
     took = []

@@ -470,3 +470,45 @@ def test_tracing_shows_both_groups(served):
     # A full layer's rows attend to their whole context; a window layer's
     # to less, by what the traffic's contexts pass the window.
     assert 0 < window < full <= context
+
+
+def test_decode_steps_read_the_experts_their_rows_chose(tmp_path):
+    """With 16 experts top-2 a 16-row decode step is expected to choose
+    0.88 of them: the K=8 decode programs take the grouped form (by the
+    rule, which the rows counter asks too), agree with the reference like
+    the dense ones, and intellillm_moe_expert_visits_total says how many
+    of 16 experts x 8 layers a substep each finished step read."""
+    from intellillm_tpu.layers.moe import grouped_rows
+    from intellillm_tpu.obs import get_efficiency_tracker
+    assert grouped_rows(16, 2, 16) and not grouped_rows(32, 2, 16)
+    _, vocab = _build_word_tokenizer(str(tmp_path))
+    cfg = dict(TINY, vocab_size=vocab, moe_num_primary_experts=16)
+    weights = write_model(str(tmp_path), cfg)
+    tracker = get_efficiency_tracker()
+    before = tracker.snapshot()
+    prompts = prompts_of(cfg, (9, 40))
+    outs = generate(make_llm(str(tmp_path)), prompts, [greedy(20)] * 2)
+    assert dlogprobs(cfg, weights, prompts, outs).max() < F32_TOL
+    after = tracker.snapshot()
+
+    def delta(*keys):
+        a, b = after, before
+        for k in keys:
+            a, b = a[k], b[k]
+        return a - b
+
+    per_substep = 16 * 8
+    decode = {v: sum(delta("moe_expert_visits", p, v)
+                     for p in ("decode_fused", "decode_cont"))
+              for v in ("yes", "no")}
+    # 19 tokens past the prefill's own sample: three K=8 steps.
+    assert decode["yes"] + decode["no"] == 3 * 8 * per_substep
+    # 2 live rows and 14 pad rows (one row 14 times): at most 6 experts a
+    # layer-substep have a row.
+    assert 0 < decode["yes"] <= 3 * 8 * 8 * 6
+    # 32-row `mixed` steps run dense and read every expert, 16-row ones
+    # are grouped like the decode steps.
+    mixed = [delta("moe_expert_visits", "mixed", v) for v in ("yes", "no")]
+    assert sum(mixed) % per_substep == 0 and mixed[0] > mixed[1]
+    assert delta("moe_ffn_rows", "grouped") >= 3 * 8 * 16
+    assert delta("moe_ffn_rows", "dense") >= 32

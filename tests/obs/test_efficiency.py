@@ -300,19 +300,26 @@ def test_pipeline_drains_count_by_cause(tracker, cause):
         c: int(c == cause) for c in DRAIN_CAUSES}
 
 
-@pytest.mark.parametrize("rows,substeps,path", [
-    (128, 8, "dense"), (256, 1, "dense"), (257, 1, "grouped"),
-    (512, 1, "grouped")])
+@pytest.mark.parametrize("rows,substeps,top_k,width,path", [
+    (128, 8, 6, 64, "dense"), (256, 1, 6, 64, "dense"),
+    (257, 1, 6, 64, "grouped"), (512, 1, 6, 64, "grouped"),
+    # So few rows that experts go unchosen: 16 decode rows of top-6 of 64
+    # and a lone request's row are grouped, 32 rows of top-4 of 32 and of
+    # top-6 of 64 are not; a share's router is wider than its stack.
+    (16, 8, 6, 64, "grouped"), (1, 8, 6, 64, "grouped"),
+    (32, 8, 4, 32, "dense"), (32, 1, 6, 64, "dense"),
+    (32, 1, 8, 192, "grouped"), (64, 1, 8, 192, "dense")])
 def test_moe_ffn_rows_count_by_the_form_the_rows_take(tracker, rows,
-                                                      substeps, path):
+                                                      substeps, top_k,
+                                                      width, path):
     """intellillm_moe_ffn_rows_total{path}: a dispatched program's rows x
-    substeps, under the form `layers/moe.py` `moe_ffn` takes at that many
-    rows (the rule is asked, not copied); both children from the first
+    substeps, under the form `layers/moe.py` `moe_ffn` takes at those
+    shapes (the rule is asked, not copied); both children from the first
     scrape; warm-up dispatches are not counted."""
     from prometheus_client import REGISTRY
     from intellillm_tpu.layers.moe import grouped_rows
     from intellillm_tpu.obs.efficiency import MOE_FFN_PATHS
-    assert grouped_rows(rows) == (path == "grouped")
+    assert grouped_rows(rows, top_k, width) == (path == "grouped")
 
     def sample(p):
         return REGISTRY.get_sample_value(
@@ -321,14 +328,46 @@ def test_moe_ffn_rows_count_by_the_form_the_rows_take(tracker, rows,
     before = {p: sample(p) for p in MOE_FFN_PATHS}
     assert None not in before.values()
     with tracker.warmup():
-        tracker.record_moe_ffn_rows(rows, substeps)
+        tracker.record_moe_ffn_rows(rows, substeps, top_k, width)
     assert tracker.snapshot()["moe_ffn_rows"] == dict.fromkeys(
         MOE_FFN_PATHS, 0)
-    tracker.record_moe_ffn_rows(rows, substeps)
+    tracker.record_moe_ffn_rows(rows, substeps, top_k, width)
     counted = tracker.snapshot()["moe_ffn_rows"]
     assert counted.pop(path) == rows * substeps and not any(counted.values())
     assert {p: sample(p) - before[p] for p in MOE_FFN_PATHS} == {
         p: rows * substeps * (p == path) for p in MOE_FFN_PATHS}
+
+
+@pytest.mark.parametrize("program,visited,held", [
+    ("decode_cont", 3104, 4096), ("decode_fused", 48, 512),
+    ("mixed", 512, 512)])
+def test_moe_expert_visits_count_what_a_step_read(tracker, program, visited,
+                                                  held):
+    """intellillm_moe_expert_visits_total{program,visited}: of the held
+    experts x layers x substeps of a finished step, those whose weights
+    were read and those no row chose; every child from the first scrape;
+    warm-up steps and programs that are not counted by name are left
+    out."""
+    from prometheus_client import REGISTRY
+    from intellillm_tpu.obs.efficiency import EXPERT_VISITED, PROGRAMS
+
+    def sample(prog, v):
+        return REGISTRY.get_sample_value(
+            "intellillm_moe_expert_visits_total",
+            {"program": prog, "visited": v})
+
+    before = {(p, v): sample(p, v) for p in PROGRAMS for v in EXPERT_VISITED}
+    assert None not in before.values()
+    zero = {p: dict.fromkeys(EXPERT_VISITED, 0) for p in PROGRAMS}
+    with tracker.warmup():
+        tracker.record_moe_expert_visits(program, visited, held)
+    tracker.record_moe_expert_visits("decode_teacher", visited, held)
+    assert tracker.snapshot()["moe_expert_visits"] == zero
+    tracker.record_moe_expert_visits(program, visited, held)
+    want = dict(zero, **{program: {"yes": visited, "no": held - visited}})
+    assert tracker.snapshot()["moe_expert_visits"] == want
+    assert {k: sample(*k) - before[k] for k in before} == {
+        (p, v): want[p][v] for p in PROGRAMS for v in EXPERT_VISITED}
 
 
 @pytest.mark.parametrize("tiles", [{"one": 128}, {"one": 3, "many": 509},

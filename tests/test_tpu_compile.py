@@ -324,6 +324,7 @@ DSMOE16B = (64, 2048, 1408, 6)
 MIXTRAL_8X7B = (8, 4096, 14336, 2)
 AXK1_SHARE = (12, 7168, 2048, 8)          # 12 of the router's 192
 LFM2_8B_A1B = (32, 2048, 1792, 4)
+SMALLTHINKER = (64, 2560, 768, 6)
 
 
 def _moe_specs(rows, widths, n_router=None):
@@ -412,6 +413,55 @@ def test_moe_grouped_copies_no_expert_stack(compiled_for_chip, force_pallas,
     assert text.count("tpu_custom_call") == 2
     assert _stack_sized_copies(text, widths) == []
     assert compiled.memory_analysis().temp_size_in_bytes < most
+
+
+# name -> (widths, router width, rows, Routing settings, activation,
+# substeps of a K-loop around the layer or 0): steps of so few rows that
+# the rule takes the grouped form for the experts nobody chose: the
+# SmallThinker cell's 16-row decode step inside its K=8 loop, a lone
+# request's row, and the 16- and 32-row `mixed` steps of two other cells.
+FEW_ROW_PROGRAMS = {
+    "smallthinker-16-in-loop-of-8": (SMALLTHINKER, 64, 16, {}, "relu", 8),
+    "smallthinker-1-in-loop-of-8": (SMALLTHINKER, 64, 1, {}, "relu", 8),
+    "dsmoe16b-16": (DSMOE16B, 64, 16, dict(renormalize=False), "silu", 0),
+    "axk1-share-32": (AXK1_SHARE, 192, 32,
+                      dict(scoring="sigmoid", n_group=8, topk_group=4,
+                           scale=2.5), "silu", 0),
+}
+
+
+@pytest.mark.parametrize("program", sorted(FEW_ROW_PROGRAMS))
+def test_moe_few_rows_take_the_grouped_kernels(compiled_for_chip,
+                                               force_pallas, program):
+    """`moe_ffn` at a step of few rows lowers to the two grouped kernels
+    for the described chip, inside a `lax.scan` as `decode_fused` /
+    `decode_cont` run it (the number of visits is a value of the loop's
+    body, not of the program), with no copy of an expert stack for the
+    life of the program and temporaries of one 128-row tile."""
+    from intellillm_tpu.layers.moe import Routing, grouped_rows, moe_ffn
+    widths, n_router, rows, settings, activation, loop = FEW_ROW_PROGRAMS[
+        program]
+    assert grouped_rows(rows, widths[3], n_router)
+    force_pallas(True)
+
+    def ffn(x, gate_w, w1, w2, w3):
+        return moe_ffn(x, gate_w, w1, w2, w3, widths[3],
+                       routing=Routing(**settings), activation=activation,
+                       count_visited=True)
+
+    def looped(x, *weights):
+        def substep(h, _):
+            out, visited = ffn(h, *weights)
+            return (h + out).astype(h.dtype), visited
+        return jax.lax.scan(substep, x, None, length=loop)
+
+    compiled = compiled_for_chip(looped if loop else ffn,
+                                 *_moe_specs(rows, widths, n_router))
+    text = compiled.as_text()
+    assert "_grouped_gate_up_call" in text and "_grouped_down_call" in text
+    assert text.count("tpu_custom_call") == 2
+    assert _stack_sized_copies(text, widths) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9
 
 
 @pytest.mark.parametrize("rows", [128, 256])
@@ -598,8 +648,9 @@ def test_decode_cont_takes_joiners_at_no_more_temporaries(
         kv = [tuple(sds(pool_dtype, CELL_BLOCKS, heads, 16, width)
                     for _ in range(pools)) for _ in range(layers)]
         # _pack's layout at logprob_k = 1, with an expert-parallel
-        # share's two counter columns.
-        share = 2 if getattr(model, "moe_choices_per_token", 0) else 0
+        # share's two counter columns and the two of the expert visits.
+        share = (2 * bool(getattr(model, "moe_choices_per_token", 0))
+                 + 2 * getattr(model, "counts_expert_visits", False))
         packed = sds(jnp.int32, b, 4 * k + share)
         i32, f32 = jnp.int32, jnp.float32
         rest = (sds(i32, b, 1), sds(i32, b, w), sds(i32, b), sds(f32, b),
@@ -842,8 +893,10 @@ def test_window_group_model_programs_compile(compiled_for_chip, one_chip,
                         ("_paged_attention_call",
                          "_paged_attention_call_window"))
     assert windowed in text and re.search(kernel + r"(?!_window)\b", text)
-    if program == "mixed":
-        # 512 rows take the grouped (ReLU) expert kernels.
-        assert "_grouped_gate_up_call" in text
+    # 512 chunk rows take the grouped (ReLU) expert kernels, and so do 16
+    # decode rows (top-6 of 64: a fifth of the experts chosen by no row),
+    # inside the K-loop, with no copy of a [64, 768, 2560] stack.
+    assert "_grouped_gate_up_call" in text and "_grouped_down_call" in text
+    assert _stack_sized_copies(text, SMALLTHINKER) == []
     # One period plus the whole vocabulary's head stay inside the chip.
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024**3
