@@ -3,7 +3,7 @@
     python chip_smoke.py            # one chip (what the driver runs)
     python chip_smoke.py --chips 4  # tensor parallelism only, tp=4 vs tp=1
 
-One chip, three phases, each a child process (this parent never imports
+One chip, four phases, each a child process (this parent never imports
 jax: a chip belongs to one process at a time, and the children need it):
 
 1. `device`  — what JAX finds. No accelerator -> exit 2, nothing printed
@@ -23,6 +23,13 @@ jax: a chip belongs to one process at a time, and the children need it):
 3. `parity`  — the same widths at 2 layers, built twice in one process:
                Pallas kernels on, then `set_use_pallas(False)`; the same
                prompts must give the same greedy tokens.
+4. `state`   — a model with per-sequence state: one Solar Open 2 period
+               (a gated GQA layer and three delta-rule layers with a
+               float32 matrix a head in the state slots) at 128-wide
+               heads; what prefill then K=8 decode through the slots
+               produced must equal a fresh full pass over the same
+               tokens (the step form on the pool against the chunk form
+               from position 0).
 
 Every stdout line is one JSON object. The last one is
 `{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`
@@ -594,6 +601,122 @@ def phase_parity(args) -> None:
         fail("greedy tokens differ, kernels on vs off")
 
 
+# --- phase: state (child) ----------------------------------------------------
+
+def solar2_config(size: str):
+    """A Solar Open 2 period (GQA, KDA, KDA, KDA) at the size's hidden
+    width and 128-wide heads (the lane width the delta rule's kernels
+    need), 8 of 16 experts held: the per-sequence state is the filters'
+    taps and a float32 matrix a head, one slot a sequence."""
+    from intellillm_tpu.transformers_utils.configs import SolarOpen2Config
+    hidden, _, _, _, vocab = WIDTHS[size]
+    heads = hidden // 128
+    return SolarOpen2Config(
+        architectures=["SolarOpen2ForCausalLM"], vocab_size=vocab,
+        hidden_size=hidden, moe_intermediate_size=256,
+        num_hidden_layers=4, gqa_layers=[0], num_attention_heads=heads,
+        num_key_value_heads=max(heads // 4, 1), head_dim=128,
+        linear_attn_config={"short_conv_kernel_size": 4, "head_dim": 128,
+                            "num_heads": heads, "num_kv_heads": None},
+        n_routed_experts=8, ep_size=2, ep_rank=0, num_experts_per_tok=4,
+        max_position_embeddings=4096)
+
+
+def phase_state(args) -> None:
+    """A model with per-sequence state (a matrix a head, in the state
+    slots): what prefill then K=8 decode through the slots produced equals
+    a fresh full pass. Each request's prompt + its first n tokens, sent as
+    a new prompt, must choose token n + 1 again, at the same logprob: the
+    first run reached that token through `kda_step` on the pool by slot
+    (and a chunked prefill for the long prompt), the second through
+    `kda_chunk` from position 0."""
+    import jax
+
+    from intellillm_tpu.config import (CacheConfig, ModelConfig,
+                                       ParallelConfig, SchedulerConfig)
+    from intellillm_tpu.engine.llm_engine import LLMEngine
+    from intellillm_tpu.obs import get_compile_tracker
+    from intellillm_tpu.sampling_params import SamplingParams
+    precision = exact_f32_matmuls()
+    cfg = SIZES[args.size]
+    vocab = WIDTHS[args.size][4]
+    t0 = time.monotonic()
+    model_config = ModelConfig.from_hf_config(
+        solar2_config(args.size), dtype="float32",
+        max_model_len=cfg["small_mml"], load_format="dummy", seed=args.seed)
+    engine = LLMEngine(
+        model_config,
+        CacheConfig(block_size=16,
+                    num_device_blocks_override=cfg["small_blocks"],
+                    swap_space_gib=0.01),
+        ParallelConfig(),
+        SchedulerConfig(max_num_batched_tokens=cfg["small_budget"],
+                        max_num_seqs=cfg["small_seqs"],
+                        max_model_len=cfg["small_mml"], max_paddings=4096,
+                        num_decode_steps=8, enable_chunked_prefill=True),
+        log_stats=False, skip_tokenizer_init=True)
+
+    def run(prompts: dict, n: int) -> dict:
+        for rid, ids in prompts.items():
+            engine.add_request(
+                request_id=rid, prompt=None,
+                sampling_params=SamplingParams(
+                    temperature=0.0, max_tokens=n, ignore_eos=True,
+                    logprobs=1),
+                prompt_token_ids=ids)
+        out = {}
+        while engine.has_unfinished_requests() or engine.has_inflight():
+            step = (engine.step_pipelined if engine.pipeline_enabled
+                    else engine.step)
+            for ro in step():
+                if ro.finished:
+                    c = ro.outputs[0]
+                    out[ro.request_id] = (
+                        list(c.token_ids),
+                        [float(getattr(lp[t], "logprob", lp[t]))
+                         for t, lp in zip(c.token_ids, c.logprobs)])
+        return out
+
+    lens = cfg["small_prompts"]
+    prompts = {f"state-{i}": prompt_ids(args.seed + 30 + i, n, vocab)
+               for i, n in enumerate(lens)}
+    first = run(prompts, 20)
+    check(all(len(t) == 20 for t, _ in first.values()),
+          f"engine returned {[(k, len(v[0])) for k, v in first.items()]}")
+    worst, wrong = 0.0, []
+    for cut in (1, 9, 19):          # inside and across the K=8 substeps
+        again = run({rid: prompts[rid] + first[rid][0][:cut]
+                     for rid in prompts}, 1)
+        for rid in prompts:
+            (tok, ), (lp, ) = again[rid]
+            worst = max(worst, abs(lp - first[rid][1][cut]))
+            if tok != first[rid][0][cut]:
+                wrong.append((rid, cut, first[rid][0][cut], tok))
+    bm = engine.scheduler.block_manager
+    dispatch = get_compile_tracker().snapshot()["kernel_dispatch"]
+    emit({"phase": "state", "layers": 4, "dtype": "float32",
+          "f32_matmul_precision": precision, "prompt_lens": lens,
+          "state_arrays": [[list(s), d] for s, d in
+                           model_config.get_state_arrays()],
+          "state_slots": bm.num_state_slots,
+          "slots_free_at_the_end": len(bm._free_state_slots),
+          "kernel_dispatch": dispatch,
+          "max_abs_dlogprob": worst, "tokens_wrong": wrong,
+          "wall_s": round(time.monotonic() - t0, 1)})
+    drop_engine(engine)
+    if jax.default_backend() == "tpu":
+        check(all(dispatch.get(f"pallas:{k}") for k in
+                  ("kda_chunk", "kda_step", "short_conv_silu"))
+              and not any(k.startswith("reference:kda") for k in dispatch),
+              f"the delta rule's seams did not run their kernels: "
+              f"{dispatch}")
+    check(len(bm._free_state_slots) == bm.num_state_slots,
+          "a state slot was not given back")
+    check(not wrong and worst < 1e-3,
+          f"prefill + decode through the slots differs from a fresh full "
+          f"pass: tokens {wrong}, max |d logprob| {worst:.3g}")
+
+
 def phase_tp(args) -> None:
     import re
 
@@ -688,13 +811,13 @@ def main() -> None:
                     help="run the phases on whatever backend there is "
                     "(CPU rehearsal); still refuses at the end")
     ap.add_argument("--phase", default=None,
-                    choices=("device", "parity", "tp"),
+                    choices=("device", "parity", "state", "tp"),
                     help="internal: run one child phase in this process")
     args = ap.parse_args()
 
     if args.phase is not None:
         {"device": phase_device, "parity": phase_parity,
-         "tp": phase_tp}[args.phase](args)
+         "state": phase_state, "tp": phase_tp}[args.phase](args)
         return
 
     check(os.path.isdir(os.path.join(REPO, "intellillm_tpu")),
@@ -716,6 +839,7 @@ def main() -> None:
     else:
         phase_serve(args, expect_tpu=on_tpu)
         run_phase_child("parity", args)
+        run_phase_child("state", args)
     emit({"phase": "all", "wall_s": round(time.monotonic() - t0, 1)})
     if not on_tpu:
         fail("rehearsal complete; refusing: JAX found no accelerator "

@@ -165,10 +165,11 @@ class ModelConfig:
         but the layer attends to the last `get_window_group_size()`
         tokens only and its blocks are a group of their own, beside
         layers that keep the whole context) or "state" (a fixed-size
-        per-sequence state of `get_state_row_shape`, one slot a running
+        per-sequence state of `get_state_arrays`, one slot a running
         sequence, not paged). A config that lists `layer_types` and a
         short-convolution cache length (`conv_L_cache`) has "state" where
-        its type is not an attention; one that lists a
+        its type is not an attention, one with a `linear_attn_config`
+        wherever `gqa_layers` does not list the layer; one that lists a
         `sliding_window_layout` of ones and zeros and a
         `sliding_window_size` has "kv_window" where the layout says 1,
         unless it says 1 everywhere (then the window is the whole
@@ -178,6 +179,11 @@ class ModelConfig:
         layout = self._window_layout()
         if layout is not None and not all(layout):
             return ["kv_window" if w else "kv" for w in layout]
+        if getattr(self.hf_config, "linear_attn_config", None):
+            # Linear-attention layers everywhere but where the config
+            # lists a softmax layer.
+            softmax = set(getattr(self.hf_config, "gqa_layers", None) or ())
+            return ["kv" if i in softmax else "state" for i in range(n)]
         types = getattr(self.hf_config, "layer_types", None)
         if not types or not getattr(self.hf_config, "conv_L_cache", None):
             return ["kv"] * n
@@ -209,14 +215,39 @@ class ModelConfig:
             return 0.0
         return min(1.0, (window + ahead_tokens) / max(self.max_model_len, 1))
 
-    def get_state_row_shape(self) -> Optional[Tuple[int, int]]:
-        """(taps, width) of what one sequence keeps in one "state" layer:
-        the last `conv_L_cache - 1` gated inputs of a short convolution,
-        `hidden_size` channels each. None for a model without such
-        layers."""
+    def get_state_arrays(self) -> Tuple[Tuple[Tuple[int, ...], str], ...]:
+        """What one sequence keeps in one "state" layer: a tuple of
+        (shape, dtype), one entry an array; () for a model without such
+        layers. A short convolution keeps its last `conv_L_cache - 1`
+        gated inputs, `hidden_size` channels each, in the model's dtype
+        (the 1-tuple case). A delta-rule linear-attention layer keeps the
+        last `short_conv_kernel_size - 1` inputs of its q, k and v
+        filters side by side, in the model's dtype, and a float32 matrix
+        `[key channels, value channels]` a head: the state is summed
+        over every token so far, and a 2-byte state's rounding would be
+        as large as what a token adds to it.
+
+        A 2-d entry (a few rows of activations) is stored as one row a
+        slot and gathered into the decode programs' stage; an entry of
+        more dimensions (a matrix a head) is updated in its pool, by
+        slot (`state_array_in_place`)."""
         if "state" not in self.get_layer_cache_kinds():
-            return None
-        return self.hf_config.conv_L_cache - 1, self.hf_config.hidden_size
+            return ()
+        linear = getattr(self.hf_config, "linear_attn_config", None)
+        if linear:
+            heads, d = linear["num_heads"], linear["head_dim"]
+            taps = linear["short_conv_kernel_size"] - 1
+            return (((taps, 3 * heads * d), self.dtype),
+                    ((heads, d, d), "float32"))
+        return (((self.hf_config.conv_L_cache - 1,
+                  self.hf_config.hidden_size), self.dtype), )
+
+    @staticmethod
+    def state_array_in_place(shape: Tuple[int, ...]) -> bool:
+        """Whether a state array is updated in its pool by slot (a matrix
+        a head: megabytes a sequence) and not through a gathered stage (a
+        few rows of activations)."""
+        return len(shape) > 2
 
     def get_num_kv_layers(self) -> int:
         """Layers with a paged KV (or latent) pool of the primary group."""

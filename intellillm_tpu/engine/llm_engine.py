@@ -119,7 +119,8 @@ class LLMEngine:
 
         self.speculative_config = speculative_config
         # A model with per-sequence state layers (a short convolution's
-        # taps: `ModelConfig.get_layer_cache_kinds`) takes a state slot a
+        # taps, a linear-attention layer's matrix a head:
+        # `ModelConfig.get_layer_cache_kinds`) takes a state slot a
         # running sequence, and refuses what would need more of the state
         # than its newest value.
         self._stateful = model_config.get_num_state_layers() > 0
@@ -505,7 +506,7 @@ class LLMEngine:
         if speculative_config is not None:
             raise NotImplementedError(
                 "Speculative decoding is not supported for a model with "
-                "per-sequence convolution state: a rejected draft token "
+                "per-sequence recurrent state: a rejected draft token "
                 "has already advanced the state, and the state pool keeps "
                 "no earlier value to roll back to.")
         role = self.scheduler_config.replica_role
@@ -513,7 +514,7 @@ class LLMEngine:
             raise NotImplementedError(
                 f"replica_role={role!r} (KV export/import, "
                 f"worker/kv_transfer.py) is not supported for a model with "
-                f"per-sequence convolution state: the IKV1 payload carries "
+                f"per-sequence recurrent state: the IKV1 payload carries "
                 f"K/V blocks of a prompt prefix and no state snapshot at "
                 f"the prefix's end.")
 
@@ -544,7 +545,7 @@ class LLMEngine:
 
     _PREFIX_REFUSAL = (
         "Prefix reuse (prefix_pos, prefix.py and the prefix pool) is not "
-        "supported for a model with per-sequence convolution state: a "
+        "supported for a model with per-sequence recurrent state: a "
         "request that skips a cached prefix needs the state as it stood at "
         "the prefix's end, and the pool keeps K/V blocks only (it would "
         "need state snapshots).")

@@ -1,0 +1,124 @@
+"""Kimi Delta Attention: a gated delta rule whose decay is a vector over the
+key channels (the mixer of a "state" layer of a linear-attention hybrid).
+
+Per head of `d` key and value channels, `x` the layer's normed input:
+
+    [q~ | k~ | v~] = x W_qkv                     (hidden -> 3 x H x d)
+    q, k, v = silu(depthwise causal 4-tap filter of q~, k~, v~)
+    q = q / ||q|| * d^-1/2 ;  k = k / ||k||      (per head, eps 1e-6)
+    g = -exp(A_log) * softplus(W_f2 (W_f1 x) + dt_bias)     (= log a <= 0)
+    b = 2 * sigmoid(x W_b)       (the 2: negative eigenvalues allowed)
+    o = delta rule over (q, k, v, g, b)          (ops/linear_attention.py)
+    y = [RMSNorm_head(o) * w_norm * sigmoid(W_g2 (W_g1 x))] W_o
+
+What a sequence keeps between steps, one slot each in the layer's two
+pools (`ModelConfig.get_state_arrays`): the last three rows of
+`[q~ | k~ | v~]` (the filters' taps, oldest first, in the model's dtype)
+and the delta rule's matrix a head, float32. As in `layers/short_conv.py`
+a step never zeroes a slot: a row at a position under 3 takes 0 for what
+lies before the sequence's start, and the delta rule starts from zero at
+position 0, whatever the slot holds.
+
+Two cases, as `layers/short_conv.py` has them:
+
+- a `mixed` step: every row is one token with its position and the slot
+  of its sequence. The taps of a row are the rows before it of the same
+  run where the step holds them, else the slot's, else 0; the delta rule
+  runs in its chunk form over the runs (`kda_chunk`). A run's last row
+  writes both pools.
+- a K-step decode program (staged): the cache is (taps stage, matrix
+  pool): the taps `[B, 3 * width]` gathered by the runner before the first
+  substep and scattered back after the last, the matrix pool itself,
+  which `kda_step` updates in place by slot.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from intellillm_tpu.layers.normalization import rms_norm
+from intellillm_tpu.layers.short_conv import _prev_rows
+from intellillm_tpu.ops.linear_attention import (kda_chunk, kda_step,
+                                                 run_structure)
+from intellillm_tpu.ops.short_conv import conv_silu_body
+
+_NORM_EPS = 1e-6
+
+
+def _l2_normalise(x: jnp.ndarray) -> jnp.ndarray:
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + _NORM_EPS)
+
+
+def kda_gates(x, lp, num_heads: int, head_dim: int):
+    """(g [B, H, d] float32 = the log of the decay, b [B, H] float32) of
+    the layer's input `x` [B, E]."""
+    f32 = jnp.float32
+    raw = ((x @ lp["f_a"]) @ lp["f_b"]).astype(f32) + lp["dt_bias"].astype(f32)
+    g = (-jnp.exp(lp["A_log"].astype(f32))[None, :, None]
+         * jax.nn.softplus(raw).reshape(-1, num_heads, head_dim))
+    beta = 2.0 * jax.nn.sigmoid((x @ lp["b_proj"]).astype(f32))
+    return g, beta
+
+
+def kda_mixer(h, lp, cache, attn_metadata, positions, *, num_heads: int,
+              head_dim: int, eps: float):
+    """`h` [B, 1, E] -> ([B, 1, E], cache). `cache` is (taps pool, matrix
+    pool) or, staged, (taps stage, matrix pool)."""
+    b, l, e = h.shape
+    assert l == 1, "a step's rows are one token each"
+    x = h.reshape(b, e)
+    width = 3 * num_heads * head_dim
+    pre = x @ lp["qkv"]                                       # [B, 3HD]
+    pos = positions.reshape(b)
+    slots = attn_metadata.state_slots
+    taps, matrices = cache
+    if attn_metadata.staged:
+        held = taps
+    else:
+        continues, last = run_structure(slots, pos)
+        held = taps.at[slots].get(mode="fill", fill_value=0)  # [B, 3 * W]
+        # `back` rows of a row's own run lie above it in the step (3 and
+        # more count as 3).
+        back = jnp.zeros((b, ), jnp.int32)
+        run = continues
+        for n in range(1, 4):
+            back = back + run.astype(jnp.int32)
+            run = run & _prev_rows(continues, n)
+    held = held.reshape(b, 3, width)
+    prev = []
+    for n in (3, 2, 1):                       # oldest first
+        if attn_metadata.staged:
+            tap = held[:, 3 - n]
+        else:
+            # The run's own row n back where the step holds it, else what
+            # the slot held when the run began.
+            from_slot = jnp.take_along_axis(
+                held, jnp.clip(3 - n + back, 0, 2)[:, None, None],
+                axis=1)[:, 0]
+            tap = jnp.where((back >= n)[:, None], _prev_rows(pre, n),
+                            from_slot)
+        # Before the sequence's start there is nothing.
+        prev.append(jnp.where((pos >= n)[:, None], tap, 0).astype(pre.dtype))
+    prev = jnp.stack(prev)                                    # [3, B, W]
+    qkv = conv_silu_body(pre, prev, lp["conv_w"])
+    q, k, v = (qkv[:, i * width // 3:(i + 1) * width // 3].reshape(
+        b, num_heads, head_dim) for i in range(3))
+    q = _l2_normalise(q) * head_dim ** -0.5
+    k = _l2_normalise(k)
+    g, beta = kda_gates(x, lp, num_heads, head_dim)
+    new_taps = jnp.concatenate([prev[1], prev[2], pre], axis=1).astype(
+        taps.dtype)
+    if attn_metadata.staged:
+        o, matrices = kda_step(q, k, v, g, beta, matrices, slots)
+        cache = (new_taps, matrices)
+    else:
+        o, matrices = kda_chunk(q, k, v, g, beta, matrices, slots, pos)
+        # Only a run's last row writes its slot; the others, and pad
+        # rows, point past the pool and are dropped.
+        dst = jnp.where(last, slots, taps.shape[0])
+        cache = (taps.at[dst].set(new_taps, mode="drop"), matrices)
+    gate = jax.nn.sigmoid(((x @ lp["g_a"]) @ lp["g_b"]).astype(jnp.float32))
+    o = rms_norm(o, lp["o_norm"].astype(jnp.float32), eps)
+    y = (o.reshape(b, width // 3) * gate).astype(h.dtype)
+    return (y @ lp["o"]).reshape(b, 1, e), cache

@@ -24,7 +24,7 @@ Two cases, as `layers/attention.py` has them:
   sequence where the step holds them, else the slot's, else 0, so nothing
   crosses a boundary between sequences. The last row of each sequence
   writes the slot.
-- a K-step decode program (staged): the layer's cache is (pool, stage),
+- a K-step decode program (staged): the layer's cache is (stage,),
   the stage `[B, 2 * hidden]` the rows' own states, which the runner
   gathered from the pool before the first substep, carries through the
   substeps and scatters back after the last (`worker/model_runner.py`).
@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from intellillm_tpu.ops.linear_attention import run_structure
 from intellillm_tpu.ops.short_conv import gated_input, short_conv_body
 
 
@@ -44,22 +45,20 @@ def _prev_rows(x: jnp.ndarray, n: int) -> jnp.ndarray:
 def short_conv_mixer(h, w_in, conv_w, w_out, cache, attn_metadata,
                      positions):
     """`h` [B, 1, E] -> ([B, 1, E], cache). `cache` is (state pool,) or,
-    staged, (state pool, stage)."""
+    staged, (stage,)."""
     b, l, e = h.shape
     assert l == 1, "a step's rows are one token each"
     bcx = (h @ w_in).reshape(b, 3 * e)
     pos = positions.reshape(b)
     if attn_metadata.staged:
-        pool, stage = cache
+        (stage, ) = cache
         p2, p1 = stage[:, :e], stage[:, e:]
     else:
         (pool, ) = cache
         slots = attn_metadata.state_slots
         # Row r-1 / r-2 is the token before / two before this row's, of
         # the same sequence.
-        same1 = ((slots == _prev_rows(slots, 1))
-                 & (pos == _prev_rows(pos, 1) + 1)
-                 & (jnp.arange(b) >= 1))
+        same1, last = run_structure(slots, pos)
         same2 = same1 & _prev_rows(same1, 1)
         g_rows = gated_input(bcx)
         held = pool.at[slots].get(mode="fill", fill_value=0)   # [B, 2E]
@@ -71,13 +70,12 @@ def short_conv_mixer(h, w_in, conv_w, w_out, cache, attn_metadata,
     p1 = jnp.where((pos >= 1)[:, None], p1, 0).astype(bcx.dtype)
     p2 = jnp.where((pos >= 2)[:, None], p2, 0).astype(bcx.dtype)
     y, g = short_conv_body(bcx, p1, p2, conv_w)
-    new = jnp.concatenate([p1, g], axis=1).astype(pool.dtype)  # [B, 2E]
+    new = jnp.concatenate([p1, g], axis=1).astype(cache[0].dtype)  # [B, 2E]
     if attn_metadata.staged:
-        cache = (pool, new)
+        cache = (new, )
     else:
         # Only a sequence's last row in the step writes its slot; the
         # others, and pad rows, point past the pool and are dropped.
-        last = ~jnp.pad(same1[1:], (0, 1))
         dst = jnp.where(last, slots, pool.shape[0])
         cache = (pool.at[dst].set(new, mode="drop"), )
     return (y @ w_out).reshape(b, 1, e), cache

@@ -29,6 +29,7 @@ from intellillm_tpu.models.phi import PhiForCausalLM
 from intellillm_tpu.models.qwen import QWenLMHeadModel
 from intellillm_tpu.models.qwen2 import Qwen2ForCausalLM
 from intellillm_tpu.models.smallthinker import SmallThinkerForCausalLM
+from intellillm_tpu.models.solar_open2 import SolarOpen2ForCausalLM
 from intellillm_tpu.models.stablelm import StableLMForCausalLM
 
 _MODEL_REGISTRY: Dict[str, Type] = {
@@ -70,6 +71,8 @@ _MODEL_REGISTRY: Dict[str, Type] = {
     "Lfm2MoeForCausalLM": Lfm2MoeForCausalLM,    # short-conv state + GQA
     # window + full attention layers (two cache groups), ReGLU experts
     "SmallThinkerForCausalLM": SmallThinkerForCausalLM,
+    # delta-rule linear attention (matrix state) + gated NoPE GQA, EP share
+    "SolarOpen2ForCausalLM": SolarOpen2ForCausalLM,
 }
 
 

@@ -80,6 +80,8 @@ ATTENTION_REACHES = ("full", "window")
 WINDOW_BLOCK_EVENTS = ("recycled", )
 # A `mixed` row by the tile of rows that walked its context with it.
 RAGGED_TILES = ("one", "many")
+# The form of a linear-attention layer's delta rule a row went through.
+LINEAR_ATTN_FORMS = ("chunk", "step")
 AXES = ("batch", "len", "block_width")
 _DEFAULT_MFU_WINDOW = 64
 _FILL_BUCKETS = (0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9,
@@ -166,6 +168,22 @@ class _EfficiencyMetrics:
             "of one prompt chunk that walked it once together). "
             "many / (one + many) is how often the tiling engages.",
             ["tile"])
+        self.counter_linear_attn_rows = Counter(
+            "intellillm_linear_attn_rows_total",
+            "Live rows x substeps of the dispatched programs that went "
+            "through a linear-attention layer's delta rule, by its form "
+            "(form: chunk = the `mixed` programs' chunk form over runs of "
+            "rows; step = a decode substep on the state pool by slot). "
+            "Stays 0 for a model without such layers.",
+            ["program", "form"])
+        self.counter_dispatch_state_bytes = Counter(
+            "intellillm_dispatch_state_bytes_total",
+            "Bytes of per-sequence matrix state the dispatched programs "
+            "read and wrote: live rows x substeps x state layers x a "
+            "slot's matrix bytes x 2. Over "
+            "intellillm_dispatch_row_steps_total: the state traffic of "
+            "one row-substep, what a roofline of the step form divides "
+            "by.", ["program"])
         self.counter_window_blocks = Counter(
             "intellillm_window_blocks_total",
             "What the block manager did with blocks of a sliding-window "
@@ -256,6 +274,9 @@ class _EfficiencyMetrics:
             self.counter_dispatch_walked_tokens.labels(program)
             for reach in ATTENTION_REACHES:
                 self.counter_dispatch_attended_tokens.labels(program, reach)
+            for form in LINEAR_ATTN_FORMS:
+                self.counter_linear_attn_rows.labels(program, form)
+            self.counter_dispatch_state_bytes.labels(program)
         for tile in RAGGED_TILES:
             self.counter_mixed_tile_rows.labels(tile)
         for event in WINDOW_BLOCK_EVENTS:
@@ -492,8 +513,9 @@ class EfficiencyTracker:
                         context_tokens: int = 0,
                         walked_tokens: int = 0,
                         attended_tokens: Optional[Dict[str, int]] = None,
-                        tile_rows: Optional[Dict[str, int]] = None
-                        ) -> None:
+                        tile_rows: Optional[Dict[str, int]] = None,
+                        linear_form: Optional[str] = None,
+                        state_bytes: int = 0) -> None:
         """Account one device dispatch. Extents are pre-padding vs
         post-padding; token counts are what the device actually
         computes (prefill: rows x padded len; decode: rows x substeps).
@@ -509,7 +531,11 @@ class EfficiencyTracker:
         mean over its layers). `attended_tokens`: by ATTENTION_REACHES,
         what those row-substeps attend to in one layer of that kind.
         `tile_rows`: by RAGGED_TILES, a `mixed` step's live rows by the
-        tile the ragged kernel ran them in."""
+        tile the ragged kernel ran them in. `linear_form` (of
+        LINEAR_ATTN_FORMS; None for a model without linear-attention
+        layers): the form of the delta rule the row-substeps went
+        through, and `state_bytes` the matrix state they read and
+        wrote."""
         if not self.enabled:
             return
         real_tokens = int(real_tokens)
@@ -566,6 +592,11 @@ class EfficiencyTracker:
                         program, reach).inc(n)
                 for tile, n in (tile_rows or {}).items():
                     m.counter_mixed_tile_rows.labels(tile).inc(n)
+                if linear_form is not None:
+                    m.counter_linear_attn_rows.labels(
+                        program, linear_form).inc(row_steps)
+                    m.counter_dispatch_state_bytes.labels(program).inc(
+                        state_bytes)
             m.counter_tokens.labels("real", phase).inc(real_tokens)
             m.counter_tokens.labels("pad", phase).inc(pad_tokens)
             for axis, ratio in fills:

@@ -1,0 +1,118 @@
+"""Seam of the gated delta rule with a per-channel decay (Kimi Delta
+Attention; labels `kda_chunk`, `kda_step`).
+
+Per head, with `S` a float32 matrix `[d_k, d_v]` (rows = key channels),
+`a_t = exp(g_t)` in (0, 1)^{d_k} and `b_t` in (0, 2):
+
+    S'  = diag(a_t) S_{t-1}
+    S_t = S' + b_t k_t (v_t - S'^T k_t)^T
+    o_t = S_t^T q_t
+
+`layers/linear_attention.py` calls the two functions below and nothing
+under them; each asks `ops/dispatch.use_kernel` once and runs its Pallas
+kernel (`ops/pallas/kda.py`: `_kda_chunk_call`, `_kda_step_call` in the
+device trace) or the jnp oracle beside it, which is the recurrence as
+written, a row at a time.
+
+The state lives in the layer's pool `[slots + 1, H, d_k, d_v]` float32
+(`worker/cache_engine.py`): a row's `slot` names its sequence's matrix,
+the last row of the pool is where pad rows point (`slot == slots`), and
+both functions return the pool updated in place (the caller donates it).
+
+- `kda_chunk` (a `mixed` step): rows of several sequences. A run of
+  consecutive rows of one slot at consecutive positions continues one
+  state: the run's first row starts from the slot's matrix, or from zero
+  at position 0 whatever the slot holds, and the run's last row writes the
+  slot. Nothing crosses a boundary between runs.
+- `kda_step` (a substep of a K-step decode program): every row is a run
+  of one token on its own slot.
+
+Arguments: `q`, `k`, `v` `[T, H, d]` (q, k normalised by the caller),
+`g` `[T, H, d_k]` float32 = log a_t <= 0, `beta` `[T, H]` float32.
+Outputs are float32 `[T, H, d_v]`.
+"""
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+from intellillm_tpu.ops.dispatch import use_kernel
+from intellillm_tpu.ops.pallas import kda as _kernels
+
+
+def delta_rule_update(state, q, k, v, g, beta):
+    """One token of the recurrence for a batch of heads: `state`
+    [..., d_k, d_v] float32, `q`, `k`, `g` [..., d_k], `v` [..., d_v],
+    `beta` [...]. Returns (o [..., d_v], new state), float32."""
+    f32 = jnp.float32
+    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    decayed = jnp.exp(g.astype(f32))[..., :, None] * state
+    u = v - jnp.einsum("...k,...kv->...v", k, decayed,
+                       precision=jax.lax.Precision.HIGHEST)
+    new = decayed + (beta.astype(f32)[..., None, None] * k[..., :, None]
+                     * u[..., None, :])
+    o = jnp.einsum("...k,...kv->...v", q, new,
+                   precision=jax.lax.Precision.HIGHEST)
+    return o, new
+
+
+def run_structure(slots: jnp.ndarray, positions: jnp.ndarray):
+    """(continues [T] bool, last [T] bool) of a `mixed` step's rows: a row
+    continues the row before it when that is the token before it of the
+    same sequence; a run's last row is one that the next row does not
+    continue."""
+    t = slots.shape[0]
+    prev_slot = jnp.pad(slots, (1, 0))[:t]
+    prev_pos = jnp.pad(positions, (1, 0))[:t]
+    continues = ((slots == prev_slot) & (positions == prev_pos + 1)
+                 & (jnp.arange(t) >= 1))
+    last = ~jnp.pad(continues[1:], (0, 1))
+    return continues, last
+
+
+def kda_chunk(q, k, v, g, beta, pool, slots, positions
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(o [T, H, d_v] float32, pool) of a `mixed` step's rows."""
+    if use_kernel("kda_chunk",
+                  _kernels.kda_supported(q, pool, _kernels.CHUNK)):
+        continues, _ = run_structure(slots, positions)
+        return _kernels.kda_chunk(q, k, v, g, beta, pool, slots, positions,
+                                  continues)
+    return kda_chunk_reference(q, k, v, g, beta, pool, slots, positions)
+
+
+def kda_step(q, k, v, g, beta, pool, slots
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(o [B, H, d_v] float32, pool) of one decode substep; a pad row's
+    slot is the pool's last row."""
+    if use_kernel("kda_step", _kernels.kda_supported(q, pool)):
+        return _kernels.kda_step(q, k, v, g, beta, pool, slots)
+    return kda_step_reference(q, k, v, g, beta, pool, slots)
+
+
+def kda_step_reference(q, k, v, g, beta, pool, slots):
+    o, new = delta_rule_update(pool[slots], q, k, v, g, beta)
+    return o, pool.at[slots].set(new)
+
+
+def kda_chunk_reference(q, k, v, g, beta, pool, slots, positions):
+    """The recurrence a row at a time, the current run's state carried
+    beside the pool."""
+    continues, last = run_structure(slots, positions)
+    pad = pool.shape[0] - 1
+
+    def row(carry, x):
+        pool, cur = carry
+        q_t, k_t, v_t, g_t, b_t, slot, pos, cont, is_last = x
+        start = jnp.where(pos == 0, jnp.zeros_like(cur), pool[slot])
+        o, new = delta_rule_update(jnp.where(cont, cur, start), q_t, k_t,
+                                   v_t, g_t, b_t)
+        pool = pool.at[jnp.where(is_last, slot, pad)].set(new)
+        return (pool, new), o
+
+    (pool, _), o = jax.lax.scan(
+        row, (pool, jnp.zeros(pool.shape[1:], pool.dtype)),
+        (q, k, v, g, beta, slots, positions, continues, last))
+    return o, pool

@@ -1,4 +1,6 @@
-"""Gate-filter-gate body of a gated short convolution, one pass.
+"""Bodies of the short convolutions, one pass each: the gate-filter-gate
+body of a gated short convolution, and (at the end of the file) a plain
+4-tap filter then SiLU.
 
 Per row t and channel e, with `[B | C | x]` the mixer's in-projection and
 `p1`, `p2` the gated inputs one and two positions before t in the row's
@@ -76,3 +78,45 @@ def short_conv(bcx, p1, p2, w):
     filter `w` [3, E] (tap 0 the oldest). Callers check
     `short_conv_supported` first."""
     return _short_conv_call(bcx, p1, p2, w)
+
+
+def conv_silu_supported(x: jnp.ndarray, prev: jnp.ndarray) -> bool:
+    """Whole sublane tiles of rows, whole lane tiles of channels, three
+    earlier taps."""
+    t, e = x.shape
+    rows = 8 if x.dtype.itemsize >= 4 else 16
+    return t % rows == 0 and e % 128 == 0 and prev.shape[0] == 3
+
+
+def _conv_silu_kernel(x_ref, p_ref, w_ref, y_ref):
+    f32 = jnp.float32
+    w = w_ref[...]
+    conv = w[3:4, :] * x_ref[...].astype(f32)
+    for j in range(3):
+        conv += w[j:j + 1, :] * p_ref[j].astype(f32)
+    y_ref[...] = (conv * jax.nn.sigmoid(conv)).astype(y_ref.dtype)
+
+
+@jax.jit
+def _conv_silu_call(x, prev, w):
+    t, e = x.shape
+    tr = next(r for r in _ROW_TILES if t % r == 0)
+    tc = next(c for c in _LANE_TILES if e % c == 0)
+    w8 = jnp.zeros((8, e), jnp.float32).at[:w.shape[0]].set(
+        w.astype(jnp.float32))
+    row = pl.BlockSpec((tr, tc), lambda i, j: (i, j))
+    return pl.pallas_call(
+        _conv_silu_kernel,
+        grid=(t // tr, e // tc),
+        in_specs=[row, pl.BlockSpec((3, tr, tc), lambda i, j: (0, i, j)),
+                  pl.BlockSpec((8, tc), lambda i, j: (0, j))],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((t, e), x.dtype),
+    )(x, prev, w8)
+
+
+def conv_silu(x, prev, w):
+    """y [T, E] = silu of the 4-tap filter `w` [4, E] (tap 0 the oldest)
+    over `prev` [3, T, E] (oldest first) and `x` [T, E]. Callers check
+    `conv_silu_supported` first."""
+    return _conv_silu_call(x, prev, w)

@@ -1,4 +1,10 @@
-"""Seam of the gated short convolution's body (label `short_conv`).
+"""Seam of the short convolutions' bodies (labels `short_conv`,
+`short_conv_silu`).
+
+Two bodies of one family, each a depthwise causal filter over a row's own
+past, resolved by the caller: LFM2's gate x 3-tap x gate
+(`short_conv_body`), and the plain 4-tap filter then SiLU that a
+delta-rule layer's q, k and v pass (`conv_silu_body`).
 
 `layers/short_conv.py` calls this and nothing below it; it asks
 `ops/dispatch.use_kernel` once and runs the Pallas kernel
@@ -11,10 +17,13 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 from intellillm_tpu.ops.dispatch import use_kernel
-from intellillm_tpu.ops.pallas.short_conv import (short_conv,
+from intellillm_tpu.ops.pallas.short_conv import (conv_silu,
+                                                  conv_silu_supported,
+                                                  short_conv,
                                                   short_conv_supported)
 
 
@@ -46,3 +55,22 @@ def short_conv_body_reference(bcx, p1, p2, w):
             + w[2] * g.astype(f32))
     y = (bcx[..., e:2 * e].astype(f32) * conv).astype(bcx.dtype)
     return y, g
+
+
+def conv_silu_body(x: jnp.ndarray, prev: jnp.ndarray,
+                   w: jnp.ndarray) -> jnp.ndarray:
+    """y [T, E] = silu(sum_j w[j] * tap_j) of a row's input `x` [T, E], its
+    inputs at the `taps - 1` positions before it `prev` [taps - 1, T, E]
+    (oldest first, 0 before the sequence's start) and the filter `w`
+    [taps, E] (tap 0 the oldest)."""
+    if use_kernel("short_conv_silu", conv_silu_supported(x, prev)):
+        return conv_silu(x, prev, w)
+    return conv_silu_body_reference(x, prev, w)
+
+
+def conv_silu_body_reference(x, prev, w):
+    f32 = jnp.float32
+    w = w.astype(f32)
+    taps = jnp.concatenate([prev.astype(f32), x.astype(f32)[None]], axis=0)
+    conv = (w[:, None, :] * taps).sum(0)
+    return (conv * jax.nn.sigmoid(conv)).astype(x.dtype)

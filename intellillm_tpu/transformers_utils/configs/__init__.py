@@ -17,6 +17,8 @@ from intellillm_tpu.transformers_utils.configs.lfm2_moe import Lfm2MoeConfig
 from intellillm_tpu.transformers_utils.configs.qwen import QWenConfig
 from intellillm_tpu.transformers_utils.configs.smallthinker import (
     SmallThinkerConfig)
+from intellillm_tpu.transformers_utils.configs.solar_open2 import (
+    SolarOpen2Config)
 from intellillm_tpu.transformers_utils.configs.yi import YiConfig
 
 _CONFIG_REGISTRY = {
@@ -30,6 +32,7 @@ _CONFIG_REGISTRY = {
     "lfm2_moe": Lfm2MoeConfig,
     "qwen": QWenConfig,
     "smallthinker": SmallThinkerConfig,
+    "solar_open2": SolarOpen2Config,
     "Yi": YiConfig,
     "yi": YiConfig,
 }
@@ -37,6 +40,6 @@ _CONFIG_REGISTRY = {
 __all__ = [
     "AquilaConfig", "AXK1Config", "BaichuanConfig", "ChatGLMConfig", "DeciLMConfig",
     "DeepseekConfig", "InternLMConfig", "Lfm2MoeConfig", "QWenConfig",
-    "SmallThinkerConfig", "YiConfig",
+    "SmallThinkerConfig", "SolarOpen2Config", "YiConfig",
     "_CONFIG_REGISTRY",
 ]

@@ -18,10 +18,15 @@ TPU redesign:
   audit's swap checksums work on both; KV export/import speaks (K, V) on
   the wire and refuses the latent kind with a message.
 - Layers are described one by one (`ModelConfig.get_layer_cache_kinds`):
-  a "kv" layer has the pools above; a "state" layer (a short convolution)
-  has ONE array `[num_state_slots, taps * width]` (a slot's taps side by
-  side, oldest first: whole lane tiles, no padded sublanes), a slot a
-  running sequence, not paged. `device_cache[i]` is layer i's tuple either way.
+  a "kv" layer has the pools above; a "state" layer has one pool an
+  array of `ModelConfig.get_state_arrays` (a short convolution: one; a
+  delta-rule layer: its filters' taps and a float32 matrix a head), a
+  slot a running sequence, not paged. A 2-d array is stored
+  `[num_state_slots, taps * width]` (a slot's taps side by side, oldest
+  first: whole lane tiles, no padded sublanes); a matrix a head is
+  `[num_state_slots + 1, heads, d_k, d_v]`, the last row where the pad
+  rows of a step program that updates the pool in place point
+  (`ops/linear_attention.py`). `device_cache[i]` is layer i's tuple either way.
   A "kv_window" layer (a sliding window beside layers that keep the whole
   context) has the same pools with the window group's own number of
   blocks (`CacheConfig.num_window_device_blocks`), and the window group's
@@ -76,7 +81,7 @@ class CacheEngine:
                               if kind == "kv_window"]
         self.state_layers = [i for i, kind in enumerate(self.layer_kinds)
                              if kind == "state"]
-        self.state_row_shape = model_config.get_state_row_shape()
+        self.state_arrays = model_config.get_state_arrays()
         self.num_state_slots = (cache_config.num_state_slots
                                 if self.state_layers else 0)
         # What a token stores in a layer (`get_cache_row_shape`): 2 pools
@@ -136,10 +141,18 @@ class CacheEngine:
         return (num_blocks, self.num_kv_heads, self.block_size,
                 self.head_size)
 
-    def _state_shape(self) -> Tuple[int, ...]:
-        # [slots, taps * width]; the same number of slots on the host.
-        taps, width = self.state_row_shape
-        return (self.num_state_slots, taps * width)
+    def _state_shapes(self, device: bool):
+        """(pool shape, dtype) of each array of a "state" layer; the host
+        holds the same number of slots (and no pad row)."""
+        return [(self.state_pool_shape(shape, self.num_state_slots, device),
+                 jnp.dtype(STR_DTYPE_TO_JNP[dtype]))
+                for shape, dtype in self.state_arrays]
+
+    @staticmethod
+    def state_pool_shape(shape, slots: int, device: bool = True):
+        if ModelConfig.state_array_in_place(shape):
+            return (slots + int(device), ) + tuple(shape)
+        return (slots, int(np.prod(shape)))
 
     def _allocate_device_cache(self) -> List[KVCache]:
         caches = []
@@ -148,10 +161,10 @@ class CacheEngine:
                                       if kind == "kv_window"
                                       else self.num_device_blocks)
             if kind == "state":
-                # The state keeps the activations' dtype whatever the KV
-                # pool's (`state_dtype`).
-                caches.append((jnp.zeros(self._state_shape(),
-                                         dtype=self.state_dtype), ))
+                # The state keeps its own dtypes whatever the KV pool's.
+                caches.append(tuple(
+                    jnp.zeros(pool, dtype=dtype)
+                    for pool, dtype in self._state_shapes(device=True)))
                 continue
             pools = []
             for _ in range(self.num_pools):
@@ -161,10 +174,6 @@ class CacheEngine:
                 pools.append(pool)
             caches.append(tuple(pools))
         return caches
-
-    @property
-    def state_dtype(self):
-        return jnp.dtype(STR_DTYPE_TO_JNP[self.model_config.dtype])
 
     @staticmethod
     def _host_dtype(dtype) -> np.dtype:
@@ -177,8 +186,8 @@ class CacheEngine:
 
     def _allocate_cpu_cache(self):
         np_dtype = self._host_dtype(self.dtype)
-        return [(np.zeros(self._state_shape(),
-                          dtype=self._host_dtype(self.state_dtype)), )
+        return [tuple(np.zeros(pool, dtype=self._host_dtype(dtype))
+                      for pool, dtype in self._state_shapes(device=False))
                 if kind == "state" else
                 tuple(np.zeros(self._block_shape(
                     self.num_window_cpu_blocks if kind == "kv_window"
@@ -293,7 +302,7 @@ class CacheEngine:
     # What KV export/import cannot carry, by cache kind.
     _KV_TRANSFER_REFUSALS = {
         "latent": "this model caches one latent pool a layer",
-        "state": "this model keeps a per-sequence convolution state "
+        "state": "this model keeps a per-sequence recurrent state "
                  "beside its K/V pools, which a prefix of blocks does not "
                  "hold (it would need a state snapshot at the prefix's end)",
         "window": "this model's sliding-window cache group has given back "
@@ -330,15 +339,15 @@ class CacheEngine:
     def swap_state_out(self, src_to_dst: Dict[int, int]) -> None:
         """Device slot -> host slot, every "state" layer."""
         for i in self.state_layers:
-            swap_blocks(self.device_cache[i][0], self.cpu_cache[i][0],
-                        src_to_dst, direction="out")
+            for dev, cpu in zip(self.device_cache[i], self.cpu_cache[i]):
+                swap_blocks(dev, cpu, src_to_dst, direction="out")
 
     def swap_state_in(self, src_to_dst: Dict[int, int]) -> None:
         """Host slot -> device slot, every "state" layer."""
         for i in self.state_layers:
-            self.device_cache[i] = (swap_blocks(
-                self.cpu_cache[i][0], self.device_cache[i][0], src_to_dst,
-                direction="in"), )
+            self.device_cache[i] = tuple(
+                swap_blocks(cpu, dev, src_to_dst, direction="in")
+                for cpu, dev in zip(self.cpu_cache[i], self.device_cache[i]))
 
     def copy_state(self, src_to_dsts: Dict[int, List[int]]) -> None:
         """A fork: the parent's slot copied to each child's."""
@@ -466,13 +475,18 @@ class CacheEngine:
     @staticmethod
     def get_state_slot_size(model_config: ModelConfig) -> int:
         """Bytes of one state slot across the "state" layers (0 for a
-        model without them), on the device: a slot's row padded to whole
-        lanes. The state is stored in the model's dtype: the cache dtype
-        quantises K/V only."""
-        row = model_config.get_state_row_shape()
-        if row is None:
-            return 0
-        taps, width = row
-        itemsize = jnp.dtype(STR_DTYPE_TO_JNP[model_config.dtype]).itemsize
-        return (model_config.get_num_state_layers()
-                * (-(-taps * width // 128) * 128) * itemsize)
+        model without them), on the device: each array's minor dimension
+        padded to whole lanes (a 2-d array is one row a slot), a matrix's
+        rows to whole sublane tiles. Every array has its own dtype: the
+        cache dtype quantises K/V only."""
+        total = 0
+        for shape, dtype in model_config.get_state_arrays():
+            itemsize = jnp.dtype(STR_DTYPE_TO_JNP[dtype]).itemsize
+            if ModelConfig.state_array_in_place(shape):
+                sublane = {4: 8, 2: 16, 1: 32}.get(itemsize, 8)
+                rows = -(-shape[-2] // sublane) * sublane
+                lead = int(np.prod(shape[:-2]))
+                total += lead * rows * (-(-shape[-1] // 128) * 128) * itemsize
+            else:
+                total += (-(-int(np.prod(shape)) // 128) * 128) * itemsize
+        return total and model_config.get_num_state_layers() * total

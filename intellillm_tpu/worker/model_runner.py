@@ -283,6 +283,17 @@ class ModelRunner:
             else (0, 1) if self.sliding_window
             else (1, 0))
         self.num_state_slots = cache_config.num_state_slots
+        state_arrays = model_config.get_state_arrays()
+        self._state_in_place = [ModelConfig.state_array_in_place(shape)
+                                for shape, _ in state_arrays]
+        # Bytes of matrix state (a linear-attention layer's, updated in
+        # its pool) one row-substep reads and writes, all layers.
+        self._matrix_state_bytes = (
+            2 * model_config.get_num_state_layers() * sum(
+                int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+                for (shape, dtype), in_place in zip(state_arrays,
+                                                    self._state_in_place)
+                if in_place))
         from intellillm_tpu.layers.attention import model_uses_alibi
         self._uses_alibi = model_uses_alibi(model)
         self.vocab_size = model_config.get_vocab_size()
@@ -422,6 +433,14 @@ class ModelRunner:
         (a step of decode rows hands in none)."""
         return {"run_starts": self._place_batch_array(
             _padded_run_starts(run_starts, padded_n))}
+
+    def _linear_attn_counts(self, staged: bool, row_steps: int):
+        """`record_dispatch`'s account of the delta rule: nothing for a
+        model without a matrix state."""
+        if not self._matrix_state_bytes:
+            return {}
+        return {"linear_form": "step" if staged else "chunk",
+                "state_bytes": row_steps * self._matrix_state_bytes}
 
     def _record_moe_ffn_rows(self, rows: int, substeps: int = 1) -> None:
         """`rows` x `substeps` of a dispatched program went through the
@@ -715,9 +734,24 @@ class ModelRunner:
         kinds = self.layer_kinds
         kv0 = kv_caches[self._kv0][0]
         hkv, d, cache_dtype = kv0.shape[1], kv0.shape[3], kv0.dtype
+        # A state layer's arrays, by kind (`ModelConfig.get_state_arrays`):
+        # a few rows of activations are gathered into a stage here,
+        # carried through the substeps and scattered back after the last;
+        # a matrix a head is carried as its pool, which the layer updates
+        # in place by slot (a pad row's slot is the pool's pad row). A
+        # row without a context is a pad row whatever slot it was given.
+        if state_slots is not None:
+            state_slots = jnp.where(base_ctx > 0, state_slots,
+                                    self.num_state_slots)
         state_stages = {
-            i: pools[0].at[state_slots].get(mode="fill", fill_value=0)
+            i: tuple(pool if in_place else
+                     pool.at[state_slots].get(mode="fill", fill_value=0)
+                     for pool, in_place in zip(pools, self._state_in_place))
             for i, pools in enumerate(kv_caches) if kinds[i] == "state"}
+        # What the scan closes over: the paged pools. A state layer's
+        # arrays are all in its stage.
+        fixed = [() if kinds[i] == "state" else tuple(pools)
+                 for i, pools in enumerate(kv_caches)]
 
         # Chunk schedule: full chunks plus a shorter tail when K is not a
         # multiple (e.g. K=40, C=16 → [16, 16, 8]). decode_chunk <= 0
@@ -731,7 +765,7 @@ class ModelRunner:
 
         from intellillm_tpu.ops.kv_cache import commit_staged_chunk
 
-        def make_substep(pool_ctx, cur_caches, chunk_base):
+        def make_substep(pool_ctx, cur_fixed, chunk_base):
             def substep(carry, k):
                 cur_tokens, stages = carry
                 if teacher_tokens is not None:
@@ -752,13 +786,15 @@ class ModelRunner:
                     window_block_tables=window_tables,
                 )
                 # A layer's cache is (K, V) or the 1-tuple (latent pool,);
-                # staged, the layer gets its pools then their stages.
+                # staged, the layer gets its pools then their stages (a
+                # state layer: its stage alone).
                 caches4 = [tuple(pools) + tuple(stage)
-                           for pools, stage in zip(cur_caches, stages)]
+                           for pools, stage in zip(cur_fixed, stages)]
                 hidden, caches4, counters = self._call_model(
                     params, cur_tokens[:, None], pos_k[:, None], caches4,
                     meta, lora)
-                stages = [tuple(c[len(c) // 2:]) for c in caches4]
+                stages = [tuple(c[len(pools):])
+                          for pools, c in zip(cur_fixed, caches4)]
                 g = (chunk_base + k).astype(jnp.uint32)
                 seeds_k = seeds + g * _SEED_STRIDE
                 (sampled, lp, tk_ids,
@@ -774,7 +810,7 @@ class ModelRunner:
                 return (next_tokens, stages), ys
             return substep
 
-        cur_caches = kv_caches
+        cur_caches = fixed
         cur_tokens = token_ids[:, 0]
         ys_chunks = []
         chunk_base = 0
@@ -786,7 +822,7 @@ class ModelRunner:
                 base_ctx > 0,
                 jnp.minimum(base_ctx - 1 + chunk_base, self.max_model_len),
                 0)
-            stages = [(state_stages[i], ) if kinds[i] == "state" else
+            stages = [state_stages[i] if kinds[i] == "state" else
                       tuple(jnp.zeros((b, csize, hkv, d), cache_dtype)
                             for _ in pools)
                       for i, pools in enumerate(cur_caches)]
@@ -805,7 +841,7 @@ class ModelRunner:
             n_valid = jnp.where(
                 base_ctx > 0,
                 jnp.clip(self.max_model_len - start, 0, csize), 0)
-            state_stages = {i: stages[i][0] for i in state_stages}
+            state_stages = {i: stages[i] for i in state_stages}
             cur_caches = [
                 pools if kinds[i] == "state" else
                 commit_staged_chunk(
@@ -815,14 +851,13 @@ class ModelRunner:
                 for i, (pools, stage) in enumerate(zip(cur_caches, stages))]
             chunk_base += csize
 
-        new_caches = cur_caches
-        if state_stages:
-            dst = jnp.where(base_ctx > 0, state_slots,
-                            self.num_state_slots)
-            new_caches = [
-                (pools[0].at[dst].set(state_stages[i], mode="drop"), )
-                if kinds[i] == "state" else pools
-                for i, pools in enumerate(cur_caches)]
+        new_caches = [
+            tuple(stage if in_place else
+                  pool.at[state_slots].set(stage, mode="drop")
+                  for pool, stage, in_place in zip(
+                      kv_caches[i], state_stages[i], self._state_in_place))
+            if kinds[i] == "state" else pools
+            for i, pools in enumerate(cur_caches)]
         # [K, B, ...] per ys leaf, chunks concatenated along the step axis.
         ys = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0),
                           *ys_chunks) if len(ys_chunks) > 1 else ys_chunks[0]
@@ -1283,7 +1318,9 @@ class ModelRunner:
             context_tokens=(int(eff_info["ctxs"].sum()) * num_steps
                             + real_rows * num_steps * (num_steps - 1) // 2),
             walked_tokens=walked, attended_tokens=attended,
-            tile_rows=tile_rows)
+            tile_rows=tile_rows,
+            **self._linear_attn_counts(num_steps > 1,
+                                       real_rows * num_steps))
         self._record_moe_ffn_rows(padded_n, num_steps)
 
         # ONE device→host transfer for everything, performed by
@@ -1551,7 +1588,8 @@ class ModelRunner:
                             ctx_arr - 1, ctx_arr, w, walkers),
                         attended_tokens=self._attended_tokens(
                             ctx_arr, int(ctx_arr.sum())),
-                        tile_rows=tile_rows)
+                        tile_rows=tile_rows,
+                        **self._linear_attn_counts(False, len(rows)))
         if n_chunk_groups:
             self._efficiency.record_dispatch(
                 "prefill", n_chunk_groups, n_chunk_groups,
@@ -1675,7 +1713,8 @@ class ModelRunner:
             row_steps=live_rows * num_steps,
             context_tokens=(int(ctx.sum()) * num_steps
                             + live_rows * num_steps * (num_steps - 1) // 2),
-            walked_tokens=walked, attended_tokens=attended)
+            walked_tokens=walked, attended_tokens=attended,
+            **self._linear_attn_counts(True, live_rows * num_steps))
         self._record_moe_ffn_rows(b, num_steps)
 
         step = InflightStep(self, packed, cont.metas, cont.rows, num_steps,

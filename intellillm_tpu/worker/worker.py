@@ -160,7 +160,8 @@ class Worker:
         temp_bytes += stage_bytes + int(0.10 * weights_bytes)
         # Per-sequence state pools (0 for a model without state layers):
         # a fixed size beside the blocks, and once more as the decode
-        # programs' stage of the rows' own states.
+        # programs' stage of the rows' own states (an upper bound: a
+        # matrix state is updated in its pool and has no stage).
         state_bytes = 2 * (CacheEngine.get_state_slot_size(self.model_config)
                            * self.scheduler_config.max_num_seqs)
         available = (int(total * hbm_utilization) - weights_bytes
@@ -202,12 +203,13 @@ class Worker:
         cache_shape = jax.ShapeDtypeStruct(
             (dummy_blocks, nkv, self.cache_config.block_size, hs),
             jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]))
-        state_row = self.model_config.get_state_row_shape()
-        state_shape = state_row and jax.ShapeDtypeStruct(
-            (self.scheduler_config.max_num_seqs,
-             state_row[0] * state_row[1]),
-            jnp.dtype(STR_DTYPE_TO_JNP[self.model_config.dtype]))
-        kv_struct = [(state_shape, ) if kind == "state"
+        state_shapes = tuple(
+            jax.ShapeDtypeStruct(
+                CacheEngine.state_pool_shape(
+                    shape, self.scheduler_config.max_num_seqs),
+                jnp.dtype(STR_DTYPE_TO_JNP[dtype]))
+            for shape, dtype in self.model_config.get_state_arrays())
+        kv_struct = [state_shapes if kind == "state"
                      else (cache_shape, ) * pools
                      for kind in self.model_config.get_layer_cache_kinds()]
 

@@ -398,9 +398,9 @@ def test_what_the_state_cannot_do_is_refused_with_a_reason(ckpt):
         engine.add_request("p", None, greedy(4),
                            prompt_token_ids=prompts_of(cfg, (40, ))[0],
                            prefix_pos=16)
-    with pytest.raises(NotImplementedError, match="convolution state"):
+    with pytest.raises(NotImplementedError, match="recurrent state"):
         engine.worker.cache_engine.export_blocks([0, 1])
-    with pytest.raises(NotImplementedError, match="convolution state"):
+    with pytest.raises(NotImplementedError, match="recurrent state"):
         engine.worker.cache_engine.import_blocks([], [])
 
 
@@ -412,7 +412,7 @@ def test_config_describes_the_layers_one_by_one(ckpt):
     assert mc.get_layer_cache_kinds() == [
         "state", "state", "kv", "state", "state", "state"]
     assert (mc.get_num_kv_layers(), mc.get_num_state_layers()) == (1, 5)
-    assert mc.get_state_row_shape() == (2, 64)
+    assert mc.get_state_arrays() == (((2, 64), "float32"), )
     # a 16-wide head stored at one 128-lane tile
     assert mc.get_head_size() == 16
     assert mc.get_cache_row_shape() == (2, 2, 128)

@@ -151,7 +151,7 @@ def test_ledger_math_against_fake_param_tree_and_cache_sizing():
         get_num_kv_layers=lambda: 2,
         get_num_window_layers=lambda: 0,
         get_window_group_share=lambda ahead: 0.0,
-        get_state_row_shape=lambda: None)
+        get_state_arrays=lambda: ())
     w = Worker.__new__(Worker)
     w.params = params
     w.model_config = model_config

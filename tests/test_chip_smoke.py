@@ -57,6 +57,16 @@ def test_one_chip_flow_rehearses_then_refuses():
     [parity] = [x for x in lines
                 if x.get("phase") == "parity" and "tokens_equal" in x]
     assert parity["tokens_equal"] and parity["co_batched_steps"] >= 1
+    # The state model: prefill + decode through the slots against a fresh
+    # full pass, the two arrays of a slot as the config describes them.
+    [state] = [x for x in lines
+               if x.get("phase") == "state" and "tokens_wrong" in x]
+    assert state["tokens_wrong"] == [] and state["max_abs_dlogprob"] < 1e-3
+    assert state["state_arrays"] == [[[3, 3 * 4 * 128], "float32"],
+                                     [[4, 128, 128], "float32"]]
+    assert state["slots_free_at_the_end"] == state["state_slots"] == 8
+    assert {"reference:kda_chunk", "reference:kda_step"} <= set(
+        state["kernel_dispatch"])          # the CPU serves the oracles
     # It got as far as the device check, after every phase.
     assert lines[-1]["phase"] == "all"
 
@@ -74,5 +84,6 @@ def test_four_chip_flow_rehearses_then_refuses():
     assert all(tp["pool_bytes_per_device"].values())
     assert tp["mixed_step_collectives"]
     # Only the tp phase and what it is compared with: no server booted.
-    assert not any(x.get("phase") in ("serve", "parity") for x in lines)
+    assert not any(x.get("phase") in ("serve", "parity", "state")
+                   for x in lines)
     assert lines[-1]["phase"] == "all"

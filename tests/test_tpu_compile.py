@@ -805,6 +805,99 @@ def test_state_model_programs_compile(compiled_for_chip, one_chip, tmp_path,
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024**3
 
 
+# --- a matrix state a head: the delta rule on the pool, in place ------------
+
+@pytest.mark.parametrize("program", ["mixed", "decode_cont"])
+def test_matrix_state_model_programs_compile(compiled_for_chip, one_chip,
+                                             tmp_path, program):
+    """`solar-open2-ep8-l4`'s programs as its cell dispatches them (`mixed`
+    at 512 rows, `decode_cont` at 128 rows x 64-block tables, K=8, bf16,
+    published widths; depth cut to the GQA layer and one KDA layer) compile
+    for the described v5e, hold the delta rule's kernel of that program
+    (`_kda_chunk_call` / `_kda_step_call`) and the filter's, and carry the
+    541 MB matrix pool through the K substeps without a copy of it: the
+    program's temporaries stay under the pool's own size."""
+    import json
+
+    from intellillm_tpu.config import (CacheConfig, ModelConfig,
+                                       ParallelConfig, SchedulerConfig)
+    from intellillm_tpu.models import get_model_class
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    from intellillm_tpu.worker.cache_engine import CacheEngine
+    from intellillm_tpu.worker.model_runner import ModelRunner
+
+    from chipbench import CHIP_DIR      # puts the harness on sys.path
+    from harness.server import hf_config_of
+
+    with open(os.path.join(CHIP_DIR, "configs",
+                           "solar-open2-ep8-l4.json")) as f:
+        cell = json.load(f)
+    serve = cell["serve"]
+    hf = hf_config_of(cell, rehearsal=False)
+    hf["num_hidden_layers"] = 2
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    model_config = ModelConfig(
+        model=str(tmp_path), dtype=serve["dtype"], load_format="dummy",
+        max_model_len=serve["max_model_len"])
+    kinds = model_config.get_layer_cache_kinds()
+    assert kinds == ["kv", "state"]
+    model = get_model_class(model_config.hf_config.architectures)(
+        model_config)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init_random_params(0)))
+    cache_config = CacheConfig(num_device_blocks_override=CELL_BLOCKS,
+                               swap_space_gib=0.01)
+    cache_config.num_state_slots = CELL_ROWS
+    set_use_pallas(True)        # jax.default_backend() is "cpu" here
+    try:
+        runner = ModelRunner(
+            model, params, model_config,
+            SchedulerConfig(max_num_batched_tokens=512,
+                            max_num_seqs=CELL_ROWS,
+                            max_model_len=serve["max_model_len"],
+                            enable_chunked_prefill=True),
+            cache_config, ParallelConfig())
+
+        def sds(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        bf16, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+        state = tuple(
+            sds(jnp.dtype(dtype),
+                *CacheEngine.state_pool_shape(shape, CELL_ROWS))
+            for shape, dtype in model_config.get_state_arrays())
+        assert [s.shape for s in state] == [(128, 73728),
+                                            (129, 64, 128, 128)]
+        kv = [state if kind == "state" else
+              (sds(bf16, CELL_BLOCKS, 8, 16, 128), ) * 2 for kind in kinds]
+        assert not jax.config.jax_enable_compilation_cache
+        if program == "mixed":
+            compiled = runner.lower_mixed_step(
+                kv, 512, CELL_TABLE_WIDTH, do_random=False).compile()
+            kernel = "_kda_chunk_call"
+        else:
+            b, w, k = CELL_ROWS, CELL_TABLE_WIDTH, CELL_K
+            rest = (sds(i32, b, 1), sds(i32, b, w), sds(i32, b),
+                    sds(f32, b), sds(i32, b), sds(f32, b), sds(f32, b),
+                    sds(jnp.uint32, b), sds(f32, b), sds(f32, b),
+                    sds(f32, b), None, None)
+            compiled = runner._jit_decode_cont.lower(
+                params, kv, sds(i32, b, 4 * k), sds(i32, b, 1),
+                sds(jnp.bool_, b, 1), *rest, state_slots=sds(i32, b),
+                prev_t1=k, num_steps=k, logprob_k=1, do_topk=False,
+                do_topp=False, do_minp=False, do_penalties=False,
+                do_random=False).compile()
+            kernel = "_kda_step_call"
+    finally:
+        set_use_pallas(None)
+    text = compiled.as_text()
+    assert kernel in text and "_conv_silu_call" in text
+    assert text.count("tpu_custom_call") >= 3
+    pool_bytes = 129 * 64 * 128 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
 # --- window and full layers: two cache groups a step ------------------------
 
 @pytest.mark.parametrize("program", ["mixed", "decode_cont"])

@@ -138,7 +138,7 @@ def test_models_the_repo_had_describe_every_layer_alike():
                      num_key_value_heads=2, max_position_embeddings=128)
     mc = ModelConfig.from_hf_config(hf, dtype="float32", max_model_len=128)
     assert mc.get_layer_cache_kinds() == ["kv"] * 3
-    assert mc.get_state_row_shape() is None
+    assert mc.get_state_arrays() == ()
     assert (mc.get_num_kv_layers(), mc.get_num_state_layers()) == (3, 0)
     assert mc.get_cache_row_shape() == (2, 2, 16)
     assert CacheEngine.get_state_slot_size(mc) == 0
@@ -148,3 +148,69 @@ def test_models_the_repo_had_describe_every_layer_alike():
     ce = CacheEngine(cache, mc, ParallelConfig())
     assert ce.state_layers == [] and ce.num_state_slots == 0
     assert [len(p) for p in ce.device_cache] == [2, 2, 2]
+
+
+# --- a state layer of two arrays, of two dtypes -------------------------------
+
+def matrix_state_engine(dtype="bfloat16", slots=4):
+    from intellillm_tpu.transformers_utils.configs import SolarOpen2Config
+    hf = SolarOpen2Config(
+        vocab_size=64, hidden_size=64, moe_intermediate_size=32,
+        num_hidden_layers=4, gqa_layers=[0], num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16,
+        linear_attn_config={"short_conv_kernel_size": 4, "head_dim": 16,
+                            "num_heads": 4, "num_kv_heads": None},
+        n_routed_experts=8, num_experts_per_tok=4,
+        max_position_embeddings=128)
+    mc = ModelConfig.from_hf_config(hf, dtype=dtype, max_model_len=128)
+    cache = CacheConfig(block_size=16, swap_space_gib=0.001,
+                        num_device_blocks_override=8)
+    cache.num_device_blocks, cache.num_cpu_blocks = 8, 8
+    cache.num_state_slots = slots
+    return CacheEngine(cache, mc, ParallelConfig())
+
+
+def test_a_state_layer_is_a_tuple_of_pools_each_with_its_dtype():
+    """The short convolution's one array is the 1-tuple case of the same
+    description."""
+    assert model_config().get_state_arrays() == (((2, 64), "float32"), )
+    ce = matrix_state_engine()
+    assert ce.layer_kinds == ["kv", "state", "state", "state"]
+    assert ce.state_arrays == (((3, 192), "bfloat16"),
+                               ((4, 16, 16), "float32"))
+    for i in ce.state_layers:
+        taps, matrices = ce.device_cache[i]
+        # taps a row a slot; the matrices with the pad row the step form's
+        # pad rows point at, which the host pool does not need
+        assert (taps.shape, taps.dtype) == ((4, 576), jnp.bfloat16)
+        assert (matrices.shape, matrices.dtype) == ((5, 4, 16, 16),
+                                                    jnp.float32)
+        assert [c.shape for c in ce.cpu_cache[i]] == [(4, 576),
+                                                      (4, 4, 16, 16)]
+        assert ce.cpu_cache[i][1].dtype == np.float32
+    assert ce.state_slot_bytes == 3 * (640 * 2 + 4 * 16 * 128 * 4)
+
+
+def test_state_ops_move_every_array_of_a_slot():
+    ce = matrix_state_engine(dtype="float32")
+    rng = np.random.default_rng(0)
+    for i in ce.state_layers:
+        ce.device_cache[i] = tuple(
+            jnp.asarray(rng.normal(size=p.shape).astype(np.float32))
+            for p in ce.device_cache[i])
+    before = [[np.asarray(p) for p in ce.device_cache[i]]
+              for i in ce.state_layers]
+    ce.execute_state_ops([("fork", 1, 3), ("swap_out", 1, 2)])
+    for i, was in zip(ce.state_layers, before):
+        for pool, host, old in zip(ce.device_cache[i], ce.cpu_cache[i], was):
+            now = np.asarray(pool)
+            np.testing.assert_array_equal(now[3], old[1])   # the copy
+            np.testing.assert_array_equal(host[2], old[1])
+            np.testing.assert_array_equal(now[[0, 1, 2]], old[[0, 1, 2]])
+    ce.execute_state_ops([("swap_in", 2, 0)])
+    for i, was in zip(ce.state_layers, before):
+        for pool, old in zip(ce.device_cache[i], was):
+            np.testing.assert_array_equal(np.asarray(pool)[0], old[1])
+        # the pad row is nobody's slot and no move touches it
+        np.testing.assert_array_equal(
+            np.asarray(ce.device_cache[i][1])[4], was[1][4])
