@@ -41,6 +41,7 @@ from intellillm_tpu.layers.short_conv import _prev_rows
 from intellillm_tpu.ops.linear_attention import (kda_chunk, kda_step,
                                                  run_structure)
 from intellillm_tpu.ops.short_conv import conv_silu_body
+from intellillm_tpu.ops.state_rows import gather_rows, scatter_rows
 
 _NORM_EPS = 1e-6
 
@@ -77,7 +78,7 @@ def kda_mixer(h, lp, cache, attn_metadata, positions, *, num_heads: int,
         held = taps
     else:
         continues, last = run_structure(slots, pos)
-        held = taps.at[slots].get(mode="fill", fill_value=0)  # [B, 3 * W]
+        held = gather_rows(taps, slots)                       # [B, 3 * W]
         # `back` rows of a row's own run lie above it in the step (3 and
         # more count as 3).
         back = jnp.zeros((b, ), jnp.int32)
@@ -117,7 +118,7 @@ def kda_mixer(h, lp, cache, attn_metadata, positions, *, num_heads: int,
         # Only a run's last row writes its slot; the others, and pad
         # rows, point past the pool and are dropped.
         dst = jnp.where(last, slots, taps.shape[0])
-        cache = (taps.at[dst].set(new_taps, mode="drop"), matrices)
+        cache = (scatter_rows(taps, dst, new_taps), matrices)
     gate = jax.nn.sigmoid(((x @ lp["g_a"]) @ lp["g_b"]).astype(jnp.float32))
     o = rms_norm(o, lp["o_norm"].astype(jnp.float32), eps)
     y = (o.reshape(b, width // 3) * gate).astype(h.dtype)

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 
 from intellillm_tpu.ops.linear_attention import run_structure
 from intellillm_tpu.ops.short_conv import gated_input, short_conv_body
+from intellillm_tpu.ops.state_rows import gather_rows, scatter_rows
 
 
 def _prev_rows(x: jnp.ndarray, n: int) -> jnp.ndarray:
@@ -61,7 +62,7 @@ def short_conv_mixer(h, w_in, conv_w, w_out, cache, attn_metadata,
         same1, last = run_structure(slots, pos)
         same2 = same1 & _prev_rows(same1, 1)
         g_rows = gated_input(bcx)
-        held = pool.at[slots].get(mode="fill", fill_value=0)   # [B, 2E]
+        held = gather_rows(pool, slots)                        # [B, 2E]
         older, newer = held[:, :e], held[:, e:]
         p1 = jnp.where(same1[:, None], _prev_rows(g_rows, 1), newer)
         p2 = jnp.where(same2[:, None], _prev_rows(g_rows, 2),
@@ -77,5 +78,5 @@ def short_conv_mixer(h, w_in, conv_w, w_out, cache, attn_metadata,
         # Only a sequence's last row in the step writes its slot; the
         # others, and pad rows, point past the pool and are dropped.
         dst = jnp.where(last, slots, pool.shape[0])
-        cache = (pool.at[dst].set(new, mode="drop"), )
+        cache = (scatter_rows(pool, dst, new), )
     return (y @ w_out).reshape(b, 1, e), cache

@@ -50,6 +50,7 @@ from intellillm_tpu.native import build_decode_batch
 from intellillm_tpu.obs import (get_compile_tracker,
                                 get_efficiency_tracker, get_kernel_ledger,
                                 get_step_tracer)
+from intellillm_tpu.ops.state_rows import gather_rows, scatter_rows
 from intellillm_tpu.sampling_params import SamplingParams, SamplingType
 from intellillm_tpu.sequence import (SamplerOutput, SequenceGroupMetadata,
                                      SequenceGroupOutput, SequenceOutput)
@@ -744,8 +745,7 @@ class ModelRunner:
             state_slots = jnp.where(base_ctx > 0, state_slots,
                                     self.num_state_slots)
         state_stages = {
-            i: tuple(pool if in_place else
-                     pool.at[state_slots].get(mode="fill", fill_value=0)
+            i: tuple(pool if in_place else gather_rows(pool, state_slots)
                      for pool, in_place in zip(pools, self._state_in_place))
             for i, pools in enumerate(kv_caches) if kinds[i] == "state"}
         # What the scan closes over: the paged pools. A state layer's
@@ -853,7 +853,7 @@ class ModelRunner:
 
         new_caches = [
             tuple(stage if in_place else
-                  pool.at[state_slots].set(stage, mode="drop")
+                  scatter_rows(pool, state_slots, stage)
                   for pool, stage, in_place in zip(
                       kv_caches[i], state_stages[i], self._state_in_place))
             if kinds[i] == "state" else pools

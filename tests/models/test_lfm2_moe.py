@@ -583,3 +583,16 @@ def test_reference_layers_match_transformers_lfm2():
         got = ref.rms_norm(x, w["model.embedding_norm.weight"], 1e-5)
     np.testing.assert_allclose(np.asarray(got), want.numpy(), rtol=2e-4,
                                atol=2e-4)
+
+
+@pytest.mark.parametrize("case", ["pad_row_with_a_slot", "permuted_slots"])
+def test_decode_stage_moves_the_state_as_the_at_forms_did(ckpt, monkeypatch,
+                                                          case):
+    """One K=8 fused decode step on pools of random bytes, the conv
+    state's rows gathered and scattered by `ops/state_rows.py` and by the
+    `.at[]` forms it replaced: the same tokens, every pool the same bytes.
+    A row without a context is a pad row whatever slot it names: that
+    slot keeps its bytes."""
+    from tests.models import state_stage
+    _, _, d = ckpt
+    assert state_stage.assert_same_step(make_llm(d), monkeypatch, case) == 5

@@ -477,3 +477,16 @@ def test_published_size_slot_bytes():
     assert CacheEngine.state_pool_shape((3, 24576), 128) == (128, 73728)
     assert CacheEngine.state_pool_shape((64, 128, 128), 128,
                                         device=False) == (128, 64, 128, 128)
+
+
+@pytest.mark.parametrize("case", ["pad_row_with_a_slot", "permuted_slots"])
+def test_decode_stage_moves_the_taps_as_the_at_forms_did(ckpt, monkeypatch,
+                                                         case):
+    """One K=8 fused decode step on pools of random bytes, the taps' rows
+    gathered and scattered by `ops/state_rows.py` and by the `.at[]` forms
+    it replaced: the same tokens, every pool the same bytes. A row without
+    a context is a pad row whatever slot it names: that slot's taps keep
+    their bytes."""
+    from tests.models import state_stage
+    _, _, d = ckpt
+    assert state_stage.assert_same_step(make_llm(d), monkeypatch, case) == 6

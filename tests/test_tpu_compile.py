@@ -723,6 +723,34 @@ def test_joined_continuation_is_the_plain_one_s_executable(
 
 # --- a hybrid stack: state layers, 64-wide heads stored at 128 lanes --------
 
+
+def _row_loops(text, shape):
+    """The `while` bodies of a compiled program that hold, themselves or
+    in a fusion they call, a `dynamic-update-slice` whose result is
+    `shape` (as `bf16[128,73728]`): a scatter that XLA expanded into a
+    loop of one row a turn. (A K-step scan replaces its carried stage
+    whole and holds none.)"""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    dus = re.compile(r" = " + re.escape(shape) + r"\S* dynamic-update-slice\(")
+
+    def holds(comp):
+        return any(dus.search(line) or any(
+            holds(c) for c in re.findall(r"calls=%([\w.\-]+)", line))
+            for line in comps.get(comp, ()))
+
+    bodies = {b for lines in comps.values() for line in lines
+              if " while(" in line
+              for b in re.findall(r"body=%([\w.\-]+)", line)}
+    return sorted(b for b in bodies if holds(b))
+
+
 @pytest.mark.parametrize("program", ["mixed", "decode_cont"])
 def test_state_model_programs_compile(compiled_for_chip, one_chip, tmp_path,
                                       program):
@@ -731,7 +759,8 @@ def test_state_model_programs_compile(compiled_for_chip, one_chip, tmp_path,
     published widths; depth cut to the two dense conv layers + one period)
     compile for the described v5e with the state slots' argument, and hold
     the attention kernel (a 64-wide head through the 128-lane pool) and
-    the short-convolution kernel."""
+    the short-convolution kernel; no loop of a row a turn writes the conv
+    state's pool."""
     import json
 
     from intellillm_tpu.config import (CacheConfig, ModelConfig,
@@ -801,6 +830,7 @@ def test_state_model_programs_compile(compiled_for_chip, one_chip, tmp_path,
     text = compiled.as_text()
     assert "_short_conv_call" in text
     assert text.count("tpu_custom_call") >= 2
+    assert _row_loops(text, "bf16[128,4096]") == []
     # One layer of each kind plus the head stay far inside the chip.
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024**3
 
@@ -816,7 +846,9 @@ def test_matrix_state_model_programs_compile(compiled_for_chip, one_chip,
     for the described v5e, hold the delta rule's kernel of that program
     (`_kda_chunk_call` / `_kda_step_call`) and the filter's, and carry the
     541 MB matrix pool through the K substeps without a copy of it: the
-    program's temporaries stay under the pool's own size."""
+    program's temporaries stay under the pool's own size. The taps pool's
+    rows are written by slot in one pass (`ops/state_rows.py`): no loop of
+    a row a turn (13 us each on the chip, 128-512 turns a layer)."""
     import json
 
     from intellillm_tpu.config import (CacheConfig, ModelConfig,
@@ -894,6 +926,7 @@ def test_matrix_state_model_programs_compile(compiled_for_chip, one_chip,
     text = compiled.as_text()
     assert kernel in text and "_conv_silu_call" in text
     assert text.count("tpu_custom_call") >= 3
+    assert _row_loops(text, "bf16[128,73728]") == []
     pool_bytes = 129 * 64 * 128 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
