@@ -23,13 +23,15 @@ jax: a chip belongs to one process at a time, and the children need it):
 3. `parity`  — the same widths at 2 layers, built twice in one process:
                Pallas kernels on, then `set_use_pallas(False)`; the same
                prompts must give the same greedy tokens.
-4. `state`   — a model with per-sequence state: one Solar Open 2 period
-               (a gated GQA layer and three delta-rule layers with a
-               float32 matrix a head in the state slots) at 128-wide
-               heads; what prefill then K=8 decode through the slots
-               produced must equal a fresh full pass over the same
-               tokens (the step form on the pool against the chunk form
-               from position 0).
+4. `state`   — two models with per-sequence state, one after the other:
+               one Solar Open 2 period (a gated GQA layer and three
+               delta-rule layers with a float32 matrix a head in the
+               state slots) at 128-wide heads, and a Granite 4.0-H
+               period cut to four layers (three Mamba-2 scan layers with
+               a float32 matrix [128, inner] and a NoPE GQA layer); what
+               prefill then K=8 decode through the slots produced must
+               equal a fresh full pass over the same tokens (the step
+               form on the pool against the chunk form from position 0).
 
 Every stdout line is one JSON object. The last one is
 `{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`
@@ -622,14 +624,48 @@ def solar2_config(size: str):
         max_position_embeddings=4096)
 
 
+def granite4h_config(size: str):
+    """A Granite 4.0-H period cut to (mamba, mamba, attention, mamba) at
+    the size's hidden width: scan heads of 64 over 128 state channels (the
+    widths the scan's kernels need), attention heads of 128 at a softmax
+    scale of 1/128, 8 of 16 experts held, a shared MLP, the four
+    multipliers as published."""
+    from intellillm_tpu.transformers_utils.configs import (
+        GraniteMoeHybridConfig)
+    hidden, _, _, _, vocab = WIDTHS[size]
+    heads = hidden // 128
+    return GraniteMoeHybridConfig(
+        architectures=["GraniteMoeHybridForCausalLM"], vocab_size=vocab,
+        hidden_size=hidden, intermediate_size=256,
+        shared_intermediate_size=512, num_hidden_layers=4,
+        layer_types=["mamba", "mamba", "attention", "mamba"],
+        num_attention_heads=heads, num_key_value_heads=max(heads // 4, 1),
+        mamba_n_heads=2 * hidden // 64, mamba_d_head=64, mamba_d_state=128,
+        num_local_experts=8, ep_size=2, ep_rank=0, num_experts_per_tok=4,
+        max_position_embeddings=4096)
+
+
+# (model, its config, the seams whose kernels a TPU has to run)
+STATE_MODELS = (
+    ("solar_open2", solar2_config, ("kda_chunk", "kda_step",
+                                    "short_conv_silu")),
+    ("granitemoehybrid", granite4h_config, ("ssd_chunk", "ssd_step",
+                                            "short_conv_silu")))
+
+
 def phase_state(args) -> None:
-    """A model with per-sequence state (a matrix a head, in the state
+    for model, config_of, seams in STATE_MODELS:
+        state_model(args, model, config_of(args.size), seams)
+
+
+def state_model(args, model: str, hf_config, seams) -> None:
+    """A model with per-sequence state (a float32 matrix in the state
     slots): what prefill then K=8 decode through the slots produced equals
     a fresh full pass. Each request's prompt + its first n tokens, sent as
     a new prompt, must choose token n + 1 again, at the same logprob: the
-    first run reached that token through `kda_step` on the pool by slot
-    (and a chunked prefill for the long prompt), the second through
-    `kda_chunk` from position 0."""
+    first run reached that token through the step form on the pool by slot
+    (and a chunked prefill for the long prompt), the second through the
+    chunk form from position 0."""
     import jax
 
     from intellillm_tpu.config import (CacheConfig, ModelConfig,
@@ -641,8 +677,9 @@ def phase_state(args) -> None:
     cfg = SIZES[args.size]
     vocab = WIDTHS[args.size][4]
     t0 = time.monotonic()
+    before = dict(get_compile_tracker().snapshot()["kernel_dispatch"])
     model_config = ModelConfig.from_hf_config(
-        solar2_config(args.size), dtype="float32",
+        hf_config, dtype="float32",
         max_model_len=cfg["small_mml"], load_format="dummy", seed=args.seed)
     engine = LLMEngine(
         model_config,
@@ -693,8 +730,9 @@ def phase_state(args) -> None:
             if tok != first[rid][0][cut]:
                 wrong.append((rid, cut, first[rid][0][cut], tok))
     bm = engine.scheduler.block_manager
-    dispatch = get_compile_tracker().snapshot()["kernel_dispatch"]
-    emit({"phase": "state", "layers": 4, "dtype": "float32",
+    dispatch = {k: v for k, v in get_compile_tracker().snapshot()[
+        "kernel_dispatch"].items() if v > before.get(k, 0)}
+    emit({"phase": "state", "model": model, "layers": 4, "dtype": "float32",
           "f32_matmul_precision": precision, "prompt_lens": lens,
           "state_arrays": [[list(s), d] for s, d in
                            model_config.get_state_arrays()],
@@ -705,16 +743,15 @@ def phase_state(args) -> None:
           "wall_s": round(time.monotonic() - t0, 1)})
     drop_engine(engine)
     if jax.default_backend() == "tpu":
-        check(all(dispatch.get(f"pallas:{k}") for k in
-                  ("kda_chunk", "kda_step", "short_conv_silu"))
-              and not any(k.startswith("reference:kda") for k in dispatch),
-              f"the delta rule's seams did not run their kernels: "
-              f"{dispatch}")
+        check(all(dispatch.get(f"pallas:{k}") for k in seams)
+              and not any(dispatch.get(f"reference:{k}") for k in seams),
+              f"{model}: the state layers' seams did not run their "
+              f"kernels: {dispatch}")
     check(len(bm._free_state_slots) == bm.num_state_slots,
           "a state slot was not given back")
     check(not wrong and worst < 1e-3,
-          f"prefill + decode through the slots differs from a fresh full "
-          f"pass: tokens {wrong}, max |d logprob| {worst:.3g}")
+          f"{model}: prefill + decode through the slots differs from a "
+          f"fresh full pass: tokens {wrong}, max |d logprob| {worst:.3g}")
 
 
 def phase_tp(args) -> None:

@@ -21,6 +21,18 @@ logger = init_logger(__name__)
 _GiB = 1024**3
 
 
+def ssd_state_shape(inner: int, d_state: int) -> Tuple[int, int, int]:
+    """`(tiles, d_state, lanes)`: a state-space layer's matrix a sequence,
+    `[d_state, inner]` with the `inner` channels (head-major) cut into
+    whole 128-lane tiles where they divide so (one tile otherwise: a tiny
+    test model). The state dimension lies on the sublanes: what a token
+    contracts with C, and everything that is one number a channel (the
+    head's decay, dt x) lies along the lanes
+    (`ops/pallas/ssd.py`)."""
+    lanes = 128 if inner % 128 == 0 else inner
+    return inner // lanes, d_state, lanes
+
+
 class ModelConfig:
     """Model + tokenizer + dtype + length limits.
 
@@ -166,10 +178,13 @@ class ModelConfig:
         tokens only and its blocks are a group of their own, beside
         layers that keep the whole context) or "state" (a fixed-size
         per-sequence state of `get_state_arrays`, one slot a running
-        sequence, not paged). A config that lists `layer_types` and a
-        short-convolution cache length (`conv_L_cache`) has "state" where
-        its type is not an attention, one with a `linear_attn_config`
-        wherever `gqa_layers` does not list the layer; one that lists a
+        sequence, not paged). Three descriptions give "state" layers: a
+        config that lists `layer_types` and a short-convolution cache
+        length (`conv_L_cache`) has one where the type is not an
+        attention; one with a `linear_attn_config` wherever `gqa_layers`
+        does not list the layer; one whose `layer_types` say "mamba" (a
+        selective state-space scan: `mamba_d_state`, `mamba_n_heads`)
+        where they say it. One that lists a
         `sliding_window_layout` of ones and zeros and a
         `sliding_window_size` has "kv_window" where the layout says 1,
         unless it says 1 everywhere (then the window is the whole
@@ -185,6 +200,8 @@ class ModelConfig:
             softmax = set(getattr(self.hf_config, "gqa_layers", None) or ())
             return ["kv" if i in softmax else "state" for i in range(n)]
         types = getattr(self.hf_config, "layer_types", None)
+        if types and "mamba" in types[:n]:
+            return ["state" if t == "mamba" else "kv" for t in types[:n]]
         if not types or not getattr(self.hf_config, "conv_L_cache", None):
             return ["kv"] * n
         return ["kv" if "attention" in t else "state" for t in types[:n]]
@@ -218,14 +235,24 @@ class ModelConfig:
     def get_state_arrays(self) -> Tuple[Tuple[Tuple[int, ...], str], ...]:
         """What one sequence keeps in one "state" layer: a tuple of
         (shape, dtype), one entry an array; () for a model without such
-        layers. A short convolution keeps its last `conv_L_cache - 1`
-        gated inputs, `hidden_size` channels each, in the model's dtype
-        (the 1-tuple case). A delta-rule linear-attention layer keeps the
-        last `short_conv_kernel_size - 1` inputs of its q, k and v
-        filters side by side, in the model's dtype, and a float32 matrix
-        `[key channels, value channels]` a head: the state is summed
-        over every token so far, and a 2-byte state's rounding would be
-        as large as what a token adds to it.
+        layers. Three descriptions:
+
+        - a short convolution (`conv_L_cache`) keeps its last
+          `conv_L_cache - 1` gated inputs, `hidden_size` channels each,
+          in the model's dtype (the 1-tuple case);
+        - a delta-rule linear-attention layer (`linear_attn_config`)
+          keeps the last `short_conv_kernel_size - 1` inputs of its q, k
+          and v filters side by side, in the model's dtype, and a float32
+          matrix `[key channels, value channels]` a head;
+        - a selective state-space scan (`layer_types` "mamba") keeps the
+          last `mamba_d_conv - 1` inputs of the one filter over x, B and
+          C (`d_inner + 2 x n_groups x d_state` channels), in the model's
+          dtype, and the float32 matrix `[d_state, d_inner]`, its channels
+          cut into lane tiles (`ssd_state_shape`).
+
+        A matrix state is float32 whatever the model's dtype: it is
+        summed over every token so far, and a 2-byte state's rounding
+        would be as large as what a token adds to it.
 
         A 2-d entry (a few rows of activations) is stored as one row a
         slot and gathered into the decode programs' stage; an entry of
@@ -233,12 +260,18 @@ class ModelConfig:
         slot (`state_array_in_place`)."""
         if "state" not in self.get_layer_cache_kinds():
             return ()
-        linear = getattr(self.hf_config, "linear_attn_config", None)
+        cfg = self.hf_config
+        linear = getattr(cfg, "linear_attn_config", None)
         if linear:
             heads, d = linear["num_heads"], linear["head_dim"]
             taps = linear["short_conv_kernel_size"] - 1
             return (((taps, 3 * heads * d), self.dtype),
                     ((heads, d, d), "float32"))
+        if "mamba" in (getattr(cfg, "layer_types", None) or ()):
+            inner = cfg.mamba_n_heads * cfg.mamba_d_head
+            filtered = inner + 2 * cfg.mamba_n_groups * cfg.mamba_d_state
+            return (((cfg.mamba_d_conv - 1, filtered), self.dtype),
+                    (ssd_state_shape(inner, cfg.mamba_d_state), "float32"))
         return (((self.hf_config.conv_L_cache - 1,
                   self.hf_config.hidden_size), self.dtype), )
 

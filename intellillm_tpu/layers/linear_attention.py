@@ -62,18 +62,16 @@ def kda_gates(x, lp, num_heads: int, head_dim: int):
     return g, beta
 
 
-def kda_mixer(h, lp, cache, attn_metadata, positions, *, num_heads: int,
-              head_dim: int, eps: float):
-    """`h` [B, 1, E] -> ([B, 1, E], cache). `cache` is (taps pool, matrix
-    pool) or, staged, (taps stage, matrix pool)."""
-    b, l, e = h.shape
-    assert l == 1, "a step's rows are one token each"
-    x = h.reshape(b, e)
-    width = 3 * num_heads * head_dim
-    pre = x @ lp["qkv"]                                       # [B, 3HD]
-    pos = positions.reshape(b)
+def earlier_taps(pre, taps, attn_metadata, pos):
+    """(prev [3, B, W], last): a 4-tap filter's three earlier inputs of
+    every row of `pre` [B, W] (oldest first, 0 before the sequence's
+    start), and which rows end their run (None staged). `taps` is the
+    layer's taps pool `[slots, 3 * W]` or, staged, its stage `[B, 3 * W]`.
+    In a `mixed` step a row's tap n back is the run's own row n back where
+    the step holds it, else what the slot held when the run began."""
+    b, width = pre.shape
     slots = attn_metadata.state_slots
-    taps, matrices = cache
+    last = None
     if attn_metadata.staged:
         held = taps
     else:
@@ -101,24 +99,49 @@ def kda_mixer(h, lp, cache, attn_metadata, positions, *, num_heads: int,
                             from_slot)
         # Before the sequence's start there is nothing.
         prev.append(jnp.where((pos >= n)[:, None], tap, 0).astype(pre.dtype))
-    prev = jnp.stack(prev)                                    # [3, B, W]
+    return jnp.stack(prev), last                              # [3, B, W]
+
+
+def later_taps(prev, pre, dtype):
+    """[B, 3 * W]: what the row after this one finds as its taps."""
+    return jnp.concatenate([prev[1], prev[2], pre], axis=1).astype(dtype)
+
+
+def store_taps(taps, new_taps, attn_metadata, last):
+    """The layer's taps after the step: staged, the new stage; in a
+    `mixed` step the pool, where only a run's last row writes its slot
+    (the others, and pad rows, point past the pool and are dropped)."""
+    if attn_metadata.staged:
+        return new_taps
+    dst = jnp.where(last, attn_metadata.state_slots, taps.shape[0])
+    return scatter_rows(taps, dst, new_taps)
+
+
+def kda_mixer(h, lp, cache, attn_metadata, positions, *, num_heads: int,
+              head_dim: int, eps: float):
+    """`h` [B, 1, E] -> ([B, 1, E], cache). `cache` is (taps pool, matrix
+    pool) or, staged, (taps stage, matrix pool)."""
+    b, l, e = h.shape
+    assert l == 1, "a step's rows are one token each"
+    x = h.reshape(b, e)
+    width = 3 * num_heads * head_dim
+    pre = x @ lp["qkv"]                                       # [B, 3HD]
+    pos = positions.reshape(b)
+    slots = attn_metadata.state_slots
+    taps, matrices = cache
+    prev, last = earlier_taps(pre, taps, attn_metadata, pos)
     qkv = conv_silu_body(pre, prev, lp["conv_w"])
     q, k, v = (qkv[:, i * width // 3:(i + 1) * width // 3].reshape(
         b, num_heads, head_dim) for i in range(3))
     q = _l2_normalise(q) * head_dim ** -0.5
     k = _l2_normalise(k)
     g, beta = kda_gates(x, lp, num_heads, head_dim)
-    new_taps = jnp.concatenate([prev[1], prev[2], pre], axis=1).astype(
-        taps.dtype)
+    new_taps = later_taps(prev, pre, taps.dtype)
     if attn_metadata.staged:
         o, matrices = kda_step(q, k, v, g, beta, matrices, slots)
-        cache = (new_taps, matrices)
     else:
         o, matrices = kda_chunk(q, k, v, g, beta, matrices, slots, pos)
-        # Only a run's last row writes its slot; the others, and pad
-        # rows, point past the pool and are dropped.
-        dst = jnp.where(last, slots, taps.shape[0])
-        cache = (scatter_rows(taps, dst, new_taps), matrices)
+    cache = (store_taps(taps, new_taps, attn_metadata, last), matrices)
     gate = jax.nn.sigmoid(((x @ lp["g_a"]) @ lp["g_b"]).astype(jnp.float32))
     o = rms_norm(o, lp["o_norm"].astype(jnp.float32), eps)
     y = (o.reshape(b, width // 3) * gate).astype(h.dtype)

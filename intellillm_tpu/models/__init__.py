@@ -22,6 +22,8 @@ from intellillm_tpu.models.decilm import DeciLMForCausalLM
 from intellillm_tpu.models.internlm import InternLMForCausalLM
 from intellillm_tpu.models.lfm2_moe import Lfm2MoeForCausalLM
 from intellillm_tpu.models.llama import LlamaForCausalLM
+from intellillm_tpu.models.granitemoehybrid import (
+    GraniteMoeHybridForCausalLM)
 from intellillm_tpu.models.mixtral import MixtralForCausalLM
 from intellillm_tpu.models.mpt import MPTForCausalLM
 from intellillm_tpu.models.opt import OPTForCausalLM
@@ -73,6 +75,8 @@ _MODEL_REGISTRY: Dict[str, Type] = {
     "SmallThinkerForCausalLM": SmallThinkerForCausalLM,
     # delta-rule linear attention (matrix state) + gated NoPE GQA, EP share
     "SolarOpen2ForCausalLM": SolarOpen2ForCausalLM,
+    # Mamba-2 state-space scan (matrix state) + NoPE GQA, EP share
+    "GraniteMoeHybridForCausalLM": GraniteMoeHybridForCausalLM,
 }
 
 

@@ -171,10 +171,11 @@ class _EfficiencyMetrics:
         self.counter_linear_attn_rows = Counter(
             "intellillm_linear_attn_rows_total",
             "Live rows x substeps of the dispatched programs that went "
-            "through a linear-attention layer's delta rule, by its form "
-            "(form: chunk = the `mixed` programs' chunk form over runs of "
-            "rows; step = a decode substep on the state pool by slot). "
-            "Stays 0 for a model without such layers.",
+            "through a matrix-state layer's recurrence (a delta rule, a "
+            "state-space scan), by its form (form: chunk = the `mixed` "
+            "programs' chunk form over runs of rows; step = a decode "
+            "substep on the state pool by slot). Stays 0 for a model "
+            "whose state, if any, is not updated in its pool.",
             ["program", "form"])
         self.counter_dispatch_state_bytes = Counter(
             "intellillm_dispatch_state_bytes_total",
@@ -532,10 +533,10 @@ class EfficiencyTracker:
         what those row-substeps attend to in one layer of that kind.
         `tile_rows`: by RAGGED_TILES, a `mixed` step's live rows by the
         tile the ragged kernel ran them in. `linear_form` (of
-        LINEAR_ATTN_FORMS; None for a model without linear-attention
-        layers): the form of the delta rule the row-substeps went
-        through, and `state_bytes` the matrix state they read and
-        wrote."""
+        LINEAR_ATTN_FORMS; None for a model without a matrix state
+        updated in its pool): the form of the recurrence the
+        row-substeps went through, and `state_bytes` the matrix state
+        they read and wrote."""
         if not self.enabled:
             return
         real_tokens = int(real_tokens)

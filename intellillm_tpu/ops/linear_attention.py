@@ -1,5 +1,11 @@
-"""Seam of the gated delta rule with a per-channel decay (Kimi Delta
-Attention; labels `kda_chunk`, `kda_step`).
+"""Seam of the recurrences that keep a float32 matrix a sequence in a state
+pool: the gated delta rule with a per-channel decay (Kimi Delta Attention;
+labels `kda_chunk`, `kda_step`) and, at the end of the file, the selective
+state-space scan with a scalar decay a head and no delta term (Mamba-2;
+labels `ssd_chunk`, `ssd_step`). Both bodies share the contract of the
+pool, the slots and the runs (`run_structure`).
+
+The delta rule.
 
 Per head, with `S` a float32 matrix `[d_k, d_v]` (rows = key channels),
 `a_t = exp(g_t)` in (0, 1)^{d_k} and `b_t` in (0, 2):
@@ -40,6 +46,7 @@ import jax.numpy as jnp
 
 from intellillm_tpu.ops.dispatch import use_kernel
 from intellillm_tpu.ops.pallas import kda as _kernels
+from intellillm_tpu.ops.pallas import ssd as _ssd
 
 
 def delta_rule_update(state, q, k, v, g, beta):
@@ -116,3 +123,87 @@ def kda_chunk_reference(q, k, v, g, beta, pool, slots, positions):
         row, (pool, jnp.zeros(pool.shape[1:], pool.dtype)),
         (q, k, v, g, beta, slots, positions, continues, last))
     return o, pool
+
+
+# --- the selective state-space scan ------------------------------------------
+#
+# Per head h of `P` channels, with `H_t` a float32 matrix `[N, P]` (rows =
+# state channels), `dt_t` > 0 and `a` < 0 one number a head, `B_t`, `C_t`
+# in R^N shared by every head (one group):
+#
+#     H_t = exp(dt_t a) H_{t-1} + B_t (dt_t x_t)^T
+#     y_t = H_t^T C_t
+#
+# (the skip `D x_t` is the layer's). Nothing in it couples two channels of
+# `x`, so a sequence's state is one matrix `[N, heads x P]` whose columns
+# decay by their head's factor; the pool holds it with the columns cut
+# into lane tiles, `[slots + 1, tiles, N, lanes]` (`config.ssd_state_shape`).
+# The contract is `kda_chunk`'s / `kda_step`'s: a run of consecutive rows
+# of one slot continues one state, starts from zero at position 0 whatever
+# the slot holds, its last row writes the slot, nothing crosses runs, pad
+# rows point at the pool's last row.
+#
+# Arguments: `x` [T, H, P], `dt` [T, H] float32, `a` [H] float32, `b`, `c`
+# [T, N]. Outputs are float32 `[T, H, P]`.
+
+
+def ssd_update(state, x, dt, a, b, c):
+    """One token of the scan: `state` [..., tiles, N, lanes] float32, `x`
+    [..., H, P], `dt` [..., H], `a` [H], `b`, `c` [..., N]. Returns (y
+    [..., H, P], new state), float32."""
+    f32 = jnp.float32
+    p = x.shape[-1]
+    lead, (tiles, _, lanes) = state.shape[:-3], state.shape[-3:]
+    dt = dt.astype(f32)
+    keep = jnp.repeat(jnp.exp(dt * a.astype(f32)), p, axis=-1)
+    dtx = dt[..., None] * x.astype(f32)
+    by_tile = lead + (tiles, 1, lanes)
+    new = (keep.reshape(by_tile) * state
+           + b.astype(f32)[..., None, :, None] * dtx.reshape(by_tile))
+    y = (c.astype(f32)[..., None, :, None] * new).sum(-2)
+    return y.reshape(x.shape), new
+
+
+def ssd_chunk(x, dt, a, b, c, pool, slots, positions
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(y [T, H, P] float32, pool) of a `mixed` step's rows."""
+    if use_kernel("ssd_chunk", _ssd.ssd_supported(x, b, pool)):
+        continues, last = run_structure(slots, positions)
+        return _ssd.ssd_chunk(x, dt, a, b, c, pool, slots, positions,
+                              continues, last)
+    return ssd_chunk_reference(x, dt, a, b, c, pool, slots, positions)
+
+
+def ssd_step(x, dt, a, b, c, pool, slots
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(y [B, H, P] float32, pool) of one decode substep; a pad row's
+    slot is the pool's last row."""
+    if use_kernel("ssd_step", _ssd.ssd_supported(x, b, pool)):
+        return _ssd.ssd_step(x, dt, a, b, c, pool, slots)
+    return ssd_step_reference(x, dt, a, b, c, pool, slots)
+
+
+def ssd_step_reference(x, dt, a, b, c, pool, slots):
+    y, new = ssd_update(pool[slots], x, dt, a, b, c)
+    return y, pool.at[slots].set(new)
+
+
+def ssd_chunk_reference(x, dt, a, b, c, pool, slots, positions):
+    """The recurrence a row at a time, the current run's state carried
+    beside the pool."""
+    continues, last = run_structure(slots, positions)
+    pad = pool.shape[0] - 1
+
+    def row(carry, r):
+        pool, cur = carry
+        x_t, dt_t, b_t, c_t, slot, pos, cont, is_last = r
+        start = jnp.where(pos == 0, jnp.zeros_like(cur), pool[slot])
+        y, new = ssd_update(jnp.where(cont, cur, start), x_t, dt_t, a, b_t,
+                            c_t)
+        pool = pool.at[jnp.where(is_last, slot, pad)].set(new)
+        return (pool, new), y
+
+    (pool, _), y = jax.lax.scan(
+        row, (pool, jnp.zeros(pool.shape[1:], pool.dtype)),
+        (x, dt, b, c, slots, positions, continues, last))
+    return y, pool

@@ -1,6 +1,6 @@
 """Bodies of the short convolutions, one pass each: the gate-filter-gate
 body of a gated short convolution, and (at the end of the file) a plain
-4-tap filter then SiLU.
+4-tap filter, with or without a bias, then SiLU.
 
 Per row t and channel e, with `[B | C | x]` the mixer's in-projection and
 `p1`, `p2` the gated inputs one and two positions before t in the row's
@@ -97,16 +97,29 @@ def _conv_silu_kernel(x_ref, p_ref, w_ref, y_ref):
     y_ref[...] = (conv * jax.nn.sigmoid(conv)).astype(y_ref.dtype)
 
 
+def _conv_bias_silu_kernel(x_ref, p_ref, w_ref, y_ref):
+    """The same filter with a bias a channel inside the SiLU: row 4 of
+    the filter's tile."""
+    f32 = jnp.float32
+    w = w_ref[...]
+    conv = w[4:5, :] + w[3:4, :] * x_ref[...].astype(f32)
+    for j in range(3):
+        conv += w[j:j + 1, :] * p_ref[j].astype(f32)
+    y_ref[...] = (conv * jax.nn.sigmoid(conv)).astype(y_ref.dtype)
+
+
 @jax.jit
-def _conv_silu_call(x, prev, w):
+def _conv_silu_call(x, prev, w, bias=None):
     t, e = x.shape
     tr = next(r for r in _ROW_TILES if t % r == 0)
     tc = next(c for c in _LANE_TILES if e % c == 0)
     w8 = jnp.zeros((8, e), jnp.float32).at[:w.shape[0]].set(
         w.astype(jnp.float32))
+    if bias is not None:
+        w8 = w8.at[4].set(bias.astype(jnp.float32))
     row = pl.BlockSpec((tr, tc), lambda i, j: (i, j))
     return pl.pallas_call(
-        _conv_silu_kernel,
+        _conv_silu_kernel if bias is None else _conv_bias_silu_kernel,
         grid=(t // tr, e // tc),
         in_specs=[row, pl.BlockSpec((3, tr, tc), lambda i, j: (0, i, j)),
                   pl.BlockSpec((8, tc), lambda i, j: (0, j))],
@@ -115,8 +128,9 @@ def _conv_silu_call(x, prev, w):
     )(x, prev, w8)
 
 
-def conv_silu(x, prev, w):
+def conv_silu(x, prev, w, bias=None):
     """y [T, E] = silu of the 4-tap filter `w` [4, E] (tap 0 the oldest)
-    over `prev` [3, T, E] (oldest first) and `x` [T, E]. Callers check
+    over `prev` [3, T, E] (oldest first) and `x` [T, E], plus `bias` [E]
+    inside the SiLU where there is one. Callers check
     `conv_silu_supported` first."""
-    return _conv_silu_call(x, prev, w)
+    return _conv_silu_call(x, prev, w, bias)

@@ -3,8 +3,9 @@
 
 Two bodies of one family, each a depthwise causal filter over a row's own
 past, resolved by the caller: LFM2's gate x 3-tap x gate
-(`short_conv_body`), and the plain 4-tap filter then SiLU that a
-delta-rule layer's q, k and v pass (`conv_silu_body`).
+(`short_conv_body`), and the plain 4-tap filter then SiLU
+(`conv_silu_body`) that a delta-rule layer's q, k and v pass and, with a
+bias a channel inside the SiLU, a state-space layer's x, B and C.
 
 `layers/short_conv.py` calls this and nothing below it; it asks
 `ops/dispatch.use_kernel` once and runs the Pallas kernel
@@ -15,7 +16,7 @@ from the state at that precision).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,20 +58,23 @@ def short_conv_body_reference(bcx, p1, p2, w):
     return y, g
 
 
-def conv_silu_body(x: jnp.ndarray, prev: jnp.ndarray,
-                   w: jnp.ndarray) -> jnp.ndarray:
-    """y [T, E] = silu(sum_j w[j] * tap_j) of a row's input `x` [T, E], its
-    inputs at the `taps - 1` positions before it `prev` [taps - 1, T, E]
-    (oldest first, 0 before the sequence's start) and the filter `w`
-    [taps, E] (tap 0 the oldest)."""
+def conv_silu_body(x: jnp.ndarray, prev: jnp.ndarray, w: jnp.ndarray,
+                   bias: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """y [T, E] = silu(sum_j w[j] * tap_j + bias) of a row's input `x`
+    [T, E], its inputs at the `taps - 1` positions before it `prev`
+    [taps - 1, T, E] (oldest first, 0 before the sequence's start), the
+    filter `w` [taps, E] (tap 0 the oldest) and, where the filter has
+    one, its bias [E]."""
     if use_kernel("short_conv_silu", conv_silu_supported(x, prev)):
-        return conv_silu(x, prev, w)
-    return conv_silu_body_reference(x, prev, w)
+        return conv_silu(x, prev, w, bias)
+    return conv_silu_body_reference(x, prev, w, bias)
 
 
-def conv_silu_body_reference(x, prev, w):
+def conv_silu_body_reference(x, prev, w, bias=None):
     f32 = jnp.float32
     w = w.astype(f32)
     taps = jnp.concatenate([prev.astype(f32), x.astype(f32)[None]], axis=0)
     conv = (w[:, None, :] * taps).sum(0)
+    if bias is not None:
+        conv = conv + bias.astype(f32)
     return (conv * jax.nn.sigmoid(conv)).astype(x.dtype)

@@ -12,6 +12,8 @@ from intellillm_tpu.transformers_utils.configs.baichuan import BaichuanConfig
 from intellillm_tpu.transformers_utils.configs.chatglm import ChatGLMConfig
 from intellillm_tpu.transformers_utils.configs.decilm import DeciLMConfig
 from intellillm_tpu.transformers_utils.configs.deepseek import DeepseekConfig
+from intellillm_tpu.transformers_utils.configs.granitemoehybrid import (
+    GraniteMoeHybridConfig)
 from intellillm_tpu.transformers_utils.configs.internlm import InternLMConfig
 from intellillm_tpu.transformers_utils.configs.lfm2_moe import Lfm2MoeConfig
 from intellillm_tpu.transformers_utils.configs.qwen import QWenConfig
@@ -28,6 +30,7 @@ _CONFIG_REGISTRY = {
     "chatglm": ChatGLMConfig,
     "deci": DeciLMConfig,
     "deepseek": DeepseekConfig,
+    "granitemoehybrid": GraniteMoeHybridConfig,
     "internlm": InternLMConfig,
     "lfm2_moe": Lfm2MoeConfig,
     "qwen": QWenConfig,
@@ -39,7 +42,8 @@ _CONFIG_REGISTRY = {
 
 __all__ = [
     "AquilaConfig", "AXK1Config", "BaichuanConfig", "ChatGLMConfig", "DeciLMConfig",
-    "DeepseekConfig", "InternLMConfig", "Lfm2MoeConfig", "QWenConfig",
+    "DeepseekConfig", "GraniteMoeHybridConfig", "InternLMConfig",
+    "Lfm2MoeConfig", "QWenConfig",
     "SmallThinkerConfig", "SolarOpen2Config", "YiConfig",
     "_CONFIG_REGISTRY",
 ]

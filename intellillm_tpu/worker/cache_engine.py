@@ -20,8 +20,9 @@ TPU redesign:
 - Layers are described one by one (`ModelConfig.get_layer_cache_kinds`):
   a "kv" layer has the pools above; a "state" layer has one pool an
   array of `ModelConfig.get_state_arrays` (a short convolution: one; a
-  delta-rule layer: its filters' taps and a float32 matrix a head), a
-  slot a running sequence, not paged. A 2-d array is stored
+  delta-rule layer: its filters' taps and a float32 matrix a head; a
+  state-space scan: its filter's taps and a float32 matrix in lane
+  tiles), a slot a running sequence, not paged. A 2-d array is stored
   `[num_state_slots, taps * width]` (a slot's taps side by side, oldest
   first: whole lane tiles, no padded sublanes); a matrix a head is
   `[num_state_slots + 1, heads, d_k, d_v]`, the last row where the pad

@@ -287,8 +287,9 @@ class ModelRunner:
         state_arrays = model_config.get_state_arrays()
         self._state_in_place = [ModelConfig.state_array_in_place(shape)
                                 for shape, _ in state_arrays]
-        # Bytes of matrix state (a linear-attention layer's, updated in
-        # its pool) one row-substep reads and writes, all layers.
+        # Bytes of matrix state (a delta-rule or state-space layer's,
+        # updated in its pool) one row-substep reads and writes, all
+        # layers.
         self._matrix_state_bytes = (
             2 * model_config.get_num_state_layers() * sum(
                 int(np.prod(shape)) * jnp.dtype(dtype).itemsize
@@ -436,8 +437,10 @@ class ModelRunner:
             _padded_run_starts(run_starts, padded_n))}
 
     def _linear_attn_counts(self, staged: bool, row_steps: int):
-        """`record_dispatch`'s account of the delta rule: nothing for a
-        model without a matrix state."""
+        """`record_dispatch`'s account of a matrix state's recurrence (a
+        delta rule, a state-space scan): nothing for a model none of
+        whose state arrays is updated in its pool
+        (`ModelConfig.state_array_in_place`)."""
         if not self._matrix_state_bytes:
             return {}
         return {"linear_form": "step" if staged else "chunk",

@@ -57,16 +57,25 @@ def test_one_chip_flow_rehearses_then_refuses():
     [parity] = [x for x in lines
                 if x.get("phase") == "parity" and "tokens_equal" in x]
     assert parity["tokens_equal"] and parity["co_batched_steps"] >= 1
-    # The state model: prefill + decode through the slots against a fresh
-    # full pass, the two arrays of a slot as the config describes them.
-    [state] = [x for x in lines
-               if x.get("phase") == "state" and "tokens_wrong" in x]
-    assert state["tokens_wrong"] == [] and state["max_abs_dlogprob"] < 1e-3
-    assert state["state_arrays"] == [[[3, 3 * 4 * 128], "float32"],
+    # The state models: prefill + decode through the slots against a fresh
+    # full pass, the two arrays of a slot as each config describes them.
+    solar, granite = [x for x in lines
+                      if x.get("phase") == "state" and "tokens_wrong" in x]
+    for state in (solar, granite):
+        assert state["tokens_wrong"] == []
+        assert state["max_abs_dlogprob"] < 1e-3
+        assert state["slots_free_at_the_end"] == state["state_slots"] == 8
+    assert solar["state_arrays"] == [[[3, 3 * 4 * 128], "float32"],
                                      [[4, 128, 128], "float32"]]
-    assert state["slots_free_at_the_end"] == state["state_slots"] == 8
+    # taps over x, B and C (1,024 + 2 x 128); the matrix [128, 1,024] in
+    # eight lane tiles
+    assert granite["state_arrays"] == [[[3, 1280], "float32"],
+                                       [[8, 128, 128], "float32"]]
+    # the CPU serves the oracles
     assert {"reference:kda_chunk", "reference:kda_step"} <= set(
-        state["kernel_dispatch"])          # the CPU serves the oracles
+        solar["kernel_dispatch"])
+    assert {"reference:ssd_chunk", "reference:ssd_step"} <= set(
+        granite["kernel_dispatch"])
     # It got as far as the device check, after every phase.
     assert lines[-1]["phase"] == "all"
 

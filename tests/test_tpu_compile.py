@@ -837,18 +837,12 @@ def test_state_model_programs_compile(compiled_for_chip, one_chip, tmp_path,
 
 # --- a matrix state a head: the delta rule on the pool, in place ------------
 
-@pytest.mark.parametrize("program", ["mixed", "decode_cont"])
-def test_matrix_state_model_programs_compile(compiled_for_chip, one_chip,
-                                             tmp_path, program):
-    """`solar-open2-ep8-l4`'s programs as its cell dispatches them (`mixed`
-    at 512 rows, `decode_cont` at 128 rows x 64-block tables, K=8, bf16,
-    published widths; depth cut to the GQA layer and one KDA layer) compile
-    for the described v5e, hold the delta rule's kernel of that program
-    (`_kda_chunk_call` / `_kda_step_call`) and the filter's, and carry the
-    541 MB matrix pool through the K substeps without a copy of it: the
-    program's temporaries stay under the pool's own size. The taps pool's
-    rows are written by slot in one pass (`ops/state_rows.py`): no loop of
-    a row a turn (13 us each on the chip, 128-512 turns a layer)."""
+def _matrix_state_program(one_chip, tmp_path, program, config_name,
+                          hf_patch, kinds, state_shapes):
+    """`config_name`'s `mixed` (512 rows) or `decode_cont` (128 rows x
+    64-block tables, K=8) program as its cell dispatches it, bf16 at the
+    published widths with `hf_patch` laid over the config (the depth cut),
+    compiled for the described v5e."""
     import json
 
     from intellillm_tpu.config import (CacheConfig, ModelConfig,
@@ -861,18 +855,15 @@ def test_matrix_state_model_programs_compile(compiled_for_chip, one_chip,
     from chipbench import CHIP_DIR      # puts the harness on sys.path
     from harness.server import hf_config_of
 
-    with open(os.path.join(CHIP_DIR, "configs",
-                           "solar-open2-ep8-l4.json")) as f:
+    with open(os.path.join(CHIP_DIR, "configs", config_name + ".json")) as f:
         cell = json.load(f)
     serve = cell["serve"]
-    hf = hf_config_of(cell, rehearsal=False)
-    hf["num_hidden_layers"] = 2
+    hf = dict(hf_config_of(cell, rehearsal=False), **hf_patch)
     (tmp_path / "config.json").write_text(json.dumps(hf))
     model_config = ModelConfig(
         model=str(tmp_path), dtype=serve["dtype"], load_format="dummy",
         max_model_len=serve["max_model_len"])
-    kinds = model_config.get_layer_cache_kinds()
-    assert kinds == ["kv", "state"]
+    assert model_config.get_layer_cache_kinds() == kinds
     model = get_model_class(model_config.hf_config.architectures)(
         model_config)
     params = jax.tree.map(
@@ -899,34 +890,75 @@ def test_matrix_state_model_programs_compile(compiled_for_chip, one_chip,
             sds(jnp.dtype(dtype),
                 *CacheEngine.state_pool_shape(shape, CELL_ROWS))
             for shape, dtype in model_config.get_state_arrays())
-        assert [s.shape for s in state] == [(128, 73728),
-                                            (129, 64, 128, 128)]
+        assert [s.shape for s in state] == state_shapes
         kv = [state if kind == "state" else
               (sds(bf16, CELL_BLOCKS, 8, 16, 128), ) * 2 for kind in kinds]
         assert not jax.config.jax_enable_compilation_cache
         if program == "mixed":
-            compiled = runner.lower_mixed_step(
+            return runner.lower_mixed_step(
                 kv, 512, CELL_TABLE_WIDTH, do_random=False).compile()
-            kernel = "_kda_chunk_call"
-        else:
-            b, w, k = CELL_ROWS, CELL_TABLE_WIDTH, CELL_K
-            rest = (sds(i32, b, 1), sds(i32, b, w), sds(i32, b),
-                    sds(f32, b), sds(i32, b), sds(f32, b), sds(f32, b),
-                    sds(jnp.uint32, b), sds(f32, b), sds(f32, b),
-                    sds(f32, b), None, None)
-            compiled = runner._jit_decode_cont.lower(
-                params, kv, sds(i32, b, 4 * k), sds(i32, b, 1),
-                sds(jnp.bool_, b, 1), *rest, state_slots=sds(i32, b),
-                prev_t1=k, num_steps=k, logprob_k=1, do_topk=False,
-                do_topp=False, do_minp=False, do_penalties=False,
-                do_random=False).compile()
-            kernel = "_kda_step_call"
+        b, w, k = CELL_ROWS, CELL_TABLE_WIDTH, CELL_K
+        rest = (sds(i32, b, 1), sds(i32, b, w), sds(i32, b),
+                sds(f32, b), sds(i32, b), sds(f32, b), sds(f32, b),
+                sds(jnp.uint32, b), sds(f32, b), sds(f32, b),
+                sds(f32, b), None, None)
+        return runner._jit_decode_cont.lower(
+            params, kv, sds(i32, b, 4 * k), sds(i32, b, 1),
+            sds(jnp.bool_, b, 1), *rest, state_slots=sds(i32, b),
+            prev_t1=k, num_steps=k, logprob_k=1, do_topk=False,
+            do_topp=False, do_minp=False, do_penalties=False,
+            do_random=False).compile()
     finally:
         set_use_pallas(None)
+
+
+@pytest.mark.parametrize("program", ["mixed", "decode_cont"])
+def test_matrix_state_model_programs_compile(compiled_for_chip, one_chip,
+                                             tmp_path, program):
+    """`solar-open2-ep8-l4`'s programs as its cell dispatches them (`mixed`
+    at 512 rows, `decode_cont` at 128 rows x 64-block tables, K=8, bf16,
+    published widths; depth cut to the GQA layer and one KDA layer) compile
+    for the described v5e, hold the delta rule's kernel of that program
+    (`_kda_chunk_call` / `_kda_step_call`) and the filter's, and carry the
+    541 MB matrix pool through the K substeps without a copy of it: the
+    program's temporaries stay under the pool's own size. The taps pool's
+    rows are written by slot in one pass (`ops/state_rows.py`): no loop of
+    a row a turn (13 us each on the chip, 128-512 turns a layer)."""
+    compiled = _matrix_state_program(
+        one_chip, tmp_path, program, "solar-open2-ep8-l4",
+        {"num_hidden_layers": 2}, ["kv", "state"],
+        [(128, 73728), (129, 64, 128, 128)])
+    kernel = "_kda_chunk_call" if program == "mixed" else "_kda_step_call"
     text = compiled.as_text()
     assert kernel in text and "_conv_silu_call" in text
     assert text.count("tpu_custom_call") >= 3
     assert _row_loops(text, "bf16[128,73728]") == []
+    pool_bytes = 129 * 64 * 128 * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+@pytest.mark.parametrize("program", ["mixed", "decode_cont"])
+def test_state_space_model_programs_compile(compiled_for_chip, one_chip,
+                                            tmp_path, program):
+    """`granite-4.0-h-small-ep4-l10`'s programs as its cell dispatches
+    them (as above; depth cut to two Mamba-2 layers and the attention
+    layer) compile for the described v5e, hold the scan's kernel of that
+    program (`_ssd_chunk_call` / `_ssd_step_call`) and the filter's, and
+    carry the 541 MB matrix pools through the K substeps without a copy of
+    one: the program's temporaries stay under one pool's size. The taps
+    pool is `[128, 25,344]`, wider than the 24,576 columns at which XLA's
+    scatter became a loop of a row a turn: its rows are written by slot in
+    one pass (`ops/state_rows.py`)."""
+    compiled = _matrix_state_program(
+        one_chip, tmp_path, program, "granite-4.0-h-small-ep4-l10",
+        {"num_hidden_layers": 3,
+         "layer_types": ["mamba", "attention", "mamba"]},
+        ["state", "kv", "state"], [(128, 25344), (129, 64, 128, 128)])
+    kernel = "_ssd_chunk_call" if program == "mixed" else "_ssd_step_call"
+    text = compiled.as_text()
+    assert kernel in text and "_conv_silu_call" in text
+    assert text.count("tpu_custom_call") >= 4
+    assert _row_loops(text, "bf16[128,25344]") == []
     pool_bytes = 129 * 64 * 128 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
