@@ -12,6 +12,14 @@ parent's, unpacked beside this one), so one chip call times both:
     python benchmarks/kernels/benchmark_paged_walk.py --heads 8x4 \
         --root chipscratch/parent --out b.json
 
+The cells' shapes and pools: Mistral `--heads 8x4`, DeepSeek-MoE `--heads
+16x1` (both fp8, the default), LFM2 `--heads 8x4 --pool-dtype bfloat16
+--head-values 64` (heads of 64 stored in 128 lanes), SmallThinker's
+windowed layers `--heads 4x7 --pool-dtype bfloat16 --window 4096 --rows 16
+--traffic ragdoc-closed --width 512 --blocks 8300`. The decode call's share
+of its HBM roofline (`harness/costs.py` `paged_attention_bytes` over
+`peaks.json`'s rate) is printed beside its time.
+
 Device time is read from a profiler trace of the timed calls (the sum of
 the kernel's own events over the calls made); a host clock around the
 same calls is printed beside it. A CPU run is a rehearsal of control
@@ -80,7 +88,7 @@ def measure(args) -> None:
     with open(os.path.join(CHECKOUT, "benchmarks", "chip", "traffic",
                            args.traffic + ".json")) as f:
         traffic = json.load(f)
-    rows_d, rows_r, w, layers = 128, 512, args.width, args.layers
+    rows_d, rows_r, w, layers = args.rows, 512, args.width, args.layers
     if args.tiny:
         rows_d, rows_r, layers = 8, 16, 2
     rng = np.random.default_rng(args.seed)
@@ -94,9 +102,12 @@ def measure(args) -> None:
     pre_ctx = np.minimum(pre_ctx, w * bs)
 
     nb = max(args.blocks, rows_d * w + 1) if args.full_width else args.blocks
-    pool = lambda: jnp.asarray(
-        rng.standard_normal((nb, hkv, bs, d), np.float32) * 0.5
-    ).astype(dtype)
+    # Heads narrower than their stored 128 lanes: the lanes past the
+    # values hold zeros, in the pool and in the query.
+    lanes = np.arange(d) < (args.head_values or d)
+    draw = lambda *shape: rng.standard_normal(shape + (d, ),
+                                              np.float32) * lanes
+    pool = lambda: jnp.asarray(draw(nb, hkv, bs) * 0.5).astype(dtype)
     k_cache, v_cache = pool(), pool()
 
     def tables_for(owner, n_rows):
@@ -121,17 +132,20 @@ def measure(args) -> None:
 
               "device": jax.devices()[0].device_kind,
               "platform": jax.devices()[0].platform}
-    scale = d ** -0.5
+    scale = (args.head_values or d) ** -0.5
+    # A sliding window: a row sees its last `window` positions only.
+    lo_of = lambda c: ({"lo": jnp.asarray(np.maximum(c - args.window, 0))}
+                       if args.window else {})
 
     # ---- decode ----------------------------------------------------------
-    q = jnp.asarray(rng.standard_normal((rows_d, 1, hkv * g, d),
-                                        np.float32)).astype(jnp.bfloat16)
+    q = jnp.asarray(draw(rows_d, 1, hkv * g)).astype(jnp.bfloat16)
     tables = jnp.asarray(tables_for(np.arange(rows_d), rows_d))
     ctx = jnp.asarray(dec_ctx)
     out, lse = pa.paged_attention(q, k_cache, v_cache, tables, ctx, scale,
-                                  return_lse=True)
+                                  return_lse=True, **lo_of(dec_ctx))
     ref, ref_lse = decode_attention_reference(
-        q, k_cache, v_cache, tables, ctx, scale, return_lse=True)
+        q, k_cache, v_cache, tables, ctx, scale, return_lse=True,
+        **lo_of(dec_ctx))
     result["decode_max_err"] = float(jnp.max(jnp.abs(
         out.astype(jnp.float32) - ref.astype(jnp.float32))))
     result["decode_lse_max_err"] = float(jnp.max(jnp.abs(lse - ref_lse)))
@@ -140,16 +154,14 @@ def measure(args) -> None:
     @jax.jit
     def decode_layers(q, k_cache, v_cache, tables, ctx):
         def layer(_, q):
-            return pa.paged_attention(q, k_cache, v_cache, tables, ctx, scale)
+            return pa.paged_attention(q, k_cache, v_cache, tables, ctx, scale,
+                                      **lo_of(dec_ctx))
         return jax.lax.fori_loop(0, layers, layer, q)
 
     # ---- ragged ----------------------------------------------------------
-    qr = jnp.asarray(rng.standard_normal((rows_r, 1, hkv * g, d),
-                                         np.float32)).astype(jnp.bfloat16)
-    k_new = jnp.asarray(rng.standard_normal((rows_r, hkv, d), np.float32)
-                        * 0.5).astype(dtype)
-    v_new = jnp.asarray(rng.standard_normal((rows_r, hkv, d), np.float32)
-                        * 0.5).astype(dtype)
+    qr = jnp.asarray(draw(rows_r, 1, hkv * g)).astype(jnp.bfloat16)
+    k_new = jnp.asarray(draw(rows_r, hkv) * 0.5).astype(dtype)
+    v_new = jnp.asarray(draw(rows_r, hkv) * 0.5).astype(dtype)
     tables_r = tables_for(seq_of, rows_r)
     slots = np.where(
         pre_ctx > 0,
@@ -159,9 +171,10 @@ def measure(args) -> None:
                               jnp.asarray(pre_ctx))
     out_r, kc, vc = rpa.ragged_paged_attention(
         qr, k_new, v_new, jnp.copy(k_cache), jnp.copy(v_cache), slots,
-        tables_r, ctx_r, scale)
+        tables_r, ctx_r, scale, **lo_of(pre_ctx))
     ref_r, kc_ref, vc_ref = ragged_fused_attention_reference(
-        qr, k_new, v_new, k_cache, v_cache, slots, tables_r, ctx_r, scale)
+        qr, k_new, v_new, k_cache, v_cache, slots, tables_r, ctx_r, scale,
+        **lo_of(pre_ctx))
     result["ragged_max_err"] = float(jnp.max(jnp.abs(
         out_r.astype(jnp.float32) - ref_r.astype(jnp.float32))))
     result["ragged_pool_equal"] = bool(
@@ -176,7 +189,8 @@ def measure(args) -> None:
         def layer(_, carry):
             q, kc, vc = carry
             return rpa.ragged_paged_attention(q, k_new, v_new, kc, vc, slots,
-                                              tables, ctx, scale)
+                                              tables, ctx, scale,
+                                              **lo_of(pre_ctx))
         return jax.lax.fori_loop(0, layers, layer, (qr, k_cache, v_cache))
 
     def run_decode():
@@ -209,6 +223,20 @@ def measure(args) -> None:
         if dev is not None:
             result[f"{name}_device_us_per_call"] = dev["us_per_call"]
             result[f"{name}_device_calls"] = dev["calls"]
+    if "decode_device_us_per_call" in result:
+        # What the decode rows attend to (from `lo` under a window), at
+        # the lanes stored, over the chip's published HBM rate.
+        from harness import costs
+        attended = dec_ctx - np.maximum(dec_ctx - args.window, 0) \
+            if args.window else dec_ctx
+        nbytes = costs.paged_attention_bytes(attended.tolist(), hkv, d,
+                                             dtype.itemsize)
+        floor_us = nbytes / costs.peaks(result["device"])[
+            "hbm_bytes_per_s"] * 1e6
+        result["decode_kv_bytes"] = nbytes
+        result["decode_hbm_floor_us"] = floor_us
+        result["decode_hbm_roofline_share"] = (
+            floor_us / result["decode_device_us_per_call"])
     print(json.dumps(result))
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
@@ -245,8 +273,16 @@ if __name__ == "__main__":
     parser.add_argument("--root", default="",
                         help="checkout to import intellillm_tpu from")
     parser.add_argument("--heads", default="8x4",
-                        help="KV heads x query heads a KV head (8x4 Mistral, "
-                             "16x1 DeepSeek-MoE)")
+                        help="KV heads x query heads a KV head (8x4 Mistral "
+                             "and LFM2, 16x1 DeepSeek-MoE, 4x7 SmallThinker)")
+    parser.add_argument("--head-values", type=int, default=0,
+                        help="lanes of the 128 stored that carry values "
+                             "(LFM2: 64); 0 = all")
+    parser.add_argument("--window", type=int, default=0,
+                        help="sliding window in tokens (the windowed "
+                             "launches); 0 = none")
+    parser.add_argument("--rows", type=int, default=128,
+                        help="decode rows")
     parser.add_argument("--pool-dtype", default="float8_e5m2")
     parser.add_argument("--traffic", default="decode-closed")
     parser.add_argument("--width", type=int, default=64,

@@ -24,8 +24,8 @@ read-modify-write they keep:
 
 What differs from the per-head kernels: one DMA stream instead of two (the
 value is a lane slice of the key already in VMEM: a token's 1,280 bytes
-are read once, not twice), no head blocks (the flat-dot's cross-head junk
-columns do not exist), the QK^T dot in the cache's own dtype when the
+are read once, not twice), no head blocks (one latent serves every
+head), the QK^T dot in the cache's own dtype when the
 query has it (bf16 x bf16 accumulated in f32 is exact in the products),
 and the scale applied to the f32 scores rather than to the query.
 

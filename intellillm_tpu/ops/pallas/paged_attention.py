@@ -19,16 +19,36 @@ twin was deleted, see the selection history below):
   [HP, block_size, head_size] slab (32 KiB at HP=8/bf16/D=128). The last
   page group prefetches the NEXT grid step's first group so the DMA
   pipeline never drains across grid steps.
-- v4 (this kernel) vectorizes the per-group math across the whole head
-  block: ONE batched dot computes all HP heads' scores ([HP, G, P·BS]
-  instead of HP unrolled [G, P·BS] matmuls) and the online-softmax
-  update runs on [HP·G, P·BS] tiles. For MHA (G=1) this turns ~30 VPU
-  ops on <1x128> vectors per head into single ops on full 8x128+ tiles —
-  the v3 profile showed op-issue overhead, not DMA bandwidth, dominating
-  at 40 GB/s effective KV read. Validated on real TPU v5e at +15%
-  end-to-end decode throughput over v3 (935.8 vs 810.6 tok/s/chip,
-  llama2-7b int8/fp8-KV bs=32); v3 and v4 agreed to 2e-6 on identical
-  inputs before the v3 twin was removed.
+- v4 vectorized the per-group math across the whole head block as ONE
+  flat float32 dot ([HP*G, D] x [P*HP*BS, D]^T) with the online softmax
+  on [HP*G, P*HP*BS] tiles: full vector tiles where v3's HP unrolled
+  [G, P*BS] pieces used a sliver of each (+15 % end to end over v3 on
+  v5e, llama2-7b int8 / fp8-KV bs=32; the v3 twin was then removed).
+  Its price: of every score tile one column in HP is the row's own
+  head's (the others were coded out of reach and masked): the position
+  code, the mask, the exp, the sums and the PV dot's streamed operand
+  all ran over HP times the columns a row attends to.
+- v5 (this kernel, PR 42) keeps the single instruction stream and drops
+  the junk columns: ONE *batched* dot a piece for all HP heads, each head
+  over its own keys ([HP, Gb, D] x [HP, P*BS, D]^T; a piece's pages
+  [P, HP, BS, D] are re-indexed head-major in registers: whole (BS, D)
+  tiles change places, nothing moves within one), and the softmax chain
+  on the rows' own columns only. The score rows keep the flat form's
+  full tiles: where G query heads a KV head are fewer than a sublane
+  tile, 8 // G heads share one 8-row block (`_heads_per_row_block`):
+  the block's rows are every one of its heads' dot operand, and each row
+  of the result is taken from the head it belongs to (a select a result
+  register), so the accumulators are the flat [HP*G] rows again, eight
+  to a tile. No per-head loop and no unroll in the source: a per-head
+  unroll of 8 measured 2.7x the parent's lowering time a program.
+  A grid step owns `_ROWS_PER_STEP` rows, walked one after the other:
+  they share the step's fixed cost (its blocks copied in and out).
+  The operands stay float32 whatever the pool: on v5e a bfloat16 operand
+  measured SLOWER (PERF.md section 6, PR 42): an fp8 value reaches
+  float32 in one unpack a register, bfloat16 costs that, a pack and a
+  relayout (the pool's 16-row slabs are two (8, 128) memory tiles, half a
+  bfloat16 register each), and the MXU's float32 passes were not what
+  bound the flat form.
 - The paged pools stay in HBM (`memory_space=ANY`); the kernel issues
   explicit `pltpu.make_async_copy`s against `k_hbm.at[page].at[head
   slice]` — the block table (scalar-prefetched to SMEM) is read at
@@ -59,7 +79,9 @@ twin was deleted, see the selection history below):
   So: a row's full groups run as static pieces (one wait a pool for
   the whole group's bytes), its tail's copies go out in a loop (a
   conditional a page, or a run of pages for each bit of the count, both
-  measured slower than moving all 16), and the tail is multiplied as
+  measured slower than moving all 16; since PR 42 a sub-group of pages
+  a loop turn, and a wait names a sub-group's bytes), and the tail is
+  multiplied as
   ONE piece sized by a tree of two-way conditionals (a chain of
   sub-group updates measured 30-70 % slower than the fixed group). A
   page that is not moved leaves in VMEM what was there, so V starts out
@@ -67,11 +89,13 @@ twin was deleted, see the selection history below):
 
 The ragged mixed-batch sibling (ops/pallas/ragged_paged_attention.py)
 reuses this module's `_page_walker` (the DMA walk) and `_row_update`
-(the flat-dot online softmax of one query token a row) and adds the
+(the online softmax of one query token a row) and adds the
 fused cache-write, the in-flight-token handling and the tiles of many
 rows the flat mixed dispatch needs.
 
-Numerics: f32 accumulation regardless of cache dtype.
+Numerics: f32 accumulation, running max, denominator and logsumexp
+regardless of cache dtype; per-row arithmetic never depends on the
+other rows of a launch.
 """
 from __future__ import annotations
 
@@ -91,8 +115,9 @@ _NEG_INF = -1e30
 # Pages of a sub-group: a row's tail is multiplied up to the last
 # sub-group that holds one of its pages.
 _SUB_PAGES = 4
-# A column of another head's, as a token position no context reaches.
-_FAR = 1 << 30
+# Rows of one grid step of the decode kernel: they share the step's fixed
+# cost (its blocks' copies in and out, ~0.35 us).
+_ROWS_PER_STEP = 8
 
 
 def _largest_divisor(n: int, cap: int) -> int:
@@ -168,8 +193,9 @@ def _page_walker(context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf,
     never drains. `walk_extent` says how far the walk goes. The row's
     full groups come first, each moved and multiplied as one static
     piece; then its tail, the pages of its last group that it holds,
-    moved page by page and multiplied in one piece up to the last
-    `sub`-page sub-group that holds one. A row with nothing to walk (a
+    moved and waited for a sub-group of pages a loop turn (then a page a
+    turn) and multiplied in one piece up to the last `sub`-page
+    sub-group that holds one. A row with nothing to walk (a
     pad row) moves and multiplies nothing. Copies are started and waited
     for by the same rule on the same row's context, so what is waited
     for is what was started.
@@ -182,6 +208,7 @@ def _page_walker(context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf,
     windowed, nothing here differs from what it was."""
     hp, ppg, bs = heads_per_block, pages_per_group, block_size
     pools = ((k_hbm_ref, k_buf, k_sem), (v_hbm_ref, v_buf, v_sem))
+    chunk = _sub_pages(ppg)
 
     def first_page(row):
         return lax.div(context_lens_ref[batch_size + row], bs)
@@ -209,10 +236,19 @@ def _page_walker(context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf,
             start_page(row, hb_, g, j, buf)
 
     def start_tail(row, hb_, g, n, buf):
+        """The first `n` pages of a group: a sub-group a loop turn, then
+        the pages left over."""
         def one(j, carry):
             start_page(row, hb_, g, j, buf)
             return carry
-        lax.fori_loop(0, n, one, 0)
+
+        def some(c, carry):
+            for i in range(chunk):
+                start_page(row, hb_, g, c * chunk + i, buf)
+            return carry
+        whole = lax.div(n, chunk)
+        lax.fori_loop(0, whole, some, 0)
+        lax.fori_loop(whole * chunk, n, one, 0)
 
     # A DMA semaphore counts bytes, so a wait names only how many: the
     # buffer itself stands in for the source.
@@ -222,12 +258,23 @@ def _page_walker(context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf,
                                   sem.at[buf]).wait()
 
     def wait_tail(n, buf):
-        def one(j, carry):
+        """For `n` pages' bytes: a sub-group's at a time (a wait costs
+        ~5 ns whatever it names), then a page's."""
+        def wait_pages(first, count: int):
             for _, vmem, sem in pools:
-                pltpu.make_async_copy(vmem.at[buf, j], vmem.at[buf, j],
-                                      sem.at[buf]).wait()
+                pages = vmem.at[buf, pl.ds(first, count)]
+                pltpu.make_async_copy(pages, pages, sem.at[buf]).wait()
+
+        def one(j, carry):
+            wait_pages(j, 1)
             return carry
-        lax.fori_loop(0, n, one, 0)
+
+        def some(c, carry):
+            wait_pages(c * chunk, chunk)
+            return carry
+        whole = lax.div(n, chunk)
+        lax.fori_loop(0, whole, some, 0)
+        lax.fori_loop(whole * chunk, n, one, 0)
 
     def start_first_group(row, hb_, buf):
         n = jnp.minimum(walk_pages(row), ppg)
@@ -280,22 +327,6 @@ def _page_walker(context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf,
     return first_page, start_first_group, walk
 
 
-def _position_code(code_scr, *, heads_per_block: int, num_groups_g: int,
-                   block_size: int) -> None:
-    """Lay out the position code of the flat score layout, once a launch.
-    The KV buffer flattens page-major: flat column c = (page*HP + head)*BS
-    + tok, so head(c) = (c // BS) % HP and the token's index within the
-    group is page(c)*BS + tok(c). Columns of other heads than the row's
-    are junk: coded out of reach."""
-    hp, bs = heads_per_block, block_size
-    rows_i = lax.broadcasted_iota(jnp.int32, code_scr.shape, 0)
-    cols_i = lax.broadcasted_iota(jnp.int32, code_scr.shape, 1)
-    col_head = lax.rem(lax.div(cols_i, bs), hp)
-    col_tok = lax.div(cols_i, hp * bs) * bs + lax.rem(cols_i, bs)
-    code_scr[...] = jnp.where(lax.div(rows_i, num_groups_g) == col_head,
-                              col_tok, _FAR)
-
-
 def _zero_pages(v_buf) -> None:
     """A page the walk does not move leaves in VMEM what was there: the
     mask zeroes its probabilities, but 0 x NaN in the PV dot is NaN, so V
@@ -308,77 +339,124 @@ def _zero_pages(v_buf) -> None:
             v_buf[i, j] = zero
 
 
-def _row_update(q_flat, slope, query_pos_f, limit, lo, k_buf, v_buf, m_scr,
-                l_scr, acc_scr, code_scr, *, first_token, heads_per_block:
-                int, pages_per_group: int, block_size: int):
+def _padded_g(num_groups_g: int) -> int:
+    """Query heads a KV head, up to whole float32 sublane tiles."""
+    return -(-num_groups_g // 8) * 8
+
+
+def _heads_per_row_block(heads_per_block: int, num_groups_g: int) -> int:
+    """KV heads whose query heads share one block of score rows: as many
+    as fill a sublane tile (8 // G of G = 1, 2, 4: then the blocks are the
+    flat [HP * G] rows, eight to a tile), one where a head's G rows take
+    a tile or more to themselves."""
+    if 8 % num_groups_g:
+        return 1
+    return _largest_divisor(heads_per_block, 8 // num_groups_g)
+
+
+def _stage_rows(dst_scr, x) -> None:
+    """A row's per-query-head values `x` [HP, G, n] into the score rows'
+    layout `dst_scr` [HP / R, Gb, n] f32: query head g of the block's
+    j-th KV head in row j * G + g (rows past R * G stay the zeros
+    `_first` laid out)."""
+    hp, g, n = x.shape
+    nb = dst_scr.shape[0]
+    x = x.astype(jnp.float32)
+    if nb != hp:
+        x = x.reshape(hp * g, n).reshape(nb, hp * g // nb, n)
+    dst_scr[:, :x.shape[1], :] = x
+
+
+def _row_update(query_pos_f, limit, lo, k_buf, v_buf, q_scr, slope_scr,
+                m_scr, l_scr, acc_scr, *, first_token, scale: float,
+                num_groups_g: int, pages_per_group: int, block_size: int):
     """`update(g, buf, npg)` of one query token a row: folds the first
     `npg` pages of group g, held in buffer `buf`, into the row's
-    accumulators. `q_flat` [HP*G, D] f32 (scaled), `slope` [HP*G, 1];
-    the row sees positions `lo <= pos < limit` (`lo` None: from 0) and
-    its walk's page 0 starts at position `first_token`."""
-    hp, ppg, bs = heads_per_block, pages_per_group, block_size
-    rows = q_flat.shape[0]
+    accumulators, every KV head of the block over its own keys.
+    `q_scr` and `slope_scr` hold the row's query and ALiBi slopes in the
+    score rows' layout (`_stage_rows`); the row sees positions
+    `lo <= pos < limit` (`lo` None: from 0) and its walk's page 0 starts
+    at position `first_token`."""
+    ppg, bs = pages_per_group, block_size
+    hp, d = k_buf.shape[2], k_buf.shape[-1]
+    nb, gb, _ = acc_scr.shape
+    r = hp // nb
+    # Which of a block's R heads a score row belongs to.
+    row_head = lax.broadcasted_iota(jnp.int32, (1, gb, 1), 1) // num_groups_g
+
+    def by_head(rows):
+        """A block's rows [HP / R, Gb, n] as the dot's operand of each of
+        its heads [HP, Gb, n]."""
+        return rows if r == 1 else jnp.repeat(rows, r, axis=0)
+
+    def own_rows(x):
+        """Of the dot's result [HP, Gb, n], each row from the head it
+        belongs to: [HP / R, Gb, n]."""
+        if r == 1:
+            return x
+        x = x.reshape(nb, r, gb, x.shape[-1])
+        out = x[:, 0]
+        for j in range(1, r):
+            out = jnp.where(row_head == j, x[:, j], out)
+        return out
 
     def update(g, buf, npg: int):
-        ncols = npg * hp * bs
-        # Token position of each FLAT column within the full sequence
-        # (out of reach for a column of another head's).
-        token_pos = g * (ppg * bs) + code_scr[:, :ncols]  # [HP*G, NC]
+        keys = npg * bs
+        # Token position of each of a head's keys within the sequence.
+        token_pos = (first_token + g * (ppg * bs)
+                     + lax.broadcasted_iota(jnp.int32, (1, 1, keys), 2))
+        mask = token_pos < limit                           # [1, 1, keys]
         if lo is not None:
-            token_pos = token_pos + first_token
-            mask = (token_pos < limit) & (token_pos >= lo)
-        else:
-            mask = token_pos < limit
+            mask = mask & (token_pos >= lo)
 
-        # ONE flat dot for all HP heads: [HP*G, D] x [NPG*HP*BS, D]^T. The
-        # cross-head scores are junk (masked out); the extra FLOPs are
-        # ~2 MXU tiles — far cheaper than HP separate small dots or a
-        # (Mosaic-hostile) batched dot.
-        d = k_buf.shape[-1]
-        k = k_buf[buf, :npg].reshape(ncols, d).astype(jnp.float32)
-        v = v_buf[buf, :npg].reshape(ncols, d).astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q_flat, k, (((1, ), (1, )), ((), ())),
-            preferred_element_type=jnp.float32)          # [HP*G, NC]
+        def heads_keys(pool_buf):
+            """The piece's pages [NPG, HP, BS, D] as each head's own keys
+            [HP, NPG * BS, D] f32: whole (BS, D) tiles change places,
+            nothing moves within one."""
+            x = pool_buf[buf, pl.ds(0, npg)].astype(jnp.float32)
+            return jnp.swapaxes(x, 0, 1).reshape(hp, keys, d)
+
+        # ONE batched dot for all HP heads, each over its own keys.
+        s = own_rows(jax.lax.dot_general(
+            by_head(q_scr[...]), heads_keys(k_buf),
+            (((2, ), (2, )), ((0, ), (0, ))),
+            preferred_element_type=jnp.float32))       # [HP/R, Gb, keys]
         # ALiBi: score += slope * (key_pos - query_pos).
-        s = s + slope * (token_pos.astype(jnp.float32) - query_pos_f)
+        s = s * scale + slope_scr[:, :, :1] * (
+            token_pos.astype(jnp.float32) - query_pos_f)
 
-        m_prev = m_scr[:, 0][:, None]                    # [HP*G, 1]
-        m_cur = jnp.max(jnp.where(mask, s, _NEG_INF), axis=1,
-                        keepdims=True)
+        m_prev = m_scr[:, :, :1]                           # [HP/R, Gb, 1]
+        m_cur = jnp.max(jnp.where(mask, s, _NEG_INF), axis=2, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
         # Mask AFTER the exp: with nothing valid m_new == s == -inf-ish
-        # and exp(0) would otherwise contribute 1s; the mask also zeroes
-        # the cross-head columns so pv below stays block-diagonal.
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)     # [HP*G, NC]
-
-        l_new = l_scr[:, 0][:, None] * alpha + jnp.sum(p, axis=1,
-                                                       keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1, ), (0, )), ((), ())),
-            preferred_element_type=jnp.float32)          # [HP*G, D]
+        # and exp(0) would otherwise contribute 1s.
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)   # [HP/R, Gb, keys]
+        l_new = l_scr[:, :, :1] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        pv = own_rows(jax.lax.dot_general(
+            by_head(p), heads_keys(v_buf), (((2, ), (1, )), ((0, ), (0, ))),
+            preferred_element_type=jnp.float32))          # [HP/R, Gb, D]
         acc_scr[...] = acc_scr[...] * alpha + pv
-        m_scr[...] = jnp.broadcast_to(m_new, (rows, 128))
-        l_scr[...] = jnp.broadcast_to(l_new, (rows, 128))
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
     return update
 
 
 def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
           slopes_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf, k_sem, v_sem,
-          m_scr, l_scr, acc_scr, code_scr, *, batch_size: int,
-          num_head_blocks: int, heads_per_block: int, num_groups_g: int,
-          pages_per_group: int, block_size: int, scale: float, w_max: int,
-          windowed: bool = False):
-    """Online softmax of this grid step's (row, kv-head block) over the
-    row's paged context, into the scratch accumulators (`_page_walker`
-    moves the pages, `_row_update` multiplies them). A step's last group
-    issues the first group of the NEXT grid step."""
-    b = pl.program_id(0)
+          q_scr, slope_scr, m_scr, l_scr, acc_scr, *, t, batch_size: int,
+          num_head_blocks: int, pages_per_group: int, block_size: int,
+          scale: float, w_max: int, windowed: bool = False):
+    """Online softmax of row `t` of this grid step's rows (and its kv-head
+    block) over the row's paged context, into the scratch accumulators
+    (`_page_walker` moves the pages, `_row_update` multiplies them). A
+    walk's last group issues the first group of the NEXT walk."""
+    rows_per_step = q_ref.shape[0]
+    row0 = pl.program_id(0) * rows_per_step
+    b = row0 + t
     hb = pl.program_id(1)
-    hp, g_sz, ppg, bs = (heads_per_block, num_groups_g, pages_per_group,
-                         block_size)
+    hp, ppg, bs = k_buf.shape[2], pages_per_group, block_size
     first_page, start_first_group, walk = _page_walker(
         context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf,
         k_sem, v_sem, held_back=0, batch_size=batch_size,
@@ -387,20 +465,23 @@ def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
 
     # Very first grid step starts its own group 0; afterwards every step's
     # group 0 was prefetched by its predecessor. It also lays out, once,
-    # what every step reads back: the position code of the flat score
-    # layout, and a V buffer that is finite.
+    # what every step reads back: a V buffer that is finite, and zeros in
+    # the score rows no query head takes.
     @pl.when(init_ref[0] == 1)
     def _first():
-        _position_code(code_scr, heads_per_block=hp, num_groups_g=g_sz,
-                       block_size=bs)
         _zero_pages(v_buf)
+        q_scr[...] = jnp.zeros_like(q_scr)
+        slope_scr[...] = jnp.zeros_like(slope_scr)
         start_first_group(b, hb, 0)
     init_ref[0] = 0
 
-    # Successor grid point (head-block fastest, then batch).
+    # The next walk: the step's next row, else the step's rows again for
+    # the next head block, else the next step's first row.
+    in_step = t + 1 < rows_per_step
     wrap = hb + 1 == num_head_blocks
-    nhb = jnp.where(wrap, 0, hb + 1)
-    nb = jnp.where(wrap, b + 1, b)
+    nhb = jnp.where(in_step, hb, jnp.where(wrap, 0, hb + 1))
+    nb = jnp.where(in_step, b + 1,
+                   jnp.where(wrap, row0 + rows_per_step, row0))
 
     def start_successor(buf):
         @pl.when(nb < batch_size)
@@ -410,16 +491,15 @@ def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
     m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
+    _stage_rows(q_scr, q_ref[t])
+    _stage_rows(slope_scr, slopes_ref[...])
 
-    q_flat = (q_ref[0].astype(jnp.float32) *
-              scale).reshape(hp * g_sz, -1)              # [HP*G, D]
     update = _row_update(
-        q_flat, slopes_ref[:, :, 0].reshape(hp * g_sz, 1),
         (context_lens_ref[b] - 1).astype(jnp.float32), context_lens_ref[b],
         context_lens_ref[batch_size + b] if windowed else None,
-        k_buf, v_buf, m_scr, l_scr, acc_scr, code_scr,
-        first_token=first_page(b) * bs if windowed else 0,
-        heads_per_block=hp, pages_per_group=ppg, block_size=bs)
+        k_buf, v_buf, q_scr, slope_scr, m_scr, l_scr, acc_scr,
+        first_token=first_page(b) * bs if windowed else 0, scale=scale,
+        num_groups_g=q_ref.shape[2], pages_per_group=ppg, block_size=bs)
     buf_idx_ref[0] = walk(b, hb, buf_idx_ref[0], start_successor, update,
                           _sub_pages(ppg))
 
@@ -431,50 +511,60 @@ def _decode_kernel(
     buf_idx_ref,        # [1] i32 — VMEM buffer holding the next step's group 0
     init_ref,           # [1] i32 — 1 until the first grid step has run
     # inputs
-    q_ref,              # [1, HP, G, D]
+    q_ref,              # [T, HP, G, D] the step's T rows
     slopes_ref,         # [HP, G, 128] f32 ALiBi slopes, col 0 (0 = none)
     k_hbm_ref,          # [NB, Hkv, BS, D] (HBM resident)
     v_hbm_ref,
     # outputs
-    o_ref,              # [1, HP, G, D]
-    lse_ref,            # [1, HP, G, 128] f32 logsumexp (col 0)
+    o_ref,              # [T, HP, G, D]
+    lse_ref,            # [T, HP, G, 128] f32 logsumexp (col 0)
     # scratch
     k_buf,              # [2, P, HP, BS, D] VMEM double buffer
     v_buf,
     k_sem,              # DMA semaphores [2]
     v_sem,
-    m_scr,              # [HP * G, 128] f32 running max
-    l_scr,              # [HP * G, 128] f32 running denominator
-    acc_scr,            # [HP * G, D] f32 running numerator
-    code_scr,           # [HP * G, P * HP * BS] i32 position code
-    *,
-    heads_per_block: int,
-    num_groups_g: int,
+    q_scr,              # [HP / R, Gb, D] f32 the query, by score row
+    slope_scr,          # [HP / R, Gb, 128] f32 the slopes, by score row
+    m_scr,              # [HP / R, Gb, 128] f32 running max
+    l_scr,              # [HP / R, Gb, 128] f32 running denominator
+    acc_scr,            # [HP / R, Gb, D] f32 running numerator
     **walk_params,
 ):
-    hp, g_sz = heads_per_block, num_groups_g
-    _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
-          slopes_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf, k_sem, v_sem,
-          m_scr, l_scr, acc_scr, code_scr, heads_per_block=hp,
-          num_groups_g=g_sz, **walk_params)
+    rows_per_step, hp, g_sz, d = q_ref.shape
+    rows = hp * g_sz // acc_scr.shape[0]     # score rows a block uses
 
-    l = l_scr[:, 0][:, None]                             # [HP*G, 1]
-    m = m_scr[:, 0][:, None]
-    o = acc_scr[...] / jnp.where(l == 0.0, 1.0, l)       # [HP*G, D]
-    o_ref[0] = o.reshape(hp, g_sz, -1).astype(o_ref.dtype)
-    lse = jnp.where(l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)), _NEG_INF)
-    lse_ref[0] = jnp.broadcast_to(
-        lse.reshape(hp, g_sz, 1), lse_ref[0].shape)
+    def one_row(t, carry):
+        _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
+              slopes_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf, k_sem, v_sem,
+              q_scr, slope_scr, m_scr, l_scr, acc_scr, t=t, **walk_params)
+        l = l_scr[:, :rows, :1]                          # [HP/R, R*G, 1]
+        m = m_scr[:, :rows, :1]
+        o = acc_scr[:, :rows, :] / jnp.where(l == 0.0, 1.0, l)
+        lse = jnp.broadcast_to(
+            jnp.where(l > 0.0, m + jnp.log(jnp.maximum(l, 1e-30)),
+                      _NEG_INF), (acc_scr.shape[0], rows, 128))
+        if rows != g_sz:    # R heads a block: the flat rows, then by head
+            o = o.reshape(hp * g_sz, d)
+            lse = lse.reshape(hp * g_sz, 128)
+        o_ref[t] = o.reshape(hp, g_sz, d).astype(o_ref.dtype)
+        lse_ref[t] = lse.reshape(hp, g_sz, 128)
+        return carry
+
+    # The rows of a grid step share the step's fixed cost.
+    lax.fori_loop(0, rows_per_step, one_row, 0)
 
 
 def _default_hp(k_cache) -> int:
     """Head-block size: each page DMA moves [HP, BS, D] — bigger HP means
     fewer, larger DMAs and fewer grid steps (the KV walk is DMA-issue-
     bound, not bandwidth-bound). Measured on v5e, llama-7b end-to-end:
-    bf16 KV: hp cap 8 -> 1487, 16 -> 1603, 32 -> 1551 tok/s/chip (32
-    pays a quadratically growing junk-column score dot); fp8 KV:
-    16 -> 1811, 32 -> 1836 (half-size pages tip the balance toward
-    fewer, larger DMAs). So: 16, and 32 for 1-byte caches."""
+    bf16 KV: hp cap 8 -> 1487, 16 -> 1603, 32 -> 1551 tok/s/chip; fp8
+    KV: 16 -> 1811, 32 -> 1836 (half-size pages tip the balance toward
+    fewer, larger DMAs). So: 16, and 32 for 1-byte caches. Those runs
+    were of the flat form, whose score tile grew quadratically in HP by
+    its junk columns; since PR 42 a head multiplies its own keys only,
+    so that ground for stopping at 16 is gone and the caps have not
+    been measured again (PERF.md section 7)."""
     return 32 if k_cache.dtype.itemsize == 1 else 16
 
 
@@ -488,6 +578,9 @@ def _paged_attention_launch(q_grouped, slopes, k_cache, v_cache,
     w = block_tables.shape[1]
     hp = _largest_divisor(hkv, _default_hp(k_cache))
     ppg = _pages_per_group(w, hp * bs * d * k_cache.dtype.itemsize)
+    blocks = hp // _heads_per_row_block(hp, g)
+    gb = _padded_g(g)
+    t = _largest_divisor(b, _ROWS_PER_STEP)
 
     # <8 sublanes in the q block: hint a f32 <1x128> layout (a bf16 <8x128>
     # memref would be mis-tiled for tiny G).
@@ -495,26 +588,27 @@ def _paged_attention_launch(q_grouped, slopes, k_cache, v_cache,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, hkv // hp),
+        grid=(b // t, hkv // hp),
         in_specs=[
-            pl.BlockSpec((1, hp, g, d), lambda b_, h_, *_: (b_, h_, 0, 0)),
+            pl.BlockSpec((t, hp, g, d), lambda b_, h_, *_: (b_, h_, 0, 0)),
             pl.BlockSpec((hp, g, 128), lambda b_, h_, *_: (h_, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=(
-            pl.BlockSpec((1, hp, g, d), lambda b_, h_, *_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, hp, g, 128), lambda b_, h_, *_: (b_, h_, 0, 0)),
+            pl.BlockSpec((t, hp, g, d), lambda b_, h_, *_: (b_, h_, 0, 0)),
+            pl.BlockSpec((t, hp, g, 128), lambda b_, h_, *_: (b_, h_, 0, 0)),
         ),
         scratch_shapes=[
             pltpu.VMEM((2, ppg, hp, bs, d), k_cache.dtype),
             pltpu.VMEM((2, ppg, hp, bs, d), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, )),
             pltpu.SemaphoreType.DMA((2, )),
-            pltpu.VMEM((hp * g, 128), jnp.float32),
-            pltpu.VMEM((hp * g, 128), jnp.float32),
-            pltpu.VMEM((hp * g, d), jnp.float32),
-            pltpu.VMEM((hp * g, ppg * hp * bs), jnp.int32),
+            pltpu.VMEM((blocks, gb, d), jnp.float32),
+            pltpu.VMEM((blocks, gb, 128), jnp.float32),
+            pltpu.VMEM((blocks, gb, 128), jnp.float32),
+            pltpu.VMEM((blocks, gb, 128), jnp.float32),
+            pltpu.VMEM((blocks, gb, d), jnp.float32),
         ],
     )
 
@@ -522,8 +616,6 @@ def _paged_attention_launch(q_grouped, slopes, k_cache, v_cache,
         _decode_kernel,
         batch_size=b,
         num_head_blocks=hkv // hp,
-        heads_per_block=hp,
-        num_groups_g=g,
         pages_per_group=ppg,
         block_size=bs,
         scale=scale_static,

@@ -31,8 +31,8 @@ tile of many; every row of any other span is a tile of one. A tile
 
 A tile of one row (a decode row, a pad row, a run's ragged ends, a run
 shorter than a span, any row of a step of fewer rows than a full tile)
-is the row-at-a-time walk the kernel had before
-tiles: one flat dot for all HP heads (`_row_update`), the self token
+is the row-at-a-time walk the kernel had before tiles: one batched dot
+for all HP heads, each over its own keys (`_row_update`), the self token
 merged from VMEM. A step of 128 single rows and a step of one 512-row
 run are the two ends of the same grid.
 
@@ -71,8 +71,8 @@ that tile moves it.)
 Numerics contract: callers pass `k_new`/`v_new` already cast to the
 cache dtype — the reference path reads the cache *after* the write, so
 a tile's own tokens must be seen post-cast (e.g. fp8-quantized), and
-DMAs cannot cast. K, V, the scaled query and the probabilities enter
-both dots in f32, in a tile of many as in `_row_update`. The caches are
+DMAs cannot cast. K, V, the query and the probabilities enter both dots
+in f32, in a tile of many as in `_row_update`. The caches are
 updated in place via `input_output_aliases` (indices count the
 scalar-prefetch operands).
 
@@ -92,11 +92,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from intellillm_tpu.ops.pallas.paged_attention import (_default_hp,
+                                                       _heads_per_row_block,
                                                        _largest_divisor,
+                                                       _padded_g,
                                                        _page_walker,
                                                        _pages_per_group,
-                                                       _position_code,
                                                        _row_update,
+                                                       _stage_rows,
                                                        _sub_pages,
                                                        _zero_pages)
 
@@ -183,7 +185,8 @@ def _page_slab_copies(k_hbm_ref, v_hbm_ref, kw_buf, vw_buf, kw_sem, vw_sem,
 def _ragged_kernel(*refs, batch_size: int, num_head_blocks: int,
                    heads_per_block: int, num_groups_g: int,
                    pages_per_group: int, block_size: int, tile: int,
-                   tiled: bool, scale: float, w_max: int, windowed: bool, alibi: bool):
+                   tiled: bool, scale: float, w_max: int, windowed: bool,
+                   alibi: bool):
     (   # scalar prefetch (SMEM)
         context_lens_ref,   # [B] i32 (include the row's own new token);
                             # windowed [2B]: then the rows' `lo`
@@ -222,10 +225,11 @@ def _ragged_kernel(*refs, batch_size: int, num_head_blocks: int,
         state_ref,          # [3] i32 SMEM: the buffer the next walk's
                             # group 0 is in; the (page, head block)
                             # kw/vw_buf hold
-        m_scr,              # [HP * G, 128] f32: a tile of one
+        q_scr,              # [HP / R, Gb, D] f32: a tile of one, by
+        slope_scr,          # [HP / R, Gb, 128]      score row
+        m_scr,              # [HP / R, Gb, 128] f32
         l_scr,
-        acc_scr,            # [HP * G, D] f32
-        code_scr,           # [HP * G, P * HP * BS] i32 position code
+        acc_scr,            # [HP / R, Gb, D] f32
         mt_scr,             # [HP, G * T, 128] f32: a tile of many
         lt_scr,
         acct_scr,           # [HP, G * T, D] f32
@@ -250,9 +254,9 @@ def _ragged_kernel(*refs, batch_size: int, num_head_blocks: int,
     # out, once, what every step reads back.
     @pl.when((span == 0) & (hb == 0))
     def _first():
-        _position_code(code_scr, heads_per_block=hp, num_groups_g=g_sz,
-                       block_size=bs)
         _zero_pages(v_buf)
+        q_scr[...] = jnp.zeros_like(q_scr)
+        slope_scr[...] = jnp.zeros_like(slope_scr)
         state_ref[0] = 0
         state_ref[1] = -1            # the write buffers hold no slab yet
         state_ref[2] = -1
@@ -350,12 +354,17 @@ def _ragged_kernel(*refs, batch_size: int, num_head_blocks: int,
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-        q_flat = q_ref[t0].astype(jnp.float32) * scale       # [HP*G, D]
+        blocks = acc_scr.shape[0]
+        rows = hp * g_sz // blocks           # score rows a block uses
+        by_row = lambda x: x.reshape(blocks, rows, x.shape[-1])
+        q = q_ref[t0].astype(jnp.float32)                    # [HP*G, D]
+        q_scr[:, :rows, :] = by_row(q)
+        _stage_rows(slope_scr, slopes_ref[...])
         update = _row_update(
-            q_flat, slopes_ref[:, :, 0].reshape(hp * g_sz, 1),
-            limit.astype(jnp.float32), limit, lo0, k_buf, v_buf, m_scr,
-            l_scr, acc_scr, code_scr, first_token=first_token,
-            heads_per_block=hp, pages_per_group=ppg, block_size=bs)
+            limit.astype(jnp.float32), limit, lo0, k_buf, v_buf, q_scr,
+            slope_scr, m_scr, l_scr, acc_scr, first_token=first_token,
+            scale=scale, num_groups_g=g_sz, pages_per_group=ppg,
+            block_size=bs)
         state_ref[0] = walk(r, hb, state_ref[0], successor_after(r + 1),
                             update, _sub_pages(ppg))
 
@@ -370,17 +379,18 @@ def _ragged_kernel(*refs, batch_size: int, num_head_blocks: int,
         v_self = jnp.broadcast_to(
             v_new_ref[t0].astype(jnp.float32)[:, None, :],
             (hp, g_sz, d)).reshape(hp * g_sz, d)
-        s_self = jnp.sum(q_flat * k_self, axis=1, keepdims=True)
+        s_self = by_row(jnp.sum(q * k_self, axis=1, keepdims=True) * scale)
         valid = ctx > 0
-        s_self = jnp.where(valid, s_self, _NEG_INF)          # [HP*G, 1]
+        s_self = jnp.where(valid, s_self, _NEG_INF)      # [HP/R, R*G, 1]
 
-        m_prev = m_scr[:, 0][:, None]
+        m_prev = m_scr[:, :rows, :1]
         m_new = jnp.maximum(m_prev, s_self)
         alpha = jnp.exp(m_prev - m_new)
         p_self = jnp.where(valid, jnp.exp(s_self - m_new), 0.0)
-        l = l_scr[:, 0][:, None] * alpha + p_self
-        acc = acc_scr[...] * alpha + p_self * v_self
-        o_ref[t0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        l = l_scr[:, :rows, :1] * alpha + p_self
+        acc = acc_scr[:, :rows, :] * alpha + p_self * by_row(v_self)
+        o_ref[t0] = (acc / jnp.where(l == 0.0, 1.0, l)).reshape(
+            hp * g_sz, d).astype(o_ref.dtype)
         return carry
 
     def tile_of_many():
@@ -404,8 +414,7 @@ def _ragged_kernel(*refs, batch_size: int, num_head_blocks: int,
         def fold(h, s, mask, v, first: bool):
             """The online-softmax update of head h's accumulators with
             scores `s` [G*T, keys] of the keys whose values are `v`
-            [keys, D] f32: the dots and their operands as in
-            `_row_update`."""
+            [keys, D] f32."""
             m_cur = jnp.max(jnp.where(mask, s, _NEG_INF), axis=1,
                             keepdims=True)
             if first:
@@ -502,6 +511,8 @@ def _ragged_launch(q, slopes, k_new, v_new, k_cache, v_cache, slot_mapping,
     w = block_tables.shape[1]
     hp = _largest_divisor(hkv, _default_hp(k_cache))
     ppg = _pages_per_group(w, hp * bs * d * k_cache.dtype.itemsize)
+    blocks = hp // _heads_per_row_block(hp, g)
+    gb = _padded_g(g)
     t = tile_rows(b, g)
     spans, gt = b // t, g * t
     live = context_lens[:b] > 0
@@ -557,10 +568,11 @@ def _ragged_launch(q, slopes, k_new, v_new, k_cache, v_cache, slot_mapping,
             pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
             pltpu.SMEM((3, ), jnp.int32),
-            pltpu.VMEM((hp * g, 128), jnp.float32),
-            pltpu.VMEM((hp * g, 128), jnp.float32),
-            pltpu.VMEM((hp * g, d), jnp.float32),
-            pltpu.VMEM((hp * g, ppg * hp * bs), jnp.int32),
+            pltpu.VMEM((blocks, gb, d), jnp.float32),
+            pltpu.VMEM((blocks, gb, 128), jnp.float32),
+            pltpu.VMEM((blocks, gb, 128), jnp.float32),
+            pltpu.VMEM((blocks, gb, 128), jnp.float32),
+            pltpu.VMEM((blocks, gb, d), jnp.float32),
             pltpu.VMEM((hp, gt, 128), jnp.float32),
             pltpu.VMEM((hp, gt, 128), jnp.float32),
             pltpu.VMEM((hp, gt, d), jnp.float32),

@@ -17,3 +17,14 @@ def _tpu_interpret_on_cpu():
     else:
         with pltpu.force_tpu_interpret_mode():
             yield
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _drop_the_modules_executables():
+    """An interpreted kernel is hundreds of small programs and host
+    callbacks: a few hundred cases leave a worker holding gigabytes of
+    them, and a later file's large CPU compile in the same process has
+    been seen to die in the compiler (a segmentation fault in
+    `backend_compile`, PR 42). Nothing of a module's is needed after it."""
+    yield
+    jax.clear_caches()

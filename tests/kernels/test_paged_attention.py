@@ -271,3 +271,114 @@ def test_walk_extent_against_a_count_over_the_traffic():
     # a pad row moves nothing; a context past the table is held to it
     assert walk_extent(np.asarray([0, -1, 5000]), bs, w)[0].tolist() == [
         0, 0, w]
+
+
+# --- the piece's arithmetic: pool dtype x heads x ALiBi x window -----------
+
+GRID_POOLS = {"f32": jnp.float32, "bf16": jnp.bfloat16,
+              "fp8_e5m2": jnp.float8_e5m2}
+# KV heads x query heads a KV head: Mistral / LFM2, DeepSeek-MoE,
+# SmallThinker, and a tensor-parallel shard of few heads.
+GRID_HEADS = {"8x4": (8, 4), "16x1": (16, 1), "4x7": (4, 7), "4x1": (4, 1)}
+GRID_WINDOW = 100           # not a whole page: the mask cuts inside one
+
+
+def _grid_case(pool, heads, use_alibi, windowed, q_dtype, seed=21):
+    """Four rows under 32-block tables: one token, a tail of three pages,
+    a tail that ends a sub-group short of the group, the whole table."""
+    hkv, g = GRID_HEADS[heads]
+    rng = np.random.default_rng(seed)
+    b, d, bs, w = 4, 128, 16, 32
+    nb = b * w + 8
+    k_cache, v_cache = make_cache(rng, nb, hkv, bs, d, np.float32)
+    k_cache = (k_cache * 0.5).astype(GRID_POOLS[pool])
+    v_cache = (v_cache * 0.5).astype(GRID_POOLS[pool])
+    q = jnp.asarray(rng.normal(size=(b, 1, hkv * g, d)).astype(np.float32)
+                    ).astype(q_dtype)
+    tables = jnp.asarray(
+        rng.permutation(nb)[:b * w].reshape(b, w).astype(np.int32))
+    ctx = np.asarray([1, 37, 300, w * bs], np.int32)
+    kwargs = {}
+    if use_alibi:
+        kwargs["alibi_slopes"] = jnp.asarray(
+            rng.random(hkv * g).astype(np.float32) * 0.1)
+    if windowed:
+        kwargs["lo"] = jnp.asarray(np.maximum(ctx - GRID_WINDOW, 0))
+    return (q, k_cache, v_cache, tables, jnp.asarray(ctx), d ** -0.5), kwargs
+
+
+@requires_tpu
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("use_alibi", [False, True], ids=["rope", "alibi"])
+@pytest.mark.parametrize("heads", sorted(GRID_HEADS))
+@pytest.mark.parametrize("pool", sorted(GRID_POOLS))
+def test_paged_attention_parity_grid(pool, heads, use_alibi, windowed):
+    """Every pool dtype x every head shape the cells serve (8 // G heads
+    share a block of score rows where G is 1, 2 or 4; G = 7 pads a row),
+    against the float32 oracle: the output as the serving path asks it (a
+    bfloat16 query for the narrow pools) and the float32 logsumexp."""
+    q_dtype = jnp.float32 if pool == "f32" else jnp.bfloat16
+    args, kwargs = _grid_case(pool, heads, use_alibi, windowed, q_dtype)
+    out, lse = jax.block_until_ready(
+        paged_attention(*args, return_lse=True, **kwargs))
+    ref, ref_lse = decode_attention_reference(*args, return_lse=True,
+                                              **kwargs)
+    tol = _oracle_tol(use_alibi) if pool == "f32" else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+    tol = _oracle_tol(use_alibi)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               rtol=tol, atol=tol)
+
+
+@requires_tpu
+@pytest.mark.parametrize("q_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["q-f32", "q-bf16"])
+@pytest.mark.parametrize("heads", sorted(GRID_HEADS))
+@pytest.mark.parametrize("pool", ["bf16", "fp8_e5m2"])
+def test_narrow_pool_loses_nothing_of_the_float32_dot_form(pool, heads,
+                                                           q_dtype):
+    """A bfloat16 / fp8 pool value is exact in float32: the same values in
+    a float32 pool are the float32-dot form on the same inputs, and the
+    narrow pool's output and logsumexp stay within 2e-5 of it (under
+    anything a bfloat16 logit can see) whatever the dots' operands are
+    made of."""
+    args, kwargs = _grid_case(pool, heads, True, True, q_dtype)
+    q, k_cache, v_cache = args[:3]
+    out, lse = jax.block_until_ready(paged_attention(
+        *args, return_lse=True, **kwargs))
+    wide, wide_lse = jax.block_until_ready(paged_attention(
+        q.astype(jnp.float32), k_cache.astype(jnp.float32),
+        v_cache.astype(jnp.float32), *args[3:], return_lse=True, **kwargs))
+    # The output's dtype is the query's: a bfloat16 output is held to its
+    # own rounding, the float32 logsumexp to 2e-5 either way.
+    out_tol = 2e-5 if q_dtype == jnp.float32 else 2.0 ** -8
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(wide), rtol=0, atol=out_tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(wide_lse),
+                               rtol=0, atol=2e-5)
+
+
+@requires_tpu
+@pytest.mark.skipif(jax.default_backend() == "tpu",
+                    reason="the chip's oracle runs bfloat16 passes")
+@pytest.mark.parametrize("plain", [True, False], ids=["plain",
+                                                      "alibi-window"])
+@pytest.mark.parametrize("heads", sorted(GRID_HEADS))
+def test_float32_pool_stands_where_the_flat_form_stood(heads, plain):
+    """A float32 pool keeps float32 dots. The flat form read at most
+    1.5e-7 (output) and 4.8e-7 (logsumexp) from the float32 oracle on
+    these inputs (PR 41's tree, interpret mode); a head's sums now run
+    over its own columns only, which reorders them and changes nothing a
+    float32 can hold: the same limits, with float32 rounding's room."""
+    args, kwargs = _grid_case("f32", heads, not plain, not plain,
+                              jnp.float32)
+    out, lse = jax.block_until_ready(
+        paged_attention(*args, return_lse=True, **kwargs))
+    ref, ref_lse = decode_attention_reference(*args, return_lse=True,
+                                              **kwargs)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=0, atol=5e-7)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               rtol=0, atol=2e-6)

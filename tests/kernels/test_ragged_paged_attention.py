@@ -421,3 +421,82 @@ def test_ragged_chunk_run_as_one_tile(hq, hkv, pool):
         np.testing.assert_array_equal(
             np.asarray(pool_got.astype(jnp.float32)),
             np.asarray(pool_want.astype(jnp.float32)))
+
+
+# --- the piece's arithmetic of a row that walks alone ----------------------
+# (`_row_update`, shared with the decode kernel): pool dtype x heads x ALiBi
+# x window, every row a tile of one.
+
+GRID_POOLS = {"f32": jnp.float32, "bf16": jnp.bfloat16,
+              "fp8_e5m2": jnp.float8_e5m2}
+GRID_HEADS = {"8x4": (8, 4), "16x1": (16, 1), "4x7": (4, 7), "4x1": (4, 1)}
+GRID_WINDOW = 100
+
+
+def _grid_case(pool, heads, use_alibi, windowed, q_dtype):
+    """Decode rows (a tail of three pages, one that crosses the 16-page
+    group, the whole 32-block table), three chunk rows of one sequence
+    and a pad row, every K/V value a value of the pool's dtype."""
+    hkv, g = GRID_HEADS[heads]
+    rng = np.random.default_rng(31)
+    dtype = GRID_POOLS[pool]
+    args = list(_mixed_batch(rng, hq=hkv * g, hkv=hkv, d=128, nb=8 * 32,
+                             w=32, ctx_lens=(1, 37, 300, 30, 31, 32, 512,
+                                             0)))
+    q, k_new, v_new, k_cache, v_cache = args[:5]
+    args[:5] = (q.astype(q_dtype), (k_new * 0.5).astype(dtype),
+                (v_new * 0.5).astype(dtype), (k_cache * 0.5).astype(dtype),
+                (v_cache * 0.5).astype(dtype))
+    kwargs = {}
+    if use_alibi:
+        kwargs["alibi_slopes"] = jnp.asarray(
+            rng.random(hkv * g).astype(np.float32) * 0.1)
+    if windowed:
+        kwargs["lo"] = jnp.maximum(args[7] - GRID_WINDOW, 0)
+    return args, kwargs
+
+
+@requires_tpu
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("use_alibi", [False, True], ids=["rope", "alibi"])
+@pytest.mark.parametrize("heads", sorted(GRID_HEADS))
+@pytest.mark.parametrize("pool", sorted(GRID_POOLS))
+def test_ragged_parity_grid(pool, heads, use_alibi, windowed):
+    from intellillm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    q_dtype = jnp.float32 if pool == "f32" else jnp.bfloat16
+    args, kwargs = _grid_case(pool, heads, use_alibi, windowed, q_dtype)
+    out_k, kc_k, vc_k = jax.block_until_ready(ragged_paged_attention(
+        *args, 128 ** -0.5, **kwargs))
+    out_r, kc_r, vc_r = ragged_fused_attention_reference(
+        *args, 128 ** -0.5, **kwargs)
+    tol = 2e-3 if pool == "f32" else 2e-2
+    live = np.asarray(args[7]) > 0
+    np.testing.assert_allclose(np.asarray(out_k, np.float32)[live],
+                               np.asarray(out_r, np.float32)[live],
+                               rtol=tol, atol=tol)
+    np.testing.assert_array_equal(np.asarray(kc_k.astype(jnp.float32)),
+                                  np.asarray(kc_r.astype(jnp.float32)))
+    np.testing.assert_array_equal(np.asarray(vc_k.astype(jnp.float32)),
+                                  np.asarray(vc_r.astype(jnp.float32)))
+
+
+@requires_tpu
+@pytest.mark.parametrize("heads", sorted(GRID_HEADS))
+@pytest.mark.parametrize("pool", ["bf16", "fp8_e5m2"])
+def test_ragged_narrow_pool_loses_nothing_of_the_float32_dot_form(pool,
+                                                                 heads):
+    """The same values in a float32 pool are the float32-dot form on the
+    same inputs: a row that walks alone reads within 2e-5 of it from the
+    narrow pool, whatever the dots' operands are made of."""
+    from intellillm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    args, kwargs = _grid_case(pool, heads, True, True, jnp.float32)
+    out = jax.block_until_ready(ragged_paged_attention(
+        *args, 128 ** -0.5, **kwargs))[0]
+    wide = [a.astype(jnp.float32) if a.dtype == GRID_POOLS[pool] else a
+            for a in args]
+    out_wide = jax.block_until_ready(ragged_paged_attention(
+        *wide, 128 ** -0.5, **kwargs))[0]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_wide),
+                               rtol=0, atol=2e-5)

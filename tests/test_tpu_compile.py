@@ -141,6 +141,61 @@ def test_ragged_paged_attention_compiles(compile_for_chip, pool, shape):
     assert "tpu_custom_call" in text
 
 
+def _kernel_dots(fn, *specs):
+    """Dots in the text of the Pallas kernels `fn` launches (every copy a
+    branch or a loop body holds counts), and the launches."""
+    jaxpr = jax.make_jaxpr(fn)(*(jax.ShapeDtypeStruct(shape, dtype)
+                                 for shape, dtype in specs))
+    text = str(jaxpr)
+    return text.count("dot_general"), text.count("pallas_call")
+
+
+@pytest.mark.parametrize("shape", ["mistral7b-128x64", "dsmoe16b-128x64",
+                                   "llama7b-96x32"])
+@pytest.mark.parametrize("pool", sorted(POOL_DTYPES))
+def test_paged_kernels_hold_one_update_a_piece_size(pool, shape):
+    """What PR 27 paid for in set-up time: `_switch` instantiates the
+    piece's update once a tail size, plus the full group. Each copy is two
+    dots (QK and PV, batched over the head block), whatever the heads: a
+    per-head unroll would multiply the kernel's text, and with it every
+    program's lowering, by the heads of a block."""
+    from intellillm_tpu.ops.pallas import paged_attention as pa
+    from intellillm_tpu.ops.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    (b, w, hq, hkv), dtype = DECODE_SHAPES[shape], POOL_DTYPES[pool]
+    hp = pa._largest_divisor(hkv, pa._default_hp(jnp.zeros((), dtype)))
+    ppg = pa._pages_per_group(
+        w, hp * BLOCK_SIZE * HEAD_DIM * jnp.dtype(dtype).itemsize)
+    row_updates = ppg // pa._sub_pages(ppg) + 1
+
+    def decode(q, k_cache, v_cache, tables, ctx):
+        return pa.paged_attention(q, k_cache, v_cache, tables, ctx,
+                                  HEAD_DIM ** -0.5, return_lse=True)
+
+    dots, launches = _kernel_dots(
+        decode, ((b, 1, hq, HEAD_DIM), jnp.bfloat16), _pool(dtype, hkv),
+        _pool(dtype, hkv), ((b, w), jnp.int32), ((b, ), jnp.int32))
+    assert launches == 1 and dots == 2 * row_updates
+
+    rows = 512
+
+    def ragged(q, k_new, v_new, k_cache, v_cache, slots, tables, ctx, starts):
+        return ragged_paged_attention(q, k_new, v_new, k_cache, v_cache,
+                                      slots, tables, ctx, HEAD_DIM ** -0.5,
+                                      run_starts=starts)
+
+    dots, launches = _kernel_dots(
+        ragged, ((rows, 1, hq, HEAD_DIM), jnp.bfloat16),
+        ((rows, hkv, HEAD_DIM), dtype), ((rows, hkv, HEAD_DIM), dtype),
+        _pool(dtype, hkv), _pool(dtype, hkv), ((rows, ), jnp.int32),
+        ((rows, w), jnp.int32), ((rows, ), jnp.int32), ((rows, ), jnp.int32))
+    # A tile of many: its in-tile part, and an update a piece size of its
+    # own coarser sub-groups (a quarter of a group), two dots each.
+    tile_updates = ppg // max(ppg // 4, 1) + 1
+    assert launches == 1
+    assert dots == 2 * row_updates + 2 * (1 + tile_updates)
+
+
 # smallthinker-21ba3b-l8's launches under its 4,096-token window: the
 # cell's 512-row chunk and its 16-row decode step, both at the widest table
 # (8,192 tokens of 16-token blocks), 28 query heads over 4 KV heads.
