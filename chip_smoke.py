@@ -399,9 +399,9 @@ def phase_serve(args, expect_tpu: bool) -> None:
               "pipeline_drains": eff["pipeline_drains"],
               "dispatches": eff["dispatches"],
               "memory_stats": mem})
-        check(eff["decode_join_rows"]["cont"] >= 1,
-              "the chunked prompt did not join the decode batch by "
-              "continuation")
+        check(eff["decode_join_rows"]["device"] >= 1,
+              "the chunked prompt did not join the decode batch by the "
+              "continuation behind its final chunk's step")
         check(eff["pipeline_drains"]["chunked_prefill"] >= 1,
               "D and D2 (an idle engine, no decode step in flight) did "
               "not take the chunked pass of a fresh schedule")

@@ -976,9 +976,13 @@ class LLMEngine:
     #     continuation: each continuation is dispatched from a re-snapshot
     #     of the row set (_dispatch_cont) in which rows the host knows to
     #     be done (max_tokens / max_model_len covered by dispatched steps,
-    #     or seen finished) give up their slot and sequences whose
-    #     prefill has been fetched take free slots of the compiled batch
-    #     bucket, their first token passed from the host;
+    #     or seen finished) give up their slot and admitted sequences take
+    #     free slots of the compiled batch bucket. One whose final prompt
+    #     step is still in flight takes its first token on the device,
+    #     gathered from that step's un-fetched output, so it rides the
+    #     first continuation dispatched behind its prompt step (every
+    #     continuation behind a prompt step takes all of its sequences
+    #     that the fused program takes, or is not dispatched);
     #   - what needs a coherent host view drains the pipeline to a full
     #     schedule(), counted by cause: no decode step in flight (idle),
     #     swap / preemption / a refused block growth (memory), a joiner
@@ -1075,8 +1079,10 @@ class LLMEngine:
             return self._drain("idle")
         if self._inflight[0].cont_state is None:
             # A prompt step is the oldest in flight and a decode step is
-            # queued behind it: fetch it first, so that its sequences
-            # join the continuation dispatched next.
+            # queued behind it: fetch it first. It is done or next to
+            # run, its first tokens are due (TTFT), and a sequence of it
+            # that a continuation has already taken must have its first
+            # token on the host before that continuation is fetched.
             return False
         if decodes >= self._pipeline_depth:
             return False
@@ -1084,7 +1090,7 @@ class LLMEngine:
             return self._drain("memory")
         # A sequence part-way through its prompt is no row of the decode
         # batch: its chunks run as prompt steps beside the continuations
-        # (the pass above) and it joins once its final chunk is fetched.
+        # (the pass above) and it joins behind its final chunk's step.
         return self._dispatch_cont(seed)
 
     def _dispatch_fresh(self) -> bool:
@@ -1160,6 +1166,22 @@ class LLMEngine:
                 return step
         return None
 
+    def _prompt_steps_in_flight(self) -> Tuple[Set[int], Dict[int, tuple]]:
+        """The sequences of the prompt steps in flight, and of those a
+        step samples a token for (a final chunk's last row): seq_id ->
+        (that step, the flat row of its output that holds the sample)."""
+        prefilling: Set[int] = set()
+        first_rows: Dict[int, tuple] = {}
+        for step in self._inflight:
+            if step.cont_state is not None:
+                continue
+            prefilling.update(step._pipeline_seq_ids)
+            if step.first_tokens is not None:
+                first_rows.update(
+                    (sid, (step, int(row)))
+                    for row, (_, sid) in zip(*step.emit))
+        return prefilling, first_rows
+
     @staticmethod
     def _fused_excludes(sp: SamplingParams) -> bool:
         """Sampling the fused K>1 decode program does not take: such a
@@ -1177,11 +1199,12 @@ class LLMEngine:
             plan = self._resnapshot(prev)
         if plan is None:
             return False
-        cont, tables, join_tokens, groups, joined = plan
-        step = self.worker.execute_decode_cont(cont, tables, join_tokens,
+        cont, tables, first_token_rows, groups, joined = plan
+        step = self.worker.execute_decode_cont(cont, tables,
+                                               first_token_rows,
                                                prev.packed, prev.t1)
         self._decode_members.update(joined)
-        self._efficiency.record_decode_join("cont", len(joined))
+        self._efficiency.record_decode_join("device", len(joined))
         self._drain_cause = None
         seq_ids = [sid for _, sid in cont.rows]
         self.scheduler.guard_seqs(seq_ids)
@@ -1232,37 +1255,57 @@ class LLMEngine:
                 loras[slot] = pc.row_loras[slot]
 
         # Joiners: running sequences no decode batch has taken yet, whose
-        # prefill step has been fetched (its token is on the host). One
-        # whose prefill is still in flight joins a later step.
-        prefilling = {sid for step in self._inflight
-                      if step.cont_state is None
-                      for sid in step._pipeline_seq_ids}
+        # final prompt step is in flight. A joiner takes its first token
+        # on the device, from that step's un-fetched output
+        # (`first_rows`): the step was dispatched before this
+        # continuation, so its KV, its state slot and its sample are
+        # ready when the continuation runs, and its lengths are known
+        # without the token. The host appends, streams and stop-checks
+        # that token when the prompt step is fetched, which is before
+        # this continuation is (`_inflight` is FIFO); a first token that
+        # ends the request leaves a zombie row, as any stop the host has
+        # not seen does.
+        prefilling, first_rows = self._prompt_steps_in_flight()
         alive: Set[int] = set()
-        joiners: List[Tuple[Sequence, SequenceGroup]] = []
+        joiners: List[Tuple[Sequence, SequenceGroup, tuple]] = []
         for group in sched.running:
+            sp = group.sampling_params
             for seq in group.get_seqs(status=SequenceStatus.RUNNING):
                 alive.add(seq.seq_id)
                 if (seq.seq_id in self._decode_members
-                        or seq.seq_id in prefilling
                         or not seq.data.prefill_complete):
                     continue
-                if self._fused_excludes(group.sampling_params):
+                if seq.seq_id not in prefilling:
+                    # Fetched, and no continuation behind its prompt step
+                    # took it: sampling the fused program excludes.
                     self._drain("params")
                     return None
-                joiners.append((seq, group))
+                # Its first token is its last where the budget or the
+                # model's length says so.
+                source = first_rows.get(seq.seq_id)
+                if (source is None or self._fused_excludes(sp)
+                        or (sp.max_tokens is not None
+                            and seq.get_output_len() + 1 >= sp.max_tokens)
+                        or seq.get_len() + 1 > mml):
+                    continue
+                joiners.append((seq, group, source))
         free = np.flatnonzero(ctx == 0)
         if len(joiners) > len(free):
             self._drain("bucket")
             return None
-        join_tokens = np.full(b, -1, np.int64)
-        for slot, (seq, group) in zip(free, joiners):
-            ctx[slot] = seq.get_len()
-            out_lens[slot] = seq.get_output_len()
+        # By prompt step in flight: the flat row of its output a slot
+        # reads its first token from, -1 for none.
+        first_token_rows: Dict[object, np.ndarray] = {}
+        for slot, (seq, group, (step, row)) in zip(free, joiners):
+            # The token still on the device counts in both lengths.
+            ctx[slot] = seq.get_len() + 1
+            out_lens[slot] = seq.get_output_len() + 1
             owners[slot] = (seq, sched.group_metadata(group), group)
             params[slot] = group.sampling_params
             if loras is not None:
                 loras[slot] = group.lora_request
-            join_tokens[slot] = seq.get_last_token_id()
+            first_token_rows.setdefault(
+                step, np.full(b, -1, np.int32))[slot] = row
         slots = np.flatnonzero(ctx)
         if not len(slots):
             # Every row's budget is covered by the steps in flight and
@@ -1311,8 +1354,8 @@ class LLMEngine:
                 cont.metas.append(meta)
                 groups.append(group)
         self._decode_members &= alive
-        return (cont, tables, join_tokens, groups,
-                [seq.seq_id for seq, _ in joiners])
+        return (cont, tables, first_token_rows, groups,
+                [seq.seq_id for seq, _, _ in joiners])
 
     def _finalize_one(self) -> List[RequestOutput]:
         step = self._inflight.popleft()

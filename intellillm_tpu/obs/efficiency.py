@@ -64,7 +64,7 @@ PROGRAMS = ("mixed", "decode_fused", "decode_cont")
 DEVICE_STATES = ("idle", "busy")
 # How a row entered a decode batch, and why the step pipeline drained to
 # a fresh schedule (engine/llm_engine.py step_pipelined).
-JOIN_VIAS = ("cont", "fresh")
+JOIN_VIAS = ("device", "fresh")
 # The two forms of the expert FFN (layers/moe.py moe_ffn).
 MOE_FFN_PATHS = ("dense", "grouped")
 # Whether a held expert's weights were read by a layer-substep.
@@ -219,9 +219,11 @@ class _EfficiencyMetrics:
             ["program", "visited"])
         self.counter_decode_join_rows = Counter(
             "intellillm_decode_join_rows_total",
-            "Rows that entered a decode batch, by how (via: cont = took a "
-            "free slot of the running batch at a continuation; fresh = "
-            "merged by a full schedule after the pipeline drained).",
+            "Rows that entered a decode batch, by how (via: device = took "
+            "a free slot of the running batch at a continuation, its "
+            "first token read on the device from its prompt step still "
+            "in flight; fresh = merged by a full schedule after the "
+            "pipeline drained).",
             ["via"])
         self.counter_pipeline_drains = Counter(
             "intellillm_pipeline_drains_total",
@@ -624,8 +626,9 @@ class EfficiencyTracker:
 
     def record_decode_join(self, via: str, rows: int) -> None:
         """`rows` sequences were given a row of a decode batch for the
-        first time: by a continuation's re-snapshot (`cont`) or by a
-        full schedule after a drain (`fresh`)."""
+        first time: by a continuation's re-snapshot (`device`: their
+        first token gathered on the device from their prompt step in
+        flight) or by a full schedule after a drain (`fresh`)."""
         if not self.enabled or not rows:
             return
         with self._lock:

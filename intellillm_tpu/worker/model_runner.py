@@ -30,7 +30,7 @@ execute_model :516, CUDAGraphRunner :701). TPU redesign:
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -136,6 +136,10 @@ class InflightStep:
         # sentinels, obs/numerics.py) — only when --enable-numerics.
         self.numerics = numerics
         self.cont_state: Optional[DecodeContState] = None
+        # A prompt step dispatched ahead of its fetch: its rows' first
+        # samples on the device (`ModelRunner._first_tokens`), for the
+        # continuations that take its sequences before the fetch.
+        self.first_tokens = None
 
     def finalize(self) -> List[SamplerOutput]:
         with self.runner._tracer.span("sample"):
@@ -367,6 +371,25 @@ class ModelRunner:
                              "do_random"),
             donate_argnames=("kv_caches", ),
         )
+        # A joiner's first token goes from its prompt step to the
+        # continuation on the device, in two small programs: a prompt
+        # step's first samples at one length (an executable a packed
+        # shape, called with every prompt step dispatched ahead of its
+        # fetch), and the gather of a continuation's `join_tokens` from
+        # them (an executable a decode batch). On a mesh of several
+        # devices their outputs are whole on each, as the zeros a
+        # continuation without joiners passes are: `decode_cont` sees
+        # one placement (on one device nothing is committed to it).
+        self._whole = None
+        if mesh is not None and mesh.size > 1:
+            from jax.sharding import NamedSharding, PartitionSpec
+            self._whole = NamedSharding(mesh, PartitionSpec())
+        self._jit_first_tokens = jax.jit(
+            self._first_tokens_fn, static_argnames=("length", ),
+            out_shardings=self._whole)
+        self._jit_join_gather = jax.jit(self._join_gather_fn,
+                                        out_shardings=self._whole)
+        self._join_batches: Set[int] = set()
 
         # Pin the trace-time kernel selection at construction: every
         # executable this runner compiles bakes these paths in, and a
@@ -676,6 +699,23 @@ class ModelRunner:
             do_topk=do_topk, do_topp=do_topp, do_minp=do_minp,
             do_penalties=do_penalties, do_random=do_random,
             state_slots=state_slots, window_tables=window_tables)
+
+    @staticmethod
+    def _first_tokens_fn(packed, *, length):
+        """A step's first sample of every flat row (column 0 of the
+        _pack layout), padded to `length` so that the gather below is
+        keyed by the decode batch alone."""
+        first = packed[:, 0]
+        return jnp.pad(first, (0, max(0, length - first.shape[0])))
+
+    @staticmethod
+    def _join_gather_fn(tokens, first_tokens, rows):
+        """The continuation's `join_tokens` [B, 1] with the first tokens
+        a prompt step sampled: slot i takes `first_tokens[rows[i]]`, and
+        keeps `tokens[i]` where `rows[i]` is -1. The step need not have
+        been fetched, nor have run: device order makes it ready."""
+        picked = first_tokens[jnp.maximum(rows, 0)]
+        return jnp.where(rows >= 0, picked, tokens[:, 0])[:, None]
 
     def _decode_teacher_fn(self, params, kv_caches, teacher_tokens,
                            positions, block_tables, context_lens,
@@ -1092,6 +1132,39 @@ class ModelRunner:
         spec = P(*(("data", ) + (None, ) * (arr.ndim - 1)))
         return jax.device_put(jnp.asarray(arr),
                               NamedSharding(self.mesh, spec))
+
+    def _first_tokens(self, packed):
+        """`_first_tokens_fn` dispatched on a prompt step's output. The
+        compile tracker counts the two join programs' executables; the
+        kernel ledger, which compiles a program a second time to read
+        its cost, lists the model's programs only."""
+        return self._compile_tracker.call(
+            "join_gather", ("first_tokens", ) + tuple(packed.shape),
+            self._jit_first_tokens, packed,
+            length=self.mixed_token_buckets[-1])
+
+    def _join_tokens(self, b: int,
+                     first_token_rows: Dict["InflightStep", np.ndarray]):
+        """A continuation's `join_tokens` [b, 1] on the device: zeros,
+        and over them the first tokens of each prompt step in flight
+        that a slot joins from (`first_token_rows`: by step, the flat
+        row of its output a slot takes, -1 for none). A batch's first
+        continuation calls the gather whether or not a row joins, so
+        that it compiles where `decode_cont` does."""
+        tokens = jax.device_put(np.zeros((b, 1), np.int32), self._whole)
+        sources = [(step.first_tokens, rows)
+                   for step, rows in first_token_rows.items()]
+        if b not in self._join_batches:
+            self._join_batches.add(b)
+            if not sources:
+                sources = [(jax.device_put(
+                    np.zeros(self.mixed_token_buckets[-1], np.int32),
+                    self._whole), np.full(b, -1, np.int32))]
+        for first_tokens, rows in sources:
+            tokens = self._compile_tracker.call(
+                "join_gather", (b, ) + tuple(first_tokens.shape),
+                self._jit_join_gather, tokens, first_tokens, rows)
+        return tokens
 
     def _activate_lora(self, row_loras, padded_n: int):
         """Returns (lora_state, effective vocab width). Extra-vocab LoRA
@@ -1622,6 +1695,9 @@ class ModelRunner:
             emit=(emit_idx, emit_rows), program="mixed",
             numerics=num_stats_dev)
         if defer_fetch:
+            # K = 1 has no continuation program to take them.
+            if n_chunk_groups and self.scheduler_config.num_decode_steps > 1:
+                step.first_tokens = self._first_tokens(packed)
             return step, new_caches
         return step.finalize(), new_caches
 
@@ -1629,7 +1705,7 @@ class ModelRunner:
         self,
         cont: DecodeContState,
         tables: List[List[int]],
-        join_tokens: np.ndarray,
+        first_token_rows: Dict[InflightStep, np.ndarray],
         prev_packed,
         prev_t1: int,
         kv_caches,
@@ -1638,10 +1714,13 @@ class ModelRunner:
         """Dispatch a continuation step of a fused decode batch. `cont` is
         this step's own snapshot (contexts advanced numerically by the
         engine: the host sequence state is allowed to trail). A row's
-        input token is sliced on device from `prev_packed`, except where
-        `join_tokens` [B] holds one (>= 0): a row that joins at this
-        step. `tables` are the rows' block tables, in `cont.rows` order,
-        already grown by the scheduler to cover this step's writes."""
+        input token is sliced on device from `prev_packed`, except for a
+        row that joins at this step: its prompt step is still in flight,
+        and `first_token_rows` has, for that step, the flat row of its
+        output the slot's token is gathered from on the device ([B], -1
+        for no row). `tables` are the rows' block tables, in `cont.rows`
+        order, already grown by the scheduler to cover this step's
+        writes."""
         num_steps = cont.num_steps
         with self._tracer.span("prepare_inputs"):
             b = cont.ctx0.shape[0]
@@ -1653,8 +1732,9 @@ class ModelRunner:
             block_tables = np.zeros((b, w), np.int32)
             for slot, t in zip(cont.slots, tables):
                 block_tables[slot, :len(t)] = t
-            join_mask = (join_tokens >= 0)[:, None]
-            join_tokens = np.maximum(join_tokens, 0).astype(np.int32)[:, None]
+            join_mask = np.zeros((b, 1), np.bool_)
+            for rows in first_token_rows.values():
+                join_mask[:, 0] |= rows >= 0
 
             # Seeds advance exactly as a fresh (caught-up) dispatch would
             # compute them, so pipelined sampling streams match
@@ -1696,7 +1776,8 @@ class ModelRunner:
         with self._tracer.span("execute"):
             packed, new_caches = self._guarded_call(
                 "decode_cont", bucket, self._jit_decode_cont,
-                self.params, kv_caches, prev_packed, place(join_tokens),
+                self.params, kv_caches, prev_packed,
+                self._join_tokens(b, first_token_rows),
                 place(join_mask), place(positions), place(block_tables),
                 place(ctx), *sampling_args, lora_state,
                 prev_t1=prev_t1, num_steps=num_steps, **flags,

@@ -418,11 +418,12 @@ class Worker:
                             # step's packed output (which the fused
                             # warm-up call above just produced with
                             # exactly the runtime shape/dtype), and the
-                            # joiners' token / mask columns.
+                            # joiners' token / mask columns (the
+                            # tokens placed as a real call's are).
                             packed, caches = runner._jit_decode_cont(
                                 self.params,
                                 self.cache_engine.device_cache,
-                                packed, zeros_i,
+                                packed, runner._join_tokens(b, {}),
                                 place(np.zeros((b, 1), np.bool_)),
                                 *args[1:], prev_t1=k,
                                 num_steps=k, **flags, **state_kwargs)
@@ -484,13 +485,13 @@ class Worker:
             self.state_ops.clear()
             self.cache_engine.execute_state_ops(ops)
 
-    def execute_decode_cont(self, cont, tables, join_tokens, prev_packed,
-                            prev_t1: int):
+    def execute_decode_cont(self, cont, tables, first_token_rows,
+                            prev_packed, prev_t1: int):
         """Dispatch a pipelined decode continuation (no swaps/copies — the
         engine only continues batches with no pending block ops)."""
         self._run_state_ops()
         step, new_caches = self.model_runner.execute_decode_cont(
-            cont, tables, join_tokens, prev_packed, prev_t1,
+            cont, tables, first_token_rows, prev_packed, prev_t1,
             self.cache_engine.device_cache, defer_fetch=True)
         self.cache_engine.device_cache = new_caches
         return step

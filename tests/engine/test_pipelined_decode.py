@@ -247,7 +247,7 @@ def test_excluded_joiner_drains_to_fresh_schedule(tiny_llama_dir,
     assert got == ref
     snap = get_efficiency_tracker().snapshot()
     assert snap["pipeline_drains"]["params"] >= 1
-    assert snap["decode_join_rows"]["cont"] == 0
+    assert snap["decode_join_rows"]["device"] == 0
     assert snap["decode_join_rows"]["fresh"] >= 3     # 2 + the joiner's rows
 
 
@@ -354,7 +354,7 @@ def test_joiners_enter_by_continuation_not_by_fresh_schedule(
     assert _dispatches("decode_fused") == 1
     assert _dispatches("decode_cont") >= 5
     snap = get_efficiency_tracker().snapshot()
-    assert snap["decode_join_rows"] == {"cont": len(reqs) - 1, "fresh": 1}
+    assert snap["decode_join_rows"] == {"device": len(reqs) - 1, "fresh": 1}
     assert snap["pipeline_drains"] == {
         "memory": 0, "params": 0, "chunked_prefill": 0, "bucket": 0,
         "idle": 1}
@@ -454,7 +454,7 @@ def test_full_seats_do_not_drain(tiny_llama_dir, example_prompts):
     assert snap["pipeline_drains"] == {
         "memory": 0, "params": 0, "chunked_prefill": 0, "bucket": 0,
         "idle": 1}
-    assert snap["decode_join_rows"] == {"cont": 6, "fresh": 4}
+    assert snap["decode_join_rows"] == {"device": 6, "fresh": 4}
     assert _dispatches("decode_fused") == 1
 
 
@@ -491,7 +491,7 @@ def test_prompt_over_the_budget_is_chunked_beside_the_decode_batch(
     assert snap["pipeline_drains"] == {
         "memory": 0, "params": 0, "chunked_prefill": 0, "bucket": 0,
         "idle": 1}
-    assert snap["decode_join_rows"] == {"cont": 1, "fresh": 1}
+    assert snap["decode_join_rows"] == {"device": 1, "fresh": 1}
 
 
 def test_prompt_over_the_budget_on_an_idle_pipeline_is_co_batched(
@@ -521,7 +521,7 @@ def test_prompt_over_the_budget_on_an_idle_pipeline_is_co_batched(
     snap = get_efficiency_tracker().snapshot()
     assert snap["co_batched_steps"] >= 1
     assert snap["pipeline_drains"]["chunked_prefill"] >= 1
-    assert snap["decode_join_rows"]["cont"] == 0
+    assert snap["decode_join_rows"]["device"] == 0
 
 
 def test_no_overshoot_cont_when_budgets_exhausted(tiny_llama_dir,
@@ -565,3 +565,271 @@ def test_pipelined_k1_falls_back(tiny_opt_dir, example_prompts):
     ref = _run_serial(_build(tiny_opt_dir, num_decode_steps=1), reqs)
     got = _run_pipelined(_build(tiny_opt_dir, num_decode_steps=1), reqs)
     assert got == ref
+
+
+# --- a joiner's first token handed over on the device ----------------------
+#
+# A sequence whose final prompt step is still in flight joins the very next
+# continuation: its first token is gathered on the device from that step's
+# un-fetched output, and the host appends it when the step is fetched.
+
+
+def _log_dispatches(engine, monkeypatch):
+    """Every step the loop dispatches, in order: ("P", the sequences a
+    prompt step samples a first token for), ("F", a fresh schedule's) and
+    ("D", the rows of a continuation)."""
+    log = []
+    dispatch = engine._dispatch
+    cont = engine.worker.execute_decode_cont
+
+    def dispatching(metas, so):
+        dispatch(metas, so)
+        step = engine._inflight[-1]
+        log.append(("P", [sid for _, sid in step.emit[1]])
+                   if so.prompt_run else ("F", step._pipeline_seq_ids))
+
+    def continuing(state, *a, **kw):
+        log.append(("D", [sid for _, sid in state.rows]))
+        return cont(state, *a, **kw)
+
+    monkeypatch.setattr(engine, "_dispatch", dispatching)
+    monkeypatch.setattr(engine.worker, "execute_decode_cont", continuing)
+    return log
+
+
+def _join_lags(log):
+    """For every sequence that joined by continuation: how many
+    continuations were dispatched between the prompt step that sampled
+    its first token and the continuation that took it."""
+    lags = {}
+    fresh = {sid for kind, sids in log if kind == "F" for sid in sids}
+    for i, (kind, sids) in enumerate(log):
+        if kind != "P":
+            continue
+        for sid in set(sids) - fresh:
+            later = [rows for k, rows in log[i + 1:] if k == "D"]
+            took = next((n for n, rows in enumerate(later) if sid in rows),
+                        None)
+            if took is not None:
+                lags[sid] = took
+    return lags
+
+
+def _run_closed(llm, requests, clients):
+    """A closed loop: `clients` requests at first, and a client's next
+    one as soon as the loop has returned its last one's end."""
+    engine = llm.llm_engine
+    pending = list(requests)
+    for _ in range(clients):
+        engine.add_request(*pending.pop(0))
+    outs = []
+    calls = 0
+    while engine.has_unfinished_requests() or engine.has_inflight():
+        new = engine.step_pipelined()
+        outs.extend(new)
+        for out in new:
+            if out.finished and pending:
+                engine.add_request(*pending.pop(0))
+        calls += 1
+        assert calls < 2000, "pipelined engine made no progress"
+    return _collect(outs)
+
+
+def test_closed_loop_joiner_takes_the_next_continuation(
+        tiny_llama_dir, example_prompts, monkeypatch):
+    """Eight clients in a closed loop: a freed seat's next request is
+    prefilled behind the decode steps in flight and joins the FIRST
+    continuation dispatched after its prompt step, not the one after
+    that step's fetch."""
+    prompts = (example_prompts * 6)[:24]
+    reqs = [(str(i), p, _params("greedy", i, 12 + 5 * (i % 4)))
+            for i, p in enumerate(prompts)]
+    ref = _run_serial(_build(tiny_llama_dir), reqs)
+    get_efficiency_tracker().reset_for_testing()
+    llm = _build(tiny_llama_dir)
+    log = _log_dispatches(llm.llm_engine, monkeypatch)
+    got = _run_closed(llm, reqs, clients=8)
+    assert got == ref
+    lags = _join_lags(log)
+    assert len(lags) == len(reqs) - 8
+    assert set(lags.values()) == {0}, lags
+    snap = get_efficiency_tracker().snapshot()
+    assert snap["decode_join_rows"] == {"device": len(lags), "fresh": 8}
+    assert snap["pipeline_drains"] == {
+        "memory": 0, "params": 0, "chunked_prefill": 0, "bucket": 0,
+        "idle": 1}
+    # One executable of each decode program, whoever handed the tokens.
+    runner = llm.llm_engine.worker.model_runner
+    assert runner._jit_decode_cont._cache_size() == 1
+
+
+_SERIAL = {}
+
+
+@pytest.mark.parametrize("sampling", SAMPLINGS)
+@pytest.mark.parametrize("arrivals", ["staggered", "continuous", "closed"])
+def test_first_token_hand_over_matches_serial(
+        tiny_llama_dir, example_prompts, arrivals, sampling):
+    """The outputs equal the serial loop's with every joiner's first
+    token handed over on the device, however the requests arrive: all
+    behind the first two, one a step behind a decode step in flight, or
+    four clients in a closed loop."""
+    prompts = (example_prompts * 3)[:8]
+    reqs = [(str(i), p, _params(sampling, i, 14 + 3 * i))
+            for i, p in enumerate(prompts)]
+    if sampling not in _SERIAL:
+        _SERIAL[sampling] = _run_serial(
+            _build(tiny_llama_dir, max_num_seqs=12), reqs)
+    get_efficiency_tracker().reset_for_testing()
+    llm = _build(tiny_llama_dir, max_num_seqs=12)
+    if arrivals == "staggered":
+        got = _run_pipelined(llm, reqs, stagger_after=2)
+    elif arrivals == "continuous":
+        got = _run_arrivals(llm, reqs, behind_decode=True)
+    else:
+        got = _run_closed(llm, reqs, clients=4)
+    assert got == _SERIAL[sampling]
+    rows = get_efficiency_tracker().snapshot()["decode_join_rows"]
+    assert rows["device"] == len(reqs) - rows["fresh"] >= len(reqs) // 2
+    runner = llm.llm_engine.worker.model_runner
+    assert runner._jit_decode_cont._cache_size() == 1
+
+
+@pytest.mark.parametrize("ending", ["stop_token", "max_tokens_1"])
+def test_joiner_whose_first_token_is_its_last(tiny_llama_dir,
+                                              example_prompts, monkeypatch,
+                                              ending):
+    """The host learns a joiner's first token a step after the device
+    used it. Where that token is a stop the row is a zombie for the one
+    continuation that took it, the request's output is that token alone
+    and its pages go with that step; where `max_tokens` is 1 the end is
+    known ahead and the sequence takes no row at all."""
+    base = [("0", example_prompts[0], _params("greedy", 0, 48)),
+            ("1", example_prompts[1], _params("greedy", 1, 48))]
+    late = example_prompts[2]
+    first = _run_serial(_build(tiny_llama_dir),
+                        [("2", late, _params("greedy", 2, 1))])["2"][0][0][0]
+    sp = (SamplingParams(temperature=0.0, max_tokens=24,
+                         stop_token_ids=[first])
+          if ending == "stop_token" else _params("greedy", 2, 1))
+    reqs = base + [("2", late, sp)]
+    ref = _run_serial(_build(tiny_llama_dir), reqs)
+    assert ref["2"][0][0] == (first, )
+    llm = _build(tiny_llama_dir)
+    engine = llm.llm_engine
+    log = _log_dispatches(engine, monkeypatch)
+    got = _run_pipelined(llm, reqs, stagger_after=2)
+    assert got == ref
+    (late_sid, ) = [sid for kind, sids in log if kind == "P"
+                    for sid in sids if sid not in log[0][1]]
+    took = [rows for kind, rows in log if kind == "D" and late_sid in rows]
+    assert len(took) == (1 if ending == "stop_token" else 0)
+    bm = engine.scheduler.block_manager
+    assert not bm.block_tables and not engine.scheduler._free_guard
+    assert bm.get_num_free_device_blocks() == 128
+
+
+def test_prompt_over_the_budget_joins_from_its_final_chunk(
+        tiny_llama_dir, example_prompts, monkeypatch):
+    """A prompt of three chunk steps beside the decode batch: only the
+    final chunk's step samples a token, and the sequence joins the first
+    continuation dispatched after that step, by the device."""
+    kw = dict(max_num_batched_tokens=32, max_model_len=128)
+    long_prompt = [2, 3, 4, 5, 6] * 16                       # 80 tokens
+    sp = _params("greedy", 0, 40)
+    reqs = [("0", example_prompts[0], sp)]
+
+    def run(pipelined):
+        llm = _build(tiny_llama_dir, **kw)
+        engine = llm.llm_engine
+        log = _log_dispatches(engine, monkeypatch) if pipelined else None
+        engine.add_request(*reqs[0])
+        outs = []
+        calls = 0
+        while (engine.has_unfinished_requests() or engine.has_inflight()
+               or calls < 3):
+            outs.extend(engine.step_pipelined() if pipelined
+                        else engine.step())
+            calls += 1
+            if calls == 3:
+                engine.add_request("1", None, sp,
+                                   prompt_token_ids=long_prompt)
+            assert calls < 400
+        return _collect(outs), log
+
+    ref, _ = run(False)
+    get_efficiency_tracker().reset_for_testing()
+    got, log = run(True)
+    assert got == ref
+    prompt_steps = [sids for kind, sids in log[1:] if kind == "P"]
+    assert len(prompt_steps) == 3 and prompt_steps[:2] == [[], []]
+    assert list(_join_lags(log[1:]).values()) == [0]
+    rows = get_efficiency_tracker().snapshot()["decode_join_rows"]
+    assert rows == {"device": 1, "fresh": 1}
+
+
+def test_lora_row_joins_on_the_device(tiny_llama_dir, example_prompts,
+                                      tmp_path, monkeypatch):
+    """A joiner's adapter rides the same snapshot as its token: a row
+    with an adapter and one without join a running batch of both on the
+    device, and every output equals the serial loop's."""
+    from intellillm_tpu.lora.request import LoRARequest
+    from tests.lora.test_lora import make_adapter
+    adapter = LoRARequest("ad", 1, make_adapter(str(tmp_path / "ad"),
+                                                seed=11, rank=4, alpha=8.0))
+    kw = dict(enable_lora=True, max_loras=2, max_lora_rank=8)
+    loras = [adapter, None, adapter, None]
+    reqs = [(str(i), p, _params("greedy", i, 24))
+            for i, p in enumerate(example_prompts[:4])]
+
+    def run(pipelined):
+        llm = _build(tiny_llama_dir, **kw)
+        engine = llm.llm_engine
+        for req, lora in zip(reqs[:2], loras):
+            engine.add_request(*req, lora_request=lora)
+        outs = []
+        calls = 0
+        while engine.has_unfinished_requests() or engine.has_inflight():
+            outs.extend(engine.step_pipelined() if pipelined
+                        else engine.step())
+            calls += 1
+            if calls == 2:
+                for req, lora in zip(reqs[2:], loras[2:]):
+                    engine.add_request(*req, lora_request=lora)
+            assert calls < 400
+        return _collect(outs)
+
+    ref = run(False)
+    assert ref["0"] != ref["1"] or ref["2"] != ref["3"]
+    get_efficiency_tracker().reset_for_testing()
+    assert run(True) == ref
+    rows = get_efficiency_tracker().snapshot()["decode_join_rows"]
+    assert rows == {"device": 2, "fresh": 2}
+
+
+@pytest.mark.parametrize("mesh", [dict(tensor_parallel_size=2),
+                                  dict(data_parallel_size=2)])
+def test_device_hand_over_on_a_mesh_keeps_one_continuation(
+        tiny_llama_dir, example_prompts, mesh):
+    """On a mesh of several devices a continuation's `join_tokens` is
+    placed one way, gathered or zeros: the continuation stays one
+    executable, the gather one a decode batch, and the outputs equal
+    the serial loop's on one device."""
+    prompts = (example_prompts * 3)[:8]
+    reqs = [(str(i), p, _params("greedy", i, 14 + 3 * i))
+            for i, p in enumerate(prompts)]
+    if "greedy" not in _SERIAL:
+        _SERIAL["greedy"] = _run_serial(
+            _build(tiny_llama_dir, max_num_seqs=12), reqs)
+    get_efficiency_tracker().reset_for_testing()
+    llm = _build(tiny_llama_dir, max_num_seqs=12, **mesh)
+    runner = llm.llm_engine.worker.model_runner
+    # (The gather is a function of the class: its jit cache is shared.)
+    gathers = runner._jit_join_gather._cache_size()
+    got = _run_arrivals(llm, reqs, behind_decode=True)
+    assert got == _SERIAL["greedy"]
+    rows = get_efficiency_tracker().snapshot()["decode_join_rows"]
+    assert rows["device"] >= len(reqs) // 2
+    assert runner._jit_decode_cont._cache_size() == 1
+    assert (runner._jit_join_gather._cache_size() - gathers
+            <= len(runner._join_batches))

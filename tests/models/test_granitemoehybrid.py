@@ -308,7 +308,7 @@ def test_sequences_join_and_leave_and_slots_are_reused(ckpt):
     assert engine.pipeline_enabled and bm.num_state_slots == 3
     prompts = prompts_of(cfg, (12, 30, 17, 25, 8, 21), seed=7)
     lens = (40, 12, 30, 20, 16, 9)
-    joins0 = get_efficiency_tracker()._decode_joins["cont"]
+    joins0 = get_efficiency_tracker()._decode_joins["device"]
     done = {}
 
     def pump(n):
@@ -330,8 +330,10 @@ def test_sequences_join_and_leave_and_slots_are_reused(ckpt):
                            prompt_token_ids=prompts[i])
     while engine.has_unfinished_requests() or engine.has_inflight():
         pump(1)
-    joins = get_efficiency_tracker()._decode_joins["cont"]
-    assert joins - joins0 >= 2, "the late requests joined by continuation"
+    joins = get_efficiency_tracker()._decode_joins["device"]
+    assert joins - joins0 >= 2, (
+        "the late requests joined the continuation dispatched behind their "
+        "prompt step, their first token read from it on the device")
     outs = [done[str(i)] for i in range(6)]
     assert worst_dlogprob(cfg, weights, prompts, outs) < F32_TOL
     assert not bm.state_slots and len(bm._free_state_slots) == 3

@@ -306,7 +306,7 @@ def test_continuation_join_crosses_the_window(ckpt):
     engine = llm.llm_engine
     assert engine.pipeline_enabled
     prompts = prompts_of(cfg, (40, 30, 50, 36), seed=7)
-    joins0 = get_efficiency_tracker()._decode_joins["cont"]
+    joins0 = get_efficiency_tracker()._decode_joins["device"]
     done = {}
 
     def pump(n):
@@ -324,8 +324,10 @@ def test_continuation_join_crosses_the_window(ckpt):
     engine.add_request("3", None, greedy(20), prompt_token_ids=prompts[3])
     while engine.has_unfinished_requests() or engine.has_inflight():
         pump(1)
-    joins = get_efficiency_tracker()._decode_joins["cont"]
-    assert joins - joins0 >= 2, "the late requests joined by continuation"
+    joins = get_efficiency_tracker()._decode_joins["device"]
+    assert joins - joins0 >= 2, (
+        "the late requests joined the continuation dispatched behind their "
+        "prompt step, their first token read from it on the device")
     outs = [done[str(i)] for i in range(4)]
     assert dlogprobs(cfg, weights, prompts, outs).max() < F32_TOL
     assert_all_given_back(llm)

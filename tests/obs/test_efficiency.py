@@ -256,25 +256,40 @@ def test_record_dispatch_counts_programs_device_state_and_context(tracker):
     assert tracker.snapshot()["dispatches"] == {"prefill": 1, "decode": 3}
 
 
-@pytest.mark.parametrize("via,rows", [("cont", 3), ("fresh", 5)])
+@pytest.mark.parametrize("via,rows", [("device", 4), ("fresh", 5)])
 def test_decode_join_rows_count_by_how_the_row_entered(tracker, via, rows):
     """intellillm_decode_join_rows_total{via}: rows that took a slot of
-    the running batch at a continuation, or were merged by a fresh
-    schedule; the other child stays where it was."""
+    the running batch at a continuation, their first token gathered on
+    the device, or were merged by a fresh schedule; the other child
+    stays where it was."""
     from prometheus_client import REGISTRY
-    other = {"cont": "fresh", "fresh": "cont"}[via]
+    from intellillm_tpu.obs.efficiency import JOIN_VIAS
+    assert JOIN_VIAS == ("device", "fresh")
 
     def sample(v):
         return REGISTRY.get_sample_value(
             "intellillm_decode_join_rows_total", {"via": v})
 
-    before = {v: sample(v) for v in (via, other)}
-    assert None not in before.values()      # both children from scrape 1
+    before = {v: sample(v) for v in JOIN_VIAS}
+    assert None not in before.values()      # every child from scrape 1
     tracker.record_decode_join(via, rows)
     tracker.record_decode_join(via, 0)
-    assert tracker.snapshot()["decode_join_rows"] == {via: rows, other: 0}
-    assert sample(via) - before[via] == rows
-    assert sample(other) == before[other]
+    assert tracker.snapshot()["decode_join_rows"] == {
+        v: rows if v == via else 0 for v in JOIN_VIAS}
+    for v in JOIN_VIAS:
+        assert sample(v) - before[v] == (rows if v == via else 0)
+
+
+def test_decode_join_device_child_is_exported_from_the_start():
+    """`via="device"` stands at 0 in `/metrics` and in
+    `/debug/efficiency` before any sequence has joined."""
+    from prometheus_client import generate_latest
+
+    from intellillm_tpu.obs.efficiency import EfficiencyTracker
+    fresh = EfficiencyTracker()
+    assert fresh.snapshot()["decode_join_rows"]["device"] == 0
+    assert (b'intellillm_decode_join_rows_total{via="device"}'
+            in generate_latest())
 
 
 @pytest.mark.parametrize("cause", ["memory", "params", "chunked_prefill",

@@ -236,7 +236,9 @@ def test_joined_continuation_counts_the_joiners_rows_and_contexts(
         engine.step_pipelined()
 
     snap = eff.snapshot()
-    assert snap["decode_join_rows"] == {"cont": 2, "fresh": 2}
+    # "2" and "3" arrive behind decode: their prompt step is in flight
+    # when the next continuation takes them, their token on the device.
+    assert snap["decode_join_rows"] == {"device": 2, "fresh": 2}
     assert snap["programs"]["decode_fused"]["idle"] == 1
     # "1" is done after the fresh step (1 + K tokens): no continuation
     # has a row for it; the joiners appear.

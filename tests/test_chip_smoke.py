@@ -48,8 +48,9 @@ def test_one_chip_flow_rehearses_then_refuses():
     assert answered["greedy_repeat_equal"] and answered["seeded_repeat_equal"]
     programs = by_event[("serve", "programs")]
     # The prompt over the budget ran its chunks beside the decode batch
-    # and joined it by continuation.
-    assert programs["decode_join_rows"]["cont"] >= 1
+    # and joined it by the continuation behind its final chunk's step,
+    # its first token read on the device.
+    assert programs["decode_join_rows"]["device"] >= 1
     # D and D2, alone on an idle engine, took the fresh chunked pass.
     assert programs["pipeline_drains"]["chunked_prefill"] >= 1
     assert {"mixed", "decode_fused", "decode_cont"} <= set(

@@ -756,9 +756,10 @@ def test_joined_continuation_is_the_plain_one_s_executable(
     joins = []
     dispatch = runner.execute_decode_cont
 
-    def recording(cont, tables, join_tokens, *args, **kwargs):
-        joins.append(int((join_tokens >= 0).sum()))
-        return dispatch(cont, tables, join_tokens, *args, **kwargs)
+    def recording(cont, tables, first_token_rows, *args, **kwargs):
+        joins.append(sum(int((rows >= 0).sum())
+                         for rows in first_token_rows.values()))
+        return dispatch(cont, tables, first_token_rows, *args, **kwargs)
 
     runner.execute_decode_cont = recording
     sp = SamplingParams(temperature=0.0, max_tokens=40, ignore_eos=True)
@@ -774,6 +775,34 @@ def test_joined_continuation_is_the_plain_one_s_executable(
     snap = tracker.snapshot()
     assert snap["compiles"]["decode_cont"] == 1
     assert runner._jit_decode_cont._cache_size() == 1
+
+
+@pytest.mark.parametrize("rows", [16, 32, 64, 128, 256, 512])
+def test_join_gather_compiles_at_each_mixed_bucket(compiled_for_chip, rows):
+    """The two programs that hand a continuation of 128 rows the first
+    tokens of a prompt step in flight compile for the described v5e at
+    every `mixed` bucket of the cells (the step's packed output at
+    logprob_k = 1 with an expert-parallel share's counter columns): the
+    step's first samples come out at the one length whatever its rows,
+    the gather over them returns the `join_tokens` operand `decode_cont`
+    takes, and both hold next to nothing."""
+    import functools
+
+    from intellillm_tpu.worker.model_runner import ModelRunner
+
+    i32 = jnp.int32
+    length = 512
+    first = compiled_for_chip(
+        functools.partial(ModelRunner._first_tokens_fn, length=length),
+        ((rows, 4 + 4), i32))
+    assert f"s32[{length}]" in first.as_text()
+    gather = compiled_for_chip(
+        ModelRunner._join_gather_fn, ((CELL_ROWS, 1), i32),
+        ((length, ), i32), ((CELL_ROWS, ), i32))
+    assert f"s32[{CELL_ROWS},1]" in gather.as_text()
+    for compiled in (first, gather):
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 64 * 1024
 
 
 # --- a hybrid stack: state layers, 64-wide heads stored at 128 lanes --------

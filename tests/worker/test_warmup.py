@@ -227,3 +227,54 @@ def test_spec_worker_warmup_ladder_scales_with_band(monkeypatch):
     rungs = k_max - k_min + 1
     assert n == 4 + 2 * rungs
     assert worker.warmup_stats["executables"] == n
+
+
+def test_join_gather_compiles_only_where_a_step_program_does(
+        tiny_llama_dir):
+    """The two small programs that hand a continuation the first tokens
+    of a prompt step in flight are one executable a prompt step's packed
+    shape and one a decode batch. Each makes its first call with the
+    first call of the step program it follows (`mixed` ahead of its
+    fetch, `decode_cont`): a prompt step of a size seen before joins a
+    batch seen before without a compile, however late the pair comes."""
+    from intellillm_tpu import LLM, SamplingParams
+    from intellillm_tpu.obs import get_compile_tracker
+    tracker = get_compile_tracker()
+    tracker.reset_for_testing()
+    llm = LLM(model=tiny_llama_dir, dtype="float32",
+              num_device_blocks_override=128, max_model_len=128,
+              max_num_seqs=8, max_paddings=512, swap_space=0.01,
+              num_decode_steps=8)
+    engine = llm.llm_engine
+    runner = engine.worker.model_runner
+    sp = SamplingParams(temperature=0.0, max_tokens=60, ignore_eos=True)
+
+    def compiles():
+        return dict(tracker.snapshot()["compiles"])
+
+    # Prompt steps of 16, 32 and 64 rows; the 32-row one comes first
+    # (alone, before any decode batch) and again last, as a joiner.
+    lens = {0: 20, 1: 5, 2: 40, 3: 24}
+    arrivals = {0: [0], 3: [1], 6: [2], 9: [3]}
+    calls = 0
+    while (engine.has_unfinished_requests() or engine.has_inflight()
+           or calls < 10):
+        for i in arrivals.get(calls, ()):
+            engine.add_request(str(i), None, sp,
+                               prompt_token_ids=[3 + i] * lens[i])
+        before = compiles()
+        engine.step_pipelined()
+        after = compiles()
+        if after.get("join_gather", 0) > before.get("join_gather", 0):
+            assert any(after.get(p, 0) > before.get(p, 0)
+                       for p in ("mixed", "decode_cont")), (before, after)
+        if calls == 8:
+            warmed = after.get("join_gather", 0)
+        calls += 1
+        assert calls < 400
+    assert runner._join_batches == {16}
+    # Three packed shapes and one decode batch: a sum, not a product.
+    assert compiles()["join_gather"] == warmed == 3 + 1
+    from intellillm_tpu.obs import get_efficiency_tracker
+    assert get_efficiency_tracker().snapshot()[
+        "decode_join_rows"]["device"] >= 3
