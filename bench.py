@@ -8,8 +8,8 @@ only when `JAX_PLATFORMS=cpu` asks for it.
 
 Baseline (BASELINE.json): Llama-2-7B serving on v5e-8 at >= 2000 output
 tok/s aggregate == 250 output tok/s per chip. This harness measures
-single-chip offline generation throughput (benchmark_throughput.py role,
-reference `benchmarks/benchmark_throughput.py`) with dummy (random)
+single-chip offline generation throughput (the role of the reference's
+offline throughput benchmark) with dummy (random)
 weights — throughput is weight-value-independent.
 
 Env knobs: INTELLILLM_BENCH_SIZE=7b|1b|tiny|moe (default 7b),
@@ -106,7 +106,7 @@ def build_engine(size: str, max_num_seqs: int, max_model_len: int,
         # K fused decode steps per dispatch: one device→host fetch per
         # K tokens/seq.
         num_decode_steps=int(os.environ.get("INTELLILLM_BENCH_K", "128")))
-    # Speculative mode (benchmarks/spec_bench.py): a dummy draft model of
+    # Speculative mode (INTELLILLM_BENCH_SPEC): a dummy draft model of
     # the named size proposes K tokens per round.
     speculative_config = None
     spec_size = os.environ.get("INTELLILLM_BENCH_SPEC", "").strip()
@@ -122,7 +122,7 @@ def build_engine(size: str, max_num_seqs: int, max_model_len: int,
             draft_hf, dtype="bfloat16", max_model_len=max_model_len,
             load_format="dummy")
         spec_k = int(os.environ.get("INTELLILLM_BENCH_SPEC_K", "4"))
-        # Optional adaptive band (benchmarks/spec_bench.py --adaptive):
+        # Optional adaptive band (INTELLILLM_BENCH_SPEC_K_MIN / _K_MAX):
         # warm the whole K-ladder and let the controller move inside it.
         speculative_config = SpeculativeConfig(
             draft_mc, spec_k,

@@ -1,11 +1,10 @@
 """Shared helpers for the benchmark harnesses.
 
-Role parity: the argument/metric surface of the reference
-`benchmarks/benchmark_{latency,throughput,serving}.py`. The TPU twist: in
-addition to real checkpoints, every harness accepts `--model dummy:7b`
-style specs that build a Llama-shaped engine with random weights (no
-checkpoint downloads in the TPU environment; throughput is
-weight-value-independent).
+Role parity: the request sampling and percentile surface of the reference
+`benchmarks/benchmark_serving.py`. The TPU twist: a `dummy:7b` style spec
+names a Llama-shaped model with random weights, materialized as a
+checkpoint dir the servers boot with `--load-format dummy` (no checkpoint
+downloads in the TPU environment; throughput is weight-value-independent).
 """
 from __future__ import annotations
 
@@ -24,10 +23,6 @@ DUMMY_SIZES = {
 }
 
 
-def is_dummy(model: str) -> bool:
-    return model.startswith("dummy:")
-
-
 def dummy_hf_config(model: str):
     from transformers import LlamaConfig
     size = model.split(":", 1)[1]
@@ -37,57 +32,6 @@ def dummy_hf_config(model: str):
         num_hidden_layers=layers, num_attention_heads=heads,
         num_key_value_heads=kv_heads, max_position_embeddings=4096,
         tie_word_embeddings=False)
-
-
-def build_llm(args):
-    """Build an offline `LLM` from harness args (real checkpoint or
-    dummy:SIZE spec)."""
-    from intellillm_tpu.config import (CacheConfig, ModelConfig,
-                                       ParallelConfig, SchedulerConfig)
-    from intellillm_tpu.engine.llm_engine import LLMEngine
-    from intellillm_tpu.entrypoints.llm import LLM
-
-    if not is_dummy(args.model):
-        return LLM(
-            model=args.model,
-            tokenizer=getattr(args, "tokenizer", None),
-            quantization=getattr(args, "quantization", None),
-            tensor_parallel_size=getattr(args, "tensor_parallel_size", 1),
-            dtype=getattr(args, "dtype", "auto"),
-            max_model_len=getattr(args, "max_model_len", None),
-            enforce_eager=getattr(args, "enforce_eager", False),
-            kv_cache_dtype=getattr(args, "kv_cache_dtype", "auto"),
-            trust_remote_code=getattr(args, "trust_remote_code", False),
-            max_num_seqs=getattr(args, "max_num_seqs", 256),
-            num_device_blocks_override=getattr(args, "num_device_blocks",
-                                               None),
-        )
-
-    model_config = ModelConfig.from_hf_config(
-        dummy_hf_config(args.model),
-        dtype=(args.dtype if getattr(args, "dtype", "auto") != "auto"
-               else "bfloat16"),
-        max_model_len=getattr(args, "max_model_len", None) or 2048,
-        load_format="dummy",
-        quantization=getattr(args, "quantization", None))
-    cache_config = CacheConfig(
-        block_size=16,
-        num_device_blocks_override=getattr(args, "num_device_blocks", None),
-        swap_space_gib=1.0,
-        cache_dtype=getattr(args, "kv_cache_dtype", "auto"))
-    scheduler_config = SchedulerConfig(
-        max_num_batched_tokens=max(2048, model_config.max_model_len),
-        max_num_seqs=getattr(args, "max_num_seqs", 256),
-        max_model_len=model_config.max_model_len,
-        max_paddings=4096)
-    engine = LLMEngine(model_config, cache_config, ParallelConfig(),
-                       scheduler_config, log_stats=False,
-                       skip_tokenizer_init=True)
-    llm = LLM.__new__(LLM)
-    llm.llm_engine = engine
-    from intellillm_tpu.utils import Counter
-    llm.request_counter = Counter()
-    return llm
 
 
 def save_dummy_checkpoint(model_spec, out_dir: str,
@@ -130,7 +74,7 @@ def sample_requests(
     seed: int = 0,
 ) -> List[Tuple[List[int], int]]:
     """(prompt_token_ids, output_len) pairs: from a ShareGPT-format json
-    when given (reference benchmark_throughput.py sample_requests role),
+    when given (the reference's sample_requests role),
     else synthetic random-token prompts."""
     rng = np.random.default_rng(seed)
     if dataset_path is None:

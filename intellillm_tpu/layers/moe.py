@@ -195,6 +195,150 @@ def held_assignments(topi: jnp.ndarray, n_held: int,
     return ((local >= 0) & (local < n_held)).sum(-1).astype(jnp.int32)
 
 
+@dataclasses.dataclass(frozen=True)
+class ExpertShare:
+    """The routed experts one chip holds, in `n_moe_layers` layers of
+    `n_held` each, of a router `ep_size` times as wide: the experts
+    `[ep_rank * n_held, (ep_rank + 1) * n_held)`. A whole model is the
+    share 0 of 1. A family builds its `Routing` from `held_start` and its
+    own scoring fields."""
+    n_held: int
+    top_k: int
+    n_moe_layers: int
+    ep_size: int = 1
+    ep_rank: int = 0
+
+    def __post_init__(self):
+        if not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(f"ep_rank {self.ep_rank} outside ep_size "
+                             f"{self.ep_size}")
+
+    @classmethod
+    def from_config(cls, cfg, n_held: int, top_k: int, n_moe_layers: int):
+        """The share a config's `ep_size` / `ep_rank` name."""
+        return cls(n_held, top_k, n_moe_layers,
+                   ep_size=int(getattr(cfg, "ep_size", 1) or 1),
+                   ep_rank=int(getattr(cfg, "ep_rank", 0) or 0))
+
+    @property
+    def n_router(self) -> int:
+        return self.n_held * self.ep_size
+
+    @property
+    def held_start(self) -> int:
+        return self.ep_rank * self.n_held
+
+    @property
+    def held(self) -> range:
+        """The router's outputs held here: what a loader takes of a
+        checkpoint's experts."""
+        return range(self.held_start, self.held_start + self.n_held)
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_held * self.n_moe_layers
+
+    @property
+    def moe_choices_per_token(self) -> int:
+        """A share reports how many of each token's choices it holds
+        (worker/model_runner.py `_pack`); the whole model has nothing to
+        report: 0."""
+        return self.top_k * self.n_moe_layers if self.ep_size > 1 else 0
+
+    def report_through(self, model) -> None:
+        """Sets on `model` what the runner reads of one whose calls return
+        `ExpertCounts.counters()` beside their result
+        (worker/model_runner.py `_finalize`)."""
+        model.counts_expert_visits = True
+        model.moe_choices_per_token = self.moe_choices_per_token
+
+    def param_shapes(self, hidden: int, inter: int, select_bias: bool = False,
+                     shared_inter: int = 0) -> dict:
+        """A routed layer's entries of a model's `_param_shapes`, in the
+        order the families' seeded draws walk them."""
+        stack = (self.n_held, inter, hidden)
+        shapes = {"gate_router": (hidden, self.n_router)}
+        if select_bias:
+            shapes["expert_bias"] = (self.n_router, )
+        shapes.update({"w1": stack, "w2": stack, "w3": stack})
+        if shared_inter:
+            shapes.update({"shared_gate": (hidden, shared_inter),
+                           "shared_up": (hidden, shared_inter),
+                           "shared_down": (shared_inter, hidden)})
+        return shapes
+
+
+# The counts of a layer without routed experts (A.X-K1's first, LFM2's
+# first two), for `ExpertCounts.add`: no expert read, nothing held.
+DENSE_LAYER = (0, None)
+
+
+class ExpertCounts:
+    """One call's counters, summed over its layers."""
+
+    def __init__(self, share: ExpertShare):
+        self.share = share
+        self.visited = jnp.int32(0)
+        self.held = None
+
+    def add(self, counts) -> None:
+        """`counts`: what `routed_experts` returned beside its output."""
+        visited, held = counts
+        self.visited += visited
+        if held is not None:
+            self.held = held if self.held is None else self.held + held
+
+    def counters(self) -> dict:
+        """What a model's `__call__` returns beside its result, for the
+        runner's `_pack`: "expert_visits" [2] i32 (held experts whose
+        weights a layer read, held experts) and, from a share, "held"
+        [B, L] i32 (how many of a token's choices are held here)."""
+        counters = {"expert_visits": jnp.stack(
+            [self.visited, jnp.int32(self.share.experts_held)])}
+        if self.share.moe_choices_per_token:
+            counters["held"] = self.held
+        return counters
+
+
+def route(h: jnp.ndarray, lp, share: ExpertShare, routing: Routing):
+    """`_route` of a layer's params `lp` over the tokens of `h` [..., D],
+    for `routed_experts`: a family whose router reads another input than
+    its experts (SmallThinker: the layer's normalised input) calls it
+    there."""
+    with jax.named_scope("moe_router"):
+        return _route(h.reshape(-1, h.shape[-1]), lp["gate_router"],
+                      share.top_k, routing=routing,
+                      select_bias=lp.get("expert_bias"))
+
+
+def routed_experts(h: jnp.ndarray, lp, share: ExpertShare, routing: Routing,
+                   mesh=None, routed=None, activation: str = "silu"):
+    """A layer's routed-experts block over `h` [B, L, D] (the post-mixer
+    norm's output): router, held experts, and the shared expert where `lp`
+    has one ("shared_gate"), all with the gate `activation`. Returns the
+    block's output [B, L, D] and the layer's counts for
+    `ExpertCounts.add`: (held experts read, and from a share how many of
+    each token's choices are held here [B, L], else None)."""
+    b, l, e = h.shape
+    flat = h.reshape(b * l, e)
+    if routed is None:
+        routed = route(flat, lp, share, routing)
+    out, visited = moe_ffn(
+        flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"], share.top_k,
+        routing=routing, routed=routed, mesh=mesh, activation=activation,
+        count_visited=True)
+    held = None
+    if share.moe_choices_per_token:
+        held = held_assignments(routed[1], share.n_held,
+                                share.held_start).reshape(b, l)
+    if "shared_gate" in lp:
+        act = gate_activation(activation)
+        with jax.named_scope("moe_shared"):
+            out = out + (act(flat @ lp["shared_gate"]) *
+                         (flat @ lp["shared_up"])) @ lp["shared_down"]
+    return out.reshape(b, l, e), (visited, held)
+
+
 def random_gate_up_stack(key, n: int, d: int, i: int, dtype) -> jnp.ndarray:
     """A random w1 or w3 for `load_format="dummy"`: drawn as [N, D, I], so
     that a seed keeps giving the model it gave when that was the stored

@@ -28,12 +28,13 @@ def rms_norm(
 
 def fused_add_rms_norm(
     x: jnp.ndarray,
-    residual: jnp.ndarray,
+    residual: Optional[jnp.ndarray],
     weight: jnp.ndarray,
     eps: float = 1e-6,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (normed(x + residual), x + residual)."""
-    added = x + residual
+    """Returns (normed(x + residual), x + residual); `residual` None (a
+    first layer's: the stream is `x` alone) stands for zeros."""
+    added = x if residual is None else x + residual
     return rms_norm(added, weight, eps), added
 
 

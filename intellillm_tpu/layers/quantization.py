@@ -18,8 +18,10 @@ Checkpoint converters (`awq_unpack` / `gptq_to_int4` /
 `squeezellm/quant_cuda_kernel.cu`): AWQ and GPTQ load to int4 exactly
 (GPTQ act-order becomes an input-row permutation — the exllama
 `gptq_shuffle` role — never a re-rounding); SqueezeLLM's non-uniform LUT
-cannot map onto an affine int4 grid, so it dequantizes and requantizes to
-int8 (documented, logged loudly at load).
+cannot map onto an affine int4 grid, so it keeps its per-channel 16-entry
+table as the exact device format `{"q4lut", "lut"}`
+(`squeezellm_to_q4lut`), and only a layout the packer cannot express (an
+odd input dim) is dequantized and requantized to int8.
 """
 from __future__ import annotations
 

@@ -12,6 +12,7 @@ float32 and gathered by position ids at call time.
 """
 from __future__ import annotations
 
+import copy
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -46,6 +47,18 @@ class RotaryEmbedding:
             (np.cos(freqs) * mscale).astype(np.float32))
         self.sin_cache = jnp.asarray(
             (np.sin(freqs) * mscale).astype(np.float32))
+
+    def trimmed_to(self, limit) -> "RotaryEmbedding":
+        """This embedding with the rows of its tables a position under
+        `limit` (a model's `max_model_len`) can reach: every compiled
+        program holds the tables as constants. A copy where rows go
+        (`get_rope` shares instances), itself where none do."""
+        if not limit or self.cos_cache.shape[0] <= limit:
+            return self
+        trimmed = copy.copy(self)
+        trimmed.cos_cache = self.cos_cache[:limit]
+        trimmed.sin_cache = self.sin_cache[:limit]
+        return trimmed
 
     def _compute_inv_freq(self, base: float) -> np.ndarray:
         return 1.0 / (base**(np.arange(0, self.rotary_dim, 2,

@@ -22,18 +22,15 @@ as Llama):
   shared expert (`layers/moe.py` `Routing`).
 
 Expert parallelism (`ep_size`, `ep_rank` in the config): with `ep_size` > 1
-`n_routed_experts` is what THIS chip holds, experts `[ep_rank * n,
-(ep_rank + 1) * n)` of a router `n * ep_size` wide. The layer routes over
-the whole router, computes its own experts' part and leaves the rest out:
-that is another chip's part of the sum, and no code here stands in for the
-other chips or their exchange. The shared expert, attention and the dense
-layer are whole on every chip (a latent cache has no head axis to divide).
+`n_routed_experts` is what THIS chip holds (`layers/moe.py` `ExpertShare`
+says of which router, `Routing.held_start` what the layer leaves out). The
+shared expert, attention and the dense layer are whole on every chip (a
+latent cache has no head axis to divide).
 
 tp > 1 is not supported for this model yet (`partition_specs` says so).
 """
 from __future__ import annotations
 
-import copy
 import math
 from typing import Dict, Optional
 
@@ -44,13 +41,15 @@ import numpy as np
 from intellillm_tpu.config import ModelConfig
 from intellillm_tpu.layers.activation import get_act_fn
 from intellillm_tpu.layers.mla_attention import LatentAttention
-from intellillm_tpu.layers.moe import (Routing, _route, held_assignments,
-                                       moe_ffn, random_gate_up_stack)
+from intellillm_tpu.layers.moe import (DENSE_LAYER, ExpertCounts, ExpertShare,
+                                       Routing, random_gate_up_stack,
+                                       routed_experts)
 from intellillm_tpu.layers.normalization import fused_add_rms_norm, rms_norm
 from intellillm_tpu.layers.rotary_embedding import (_yarn_get_mscale,
                                                     get_rope)
 from intellillm_tpu.models.weight_utils import (cast_array,
-                                                hf_model_weights_iterator)
+                                                hf_model_weights_iterator,
+                                                stack_experts)
 
 Params = Dict[str, object]
 
@@ -59,9 +58,6 @@ class AXK1ForCausalLM:
 
     supports_lora = False
     supported_quantization = ()
-    # A call returns {"expert_visits": ...} beside its result
-    # (worker/model_runner.py `_call_model`).
-    counts_expert_visits = True
 
     def __init__(self, model_config: ModelConfig) -> None:
         cfg = model_config.hf_config
@@ -87,42 +83,28 @@ class AXK1ForCausalLM:
         self.n_shared = cfg.n_shared_experts or 0
         self.top_k = cfg.num_experts_per_tok
         # The share: `n_routed_experts` held here of a router ep_size x wider.
-        self.ep_size = int(getattr(cfg, "ep_size", 1) or 1)
-        self.ep_rank = int(getattr(cfg, "ep_rank", 0) or 0)
-        if not 0 <= self.ep_rank < self.ep_size:
-            raise ValueError(f"ep_rank {self.ep_rank} outside ep_size "
-                             f"{self.ep_size}")
         self.n_held = cfg.n_routed_experts
-        self.n_router = self.n_held * self.ep_size
-        if self.n_router % cfg.n_group:
-            raise ValueError(f"router width {self.n_router} does not divide "
-                             f"into n_group {cfg.n_group}")
+        self.share = ExpertShare.from_config(
+            cfg, self.n_held, self.top_k,
+            sum(self._is_moe_layer(i) for i in range(self.num_layers)))
+        self.share.report_through(self)
+        if self.share.n_router % cfg.n_group:
+            raise ValueError(f"router width {self.share.n_router} does not "
+                             f"divide into n_group {cfg.n_group}")
         self.routing = Routing(
             scoring=cfg.scoring_func,
             renormalize=bool(cfg.norm_topk_prob),
             n_group=cfg.n_group, topk_group=cfg.topk_group,
             scale=float(cfg.routed_scaling_factor),
-            held_start=self.ep_rank * self.n_held)
-        n_moe = sum(self._is_moe_layer(i) for i in range(self.num_layers))
-        self.experts_held = self.n_held * n_moe
-        # A share reports how many of each token's choices it holds
-        # (worker/model_runner.py `_pack`); the whole model has nothing
-        # to report.
-        self.moe_choices_per_token = (self.top_k * n_moe
-                                      if self.ep_size > 1 else 0)
+            held_start=self.share.held_start)
 
         scaling = getattr(cfg, "rope_scaling", None)
-        self.rope = get_rope(self.d_rope, self.d_rope,
-                             cfg.max_position_embeddings, cfg.rope_theta,
-                             is_neox_style=False, rope_scaling=scaling)
         # YaRN's table has factor x original positions (131,072 rows, 34 MB
-        # for cos and sin) and every compiled program holds its own copy
-        # of it as a constant: keep the rows a position can reach.
-        limit = model_config.max_model_len
-        if limit and self.rope.cos_cache.shape[0] > limit:
-            self.rope = copy.copy(self.rope)
-            self.rope.cos_cache = self.rope.cos_cache[:limit]
-            self.rope.sin_cache = self.rope.sin_cache[:limit]
+        # for cos and sin): keep the rows a position can reach.
+        self.rope = get_rope(
+            self.d_rope, self.d_rope, cfg.max_position_embeddings,
+            cfg.rope_theta, is_neox_style=False,
+            rope_scaling=scaling).trimmed_to(model_config.max_model_len)
         scale = (self.d_nope + self.d_rope)**-0.5
         if scaling and scaling.get("type", scaling.get("rope_type")) == "yarn":
             m = _yarn_get_mscale(scaling["factor"],
@@ -141,22 +123,14 @@ class AXK1ForCausalLM:
         h = params["embed_tokens"][input_ids]
         residual = None
         new_caches = []
-        held = None
-        visited = jnp.int32(0)
+        counts = ExpertCounts(self.share)
         for i in range(self.num_layers):
-            h, residual, cache, held_i, visited_i = self._layer(
+            h, residual, cache = self._layer(
                 params["layers"][i], h, residual, kv_caches[i],
-                attn_metadata, positions)
+                attn_metadata, positions, counts)
             new_caches.append(cache)
-            visited += visited_i
-            if held_i is not None:
-                held = held_i if held is None else held + held_i
         h, _ = fused_add_rms_norm(h, residual, params["norm"], self.rms_eps)
-        counters = {"expert_visits": jnp.stack(
-            [visited, jnp.int32(self.experts_held)])}
-        if self.moe_choices_per_token:
-            counters["held"] = held
-        return h, new_caches, counters
+        return h, new_caches, counts.counters()
 
     def _attention(self, lp, h, kv_cache, attn_metadata, positions):
         b, l, _ = h.shape
@@ -180,38 +154,23 @@ class AXK1ForCausalLM:
             o = jnp.einsum("blhr,hrv->blhv", o_lat, lp["w_uv"])
         return o.reshape(b, l, hh * self.d_v) @ lp["o"], kv_cache
 
-    def _layer(self, lp, h, residual, kv_cache, attn_metadata, positions):
-        b, l, e = h.shape
-        if residual is None:
-            residual = h
-            h = rms_norm(h, lp["input_norm"], self.rms_eps)
-        else:
-            h, residual = fused_add_rms_norm(h, residual, lp["input_norm"],
-                                             self.rms_eps)
+    def _layer(self, lp, h, residual, kv_cache, attn_metadata, positions,
+               counts):
+        h, residual = fused_add_rms_norm(h, residual, lp["input_norm"],
+                                         self.rms_eps)
         h, kv_cache = self._attention(lp, h, kv_cache, attn_metadata,
                                       positions)
         h, residual = fused_add_rms_norm(h, residual, lp["post_attn_norm"],
                                          self.rms_eps)
         if "w1" not in lp:
             h = (self.act(h @ lp["gate"]) * (h @ lp["up"])) @ lp["down"]
-            return h, residual, kv_cache, None, 0
-        flat = h.reshape(b * l, e)
-        with jax.named_scope("moe_router"):
-            routed = _route(flat, lp["gate_router"], self.top_k,
-                            routing=self.routing)
-        out, visited = moe_ffn(
-            flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
-            self.top_k, routing=self.routing, routed=routed,
-            mesh=attn_metadata.mesh, count_visited=True)
-        held = None
-        if self.moe_choices_per_token:
-            held = held_assignments(routed[1], self.n_held,
-                                    self.routing.held_start).reshape(b, l)
-        if self.n_shared:
-            with jax.named_scope("moe_shared"):
-                out = out + (self.act(flat @ lp["shared_gate"]) *
-                             (flat @ lp["shared_up"])) @ lp["shared_down"]
-        return out.reshape(b, l, e), residual, kv_cache, held, visited
+            counts.add(DENSE_LAYER)
+            return h, residual, kv_cache
+        h, layer_counts = routed_experts(
+            h, lp, self.share, self.routing, mesh=attn_metadata.mesh,
+            activation=self.config.hidden_act.lower())
+        counts.add(layer_counts)
+        return h, residual, kv_cache
 
     def compute_logits(self, params: Params, hidden, lora=None):
         return hidden @ params["lm_head"]
@@ -243,15 +202,9 @@ class AXK1ForCausalLM:
                 "o": (hh * self.d_v, e),
             }
             if self._is_moe_layer(i):
-                mi, n = self.moe_inter, self.n_held
-                layer.update({
-                    "gate_router": (e, self.n_router),
-                    "w1": (n, mi, e), "w2": (n, mi, e), "w3": (n, mi, e)})
-                if self.n_shared:
-                    si = mi * self.n_shared
-                    layer.update({"shared_gate": (e, si),
-                                  "shared_up": (e, si),
-                                  "shared_down": (si, e)})
+                layer.update(self.share.param_shapes(
+                    e, self.moe_inter,
+                    shared_inter=self.moe_inter * self.n_shared))
             else:
                 inter = cfg.intermediate_size
                 layer.update({"gate": (e, inter), "up": (e, inter),
@@ -313,12 +266,8 @@ class AXK1ForCausalLM:
         (`self_attn.{q_a_proj, q_a_layernorm, q_b_proj, kv_a_proj_with_mqa,
         kv_a_layernorm, kv_b_proj, o_proj}`, `mlp.gate`, `mlp.experts.N`,
         `mlp.shared_experts`). A share loads its own experts' slices."""
-        raw: Dict[str, np.ndarray] = {}
-        for name, arr in hf_model_weights_iterator(model_name_or_path,
-                                                   load_format, revision):
-            if "rotary_emb.inv_freq" in name:
-                continue
-            raw[name] = arr
+        raw: Dict[str, np.ndarray] = dict(hf_model_weights_iterator(
+            model_name_or_path, load_format, revision))
 
         def T(key):       # [out, in] -> [in, out]
             return cast_array(raw[key].T, self.dtype)
@@ -334,7 +283,7 @@ class AXK1ForCausalLM:
             "lm_head": T("lm_head.weight")[:, :vocab],
             "layers": [],
         }
-        first = self.routing.held_start
+        held = self.share.held
         for i in range(self.num_layers):
             p = f"model.layers.{i}."
             a = p + "self_attn."
@@ -355,23 +304,15 @@ class AXK1ForCausalLM:
             }
             m = p + "mlp."
             if self._is_moe_layer(i):
-                held = range(first, first + self.n_held)
                 layer["gate_router"] = cast_array(raw[m + "gate.weight"].T,
                                                   "float32")
-                # Stacks are [N, I, D] (layers/moe.py).
-                layer["w1"] = np.stack(
-                    [V(f"{m}experts.{j}.gate_proj.weight") for j in held])
-                layer["w2"] = np.stack(
-                    [T(f"{m}experts.{j}.down_proj.weight") for j in held])
-                layer["w3"] = np.stack(
-                    [V(f"{m}experts.{j}.up_proj.weight") for j in held])
+                layer.update(stack_experts(
+                    raw, m + "experts.", held,
+                    ("gate_proj", "down_proj", "up_proj"), self.dtype))
                 if self.n_shared:
-                    layer["shared_gate"] = T(
-                        m + "shared_experts.gate_proj.weight")
-                    layer["shared_up"] = T(
-                        m + "shared_experts.up_proj.weight")
-                    layer["shared_down"] = T(
-                        m + "shared_experts.down_proj.weight")
+                    for x in ("gate", "up", "down"):
+                        layer["shared_" + x] = T(
+                            f"{m}shared_experts.{x}_proj.weight")
             else:
                 layer["gate"] = T(m + "gate_proj.weight")
                 layer["up"] = T(m + "up_proj.weight")

@@ -21,12 +21,9 @@ Source: https://huggingface.co/ibm-granite/granite-4.0-h-small/blob/main/config.
 - `logits = final_norm(h) @ embed^T / logits_scaling` (a tied head).
 
 Expert parallelism (`ep_size`, `ep_rank` in the config, as
-`models/solar_open2.py`): with `ep_size` > 1 `num_local_experts` is what
-THIS chip holds, experts `[ep_rank * n, (ep_rank + 1) * n)` of a router
-`n * ep_size` wide. The layer routes over the whole router, computes its
-own experts' part and leaves the rest out: that is another chip's part of
-the sum, and no code here stands in for the other chips or their exchange.
-The shared MLP and both mixers are whole on every chip.
+`models/axk1.py`): with `ep_size` > 1 `num_local_experts` is what THIS
+chip holds (`layers/moe.py` `ExpertShare`). The shared MLP and both mixers
+are whole on every chip.
 
 A layer's cache in `kv_caches[i]` is what `ModelConfig.
 get_layer_cache_kinds` says: a (K, V) pool pair for an attention layer, the
@@ -46,8 +43,8 @@ import numpy as np
 from intellillm_tpu.config import ModelConfig
 from intellillm_tpu.layers.attention import PagedAttention
 from intellillm_tpu.layers.mamba2 import mamba2_mixer
-from intellillm_tpu.layers.moe import (Routing, _route, held_assignments,
-                                       moe_ffn, random_gate_up_stack)
+from intellillm_tpu.layers.moe import (ExpertCounts, ExpertShare, Routing,
+                                       random_gate_up_stack, routed_experts)
 from intellillm_tpu.layers.normalization import rms_norm
 from intellillm_tpu.models.weight_utils import (cast_array,
                                                 hf_model_weights_iterator)
@@ -59,9 +56,6 @@ class GraniteMoeHybridForCausalLM:
 
     supports_lora = False
     supported_quantization = ()
-    # A call returns {"expert_visits": ...} beside its result
-    # (worker/model_runner.py `_call_model`).
-    counts_expert_visits = True
 
     def __init__(self, model_config: ModelConfig) -> None:
         cfg = model_config.hf_config
@@ -105,20 +99,10 @@ class GraniteMoeHybridForCausalLM:
         self.top_k = cfg.num_experts_per_tok
         # The share: `num_local_experts` held here of a router ep_size x
         # wider.
-        self.ep_size = int(getattr(cfg, "ep_size", 1) or 1)
-        self.ep_rank = int(getattr(cfg, "ep_rank", 0) or 0)
-        if not 0 <= self.ep_rank < self.ep_size:
-            raise ValueError(f"ep_rank {self.ep_rank} outside ep_size "
-                             f"{self.ep_size}")
-        self.n_held = cfg.num_local_experts
-        self.n_router = self.n_held * self.ep_size
-        self.routing = Routing(held_start=self.ep_rank * self.n_held)
-        self.experts_held = self.n_held * self.num_layers
-        # A share reports how many of each token's choices it holds
-        # (worker/model_runner.py `_pack`); the whole model has nothing
-        # to report.
-        self.moe_choices_per_token = (self.top_k * self.num_layers
-                                      if self.ep_size > 1 else 0)
+        self.share = ExpertShare.from_config(
+            cfg, cfg.num_local_experts, self.top_k, self.num_layers)
+        self.share.report_through(self)
+        self.routing = Routing(held_start=self.share.held_start)
         self.attn = PagedAttention(self.num_heads, self.head_dim,
                                    float(cfg.attention_multiplier),
                                    self.num_kv_heads)
@@ -131,22 +115,14 @@ class GraniteMoeHybridForCausalLM:
         h = (h.astype(jnp.float32) * self.embedding_multiplier).astype(
             h.dtype)
         new_caches = []
-        held = None
-        visited = jnp.int32(0)
+        counts = ExpertCounts(self.share)
         for i in range(self.num_layers):
-            h, cache, held_i, visited_i = self._layer(
+            h, cache = self._layer(
                 params["layers"][i], self.layer_kinds[i], h, kv_caches[i],
-                attn_metadata, positions)
+                attn_metadata, positions, counts)
             new_caches.append(cache)
-            visited += visited_i
-            if held_i is not None:
-                held = held_i if held is None else held + held_i
         h = rms_norm(h, params["norm"], self.eps)
-        counters = {"expert_visits": jnp.stack(
-            [visited, jnp.int32(self.experts_held)])}
-        if self.moe_choices_per_token:
-            counters["held"] = held
-        return h, new_caches, counters
+        return h, new_caches, counts.counters()
 
     def _attention(self, lp, h, kv_cache, attn_metadata):
         b, l, _ = h.shape
@@ -162,8 +138,7 @@ class GraniteMoeHybridForCausalLM:
         return (h.astype(jnp.float32) + self.residual_multiplier
                 * update.astype(jnp.float32)).astype(h.dtype)
 
-    def _layer(self, lp, kind, h, cache, attn_metadata, positions):
-        b, l, e = h.shape
+    def _layer(self, lp, kind, h, cache, attn_metadata, positions, counts):
         x = rms_norm(h, lp["input_norm"], self.eps)
         if kind == "state":
             with jax.named_scope("mamba2_mixer"):
@@ -174,22 +149,12 @@ class GraniteMoeHybridForCausalLM:
         else:
             x, cache = self._attention(lp, x, cache, attn_metadata)
         h = self._add(h, x)
-        flat = rms_norm(h, lp["post_norm"], self.eps).reshape(b * l, e)
-        with jax.named_scope("moe_router"):
-            routed = _route(flat, lp["gate_router"], self.top_k,
-                            routing=self.routing)
-        out, visited = moe_ffn(
-            flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
-            self.top_k, routing=self.routing, routed=routed,
-            mesh=attn_metadata.mesh, count_visited=True)
-        held = None
-        if self.moe_choices_per_token:
-            held = held_assignments(routed[1], self.n_held,
-                                    self.routing.held_start).reshape(b, l)
-        with jax.named_scope("moe_shared"):
-            out = out + (jax.nn.silu(flat @ lp["shared_gate"]) *
-                         (flat @ lp["shared_up"])) @ lp["shared_down"]
-        return self._add(h, out.reshape(b, l, e)), cache, held, visited
+        out, layer_counts = routed_experts(
+            rms_norm(h, lp["post_norm"], self.eps), lp, self.share,
+            self.routing, mesh=attn_metadata.mesh)
+        h = self._add(h, out)
+        counts.add(layer_counts)
+        return h, cache
 
     def compute_logits(self, params: Params, hidden, lora=None):
         # Tied: contract on the embedding's own last axis (no transposed
@@ -231,12 +196,8 @@ class GraniteMoeHybridForCausalLM:
                     "k": (e, self.num_kv_heads * d),
                     "v": (e, self.num_kv_heads * d),
                     "o": (self.num_heads * d, e)})
-            mi, held, si = self.moe_inter, self.n_held, self.shared_inter
-            layer.update({"gate_router": (e, self.n_router),
-                          "w1": (held, mi, e), "w2": (held, mi, e),
-                          "w3": (held, mi, e),
-                          "shared_gate": (e, si), "shared_up": (e, si),
-                          "shared_down": (si, e)})
+            layer.update(self.share.param_shapes(
+                e, self.moe_inter, shared_inter=self.shared_inter))
             layers.append(layer)
         return {"embed_tokens": (cfg.vocab_size, e), "norm": (e, ),
                 "layers": layers}
@@ -331,10 +292,8 @@ class GraniteMoeHybridForCausalLM:
         output_linear [experts, hidden, inner]}`; `shared_mlp.{
         input_linear [2 x inner, hidden], output_linear}`. A share loads
         its own experts' slices and its slice of the vocabulary."""
-        raw: Dict[str, np.ndarray] = {}
-        for name, arr in hf_model_weights_iterator(model_name_or_path,
-                                                   load_format, revision):
-            raw[name] = arr
+        raw: Dict[str, np.ndarray] = dict(hf_model_weights_iterator(
+            model_name_or_path, load_format, revision))
 
         def T(key):       # [out, in] -> [in, out]
             return cast_array(raw[key].T, self.dtype)
@@ -348,7 +307,7 @@ class GraniteMoeHybridForCausalLM:
             "norm": V("model.norm.weight"),
             "layers": [],
         }
-        first = self.routing.held_start
+        held = slice(self.share.held.start, self.share.held.stop)
         mi, si = self.moe_inter, self.shared_inter
         for i in range(self.num_layers):
             p = f"model.layers.{i}."
@@ -377,12 +336,12 @@ class GraniteMoeHybridForCausalLM:
             layer["gate_router"] = cast_array(
                 raw[e + "router.layer.weight"].T, "float32")
             # Stacks are [N, I, D] (layers/moe.py).
-            fused = raw[e + "input_linear.weight"][first:first + self.n_held]
+            fused = raw[e + "input_linear.weight"][held]
             layer["w1"] = cast_array(fused[:, :mi], self.dtype)
             layer["w3"] = cast_array(fused[:, mi:], self.dtype)
             layer["w2"] = cast_array(
-                raw[e + "output_linear.weight"][
-                    first:first + self.n_held].swapaxes(1, 2), self.dtype)
+                raw[e + "output_linear.weight"][held].swapaxes(1, 2),
+                self.dtype)
             s = p + "shared_mlp."
             fused = raw[s + "input_linear.weight"]
             layer["shared_gate"] = cast_array(fused[:si].T, self.dtype)

@@ -31,7 +31,6 @@ tp > 1 is not supported for this model yet (`partition_specs` says so).
 """
 from __future__ import annotations
 
-import copy
 import math
 from typing import Dict, Optional
 
@@ -41,13 +40,15 @@ import numpy as np
 
 from intellillm_tpu.config import ModelConfig
 from intellillm_tpu.layers.attention import PagedAttention
-from intellillm_tpu.layers.moe import (Routing, _route, moe_ffn,
-                                       random_gate_up_stack)
+from intellillm_tpu.layers.moe import (DENSE_LAYER, ExpertCounts, ExpertShare,
+                                       Routing, random_gate_up_stack,
+                                       routed_experts)
 from intellillm_tpu.layers.normalization import fused_add_rms_norm, rms_norm
 from intellillm_tpu.layers.rotary_embedding import get_rope
 from intellillm_tpu.layers.short_conv import short_conv_mixer
 from intellillm_tpu.models.weight_utils import (cast_array,
-                                                hf_model_weights_iterator)
+                                                hf_model_weights_iterator,
+                                                stack_experts)
 
 Params = Dict[str, object]
 
@@ -59,9 +60,6 @@ class Lfm2MoeForCausalLM:
 
     supports_lora = False
     supported_quantization = ()
-    # A call returns {"expert_visits": ...} beside its result
-    # (worker/model_runner.py `_call_model`).
-    counts_expert_visits = True
 
     def __init__(self, model_config: ModelConfig) -> None:
         cfg = model_config.hf_config
@@ -88,21 +86,20 @@ class Lfm2MoeForCausalLM:
                 f"(ops/short_conv.py) is written for 3 taps")
         if cfg.conv_bias:
             raise NotImplementedError("conv_bias is not supported")
+        self.share = ExpertShare(
+            self.num_experts, self.top_k,
+            sum(self._is_moe_layer(i) for i in range(self.num_layers)))
+        self.share.report_through(self)
         self.routing = Routing(
             scoring="sigmoid", renormalize=bool(cfg.norm_topk_prob),
             scale=float(cfg.routed_scaling_factor),
             select_bias=bool(cfg.use_expert_bias),
             sum_eps=_ROUTER_SUM_EPS)
-        self.rope = get_rope(self.head_dim, self.head_dim,
-                             cfg.max_position_embeddings, cfg.rope_theta,
-                             is_neox_style=True)
-        # Every compiled program holds the table as a constant: keep the
-        # rows a position can reach (128,000 published).
-        limit = model_config.max_model_len
-        if limit and self.rope.cos_cache.shape[0] > limit:
-            self.rope = copy.copy(self.rope)
-            self.rope.cos_cache = self.rope.cos_cache[:limit]
-            self.rope.sin_cache = self.rope.sin_cache[:limit]
+        # Of the 128,000 published positions, those one can reach.
+        self.rope = get_rope(
+            self.head_dim, self.head_dim, cfg.max_position_embeddings,
+            cfg.rope_theta, is_neox_style=True).trimmed_to(
+                model_config.max_model_len)
         self.attn = PagedAttention(self.num_heads, self.head_dim,
                                    self.head_dim**-0.5, self.num_kv_heads)
 
@@ -116,19 +113,15 @@ class Lfm2MoeForCausalLM:
         h = params["embed_tokens"][input_ids]
         residual = None
         new_caches = []
-        visited = jnp.int32(0)
+        counts = ExpertCounts(self.share)
         for i in range(self.num_layers):
-            h, residual, cache, visited_i = self._layer(
+            h, residual, cache = self._layer(
                 params["layers"][i], self.layer_kinds[i], h, residual,
-                kv_caches[i], attn_metadata, positions)
+                kv_caches[i], attn_metadata, positions, counts)
             new_caches.append(cache)
-            visited += visited_i
         h, _ = fused_add_rms_norm(h, residual, params["embedding_norm"],
                                   self.eps)
-        held = self.num_experts * sum(
-            self._is_moe_layer(i) for i in range(self.num_layers))
-        return h, new_caches, {
-            "expert_visits": jnp.stack([visited, jnp.int32(held)])}
+        return h, new_caches, counts.counters()
 
     def _attention(self, lp, h, kv_cache, attn_metadata, positions):
         b, l, _ = h.shape
@@ -143,14 +136,9 @@ class Lfm2MoeForCausalLM:
         return out.reshape(b, l, self.num_heads * d) @ lp["o"], kv_cache
 
     def _layer(self, lp, kind, h, residual, cache, attn_metadata,
-               positions):
-        b, l, e = h.shape
-        if residual is None:
-            residual = h
-            h = rms_norm(h, lp["operator_norm"], self.eps)
-        else:
-            h, residual = fused_add_rms_norm(h, residual,
-                                             lp["operator_norm"], self.eps)
+               positions, counts):
+        h, residual = fused_add_rms_norm(h, residual, lp["operator_norm"],
+                                         self.eps)
         if kind == "state":
             h, cache = short_conv_mixer(h, lp["in_proj"], lp["conv_w"],
                                         lp["out_proj"], cache,
@@ -162,17 +150,12 @@ class Lfm2MoeForCausalLM:
                                          self.eps)
         if "w1" not in lp:
             h = (jax.nn.silu(h @ lp["gate"]) * (h @ lp["up"])) @ lp["down"]
-            return h, residual, cache, 0
-        flat = h.reshape(b * l, e)
-        with jax.named_scope("moe_router"):
-            routed = _route(flat, lp["gate_router"], self.top_k,
-                            routing=self.routing,
-                            select_bias=lp.get("expert_bias"))
-        out, visited = moe_ffn(
-            flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
-            self.top_k, routing=self.routing, routed=routed,
-            mesh=attn_metadata.mesh, count_visited=True)
-        return out.reshape(b, l, e), residual, cache, visited
+            counts.add(DENSE_LAYER)
+            return h, residual, cache
+        h, layer_counts = routed_experts(h, lp, self.share, self.routing,
+                                         mesh=attn_metadata.mesh)
+        counts.add(layer_counts)
+        return h, residual, cache
 
     def compute_logits(self, params: Params, hidden, lora=None):
         # Logits leave the matmul in float32 (the sampler works in float32
@@ -215,12 +198,12 @@ class Lfm2MoeForCausalLM:
                     "q_norm": (d, ), "k_norm": (d, ),
                     "o": (self.num_heads * d, e)})
             if self._is_moe_layer(i):
-                mi, n = cfg.moe_intermediate_size, self.num_experts
-                layer.update({"gate_router": (e, n),
-                              "w1": (n, mi, e), "w2": (n, mi, e),
-                              "w3": (n, mi, e)})
+                layer.update(self.share.param_shapes(
+                    e, cfg.moe_intermediate_size))
+                # After the stacks, where this family's seeded draws have
+                # it.
                 if cfg.use_expert_bias:
-                    layer["expert_bias"] = (n, )
+                    layer["expert_bias"] = (self.num_experts, )
             else:
                 inter = cfg.intermediate_size
                 layer.update({"gate": (e, inter), "up": (e, inter),
@@ -320,10 +303,8 @@ class Lfm2MoeForCausalLM:
         q_layernorm, k_layernorm}`, `feed_forward.{w1, w2, w3}` dense or
         `feed_forward.{gate, expert_bias, experts.J.{w1, w2, w3}}`,
         `model.embedding_norm`, `model.embed_tokens`)."""
-        raw: Dict[str, np.ndarray] = {}
-        for name, arr in hf_model_weights_iterator(model_name_or_path,
-                                                   load_format, revision):
-            raw[name] = arr
+        raw: Dict[str, np.ndarray] = dict(hf_model_weights_iterator(
+            model_name_or_path, load_format, revision))
 
         def T(key):       # [out, in] -> [in, out]
             return cast_array(raw[key].T, self.dtype)
@@ -360,19 +341,14 @@ class Lfm2MoeForCausalLM:
                     "k_norm": V(a + "k_layernorm.weight")})
             m = p + "feed_forward."
             if self._is_moe_layer(i):
-                experts = range(self.num_experts)
                 layer["gate_router"] = cast_array(raw[m + "gate.weight"].T,
                                                   "float32")
                 if self.config.use_expert_bias:
                     layer["expert_bias"] = cast_array(
                         raw[m + "expert_bias"], "float32")
-                # Stacks are [N, I, D] (layers/moe.py).
-                layer["w1"] = np.stack(
-                    [V(f"{m}experts.{j}.w1.weight") for j in experts])
-                layer["w2"] = np.stack(
-                    [T(f"{m}experts.{j}.w2.weight") for j in experts])
-                layer["w3"] = np.stack(
-                    [V(f"{m}experts.{j}.w3.weight") for j in experts])
+                layer.update(stack_experts(
+                    raw, m + "experts.", self.share.held,
+                    ("w1", "w2", "w3"), self.dtype))
             else:
                 layer["gate"] = T(m + "w1.weight")
                 layer["up"] = T(m + "w3.weight")

@@ -30,7 +30,6 @@ tp > 1 is not supported for this model yet (`partition_specs` says so).
 """
 from __future__ import annotations
 
-import copy
 import math
 from typing import Dict, Optional
 
@@ -40,12 +39,14 @@ import numpy as np
 
 from intellillm_tpu.config import ModelConfig
 from intellillm_tpu.layers.attention import PagedAttention
-from intellillm_tpu.layers.moe import (Routing, _route, moe_ffn,
-                                       random_gate_up_stack)
-from intellillm_tpu.layers.normalization import fused_add_rms_norm, rms_norm
+from intellillm_tpu.layers.moe import (ExpertCounts, ExpertShare, Routing,
+                                       random_gate_up_stack, route,
+                                       routed_experts)
+from intellillm_tpu.layers.normalization import fused_add_rms_norm
 from intellillm_tpu.layers.rotary_embedding import get_rope
 from intellillm_tpu.models.weight_utils import (cast_array,
-                                                hf_model_weights_iterator)
+                                                hf_model_weights_iterator,
+                                                stack_experts)
 
 Params = Dict[str, object]
 
@@ -54,9 +55,6 @@ class SmallThinkerForCausalLM:
 
     supports_lora = False
     supported_quantization = ()
-    # A call returns {"expert_visits": ...} beside its result
-    # (worker/model_runner.py `_call_model`).
-    counts_expert_visits = True
 
     def __init__(self, model_config: ModelConfig) -> None:
         cfg = model_config.hf_config
@@ -80,18 +78,15 @@ class SmallThinkerForCausalLM:
         self.top_k = cfg.moe_num_active_primary_experts
         self.inter = cfg.moe_ffn_hidden_size
         self.rope_layout = [bool(r) for r in cfg.rope_layout[:self.num_layers]]
+        self.share = ExpertShare(self.num_experts, self.top_k,
+                                 self.num_layers)
+        self.share.report_through(self)
         self.routing = Routing(renormalize=bool(cfg.norm_topk_prob))
-        self.rope = get_rope(self.head_dim, self.head_dim,
-                             cfg.max_position_embeddings, cfg.rope_theta,
-                             is_neox_style=True,
-                             rope_scaling=cfg.rope_scaling)
-        # Every compiled program holds the table as a constant: keep the
-        # rows a position can reach.
-        limit = model_config.max_model_len
-        if limit and self.rope.cos_cache.shape[0] > limit:
-            self.rope = copy.copy(self.rope)
-            self.rope.cos_cache = self.rope.cos_cache[:limit]
-            self.rope.sin_cache = self.rope.sin_cache[:limit]
+        self.rope = get_rope(
+            self.head_dim, self.head_dim, cfg.max_position_embeddings,
+            cfg.rope_theta, is_neox_style=True,
+            rope_scaling=cfg.rope_scaling).trimmed_to(
+                model_config.max_model_len)
         window = int(cfg.sliding_window_size)
         scale = self.head_dim**-0.5
         self.attn_full = PagedAttention(self.num_heads, self.head_dim, scale,
@@ -109,31 +104,23 @@ class SmallThinkerForCausalLM:
         h = params["embed_tokens"][input_ids]
         residual = None
         new_caches = []
-        visited = jnp.int32(0)
+        counts = ExpertCounts(self.share)
         for i in range(self.num_layers):
-            h, residual, cache, visited_i = self._layer(
+            h, residual, cache = self._layer(
                 params["layers"][i], i, h, residual, kv_caches[i],
-                attn_metadata, positions)
+                attn_metadata, positions, counts)
             new_caches.append(cache)
-            visited += visited_i
         h, _ = fused_add_rms_norm(h, residual, params["norm"], self.eps)
-        held = self.num_layers * self.num_experts
-        return h, new_caches, {
-            "expert_visits": jnp.stack([visited, jnp.int32(held)])}
+        return h, new_caches, counts.counters()
 
-    def _layer(self, lp, i, h, residual, cache, attn_metadata, positions):
+    def _layer(self, lp, i, h, residual, cache, attn_metadata, positions,
+               counts):
         b, l, e = h.shape
         d = self.head_dim
-        if residual is None:
-            residual = h
-            h = rms_norm(h, lp["input_norm"], self.eps)
-        else:
-            h, residual = fused_add_rms_norm(h, residual, lp["input_norm"],
-                                             self.eps)
+        h, residual = fused_add_rms_norm(h, residual, lp["input_norm"],
+                                         self.eps)
         # The router reads the layer's normalised input, not the experts'.
-        with jax.named_scope("moe_router"):
-            routed = _route(h.reshape(b * l, e), lp["gate_router"],
-                            self.top_k, routing=self.routing)
+        routed = route(h, lp, self.share, self.routing)
         q = (h @ lp["q"]).reshape(b, l, self.num_heads, d)
         k = (h @ lp["k"]).reshape(b, l, self.num_kv_heads, d)
         v = (h @ lp["v"]).reshape(b, l, self.num_kv_heads, d)
@@ -144,11 +131,11 @@ class SmallThinkerForCausalLM:
         h = out.reshape(b, l, self.num_heads * d) @ lp["o"]
         h, residual = fused_add_rms_norm(h, residual, lp["post_attn_norm"],
                                          self.eps)
-        out, visited = moe_ffn(
-            h.reshape(b * l, e), lp["gate_router"], lp["w1"], lp["w2"],
-            lp["w3"], self.top_k, routing=self.routing, routed=routed,
-            mesh=attn_metadata.mesh, activation="relu", count_visited=True)
-        return out.reshape(b, l, e), residual, cache, visited
+        h, layer_counts = routed_experts(
+            h, lp, self.share, self.routing, mesh=attn_metadata.mesh,
+            routed=routed, activation="relu")
+        counts.add(layer_counts)
+        return h, residual, cache
 
     def compute_logits(self, params: Params, hidden, lora=None):
         # Logits leave the matmul in float32 (models/lfm2_moe.py says why).
@@ -167,14 +154,12 @@ class SmallThinkerForCausalLM:
     def _param_shapes(self):
         """The param tree's structure (leaves: shapes)."""
         cfg = self.config
-        e, d, n, mi = (self.hidden_size, self.head_dim, self.num_experts,
-                       self.inter)
+        e, d = self.hidden_size, self.head_dim
         layer = {
             "input_norm": (e, ), "post_attn_norm": (e, ),
             "q": (e, self.num_heads * d), "k": (e, self.num_kv_heads * d),
             "v": (e, self.num_kv_heads * d), "o": (self.num_heads * d, e),
-            "gate_router": (e, n),
-            "w1": (n, mi, e), "w2": (n, mi, e), "w3": (n, mi, e)}
+            **self.share.param_shapes(e, self.inter)}
         return {"embed_tokens": (cfg.vocab_size, e), "norm": (e, ),
                 "lm_head": (e, cfg.vocab_size),
                 "layers": [dict(layer) for _ in range(self.num_layers)]}
@@ -244,10 +229,8 @@ class SmallThinkerForCausalLM:
         `self_attn.{q,k,v,o}_proj`, `block_sparse_moe.primary_router`,
         `block_sparse_moe.experts.E.{gate,up,down}`, `model.norm`,
         `model.embed_tokens`, `lm_head`)."""
-        raw: Dict[str, np.ndarray] = {}
-        for name, arr in hf_model_weights_iterator(model_name_or_path,
-                                                   load_format, revision):
-            raw[name] = arr
+        raw: Dict[str, np.ndarray] = dict(hf_model_weights_iterator(
+            model_name_or_path, load_format, revision))
 
         def T(key):       # [out, in] -> [in, out]
             return cast_array(raw[key].T, self.dtype)
@@ -261,7 +244,6 @@ class SmallThinkerForCausalLM:
             "lm_head": T("lm_head.weight"),
             "layers": [],
         }
-        experts = range(self.num_experts)
         for i in range(self.num_layers):
             p = f"model.layers.{i}."
             a, m = p + "self_attn.", p + "block_sparse_moe."
@@ -272,13 +254,7 @@ class SmallThinkerForCausalLM:
                 "v": T(a + "v_proj.weight"), "o": T(a + "o_proj.weight"),
                 "gate_router": cast_array(
                     raw[m + "primary_router.weight"].T, "float32"),
-                # Stacks are [N, I, D] (layers/moe.py): gate and up are the
-                # checkpoint's own [out, in], down its transpose.
-                "w1": np.stack(
-                    [V(f"{m}experts.{j}.gate.weight") for j in experts]),
-                "w2": np.stack(
-                    [T(f"{m}experts.{j}.down.weight") for j in experts]),
-                "w3": np.stack(
-                    [V(f"{m}experts.{j}.up.weight") for j in experts]),
+                **stack_experts(raw, m + "experts.", self.share.held,
+                                ("gate", "down", "up"), self.dtype),
             })
         return params

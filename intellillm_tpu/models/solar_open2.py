@@ -21,11 +21,8 @@ RMSNorm (`rms_norm_eps`) throughout, pre-norm:
 
 Expert parallelism (`ep_size`, `ep_rank` in the config, as
 `models/axk1.py`): with `ep_size` > 1 `n_routed_experts` is what THIS chip
-holds, experts `[ep_rank * n, (ep_rank + 1) * n)` of a router `n *
-ep_size` wide. The layer routes over the whole router, computes its own
-experts' part and leaves the rest out: that is another chip's part of the
-sum, and no code here stands in for the other chips or their exchange.
-The shared expert and both mixers are whole on every chip.
+holds (`layers/moe.py` `ExpertShare`). The shared expert and both mixers
+are whole on every chip.
 
 A layer's cache in `kv_caches[i]` is what `ModelConfig.
 get_layer_cache_kinds` says: a (K, V) pool pair for a GQA layer, the (taps,
@@ -45,11 +42,12 @@ import numpy as np
 from intellillm_tpu.config import ModelConfig
 from intellillm_tpu.layers.attention import PagedAttention
 from intellillm_tpu.layers.linear_attention import kda_mixer
-from intellillm_tpu.layers.moe import (Routing, _route, held_assignments,
-                                       moe_ffn, random_gate_up_stack)
-from intellillm_tpu.layers.normalization import fused_add_rms_norm, rms_norm
+from intellillm_tpu.layers.moe import (ExpertCounts, ExpertShare, Routing,
+                                       random_gate_up_stack, routed_experts)
+from intellillm_tpu.layers.normalization import fused_add_rms_norm
 from intellillm_tpu.models.weight_utils import (cast_array,
-                                                hf_model_weights_iterator)
+                                                hf_model_weights_iterator,
+                                                stack_experts)
 
 Params = Dict[str, object]
 
@@ -58,9 +56,6 @@ class SolarOpen2ForCausalLM:
 
     supports_lora = False
     supported_quantization = ()
-    # A call returns {"expert_visits": ...} beside its result
-    # (worker/model_runner.py `_call_model`).
-    counts_expert_visits = True
 
     def __init__(self, model_config: ModelConfig) -> None:
         cfg = model_config.hf_config
@@ -101,23 +96,13 @@ class SolarOpen2ForCausalLM:
         self.n_shared = cfg.n_shared_experts or 0
         self.top_k = cfg.num_experts_per_tok
         # The share: `n_routed_experts` held here of a router ep_size x wider.
-        self.ep_size = int(getattr(cfg, "ep_size", 1) or 1)
-        self.ep_rank = int(getattr(cfg, "ep_rank", 0) or 0)
-        if not 0 <= self.ep_rank < self.ep_size:
-            raise ValueError(f"ep_rank {self.ep_rank} outside ep_size "
-                             f"{self.ep_size}")
-        self.n_held = cfg.n_routed_experts
-        self.n_router = self.n_held * self.ep_size
+        self.share = ExpertShare.from_config(
+            cfg, cfg.n_routed_experts, self.top_k, self.num_layers)
+        self.share.report_through(self)
         self.routing = Routing(
             scoring="sigmoid", renormalize=bool(cfg.norm_topk_prob),
             scale=float(cfg.routed_scaling_factor), select_bias=True,
-            held_start=self.ep_rank * self.n_held)
-        self.experts_held = self.n_held * self.num_layers
-        # A share reports how many of each token's choices it holds
-        # (worker/model_runner.py `_pack`); the whole model has nothing
-        # to report.
-        self.moe_choices_per_token = (self.top_k * self.num_layers
-                                      if self.ep_size > 1 else 0)
+            held_start=self.share.held_start)
         self.attn = PagedAttention(self.num_heads, self.head_dim,
                                    self.head_dim**-0.5, self.num_kv_heads)
 
@@ -128,22 +113,14 @@ class SolarOpen2ForCausalLM:
         h = params["embed_tokens"][input_ids]
         residual = None
         new_caches = []
-        held = None
-        visited = jnp.int32(0)
+        counts = ExpertCounts(self.share)
         for i in range(self.num_layers):
-            h, residual, cache, held_i, visited_i = self._layer(
+            h, residual, cache = self._layer(
                 params["layers"][i], self.layer_kinds[i], h, residual,
-                kv_caches[i], attn_metadata, positions)
+                kv_caches[i], attn_metadata, positions, counts)
             new_caches.append(cache)
-            visited += visited_i
-            if held_i is not None:
-                held = held_i if held is None else held + held_i
         h, _ = fused_add_rms_norm(h, residual, params["norm"], self.eps)
-        counters = {"expert_visits": jnp.stack(
-            [visited, jnp.int32(self.experts_held)])}
-        if self.moe_choices_per_token:
-            counters["held"] = held
-        return h, new_caches, counters
+        return h, new_caches, counts.counters()
 
     def _attention(self, lp, h, kv_cache, attn_metadata):
         b, l, _ = h.shape
@@ -159,14 +136,9 @@ class SolarOpen2ForCausalLM:
         return out @ lp["o"], kv_cache
 
     def _layer(self, lp, kind, h, residual, cache, attn_metadata,
-               positions):
-        b, l, e = h.shape
-        if residual is None:
-            residual = h
-            h = rms_norm(h, lp["input_norm"], self.eps)
-        else:
-            h, residual = fused_add_rms_norm(h, residual, lp["input_norm"],
-                                             self.eps)
+               positions, counts):
+        h, residual = fused_add_rms_norm(h, residual, lp["input_norm"],
+                                         self.eps)
         if kind == "state":
             with jax.named_scope("kda_mixer"):
                 h, cache = kda_mixer(h, lp, cache, attn_metadata, positions,
@@ -176,24 +148,10 @@ class SolarOpen2ForCausalLM:
             h, cache = self._attention(lp, h, cache, attn_metadata)
         h, residual = fused_add_rms_norm(h, residual, lp["post_norm"],
                                          self.eps)
-        flat = h.reshape(b * l, e)
-        with jax.named_scope("moe_router"):
-            routed = _route(flat, lp["gate_router"], self.top_k,
-                            routing=self.routing,
-                            select_bias=lp["expert_bias"])
-        out, visited = moe_ffn(
-            flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
-            self.top_k, routing=self.routing, routed=routed,
-            mesh=attn_metadata.mesh, count_visited=True)
-        held = None
-        if self.moe_choices_per_token:
-            held = held_assignments(routed[1], self.n_held,
-                                    self.routing.held_start).reshape(b, l)
-        if self.n_shared:
-            with jax.named_scope("moe_shared"):
-                out = out + (jax.nn.silu(flat @ lp["shared_gate"]) *
-                             (flat @ lp["shared_up"])) @ lp["shared_down"]
-        return out.reshape(b, l, e), residual, cache, held, visited
+        h, layer_counts = routed_experts(h, lp, self.share, self.routing,
+                                         mesh=attn_metadata.mesh)
+        counts.add(layer_counts)
+        return h, residual, cache
 
     def compute_logits(self, params: Params, hidden, lora=None):
         # Logits leave the matmul in float32 (models/lfm2_moe.py says why).
@@ -235,15 +193,9 @@ class SolarOpen2ForCausalLM:
                     "o": (self.num_heads * d, e)})
                 if self.gqa_gate:
                     layer["gate"] = (e, self.num_heads * d)
-            mi, n = self.moe_inter, self.n_held
-            layer.update({"gate_router": (e, self.n_router),
-                          "expert_bias": (self.n_router, ),
-                          "w1": (n, mi, e), "w2": (n, mi, e),
-                          "w3": (n, mi, e)})
-            if self.n_shared:
-                si = mi * self.n_shared
-                layer.update({"shared_gate": (e, si), "shared_up": (e, si),
-                              "shared_down": (si, e)})
+            layer.update(self.share.param_shapes(
+                e, self.moe_inter, select_bias=True,
+                shared_inter=self.moe_inter * self.n_shared))
             layers.append(layer)
         return {"embed_tokens": (cfg.vocab_size, e), "norm": (e, ),
                 "lm_head": (e, cfg.vocab_size), "layers": layers}
@@ -330,10 +282,8 @@ class SolarOpen2ForCausalLM:
         `mlp.{gate, gate.e_score_correction_bias, experts.J.{gate_proj,
         up_proj, down_proj}, shared_experts}`. A share loads its own
         experts' slices and its slice of the vocabulary."""
-        raw: Dict[str, np.ndarray] = {}
-        for name, arr in hf_model_weights_iterator(model_name_or_path,
-                                                   load_format, revision):
-            raw[name] = arr
+        raw: Dict[str, np.ndarray] = dict(hf_model_weights_iterator(
+            model_name_or_path, load_format, revision))
 
         def T(key):       # [out, in] -> [in, out]
             return cast_array(raw[key].T, self.dtype)
@@ -348,7 +298,7 @@ class SolarOpen2ForCausalLM:
             "lm_head": T("lm_head.weight")[:, :vocab],
             "layers": [],
         }
-        first = self.routing.held_start
+        held = self.share.held
         for i in range(self.num_layers):
             p = f"model.layers.{i}."
             a = p + "self_attn."
@@ -378,23 +328,16 @@ class SolarOpen2ForCausalLM:
                 if self.gqa_gate:
                     layer["gate"] = T(a + "g_proj.weight")
             m = p + "mlp."
-            held = range(first, first + self.n_held)
             layer["gate_router"] = cast_array(raw[m + "gate.weight"].T,
                                               "float32")
             layer["expert_bias"] = cast_array(
                 raw[m + "gate.e_score_correction_bias"], "float32")
-            # Stacks are [N, I, D] (layers/moe.py).
-            layer["w1"] = np.stack(
-                [V(f"{m}experts.{j}.gate_proj.weight") for j in held])
-            layer["w2"] = np.stack(
-                [T(f"{m}experts.{j}.down_proj.weight") for j in held])
-            layer["w3"] = np.stack(
-                [V(f"{m}experts.{j}.up_proj.weight") for j in held])
+            layer.update(stack_experts(
+                raw, m + "experts.", held,
+                ("gate_proj", "down_proj", "up_proj"), self.dtype))
             if self.n_shared:
-                layer["shared_gate"] = T(
-                    m + "shared_experts.gate_proj.weight")
-                layer["shared_up"] = T(m + "shared_experts.up_proj.weight")
-                layer["shared_down"] = T(
-                    m + "shared_experts.down_proj.weight")
+                for x in ("gate", "up", "down"):
+                    layer["shared_" + x] = T(
+                        f"{m}shared_experts.{x}_proj.weight")
             params["layers"].append(layer)
         return params

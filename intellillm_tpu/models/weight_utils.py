@@ -95,6 +95,20 @@ def cast_array(arr: np.ndarray, dtype_str: str) -> "np.ndarray":
     return arr.astype(target)
 
 
+def stack_experts(raw, prefix: str, held, names, dtype: str) -> dict:
+    """The stacks "w1", "w2", "w3" [N, I, D] (layers/moe.py) of the experts
+    `held` (`ExpertShare.held`) from a checkpoint's per-expert [out, in]
+    matrices `{prefix}{j}.{name}.weight`, `names` their (gate, down, up):
+    gate and up as they are, down transposed."""
+    def stack(name, transposed=False):
+        return np.stack([cast_array(w.T if transposed else w, dtype)
+                         for w in (raw[f"{prefix}{j}.{name}.weight"]
+                                   for j in held)])
+
+    gate, down, up = names
+    return {"w1": stack(gate), "w2": stack(down, True), "w3": stack(up)}
+
+
 def load_linear(raw, prefix: str, dtype: str, quantization=None,
                 fp_ok: bool = False):
     """Resolve one linear layer's weight from a checkpoint dict, handling
