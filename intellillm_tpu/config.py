@@ -150,17 +150,37 @@ class ModelConfig:
             return None
         return -(-(rank + rope) // 128) * 128
 
+    def get_packed_latent_layers(self) -> Optional[int]:
+        """A latent-attention model whose config says
+        `latent_layers_in_one_pool` keeps ALL its layers' vectors in one
+        pool `[num_blocks, layers, block_size, width]`: the layers lie
+        where a per-head pool has its heads, which a latent cache has
+        none of. The cache engine, the block manager and the runner then
+        see one "kv" layer whose token stores `layers` rows (swap, copy,
+        the decode stage and its commit move a block's every layer at
+        once), and the model can walk its layers in a `lax.scan`: layer
+        `l` reads the pool viewed as `[num_blocks * layers, 1, ...]`
+        through block numbers `b * layers + l`
+        (`layers/mla_attention.py`). Returns the layer count, None for
+        every other model."""
+        if (self.get_latent_cache_width() and getattr(
+                self.hf_config, "latent_layers_in_one_pool", False)):
+            return self.get_num_layers()
+        return None
+
     def get_cache_row_shape(self) -> Tuple[int, int, int]:
         """(pools, heads, width) of what one token stores in one layer's
         cache: a layer's cache is `pools` arrays of
         `[num_blocks, heads, block_size, width]`. Per-head K/V: (2, kv
-        heads, head size). Latent attention: (1, 1, stored latent width).
+        heads, head size). Latent attention: (1, 1, stored latent width),
+        or (1, layers, width) where the layers share one pool
+        (`get_packed_latent_layers`).
         The cache engine, the worker's memory profile and the runner size
         everything from this; `get_head_size` / `get_total_num_kv_heads`
         keep describing the attention itself."""
         latent = self.get_latent_cache_width()
         if latent:
-            return 1, 1, latent
+            return 1, self.get_packed_latent_layers() or 1, latent
         # A config may state a stored head width wider than the head (the
         # head zero-padded to whole 128-lane tiles, which is what the
         # device pads a narrower pool row to anyway and what the Pallas
@@ -189,8 +209,11 @@ class ModelConfig:
         `sliding_window_size` has "kv_window" where the layout says 1,
         unless it says 1 everywhere (then the window is the whole
         model's, `get_sliding_window`, and there is one group); every
-        other config is "kv" throughout."""
+        other config is "kv" throughout. A latent model whose layers
+        share one pool (`get_packed_latent_layers`) is ONE "kv" entry."""
         n = self.get_num_layers()
+        if self.get_packed_latent_layers():
+            return ["kv"]           # one pool for every layer
         layout = self._window_layout()
         if layout is not None and not all(layout):
             return ["kv_window" if w else "kv" for w in layout]

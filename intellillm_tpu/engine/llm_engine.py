@@ -141,6 +141,14 @@ class LLMEngine:
                 scheduler_config.max_num_batched_tokens,
                 scheduler_config.num_decode_steps)
         if speculative_config is not None:
+            # A family that cannot be speculated for says why itself.
+            from intellillm_tpu.models import get_model_class
+            architectures = getattr(model_config.hf_config,
+                                    "architectures", None) or []
+            refusal = getattr(get_model_class(architectures),
+                              "speculation_refusal", None)
+            if refusal:
+                raise NotImplementedError(refusal)
             # One engine decode "step" = K draft proposals + the bonus
             # token; the scheduler must reserve K+1 KV slots per pass.
             scheduler_config.num_decode_steps = (

@@ -215,7 +215,13 @@ class ExpertShare:
 
     @classmethod
     def from_config(cls, cfg, n_held: int, top_k: int, n_moe_layers: int):
-        """The share a config's `ep_size` / `ep_rank` name."""
+        """The share a config's `ep_size` / `ep_rank` name, for every
+        family that has one (their config shims and model files point
+        here): `ep_size` chips share each layer's experts (default 1: the
+        whole model), this chip is rank `ep_rank` (default 0) and holds the
+        `n_held` experts `[ep_rank * n_held, (ep_rank + 1) * n_held)`; the
+        family's own expert-count key then counts the experts held HERE
+        and the router keeps its published width `n_held * ep_size`."""
         return cls(n_held, top_k, n_moe_layers,
                    ep_size=int(getattr(cfg, "ep_size", 1) or 1),
                    ep_rank=int(getattr(cfg, "ep_rank", 0) or 0))
@@ -312,21 +318,31 @@ def route(h: jnp.ndarray, lp, share: ExpertShare, routing: Routing):
 
 
 def routed_experts(h: jnp.ndarray, lp, share: ExpertShare, routing: Routing,
-                   mesh=None, routed=None, activation: str = "silu"):
+                   mesh=None, routed=None, activation: str = "silu",
+                   stacks=None):
     """A layer's routed-experts block over `h` [B, L, D] (the post-mixer
     norm's output): router, held experts, and the shared expert where `lp`
     has one ("shared_gate"), all with the gate `activation`. Returns the
     block's output [B, L, D] and the layer's counts for
     `ExpertCounts.add`: (held experts read, and from a share how many of
-    each token's choices are held here [B, L], else None)."""
+    each token's choices are held here [B, L], else None). `stacks`: for
+    a model that walks its layers in a `lax.scan`, (`w1`, `w2`, `w3`, the
+    layer's place among the routed layers): the stacks of EVERY routed
+    layer, `[layers * n_held, I, D]`, in place of `lp`'s own (`moe_ffn`
+    `held_at`)."""
     b, l, e = h.shape
     flat = h.reshape(b * l, e)
     if routed is None:
         routed = route(flat, lp, share, routing)
+    if stacks is None:
+        w1, w2, w3, held_at = lp["w1"], lp["w2"], lp["w3"], None
+    else:
+        w1, w2, w3, place = stacks
+        held_at = (place * share.n_held, share.n_held)
     out, visited = moe_ffn(
-        flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"], share.top_k,
+        flat, lp["gate_router"], w1, w2, w3, share.top_k,
         routing=routing, routed=routed, mesh=mesh, activation=activation,
-        count_visited=True)
+        count_visited=True, held_at=held_at)
     held = None
     if share.moe_choices_per_token:
         held = held_assignments(routed[1], share.n_held,
@@ -431,6 +447,7 @@ def moe_ffn_grouped(
     mesh=None,
     activation: str = "silu",
     count_visited: bool = False,
+    held_at=None,
 ):
     """`tile`: rows a tile of the grouped kernels' walk (the sorted rows
     are padded to a whole number of them, once, not an expert). `mesh`:
@@ -438,9 +455,12 @@ def moe_ffn_grouped(
     on its share of the stacks' inner dimension (`_over_inner_shards`).
     `count_visited`: also return how many held experts have a row (i32
     scalar): the experts whose weights the kernels read, since an expert
-    without a row gets no visit (`visit_metadata`)."""
+    without a row gets no visit (`visit_metadata`). `held_at`: as
+    `moe_ffn`'s: the kernels get the stacks whole and this layer's group
+    sizes at its experts' places among zeros, so no expert of another
+    layer gets a visit and no slice of a stack is copied."""
     t, d = x.shape
-    n = w1.shape[0]
+    n = w1.shape[0] if held_at is None else held_at[1]
     tk = t * top_k
 
     if routed is None:
@@ -476,9 +496,13 @@ def moe_ffn_grouped(
         rows = jnp.where(here[:, None], out[place] * flat_w[:, None], 0.0)
         return rows.reshape(t, top_k, d).sum(1)
 
+    sizes = counts[:n]
+    if held_at is not None:
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((w1.shape[0], ), sizes.dtype), sizes, (held_at[0], ))
     with jax.named_scope("moe_experts"):
         y = _over_inner_shards(experts, mesh, w1.shape[1])(
-            xs, w1, w2, w3, counts[:n], place, flat_w, here)
+            xs, w1, w2, w3, sizes, place, flat_w, here)
     y = y.astype(x.dtype)
     if count_visited:
         return y, (counts[:n] > 0).sum().astype(jnp.int32)
@@ -546,11 +570,16 @@ def moe_ffn(
     mesh=None,
     activation: str = "silu",
     count_visited: bool = False,
+    held_at=None,
 ):
     """The expert FFN in the form `grouped_rows` picks for these shapes.
     `count_visited`: also return how many held experts the step read (i32
     scalar): those with a row in the grouped form, all of them in the
-    dense one (intellillm_moe_expert_visits_total)."""
+    dense one (intellillm_moe_expert_visits_total). `held_at`: (index of
+    this layer's first expert in the stacks (may be traced), experts a
+    layer) where the stacks are every routed layer's, `[layers * n, I,
+    D]`, of a model that walks its layers in a `lax.scan`; None where
+    they are this layer's own."""
     # All three stacks are [N, I, D] (module docstring): w1/w3 contract on
     # their last axis, so no loop around this call makes XLA re-lay them.
     # QuantMixtral (reference mixtral_quant.py): expert stacks arrive as
@@ -571,7 +600,12 @@ def moe_ffn(
         return moe_ffn_grouped(x, gate_w, w1, w2, w3, top_k,
                                routing=routing, routed=routed, mesh=mesh,
                                activation=activation,
-                               count_visited=count_visited)
+                               count_visited=count_visited,
+                               held_at=held_at)
+    if held_at is not None:
+        # A slice of the stacks that XLA reads inside the dots' fusions.
+        w1, w2, w3 = (jax.lax.dynamic_slice_in_dim(w, *held_at)
+                      for w in (w1, w2, w3))
     y = moe_ffn_dense(x, gate_w, w1, w2, w3, top_k, routing=routing,
                       routed=routed, activation=activation)
     return (y, jnp.int32(w1.shape[0])) if count_visited else y

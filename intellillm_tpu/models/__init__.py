@@ -33,6 +33,7 @@ from intellillm_tpu.models.qwen2 import Qwen2ForCausalLM
 from intellillm_tpu.models.smallthinker import SmallThinkerForCausalLM
 from intellillm_tpu.models.solar_open2 import SolarOpen2ForCausalLM
 from intellillm_tpu.models.stablelm import StableLMForCausalLM
+from intellillm_tpu.models.xing4 import Xing4ForCausalLM
 
 _MODEL_REGISTRY: Dict[str, Type] = {
     "LlamaForCausalLM": LlamaForCausalLM,
@@ -77,6 +78,8 @@ _MODEL_REGISTRY: Dict[str, Type] = {
     "SolarOpen2ForCausalLM": SolarOpen2ForCausalLM,
     # Mamba-2 state-space scan (matrix state) + NoPE GQA, EP share
     "GraniteMoeHybridForCausalLM": GraniteMoeHybridForCausalLM,
+    # latent attention under a four-stream hyper-connection residual, EP share
+    "Xing4ForCausalLM": Xing4ForCausalLM,
 }
 
 

@@ -10,11 +10,13 @@ as Llama):
   ratio `yarn_mscale(f, mscale) / yarn_mscale(f, mscale_all_dim)`); the
   cache holds `[c_kv | k_rope]`. Scores are scaled by
   `(d_nope + d_rope)^-1/2 * m^2`, `m = yarn_mscale(f, mscale_all_dim)`.
-  The program runs the absorbed form (`layers/mla_attention.py`): `W_kvb` is
-  stored split per head as `w_uk [H, d_nope, r_kv]` and `w_uv [H, r_kv,
-  d_v]` (`W_kvb,h = [W_UK,h | W_UV,h]`), `q~_h = q_nope,h W_UK,h^T` goes to
-  the kernel with `q_rope`, and the attended latent comes back through
-  `W_UV,h` and `W_o`.
+  The program runs the absorbed form, and the whole sublayer (projections,
+  norms, RoPE split, absorption, `W_o`) lives in `layers/mla_attention.py`
+  `LatentAttentionBlock`, written once for the families that run this
+  block: `W_kvb` is stored split per head as `w_uk [H, d_nope, r_kv]` and
+  `w_uv [H, r_kv, d_v]` (`W_kvb,h = [W_UK,h | W_UV,h]`), `q~_h = q_nope,h
+  W_UK,h^T` goes to the kernel with `q_rope`, and the attended latent comes
+  back through `W_UV,h` and `W_o`.
 - FFN. The first `first_k_dense_replace` layers: SwiGLU of width
   `intermediate_size`. The others: `sigmoid` router over ALL experts,
   `n_group` groups of which the best `topk_group` are kept, top-k inside
@@ -40,13 +42,11 @@ import numpy as np
 
 from intellillm_tpu.config import ModelConfig
 from intellillm_tpu.layers.activation import get_act_fn
-from intellillm_tpu.layers.mla_attention import LatentAttention
+from intellillm_tpu.layers.mla_attention import LatentAttentionBlock
 from intellillm_tpu.layers.moe import (DENSE_LAYER, ExpertCounts, ExpertShare,
                                        Routing, random_gate_up_stack,
                                        routed_experts)
-from intellillm_tpu.layers.normalization import fused_add_rms_norm, rms_norm
-from intellillm_tpu.layers.rotary_embedding import (_yarn_get_mscale,
-                                                    get_rope)
+from intellillm_tpu.layers.normalization import fused_add_rms_norm
 from intellillm_tpu.models.weight_utils import (cast_array,
                                                 hf_model_weights_iterator,
                                                 stack_experts)
@@ -68,15 +68,9 @@ class AXK1ForCausalLM:
         self.model_config = model_config
         self.dtype = model_config.dtype
         self.num_layers = cfg.num_hidden_layers
-        self.num_heads = cfg.num_attention_heads
         self.hidden_size = cfg.hidden_size
         self.rms_eps = cfg.rms_norm_eps
         self.act = get_act_fn(cfg.hidden_act)
-        self.q_rank = cfg.q_lora_rank
-        self.kv_rank = cfg.kv_lora_rank
-        self.d_nope = cfg.qk_nope_head_dim
-        self.d_rope = cfg.qk_rope_head_dim
-        self.d_v = cfg.v_head_dim
         self.first_dense = cfg.first_k_dense_replace
         self.moe_freq = cfg.moe_layer_freq
         self.moe_inter = cfg.moe_intermediate_size
@@ -98,20 +92,7 @@ class AXK1ForCausalLM:
             scale=float(cfg.routed_scaling_factor),
             held_start=self.share.held_start)
 
-        scaling = getattr(cfg, "rope_scaling", None)
-        # YaRN's table has factor x original positions (131,072 rows, 34 MB
-        # for cos and sin): keep the rows a position can reach.
-        self.rope = get_rope(
-            self.d_rope, self.d_rope, cfg.max_position_embeddings,
-            cfg.rope_theta, is_neox_style=False,
-            rope_scaling=scaling).trimmed_to(model_config.max_model_len)
-        scale = (self.d_nope + self.d_rope)**-0.5
-        if scaling and scaling.get("type", scaling.get("rope_type")) == "yarn":
-            m = _yarn_get_mscale(scaling["factor"],
-                                 scaling.get("mscale_all_dim", 0.0))
-            scale *= m * m
-        self.attn = LatentAttention(self.num_heads, self.kv_rank,
-                                    self.d_rope, scale)
+        self.mla = LatentAttentionBlock(cfg, model_config.max_model_len)
 
     def _is_moe_layer(self, i: int) -> bool:
         return i >= self.first_dense and i % self.moe_freq == 0
@@ -132,34 +113,11 @@ class AXK1ForCausalLM:
         h, _ = fused_add_rms_norm(h, residual, params["norm"], self.rms_eps)
         return h, new_caches, counts.counters()
 
-    def _attention(self, lp, h, kv_cache, attn_metadata, positions):
-        b, l, _ = h.shape
-        hh = self.num_heads
-        with jax.named_scope("mla_q_proj"):
-            c_q = rms_norm(h @ lp["q_a"], lp["q_a_norm"], self.rms_eps)
-            q = (c_q @ lp["q_b"]).reshape(b, l, hh, self.d_nope + self.d_rope)
-            q_nope, q_rope = q[..., :self.d_nope], q[..., self.d_nope:]
-        with jax.named_scope("mla_kv_proj"):
-            ckv = h @ lp["kv_a"]
-            c_kv = rms_norm(ckv[..., :self.kv_rank], lp["kv_a_norm"],
-                            self.rms_eps)
-            k_rope = ckv[..., self.kv_rank:][:, :, None, :]
-        q_rope, k_rope = self.rope(positions, q_rope, k_rope)
-        with jax.named_scope("mla_absorb_q"):
-            q_abs = jnp.einsum("blhn,hnr->blhr", q_nope, lp["w_uk"])
-        q_lat = jnp.concatenate([q_abs, q_rope], axis=-1)
-        kv_new = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)
-        o_lat, kv_cache = self.attn(q_lat, kv_new, kv_cache, attn_metadata)
-        with jax.named_scope("mla_absorb_o"):
-            o = jnp.einsum("blhr,hrv->blhv", o_lat, lp["w_uv"])
-        return o.reshape(b, l, hh * self.d_v) @ lp["o"], kv_cache
-
     def _layer(self, lp, h, residual, kv_cache, attn_metadata, positions,
                counts):
         h, residual = fused_add_rms_norm(h, residual, lp["input_norm"],
                                          self.rms_eps)
-        h, kv_cache = self._attention(lp, h, kv_cache, attn_metadata,
-                                      positions)
+        h, kv_cache = self.mla(lp, h, kv_cache, attn_metadata, positions)
         h, residual = fused_add_rms_norm(h, residual, lp["post_attn_norm"],
                                          self.rms_eps)
         if "w1" not in lp:
@@ -188,19 +146,11 @@ class AXK1ForCausalLM:
     def _param_shapes(self):
         """The param tree's structure (leaves: shapes)."""
         cfg = self.config
-        e, hh = self.hidden_size, self.num_heads
+        e = self.hidden_size
         layers = []
         for i in range(self.num_layers):
-            layer = {
-                "input_norm": (e, ), "post_attn_norm": (e, ),
-                "q_a": (e, self.q_rank), "q_a_norm": (self.q_rank, ),
-                "q_b": (self.q_rank, hh * (self.d_nope + self.d_rope)),
-                "kv_a": (e, self.kv_rank + self.d_rope),
-                "kv_a_norm": (self.kv_rank, ),
-                "w_uk": (hh, self.d_nope, self.kv_rank),
-                "w_uv": (hh, self.kv_rank, self.d_v),
-                "o": (hh * self.d_v, e),
-            }
+            layer = {"input_norm": (e, ), "post_attn_norm": (e, ),
+                     **self.mla.param_shapes(e)}
             if self._is_moe_layer(i):
                 layer.update(self.share.param_shapes(
                     e, self.moe_inter,
@@ -275,7 +225,6 @@ class AXK1ForCausalLM:
         def V(key):
             return cast_array(raw[key], self.dtype)
 
-        hh = self.num_heads
         vocab = self.config.vocab_size
         params: Params = {
             "embed_tokens": V("model.embed_tokens.weight")[:vocab],
@@ -286,21 +235,10 @@ class AXK1ForCausalLM:
         held = self.share.held
         for i in range(self.num_layers):
             p = f"model.layers.{i}."
-            a = p + "self_attn."
-            kv_b = V(a + "kv_b_proj.weight").reshape(
-                hh, self.d_nope + self.d_v, self.kv_rank)
             layer = {
                 "input_norm": V(p + "input_layernorm.weight"),
                 "post_attn_norm": V(p + "post_attention_layernorm.weight"),
-                "q_a": T(a + "q_a_proj.weight"),
-                "q_a_norm": V(a + "q_a_layernorm.weight"),
-                "q_b": T(a + "q_b_proj.weight"),
-                "kv_a": T(a + "kv_a_proj_with_mqa.weight"),
-                "kv_a_norm": V(a + "kv_a_layernorm.weight"),
-                "w_uk": np.ascontiguousarray(kv_b[:, :self.d_nope, :]),
-                "w_uv": np.ascontiguousarray(
-                    kv_b[:, self.d_nope:, :].swapaxes(1, 2)),
-                "o": T(a + "o_proj.weight"),
+                **self.mla.load(p + "self_attn.", T, V),
             }
             m = p + "mlp."
             if self._is_moe_layer(i):

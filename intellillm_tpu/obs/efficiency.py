@@ -185,6 +185,24 @@ class _EfficiencyMetrics:
             "intellillm_dispatch_row_steps_total: the state traffic of "
             "one row-substep, what a roofline of the step form divides "
             "by.", ["program"])
+        self.counter_dispatch_stream_mix_bytes = Counter(
+            "intellillm_dispatch_stream_mix_bytes_total",
+            "Bytes of residual streams the dispatched programs of a model "
+            "with a multi-stream residual (hyper-connections) moved "
+            "through its mixes: live rows x substeps x sublayers x the "
+            "streams' bytes a row x 3 (a pre-mix reads the streams once, "
+            "a post-mix reads them and writes them). Over "
+            "intellillm_dispatch_row_steps_total: the mixes' traffic of "
+            "one row-substep. Stays 0 for every other model.", ["program"])
+        self.gauge_mhc_col_error_max = Gauge(
+            "intellillm_mhc_col_error_max",
+            "The largest |column sum - 1| of any stream-mixing matrix of "
+            "a live row in the finished steps since boot (warm-up "
+            "excluded): how far the Sinkhorn sweeps, the clamp and the "
+            "epsilon leave the matrices from doubly stochastic (rows are "
+            "normalised last and sum to 1 to rounding). It rides the "
+            "step's packed device-to-host fetch. 0 for a model without a "
+            "multi-stream residual.")
         self.counter_window_blocks = Counter(
             "intellillm_window_blocks_total",
             "What the block manager did with blocks of a sliding-window "
@@ -280,6 +298,8 @@ class _EfficiencyMetrics:
             for form in LINEAR_ATTN_FORMS:
                 self.counter_linear_attn_rows.labels(program, form)
             self.counter_dispatch_state_bytes.labels(program)
+            self.counter_dispatch_stream_mix_bytes.labels(program)
+        self.gauge_mhc_col_error_max.set(0)
         for tile in RAGGED_TILES:
             self.counter_mixed_tile_rows.labels(tile)
         for event in WINDOW_BLOCK_EVENTS:
@@ -405,6 +425,10 @@ class EfficiencyTracker:
             for program in PROGRAMS}
         self._mixed_tile_rows: Dict[str, int] = dict.fromkeys(
             RAGGED_TILES, 0)
+        # A multi-stream residual's bytes (record_dispatch) and the
+        # largest column error of its matrices (record_mhc_col_error).
+        self._mhc: Dict[str, float] = {"stream_mix_bytes": 0,
+                                       "col_error_max": 0.0}
         self._pipeline_drains: Dict[str, int] = dict.fromkeys(
             DRAIN_CAUSES, 0)
         # (phase, axis) -> [sum of fill ratios, observations]
@@ -518,7 +542,8 @@ class EfficiencyTracker:
                         attended_tokens: Optional[Dict[str, int]] = None,
                         tile_rows: Optional[Dict[str, int]] = None,
                         linear_form: Optional[str] = None,
-                        state_bytes: int = 0) -> None:
+                        state_bytes: int = 0,
+                        stream_mix_bytes: int = 0) -> None:
         """Account one device dispatch. Extents are pre-padding vs
         post-padding; token counts are what the device actually
         computes (prefill: rows x padded len; decode: rows x substeps).
@@ -538,7 +563,9 @@ class EfficiencyTracker:
         LINEAR_ATTN_FORMS; None for a model without a matrix state
         updated in its pool): the form of the recurrence the
         row-substeps went through, and `state_bytes` the matrix state
-        they read and wrote."""
+        they read and wrote. `stream_mix_bytes` (0 for a model without a
+        multi-stream residual): the streams' bytes those row-substeps
+        moved through its mixes."""
         if not self.enabled:
             return
         real_tokens = int(real_tokens)
@@ -581,6 +608,7 @@ class EfficiencyTracker:
                 counts["walked_tokens"] += int(walked_tokens)
                 for tile, n in (tile_rows or {}).items():
                     self._mixed_tile_rows[tile] += int(n)
+                self._mhc["stream_mix_bytes"] += int(stream_mix_bytes)
         if self._metrics is not None:
             m = self._metrics
             if program is not None:
@@ -600,6 +628,9 @@ class EfficiencyTracker:
                         program, linear_form).inc(row_steps)
                     m.counter_dispatch_state_bytes.labels(program).inc(
                         state_bytes)
+                if stream_mix_bytes:
+                    m.counter_dispatch_stream_mix_bytes.labels(
+                        program).inc(stream_mix_bytes)
             m.counter_tokens.labels("real", phase).inc(real_tokens)
             m.counter_tokens.labels("pad", phase).inc(pad_tokens)
             for axis, ratio in fills:
@@ -709,6 +740,22 @@ class EfficiencyTracker:
                 self._metrics.counter_moe_expert_visits.labels(
                     program, key).inc(n)
 
+    def record_mhc_col_error(self, worst: float) -> None:
+        """A finished step's largest |column sum - 1| over the
+        stream-mixing matrices of its live rows (a multi-stream residual;
+        the number rides the step's packed fetch: worker/model_runner.py
+        `_pack`). A NaN is kept: it is what went wrong."""
+        if not self.enabled:
+            return
+        with self._lock:
+            if self._warmup_depth > 0:
+                return
+            if not worst <= self._mhc["col_error_max"]:
+                self._mhc["col_error_max"] = worst
+            worst = self._mhc["col_error_max"]
+        if self._metrics is not None:
+            self._metrics.gauge_mhc_col_error_max.set(worst)
+
     def record_step(self, step_time: float) -> Optional[float]:
         """Engine step boundary: fold the real tokens dispatched since
         the previous boundary with this step's wall time into the
@@ -814,6 +861,7 @@ class EfficiencyTracker:
                     program: dict(counts) for program, counts
                     in self._moe_expert_visits.items()},
                 "mixed_tile_rows": dict(self._mixed_tile_rows),
+                "mhc": dict(self._mhc),
                 "pipeline_drains": dict(self._pipeline_drains),
                 "co_batched_steps": self._co_batched_steps,
                 "warmup_excluded_dispatches": self._warmup_excluded,

@@ -124,6 +124,41 @@ def commit_staged_chunk(
     return tuple(merge(stage, pool) for stage, pool in zip(stages, pools))
 
 
+def commit_staged_chunk_by_layer(
+    stages: Tuple[jnp.ndarray, ...],   # ([B, C, layers, D], )
+    pools: Tuple[jnp.ndarray, ...],    # ([NB, layers, BS, D], )
+    start_pos: jnp.ndarray,
+    n_valid: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    group: int = 8,
+) -> Tuple[jnp.ndarray, ...]:
+    """`commit_staged_chunk` for a latent pool that holds every layer of
+    the model where a per-head pool has its heads
+    (`ModelConfig.get_packed_latent_layers`). A "page" of all 40 layers
+    is 819 KB, and XLA's gather of such rows cuts the WHOLE pool in two
+    along its lanes first (2.2 + 1.5 GB of temporaries, compiled for a
+    described v5e); so the layers go as rows instead: the pool viewed as
+    `[NB * layers, 1, BS, D]` (the same bytes), layer `l`'s block `b` at
+    `b * layers + l`, `group` layers a call (each call's temporaries are
+    its own pages: 128 rows x 8 layers x 2 pages x 20 KB x (2 + 4 + 2 B
+    a value) = 170 MB, not 840)."""
+    (stage, ), (pool, ) = stages, pools
+    b, c, layers, d = stage.shape
+    nb, _, bs, _ = pool.shape
+    flat = pool.reshape(nb * layers, 1, bs, d)
+    for first in range(0, layers, group):
+        g = min(group, layers - first)
+        of = jnp.arange(first, first + g, dtype=jnp.int32)
+        rows = stage[:, :, first:first + g].transpose(2, 0, 1, 3).reshape(
+            g * b, c, 1, d)
+        tables = (block_tables[None] * layers + of[:, None, None]).reshape(
+            g * b, -1)
+        (flat, ) = commit_staged_chunk(
+            (rows, ), (flat, ), jnp.tile(start_pos, g), jnp.tile(n_valid, g),
+            tables)
+    return (flat.reshape(pool.shape), )
+
+
 def gather_kv_for_attention(
     cache: jnp.ndarray,          # [NB, H, BS, D]
     block_tables: jnp.ndarray,   # [B, W] i32

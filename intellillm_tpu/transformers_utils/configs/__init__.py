@@ -21,6 +21,7 @@ from intellillm_tpu.transformers_utils.configs.smallthinker import (
     SmallThinkerConfig)
 from intellillm_tpu.transformers_utils.configs.solar_open2 import (
     SolarOpen2Config)
+from intellillm_tpu.transformers_utils.configs.xing4 import Xing4Config
 from intellillm_tpu.transformers_utils.configs.yi import YiConfig
 
 _CONFIG_REGISTRY = {
@@ -36,6 +37,7 @@ _CONFIG_REGISTRY = {
     "qwen": QWenConfig,
     "smallthinker": SmallThinkerConfig,
     "solar_open2": SolarOpen2Config,
+    "xing4_0": Xing4Config,
     "Yi": YiConfig,
     "yi": YiConfig,
 }
@@ -44,6 +46,6 @@ __all__ = [
     "AquilaConfig", "AXK1Config", "BaichuanConfig", "ChatGLMConfig", "DeciLMConfig",
     "DeepseekConfig", "GraniteMoeHybridConfig", "InternLMConfig",
     "Lfm2MoeConfig", "QWenConfig",
-    "SmallThinkerConfig", "SolarOpen2Config", "YiConfig",
+    "SmallThinkerConfig", "SolarOpen2Config", "Xing4Config", "YiConfig",
     "_CONFIG_REGISTRY",
 ]

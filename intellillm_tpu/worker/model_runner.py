@@ -165,10 +165,15 @@ class InflightStep:
                 self.packed)
         sampled, sampled_lp, topk_ids, topk_lp = r._unpack(
             packed, self.t1, self.t2, self.logprob_k)
-        # The counter columns `_pack` appended, from the end: the expert
-        # visits of a model that counts them, before them the two of an
-        # expert-parallel share.
+        # The counter columns `_pack` appended, from the end: the mixing
+        # matrices' column error of a model with a multi-stream residual,
+        # the expert visits of a model that counts them, before them the
+        # two of an expert-parallel share.
         end = packed.shape[1]
+        if r._mhc_bytes_per_row:
+            end -= 1
+            r._efficiency.record_mhc_col_error(
+                float(packed[0, end:end + 1].view(np.float32)[0]))
         if getattr(r.model, "counts_expert_visits", False):
             end -= 2
             r._efficiency.record_moe_expert_visits(
@@ -300,6 +305,10 @@ class ModelRunner:
                 for (shape, dtype), in_place in zip(state_arrays,
                                                     self._state_in_place)
                 if in_place))
+        # A model with a multi-stream residual (layers/
+        # hyper_connections.py): the streams' bytes a row moves through
+        # all its sublayers; 0 for every other model.
+        self._mhc_bytes_per_row = getattr(model, "mhc_bytes_per_row", 0)
         from intellillm_tpu.layers.attention import model_uses_alibi
         self._uses_alibi = model_uses_alibi(model)
         self.vocab_size = model_config.get_vocab_size()
@@ -459,15 +468,22 @@ class ModelRunner:
         return {"run_starts": self._place_batch_array(
             _padded_run_starts(run_starts, padded_n))}
 
-    def _linear_attn_counts(self, staged: bool, row_steps: int):
-        """`record_dispatch`'s account of a matrix state's recurrence (a
-        delta rule, a state-space scan): nothing for a model none of
-        whose state arrays is updated in its pool
-        (`ModelConfig.state_array_in_place`)."""
-        if not self._matrix_state_bytes:
-            return {}
-        return {"linear_form": "step" if staged else "chunk",
-                "state_bytes": row_steps * self._matrix_state_bytes}
+    def _row_step_counts(self, staged: bool, row_steps: int):
+        """`record_dispatch`'s account of what `row_steps` live
+        row-substeps moved besides the cache: a matrix state's recurrence
+        (a delta rule, a state-space scan; nothing for a model none of
+        whose state arrays is updated in its pool:
+        `ModelConfig.state_array_in_place`) and a multi-stream residual's
+        mixes (nothing for a model without one)."""
+        counts = {}
+        if self._matrix_state_bytes:
+            counts.update(
+                linear_form="step" if staged else "chunk",
+                state_bytes=row_steps * self._matrix_state_bytes)
+        if self._mhc_bytes_per_row:
+            counts.update(
+                stream_mix_bytes=row_steps * self._mhc_bytes_per_row)
+        return counts
 
     def _record_moe_ffn_rows(self, rows: int, substeps: int = 1) -> None:
         """`rows` x `substeps` of a dispatched program went through the
@@ -521,7 +537,11 @@ class ModelRunner:
         intellillm_moe_assignments_total. Then "expert_visits" [2] (held
         experts whose weights were read, held experts, both x layers x
         substeps), in row 0 of two columns of their own, for
-        intellillm_moe_expert_visits_total.
+        intellillm_moe_expert_visits_total. Last "mhc_col_error" [B] f32
+        (a multi-stream residual: a row's largest |column sum - 1| over
+        its mixing matrices, the largest over the substeps): the live
+        rows' largest, bitcast into row 0 of one column, for
+        intellillm_mhc_col_error_max.
         """
         b = sampled.shape[0]
         parts = [
@@ -537,6 +557,10 @@ class ModelRunner:
         if "expert_visits" in counters:
             parts.append(jnp.zeros((b, 2), jnp.int32).at[0].set(
                 counters["expert_visits"]))
+        if "mhc_col_error" in counters:
+            worst = jnp.max(jnp.where(live, counters["mhc_col_error"], 0.0))
+            parts.append(jnp.zeros((b, 1), jnp.int32).at[0, 0].set(
+                jax.lax.bitcast_convert_type(worst, jnp.int32)))
         return jnp.concatenate(parts, axis=-1)
 
     @staticmethod
@@ -563,12 +587,15 @@ class ModelRunner:
         # A model may return counters of the call beside its result, each
         # summed over its layers: "held" ([B, L] i32, an expert-parallel
         # share: how many of a token's expert choices this chip holds; L
-        # is 1 in every program) and "expert_visits" ([2] i32: held
-        # experts whose weights a layer read, held experts).
+        # is 1 in every program), "expert_visits" ([2] i32: held
+        # experts whose weights a layer read, held experts) and
+        # "mhc_col_error" ([B, L] f32, a multi-stream residual: a token's
+        # largest |column sum - 1| over the call's mixing matrices).
         hidden, caches, *rest = out
         counters = dict(rest[0]) if rest else {}
-        if "held" in counters:
-            counters["held"] = counters["held"][:, 0]
+        for key in ("held", "mhc_col_error"):
+            if key in counters:
+                counters[key] = counters[key][:, 0]
         return hidden, caches, counters
 
     # --- jitted step functions -------------------------------------------
@@ -806,7 +833,11 @@ class ModelRunner:
         if num_steps % chunk:
             chunk_sizes.append(num_steps % chunk)
 
-        from intellillm_tpu.ops.kv_cache import commit_staged_chunk
+        from intellillm_tpu.ops.kv_cache import (
+            commit_staged_chunk, commit_staged_chunk_by_layer)
+        if self.model_config.get_packed_latent_layers():
+            # One latent pool for every layer: its layers go as rows.
+            commit_staged_chunk = commit_staged_chunk_by_layer
 
         def make_substep(pool_ctx, cur_fixed, chunk_base):
             def substep(carry, k):
@@ -910,7 +941,10 @@ class ModelRunner:
                             jnp.swapaxes(lp_k, 0, 1),
                             jnp.swapaxes(tk_ids_k, 0, 1),
                             jnp.swapaxes(tk_lp_k, 0, 1),
-                            counters=jax.tree.map(lambda a: a.sum(0), ys[4]),
+                            counters={
+                                key: (a.max(0) if key == "mhc_col_error"
+                                      else a.sum(0))
+                                for key, a in ys[4].items()},
                             live=base_ctx > 0, substeps=num_steps)
         return packed, new_caches
 
@@ -1395,7 +1429,7 @@ class ModelRunner:
                             + real_rows * num_steps * (num_steps - 1) // 2),
             walked_tokens=walked, attended_tokens=attended,
             tile_rows=tile_rows,
-            **self._linear_attn_counts(num_steps > 1,
+            **self._row_step_counts(num_steps > 1,
                                        real_rows * num_steps))
         self._record_moe_ffn_rows(padded_n, num_steps)
 
@@ -1665,7 +1699,7 @@ class ModelRunner:
                         attended_tokens=self._attended_tokens(
                             ctx_arr, int(ctx_arr.sum())),
                         tile_rows=tile_rows,
-                        **self._linear_attn_counts(False, len(rows)))
+                        **self._row_step_counts(False, len(rows)))
         if n_chunk_groups:
             self._efficiency.record_dispatch(
                 "prefill", n_chunk_groups, n_chunk_groups,
@@ -1798,7 +1832,7 @@ class ModelRunner:
             context_tokens=(int(ctx.sum()) * num_steps
                             + live_rows * num_steps * (num_steps - 1) // 2),
             walked_tokens=walked, attended_tokens=attended,
-            **self._linear_attn_counts(True, live_rows * num_steps))
+            **self._row_step_counts(True, live_rows * num_steps))
         self._record_moe_ffn_rows(b, num_steps)
 
         step = InflightStep(self, packed, cont.metas, cont.rows, num_steps,

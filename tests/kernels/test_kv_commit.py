@@ -113,3 +113,33 @@ def test_commit_page_straddle_two_pages():
             np.testing.assert_array_equal(
                 got_k[blk, :, pos % bs, :],
                 np.asarray(k_stage)[i, t, :, :])
+
+
+@pytest.mark.parametrize("layers,group", [(5, 2), (8, 8), (3, 8)])
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_commit_by_layer_matches_the_commit_with_layers_as_heads(
+        layers, group, dtype):
+    """A latent pool that holds every layer where a per-head pool has its
+    heads (`ModelConfig.get_packed_latent_layers`): committing the stage
+    with the layers as rows of the pool viewed `[NB * layers, 1, BS, D]`,
+    `group` layers a call, leaves the pool bit for bit as the page commit
+    with the layers as heads does (which the row scatter above is held
+    to), pad rows, truncation and page-straddling starts included."""
+    from intellillm_tpu.ops.kv_cache import commit_staged_chunk_by_layer
+    rng = np.random.default_rng(1)
+    b, d, bs, nb, w, chunk = 4, 128, 16, 24, 6, 8
+    pool = jnp.asarray(rng.normal(size=(nb, layers, bs, d)), dtype=dtype)
+    stage = jnp.asarray(rng.normal(size=(b, chunk, layers, d)), dtype=dtype)
+    tables = jnp.asarray(
+        rng.permutation(nb)[:b * w].reshape(b, w).astype(np.int32))
+    start = jnp.asarray(np.asarray([0, 19, 47, 9], np.int32))
+    n_valid = jnp.asarray(np.asarray([chunk, chunk, chunk // 2, 0], np.int32))
+    (want, ) = commit_staged_chunk((stage, ), (pool, ), start, n_valid,
+                                   tables)
+    (got, ) = commit_staged_chunk_by_layer((stage, ), (pool, ), start,
+                                           n_valid, tables, group=group)
+    assert got.shape == pool.shape
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert not np.array_equal(np.asarray(got, np.float32),
+                              np.asarray(pool, np.float32))

@@ -203,3 +203,44 @@ def test_block_is_the_sequence_written_out(family):
         assert 0 < int(want_held.sum()) < b * l * top_k
     else:
         assert held is None
+
+
+@pytest.mark.parametrize("rows,path", [(8, "grouped"), (64, "dense")])
+def test_a_layer_of_stacks_that_hold_every_layer(rows, path, monkeypatch):
+    """`moe_ffn(held_at=)`: given the stacks of EVERY routed layer end to
+    end (a model that walks its layers in a `lax.scan`) and the place of
+    this layer's first expert, traced, both forms give what they give on
+    the layer's own stacks, and count the same experts visited."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from intellillm_tpu.layers import moe
+    layers, n, inter, hidden, top_k = 3, 4, 32, 64, 2
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(rows, hidden)).astype(np.float32))
+    gate = jnp.asarray(rng.normal(size=(hidden, 2 * n)).astype(np.float32))
+    stacks = [jnp.asarray(rng.normal(size=(layers * n, inter, hidden)).astype(
+        np.float32) / 8) for _ in range(3)]
+    # a share: the router is twice as wide as what is held, from expert 4 on
+    routing = moe.Routing(scoring="sigmoid", renormalize=True, held_start=n)
+    taken = []
+    for name in ("moe_ffn_dense", "moe_ffn_grouped"):
+        def spy(*a, name=name, orig=getattr(moe, name), **kw):
+            taken.append(name)
+            return orig(*a, **kw)
+        monkeypatch.setattr(moe, name, spy)
+
+    @jax.jit
+    def at(place):
+        return moe.moe_ffn(x, gate, *stacks, top_k, routing=routing,
+                           count_visited=True, held_at=(place * n, n))
+
+    for place in range(layers):
+        own = [w[place * n:(place + 1) * n] for w in stacks]
+        want, visited = moe.moe_ffn(x, gate, *own, top_k, routing=routing,
+                                    count_visited=True)
+        got, got_visited = at(jnp.int32(place))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+        assert int(got_visited) == int(visited) > 0
+    assert set(taken) == {"moe_ffn_" + path}

@@ -578,3 +578,79 @@ def test_share_counts_its_assignments(ckpt):
     assert (42 + 18) * 8 <= here + absent <= (42 + 48) * 8
     assert (here + absent) % 8 == 0            # top-4 x 2 MoE layers a row
     assert 0 < here < absent
+
+
+# --- the projection block moved into a layer (PR 45) --------------------------
+
+def _attention_as_the_parent_held_it(mla):
+    """`AXK1ForCausalLM._attention` as models/axk1.py held it at commit
+    95850e3 (before `layers/mla_attention.py` `LatentAttentionBlock`),
+    verbatim but for where its widths live."""
+    from intellillm_tpu.layers.normalization import rms_norm
+
+    def attention(lp, h, kv_cache, attn_metadata, positions):
+        b, l, _ = h.shape
+        hh = mla.num_heads
+        with jax.named_scope("mla_q_proj"):
+            c_q = rms_norm(h @ lp["q_a"], lp["q_a_norm"], mla.rms_eps)
+            q = (c_q @ lp["q_b"]).reshape(b, l, hh, mla.d_nope + mla.d_rope)
+            q_nope, q_rope = q[..., :mla.d_nope], q[..., mla.d_nope:]
+        with jax.named_scope("mla_kv_proj"):
+            ckv = h @ lp["kv_a"]
+            c_kv = rms_norm(ckv[..., :mla.kv_rank], lp["kv_a_norm"],
+                            mla.rms_eps)
+            k_rope = ckv[..., mla.kv_rank:][:, :, None, :]
+        q_rope, k_rope = mla.rope(positions, q_rope, k_rope)
+        with jax.named_scope("mla_absorb_q"):
+            q_abs = jnp.einsum("blhn,hnr->blhr", q_nope, lp["w_uk"])
+        q_lat = jnp.concatenate([q_abs, q_rope], axis=-1)
+        kv_new = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)
+        o_lat, kv_cache = mla.attn(q_lat, kv_new, kv_cache, attn_metadata)
+        with jax.named_scope("mla_absorb_o"):
+            o = jnp.einsum("blhr,hrv->blhv", o_lat, lp["w_uv"])
+        return o.reshape(b, l, hh * mla.d_v) @ lp["o"], kv_cache
+
+    return attention
+
+
+@pytest.mark.parametrize("staged", [False, True],
+                         ids=["mixed", "decode_substep"])
+def test_the_moved_projection_block_traces_what_the_parent_traced(staged):
+    """A.X-K1's programs are unchanged by the move: the model's jaxpr over
+    the `mixed` step's call and over a staged decode substep's, with the
+    block in `layers/mla_attention.py`, equals the jaxpr with the method
+    the parent held (same operations, same order, same shapes)."""
+    from intellillm_tpu.layers.attention import AttentionMetadata
+    from intellillm_tpu.models.axk1 import AXK1ForCausalLM
+    cfg = dict(TINY, vocab_size=64)
+    mc, _, _ = latent_cache_engine(cfg, "float32")
+    model = AXK1ForCausalLM(mc)
+    f32, i32 = jnp.float32, jnp.int32
+    rows, width, blocks, chunk = 8, 4, 8, 8
+
+    def spec(tree):
+        if isinstance(tree, dict):
+            return {k: spec(v) for k, v in tree.items()}
+        if isinstance(tree, list):
+            return [spec(v) for v in tree]
+        return jax.ShapeDtypeStruct(tree, f32)
+
+    pool = jax.ShapeDtypeStruct((blocks, 1, 16, 128), f32)
+    stage = jax.ShapeDtypeStruct((rows, chunk, 1, 128), f32)
+    caches = [(pool, stage) if staged else (pool, )] * model.num_layers
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, i32)      # noqa: E731
+
+    def call(params, ids, positions, caches, slots, ctx, tables, k):
+        meta = AttentionMetadata(
+            slot_mapping=None if staged else slots, context_lens=ctx,
+            block_tables=tables, staged=staged,
+            stage_index=k if staged else None)
+        return model(params, ids, positions, caches, meta)
+
+    args = (spec(model._param_shapes()), ints(rows, 1), ints(rows, 1),
+            caches, ints(rows, 1), ints(rows), ints(rows, width), ints())
+    now = str(jax.make_jaxpr(call)(*args))
+    model.mla = _attention_as_the_parent_held_it(model.mla)
+    then = str(jax.make_jaxpr(call)(*args))
+    assert "dot_general" in now and len(now) > 5000
+    assert now == then

@@ -1142,3 +1142,116 @@ def test_window_group_model_programs_compile(compiled_for_chip, one_chip,
     assert _stack_sized_copies(text, SMALLTHINKER) == []
     # One period plus the whole vocabulary's head stay inside the chip.
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024**3
+
+
+# --- latent attention under a multi-stream residual --------------------------
+
+XING_BLOCKS = 4500
+
+
+@pytest.mark.parametrize("program", ["mixed", "decode_cont"])
+def test_multi_stream_latent_model_programs_compile(compiled_for_chip,
+                                                    one_chip, tmp_path,
+                                                    program):
+    """`xing4.0-29b-a4b-ep8`'s programs as its cell dispatches them
+    (`mixed` at 512 rows, `decode_cont` at 128 rows x 64-block tables, K=8,
+    bf16, published widths; depth cut to 2 dense + 4 routed layers: whether
+    all 40 fit is the chip's to say) compile for the described v5e and
+    hold the latent kernel of that program at 32 heads (`_ragged_call_mla`
+    / `_paged_attention_call_mla`: A.X-K1 launches them at 64; the kernels
+    take all H heads of a row in one block, so no block-shape rule changed
+    and `test_mla_*` above still compile the 64-head launches). The routed
+    layers are ONE `while` (the scan over them) whatever their number, so
+    the sweeps are 2 x (2 dense layers + 1 scanned layer) loops of the
+    Sinkhorn iteration; the streams keep a shape of their own (`[rows, 1,
+    4, 3584]`), which the benchmark's `mhc_dev_share` reads by. The scan
+    copies neither the one latent pool (every layer a view of it) nor an
+    expert stack (the grouped kernels and the dense dots read the stacked
+    arrays in place): the program's temporaries stay under a third of the
+    pool and no operation copies a layer's stack. (`XING_LAYERS=40
+    XING_HLO_DIR=<dir>` compiles the full depth and writes the text and
+    the memory analysis there: 35 s a program, PERF.md section 6.)"""
+    import json
+
+    from intellillm_tpu.config import (CacheConfig, ModelConfig,
+                                       ParallelConfig, SchedulerConfig)
+    from intellillm_tpu.models import get_model_class
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    from intellillm_tpu.worker.model_runner import ModelRunner
+
+    from chipbench import CHIP_DIR      # puts the harness on sys.path
+    from harness.server import hf_config_of
+
+    with open(os.path.join(CHIP_DIR, "configs",
+                           "xing4.0-29b-a4b-ep8.json")) as f:
+        cell = json.load(f)
+    serve = cell["serve"]
+    layers = int(os.environ.get("XING_LAYERS", 6))
+    hf = dict(hf_config_of(cell, rehearsal=False), num_hidden_layers=layers)
+    assert (hf["hidden_size"], hf["num_attention_heads"], hf["hc_mult"],
+            hf["first_k_dense_replace"]) == (3584, 32, 4, 2)
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    model_config = ModelConfig(
+        model=str(tmp_path), dtype=serve["dtype"], load_format="dummy",
+        max_model_len=serve["max_model_len"])
+    model = get_model_class(model_config.hf_config.architectures)(
+        model_config)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init_random_params(0)))
+    set_use_pallas(True)        # jax.default_backend() is "cpu" here
+    try:
+        runner = ModelRunner(
+            model, params, model_config,
+            SchedulerConfig(max_num_batched_tokens=512,
+                            max_num_seqs=CELL_ROWS,
+                            max_model_len=serve["max_model_len"],
+                            enable_chunked_prefill=True),
+            CacheConfig(num_device_blocks_override=XING_BLOCKS,
+                        swap_space_gib=0.01), ParallelConfig())
+
+        def sds(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        i32, f32 = jnp.int32, jnp.float32
+        assert model_config.get_cache_row_shape() == (1, layers, 640)
+        kv = [(sds(jnp.bfloat16, XING_BLOCKS, layers, 16, 640), )]
+        assert not jax.config.jax_enable_compilation_cache
+        if program == "mixed":
+            compiled = runner.lower_mixed_step(
+                kv, 512, CELL_TABLE_WIDTH, do_random=False).compile()
+        else:
+            b, w, k = CELL_ROWS, CELL_TABLE_WIDTH, CELL_K
+            rest = (sds(i32, b, 1), sds(i32, b, w), sds(i32, b),
+                    sds(f32, b), sds(i32, b), sds(f32, b), sds(f32, b),
+                    sds(jnp.uint32, b), sds(f32, b), sds(f32, b),
+                    sds(f32, b), None, None)
+            # _pack's layout at logprob_k = 1: the share's two columns,
+            # the visits' two, the mixes' one.
+            compiled = runner._jit_decode_cont.lower(
+                params, kv, sds(i32, b, 4 * k + 5), sds(i32, b, 1),
+                sds(jnp.bool_, b, 1), *rest, prev_t1=k, num_steps=k,
+                logprob_k=1, do_topk=False, do_topp=False, do_minp=False,
+                do_penalties=False, do_random=False).compile()
+    finally:
+        set_use_pallas(None)
+    text = compiled.as_text()
+    if os.environ.get("XING_HLO_DIR"):
+        print("MEMORY", program, compiled.memory_analysis())
+        with open(os.path.join(os.environ["XING_HLO_DIR"],
+                               f"xing4_{program}.hlo"), "w") as f:
+            f.write(text)
+    kernel = ("_ragged_call_mla" if program == "mixed"
+              else "_paged_attention_call_mla")
+    assert kernel in text and "tpu_custom_call" in text
+    rows = 512 if program == "mixed" else CELL_ROWS
+    assert f"[{rows},1,4,3584]" in text or f"[{rows},4,3584]" in text
+    # 2 sublayers' sweeps in each dense layer and in the scanned layer, the
+    # scan over the routed layers, and the K-substep scan around them in
+    # the decode program; no other loop.
+    whiles = len(re.findall(r" while\(", text))
+    assert whiles == 2 * 3 + 1 + (program == "decode_cont"), whiles
+    pool_bytes = XING_BLOCKS * layers * 16 * 640 * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes // 3, (temp, pool_bytes)
+    assert not re.search(r"= bf16\[8,1024,3584\]\S* copy\(", text)
