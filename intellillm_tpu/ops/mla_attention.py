@@ -15,9 +15,10 @@ trace time (`use_kernel("mla", ...)`, dispatch labels `pallas:mla` /
 
 The jnp references compose the per-head references (`ops/attention.py`)
 with the pool given as both K and V and one kv head: they are the oracle
-the Pallas kernels (`ops/pallas/mla_attention.py`) are held to.
-`walk_extent` is those kernels' page-walk rule, here for the runner's
-walked-tokens counter.
+the Pallas kernels (`ops/pallas/mla_attention.py`) are held to. Those
+kernels' page walk is the per-head kernels' (`ops/ragged_attention.py`
+`walk_extent`, which the runner's walked-tokens counter reads for every
+cache kind).
 """
 from __future__ import annotations
 
@@ -28,8 +29,8 @@ import jax.numpy as jnp
 from intellillm_tpu.ops.attention import decode_attention_reference
 from intellillm_tpu.ops.dispatch import use_kernel
 from intellillm_tpu.ops.kv_cache import scatter_rows
-from intellillm_tpu.ops.pallas.mla_attention import (  # noqa: F401
-    mla_paged_attention, mla_ragged_paged_attention, walk_extent)
+from intellillm_tpu.ops.pallas.mla_attention import (
+    mla_paged_attention, mla_ragged_paged_attention)
 
 
 def _use_kernel(pool: jnp.ndarray, v_width: int) -> bool:

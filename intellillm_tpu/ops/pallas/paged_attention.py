@@ -174,12 +174,15 @@ def _switch(index, lo: int, hi: int, branch) -> None:
              lambda: _switch(index, mid + 1, hi, branch))
 
 
-def _page_walker(context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf,
-                 v_buf, k_sem, v_sem, *, held_back: int, batch_size: int,
-                 heads_per_block: int, pages_per_group: int,
+def _page_walker(context_lens_ref, tables_ref, pools, *, held_back: int,
+                 batch_size: int, heads_per_block: int, pages_per_group: int,
                  block_size: int, w_max: int, windowed: bool = False):
     """The DMA side of the page walk, for one launch's refs: returns
-    (`first_page`, `start_first_group`, `walk`).
+    (`first_page`, `start_first_group`, `walk`). `pools` are the pools
+    the walk moves, each as (the pool in HBM `[NB, heads, BS, D]`, its
+    VMEM double buffer `[2, P, HP, BS, D]`, its DMA semaphores `[2]`):
+    K and V for the per-head kernels, the one latent pool (a head block
+    of 1) for `mla_attention.py`.
 
     `walk(row, hb, start_buf, start_successor, update, sub)` streams the
     pages `row` holds of its context less its last `held_back` tokens
@@ -195,7 +198,9 @@ def _page_walker(context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf,
     piece; then its tail, the pages of its last group that it holds,
     moved and waited for a sub-group of pages a loop turn (then a page a
     turn) and multiplied in one piece up to the last `sub`-page
-    sub-group that holds one. A row with nothing to walk (a
+    sub-group that holds one (`sub` a tuple of ascending page counts
+    that ends in `pages_per_group`: the smallest of these pieces that
+    holds the tail). A row with nothing to walk (a
     pad row) moves and multiplies nothing. Copies are started and waited
     for by the same rule on the same row's context, so what is waited
     for is what was started.
@@ -207,7 +212,6 @@ def _page_walker(context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf,
     `update` masks the positions below `lo` in that first page. Not
     windowed, nothing here differs from what it was."""
     hp, ppg, bs = heads_per_block, pages_per_group, block_size
-    pools = ((k_hbm_ref, k_buf, k_sem), (v_hbm_ref, v_buf, v_sem))
     chunk = _sub_pages(ppg)
 
     def first_page(row):
@@ -312,8 +316,14 @@ def _page_walker(context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf,
             wait_tail(tail, tail_buf)
             # One piece whatever its size: a chain of smaller updates
             # would pay the QK -> softmax -> PV latency once a link.
-            _switch(lax.div(tail + sub - 1, sub), 1, ppg // sub,
-                    lambda t: update(full_groups, tail_buf, t * sub))
+            if isinstance(sub, int):
+                _switch(lax.div(tail + sub - 1, sub), 1, ppg // sub,
+                        lambda t: update(full_groups, tail_buf, t * sub))
+            else:
+                # The piece sizes given: the smallest that holds the tail.
+                _switch(sum((tail > n).astype(jnp.int32) for n in sub[:-1]),
+                        0, len(sub) - 1,
+                        lambda i: update(full_groups, tail_buf, sub[i]))
 
         def no_tail():
             @pl.when(full_groups == 0)  # nothing held: nothing in flight
@@ -458,10 +468,10 @@ def _walk(context_lens_ref, tables_ref, buf_idx_ref, init_ref, q_ref,
     hb = pl.program_id(1)
     hp, ppg, bs = k_buf.shape[2], pages_per_group, block_size
     first_page, start_first_group, walk = _page_walker(
-        context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf,
-        k_sem, v_sem, held_back=0, batch_size=batch_size,
-        heads_per_block=hp, pages_per_group=ppg, block_size=bs,
-        w_max=w_max, windowed=windowed)
+        context_lens_ref, tables_ref,
+        ((k_hbm_ref, k_buf, k_sem), (v_hbm_ref, v_buf, v_sem)),
+        held_back=0, batch_size=batch_size, heads_per_block=hp,
+        pages_per_group=ppg, block_size=bs, w_max=w_max, windowed=windowed)
 
     # Very first grid step starts its own group 0; afterwards every step's
     # group 0 was prefetched by its predecessor. It also lays out, once,

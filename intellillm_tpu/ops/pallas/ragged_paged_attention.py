@@ -241,10 +241,10 @@ def _ragged_kernel(*refs, batch_size: int, num_head_blocks: int,
     gt = g_sz * t_sz
     d = k_buf.shape[-1]
     first_page, start_first_group, walk = _page_walker(
-        context_lens_ref, tables_ref, k_hbm_ref, v_hbm_ref, k_buf, v_buf,
-        k_sem, v_sem, held_back=1, batch_size=batch_size,
-        heads_per_block=hp, pages_per_group=ppg, block_size=bs,
-        w_max=w_max, windowed=windowed)
+        context_lens_ref, tables_ref,
+        ((k_hbm_ref, k_buf, k_sem), (v_hbm_ref, v_buf, v_sem)),
+        held_back=1, batch_size=batch_size, heads_per_block=hp,
+        pages_per_group=ppg, block_size=bs, w_max=w_max, windowed=windowed)
     n_live = spans_ref[pl.num_programs(0)]
     row0 = span * t_sz                   # the span's first row
     row_end = jnp.minimum(row0 + t_sz, n_live)

@@ -50,6 +50,7 @@ from intellillm_tpu.native import build_decode_batch
 from intellillm_tpu.obs import (get_compile_tracker,
                                 get_efficiency_tracker, get_kernel_ledger,
                                 get_step_tracer)
+from intellillm_tpu.ops.ragged_attention import walk_extent
 from intellillm_tpu.ops.state_rows import gather_rows, scatter_rows
 from intellillm_tpu.sampling_params import SamplingParams, SamplingType
 from intellillm_tpu.sequence import (SamplerOutput, SequenceGroupMetadata,
@@ -259,13 +260,6 @@ class ModelRunner:
 
         self.block_size = cache_config.block_size
         self.sliding_window = model_config.get_sliding_window()
-        # The page walk's rule of the attention kernels this model runs
-        # (what the walked-tokens counter reports).
-        if model_config.get_latent_cache_width():
-            from intellillm_tpu.ops.mla_attention import walk_extent
-        else:
-            from intellillm_tpu.ops.ragged_attention import walk_extent
-        self._walk_extent = walk_extent
         # Query heads a KV head: with the table width, what the ragged
         # kernel sizes its tiles of rows by (`_mixed_walkers`). None: the
         # latent kernels walk a row at a time.
@@ -1039,12 +1033,12 @@ class ModelRunner:
         def moved(pages) -> int:
             return int((pages if walkers is None else pages[walkers]).sum())
         if full_layers:
-            pages, _ = self._walk_extent(seen, self.block_size, w)
+            pages, _ = walk_extent(seen, self.block_size, w)
             walked += full_layers * moved(pages)
         if window_layers:
             lo = np.clip(np.asarray(query_ends, np.int64) - self._window,
                          0, None)
-            pages, _ = self._walk_extent(seen, self.block_size, w, lo=lo)
+            pages, _ = walk_extent(seen, self.block_size, w, lo=lo)
             walked += window_layers * moved(pages)
         return walked * self.block_size // (full_layers + window_layers)
 

@@ -543,6 +543,7 @@ def test_moe_up_to_256_rows_is_the_dense_program(rows, widths, n_router,
 # A.X-K1's latent attention, as the cell `axk1-ep16-decode-closed` runs it:
 # 64 heads over one stored vector of 640 lanes (512 + 64 + pad), the value
 # its first 512; a 9,000-block bf16 pool; max_model_len 4096 tables.
+# Xing4.0 (`xing4-ep8-decode-closed`) launches the same kernels at 32 heads.
 MLA_HEADS, MLA_DK, MLA_DV, MLA_BLOCKS = 64, 640, 512, 9000
 MLA_SCALE = 192 ** -0.5 * 1.3466 ** 2
 
@@ -551,9 +552,10 @@ def _latent_pool(dtype=jnp.bfloat16):
     return ((MLA_BLOCKS, 1, BLOCK_SIZE, MLA_DK), dtype)
 
 
-def test_mla_paged_attention_compiles(compile_for_chip):
+@pytest.mark.parametrize("heads", [MLA_HEADS, 32])
+def test_mla_paged_attention_compiles(compile_for_chip, heads):
     """The pool walk of decode_fused/decode_cont at 128 rows x 64-block
-    tables."""
+    tables, at both latent cells' head counts."""
     from intellillm_tpu.ops.pallas.mla_attention import mla_paged_attention
     b, w = 128, 64
 
@@ -561,13 +563,18 @@ def test_mla_paged_attention_compiles(compile_for_chip):
         return mla_paged_attention(q, pool, tables, ctx, MLA_SCALE, MLA_DV)
 
     text = compile_for_chip(
-        fn, ((b, MLA_HEADS, MLA_DK), jnp.bfloat16), _latent_pool(),
+        fn, ((b, heads, MLA_DK), jnp.bfloat16), _latent_pool(),
         ((b, w), jnp.int32), ((b, ), jnp.int32))
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("rows,width", [(256, 32), (4096, 256)])
-def test_mla_ragged_paged_attention_compiles(compile_for_chip, rows, width):
+@pytest.mark.parametrize("rows,width,heads", [
+    (256, 32, MLA_HEADS), (4096, 256, MLA_HEADS),
+    # `decode-closed`'s `mixed` launches under 64-block tables
+    (512, 64, MLA_HEADS), (128, 64, MLA_HEADS),
+    (512, 64, 32), (256, 64, 32), (128, 64, 32)])
+def test_mla_ragged_paged_attention_compiles(compile_for_chip, rows, width,
+                                             heads):
     """The mixed step's fused latent-cache write + walk. (4096, 256) is
     the warm-up shape: more SMEM than one launch may take, so the wrapper
     must have split it."""
@@ -579,7 +586,7 @@ def test_mla_ragged_paged_attention_compiles(compile_for_chip, rows, width):
                                           MLA_SCALE, MLA_DV)
 
     text = compile_for_chip(
-        fn, ((rows, MLA_HEADS, MLA_DK), jnp.bfloat16),
+        fn, ((rows, heads, MLA_DK), jnp.bfloat16),
         ((rows, MLA_DK), jnp.bfloat16), _latent_pool(),
         ((rows, ), jnp.int32), ((rows, width), jnp.int32),
         ((rows, ), jnp.int32), donate=(2, ))
