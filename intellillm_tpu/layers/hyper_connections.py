@@ -47,7 +47,15 @@ loop is a `while` of 20 turns of 3 operations each and compiles in a
 tenth of a second anywhere. The two mixes are 4- and 16-term sums over
 the streams (the pre-mix written out, the post-mix a broadcast product
 summed over the stream axis): they move `X`'s bytes (read once by the pre-mix,
-read and written by the post-mix) and multiply nothing on the MXU. One fused kernel a sublayer is a `perf_opt` PR's (PERF.md
+read and written by the post-mix) and multiply nothing on the MXU.
+
+This file is the ORACLE of the seam `ops/hyper_connections.py`, which is
+what `models/xing4.py` calls: on a TPU each mix is one Pallas kernel
+(`ops/pallas/hyper_connections.py`, since PR 47: the streams flat `[T,
+n C]`, the sweeps a loop inside the pre-mix's launch on values that stay
+in VMEM, `sinkhorn` below its body as it is XLA's), and everywhere else,
+in every CPU test and rehearsal, these functions. What is left open is one
+launch for the post-mix of a sublayer and the pre-mix of the next (PERF.md
 section 7).
 """
 from __future__ import annotations

@@ -9,7 +9,9 @@ Source: https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B/blob/main/config.jso
             X = HC(X, f -> FFN(RMSNorm_post(f)), ffn_hc)
     logits = RMSNorm(sum_i X_L[i]) W_head
 
-- `HC` is `layers/hyper_connections.py` (`mhc_pre` / `mhc_post`): every
+- `HC` is `layers/hyper_connections.py`'s equations through the seam
+  `ops/hyper_connections.py` (`mhc_pre` / `mhc_post`: a Pallas kernel each on
+  a TPU, on the streams held FLAT, `[B, L, n C]`, and in float32): every
   sublayer reads a per-token mix of the streams and writes to all of them
   through a per-token doubly stochastic matrix. There is no carried
   `(h, residual)` pair and no `fused_add_rms_norm`: the block norms act on
@@ -33,8 +35,10 @@ The layers after the dense ones are alike, so they are the turns of ONE
 written out took XLA 90 s a program and the cell 21 minutes of set-up,
 PERF.md section 6, PR 45). For that their parameters are stacked along a
 leading axis (`params["moe_layers"]`; the expert stacks `[layers * held, I,
-D]`, which `layers/moe.py` reads in place through `held_at`), and every
-layer's latent vectors lie in ONE pool `[blocks, layers, block_size, width]`
+D]`, which `layers/moe.py` reads in place through `held_at`; the mixes'
+parameters of ALL layers, the dense ones' too, in `params["attn_hc"]` /
+`params["ffn_hc"]`, which the mixes' kernel reads in place by the layer's
+number), and every layer's latent vectors lie in ONE pool `[blocks, layers, block_size, width]`
 (`ModelConfig.get_packed_latent_layers`; `kv_caches` is the one-entry list
 `[(pool,)]`, or `[(pool, stage)]` in the staged decode programs), of which
 layer `l` sees its own rows (`layers/mla_attention.py` `_layer_view`).
@@ -55,9 +59,7 @@ import numpy as np
 
 from intellillm_tpu.config import ModelConfig
 from intellillm_tpu.layers.activation import get_act_fn
-from intellillm_tpu.layers.hyper_connections import (HyperConnections,
-                                                     col_error, mhc_post,
-                                                     mhc_pre)
+from intellillm_tpu.layers.hyper_connections import HyperConnections
 from intellillm_tpu.layers.mla_attention import LatentAttentionBlock
 from intellillm_tpu.layers.moe import (DENSE_LAYER, ExpertCounts, ExpertShare,
                                        Routing, random_gate_up_stack,
@@ -66,11 +68,13 @@ from intellillm_tpu.layers.normalization import rms_norm
 from intellillm_tpu.models.weight_utils import (cast_array,
                                                 hf_model_weights_iterator,
                                                 stack_experts)
+from intellillm_tpu.ops.hyper_connections import mhc_post, mhc_pre
 
 Params = Dict[str, object]
 
 _HC_NAMES = {"phi": "phi.weight", "alpha": "alpha", "b_pre": "b_pre",
              "b_post": "b_post", "b_res": "b_res"}
+_MIXES = ("attn_hc", "ffn_hc")      # a layer's two, and the tree's stacks
 
 
 class Xing4ForCausalLM:
@@ -135,12 +139,20 @@ class Xing4ForCausalLM:
                  attn_metadata):
         (cache, ) = kv_caches       # every layer's: (pool,) | (pool, stage)
         h = params["embed_tokens"][input_ids]
-        x = jnp.broadcast_to(h[:, :, None, :],
-                             h.shape[:2] + (self.hc.n, h.shape[-1]))
+        # The streams flat, [B, L, n C]: stream i the lanes [i C, (i + 1) C)
+        # (ops/hyper_connections.py says why), and float32 from the
+        # embedding to the head's sum: a sublayer's input and output are in
+        # the model's dtype, the residual they are mixed into is not
+        # rounded (`_layer`).
+        x = jnp.tile(h, (1, 1, self.hc.n)).astype(jnp.float32)
         worst = jnp.zeros(h.shape[:2], jnp.float32)
+        # Every layer's mixes, stacked: their kernel reads a layer's `phi`
+        # out of the stack (a slice of it is a copy), dense layer or
+        # scanned, so a program holds one kernel a sublayer.
+        mixes = (params["attn_hc"], params["ffn_hc"])
         for i, lp in enumerate(params["dense_layers"]):
-            x, cache, err, _ = self._layer(lp, x, cache, attn_metadata,
-                                           positions, i)
+            x, cache, err, _ = self._layer(lp, mixes, x, cache,
+                                           attn_metadata, positions, i)
             worst = jnp.maximum(worst, err)
         counts = ExpertCounts(self.share)
         if self.num_moe_layers:
@@ -154,8 +166,8 @@ class Xing4ForCausalLM:
                 x, carried, worst, visited, held = carry
                 lp, j = xs
                 x, cache, err, (v, hl) = self._layer(
-                    lp, x, fixed + carried, attn_metadata, positions,
-                    self.first_dense + j, stacks + (j, ))
+                    lp, mixes, x, fixed + carried, attn_metadata,
+                    positions, self.first_dense + j, stacks + (j, ))
                 if hl is not None:
                     held = held + hl
                 return (x, cache[len(fixed):], jnp.maximum(worst, err),
@@ -168,38 +180,45 @@ class Xing4ForCausalLM:
             cache = fixed + carried
             counts.add((visited, held if self.share.moe_choices_per_token
                         else None))
-        h = rms_norm(x.astype(jnp.float32).sum(axis=-2).astype(x.dtype),
-                     params["norm"], self.rms_eps)
+        x = x.reshape(h.shape[:2] + (self.hc.n, h.shape[-1]))
+        h = rms_norm(x.sum(axis=-2).astype(h.dtype), params["norm"],
+                     self.rms_eps)
         counters = counts.counters()
         # [B, L] f32: a token's largest |column sum - 1| over the call's
         # mixing matrices (worker/model_runner.py `_pack`).
         counters["mhc_col_error"] = worst
         return h, [cache], counters
 
-    def _layer(self, lp, x, kv_cache, attn_metadata, positions, layer,
-               stacks=None):
+    def _layer(self, lp, mixes, x, kv_cache, attn_metadata, positions,
+               layer, stacks=None):
         """Layer `layer` (a Python int, or traced in the scan) over the
-        streams. `stacks`: the routed layers' expert stacks and this
-        layer's place among them (`routed_experts`), None for a dense
-        layer. Returns (streams, kv_cache, a token's largest column error
-        [B, L], the layer's counts for `ExpertCounts.add`)."""
-        u, post, comb = mhc_pre(x, lp["attn_hc"], self.hc)
-        err = col_error(comb)
+        streams. `mixes`: every layer's (attention, FFN) mixes, stacked.
+        `stacks`: the routed layers' expert stacks and this layer's place
+        among them (`routed_experts`), None for a dense layer. Returns
+        (streams, kv_cache, a token's largest column error [B, L], the
+        layer's counts for `ExpertCounts.add`)."""
+        mesh = attn_metadata.mesh
+        attn_hc, ffn_hc = mixes
+        dtype = lp["input_norm"].dtype          # the model's
+        # The streams are float32 and so is the mixed vector `u` until its
+        # norm has read it (ops/pallas/hyper_connections.py says why); the
+        # sublayers compute in the model's dtype.
+        u, coef, err = mhc_pre(x, attn_hc, self.hc, layer, mesh)
         y, kv_cache = self.mla(
-            lp, rms_norm(u, lp["input_norm"], self.rms_eps), kv_cache,
-            attn_metadata, positions, layer=layer)
-        x = mhc_post(x, y, post, comb)
-        u, post, comb = mhc_pre(x, lp["ffn_hc"], self.hc)
-        err = jnp.maximum(err, col_error(comb))
-        f = rms_norm(u, lp["post_attn_norm"], self.rms_eps)
+            lp, rms_norm(u, lp["input_norm"], self.rms_eps).astype(dtype),
+            kv_cache, attn_metadata, positions, layer=layer)
+        x = mhc_post(x, y, coef, self.hc, mesh)
+        u, coef, ffn_err = mhc_pre(x, ffn_hc, self.hc, layer, mesh)
+        err = jnp.maximum(err, ffn_err)
+        f = rms_norm(u, lp["post_attn_norm"], self.rms_eps).astype(dtype)
         if stacks is None:
             y = (self.act(f @ lp["gate"]) * (f @ lp["up"])) @ lp["down"]
             layer_counts = DENSE_LAYER
         else:
             y, layer_counts = routed_experts(
-                f, lp, self.share, self.routing, mesh=attn_metadata.mesh,
+                f, lp, self.share, self.routing, mesh=mesh,
                 activation=self.config.hidden_act.lower(), stacks=stacks)
-        return mhc_post(x, y, post, comb), kv_cache, err, layer_counts
+        return mhc_post(x, y, coef, self.hc, mesh), kv_cache, err, layer_counts
 
     def compute_logits(self, params: Params, hidden, lora=None):
         return hidden @ params["lm_head"]
@@ -215,7 +234,9 @@ class Xing4ForCausalLM:
             "mesh")
 
     def _layer_shapes(self, moe: bool):
-        """One layer's entries (leaves: shapes)."""
+        """One layer's entries (leaves: shapes), its two mixes among them
+        (`layer_params` hands them over so; in the param tree they lie in
+        the stacks over all layers, `_MIXES`)."""
         cfg = self.config
         e = self.hidden_size
         layer = {"input_norm": (e, ), "post_attn_norm": (e, ),
@@ -236,32 +257,40 @@ class Xing4ForCausalLM:
         """The param tree's structure (leaves: shapes): the dense layers
         one dict each, the routed layers ONE dict whose leaves carry the
         layers along a leading axis (the expert stacks: layers x held
-        experts along their first)."""
+        experts along their first), and the two mixes of EVERY layer,
+        dense or routed, in one stack each over all the layers."""
         cfg = self.config
         e = self.hidden_size
         n = self.num_moe_layers
 
-        def stacked(tree, name=None):
+        def stacked(tree, layers, name=None):
             if isinstance(tree, dict):
-                return {k: stacked(v, k) for k, v in tree.items()}
+                return {k: stacked(v, layers, k) for k, v in tree.items()
+                        if k not in _MIXES}
             if name in ("w1", "w2", "w3"):
-                return (n * tree[0], ) + tuple(tree[1:])
-            return (n, ) + tuple(tree)
+                return (layers * tree[0], ) + tuple(tree[1:])
+            return (layers, ) + tuple(tree)
 
+        dense = {k: v for k, v in self._layer_shapes(False).items()
+                 if k not in _MIXES}
         tree = {"embed_tokens": (cfg.vocab_size, e), "norm": (e, ),
                 "lm_head": (e, cfg.vocab_size),
-                "dense_layers": [self._layer_shapes(False)
+                "dense_layers": [dict(dense)
                                  for _ in range(self.first_dense)]}
         if n:
-            tree["moe_layers"] = stacked(self._layer_shapes(True))
+            tree["moe_layers"] = stacked(self._layer_shapes(True), n)
+        for hc in _MIXES:
+            tree[hc] = stacked(self.hc.param_shapes(e), self.num_layers)
         return tree
 
     def layer_params(self, params: Params, i: int) -> Params:
         """Layer `i`'s own entries of `params`, as `_layer_shapes` names
         them (for a caller that walks the layers one by one: the
         reference checks)."""
+        mixes = {hc: {k: v[i] for k, v in params[hc].items()}
+                 for hc in _MIXES}
         if i < self.first_dense:
-            return params["dense_layers"][i]
+            return {**params["dense_layers"][i], **mixes}
         j, n = i - self.first_dense, self.share.n_held
 
         def own(tree, name=None):
@@ -271,7 +300,7 @@ class Xing4ForCausalLM:
                 return tree[j * n:(j + 1) * n]
             return tree[j]
 
-        return own(params["moe_layers"])
+        return {**own(params["moe_layers"]), **mixes}
 
     # --- weights ---------------------------------------------------------
 
@@ -352,11 +381,23 @@ class Xing4ForCausalLM:
                 out[name] = jax.block_until_ready(whole)
             return out
 
-        shapes = self._param_shapes()
-        shapes.pop("moe_layers", None)
-        params = build(shapes)
+        e = self.hidden_size
+        params = build({
+            "embed_tokens": (self.config.vocab_size, e), "norm": (e, ),
+            "lm_head": (e, self.config.vocab_size),
+            "dense_layers": [self._layer_shapes(False)
+                             for _ in range(self.first_dense)]})
         if self.num_moe_layers:
             params["moe_layers"] = build_stacked(self._layer_shapes(True))
+        # Drawn with their layers (a seed's weights are what they were),
+        # the mixes then go into their stacks over all the layers.
+        for hc in _MIXES:
+            parts = [jax.tree.map(lambda v: v[None], lp.pop(hc))
+                     for lp in params["dense_layers"]]
+            if self.num_moe_layers:
+                parts.append(params["moe_layers"].pop(hc))
+            params[hc] = jax.block_until_ready(jax.tree.map(
+                lambda *v: jnp.concatenate(v), *parts))
         return params
 
     def load_weights(self, model_name_or_path: str,
@@ -418,9 +459,6 @@ class Xing4ForCausalLM:
             else:
                 for x in ("gate", "up", "down"):
                     layer[x] = T(f"{m}{x}_proj.weight")
-            for hc in ("attn_hc", "ffn_hc"):
-                layer[hc] = {ours: F(f"{p}{hc}.{theirs}")
-                             for ours, theirs in _HC_NAMES.items()}
             (moe_layers if i >= self.first_dense
              else params["dense_layers"]).append(layer)
         if moe_layers:
@@ -433,4 +471,9 @@ class Xing4ForCausalLM:
                         else np.stack)(trees)
 
             params["moe_layers"] = stacked(moe_layers)
+        for hc in _MIXES:
+            params[hc] = {ours: np.stack([
+                F(f"model.layers.{i}.{hc}.{theirs}")
+                for i in range(self.num_layers)])
+                for ours, theirs in _HC_NAMES.items()}
         return params

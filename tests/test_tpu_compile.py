@@ -1167,17 +1167,24 @@ def test_multi_stream_latent_model_programs_compile(compiled_for_chip,
     hold the latent kernel of that program at 32 heads (`_ragged_call_mla`
     / `_paged_attention_call_mla`: A.X-K1 launches them at 64; the kernels
     take all H heads of a row in one block, so no block-shape rule changed
-    and `test_mla_*` above still compile the 64-head launches). The routed
-    layers are ONE `while` (the scan over them) whatever their number, so
-    the sweeps are 2 x (2 dense layers + 1 scanned layer) loops of the
-    Sinkhorn iteration; the streams keep a shape of their own (`[rows, 1,
-    4, 3584]`), which the benchmark's `mhc_dev_share` reads by. The scan
-    copies neither the one latent pool (every layer a view of it) nor an
-    expert stack (the grouped kernels and the dense dots read the stacked
-    arrays in place): the program's temporaries stay under a third of the
-    pool and no operation copies a layer's stack. (`XING_LAYERS=40
-    XING_HLO_DIR=<dir>` compiles the full depth and writes the text and
-    the memory analysis there: 35 s a program, PERF.md section 6.)"""
+    and `test_mla_*` above still compile the 64-head launches) beside the
+    two kernels of the stream mixes (`_mhc_pre_call`, `_mhc_post_call`,
+    since PR 47: the Sinkhorn sweeps are a loop INSIDE the first, so the
+    program's only `while`s are the scan over the routed layers, ONE
+    whatever their number, and the K-substep scan around it in the decode
+    program). The streams are flat, `[rows, 14336]` (`[rows, 1, 14336]`
+    where the model holds them), float32 from the embedding to the head's
+    sum (one kernel a mix in a program's text to trace and lower, whichever
+    sublayer or layer calls it), a shape no other tensor of the
+    configuration has, which the benchmark's `mhc_dev_share` reads the
+    kernels by (its `,14336]`); the `[rows, 1, 4, 3584]` view is left at
+    the head's sum alone. The scan copies neither the one latent pool
+    (every layer a view of it) nor an expert stack (the grouped kernels and
+    the dense dots read the stacked arrays in place): the program's
+    temporaries stay under a third of the pool and no operation copies a
+    layer's stack. (`XING_LAYERS=40 XING_HLO_DIR=<dir>` compiles the full
+    depth and writes the text and the memory analysis there: 35 s a
+    program, PERF.md section 6.)"""
     import json
 
     from intellillm_tpu.config import (CacheConfig, ModelConfig,
@@ -1252,13 +1259,76 @@ def test_multi_stream_latent_model_programs_compile(compiled_for_chip,
               else "_paged_attention_call_mla")
     assert kernel in text and "tpu_custom_call" in text
     rows = 512 if program == "mixed" else CELL_ROWS
-    assert f"[{rows},1,4,3584]" in text or f"[{rows},4,3584]" in text
-    # 2 sublayers' sweeps in each dense layer and in the scanned layer, the
-    # scan over the routed layers, and the K-substep scan around them in
-    # the decode program; no other loop.
+    # Two sublayers' kernels in each dense layer and in the scanned layer,
+    # each reading the flat float32 streams.
+    for name in ("_mhc_pre_call", "_mhc_post_call"):
+        calls = re.findall(rf"%{name}[.\d]* = .*custom-call\(.*", text)
+        assert len(calls) == 2 * 3, (name, len(calls))
+        assert all(f"f32[{rows},14336]" + "{1,0}" in call.split(
+            "operand_layout_constraints")[1] for call in calls), name
+        assert not any(f"bf16[{rows},14336]" in call for call in calls), name
+    # The scan over the routed layers, and the K-substep scan around it in
+    # the decode program; no sweep loop and no other.
     whiles = len(re.findall(r" while\(", text))
-    assert whiles == 2 * 3 + 1 + (program == "decode_cont"), whiles
+    assert whiles == 1 + (program == "decode_cont"), whiles
+    # No copy of the streams is made, in either shape or dtype.
+    assert not re.search(
+        rf"= (f32|bf16)\[{rows},(1,)?14336\]\S* copy\(", text)
+    assert not re.search(rf"= f32\[{rows},(1,)?4,3584\]\S* copy\(", text)
     pool_bytes = XING_BLOCKS * layers * 16 * 640 * 2
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes // 3, (temp, pool_bytes)
     assert not re.search(r"= bf16\[8,1024,3584\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("layers", [1, 40])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("rows", [16, 128, 512])
+def test_stream_mix_kernels_compile(compile_for_chip, rows, dtype, layers):
+    """The two kernels of a hyper-connection sublayer alone, through their
+    seam, at Xing4.0's widths (4 streams of 3,584): the decode programs'
+    128 rows, the largest `mixed` bucket and the smallest (a part of one
+    128-row tile: the pipeline pads it, the seam does not step down), in
+    the cell's bfloat16 and the sharp reference check's float32, the
+    parameters a stack of one layer and the model's stack of forty with
+    the layer's place (the kernel reads `Phi` out of the stack: no copy of
+    a `[24, 14336]` slice is made for it)."""
+    from intellillm_tpu.layers.hyper_connections import HyperConnections
+    from intellillm_tpu.obs import get_compile_tracker
+    from intellillm_tpu.ops import hyper_connections as seam
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    n, c = 4, 3584
+    hc = HyperConnections(n=n, sweeps=20, eps=1e-6, clamp_min=-30.0,
+                          clamp_max=30.0, rms_eps=1e-6)
+    names = ("phi", "alpha", "b_pre", "b_post", "b_res")
+    lead = (layers, )
+
+    def sublayer(x, y, layer, *leaves):
+        u, coef, err = seam.mhc_pre(x, dict(zip(names, leaves)), hc, layer)
+        return u, err, seam.mhc_post(x, y, coef, hc)
+
+    paths = lambda: dict(                                       # noqa: E731
+        get_compile_tracker().snapshot()["kernel_dispatch"])
+    before = paths()
+    set_use_pallas(True)        # jax.default_backend() is "cpu" here
+    try:
+        # The sharp check runs under JAX_DEFAULT_MATMUL_PRECISION=highest,
+        # which reaches a kernel's own dots too.
+        with jax.default_matmul_precision(
+                "highest" if dtype == jnp.float32 else "default"):
+            text = compile_for_chip(
+                sublayer, ((rows, 1, n * c), dtype), ((rows, 1, c), dtype),
+                ((), jnp.int32),
+                *[(lead + shape, jnp.float32)
+                  for shape in hc.param_shapes(c).values()])
+    finally:
+        set_use_pallas(None)
+    after = paths()
+    for label in ("mhc_pre", "mhc_post"):
+        assert after.get("pallas:" + label, 0) == before.get(
+            "pallas:" + label, 0) + 1
+        assert after.get("reference:" + label, 0) == before.get(
+            "reference:" + label, 0)
+        assert f"_{label}_call" in text
+    assert text.count("tpu_custom_call") >= 2
+    assert not re.search(r"= f32\[(1,)?24,14336\]\S* (copy|fusion)\(", text)
