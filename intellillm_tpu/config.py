@@ -21,15 +21,18 @@ logger = init_logger(__name__)
 _GiB = 1024**3
 
 
-def ssd_state_shape(inner: int, d_state: int) -> Tuple[int, int, int]:
+def ssd_state_shape(inner: int, d_state: int,
+                    groups: int = 1) -> Tuple[int, int, int]:
     """`(tiles, d_state, lanes)`: a state-space layer's matrix a sequence,
     `[d_state, inner]` with the `inner` channels (head-major) cut into
-    whole 128-lane tiles where they divide so (one tile otherwise: a tiny
-    test model). The state dimension lies on the sublanes: what a token
+    whole 128-lane tiles where a group's channels divide so (one tile a
+    group otherwise: a tiny test model), so that a tile reads one group of
+    B and C. The state dimension lies on the sublanes: what a token
     contracts with C, and everything that is one number a channel (the
     head's decay, dt x) lies along the lanes
     (`ops/pallas/ssd.py`)."""
-    lanes = 128 if inner % 128 == 0 else inner
+    per_group = inner // groups
+    lanes = 128 if per_group % 128 == 0 else per_group
     return inner // lanes, d_state, lanes
 
 
@@ -190,8 +193,21 @@ class ModelConfig:
         return (2, self.get_total_num_kv_heads(),
                 width or self.get_head_size())
 
+    def _parallel_scan_layers(self) -> bool:
+        """Whether every layer holds a selective state-space scan BESIDE
+        its attention (both read one normed input, their outputs are
+        summed): a config with the scan's sizes (`mamba_d_ssm`) and
+        `attn_layer_indices` null, and no `layer_types` that would say
+        one or the other layer by layer."""
+        cfg = self.hf_config
+        return (getattr(cfg, "mamba_d_ssm", None) is not None
+                and not getattr(cfg, "layer_types", None)
+                and getattr(cfg, "attn_layer_indices", None) is None)
+
     def get_layer_cache_kinds(self) -> List[str]:
-        """What each layer of the stack caches, told by mechanism: "kv"
+        """What each entry of the cache list holds (`kv_caches[i]`, one
+        entry a layer but for the two cases at the end), told by
+        mechanism: "kv"
         (a paged pool of `get_cache_row_shape`, attention of either kind,
         the model's primary cache group), "kv_window" (the same pool rows,
         but the layer attends to the last `get_window_group_size()`
@@ -210,10 +226,17 @@ class ModelConfig:
         unless it says 1 everywhere (then the window is the whole
         model's, `get_sliding_window`, and there is one group); every
         other config is "kv" throughout. A latent model whose layers
-        share one pool (`get_packed_latent_layers`) is ONE "kv" entry."""
+        share one pool (`get_packed_latent_layers`) is ONE "kv" entry. A
+        layer that holds BOTH a paged K/V pool and a state slot (a scan
+        beside its attention, `_parallel_scan_layers`) is TWO entries,
+        "kv" then "state": layer l's caches are entries 2l and 2l + 1, so
+        that it counts in both totals and every block op and every state
+        op below finds its pools where it finds any other layer's."""
         n = self.get_num_layers()
         if self.get_packed_latent_layers():
             return ["kv"]           # one pool for every layer
+        if self._parallel_scan_layers():
+            return ["kv", "state"] * n
         layout = self._window_layout()
         if layout is not None and not all(layout):
             return ["kv_window" if w else "kv" for w in layout]
@@ -267,7 +290,8 @@ class ModelConfig:
           keeps the last `short_conv_kernel_size - 1` inputs of its q, k
           and v filters side by side, in the model's dtype, and a float32
           matrix `[key channels, value channels]` a head;
-        - a selective state-space scan (`layer_types` "mamba") keeps the
+        - a selective state-space scan (`layer_types` "mamba", or a scan
+          beside the attention in every layer) keeps the
           last `mamba_d_conv - 1` inputs of the one filter over x, B and
           C (`d_inner + 2 x n_groups x d_state` channels), in the model's
           dtype, and the float32 matrix `[d_state, d_inner]`, its channels
@@ -290,11 +314,13 @@ class ModelConfig:
             taps = linear["short_conv_kernel_size"] - 1
             return (((taps, 3 * heads * d), self.dtype),
                     ((heads, d, d), "float32"))
-        if "mamba" in (getattr(cfg, "layer_types", None) or ()):
+        if ("mamba" in (getattr(cfg, "layer_types", None) or ())
+                or self._parallel_scan_layers()):
             inner = cfg.mamba_n_heads * cfg.mamba_d_head
             filtered = inner + 2 * cfg.mamba_n_groups * cfg.mamba_d_state
             return (((cfg.mamba_d_conv - 1, filtered), self.dtype),
-                    (ssd_state_shape(inner, cfg.mamba_d_state), "float32"))
+                    (ssd_state_shape(inner, cfg.mamba_d_state,
+                                     cfg.mamba_n_groups), "float32"))
         return (((self.hf_config.conv_L_cache - 1,
                   self.hf_config.hidden_size), self.dtype), )
 

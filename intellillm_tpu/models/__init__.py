@@ -14,6 +14,7 @@ from intellillm_tpu.models.bloom import BloomForCausalLM
 from intellillm_tpu.models.chatglm import ChatGLMForCausalLM
 from intellillm_tpu.models.deepseek import DeepseekForCausalLM
 from intellillm_tpu.models.falcon import FalconForCausalLM
+from intellillm_tpu.models.falcon_h1 import FalconH1ForCausalLM
 from intellillm_tpu.models.gpt2 import GPT2LMHeadModel
 from intellillm_tpu.models.gpt_bigcode import GPTBigCodeForCausalLM
 from intellillm_tpu.models.gpt_neox import GPTNeoXForCausalLM
@@ -80,6 +81,8 @@ _MODEL_REGISTRY: Dict[str, Type] = {
     "GraniteMoeHybridForCausalLM": GraniteMoeHybridForCausalLM,
     # latent attention under a four-stream hyper-connection residual, EP share
     "Xing4ForCausalLM": Xing4ForCausalLM,
+    # a Mamba-2 scan (two groups of B and C) beside RoPE GQA in every layer
+    "FalconH1ForCausalLM": FalconH1ForCausalLM,
 }
 
 

@@ -9,7 +9,9 @@ Source: https://huggingface.co/ibm-granite/granite-4.0-h-small/blob/main/config.
 
 - `h = embed(ids) * embedding_multiplier`.
 - `h += rm * mixer(input_norm(h))`. A "mamba" layer: the selective
-  state-space scan of `layers/mamba2.py` (per-sequence state, no K/V). An
+  state-space scan of `layers/mamba2.py` (per-sequence state, no K/V;
+  `mamba_n_groups` groups of B and C, 1 as published, are the layer's, and
+  the gated norm is then a group's channels at a time). An
   "attention" layer: `q, k, v` without bias and WITHOUT position encoding
   (`position_embedding_type` "nope"), causal GQA whose softmax scale is
   `attention_multiplier` (not `head_dim ** -0.5`), `W_o`.
@@ -81,16 +83,17 @@ class GraniteMoeHybridForCausalLM:
                 f"mamba_d_conv {cfg.mamba_d_conv}: the filter's body "
                 f"(ops/short_conv.py `conv_silu_body`) is written for 4 "
                 f"taps")
-        if (cfg.mamba_n_groups != 1 or cfg.mamba_proj_bias
+        if (self.ssm_heads % cfg.mamba_n_groups or cfg.mamba_proj_bias
                 or not cfg.mamba_conv_bias or cfg.attention_bias
                 or cfg.position_embedding_type not in (None, "nope")
                 or not cfg.tie_word_embeddings
                 or not cfg.num_local_experts):
             raise NotImplementedError(
-                "only the published Granite 4.0-H Small setting is served: "
-                "mamba_n_groups 1 (B and C shared by every head), "
-                "mamba_conv_bias true, no projection or attention bias, "
-                "no position encoding, a tied head, experts in every layer")
+                "only the published Granite 4.0-H setting is served: whole "
+                "heads a group of B and C (mamba_n_groups 1 as published; "
+                "more are the layer's, layers/mamba2.py), mamba_conv_bias "
+                "true, no projection or attention bias, no position "
+                "encoding, a tied head, experts in every layer")
         self.embedding_multiplier = float(cfg.embedding_multiplier)
         self.residual_multiplier = float(cfg.residual_multiplier)
         self.logits_scaling = float(cfg.logits_scaling)
@@ -145,7 +148,8 @@ class GraniteMoeHybridForCausalLM:
                 x, cache = mamba2_mixer(
                     x, lp, cache, attn_metadata, positions,
                     num_heads=self.ssm_heads, head_dim=self.ssm_dim,
-                    d_state=self.ssm_state, eps=self.eps)
+                    d_state=self.ssm_state, eps=self.eps,
+                    groups=self.config.mamba_n_groups)
         else:
             x, cache = self._attention(lp, x, cache, attn_metadata)
         h = self._add(h, x)
@@ -180,14 +184,16 @@ class GraniteMoeHybridForCausalLM:
         """The param tree's structure (leaves: shapes)."""
         cfg = self.config
         e, d = self.hidden_size, self.head_dim
-        inner, n, hs = self.ssm_inner, self.ssm_state, self.ssm_heads
+        inner, hs = self.ssm_inner, self.ssm_heads
+        bc = cfg.mamba_n_groups * self.ssm_state     # B's (and C's) width
         layers = []
         for i in range(self.num_layers):
             layer = {"input_norm": (e, ), "post_norm": (e, )}
             if self.layer_kinds[i] == "state":
                 layer.update({
-                    "in_proj": (e, 2 * inner + 2 * n + hs),
-                    "conv_w": (4, inner + 2 * n), "conv_b": (inner + 2 * n, ),
+                    "in_proj": (e, 2 * inner + 2 * bc + hs),
+                    "conv_w": (4, inner + 2 * bc),
+                    "conv_b": (inner + 2 * bc, ),
                     "dt_bias": (hs, ), "A_log": (hs, ), "D": (hs, ),
                     "gate_norm": (inner, ), "out_proj": (inner, e)})
             else:

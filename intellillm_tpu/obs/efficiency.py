@@ -153,6 +153,14 @@ class _EfficiencyMetrics:
             "for the same rows and substeps (whole pages, by the "
             "kernel's own rule): over the context tokens, what the walk "
             "fetches beyond what the rows hold.", ["program"])
+        self.counter_dispatch_kv_walk_bytes = Counter(
+            "intellillm_dispatch_kv_walk_bytes_total",
+            "Bytes of paged K/V (or latent) cache the attention kernels' "
+            "walks moved: intellillm_dispatch_walked_tokens_total x the "
+            "bytes one token holds over every layer with a paged pool. "
+            "Beside intellillm_dispatch_state_bytes_total: which of a "
+            "hybrid's two caches a decode step's bytes go to.",
+            ["program"])
         self.counter_dispatch_attended_tokens = Counter(
             "intellillm_dispatch_attended_tokens_total",
             "Tokens a row attends to in ONE layer of a kind (layers: full "
@@ -293,6 +301,7 @@ class _EfficiencyMetrics:
             self.counter_dispatch_row_steps.labels(program)
             self.counter_dispatch_context_tokens.labels(program)
             self.counter_dispatch_walked_tokens.labels(program)
+            self.counter_dispatch_kv_walk_bytes.labels(program)
             for reach in ATTENTION_REACHES:
                 self.counter_dispatch_attended_tokens.labels(program, reach)
             for form in LINEAR_ATTN_FORMS:
@@ -539,6 +548,7 @@ class EfficiencyTracker:
                         row_steps: int = 0,
                         context_tokens: int = 0,
                         walked_tokens: int = 0,
+                        kv_walk_bytes: int = 0,
                         attended_tokens: Optional[Dict[str, int]] = None,
                         tile_rows: Optional[Dict[str, int]] = None,
                         linear_form: Optional[str] = None,
@@ -556,7 +566,9 @@ class EfficiencyTracker:
         summed over them. `walked_tokens`: what the kernel's page walk
         moves for those same row-substeps (`walk_extent` of the kernel
         module: whole pages; where the model has two cache groups, the
-        mean over its layers). `attended_tokens`: by ATTENTION_REACHES,
+        mean over its layers), and `kv_walk_bytes` the cache bytes that
+        is over all the layers with a paged pool. `attended_tokens`: by
+        ATTENTION_REACHES,
         what those row-substeps attend to in one layer of that kind.
         `tile_rows`: by RAGGED_TILES, a `mixed` step's live rows by the
         tile the ragged kernel ran them in. `linear_form` (of
@@ -618,6 +630,8 @@ class EfficiencyTracker:
                     context_tokens)
                 m.counter_dispatch_walked_tokens.labels(program).inc(
                     walked_tokens)
+                m.counter_dispatch_kv_walk_bytes.labels(program).inc(
+                    kv_walk_bytes)
                 for reach, n in (attended_tokens or {}).items():
                     m.counter_dispatch_attended_tokens.labels(
                         program, reach).inc(n)

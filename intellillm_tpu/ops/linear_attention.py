@@ -128,39 +128,46 @@ def kda_chunk_reference(q, k, v, g, beta, pool, slots, positions):
 # --- the selective state-space scan ------------------------------------------
 #
 # Per head h of `P` channels, with `H_t` a float32 matrix `[N, P]` (rows =
-# state channels), `dt_t` > 0 and `a` < 0 one number a head, `B_t`, `C_t`
-# in R^N shared by every head (one group):
+# state channels), `dt_t` > 0 and `a` < 0 one number a head, `B_t[g]`,
+# `C_t[g]` in R^N shared by the heads of group g (`G` groups; Mamba-2's
+# map: head h reads group `h // (H / G)`, a group's heads contiguous):
 #
-#     H_t = exp(dt_t a) H_{t-1} + B_t (dt_t x_t)^T
-#     y_t = H_t^T C_t
+#     H_t = exp(dt_t a) H_{t-1} + B_t[g] (dt_t x_t)^T
+#     y_t = H_t^T C_t[g]
 #
 # (the skip `D x_t` is the layer's). Nothing in it couples two channels of
 # `x`, so a sequence's state is one matrix `[N, heads x P]` whose columns
 # decay by their head's factor; the pool holds it with the columns cut
-# into lane tiles, `[slots + 1, tiles, N, lanes]` (`config.ssd_state_shape`).
+# into lane tiles, `[slots + 1, tiles, N, lanes]` (`config.ssd_state_shape`),
+# and lane tile t reads group `t // (tiles / G)` (a group's channels are
+# whole tiles).
 # The contract is `kda_chunk`'s / `kda_step`'s: a run of consecutive rows
 # of one slot continues one state, starts from zero at position 0 whatever
 # the slot holds, its last row writes the slot, nothing crosses runs, pad
 # rows point at the pool's last row.
 #
 # Arguments: `x` [T, H, P], `dt` [T, H] float32, `a` [H] float32, `b`, `c`
-# [T, N]. Outputs are float32 `[T, H, P]`.
+# [T, G, N] (or [T, N]: one group). Outputs are float32 `[T, H, P]`.
 
 
 def ssd_update(state, x, dt, a, b, c):
     """One token of the scan: `state` [..., tiles, N, lanes] float32, `x`
-    [..., H, P], `dt` [..., H], `a` [H], `b`, `c` [..., N]. Returns (y
-    [..., H, P], new state), float32."""
+    [..., H, P], `dt` [..., H], `a` [H], `b`, `c` [..., G, N] (or
+    [..., N]: one group). Returns (y [..., H, P], new state), float32."""
     f32 = jnp.float32
     p = x.shape[-1]
     lead, (tiles, _, lanes) = state.shape[:-3], state.shape[-3:]
+    if b.ndim == dt.ndim:
+        b, c = b[..., None, :], c[..., None, :]
     dt = dt.astype(f32)
     keep = jnp.repeat(jnp.exp(dt * a.astype(f32)), p, axis=-1)
     dtx = dt[..., None] * x.astype(f32)
     by_tile = lead + (tiles, 1, lanes)
-    new = (keep.reshape(by_tile) * state
-           + b.astype(f32)[..., None, :, None] * dtx.reshape(by_tile))
-    y = (c.astype(f32)[..., None, :, None] * new).sum(-2)
+    # [..., tiles, N, 1]: a group's column for each of its tiles in turn.
+    b_c, c_c = (jnp.repeat(v.astype(f32), tiles // b.shape[-2],
+                           axis=-2)[..., None] for v in (b, c))
+    new = keep.reshape(by_tile) * state + b_c * dtx.reshape(by_tile)
+    y = (c_c * new).sum(-2)
     return y.reshape(x.shape), new
 
 

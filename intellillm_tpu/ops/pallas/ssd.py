@@ -1,6 +1,6 @@
 """The selective state-space scan (Mamba-2: a scalar decay a head, no delta
-term, B and C shared by every head) on the state pool, in place: a step
-form and a chunk form.
+term, B and C shared by the heads of a group) on the state pool, in place:
+a step form and a chunk form.
 
 A sequence's state is one float32 matrix `[N, inner]` (`N` state channels
 on the sublanes, the layer's `inner = heads x P` channels along the lanes,
@@ -12,8 +12,10 @@ cut into `tiles` lane tiles of 128: `config.ssd_state_shape`). With
 
 Everything that is one number a channel (`keep`, `u`, `y`) is a row along
 the lanes, as the activations hold it; B and C are columns along the
-sublanes, the same for every tile; the contraction with C is a sublane sum
-(VPU adds and one 8-row reduce a tile). With the state a head as `[P, N]`
+sublanes, a pair a group, `[G N, 2]` a row: lane tile j reads the `N`
+sublanes of its group, `tile_groups(tiles, G)[j]` (a static map; with
+one group the same for every tile). The contraction with
+C is a sublane sum (VPU adds and one 8-row reduce a tile). With the state a head as `[P, N]`
 the same contraction would be a lane reduce a vreg, 1,024 of them a row.
 Nothing here runs on the MXU: a float32 product costs it three to six
 passes, more than the copy of the state that bounds the step form.
@@ -63,11 +65,14 @@ _START, _ZERO, _LAST = 1, 2, 4
 
 def ssd_supported(x: jnp.ndarray, b: jnp.ndarray, pool: jnp.ndarray) -> bool:
     """A float32 pool of whole 128-lane tiles that hold the layer's
-    channels, and whole sublane tiles of state channels and of rows."""
+    channels, whole tiles a group of B and C, and whole sublane tiles of
+    state channels and of rows."""
     t, h, p = x.shape
     tiles, n, lanes = pool.shape[1:]
+    groups = _groups(b)
     return (pool.dtype == _F32 and lanes == _LANES and n % 8 == 0
-            and tiles * lanes == h * p and b.shape[-1] == n and t % 8 == 0)
+            and tiles * lanes == h * p and b.shape[-1] == n and t % 8 == 0
+            and tiles % groups == 0)
 
 
 def _by_channel(x, dt, a):
@@ -79,9 +84,32 @@ def _by_channel(x, dt, a):
     return keep, u
 
 
+def _groups(b) -> int:
+    """`b` (or `c`) is [T, G, N], or [T, N] for one group."""
+    return b.shape[1] if b.ndim == 3 else 1
+
+
 def _columns(b, c):
-    """[T, N, 2]: B and C as columns along the sublanes."""
-    return jnp.stack([b.astype(_F32), c.astype(_F32)], axis=-1)
+    """([T, G N, 2], G): B and C as columns along the sublanes, group after
+    group."""
+    groups = _groups(b)
+    cols = jnp.stack([b.astype(_F32), c.astype(_F32)], axis=-1)
+    return cols.reshape(b.shape[0], groups * b.shape[-1], 2), groups
+
+
+def tile_groups(tiles: int, groups: int):
+    """The group of B and C each lane tile reads: a group's heads are
+    contiguous, so its channels are `tiles / groups` tiles in a row."""
+    return tuple(t // (tiles // groups) for t in range(tiles))
+
+
+def _tile_columns(cols_ref, row, tiles: int, groups: int):
+    """Per lane tile, (B column, C column) [N, 1] of the tile's group in
+    row `row` of `cols_ref` [rows, G N, 2]: one pair a group, loaded once."""
+    n = cols_ref.shape[1] // groups
+    pairs = [(cols_ref[row, g * n:(g + 1) * n, 0:1],
+              cols_ref[row, g * n:(g + 1) * n, 1:2]) for g in range(groups)]
+    return [pairs[g] for g in tile_groups(tiles, groups)]
 
 
 def _update(keep, u, b_c, c_c, state):
@@ -94,10 +122,10 @@ def _update(keep, u, b_c, c_c, state):
 # --- the step form -----------------------------------------------------------
 
 def _ssd_step_kernel(slots_ref, keep_ref, u_ref, cols_ref, s_ref, y_ref,
-                     s_out_ref, *, tiles: int):
+                     s_out_ref, *, tiles: int, groups: int):
     del slots_ref
-    b_c, c_c = cols_ref[0, :, 0:1], cols_ref[0, :, 1:2]
-    for j in range(tiles):
+    for j, (b_c, c_c) in enumerate(_tile_columns(cols_ref, 0, tiles,
+                                                 groups)):
         y, new = _update(keep_ref[0, j:j + 1, :], u_ref[0, j:j + 1, :], b_c,
                          c_c, s_ref[0, j])
         s_out_ref[0, j] = new
@@ -109,14 +137,16 @@ def _ssd_step_call(x, dt, a, b, c, pool, slots):
     rows, h, p = x.shape
     tiles, n, lanes = pool.shape[1:]
     keep, u = (v.reshape(rows, tiles, lanes) for v in _by_channel(x, dt, a))
+    cols, groups = _columns(b, c)
     state = pl.BlockSpec((1, tiles, n, lanes), lambda i, s: (s[i], 0, 0, 0))
     row = pl.BlockSpec((1, tiles, lanes), lambda i, s: (i, 0, 0))
     y, pool = pl.pallas_call(
-        functools.partial(_ssd_step_kernel, tiles=tiles),
+        functools.partial(_ssd_step_kernel, tiles=tiles, groups=groups),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(rows, ),
             in_specs=[row, row,
-                      pl.BlockSpec((1, n, 2), lambda i, s: (i, 0, 0)),
+                      pl.BlockSpec((1, groups * n, 2),
+                                   lambda i, s: (i, 0, 0)),
                       state],
             out_specs=(row, state)),
         out_shape=(jax.ShapeDtypeStruct((rows, tiles, lanes), _F32),
@@ -126,7 +156,7 @@ def _ssd_step_call(x, dt, a, b, c, pool, slots):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", ),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-    )(slots.astype(jnp.int32), keep, u, _columns(b, c), pool)
+    )(slots.astype(jnp.int32), keep, u, cols, pool)
     return y.reshape(rows, h, p), pool
 
 
@@ -139,7 +169,7 @@ def ssd_step(x, dt, a, b, c, pool, slots):
 
 def _ssd_chunk_kernel(slots_ref, flags_ref, keep_ref, u_ref, cols_ref,
                       pool_in, y_ref, pool_out, z_ref, sem, *, tiles: int,
-                      pad: int, rows: int):
+                      groups: int, pad: int, rows: int):
     row0 = pl.program_id(0) * rows
     y_ref[...] = jnp.zeros_like(y_ref)
 
@@ -158,8 +188,8 @@ def _ssd_chunk_kernel(slots_ref, flags_ref, keep_ref, u_ref, cols_ref,
                 read.start()
                 read.wait()
 
-            b_c, c_c = cols_ref[t, :, 0:1], cols_ref[t, :, 1:2]
-            for j in range(tiles):
+            for j, (b_c, c_c) in enumerate(_tile_columns(cols_ref, t, tiles,
+                                                         groups)):
                 y, new = _update(keep_ref[t, j:j + 1, :],
                                  u_ref[t, j:j + 1, :], b_c, c_c, z_ref[j])
                 z_ref[j] = new
@@ -185,6 +215,7 @@ def _ssd_chunk_call(x, dt, a, b, c, pool, slots, positions, continues, last):
     rows = next(r for r in range(min(t, ROWS), 0, -8) if t % r == 0)
     i32 = jnp.int32
     keep, u = (v.reshape(t, tiles, lanes) for v in _by_channel(x, dt, a))
+    cols, groups = _columns(b, c)
     flags = ((~continues).astype(i32) * _START
              + (positions == 0).astype(i32) * _ZERO
              + last.astype(i32) * _LAST)
@@ -193,12 +224,13 @@ def _ssd_chunk_call(x, dt, a, b, c, pool, slots, positions, continues, last):
     block = pl.BlockSpec((rows, tiles, lanes), lambda i, *_: (i, 0, 0))
     any_space = pl.BlockSpec(memory_space=pl.ANY)
     y, pool = pl.pallas_call(
-        functools.partial(_ssd_chunk_kernel, tiles=tiles, pad=pad,
-                          rows=rows),
+        functools.partial(_ssd_chunk_kernel, tiles=tiles, groups=groups,
+                          pad=pad, rows=rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(t // rows, ),
             in_specs=[block, block,
-                      pl.BlockSpec((rows, n, 2), lambda i, *_: (i, 0, 0)),
+                      pl.BlockSpec((rows, groups * n, 2),
+                                   lambda i, *_: (i, 0, 0)),
                       any_space],
             out_specs=(block, any_space),
             scratch_shapes=[pltpu.VMEM((tiles, n, lanes), _F32),
@@ -210,7 +242,7 @@ def _ssd_chunk_call(x, dt, a, b, c, pool, slots, positions, continues, last):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", ),
             has_side_effects=True, vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-    )(slots.astype(i32), flags, keep, u, _columns(b, c), pool)
+    )(slots.astype(i32), flags, keep, u, cols, pool)
     return y.reshape(t, h, p), pool
 
 

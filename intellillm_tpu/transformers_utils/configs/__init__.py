@@ -12,6 +12,8 @@ from intellillm_tpu.transformers_utils.configs.baichuan import BaichuanConfig
 from intellillm_tpu.transformers_utils.configs.chatglm import ChatGLMConfig
 from intellillm_tpu.transformers_utils.configs.decilm import DeciLMConfig
 from intellillm_tpu.transformers_utils.configs.deepseek import DeepseekConfig
+from intellillm_tpu.transformers_utils.configs.falcon_h1 import (
+    FalconH1Config)
 from intellillm_tpu.transformers_utils.configs.granitemoehybrid import (
     GraniteMoeHybridConfig)
 from intellillm_tpu.transformers_utils.configs.internlm import InternLMConfig
@@ -31,6 +33,7 @@ _CONFIG_REGISTRY = {
     "chatglm": ChatGLMConfig,
     "deci": DeciLMConfig,
     "deepseek": DeepseekConfig,
+    "falcon_h1": FalconH1Config,
     "granitemoehybrid": GraniteMoeHybridConfig,
     "internlm": InternLMConfig,
     "lfm2_moe": Lfm2MoeConfig,
@@ -44,7 +47,7 @@ _CONFIG_REGISTRY = {
 
 __all__ = [
     "AquilaConfig", "AXK1Config", "BaichuanConfig", "ChatGLMConfig", "DeciLMConfig",
-    "DeepseekConfig", "GraniteMoeHybridConfig", "InternLMConfig",
+    "DeepseekConfig", "FalconH1Config", "GraniteMoeHybridConfig", "InternLMConfig",
     "Lfm2MoeConfig", "QWenConfig",
     "SmallThinkerConfig", "SolarOpen2Config", "Xing4Config", "YiConfig",
     "_CONFIG_REGISTRY",

@@ -36,6 +36,8 @@ TPU redesign:
   Block ops touch the "kv" layers, the state ops (`swap_state_out`,
   `swap_state_in`, `copy_state`) the "state" layers, by slot. Models
   whose layers are all "kv" have no state pool and run no state op.
+  A model layer that holds BOTH (a scan beside its attention) is two
+  entries of the list, "kv" then "state", and so is moved by both.
 - Swaps are jax device↔host transfers (no CUDA streams/events; JAX's async
   dispatch overlaps them with compute until the arrays are consumed).
 - Copies (CoW) are fused gather/scatter updates executed functionally; the
@@ -73,7 +75,8 @@ class CacheEngine:
         self.model_config = model_config
         self.parallel_config = parallel_config
 
-        # One entry a layer: "kv" (paged pools) or "state" (slots).
+        # One entry a cache: "kv" (paged pools) or "state" (slots); a layer
+        # with both is two entries.
         self.layer_kinds = model_config.get_layer_cache_kinds()
         self.num_layers = len(self.layer_kinds)
         self.kv_layers = [i for i, kind in enumerate(self.layer_kinds)

@@ -55,7 +55,8 @@ from intellillm_tpu.ops.state_rows import gather_rows, scatter_rows
 from intellillm_tpu.sampling_params import SamplingParams, SamplingType
 from intellillm_tpu.sequence import (SamplerOutput, SequenceGroupMetadata,
                                      SequenceGroupOutput, SequenceOutput)
-from intellillm_tpu.utils import default_len_buckets, pad_to_bucket
+from intellillm_tpu.utils import (STR_DTYPE_TO_JNP, default_len_buckets,
+                                  pad_to_bucket)
 
 logger = init_logger(__name__)
 
@@ -299,6 +300,17 @@ class ModelRunner:
                 for (shape, dtype), in_place in zip(state_arrays,
                                                     self._state_in_place)
                 if in_place))
+        # Bytes of K/V (or latent) one token holds over every layer with a
+        # paged pool, either group: what a walked token moves.
+        pools, kv_heads, kv_width = model_config.get_cache_row_shape()
+        cache_dtype = (model_config.dtype
+                       if cache_config.cache_dtype == "auto"
+                       else cache_config.cache_dtype)
+        self._kv_token_bytes = (
+            pools * kv_heads * kv_width
+            * jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]).itemsize
+            * (model_config.get_num_kv_layers()
+               + model_config.get_num_window_layers()))
         # A model with a multi-stream residual (layers/
         # hyper_connections.py): the streams' bytes a row moves through
         # all its sublayers; 0 for every other model.
@@ -462,14 +474,18 @@ class ModelRunner:
         return {"run_starts": self._place_batch_array(
             _padded_run_starts(run_starts, padded_n))}
 
-    def _row_step_counts(self, staged: bool, row_steps: int):
+    def _row_step_counts(self, staged: bool, row_steps: int,
+                         walked_tokens: int):
         """`record_dispatch`'s account of what `row_steps` live
-        row-substeps moved besides the cache: a matrix state's recurrence
+        row-substeps moved: the K/V pages their attention walked
+        (`walked_tokens` x a token's bytes over the layers with a paged
+        pool) and, besides the cache, a matrix state's recurrence
         (a delta rule, a state-space scan; nothing for a model none of
         whose state arrays is updated in its pool:
         `ModelConfig.state_array_in_place`) and a multi-stream residual's
         mixes (nothing for a model without one)."""
-        counts = {}
+        counts = dict(walked_tokens=walked_tokens,
+                      kv_walk_bytes=walked_tokens * self._kv_token_bytes)
         if self._matrix_state_bytes:
             counts.update(
                 linear_form="step" if staged else "chunk",
@@ -1421,10 +1437,9 @@ class ModelRunner:
             row_steps=real_rows * num_steps,
             context_tokens=(int(eff_info["ctxs"].sum()) * num_steps
                             + real_rows * num_steps * (num_steps - 1) // 2),
-            walked_tokens=walked, attended_tokens=attended,
-            tile_rows=tile_rows,
-            **self._row_step_counts(num_steps > 1,
-                                       real_rows * num_steps))
+            attended_tokens=attended, tile_rows=tile_rows,
+            **self._row_step_counts(num_steps > 1, real_rows * num_steps,
+                                    walked))
         self._record_moe_ffn_rows(padded_n, num_steps)
 
         # ONE device→host transfer for everything, performed by
@@ -1688,12 +1703,12 @@ class ModelRunner:
         walkers, tile_rows = self._mixed_walkers(run_starts, padded_n, w)
         dispatch = dict(program="mixed", device_idle=device_idle,
                         row_steps=len(rows), context_tokens=sum(ctxs),
-                        walked_tokens=self._walked_tokens(
-                            ctx_arr - 1, ctx_arr, w, walkers),
                         attended_tokens=self._attended_tokens(
                             ctx_arr, int(ctx_arr.sum())),
                         tile_rows=tile_rows,
-                        **self._row_step_counts(False, len(rows)))
+                        **self._row_step_counts(
+                            False, len(rows), self._walked_tokens(
+                                ctx_arr - 1, ctx_arr, w, walkers)))
         if n_chunk_groups:
             self._efficiency.record_dispatch(
                 "prefill", n_chunk_groups, n_chunk_groups,
@@ -1825,8 +1840,8 @@ class ModelRunner:
             row_steps=live_rows * num_steps,
             context_tokens=(int(ctx.sum()) * num_steps
                             + live_rows * num_steps * (num_steps - 1) // 2),
-            walked_tokens=walked, attended_tokens=attended,
-            **self._row_step_counts(True, live_rows * num_steps))
+            attended_tokens=attended,
+            **self._row_step_counts(True, live_rows * num_steps, walked))
         self._record_moe_ffn_rows(b, num_steps)
 
         step = InflightStep(self, packed, cont.metas, cont.rows, num_steps,

@@ -982,8 +982,10 @@ def _matrix_state_program(one_chip, tmp_path, program, config_name,
                 *CacheEngine.state_pool_shape(shape, CELL_ROWS))
             for shape, dtype in model_config.get_state_arrays())
         assert [s.shape for s in state] == state_shapes
+        pools, kv_heads, width = model_config.get_cache_row_shape()
         kv = [state if kind == "state" else
-              (sds(bf16, CELL_BLOCKS, 8, 16, 128), ) * 2 for kind in kinds]
+              (sds(bf16, CELL_BLOCKS, kv_heads, 16, width), ) * pools
+              for kind in kinds]
         assert not jax.config.jax_enable_compilation_cache
         if program == "mixed":
             return runner.lower_mixed_step(
@@ -1052,6 +1054,32 @@ def test_state_space_model_programs_compile(compiled_for_chip, one_chip,
     assert _row_loops(text, "bf16[128,25344]") == []
     pool_bytes = 129 * 64 * 128 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+@pytest.mark.skipif(not os.environ.get("FALCON_H1_COMPILE"),
+                    reason="35 s a program: run by hand with "
+                    "FALCON_H1_COMPILE=1 (tier-1 has no time for it)")
+@pytest.mark.parametrize("program", ["mixed", "decode_cont"])
+def test_parallel_hybrid_model_programs_compile(compiled_for_chip, one_chip,
+                                                tmp_path, program):
+    """`falcon-h1-34b-l6`'s programs as its cell dispatches them (as above;
+    depth cut to two layers, each with BOTH caches; the whole 261,120-row
+    head) compile for the described v5e, hold the scan's kernel of that
+    program at two groups of B and C over 256 state channels beside the
+    paged attention's, and carry the 541 MB matrix pools through the K
+    substeps without a copy of one."""
+    compiled = _matrix_state_program(
+        one_chip, tmp_path, program, "falcon-h1-34b-l6",
+        {"num_hidden_layers": 2}, ["kv", "state"] * 2,
+        [(128, 15360), (129, 32, 256, 128)])
+    kernel = "_ssd_chunk_call" if program == "mixed" else "_ssd_step_call"
+    attn = ("_ragged" if program == "mixed" else "_paged_attention_call")
+    text = compiled.as_text()
+    assert kernel in text and "_conv_silu_call" in text and attn in text
+    pool_bytes = 129 * 32 * 256 * 128 * 4
+    memory = compiled.memory_analysis()
+    print(program, "temporaries", memory.temp_size_in_bytes / 1e9, "GB")
+    assert memory.temp_size_in_bytes < 2 * pool_bytes
 
 
 # --- window and full layers: two cache groups a step ------------------------
