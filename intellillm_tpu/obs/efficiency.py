@@ -82,6 +82,8 @@ WINDOW_BLOCK_EVENTS = ("recycled", )
 RAGGED_TILES = ("one", "many")
 # The form of a linear-attention layer's delta rule a row went through.
 LINEAR_ATTN_FORMS = ("chunk", "step")
+# Rows x substeps of a dispatched program by what the head did with them.
+HEAD_ROW_KINDS = ("batch", "computed", "emitting")
 AXES = ("batch", "len", "block_width")
 _DEFAULT_MFU_WINDOW = 64
 _FILL_BUCKETS = (0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9,
@@ -176,6 +178,17 @@ class _EfficiencyMetrics:
             "of one prompt chunk that walked it once together). "
             "many / (one + many) is how often the tiling engages.",
             ["tile"])
+        self.counter_head_rows = Counter(
+            "intellillm_head_rows_total",
+            "Rows x substeps of the dispatched programs by what the head "
+            "(final projection onto the vocabulary) and the sampler did "
+            "with them (kind: batch = rows the program held, pad rows "
+            "included; computed = rows whose logits were computed: all "
+            "of them, or the rows that emit made up to a bucket, or none "
+            "in a step no row of which emits; emitting = rows whose "
+            "token is a result). On program=\"mixed\", 1 - computed / "
+            "batch is the share of the head's rows a prompt step did "
+            "not compute.", ["program", "kind"])
         self.counter_linear_attn_rows = Counter(
             "intellillm_linear_attn_rows_total",
             "Live rows x substeps of the dispatched programs that went "
@@ -306,6 +319,8 @@ class _EfficiencyMetrics:
                 self.counter_dispatch_attended_tokens.labels(program, reach)
             for form in LINEAR_ATTN_FORMS:
                 self.counter_linear_attn_rows.labels(program, form)
+            for kind in HEAD_ROW_KINDS:
+                self.counter_head_rows.labels(program, kind)
             self.counter_dispatch_state_bytes.labels(program)
             self.counter_dispatch_stream_mix_bytes.labels(program)
         self.gauge_mhc_col_error_max.set(0)
@@ -434,6 +449,9 @@ class EfficiencyTracker:
             for program in PROGRAMS}
         self._mixed_tile_rows: Dict[str, int] = dict.fromkeys(
             RAGGED_TILES, 0)
+        self._head_rows: Dict[str, Dict[str, int]] = {
+            program: dict.fromkeys(HEAD_ROW_KINDS, 0)
+            for program in PROGRAMS}
         # A multi-stream residual's bytes (record_dispatch) and the
         # largest column error of its matrices (record_mhc_col_error).
         self._mhc: Dict[str, float] = {"stream_mix_bytes": 0,
@@ -551,6 +569,7 @@ class EfficiencyTracker:
                         kv_walk_bytes: int = 0,
                         attended_tokens: Optional[Dict[str, int]] = None,
                         tile_rows: Optional[Dict[str, int]] = None,
+                        head_rows: Optional[Dict[str, int]] = None,
                         linear_form: Optional[str] = None,
                         state_bytes: int = 0,
                         stream_mix_bytes: int = 0) -> None:
@@ -571,7 +590,10 @@ class EfficiencyTracker:
         ATTENTION_REACHES,
         what those row-substeps attend to in one layer of that kind.
         `tile_rows`: by RAGGED_TILES, a `mixed` step's live rows by the
-        tile the ragged kernel ran them in. `linear_form` (of
+        tile the ragged kernel ran them in. `head_rows`: by
+        HEAD_ROW_KINDS, the program's rows x substeps (pad rows
+        included), those the head computed logits of, and those whose
+        token is a result. `linear_form` (of
         LINEAR_ATTN_FORMS; None for a model without a matrix state
         updated in its pool): the form of the recurrence the
         row-substeps went through, and `state_bytes` the matrix state
@@ -620,6 +642,8 @@ class EfficiencyTracker:
                 counts["walked_tokens"] += int(walked_tokens)
                 for tile, n in (tile_rows or {}).items():
                     self._mixed_tile_rows[tile] += int(n)
+                for kind, n in (head_rows or {}).items():
+                    self._head_rows[program][kind] += int(n)
                 self._mhc["stream_mix_bytes"] += int(stream_mix_bytes)
         if self._metrics is not None:
             m = self._metrics
@@ -637,6 +661,8 @@ class EfficiencyTracker:
                         program, reach).inc(n)
                 for tile, n in (tile_rows or {}).items():
                     m.counter_mixed_tile_rows.labels(tile).inc(n)
+                for kind, n in (head_rows or {}).items():
+                    m.counter_head_rows.labels(program, kind).inc(n)
                 if linear_form is not None:
                     m.counter_linear_attn_rows.labels(
                         program, linear_form).inc(row_steps)
@@ -875,6 +901,8 @@ class EfficiencyTracker:
                     program: dict(counts) for program, counts
                     in self._moe_expert_visits.items()},
                 "mixed_tile_rows": dict(self._mixed_tile_rows),
+                "head_rows": {program: dict(counts) for program, counts
+                              in self._head_rows.items()},
                 "mhc": dict(self._mhc),
                 "pipeline_drains": dict(self._pipeline_drains),
                 "co_batched_steps": self._co_batched_steps,

@@ -416,10 +416,15 @@ class ModelRunner:
                     self.kernel_selection)
 
     def lower_mixed_step(self, kv_struct, b: int, w: int, *,
-                         logprob_k: int = 1, do_random: bool = True):
+                         logprob_k: int = 1, do_random: bool = True,
+                         emit_slots: Optional[int] = None):
         """The `mixed` program lowered from shapes alone — b rows, w-block
         tables, `kv_struct` a pytree of cache ShapeDtypeStructs — for
-        what only a compile can say (temp bytes, compiled text)."""
+        what only a compile can say (temp bytes, compiled text). The
+        head over all b rows, which is what a step may need (every row
+        a decode row, a `prompt_logprobs` panel) and so what the memory
+        profile reserves; `emit_slots`: the form that runs it on so
+        many emitting rows (`_decode_fn_single` `emit_rows`)."""
         def sds(dtype, *shape):
             return jax.ShapeDtypeStruct(shape, dtype)
 
@@ -433,7 +438,8 @@ class ModelRunner:
             do_random=do_random, run_starts=sds(i32, b),
             **({"state_slots": sds(i32, b)} if self.stateful else {}),
             **({"window_tables": sds(i32, b, w)} if self.window_group
-               else {}))
+               else {}),
+            **({"emit_rows": sds(i32, emit_slots)} if emit_slots else {}))
 
     def window_table_kwargs(self, tables, rows, padded_n: int,
                             w: int) -> Dict[str, Any]:
@@ -530,18 +536,27 @@ class ModelRunner:
     # --- packing helpers --------------------------------------------------
 
     @staticmethod
-    def _pack(sampled, sampled_lp, topk_ids, topk_lp, counters=None,
-              live=None, substeps=1):
+    def _pack_samples(sampled, sampled_lp, topk_ids, topk_lp):
         """[B,T1] i32, [B,T1] f32, [B,T2,Kt] i32, [B,T2,Kt] f32 →
         single [B, 2*T1 + 2*T2*Kt] int32 for a 1-fetch D2H.
 
         Packed as INT (floats bitcast to their bit patterns): small ints
         bitcast to f32 are denormals, which TPU ops flush to zero — the
-        reverse direction is safe.
+        reverse direction is safe."""
+        b = sampled.shape[0]
+        return jnp.concatenate([
+            sampled,
+            jax.lax.bitcast_convert_type(sampled_lp, jnp.int32),
+            topk_ids.reshape(b, -1),
+            jax.lax.bitcast_convert_type(topk_lp, jnp.int32).reshape(b, -1),
+        ], axis=-1)
 
-        A model's `counters` (`_call_model`), summed over layers and
-        substeps, append columns after everything `_unpack` reads, so
-        they ride this same fetch. "held" [B] (an expert-parallel share:
+    @staticmethod
+    def _pack_counters(samples, counters=None, live=None, substeps=1):
+        """`samples` (`_pack_samples`, a row a batch row) with a model's
+        `counters` (`_call_model`), summed over layers and substeps, as
+        columns after everything `_unpack` reads, so that they ride the
+        same fetch. "held" [B] (an expert-parallel share:
         a row's expert choices held on this chip): the held choices of
         live rows and the substeps of live rows (0 for pad rows), for
         intellillm_moe_assignments_total. Then "expert_visits" [2] (held
@@ -553,13 +568,8 @@ class ModelRunner:
         rows' largest, bitcast into row 0 of one column, for
         intellillm_mhc_col_error_max.
         """
-        b = sampled.shape[0]
-        parts = [
-            sampled,
-            jax.lax.bitcast_convert_type(sampled_lp, jnp.int32),
-            topk_ids.reshape(b, -1),
-            jax.lax.bitcast_convert_type(topk_lp, jnp.int32).reshape(b, -1),
-        ]
+        b = samples.shape[0]
+        parts = [samples]
         counters = counters or {}
         if "held" in counters:
             parts += [jnp.where(live, counters["held"], 0)[:, None],
@@ -572,6 +582,14 @@ class ModelRunner:
             parts.append(jnp.zeros((b, 1), jnp.int32).at[0, 0].set(
                 jax.lax.bitcast_convert_type(worst, jnp.int32)))
         return jnp.concatenate(parts, axis=-1)
+
+    @classmethod
+    def _pack(cls, sampled, sampled_lp, topk_ids, topk_lp, counters=None,
+              live=None, substeps=1):
+        """`_pack_samples` of a step's results, then `_pack_counters`."""
+        return cls._pack_counters(
+            cls._pack_samples(sampled, sampled_lp, topk_ids, topk_lp),
+            counters, live, substeps)
 
     @staticmethod
     def _unpack(packed: np.ndarray, t1: int, t2: int, kt: int):
@@ -635,7 +653,10 @@ class ModelRunner:
         do_numerics/numerics_inject: the in-graph sentinels
         (obs/numerics.py). When enabled the call additionally returns a
         [B, 5] float32 panel (NaN count, +Inf count, finite max-abs,
-        top-1 prob, entropy) of the FINAL sampling logits;
+        top-1 prob, entropy) of the FINAL sampling logits, so of the
+        rows this call was handed: a `mixed` step that runs the head on
+        its emitting rows (`_head_on_rows`) watches those, the rows
+        `InflightStep` reads the panel at;
         numerics_inject is the forced-corruption testing hook — an
         additive [B] row vector (zeros, or NaN on a poisoned row)."""
         lora_vocab = lora is not None and "vocab" in lora
@@ -964,7 +985,8 @@ class ModelRunner:
                           prompt_tokens, output_tokens, lora=None,
                           fetch_indices=None, plp_targets=None,
                           numerics_inject=None, state_slots=None,
-                          window_tables=None, run_starts=None, *,
+                          window_tables=None, run_starts=None,
+                          emit_rows=None, *,
                           num_samples=1, plp_k=0, do_numerics=False,
                           logprob_k, do_topk, do_topp, do_minp,
                           do_penalties, do_random=True):
@@ -976,12 +998,20 @@ class ModelRunner:
         offset in either). `run_starts` [B]: 0 for a row that continues
         the row before it (`AttentionMetadata.run_starts`).
 
+        `emit_rows` [E] (E < B): the batch rows that emit a token, made
+        up to E with B. The head and the sampler then run on those rows'
+        hidden states alone, with their rows of the sampling tensors, and
+        not at all in a step none of whose rows emits; every other row
+        of the packed output's sample columns is zeros (`_head_on_rows`;
+        `fetch_indices` then index `emit_rows`). None: every row goes
+        through the head, as a step needs whose rows all emit or that
+        carries a prompt-logprob panel (`plp_k`).
+
         num_samples > 1 serves final-chunk `best_of` fan-out (every row
         draws num_samples gumbel streams; a row's sample 0 is bit-equal
         to its num_samples=1 draw, so co-batched decode rows are
         unaffected). plp_k > 0 adds the per-row prompt-logprob panel."""
         bs = self.block_size
-        b = token_ids.shape[0]
         pos = positions[:, 0]
 
         def slots_of(tables, pool):
@@ -1009,19 +1039,36 @@ class ModelRunner:
         )
         hidden, new_caches, counters = self._call_model(
             params, token_ids, pos[:, None], kv_caches, meta, lora)
-        (sampled, lp, tk_ids, tk_lp, fetched, plp_out,
-         num_stats) = self._compute_logits_and_sample(
-            params, hidden[:, 0], temperatures, top_ks, top_ps, min_ps,
-            seeds, pres_pen, freq_pen, rep_pen, prompt_tokens, output_tokens,
-            lora, num_samples=num_samples, logprob_k=logprob_k,
-            do_topk=do_topk, do_topp=do_topp, do_minp=do_minp,
-            do_penalties=do_penalties, do_random=do_random,
-            fetch_indices=fetch_indices, plp_targets=plp_targets,
-            plp_k=plp_k, do_numerics=do_numerics,
-            numerics_inject=numerics_inject)
-        packed = self._pack(sampled, lp, tk_ids[:, None, :],
-                            tk_lp[:, None, :],
-                            counters=counters, live=context_lens > 0)
+
+        def head(rows, sampling, lora, inject):
+            """The head and the sampler over `rows` [N, H] of hidden
+            states: (packed samples [N, ...], raw logits of
+            `fetch_indices`, prompt-logprob panel, numerics panel)."""
+            (sampled, lp, tk_ids, tk_lp, fetched, plp_out,
+             num_stats) = self._compute_logits_and_sample(
+                params, rows, *sampling, lora,
+                num_samples=num_samples, logprob_k=logprob_k,
+                do_topk=do_topk, do_topp=do_topp, do_minp=do_minp,
+                do_penalties=do_penalties, do_random=do_random,
+                fetch_indices=fetch_indices, plp_targets=plp_targets,
+                plp_k=plp_k, do_numerics=do_numerics,
+                numerics_inject=inject)
+            samples = self._pack_samples(sampled, lp, tk_ids[:, None, :],
+                                         tk_lp[:, None, :])
+            return samples, fetched, plp_out, num_stats
+
+        sampling = (temperatures, top_ks, top_ps, min_ps, seeds, pres_pen,
+                    freq_pen, rep_pen, prompt_tokens, output_tokens)
+        if emit_rows is None:
+            samples, fetched, plp_out, num_stats = head(
+                hidden[:, 0], sampling, lora, numerics_inject)
+        else:
+            assert not plp_k, "a prompt-logprob panel is of every row"
+            samples, fetched, plp_out, num_stats = self._head_on_rows(
+                head, emit_rows, hidden[:, 0], sampling, lora,
+                numerics_inject)
+        packed = self._pack_counters(samples, counters,
+                                     live=context_lens > 0)
         extras = ()
         if plp_out is not None:
             extras += (plp_out, )
@@ -1030,6 +1077,45 @@ class ModelRunner:
         if num_stats is not None:
             extras += (num_stats, )
         return (packed, ) + extras + (new_caches, )
+
+    @staticmethod
+    def _head_on_rows(head, emit_rows, hidden_rows, sampling, lora, inject):
+        """`head` (of `_decode_fn_single`) over the rows `emit_rows` [E]
+        names of a batch of B, each with its own rows of the per-row
+        arguments, and its results back in their rows of a batch of B:
+        zeros in every other row. An entry of `emit_rows` that is B names
+        no row; where none names one, `head` does not run (the other
+        side of a conditional: its weights are not read) and every row
+        is zeros. A row's logits come from the same function of the same
+        hidden row as in a call over all B rows, and its draw is keyed by
+        its own seed, not by its place (`layers/sampler.py`
+        `row_gumbel`)."""
+        b = hidden_rows.shape[0]
+        at = jnp.minimum(emit_rows, b - 1)
+
+        def of_rows(a):
+            return None if a is None else a[at]
+
+        def run():
+            return head(
+                hidden_rows[at], tuple(of_rows(a) for a in sampling),
+                None if lora is None else
+                {**lora, "row_slots": lora["row_slots"][at]},
+                of_rows(inject))
+
+        def skip():
+            return jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                                jax.eval_shape(run))
+
+        samples, fetched, plp_out, num_stats = jax.lax.cond(
+            jnp.any(emit_rows < b), run, skip)
+
+        def in_batch(rows):
+            return None if rows is None else scatter_rows(
+                jnp.zeros((b, ) + rows.shape[1:], rows.dtype), emit_rows,
+                rows)
+
+        return in_batch(samples), fetched, plp_out, in_batch(num_stats)
 
     # --- batch prep -------------------------------------------------------
 
@@ -1438,6 +1524,9 @@ class ModelRunner:
             context_tokens=(int(eff_info["ctxs"].sum()) * num_steps
                             + real_rows * num_steps * (num_steps - 1) // 2),
             attended_tokens=attended, tile_rows=tile_rows,
+            head_rows=dict(batch=padded_n * num_steps,
+                           computed=padded_n * num_steps,
+                           emitting=real_rows * num_steps),
             **self._row_step_counts(num_steps > 1, real_rows * num_steps,
                                     walked))
         self._record_moe_ffn_rows(padded_n, num_steps)
@@ -1619,6 +1708,22 @@ class ModelRunner:
                 for row, _, _, tgt, _ in plp_jobs:
                     plp_targets[row] = tgt
 
+            padded_n = pad_to_bucket(len(rows), self.mixed_token_buckets)
+            # The head runs on the rows that emit, made up to a bucket of
+            # their own, where that is fewer rows than the step holds; a
+            # prompt-logprob panel is of every row.
+            emit_slots = pad_to_bucket(len(emit_idx),
+                                       self.mixed_token_buckets)
+            head_kwargs = {}
+            if emit_slots < padded_n and not plp_k:
+                emit_arr = np.full(emit_slots, padded_n, np.int32)
+                emit_arr[:len(emit_idx)] = emit_idx
+                head_kwargs = {"emit_rows": jnp.asarray(emit_arr)}
+            head_rows = dict(
+                batch=padded_n, emitting=len(emit_idx),
+                computed=(padded_n if not head_kwargs
+                          else emit_slots if emit_idx else 0))
+
             # logits_processors escape: only emitting rows matter (the
             # panel is pre-penalty, mid-chunk samples are discarded).
             proc_rows = [i for i in emit_idx
@@ -1627,9 +1732,12 @@ class ModelRunner:
             if proc_rows:
                 m = pad_to_bucket(len(proc_rows), self.mixed_token_buckets)
                 fetch_indices = np.zeros(m, np.int32)
-                fetch_indices[:len(proc_rows)] = proc_rows
+                # They index the rows the head runs on.
+                slot_of = {row: slot for slot, row in enumerate(emit_idx)}
+                fetch_indices[:len(proc_rows)] = (
+                    [slot_of[i] for i in proc_rows] if head_kwargs
+                    else proc_rows)
 
-            padded_n = pad_to_bucket(len(rows), self.mixed_token_buckets)
             w = pad_to_bucket(max(max(len(t) for t in tables),
                                   _MIN_BLOCK_TABLE_WIDTH),
                               self.mixed_token_buckets)
@@ -1671,6 +1779,8 @@ class ModelRunner:
                   tuple(sorted(common.items())))
         if num_on:
             bucket = bucket + ("numerics", )
+        if head_kwargs:
+            bucket = bucket + (("emit_rows", emit_slots), )
         device_idle = self._tracer.device_dry()
         with self._tracer.span("execute"):
             result = self._guarded_call(
@@ -1682,8 +1792,11 @@ class ModelRunner:
                 place(fetch_indices) if fetch_indices is not None else None,
                 place(plp_targets) if plp_k else None,
                 num_samples=num_samples, plp_k=plp_k, **common,
-                **numerics_kwargs, **state_kwargs)
-            self._tracer.note_dispatch("mixed", result[0], bucket)
+                **numerics_kwargs, **state_kwargs, **head_kwargs)
+            # (A step whose head does not run is the shorter for it.)
+            self._tracer.note_dispatch(
+                "mixed", result[0],
+                bucket + (bool(emit_idx), ) if head_kwargs else bucket)
         result = list(result)
         packed = result.pop(0)
         plp_dev = result.pop(0) if plp_k else None
@@ -1705,7 +1818,7 @@ class ModelRunner:
                         row_steps=len(rows), context_tokens=sum(ctxs),
                         attended_tokens=self._attended_tokens(
                             ctx_arr, int(ctx_arr.sum())),
-                        tile_rows=tile_rows,
+                        tile_rows=tile_rows, head_rows=head_rows,
                         **self._row_step_counts(
                             False, len(rows), self._walked_tokens(
                                 ctx_arr - 1, ctx_arr, w, walkers)))
@@ -1841,6 +1954,8 @@ class ModelRunner:
             context_tokens=(int(ctx.sum()) * num_steps
                             + live_rows * num_steps * (num_steps - 1) // 2),
             attended_tokens=attended,
+            head_rows=dict(batch=b * num_steps, computed=b * num_steps,
+                           emitting=live_rows * num_steps),
             **self._row_step_counts(True, live_rows * num_steps, walked))
         self._record_moe_ffn_rows(b, num_steps)
 

@@ -262,8 +262,10 @@ class Worker:
         (token_budget,)-bucketed program at the top token bucket and the
         narrowest block-table width, in its two steady-state sampler
         variants (greedy and plain random) — exactly 2 executables by
-        default. Populates the (persistent) XLA compilation cache so the
-        first real step doesn't pay compile latency mid-serving.
+        default, in the form a step of that size takes in serving (with
+        the K-step decode programs: a prompt step, the head on its
+        emitting rows). Populates the (persistent) XLA compilation cache
+        so the first real step doesn't pay compile latency mid-serving.
 
         INTELLILLM_WARMUP_FULL=1 extends warm-up to every token bucket up
         to the top, a second block-table width, the logits-processor
@@ -377,11 +379,26 @@ class Worker:
                     **runner.state_slot_kwargs([], b),
                     **runner.window_table_kwargs((), (), b, w)}
                 run_starts = runner.run_start_kwargs((), b)
+                # The form the steps of serving take. Beside the K-step
+                # decode programs a `mixed` step is a prompt step, whose
+                # head runs on its few emitting rows (`emit_rows`, one
+                # bucket of them: here none emits), unless it is too
+                # small for that. A K = 1 engine's steps of decode rows
+                # are `mixed` too: the head over every row.
+                head_args, head_kwargs = (), {}
+                if (self.scheduler_config.num_decode_steps > 1
+                        and b > buckets[0]):
+                    # (Bound as `_execute_mixed`, which alone dispatches
+                    # this form, binds it.)
+                    head_args = (None, None)
+                    head_kwargs = dict(
+                        emit_rows=jnp.full(buckets[0], b, jnp.int32),
+                        num_samples=1, plp_k=0)
                 for flags in flag_variants:
                     result = runner._jit_decode_single(
                         self.params, self.cache_engine.device_cache,
-                        *args, **flags, **numerics_kwargs, **state_kwargs,
-                        **run_starts)
+                        *args, *head_args, **flags, **numerics_kwargs,
+                        **state_kwargs, **run_starts, **head_kwargs)
                     # (packed, [sentinel panel,] caches) — the panel
                     # rides along only under --enable-numerics.
                     packed, caches = result[0], result[-1]
@@ -395,13 +412,15 @@ class Worker:
                         # request doesn't trigger a full XLA compile
                         # mid-serving.
                         m = pad_to_bucket(1, buckets)
+                        # (fetch_indices in the first of head_args'
+                        # two places.)
                         fargs = args + (
-                            place(np.zeros(m, np.int32)), )
+                            place(np.zeros(m, np.int32)), ) + head_args[1:]
                         result = runner._jit_decode_single(
                             self.params,
                             self.cache_engine.device_cache,
                             *fargs, **flags, **numerics_kwargs,
-                            **state_kwargs, **run_starts)
+                            **state_kwargs, **run_starts, **head_kwargs)
                         packed, caches = result[0], result[-1]
                         self.cache_engine.device_cache = caches
                         n += 1

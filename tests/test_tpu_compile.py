@@ -645,21 +645,18 @@ CELL_CONFIGS = {"mistral-7b-v0.2": 2, "deepseek-moe-16b-l5": 2,
 CELL_ROWS, CELL_TABLE_WIDTH, CELL_K, CELL_BLOCKS = 128, 64, 8, 9000
 
 
-@pytest.mark.parametrize("config", sorted(CELL_CONFIGS))
-def test_decode_cont_takes_joiners_at_no_more_temporaries(
-        compiled_for_chip, one_chip, tmp_path, config):
-    """`decode_cont` as the cells dispatch it (128 rows x 64-block tables,
-    K=8, the cell's dtype, quantization and pool dtype, published widths)
-    compiles for the described v5e with the joiners' token and mask
-    columns, holds both paged kernels, and needs no more temporaries
-    than the program it replaces (input tokens sliced from the previous
-    step's packed output alone)."""
+def _cell_runner(one_chip, tmp_path, config, layers):
+    """A `ModelRunner` of the cell configuration `config` cut to `layers`
+    layers (the cell's dtype, quantization and pool dtype, published
+    widths; 512-token steps, 128 seats), its parameters and caches
+    shapes on the described chip: (runner, model, params, kv, hf).
+    `set_use_pallas(True)` is the caller's, around this and its
+    lowerings."""
     import json
 
     from intellillm_tpu.config import (CacheConfig, ModelConfig,
                                        ParallelConfig, SchedulerConfig)
     from intellillm_tpu.models import get_model_class
-    from intellillm_tpu.ops.dispatch import set_use_pallas
     from intellillm_tpu.utils import STR_DTYPE_TO_JNP
     from intellillm_tpu.worker.model_runner import ModelRunner
 
@@ -669,7 +666,6 @@ def test_decode_cont_takes_joiners_at_no_more_temporaries(
     with open(os.path.join(CHIP_DIR, "configs", config + ".json")) as f:
         cell = json.load(f)
     serve = cell["serve"]
-    layers = CELL_CONFIGS[config]
     hf = hf_config_of(cell, rehearsal=False)
     hf["num_hidden_layers"] = layers
     (tmp_path / "config.json").write_text(json.dumps(hf))
@@ -679,36 +675,49 @@ def test_decode_cont_takes_joiners_at_no_more_temporaries(
         quantization=serve["quantization"])
     model = get_model_class(model_config.hf_config.architectures)(
         model_config)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init_random_params(0)))
+    runner = ModelRunner(
+        model, params, model_config,
+        SchedulerConfig(max_num_batched_tokens=512,
+                        max_num_seqs=CELL_ROWS,
+                        max_model_len=serve["max_model_len"],
+                        enable_chunked_prefill=True),
+        CacheConfig(cache_dtype=serve["kv_cache_dtype"],
+                    num_device_blocks_override=CELL_BLOCKS,
+                    swap_space_gib=0.01),
+        ParallelConfig())
+    pools, heads, width = model_config.get_cache_row_shape()
+    pool_dtype = jnp.dtype(STR_DTYPE_TO_JNP[
+        serve["dtype"] if serve["kv_cache_dtype"] == "auto"
+        else serve["kv_cache_dtype"]])
+    kv = [tuple(jax.ShapeDtypeStruct((CELL_BLOCKS, heads, 16, width),
+                                     pool_dtype, sharding=one_chip)
+                for _ in range(pools)) for _ in range(layers)]
+    return runner, model, params, kv, hf
 
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
 
-    params = on_chip(jax.eval_shape(lambda: model.init_random_params(0)))
+@pytest.mark.parametrize("config", sorted(CELL_CONFIGS))
+def test_decode_cont_takes_joiners_at_no_more_temporaries(
+        compiled_for_chip, one_chip, tmp_path, config):
+    """`decode_cont` as the cells dispatch it (128 rows x 64-block tables,
+    K=8, the cell's dtype, quantization and pool dtype, published widths)
+    compiles for the described v5e with the joiners' token and mask
+    columns, holds both paged kernels, and needs no more temporaries
+    than the program it replaces (input tokens sliced from the previous
+    step's packed output alone)."""
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+
     set_use_pallas(True)        # jax.default_backend() is "cpu" here
     try:
-        runner = ModelRunner(
-            model, None, model_config,
-            SchedulerConfig(max_num_batched_tokens=512,
-                            max_num_seqs=CELL_ROWS,
-                            max_model_len=serve["max_model_len"],
-                            enable_chunked_prefill=True),
-            CacheConfig(cache_dtype=serve["kv_cache_dtype"],
-                        num_device_blocks_override=CELL_BLOCKS,
-                        swap_space_gib=0.01),
-            ParallelConfig())
-        pools, heads, width = model_config.get_cache_row_shape()
-        pool_dtype = jnp.dtype(STR_DTYPE_TO_JNP[
-            serve["dtype"] if serve["kv_cache_dtype"] == "auto"
-            else serve["kv_cache_dtype"]])
+        runner, model, params, kv, _ = _cell_runner(
+            one_chip, tmp_path, config, CELL_CONFIGS[config])
         b, w, k = CELL_ROWS, CELL_TABLE_WIDTH, CELL_K
 
         def sds(dtype, *shape):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-        kv = [tuple(sds(pool_dtype, CELL_BLOCKS, heads, 16, width)
-                    for _ in range(pools)) for _ in range(layers)]
         # _pack's layout at logprob_k = 1, with an expert-parallel
         # share's two counter columns and the two of the expert visits.
         share = (2 * bool(getattr(model, "moe_choices_per_token", 0))
@@ -743,6 +752,37 @@ def test_decode_cont_takes_joiners_at_no_more_temporaries(
     assert joined.as_text().count("tpu_custom_call") >= 2
     temp = joined.memory_analysis().temp_size_in_bytes
     assert temp <= 1.01 * before.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("config", ["deepseek-moe-16b-l5",
+                                    "mistral-7b-v0.2"])
+def test_mixed_head_runs_on_the_emitting_rows(compiled_for_chip, one_chip,
+                                              tmp_path, config):
+    """`mixed` at 512 rows x 64-block tables with 16 emitting slots (what
+    a prompt step of the cells is: `_decode_fn_single` `emit_rows`), the
+    cell's dtype, quantization and pool dtype, one layer at the published
+    widths, compiled for the described v5e: one head of `[16, vocab]`
+    under a conditional and no float32 value of `[512, vocab]`; without
+    `emit_rows` the logits of all 512 rows are there, as they were."""
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+
+    set_use_pallas(True)        # jax.default_backend() is "cpu" here
+    try:
+        runner, _, _, kv, hf = _cell_runner(one_chip, tmp_path, config, 1)
+        assert not jax.config.jax_enable_compilation_cache
+        texts = {slots: runner.lower_mixed_step(
+            kv, 512, CELL_TABLE_WIDTH, do_random=False,
+            emit_slots=slots).compile().as_text() for slots in (16, None)}
+    finally:
+        set_use_pallas(None)
+    vocab = hf["vocab_size"]
+    every_row = re.compile(r"\b\w+\[512,%d\]" % vocab)
+    few_rows = re.compile(r"\b(f32|bf16)\[16,%d\]" % vocab)
+    assert every_row.search(texts[None])
+    assert not every_row.search(texts[16])
+    assert few_rows.search(texts[16]) and "conditional(" in texts[16]
+    assert "conditional(" not in texts[None]
+    assert all("tpu_custom_call" in text for text in texts.values())
 
 
 def test_joined_continuation_is_the_plain_one_s_executable(

@@ -278,3 +278,37 @@ def test_join_gather_compiles_only_where_a_step_program_does(
     from intellillm_tpu.obs import get_efficiency_tracker
     assert get_efficiency_tracker().snapshot()[
         "decode_join_rows"]["device"] >= 3
+
+
+@pytest.mark.parametrize("num_decode_steps,rows,cold", [
+    (4, 40, 0),     # a prompt step beside the K-step decode programs
+    (1, 40, 1),     # K = 1 warms the head over every row (decode steps)
+    (4, 64, 0),     # mid-prompt: no row emits, the same executable
+])
+def test_warm_up_covers_the_form_a_prompt_step_takes(monkeypatch,
+                                                     num_decode_steps, rows,
+                                                     cold):
+    """With the K-step decode programs a `mixed` step of the top bucket
+    is a prompt step, whose head runs on its emitting rows
+    (`_decode_fn_single` `emit_rows`): warm-up compiles that form, and
+    the first such step of serving finds it."""
+    from intellillm_tpu.sampling_params import SamplingParams
+    from intellillm_tpu.sequence import SequenceData, SequenceGroupMetadata
+
+    worker = _make_worker(num_decode_steps, max_num_batched_tokens=64,
+                          enable_chunked_prefill=True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert worker.warm_up_model() == 2
+    program = worker.model_runner._jit_decode_single
+    assert program._cache_size() == 2
+    prompt = list(range(3, 3 + 100))
+    data = SequenceData(prompt)
+    meta = SequenceGroupMetadata(
+        request_id="0", is_prompt=True, seq_data={0: data},
+        sampling_params=SamplingParams(temperature=0.0, max_tokens=4),
+        block_tables={0: list(range(7))},
+        token_chunk_size=rows, num_computed_tokens=100 - rows
+        if rows < 64 else 0)
+    out = worker.execute_model([meta], {}, {}, {})
+    assert len(out) == 1
+    assert program._cache_size() == 2 + cold
