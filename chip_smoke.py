@@ -393,7 +393,10 @@ def phase_serve(args, expect_tpu: bool) -> None:
                   "introspection": kernels.get("introspection"),
                   "programs_relowered": sum(
                       1 for s in relower if s is not None),
-                  "relower_s": round(sum(s or 0.0 for s in relower), 1)},
+                  # The set-up record's stage: one source for one number.
+                  "relower_s": round(
+                      detail["boot"]["setup"]["first_calls"]["by_stage_s"][
+                          "introspect"], 1)},
               "co_batched_steps": eff["co_batched_steps"],
               "decode_join_rows": eff["decode_join_rows"],
               "pipeline_drains": eff["pipeline_drains"],

@@ -177,11 +177,13 @@ class AsyncLLMEngine:
     @classmethod
     def from_engine_args(cls, engine_args: AsyncEngineArgs,
                          **kwargs) -> "AsyncLLMEngine":
-        configs = engine_args.create_engine_configs()
-        return cls(*configs,
-                   log_stats=not engine_args.disable_log_stats,
-                   log_requests=not engine_args.disable_log_requests,
-                   **kwargs)
+        from intellillm_tpu.obs import get_boot_timeline
+        with get_boot_timeline().phase("engine_init"):
+            configs = engine_args.create_engine_configs()
+            return cls(*configs,
+                       log_stats=not engine_args.disable_log_stats,
+                       log_requests=not engine_args.disable_log_requests,
+                       **kwargs)
 
     @property
     def is_running(self) -> bool:

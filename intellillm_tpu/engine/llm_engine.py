@@ -27,7 +27,7 @@ from intellillm_tpu.engine.arg_utils import EngineArgs
 from intellillm_tpu.engine.metrics import StatLogger, Stats
 from intellillm_tpu.logger import init_logger
 from intellillm_tpu.obs import (get_alert_manager, get_boot_timeline,
-                                get_device_telemetry,
+                                get_compile_tracker, get_device_telemetry,
                                 get_efficiency_tracker,
                                 get_flight_recorder, get_metrics_history,
                                 get_numerics_tracker, get_slo_tracker,
@@ -167,8 +167,12 @@ class LLMEngine:
         # /health/detail — a persistent compile cache should show up as
         # a collapsed warm-up phase.
         self._boot = get_boot_timeline()
-        with self._boot.phase("weights_load"):
+        # Before anything compiles: what jax traces and compiles from
+        # here on is accounted by stage (obs/compile_tracker.py).
+        get_compile_tracker().install_listeners()
+        with self._boot.phase("init_model"):
             self.worker.init_model()
+        with self._boot.phase("load_model"):
             self.worker.load_model()
 
         # Fused multi-step decode is incompatible with ALiBi (bias needs
@@ -684,10 +688,13 @@ class LLMEngine:
     @classmethod
     def from_engine_args(cls, engine_args: EngineArgs,
                          **kwargs) -> "LLMEngine":
-        configs = engine_args.create_engine_configs()
-        return cls(*configs,
-                   log_stats=not engine_args.disable_log_stats,
-                   **kwargs)
+        # `engine_init` keeps what the phases inside the constructor
+        # leave: the configs, the tokenizer, the scheduler.
+        with get_boot_timeline().phase("engine_init"):
+            configs = engine_args.create_engine_configs()
+            return cls(*configs,
+                       log_stats=not engine_args.disable_log_stats,
+                       **kwargs)
 
     # --- requests ---------------------------------------------------------
 

@@ -339,7 +339,10 @@ def add_debug_routes(app: web.Application,
             # CONTENTION panel).
             "contention": get_decision_log().summary(),
             "alerts": alerts.summary(),
-            "boot": get_boot_timeline().snapshot(),
+            # Phases from the process's start, and under `setup` every
+            # first call's stages (obs/compile_tracker.py).
+            "boot": dict(get_boot_timeline().snapshot(),
+                         setup=get_compile_tracker().setup_record()),
             # Compact: the per-bucket table lives at /debug/predictor.
             # The router's load estimator consumes calibration_factor
             # from here to correct its own predicted lengths.
@@ -495,6 +498,12 @@ def add_debug_routes(app: web.Application,
             "clock": clock,
         })
 
+    async def mark_serving(app: web.Application) -> None:
+        # The boot's last phase (`serve_start`) ends where the server
+        # starts to listen.
+        get_boot_timeline().mark_serving()
+
+    app.on_startup.append(mark_serving)
     app.router.add_get("/metrics", metrics)
     app.router.add_get("/debug/trace", debug_trace)
     app.router.add_get("/debug/workload", debug_workload)

@@ -227,6 +227,10 @@ async def init_serving(engine: AsyncLLMEngine, served_model: str,
 
 
 def main():
+    # The interpreter's start and this module's imports, from the
+    # process's start (obs/boot.py).
+    from intellillm_tpu.obs import get_boot_timeline
+    get_boot_timeline().mark("imports")
     args = make_arg_parser().parse_args()
     engine_args = AsyncEngineArgs.from_cli_args(args)
     served_model = args.served_model_name or args.model

@@ -362,17 +362,23 @@ class KernelLedger:
             "analysis": "skipped",
         }
         if pending.introspect:
-            t0 = time.monotonic()
-            try:
-                self._introspect_into(entry, pending)
-            except Exception as e:
-                entry["analysis"] = "error"
-                logger.warning(
-                    "Kernel ledger: introspection failed for %s %s (%s); "
-                    "entry fields stay null.", pending.program,
-                    entry["bucket"], e)
-            # What the second lower+compile of this program cost.
-            entry["introspect_seconds"] = round(time.monotonic() - t0, 6)
+            # The first call's stage `introspect` (obs/compile_tracker.py):
+            # what this `lower().compile()` behind it cost (on jax 0.9.0
+            # it is served from jax's in-memory caches: 0.02-0.05 s a
+            # program on the chip, PERF.md section 7).
+            from intellillm_tpu.obs.compile_tracker import (
+                get_compile_tracker)
+            with get_compile_tracker().introspection(
+                    pending.program, pending.key) as span:
+                try:
+                    self._introspect_into(entry, pending)
+                except Exception as e:
+                    entry["analysis"] = "error"
+                    logger.warning(
+                        "Kernel ledger: introspection failed for %s %s "
+                        "(%s); entry fields stay null.", pending.program,
+                        entry["bucket"], e)
+            entry["introspect_seconds"] = round(span["seconds"], 6)
         with self._lock:
             self._entries[(pending.program, entry["bucket"])] = entry
             self._account_flops_locked(entry)

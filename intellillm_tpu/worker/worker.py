@@ -74,6 +74,9 @@ class Worker:
     def init_model(self) -> None:
         from intellillm_tpu.utils import enable_persistent_compilation_cache
         enable_persistent_compilation_cache()
+        from intellillm_tpu.obs import get_boot_timeline
+        with get_boot_timeline().phase("runtime_init"):
+            jax.devices()      # the backend starts at its first use
         self.mesh = build_mesh(self.parallel_config)
         logger.info("Initialized mesh: %s (backend=%s)", self.mesh,
                     jax.default_backend())

@@ -528,6 +528,13 @@ def test_history_and_alerts_endpoints_on_both_servers(monkeypatch):
             assert data["alerts"]["page_firing"] is False
             assert "firing" in data["alerts"]
             assert "phases_s" in data["boot"]
+            # The set-up record rides the boot block from the first poll.
+            setup = data["boot"]["setup"]
+            assert setup["phases_s"] == data["boot"]["phases_s"]
+            assert set(setup["first_calls"]["by_stage_s"]) == {
+                "trace", "lower", "compile", "cache_read", "introspect",
+                "rest"}
+            assert isinstance(setup["calls"], list)
 
         _run(demo_server.build_app(), scenario)
         _run(openai_server.build_app(), scenario)

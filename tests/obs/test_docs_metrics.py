@@ -26,6 +26,10 @@ def test_sources_define_metrics():
     assert "intellillm_router_requests_total" in names
     assert "intellillm_trace_hop_seconds" in names
     assert "intellillm_trace_exported_total" in names
+    # Set-up accounting (PR 50).
+    assert "intellillm_xla_stage_seconds_total" in names
+    assert "intellillm_xla_persistent_cache_total" in names
+    assert "intellillm_boot_phase_seconds" in names
 
 
 def test_every_source_metric_is_documented():
@@ -49,3 +53,6 @@ def test_doc_scrape_sees_documented_metrics():
     documented = set(doc_metric_names(load_project().settings))
     assert len(documented) >= 20, sorted(documented)
     assert "intellillm_step_phase_seconds" in documented
+    assert "intellillm_xla_stage_seconds_total" in documented
+    assert "intellillm_xla_persistent_cache_total" in documented
+    assert "intellillm_boot_phase_seconds" in documented

@@ -72,6 +72,11 @@ def test_constructors_are_found():
     assert "intellillm_router_canary_runs_total" in names
     assert "intellillm_router_canary_divergence_total" in names
     assert "intellillm_router_canary_suspect" in names
+    # Set-up accounting families (PR 50): first calls by stage, the
+    # persistent cache's results, the boot's phases.
+    assert "intellillm_xla_stage_seconds_total" in names
+    assert "intellillm_xla_persistent_cache_total" in names
+    assert "intellillm_boot_phase_seconds" in names
 
 
 def test_every_metric_name_is_prefixed():
