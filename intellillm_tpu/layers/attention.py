@@ -71,6 +71,12 @@ class AttentionMetadata:
     # walks a run's paged context once a tile of its rows. None: no row
     # is known to continue another.
     run_starts: Optional[jnp.ndarray] = None
+    # [B] bool: which rows of the step are a sequence's (the runner's own
+    # test of a pad row; staged, `context_lens` counts the pool's tokens
+    # and is 0 for a live row whose first token is still in the stage).
+    # The routed experts compute nothing for the others
+    # (layers/moe.py `moe_ffn`). None: every row is live.
+    live_rows: Optional[jnp.ndarray] = None
 
 
 class PagedAttention:

@@ -3,8 +3,8 @@
 Role parity: reference `vllm/model_executor/layers/fused_moe.py` (Triton
 grouped-GEMM over experts + CUDA `moe_align_block_size`,
 `csrc/moe_align_block_size_kernels.cu`). TPU redesign, two forms of one
-sum (every routed choice computed, none dropped or clipped; bf16 products,
-float32 accumulation and combine):
+sum (every routed choice of a live row computed, none dropped or clipped;
+bf16 products, float32 accumulation and combine):
 
 - `moe_ffn_dense` — every held expert over every row, combined by the
   routing weights, in chunks of at most 256 rows. Its arithmetic
@@ -12,12 +12,14 @@ float32 accumulation and combine):
   197 TFLOP/s / 819 GB/s = 240: a step of up to 256 rows is bound by
   reading the stacks, which no form avoids once every expert is chosen
   by some row, and dense does it with no sort and no gather.
-- `moe_ffn_grouped` — the routed rows only. The token-choices are placed
-  in expert order (a count and a prefix sum: the keys are expert ids), the
-  rows gathered once, and `ops/grouped_ffn.grouped_expert_ffn` runs gate,
-  up, activation and down over tiles of 128 sorted rows, each tile
-  multiplied by the one or two experts whose rows it holds: no padding
-  block an expert, an expert's weights read about once. The choice's
+- `moe_ffn_grouped` — the routed rows only, and of them the rows that
+  are a sequence's (`live`: a step's pad rows choose nothing). The
+  token-choices are placed in expert order (a count and a prefix sum: the
+  keys are expert ids), the rows gathered once, and
+  `ops/grouped_ffn.grouped_expert_ffn` runs gate, up, activation and down
+  over tiles of 128 sorted rows, each tile multiplied by the one or two
+  experts whose rows it holds: no padding block an expert, an expert's
+  weights read about once. The choice's
   weight is applied to the float32 output row and a token's rows summed
   in float32.
 
@@ -317,9 +319,15 @@ def route(h: jnp.ndarray, lp, share: ExpertShare, routing: Routing):
                       select_bias=lp.get("expert_bias"))
 
 
+def live_tokens(live_rows, l: int):
+    """`AttentionMetadata.live_rows` [B] as `moe_ffn`'s `live` over the
+    [B * L] tokens of a flattened [B, L, D] (None stays None)."""
+    return None if live_rows is None else jnp.repeat(live_rows, l)
+
+
 def routed_experts(h: jnp.ndarray, lp, share: ExpertShare, routing: Routing,
                    mesh=None, routed=None, activation: str = "silu",
-                   stacks=None):
+                   stacks=None, live=None):
     """A layer's routed-experts block over `h` [B, L, D] (the post-mixer
     norm's output): router, held experts, and the shared expert where `lp`
     has one ("shared_gate"), all with the gate `activation`. Returns the
@@ -329,7 +337,8 @@ def routed_experts(h: jnp.ndarray, lp, share: ExpertShare, routing: Routing,
     a model that walks its layers in a `lax.scan`, (`w1`, `w2`, `w3`, the
     layer's place among the routed layers): the stacks of EVERY routed
     layer, `[layers * n_held, I, D]`, in place of `lp`'s own (`moe_ffn`
-    `held_at`)."""
+    `held_at`). `live` [B] bool: the step's rows that are a sequence's
+    (`AttentionMetadata.live_rows`; `moe_ffn` says what it decides)."""
     b, l, e = h.shape
     flat = h.reshape(b * l, e)
     if routed is None:
@@ -342,7 +351,7 @@ def routed_experts(h: jnp.ndarray, lp, share: ExpertShare, routing: Routing,
     out, visited = moe_ffn(
         flat, lp["gate_router"], w1, w2, w3, share.top_k,
         routing=routing, routed=routed, mesh=mesh, activation=activation,
-        count_visited=True, held_at=held_at)
+        count_visited=True, held_at=held_at, live=live_tokens(live, l))
     held = None
     if share.moe_choices_per_token:
         held = held_assignments(routed[1], share.n_held,
@@ -448,6 +457,7 @@ def moe_ffn_grouped(
     activation: str = "silu",
     count_visited: bool = False,
     held_at=None,
+    live=None,
 ):
     """`tile`: rows a tile of the grouped kernels' walk (the sorted rows
     are padded to a whole number of them, once, not an expert). `mesh`:
@@ -458,7 +468,11 @@ def moe_ffn_grouped(
     without a row gets no visit (`visit_metadata`). `held_at`: as
     `moe_ffn`'s: the kernels get the stacks whole and this layer's group
     sizes at its experts' places among zeros, so no expert of another
-    layer gets a visit and no slice of a stack is copied."""
+    layer gets a visit and no slice of a stack is copied. `live` [T]
+    bool: a row that is not live (a step's pad row) has no choice: no
+    expert gets a row or a visit for it, it counts towards no visit, and
+    its output is zeros; a live row's output is what it is without the
+    mask, bit for bit. None: every row is live."""
     t, d = x.shape
     n = w1.shape[0] if held_at is None else held_at[1]
     tk = t * top_k
@@ -472,9 +486,11 @@ def moe_ffn_grouped(
     # is wider than the stack) goes to a group of its own past the last
     # held expert, index n, which sorts last, which nothing computes and
     # whose rows the combine leaves out. A stack that holds every expert has
-    # no such row.
+    # no such row. The choices of a row that is not live go the same way.
     local = topi.reshape(-1) - routing.held_start              # [T*K]
     here = (local >= 0) & (local < n)
+    if live is not None:
+        here &= jnp.repeat(live, top_k)
     flat_e = jnp.where(here, local, n)
     flat_w = topw.reshape(-1)
 
@@ -571,6 +587,7 @@ def moe_ffn(
     activation: str = "silu",
     count_visited: bool = False,
     held_at=None,
+    live=None,
 ):
     """The expert FFN in the form `grouped_rows` picks for these shapes.
     `count_visited`: also return how many held experts the step read (i32
@@ -579,7 +596,11 @@ def moe_ffn(
     this layer's first expert in the stacks (may be traced), experts a
     layer) where the stacks are every routed layer's, `[layers * n, I,
     D]`, of a model that walks its layers in a `lax.scan`; None where
-    they are this layer's own."""
+    they are this layer's own. `live` [T] bool: the rows that are a
+    sequence's, None for all of them. The grouped form leaves the others
+    out (`moe_ffn_grouped`); the dense form reads every stack whatever the
+    rows say and computes them like any other: their output is the
+    caller's to throw away in either form."""
     # All three stacks are [N, I, D] (module docstring): w1/w3 contract on
     # their last axis, so no loop around this call makes XLA re-lay them.
     # QuantMixtral (reference mixtral_quant.py): expert stacks arrive as
@@ -601,7 +622,7 @@ def moe_ffn(
                                routing=routing, routed=routed, mesh=mesh,
                                activation=activation,
                                count_visited=count_visited,
-                               held_at=held_at)
+                               held_at=held_at, live=live)
     if held_at is not None:
         # A slice of the stacks that XLA reads inside the dots' fusions.
         w1, w2, w3 = (jax.lax.dynamic_slice_in_dim(w, *held_at)

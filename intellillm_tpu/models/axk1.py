@@ -126,7 +126,8 @@ class AXK1ForCausalLM:
             return h, residual, kv_cache
         h, layer_counts = routed_experts(
             h, lp, self.share, self.routing, mesh=attn_metadata.mesh,
-            activation=self.config.hidden_act.lower())
+            activation=self.config.hidden_act.lower(),
+            live=attn_metadata.live_rows)
         counts.add(layer_counts)
         return h, residual, kv_cache
 

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from intellillm_tpu.config import ModelConfig
-from intellillm_tpu.layers.moe import (Routing, moe_ffn,
+from intellillm_tpu.layers.moe import (Routing, live_tokens, moe_ffn,
                                        random_gate_up_stack)
 from intellillm_tpu.layers.normalization import fused_add_rms_norm, rms_norm
 from intellillm_tpu.models.llama import LlamaForCausalLM, Params
@@ -73,7 +73,8 @@ class DeepseekForCausalLM(LlamaForCausalLM):
         flat = h.reshape(b * l, e)
         out = moe_ffn(flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"],
                       self.top_k, routing=self.routing,
-                      mesh=attn_metadata.mesh)
+                      mesh=attn_metadata.mesh,
+                      live=live_tokens(attn_metadata.live_rows, l))
         if self.n_shared:
             with jax.named_scope("moe_shared"):
                 gate = flat @ lp["shared_gate"]

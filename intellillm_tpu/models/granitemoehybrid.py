@@ -155,7 +155,8 @@ class GraniteMoeHybridForCausalLM:
         h = self._add(h, x)
         out, layer_counts = routed_experts(
             rms_norm(h, lp["post_norm"], self.eps), lp, self.share,
-            self.routing, mesh=attn_metadata.mesh)
+            self.routing, mesh=attn_metadata.mesh,
+            live=attn_metadata.live_rows)
         h = self._add(h, out)
         counts.add(layer_counts)
         return h, cache

@@ -153,7 +153,8 @@ class Lfm2MoeForCausalLM:
             counts.add(DENSE_LAYER)
             return h, residual, cache
         h, layer_counts = routed_experts(h, lp, self.share, self.routing,
-                                         mesh=attn_metadata.mesh)
+                                         mesh=attn_metadata.mesh,
+                                         live=attn_metadata.live_rows)
         counts.add(layer_counts)
         return h, residual, cache
 

@@ -16,7 +16,8 @@ import numpy as np
 
 from intellillm_tpu.config import ModelConfig
 from intellillm_tpu.layers.attention import KVCache
-from intellillm_tpu.layers.moe import moe_ffn, random_gate_up_stack
+from intellillm_tpu.layers.moe import (live_tokens, moe_ffn,
+                                       random_gate_up_stack)
 from intellillm_tpu.layers.normalization import fused_add_rms_norm, rms_norm
 from intellillm_tpu.layers.quantization import qmatmul
 from intellillm_tpu.logger import init_logger
@@ -73,7 +74,8 @@ class MixtralForCausalLM(LlamaForCausalLM):
                                          self.rms_eps)
         flat = h.reshape(b * l, e)
         moe_out = moe_ffn(flat, lp["gate_router"], lp["w1"], lp["w2"],
-                          lp["w3"], self.top_k, mesh=attn_metadata.mesh)
+                          lp["w3"], self.top_k, mesh=attn_metadata.mesh,
+                          live=live_tokens(attn_metadata.live_rows, l))
         return moe_out.reshape(b, l, e), residual, kv_cache
 
     def partition_specs(self):

@@ -133,7 +133,7 @@ class SmallThinkerForCausalLM:
                                          self.eps)
         h, layer_counts = routed_experts(
             h, lp, self.share, self.routing, mesh=attn_metadata.mesh,
-            routed=routed, activation="relu")
+            routed=routed, activation="relu", live=attn_metadata.live_rows)
         counts.add(layer_counts)
         return h, residual, cache
 

@@ -149,7 +149,8 @@ class SolarOpen2ForCausalLM:
         h, residual = fused_add_rms_norm(h, residual, lp["post_norm"],
                                          self.eps)
         h, layer_counts = routed_experts(h, lp, self.share, self.routing,
-                                         mesh=attn_metadata.mesh)
+                                         mesh=attn_metadata.mesh,
+                                         live=attn_metadata.live_rows)
         counts.add(layer_counts)
         return h, residual, cache
 

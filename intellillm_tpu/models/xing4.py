@@ -217,7 +217,8 @@ class Xing4ForCausalLM:
         else:
             y, layer_counts = routed_experts(
                 f, lp, self.share, self.routing, mesh=mesh,
-                activation=self.config.hidden_act.lower(), stacks=stacks)
+                activation=self.config.hidden_act.lower(), stacks=stacks,
+                live=attn_metadata.live_rows)
         return mhc_post(x, y, coef, self.hc, mesh), kv_cache, err, layer_counts
 
     def compute_logits(self, params: Params, hidden, lora=None):

@@ -889,6 +889,7 @@ class ModelRunner:
                     mesh=self.mesh,
                     state_slots=state_slots,
                     window_block_tables=window_tables,
+                    live_rows=base_ctx > 0,
                 )
                 # A layer's cache is (K, V) or the 1-tuple (latent pool,);
                 # staged, the layer gets its pools then their stages (a
@@ -1035,6 +1036,7 @@ class ModelRunner:
             mesh=self.mesh,
             state_slots=state_slots,
             run_starts=run_starts,
+            live_rows=context_lens > 0,
             **window_kwargs,
         )
         hidden, new_caches, counters = self._call_model(

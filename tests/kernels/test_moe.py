@@ -384,3 +384,146 @@ def test_kernels_at_one_tile_of_a_decode_step(activation):
     ref = grouped_expert_ffn_reference(xs, w1, w2, w3, sizes, activation)
     np.testing.assert_allclose(np.asarray(out)[:live],
                                np.asarray(ref)[:live], rtol=2e-4, atol=2e-5)
+
+
+# rows, live rows, top-k, router width, (first held expert, experts held),
+# whether the stacks are every layer's (`held_at`, the layer's place
+# traced): the cell's decode step (16 seats, 13 live, 64 ReGLU experts
+# top-6), the same on stacks of three layers, two expert-parallel shares,
+# a `mixed` step's rows, and the edges of the live count.
+LIVE_CASES = {
+    "decode-16x13-64x6": (16, 13, 6, 64, (0, 64), False),
+    "stacked-16x13-64x6": (16, 13, 6, 64, (0, 64), True),
+    "share-16x11-192x8": (16, 11, 8, 192, (24, 12), False),
+    "share-stacked-16x12-64x8": (16, 12, 8, 64, (16, 16), True),
+    "chunk-300x260-64x6": (300, 260, 6, 64, (0, 64), False),
+    "top1-8x5-4x1": (8, 5, 1, 4, (0, 4), False),
+    "one-live-16x1": (16, 1, 6, 64, (0, 64), False),
+    "all-live-16x16": (16, 16, 6, 64, (0, 64), False),
+    "none-live-16x0": (16, 0, 6, 64, (0, 64), False),
+}
+
+
+def _grouped_with_mask(x, gate_w, stacks, top_k, routing, routed, live,
+                       stacked=None):
+    """`moe_ffn_grouped` with its count, jitted. `stacked`: (the layer's
+    place among the layers whose stacks these are, experts a layer), the
+    place traced, as a `lax.scan` over the layers hands it."""
+    place, n = stacked or (None, None)
+
+    @jax.jit
+    def call(place, live):
+        return moe_ffn_grouped(
+            x, gate_w, *stacks, top_k, routing=routing, routed=routed,
+            activation="relu", count_visited=True, live=live,
+            held_at=None if place is None else (place * n, n))
+
+    return jax.block_until_ready(
+        call(None if place is None else jnp.int32(place), live))
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["jnp", "pallas"])
+@pytest.mark.parametrize("case", sorted(LIVE_CASES))
+def test_rows_that_are_not_live_choose_no_expert(case, kernel, force_pallas):
+    """`moe_ffn_grouped(live=)`: a live row's output is the unmasked
+    call's bit for bit, every other row's is exactly 0, and the experts
+    visited are the held ones the live rows' choices name, counted here
+    from the routing."""
+    t, n_live, top_k, n_router, (held_start, n), stacked = LIVE_CASES[case]
+    d, i = 128, 128
+    key = jax.random.PRNGKey(sum(map(ord, case)))
+    layers, place = (3, (1, n)) if stacked else (1, None)
+    _, w1, w2, w3 = _rand_weights(key, layers * n, d, i)
+    gate_w = jax.random.normal(key, (d, n_router), jnp.float32) * 0.1
+    x = jax.random.normal(jax.random.fold_in(key, 1), (t, d), jnp.float32)
+    routing = Routing(scoring="sigmoid", held_start=held_start)
+    routed = _route(x, gate_w, top_k, routing)
+    live = np.zeros(t, bool)
+    live[np.random.default_rng(t + n_live).choice(t, n_live, False)] = True
+    force_pallas(kernel)
+    args = (x, gate_w, (w1, w2, w3), top_k, routing, routed)
+    want, visited_unmasked = _grouped_with_mask(*args, None, place)
+    got, visited = _grouped_with_mask(*args, jnp.asarray(live), place)
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.array_equal(got[live], want[live])
+    assert not got[~live].any()
+    assert n_live == 0 or np.abs(got[live]).max() > 0
+
+    def held_chosen(rows):
+        local = np.asarray(routed[1])[rows].reshape(-1) - held_start
+        return len(set(local[(local >= 0) & (local < n)].tolist()))
+
+    assert int(visited) == held_chosen(live)
+    assert int(visited_unmasked) == held_chosen(np.ones(t, bool))
+    assert int(visited) <= int(visited_unmasked)
+    if n_live == t:
+        # all live = no mask at all
+        assert np.array_equal(got, want)
+        assert int(visited) == int(visited_unmasked)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["jnp", "pallas"])
+def test_an_expert_only_pad_rows_chose_gets_no_visit(kernel, force_pallas):
+    """Routing built by hand: 13 live rows choose among experts 0-9, the
+    3 pad rows choose experts 60-63 and one the live rows chose too. The
+    kernels' group sizes hold the live rows' choices alone, so experts
+    60-63 have no row and get no visit (`visit_metadata`)."""
+    from intellillm_tpu.ops.pallas.grouped_ffn import visit_metadata
+    t, top_k, n, d, i = 16, 3, 64, 128, 128
+    key = jax.random.PRNGKey(51)
+    gate_w, w1, w2, w3 = _rand_weights(key, n, d, i)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (t, d), jnp.float32)
+    rng = np.random.default_rng(51)
+    live = np.ones(t, bool)
+    live[[2, 9, 15]] = False
+    topi = np.stack([rng.choice(10, top_k, False) if live[r] else
+                     np.array([60 + r % 4, 63 - r % 3, 4]) for r in range(t)])
+    assert len({*topi[~live].reshape(-1)} - {*topi[live].reshape(-1)}) >= 3
+    topw = rng.uniform(0.1, 1.0, size=(t, top_k)).astype(np.float32)
+    routed = (jnp.asarray(topw), jnp.asarray(topi, jnp.int32))
+    force_pallas(kernel)
+    args = (x, gate_w, (w1, w2, w3), top_k, Routing(), routed)
+    want, visited_unmasked = _grouped_with_mask(*args, None)
+    got, visited = _grouped_with_mask(*args, jnp.asarray(live))
+    chosen_by_live = set(topi[live].reshape(-1).tolist())
+    assert int(visited) == len(chosen_by_live) <= 10
+    assert int(visited_unmasked) == len(set(topi.reshape(-1).tolist()))
+    assert int(visited_unmasked) - int(visited) >= 3
+    assert np.array_equal(np.asarray(got)[live], np.asarray(want)[live])
+    assert not np.asarray(got)[~live].any()
+    sizes = np.bincount(topi[live].reshape(-1), minlength=n)
+    (_, expert_of, _), visits = visit_metadata(
+        jnp.asarray(sizes, jnp.int32), 128, 128)
+    assert int(visits) == int(visited)
+    assert set(np.asarray(expert_of)[:int(visits)]) == chosen_by_live
+
+
+@pytest.mark.parametrize("form", ["grouped", "dense"])
+def test_no_mask_and_the_dense_form_are_as_they_were(form):
+    """`live=None` is the call without the argument in both forms, and the
+    dense form takes no notice of a mask: it reads every stack whatever
+    the rows say and computes a pad row like any other."""
+    t, top_k, n, d, i = (16, 6, 64, 128, 128) if form == "grouped" else (
+        64, 6, 64, 128, 128)
+    key = jax.random.PRNGKey(7)
+    gate_w, w1, w2, w3 = _rand_weights(key, n, d, i)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (t, d), jnp.float32)
+    assert moe.grouped_rows(t, top_k, n) == (form == "grouped")
+    live = jnp.arange(t) % 5 != 0
+    today, visited_today = moe_ffn(x, gate_w, w1, w2, w3, top_k,
+                                   count_visited=True)
+    none, visited_none = moe_ffn(x, gate_w, w1, w2, w3, top_k,
+                                 count_visited=True, live=None)
+    assert np.array_equal(np.asarray(none), np.asarray(today))
+    assert int(visited_none) == int(visited_today)
+    masked, visited = moe_ffn(x, gate_w, w1, w2, w3, top_k,
+                              count_visited=True, live=live)
+    if form == "dense":
+        assert np.array_equal(np.asarray(masked), np.asarray(today))
+        assert int(visited) == n
+    else:
+        keep = np.asarray(live)
+        assert np.array_equal(np.asarray(masked)[keep],
+                              np.asarray(today)[keep])
+        assert not np.asarray(masked)[~keep].any()
+        assert int(visited) <= int(visited_today) < n

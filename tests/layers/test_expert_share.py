@@ -149,14 +149,23 @@ FAMILIES = {
 }
 
 
+@pytest.mark.parametrize("masked", [False, True], ids=["all-live", "masked"])
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_block_is_the_sequence_written_out(family):
+def test_block_is_the_sequence_written_out(family, masked):
     """Router, `moe_ffn`, the held count and the shared matmuls one after
     the other, as each family's `_layer` had them, give the block's output
-    and counts bit for bit."""
+    and counts bit for bit. `masked`: with one of three batch rows of two
+    tokens each not live (`AttentionMetadata.live_rows`): the block hands
+    `moe_ffn` the mask a token (six rows: the grouped form, which leaves
+    the row's two tokens out); a share's held counts take no notice."""
+    from intellillm_tpu.layers.moe import grouped_rows
     case = FAMILIES[family]
     rng = np.random.default_rng(5)
-    b, l, d, inter, router, top_k = 6, 1, 32, 16, 16, 4
+    b, l, d, inter, router, top_k = (3, 2, 32, 16, 16, 4) if masked else (
+        6, 1, 32, 16, 16, 4)
+    assert grouped_rows(b * l, top_k, router)
+    live = jnp.asarray([True, False, True]) if masked else None
+    live_kw = dict(live=jnp.repeat(live, l)) if masked else {}
     ep_size, ep_rank = case["ep"]
     share = ExpertShare(router // ep_size, top_k, n_moe_layers=1,
                         ep_size=ep_size, ep_rank=ep_rank)
@@ -180,14 +189,18 @@ def test_block_is_the_sequence_written_out(family):
         router_input = other.reshape(b * l, d)
         routed = route(other, lp, share, routing)
     got, (visited, held) = routed_experts(
-        h, lp, share, routing, routed=routed, activation=case["activation"])
+        h, lp, share, routing, routed=routed, activation=case["activation"],
+        live=live)
 
     want_routed = _route(router_input, lp["gate_router"], top_k, routing,
                          select_bias=lp.get("expert_bias"))
     want, want_visited = moe_ffn(
         flat, lp["gate_router"], lp["w1"], lp["w2"], lp["w3"], top_k,
         routing=routing, routed=want_routed, activation=case["activation"],
-        count_visited=True)
+        count_visited=True, **live_kw)
+    if masked:
+        assert not np.asarray(want).reshape(b, l, d)[1].any()
+        assert np.asarray(want).reshape(b, l, d)[[0, 2]].any()
     if case["shared"]:
         want = want + (act(flat @ lp["shared_gate"])
                        * (flat @ lp["shared_up"])) @ lp["shared_down"]

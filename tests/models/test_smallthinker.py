@@ -19,6 +19,7 @@ import json
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -505,12 +506,119 @@ def test_decode_steps_read_the_experts_their_rows_chose(tmp_path):
               for v in ("yes", "no")}
     # 19 tokens past the prefill's own sample: three K=8 steps.
     assert decode["yes"] + decode["no"] == 3 * 8 * per_substep
-    # 2 live rows and 14 pad rows (one row 14 times): at most 6 experts a
-    # layer-substep have a row.
-    assert 0 < decode["yes"] <= 3 * 8 * 8 * 6
+    # 2 live rows and 14 pad rows, which choose nothing: at most 4 experts
+    # a layer-substep have a row.
+    assert 0 < decode["yes"] <= 3 * 8 * 8 * 4
     # 32-row `mixed` steps run dense and read every expert, 16-row ones
     # are grouped like the decode steps.
     mixed = [delta("moe_expert_visits", "mixed", v) for v in ("yes", "no")]
     assert sum(mixed) % per_substep == 0 and mixed[0] > mixed[1]
     assert delta("moe_ffn_rows", "grouped") >= 3 * 8 * 16
     assert delta("moe_ffn_rows", "dense") >= 32
+
+
+@pytest.fixture(scope="module")
+def seats_13_of_16(tmp_path_factory):
+    """13 two-token prompts in 16 seats, 32 experts top-2: one 32-row
+    `mixed` step (26 live rows) and two K=8 decode steps of 16 rows with 3
+    seats empty, all in the grouped form, run twice: as the engine runs
+    them, and with the rows' liveness kept from `moe_ffn_grouped` (every
+    row routed like a live one: the engine before it knew). A callback in
+    front of `moe_ffn_grouped` records, each time a layer runs, the
+    program it runs in, its rows, the live ones among them and how many
+    experts those rows' choices name. {masked: (outputs, visits counted
+    by the engine a program, records)}, with cfg, weights and prompts."""
+    from intellillm_tpu.layers import moe
+    from intellillm_tpu.layers.moe import grouped_rows
+    from intellillm_tpu.obs import get_efficiency_tracker
+    from intellillm_tpu.worker.model_runner import ModelRunner
+    assert grouped_rows(16, 2, 32) and grouped_rows(32, 2, 32)
+    d = str(tmp_path_factory.mktemp("smallthinker-seats") / "tiny")
+    os.makedirs(d)
+    _, vocab = _build_word_tokenizer(d)
+    cfg = dict(TINY, vocab_size=vocab, moe_num_primary_experts=32)
+    weights = write_model(d, cfg)
+    prompts = prompts_of(cfg, (2, ) * 13)
+    tracing, records, runs = {}, [], {}
+    grouped = moe.moe_ffn_grouped
+
+    def traced_as(name, program):
+        step = getattr(ModelRunner, name)
+
+        def fn(self, *args, **kwargs):
+            tracing["program"] = program    # while jit traces `step`
+            return step(self, *args, **kwargs)
+        return fn
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ModelRunner, "_decode_fn",
+                      traced_as("_decode_fn", "decode"))
+        patch.setattr(ModelRunner, "_decode_fn_single",
+                      traced_as("_decode_fn_single", "mixed"))
+        tracker = get_efficiency_tracker()
+        for masked in (True, False):
+
+            def spy(x, *args, live=None, masked=masked, **kwargs):
+                program = tracing["program"]
+                assert live is not None and live.shape == x.shape[:1]
+
+                def record(experts, live):
+                    experts, live = np.asarray(experts), np.asarray(live)
+                    records.append((program, len(live), int(live.sum()),
+                                    len(set(experts[live].reshape(-1)
+                                            .tolist()))))
+                jax.debug.callback(record, kwargs["routed"][1],
+                                   live if masked else jnp.ones_like(live))
+                return grouped(x, *args, live=live if masked else None,
+                               **kwargs)
+
+            patch.setattr(moe, "moe_ffn_grouped", spy)
+            del records[:]
+            before = tracker.snapshot()["moe_expert_visits"]
+            outs = generate(make_llm(d, max_num_seqs=16, blocks=512),
+                            prompts, [greedy(17)] * 13)
+            jax.effects_barrier()
+            after = tracker.snapshot()["moe_expert_visits"]
+            visits = {"mixed": after["mixed"]["yes"] - before["mixed"]["yes"],
+                      "decode": sum(after[p]["yes"] - before[p]["yes"]
+                                    for p in ("decode_fused", "decode_cont"))}
+            runs[masked] = (outs, visits, list(records))
+    return cfg, weights, prompts, runs
+
+
+def test_empty_seats_leave_the_live_rows_results_alone(seats_13_of_16):
+    """The 13 live rows' tokens and logprobs are, bit for bit, what the
+    same batch gives with the pad rows routed like live ones, and agree
+    with the reference."""
+    cfg, weights, prompts, runs = seats_13_of_16
+    (masked, _, _), (unmasked, _, _) = runs[True], runs[False]
+
+    def logprobs(out):
+        return [{t: float(getattr(v, "logprob", v)) for t, v in lp.items()}
+                for lp in out.outputs[0].logprobs]
+
+    for a, b in zip(masked, unmasked):
+        assert a.outputs[0].token_ids == b.outputs[0].token_ids
+        assert len(a.outputs[0].token_ids) == 17
+        assert logprobs(a) == logprobs(b)
+    assert dlogprobs(cfg, weights, prompts, masked).max() < F32_TOL
+
+
+@pytest.mark.parametrize("program,rows,live", [("decode", 16, 13),
+                                               ("mixed", 32, 26)])
+def test_a_step_visits_the_experts_its_live_rows_chose(seats_13_of_16,
+                                                       program, rows, live):
+    """intellillm_moe_expert_visits_total{visited="yes"} of the K=8 decode
+    steps (`_decode_fn`: 16 seats, 3 empty) and of the `mixed` step
+    (`_decode_fn_single`: 32 rows, 6 of them pad) is what the live rows'
+    routing alone names, layer by layer and substep by substep; routed
+    like live ones the pad rows had more experts read."""
+    _, _, _, runs = seats_13_of_16
+    for masked, live_seen in ((True, live), (False, rows)):
+        _, visits, records = runs[masked]
+        mine = [r for r in records if r[0] == program]
+        # 8 layers a substep; two K=8 decode steps, one `mixed` step
+        assert len(mine) == 8 * (16 if program == "decode" else 1)
+        assert {r[1:3] for r in mine} == {(rows, live_seen)}
+        assert visits[program] == sum(r[3] for r in mine) > 0
+    assert runs[True][1][program] < runs[False][1][program]

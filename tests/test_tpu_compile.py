@@ -485,33 +485,40 @@ FEW_ROW_PROGRAMS = {
 }
 
 
+@pytest.mark.parametrize("masked", [False, True], ids=["all-live", "masked"])
 @pytest.mark.parametrize("program", sorted(FEW_ROW_PROGRAMS))
 def test_moe_few_rows_take_the_grouped_kernels(compiled_for_chip,
-                                               force_pallas, program):
+                                               force_pallas, program, masked):
     """`moe_ffn` at a step of few rows lowers to the two grouped kernels
     for the described chip, inside a `lax.scan` as `decode_fused` /
     `decode_cont` run it (the number of visits is a value of the loop's
     body, not of the program), with no copy of an expert stack for the
-    life of the program and temporaries of one 128-row tile."""
+    life of the program and temporaries of one 128-row tile. `masked`:
+    with the step's live rows as one more operand (`moe_ffn(live=)`, the
+    runner's `context_lens > 0`): the same two kernels and nothing more
+    of an expert stack's size."""
     from intellillm_tpu.layers.moe import Routing, grouped_rows, moe_ffn
     widths, n_router, rows, settings, activation, loop = FEW_ROW_PROGRAMS[
         program]
     assert grouped_rows(rows, widths[3], n_router)
     force_pallas(True)
 
-    def ffn(x, gate_w, w1, w2, w3):
+    def ffn(x, gate_w, w1, w2, w3, context_lens=None):
         return moe_ffn(x, gate_w, w1, w2, w3, widths[3],
                        routing=Routing(**settings), activation=activation,
-                       count_visited=True)
+                       count_visited=True,
+                       live=context_lens > 0 if masked else None)
 
-    def looped(x, *weights):
+    def looped(x, *operands):
         def substep(h, _):
-            out, visited = ffn(h, *weights)
+            out, visited = ffn(h, *operands)
             return (h + out).astype(h.dtype), visited
         return jax.lax.scan(substep, x, None, length=loop)
 
-    compiled = compiled_for_chip(looped if loop else ffn,
-                                 *_moe_specs(rows, widths, n_router))
+    specs = _moe_specs(rows, widths, n_router)
+    if masked:
+        specs += (((rows, ), jnp.int32), )
+    compiled = compiled_for_chip(looped if loop else ffn, *specs)
     text = compiled.as_text()
     assert "_grouped_gate_up_call" in text and "_grouped_down_call" in text
     assert text.count("tpu_custom_call") == 2
