@@ -184,6 +184,9 @@ class ModelConfig:
         latent = self.get_latent_cache_width()
         if latent:
             return 1, self.get_packed_latent_layers() or 1, latent
+        described = self._cache_description()
+        if described:
+            return tuple(described["row_shape"])
         # A config may state a stored head width wider than the head (the
         # head zero-padded to whole 128-lane tiles, which is what the
         # device pads a narrower pool row to anyway and what the Pallas
@@ -203,6 +206,46 @@ class ModelConfig:
         return (getattr(cfg, "mamba_d_ssm", None) is not None
                 and not getattr(cfg, "layer_types", None)
                 and getattr(cfg, "attn_layer_indices", None) is None)
+
+    def _cache_description(self) -> Optional[dict]:
+        """What the config CLASS says of its caches
+        (`cache_description(dtype)`: `transformers_utils/configs/
+        phi4flash.py` has the keys), None for a class that says nothing:
+        its caches are then read off the config's keys by the five
+        descriptions below. A new family whose layers differ states them
+        there and adds nothing here."""
+        describe = getattr(self.hf_config, "cache_description", None)
+        return describe(self.dtype) if callable(describe) else None
+
+    def get_layer_cache_entries(self) -> List[Optional[int]]:
+        """For each MODEL layer, the entry of the cache list
+        (`get_layer_cache_kinds`) it reads: its own, another layer's (a
+        layer that holds nothing and attends to the keys and values of a
+        layer before it), or None (a layer with no cache at all). Every
+        family but a described one has one entry a layer, in order (or
+        the two special shapes `get_layer_cache_kinds` names, which their
+        models index themselves)."""
+        described = self._cache_description()
+        if not described:
+            return list(range(len(self.get_layer_cache_kinds())))
+        layers = described["layers"]
+        holders = [i for i, kind in enumerate(layers)
+                   if isinstance(kind, str)]
+        entry = {layer: i for i, layer in enumerate(holders)}
+        return [None if kind is None
+                else entry[layer if isinstance(kind, str) else kind]
+                for layer, kind in enumerate(layers)]
+
+    def get_cache_walkers(self) -> Tuple[int, int, int]:
+        """(layers that walk a "kv" pool, layers that walk a "kv_window"
+        pool, those of the first that hold no pool of their own): what
+        the attention kernels' walks are counted by. Without a described
+        reader every walker is a holder."""
+        kinds = self.get_layer_cache_kinds()
+        entries = self.get_layer_cache_entries()
+        full = [e for e in entries if e is not None and kinds[e] == "kv"]
+        return (len(full), kinds.count("kv_window"),
+                len(full) - len(set(full)))
 
     def get_layer_cache_kinds(self) -> List[str]:
         """What each entry of the cache list holds (`kv_caches[i]`, one
@@ -231,8 +274,18 @@ class ModelConfig:
         beside its attention, `_parallel_scan_layers`) is TWO entries,
         "kv" then "state": layer l's caches are entries 2l and 2l + 1, so
         that it counts in both totals and every block op and every state
-        op below finds its pools where it finds any other layer's."""
+        op below finds its pools where it finds any other layer's. A
+        config CLASS that describes its caches itself
+        (`_cache_description`) is asked first: its list has one entry a
+        layer that holds something, of any of the three kinds side by
+        side, and `get_layer_cache_entries` maps the model's layers,
+        those that hold nothing included, onto it."""
         n = self.get_num_layers()
+        described = self._cache_description()
+        if described:
+            # One entry a layer that HOLDS something, in layer order.
+            return [kind for kind in described["layers"]
+                    if isinstance(kind, str)]
         if self.get_packed_latent_layers():
             return ["kv"]           # one pool for every layer
         if self._parallel_scan_layers():
@@ -266,6 +319,9 @@ class ModelConfig:
         model without such a group."""
         if "kv_window" not in self.get_layer_cache_kinds():
             return None
+        described = self._cache_description()
+        if described:
+            return int(described["window"])
         return int(self.hf_config.sliding_window_size)
 
     def get_window_group_share(self, ahead_tokens: int) -> float:
@@ -307,6 +363,9 @@ class ModelConfig:
         slot (`state_array_in_place`)."""
         if "state" not in self.get_layer_cache_kinds():
             return ()
+        described = self._cache_description()
+        if described:
+            return tuple(described["state_arrays"])
         cfg = self.hf_config
         linear = getattr(cfg, "linear_attn_config", None)
         if linear:
@@ -395,6 +454,9 @@ class ModelConfig:
         layout = self._window_layout()
         if layout is not None and all(layout):
             return int(self.hf_config.sliding_window_size)
+        if self._cache_description():
+            # A described family's window is its "kv_window" layers'.
+            return None
         return getattr(self.hf_config, "sliding_window", None)
 
 
@@ -528,6 +590,7 @@ class SchedulerConfig:
         predictor_path: Optional[str] = None,
         replica_role: str = "mixed",
         tenant_fairness: bool = True,
+        min_block_table_width: int = 16,
     ) -> None:
         self.enable_chunked_prefill = enable_chunked_prefill
         if max_num_batched_tokens is not None:
@@ -559,6 +622,12 @@ class SchedulerConfig:
         # reference's CUDA-graph + async-loop host-latency hiding. Beam
         # search and penalty-bearing batches fall back to 1.
         self.num_decode_steps = num_decode_steps
+        # The narrowest padded block table (in blocks) a step program is
+        # compiled for. Every width bucket between it and
+        # max_model_len / block_size is one more executable a row bucket;
+        # a deployment whose prompts are all long raises it and warms up
+        # fewer programs (at max_model_len / block_size: one width).
+        self.min_block_table_width = min_block_table_width
         # Disaggregated serving role (docs/routing.md "Disaggregated
         # roles"): "mixed" (default) runs the normal chunked prefill +
         # decode loop; "prefill" finishes every request at
@@ -593,6 +662,8 @@ class SchedulerConfig:
                 "max_num_batched_tokens must be >= max_num_seqs")
         if self.num_decode_steps < 1:
             raise ValueError("num_decode_steps must be >= 1")
+        if self.min_block_table_width < 1:
+            raise ValueError("min_block_table_width must be >= 1")
         if self.sjf_starvation_s is not None and self.sjf_starvation_s < 0:
             raise ValueError("sjf_starvation_s must be >= 0 (0 disables)")
         if self.replica_role not in ("mixed", "prefill", "decode"):

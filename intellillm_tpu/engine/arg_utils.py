@@ -40,6 +40,7 @@ class EngineArgs:
     sjf_starvation_s: Optional[float] = None
     predictor_path: Optional[str] = None
     num_decode_steps: int = 8
+    min_block_table_width: int = 16
     enable_chunked_prefill: bool = True
     disable_chunked_prefill: bool = False
     replica_role: str = "mixed"
@@ -133,6 +134,12 @@ class EngineArgs:
                             "heuristic; see docs/scheduling.md)")
         parser.add_argument("--num-decode-steps", type=int, default=8,
                             help="decode iterations fused per device call")
+        parser.add_argument("--min-block-table-width", type=int, default=16,
+                            help="narrowest padded block table (in blocks) "
+                            "a step program is compiled for; each width "
+                            "bucket above it is one more executable a row "
+                            "bucket, so a deployment of long prompts "
+                            "raises it and warms up fewer programs")
         parser.add_argument("--replica-role", type=str, default="mixed",
                             choices=["mixed", "prefill", "decode"],
                             help="disaggregated-serving role: 'prefill' "
@@ -271,6 +278,7 @@ class EngineArgs:
             max_paddings=self.max_paddings,
             policy=self.scheduling_policy,
             num_decode_steps=self.num_decode_steps,
+            min_block_table_width=self.min_block_table_width,
             enable_chunked_prefill=(self.enable_chunked_prefill
                                     and not self.disable_chunked_prefill),
             sjf_starvation_s=self.sjf_starvation_s,

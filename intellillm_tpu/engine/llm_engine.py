@@ -618,16 +618,20 @@ class LLMEngine:
                 kv_window=(layer_block_bytes * mc.get_num_window_layers()
                            * cc.num_window_device_blocks),
                 state=state_slot_bytes * cc.num_state_slots),
-            # The cache groups: layers, blocks and tokens each holds. A
+            # The cache groups: layers that hold a pool of it, layers
+            # that read one (the holders, and layers that hold nothing
+            # and walk another layer's pool), blocks and tokens. A
             # window group (layers with a sliding window beside layers
             # that keep the whole context) has pools of its own.
             "groups": {
                 "kv": {"layers": mc.get_num_kv_layers(),
+                       "readers": mc.get_cache_walkers()[0],
                        "window": mc.get_sliding_window(),
                        "num_device_blocks": cc.num_device_blocks,
                        "tokens": cc.num_device_blocks * cc.block_size},
                 "kv_window": {
                     "layers": mc.get_num_window_layers(),
+                    "readers": mc.get_cache_walkers()[1],
                     "window": mc.get_window_group_size(),
                     "num_device_blocks": cc.num_window_device_blocks,
                     "tokens": cc.num_window_device_blocks * cc.block_size},

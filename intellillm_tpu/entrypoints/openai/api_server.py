@@ -54,9 +54,17 @@ async def _streaming_response(request: web.Request, generator,
         headers["X-Request-Id"] = request_id
     response = web.StreamResponse(headers=headers)
     await response.prepare(request)
-    async for chunk in generator:
-        await response.write(chunk.encode())
-    await response.write_eof()
+    try:
+        async for chunk in generator:
+            await response.write(chunk.encode())
+        await response.write_eof()
+    except ConnectionError:
+        # The client has gone. Closing the generator below is what aborts
+        # its request; without it the engine decodes to max_tokens for
+        # nobody.
+        logger.info("client of %s disconnected", request_id)
+    finally:
+        await generator.aclose()
     return response
 
 

@@ -191,6 +191,65 @@ class PagedAttention:
         return out, (k_pool, v_pool, k_stage, v_stage)
 
 
+class DifferentialAttention:
+    """Differential attention (arXiv:2410.05258) around the seams above,
+    which it leaves as they are.
+
+    Heads come in pairs: query heads `2i, 2i + 1` (`q1_i`, `q2_i`) and K/V
+    heads `2j, 2j + 1` (`k1_j, k2_j`, `v1_j, v2_j`), `j = i // (query
+    pairs a K/V pair)`, each `d` wide:
+
+        a1 = softmax(q1 k1^T / sqrt(d)) [v1 | v2]
+        a2 = softmax(q2 k2^T / sqrt(d)) [v1 | v2]               (2 d wide)
+        out = (1 - lambda_init) RMSNorm_{2d}(a1 - lambda a2) * w
+
+    The fold: K/V heads `2j, 2j + 1` side by side ARE one stored head of
+    `2 d` (`[k1 | k2]`, `[v1 | v2]`: a plain reshape, and the pool's row
+    `ModelConfig.get_cache_row_shape` gives), `q1` enters as `[q1 | 0]` and
+    `q2` as `[0 | q2]`, and plain grouped-query attention of all the query
+    heads over the stored heads at scale `d ** -0.5` returns `a1` on the
+    even heads and `a2` on the odd ones: the zeros drop the other half's
+    products from a score, and the values are both halves as they are
+    wanted. At `d` = 64 the stored head is 128 lanes: the kernels run
+    without padding.
+
+    A layer that reads another layer's pool (a cross layer) calls this
+    with that layer's `key`, `value` and cache: the seams write the rows
+    again, with the values they hold, and attend."""
+
+    def __init__(self, num_heads: int, head_size: int, num_kv_heads: int,
+                 sliding_window: Optional[int] = None,
+                 eps: float = 1e-5) -> None:
+        assert num_heads % 2 == 0 and num_kv_heads % 2 == 0
+        self.head_size = head_size
+        self.eps = eps
+        self.inner = PagedAttention(num_heads, 2 * head_size,
+                                    head_size**-0.5, num_kv_heads // 2,
+                                    sliding_window=sliding_window)
+
+    def __call__(self, query, key, value, kv_cache, attn_metadata, *,
+                 lam, lambda_init: float, subln):
+        """`query` [B, L, Hq, d], `key`, `value` [B, L, Hkv, d] -> ([B, L,
+        Hq d], cache). `lam` a float32 scalar, `subln` [2 d]."""
+        b, l, hq, d = query.shape
+        pairs = query.reshape(b, l, hq // 2, 2, d)
+        zeros = jnp.zeros_like(pairs[..., 0, :])
+        folded_q = jnp.stack(
+            [jnp.concatenate([pairs[..., 0, :], zeros], -1),
+             jnp.concatenate([zeros, pairs[..., 1, :]], -1)],
+            axis=-2).reshape(b, l, hq, 2 * d)
+        stored = key.shape[2] // 2
+        out, kv_cache = self.inner(
+            folded_q, key.reshape(b, l, stored, 2 * d),
+            value.reshape(b, l, stored, 2 * d), kv_cache, attn_metadata)
+        out = out.reshape(b, l, hq // 2, 2, 2 * d).astype(jnp.float32)
+        diff = out[..., 0, :] - lam * out[..., 1, :]
+        diff = diff * jax.lax.rsqrt(
+            jnp.mean(diff * diff, axis=-1, keepdims=True) + self.eps)
+        diff = diff * subln.astype(jnp.float32) * (1.0 - lambda_init)
+        return diff.reshape(b, l, hq * d).astype(query.dtype), kv_cache
+
+
 def _any_attention_layer(model, said) -> bool:
     """Whether `said(layer)` holds of any PagedAttention in the model."""
     seen = set()

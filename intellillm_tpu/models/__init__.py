@@ -29,6 +29,7 @@ from intellillm_tpu.models.mixtral import MixtralForCausalLM
 from intellillm_tpu.models.mpt import MPTForCausalLM
 from intellillm_tpu.models.opt import OPTForCausalLM
 from intellillm_tpu.models.phi import PhiForCausalLM
+from intellillm_tpu.models.phi4flash import Phi4FlashForCausalLM
 from intellillm_tpu.models.qwen import QWenLMHeadModel
 from intellillm_tpu.models.qwen2 import Qwen2ForCausalLM
 from intellillm_tpu.models.smallthinker import SmallThinkerForCausalLM
@@ -83,6 +84,9 @@ _MODEL_REGISTRY: Dict[str, Type] = {
     "Xing4ForCausalLM": Xing4ForCausalLM,
     # a Mamba-2 scan (two groups of B and C) beside RoPE GQA in every layer
     "FalconH1ForCausalLM": FalconH1ForCausalLM,
+    # Mamba-1 scans + window layers, then GMUs and cross layers over ONE
+    # full-attention layer's pool; differential attention
+    "Phi4FlashForCausalLM": Phi4FlashForCausalLM,
 }
 
 

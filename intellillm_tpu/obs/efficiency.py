@@ -163,6 +163,13 @@ class _EfficiencyMetrics:
             "Beside intellillm_dispatch_state_bytes_total: which of a "
             "hybrid's two caches a decode step's bytes go to.",
             ["program"])
+        self.counter_dispatch_shared_kv_walk_bytes = Counter(
+            "intellillm_dispatch_shared_kv_walk_bytes_total",
+            "The part of intellillm_dispatch_kv_walk_bytes_total moved by "
+            "layers that hold no pool of their own and walk another "
+            "layer's (cross layers over one full-attention layer's keys "
+            "and values). Stays 0 for a model every walker of which "
+            "holds what it walks.", ["program"])
         self.counter_dispatch_attended_tokens = Counter(
             "intellillm_dispatch_attended_tokens_total",
             "Tokens a row attends to in ONE layer of a kind (layers: full "
@@ -315,6 +322,7 @@ class _EfficiencyMetrics:
             self.counter_dispatch_context_tokens.labels(program)
             self.counter_dispatch_walked_tokens.labels(program)
             self.counter_dispatch_kv_walk_bytes.labels(program)
+            self.counter_dispatch_shared_kv_walk_bytes.labels(program)
             for reach in ATTENTION_REACHES:
                 self.counter_dispatch_attended_tokens.labels(program, reach)
             for form in LINEAR_ATTN_FORMS:
@@ -567,6 +575,7 @@ class EfficiencyTracker:
                         context_tokens: int = 0,
                         walked_tokens: int = 0,
                         kv_walk_bytes: int = 0,
+                        shared_kv_walk_bytes: int = 0,
                         attended_tokens: Optional[Dict[str, int]] = None,
                         tile_rows: Optional[Dict[str, int]] = None,
                         head_rows: Optional[Dict[str, int]] = None,
@@ -586,7 +595,9 @@ class EfficiencyTracker:
         moves for those same row-substeps (`walk_extent` of the kernel
         module: whole pages; where the model has two cache groups, the
         mean over its layers), and `kv_walk_bytes` the cache bytes that
-        is over all the layers with a paged pool. `attended_tokens`: by
+        is over all the layers that walk a paged pool, of which
+        `shared_kv_walk_bytes` by layers that hold none and walk another
+        layer's. `attended_tokens`: by
         ATTENTION_REACHES,
         what those row-substeps attend to in one layer of that kind.
         `tile_rows`: by RAGGED_TILES, a `mixed` step's live rows by the
@@ -656,6 +667,9 @@ class EfficiencyTracker:
                     walked_tokens)
                 m.counter_dispatch_kv_walk_bytes.labels(program).inc(
                     kv_walk_bytes)
+                if shared_kv_walk_bytes:
+                    m.counter_dispatch_shared_kv_walk_bytes.labels(
+                        program).inc(shared_kv_walk_bytes)
                 for reach, n in (attended_tokens or {}).items():
                     m.counter_dispatch_attended_tokens.labels(
                         program, reach).inc(n)

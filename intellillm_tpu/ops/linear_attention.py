@@ -1,9 +1,11 @@
 """Seam of the recurrences that keep a float32 matrix a sequence in a state
 pool: the gated delta rule with a per-channel decay (Kimi Delta Attention;
-labels `kda_chunk`, `kda_step`) and, at the end of the file, the selective
-state-space scan with a scalar decay a head and no delta term (Mamba-2;
-labels `ssd_chunk`, `ssd_step`). Both bodies share the contract of the
-pool, the slots and the runs (`run_structure`).
+labels `kda_chunk`, `kda_step`), the selective state-space scan with a
+scalar decay a head and no delta term (Mamba-2; labels `ssd_chunk`,
+`ssd_step`) and, at the end of the file, the selective scan whose decay is
+a matrix over (channel, state) pairs (Mamba-1; labels `sscan_chunk`,
+`sscan_step`). The three bodies share the contract of the pool, the slots
+and the runs (`run_structure`).
 
 The delta rule.
 
@@ -46,6 +48,7 @@ import jax.numpy as jnp
 
 from intellillm_tpu.ops.dispatch import use_kernel
 from intellillm_tpu.ops.pallas import kda as _kernels
+from intellillm_tpu.ops.pallas import selective_scan as _sscan
 from intellillm_tpu.ops.pallas import ssd as _ssd
 
 
@@ -207,6 +210,85 @@ def ssd_chunk_reference(x, dt, a, b, c, pool, slots, positions):
         start = jnp.where(pos == 0, jnp.zeros_like(cur), pool[slot])
         y, new = ssd_update(jnp.where(cont, cur, start), x_t, dt_t, a, b_t,
                             c_t)
+        pool = pool.at[jnp.where(is_last, slot, pad)].set(new)
+        return (pool, new), y
+
+    (pool, _), y = jax.lax.scan(
+        row, (pool, jnp.zeros(pool.shape[1:], pool.dtype)),
+        (x, dt, b, c, slots, positions, continues, last))
+    return y, pool
+
+
+# --- the selective scan with a decay a (channel, state) pair ------------------
+#
+# Mamba-1: no heads. With `S_t` a float32 matrix `[N, inner]` (rows = state
+# channels), `dt_t` > 0 a channel, `A` `[N, inner]` < 0 learned, `B_t`, `C_t`
+# in R^N one each a token:
+#
+#     S_t = exp(dt_t[c] A[n, c]) S_{t-1} + B_t (dt_t x_t)^T
+#     y_t = S_t^T C_t
+#
+# (the skip `D x_t` is the layer's). The decay is a matrix as large as the
+# state, so no scalar a head can stand for it and neither `ssd_*` kernel
+# computes it. The pool holds the state with its channels cut into lane
+# tiles, `[slots + 1, tiles, N, 128]`; the contract of slots, runs and the
+# pad row is the two bodies' above.
+#
+# Arguments: `x`, `dt` [T, inner] (`dt` float32), `a` [N, inner] float32,
+# `b`, `c` [T, N]. Outputs are float32 `[T, inner]`.
+
+
+def sscan_update(state, x, dt, a, b, c):
+    """One token of the scan: `state` [..., tiles, N, lanes] float32, `x`,
+    `dt` [..., inner], `a` [N, inner], `b`, `c` [..., N]. Returns (y
+    [..., inner], new state), float32."""
+    f32 = jnp.float32
+    lead, (tiles, n, lanes) = state.shape[:-3], state.shape[-3:]
+    dt = dt.astype(f32)
+    by_tile = lead + (tiles, 1, lanes)
+    a_t = a.astype(f32).reshape(n, tiles, lanes).swapaxes(0, 1)
+    b_c, c_c = (v.astype(f32)[..., None, :, None] for v in (b, c))
+    new = (jnp.exp(dt.reshape(by_tile) * a_t) * state
+           + b_c * (dt * x.astype(f32)).reshape(by_tile))
+    return (c_c * new).sum(-2).reshape(x.shape), new
+
+
+def sscan_chunk(x, dt, a, b, c, pool, slots, positions
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(y [T, inner] float32, pool) of a `mixed` step's rows."""
+    if use_kernel("sscan_chunk", _sscan.sscan_supported(x, pool)):
+        continues, last = run_structure(slots, positions)
+        return _sscan.sscan_chunk(x, dt, a, b, c, pool, slots, positions,
+                                  continues, last)
+    return sscan_chunk_reference(x, dt, a, b, c, pool, slots, positions)
+
+
+def sscan_step(x, dt, a, b, c, pool, slots
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(y [B, inner] float32, pool) of one decode substep; a pad row's
+    slot is the pool's last row."""
+    if use_kernel("sscan_step", _sscan.sscan_supported(x, pool)):
+        return _sscan.sscan_step(x, dt, a, b, c, pool, slots)
+    return sscan_step_reference(x, dt, a, b, c, pool, slots)
+
+
+def sscan_step_reference(x, dt, a, b, c, pool, slots):
+    y, new = sscan_update(pool[slots], x, dt, a, b, c)
+    return y, pool.at[slots].set(new)
+
+
+def sscan_chunk_reference(x, dt, a, b, c, pool, slots, positions):
+    """The recurrence a row at a time, the current run's state carried
+    beside the pool."""
+    continues, last = run_structure(slots, positions)
+    pad = pool.shape[0] - 1
+
+    def row(carry, r):
+        pool, cur = carry
+        x_t, dt_t, b_t, c_t, slot, pos, cont, is_last = r
+        start = jnp.where(pos == 0, jnp.zeros_like(cur), pool[slot])
+        y, new = sscan_update(jnp.where(cont, cur, start), x_t, dt_t, a,
+                              b_t, c_t)
         pool = pool.at[jnp.where(is_last, slot, pad)].set(new)
         return (pool, new), y
 

@@ -18,6 +18,8 @@ from intellillm_tpu.transformers_utils.configs.granitemoehybrid import (
     GraniteMoeHybridConfig)
 from intellillm_tpu.transformers_utils.configs.internlm import InternLMConfig
 from intellillm_tpu.transformers_utils.configs.lfm2_moe import Lfm2MoeConfig
+from intellillm_tpu.transformers_utils.configs.phi4flash import (
+    Phi4FlashConfig)
 from intellillm_tpu.transformers_utils.configs.qwen import QWenConfig
 from intellillm_tpu.transformers_utils.configs.smallthinker import (
     SmallThinkerConfig)
@@ -37,6 +39,7 @@ _CONFIG_REGISTRY = {
     "granitemoehybrid": GraniteMoeHybridConfig,
     "internlm": InternLMConfig,
     "lfm2_moe": Lfm2MoeConfig,
+    "phi4flash": Phi4FlashConfig,
     "qwen": QWenConfig,
     "smallthinker": SmallThinkerConfig,
     "solar_open2": SolarOpen2Config,
@@ -48,7 +51,7 @@ _CONFIG_REGISTRY = {
 __all__ = [
     "AquilaConfig", "AXK1Config", "BaichuanConfig", "ChatGLMConfig", "DeciLMConfig",
     "DeepseekConfig", "FalconH1Config", "GraniteMoeHybridConfig", "InternLMConfig",
-    "Lfm2MoeConfig", "QWenConfig",
+    "Lfm2MoeConfig", "Phi4FlashConfig", "QWenConfig",
     "SmallThinkerConfig", "SolarOpen2Config", "Xing4Config", "YiConfig",
     "_CONFIG_REGISTRY",
 ]

@@ -267,7 +267,7 @@ class ModelRunner:
         self._queries_per_kv = (
             None if model_config.get_latent_cache_width()
             else model_config.get_num_attention_heads()
-            // model_config.get_total_num_kv_heads())
+            // model_config.get_cache_row_shape()[1])
         # What each layer caches (`ModelConfig.get_layer_cache_kinds`).
         # A model with "state" layers gives every row of a step the state
         # slot of its sequence (`AttentionMetadata.state_slots`); the
@@ -282,9 +282,13 @@ class ModelRunner:
         # one (every layer's, or the window group's).
         self.window_group = model_config.get_window_group_size()
         self._window = self.sliding_window or self.window_group
+        # The layers that WALK a pool, by reach: those that hold one and
+        # those that read another layer's (`get_cache_walkers`; of the
+        # latter, `_shared_walkers`, every one reads a full pool).
+        full_walkers, window_walkers, self._shared_walkers = \
+            model_config.get_cache_walkers()
         self._layers_by_reach = (
-            (self.layer_kinds.count("kv"),
-             self.layer_kinds.count("kv_window")) if self.window_group
+            (full_walkers, window_walkers) if self.window_group
             else (0, 1) if self.sliding_window
             else (1, 0))
         self.num_state_slots = cache_config.num_state_slots
@@ -300,17 +304,18 @@ class ModelRunner:
                 for (shape, dtype), in_place in zip(state_arrays,
                                                     self._state_in_place)
                 if in_place))
-        # Bytes of K/V (or latent) one token holds over every layer with a
-        # paged pool, either group: what a walked token moves.
+        # Bytes of K/V (or latent) one token moves over every layer that
+        # walks a paged pool, either group (`_kv_layer_token_bytes`: in
+        # one of them).
         pools, kv_heads, kv_width = model_config.get_cache_row_shape()
         cache_dtype = (model_config.dtype
                        if cache_config.cache_dtype == "auto"
                        else cache_config.cache_dtype)
-        self._kv_token_bytes = (
+        self._kv_layer_token_bytes = (
             pools * kv_heads * kv_width
-            * jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]).itemsize
-            * (model_config.get_num_kv_layers()
-               + model_config.get_num_window_layers()))
+            * jnp.dtype(STR_DTYPE_TO_JNP[cache_dtype]).itemsize)
+        self._kv_token_bytes = self._kv_layer_token_bytes * (
+            full_walkers + window_walkers)
         # A model with a multi-stream residual (layers/
         # hyper_connections.py): the streams' bytes a row moves through
         # all its sublayers; 0 for every other model.
@@ -356,6 +361,11 @@ class ModelRunner:
         self.max_table_width = pad_to_bucket(
             max(max_blocks, _MIN_BLOCK_TABLE_WIDTH),
             self.mixed_token_buckets)
+        # The narrowest: a deployment whose contexts are all long asks for
+        # a wider one (--min-block-table-width) and gets fewer executables.
+        self.min_table_width = min(
+            max(scheduler_config.min_block_table_width,
+                _MIN_BLOCK_TABLE_WIDTH), self.max_table_width)
 
         self._jit_decode = jax.jit(
             self._decode_fn,
@@ -480,18 +490,23 @@ class ModelRunner:
         return {"run_starts": self._place_batch_array(
             _padded_run_starts(run_starts, padded_n))}
 
-    def _row_step_counts(self, staged: bool, row_steps: int,
-                         walked_tokens: int):
+    def _row_step_counts(self, staged: bool, row_steps: int, walks):
         """`record_dispatch`'s account of what `row_steps` live
         row-substeps moved: the K/V pages their attention walked
-        (`walked_tokens` x a token's bytes over the layers with a paged
-        pool) and, besides the cache, a matrix state's recurrence
+        (`walks` as `_walks` gives them: the walked tokens x a token's
+        bytes over the layers that walk a paged pool, and of those bytes
+        the ones moved by layers that hold no pool of their own) and,
+        besides the cache, a matrix state's recurrence
         (a delta rule, a state-space scan; nothing for a model none of
         whose state arrays is updated in its pool:
         `ModelConfig.state_array_in_place`) and a multi-stream residual's
         mixes (nothing for a model without one)."""
+        walked_tokens, shared_walks = (int(n) for n in walks)
         counts = dict(walked_tokens=walked_tokens,
                       kv_walk_bytes=walked_tokens * self._kv_token_bytes)
+        if self._shared_walkers:
+            counts.update(shared_kv_walk_bytes=(
+                shared_walks * self._kv_layer_token_bytes))
         if self._matrix_state_bytes:
             counts.update(
                 linear_form="step" if staged else "chunk",
@@ -1125,26 +1140,36 @@ class ModelRunner:
                        walkers=None) -> int:
         """Tokens the attention kernel's page walk moves for rows that
         must see `seen` tokens of their contexts under `w`-wide tables,
-        a layer: the mean over the model's paged layers, of which those
-        with a sliding window walk from the page of the first position a
-        query that ends at `query_ends` (its position + 1) sees.
-        `walkers` (a `mixed` step, `_mixed_walkers`): the rows that walk;
-        the others ride in a walker's tile and move nothing."""
+        a layer: the mean over the layers that walk a paged pool, of
+        which those with a sliding window walk from the page of the
+        first position a query that ends at `query_ends` (its position
+        + 1) sees. `walkers` (a `mixed` step, `_mixed_walkers`): the
+        rows that walk; the others ride in a walker's tile and move
+        nothing."""
+        return int(self._walks(seen, query_ends, w, walkers)[0])
+
+    def _walks(self, seen, query_ends, w: int, walkers=None):
+        """(`_walked_tokens`, the tokens moved by the walks of layers
+        that hold no pool and read a full layer's, summed over those
+        layers: 0 for a model without such readers), an int64 pair."""
         seen = np.asarray(seen, np.int64)
         full_layers, window_layers = self._layers_by_reach
-        walked = 0
+        walked = shared = 0
 
         def moved(pages) -> int:
             return int((pages if walkers is None else pages[walkers]).sum())
         if full_layers:
             pages, _ = walk_extent(seen, self.block_size, w)
-            walked += full_layers * moved(pages)
+            full_pages = moved(pages)
+            walked += full_layers * full_pages
+            shared = self._shared_walkers * full_pages * self.block_size
         if window_layers:
             lo = np.clip(np.asarray(query_ends, np.int64) - self._window,
                          0, None)
             pages, _ = walk_extent(seen, self.block_size, w, lo=lo)
             walked += window_layers * moved(pages)
-        return walked * self.block_size // (full_layers + window_layers)
+        return np.array([walked * self.block_size
+                         // (full_layers + window_layers), shared], np.int64)
 
     def _mixed_walkers(self, run_starts, padded_n: int, w: int):
         """(which of a `mixed` step's live rows walk their context, the
@@ -1174,29 +1199,35 @@ class ModelRunner:
                 "window": int(np.minimum(ends, self._window).sum())}
 
     def _decode_counts(self, ctx: np.ndarray, num_steps: int, w: int):
-        """(walked tokens, attended tokens) of a K-step decode program
-        (`_decode_fn`): every substep of a staging chunk walks the pool
-        as the chunk found it, the context less the row's newest token
-        plus the chunks committed before; substep k's query ends at the
-        context + k."""
+        """(walks as `_walks` gives them, attended tokens) of a K-step
+        decode program (`_decode_fn`): every substep of a staging chunk
+        walks the pool as the chunk found it, the context less the row's
+        newest token plus the chunks committed before; substep k's query
+        ends at the context + k."""
         chunk = self.decode_chunk if self.decode_chunk > 0 else num_steps
         live = ctx > 0
-        walked = 0
+        walked = np.zeros(2, np.int64)
         for base in range(0, num_steps, chunk):
             pool = np.where(live, np.minimum(ctx - 1 + base,
                                              self.max_model_len), 0)
             substeps = min(chunk, num_steps - base)
             if not self._window:
-                walked += substeps * self._walked_tokens(pool, None, w)
+                walked += substeps * self._walks(pool, None, w)
                 continue
             k = np.arange(substeps)[:, None]
-            walked += self._walked_tokens(
+            walked += self._walks(
                 np.broadcast_to(pool, (substeps, ) + pool.shape),
                 pool[None, :] + k + 1, w)
         if not self._window:
             return walked, None
         ends = (ctx[live][None, :] + np.arange(num_steps)[:, None])
         return walked, self._attended_tokens(ends, int(ends.sum()))
+
+    def table_width(self, blocks: int) -> int:
+        """The padded width of a step's block tables whose longest holds
+        `blocks` entries."""
+        return pad_to_bucket(max(blocks, self.min_table_width),
+                             self.mixed_token_buckets)
 
     def decode_batch_bucket(self, rows: int) -> int:
         """The padded batch a fresh decode step of `rows` rows runs at."""
@@ -1229,9 +1260,7 @@ class ModelRunner:
                     row_slots.append(meta.state_slots[seq_id])
 
         b = self.decode_batch_bucket(len(rows))
-        w = pad_to_bucket(max(max(len(t) for t in tables),
-                              _MIN_BLOCK_TABLE_WIDTH),
-                          self.mixed_token_buckets)
+        w = self.table_width(max(len(t) for t in tables))
 
         token_ids, positions, context_lens, block_tables = \
             build_decode_batch(tables, tokens, poss, ctxs, b, w)
@@ -1505,9 +1534,8 @@ class ModelRunner:
         if num_steps == 1:
             # The single-step program walks a row's context less its own
             # token, as every `mixed` step does.
-            walked = self._walked_tokens(eff_info["ctxs"] - 1,
-                                         eff_info["ctxs"],
-                                         eff_info["width_padded"])
+            walked = self._walks(eff_info["ctxs"] - 1, eff_info["ctxs"],
+                                 eff_info["width_padded"])
             attended = self._attended_tokens(eff_info["ctxs"],
                                              int(eff_info["ctxs"].sum()))
             tile_rows = {"one": real_rows}
@@ -1740,9 +1768,7 @@ class ModelRunner:
                     [slot_of[i] for i in proc_rows] if head_kwargs
                     else proc_rows)
 
-            w = pad_to_bucket(max(max(len(t) for t in tables),
-                                  _MIN_BLOCK_TABLE_WIDTH),
-                              self.mixed_token_buckets)
+            w = self.table_width(max(len(t) for t in tables))
             token_ids, positions, context_lens, block_tables = \
                 build_decode_batch(tables, tokens, poss, ctxs, padded_n, w)
 
@@ -1822,7 +1848,7 @@ class ModelRunner:
                             ctx_arr, int(ctx_arr.sum())),
                         tile_rows=tile_rows, head_rows=head_rows,
                         **self._row_step_counts(
-                            False, len(rows), self._walked_tokens(
+                            False, len(rows), self._walks(
                                 ctx_arr - 1, ctx_arr, w, walkers)))
         if n_chunk_groups:
             self._efficiency.record_dispatch(
@@ -1884,9 +1910,8 @@ class ModelRunner:
             b = cont.ctx0.shape[0]
             ctx = cont.ctx0.astype(np.int32)
             positions = np.maximum(ctx - 1, 0).astype(np.int32)[:, None]
-            w = pad_to_bucket(max(max((len(t) for t in tables), default=1),
-                                  _MIN_BLOCK_TABLE_WIDTH),
-                              self.mixed_token_buckets)
+            w = self.table_width(
+                max((len(t) for t in tables), default=1))
             block_tables = np.zeros((b, w), np.int32)
             for slot, t in zip(cont.slots, tables):
                 block_tables[slot, :len(t)] = t

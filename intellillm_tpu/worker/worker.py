@@ -349,7 +349,10 @@ class Worker:
         # default) warms the exact pre-sentinel call structure.
         from intellillm_tpu.obs import get_numerics_tracker
         num_on = get_numerics_tracker().enabled
-        widths = buckets[:2] if full else buckets[:1]
+        # From the narrowest width a step can take
+        # (--min-block-table-width).
+        first = buckets.index(runner.table_width(1))
+        widths = buckets[first:first + (2 if full else 1)]
         for b in batch_sizes:
             zeros_i = place(np.zeros((b, 1), np.int32))
             # A LoRA-enabled engine passes the lora pytree on EVERY
@@ -408,7 +411,7 @@ class Worker:
                     self.cache_engine.device_cache = caches
                     n += 1
                     if (full and not flags["do_random"] and b == top
-                            and w == buckets[0]):
+                            and w == widths[0]):
                         # Passing fetch_indices changes the jit arg
                         # pytree (logits_processors escape path) —
                         # warm it too, so the first processor-bearing

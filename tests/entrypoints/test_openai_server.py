@@ -271,3 +271,60 @@ def test_client_disconnect_aborts_request(openai_server):
 
     body = asyncio.run(run())
     assert body["choices"][0]["text"] is not None
+
+
+def test_dropped_stream_closes_its_generator():
+    """`_streaming_response` alone, no engine, behind a runner configured
+    as `web.run_app` configures the real one (a handler is NOT cancelled
+    when its connection drops; aiohttp's test server would cancel it): the
+    next write fails, and the handler closes the generator it was fed from
+    (that close is what aborts the request in the engine) and ends quietly.
+    A stream nobody reads must not run to its end."""
+    from aiohttp import web
+    from intellillm_tpu.entrypoints.openai.api_server import (
+        _streaming_response)
+
+    seen = {"chunks": 0, "closed": False}
+
+    async def endless():
+        try:
+            while True:
+                seen["chunks"] += 1
+                yield "data: " + "x" * 4096 + "\n\n"
+                await asyncio.sleep(0.005)
+        finally:
+            seen["closed"] = True
+
+    async def handler(request):
+        # Held here as the engine's producer tasks hold the real one: no
+        # garbage collection closes it for the handler.
+        seen["generator"] = endless()
+        return await _streaming_response(request, seen["generator"],
+                                         "cmpl-test")
+
+    async def run():
+        app = web.Application()
+        app.router.add_post("/v1/completions", handler)
+        runner = web.AppRunner(app)
+        await runner.setup()
+        site = web.TCPSite(runner, "127.0.0.1", 0)
+        await site.start()
+        port = site._server.sockets[0].getsockname()[1]
+        try:
+            async with aiohttp.ClientSession() as s:
+                resp = await s.post(
+                    f"http://127.0.0.1:{port}/v1/completions")
+                assert resp.headers["X-Request-Id"] == "cmpl-test"
+                await resp.content.readany()
+                resp.close()
+            for _ in range(200):
+                if seen["closed"]:
+                    break
+                await asyncio.sleep(0.025)
+            # Read here: asyncio.run() closes what is left open as it ends.
+            return seen["chunks"], seen["closed"]
+        finally:
+            await runner.cleanup()
+
+    chunks, closed = asyncio.run(run())
+    assert closed, f"still streaming after {chunks} chunks"

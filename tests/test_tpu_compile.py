@@ -1226,6 +1226,126 @@ def test_window_group_model_programs_compile(compiled_for_chip, one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024**3
 
 
+# --- scans, a window group and one shared full pool in one model -------------
+
+@pytest.mark.parametrize("program", ["mixed", "decode_cont"])
+def test_shared_pool_hybrid_model_programs_compile(compiled_for_chip,
+                                                   one_chip, tmp_path,
+                                                   program):
+    """`phi-4-mini-flash-reasoning`'s programs as its cell dispatches them
+    (`mixed` at 512 rows, `decode_cont` at 64 rows, both at 256-block
+    tables of a 4,096-token model length, K=8, bf16, published widths and
+    the whole 200,064-row head; depth cut to 8 layers, which the model's own
+    rule lays out as scan, window, scan, window, scan (gives `m`), full,
+    GMU, cross) compile for the described v5e with the kernels on: the
+    Mamba-1 scan's kernel of that program, the filter's, the windowed and
+    the unwindowed attention kernels side by side at a stored head of 128
+    (two 64-wide heads: no padding), the full pool read by two layers. The
+    decode program carries the three 21 MB matrix pools through the K
+    substeps without a copy of one: its temporaries stay under one pool's
+    size beside the staged logits."""
+    import json
+
+    from intellillm_tpu.config import (CacheConfig, ModelConfig,
+                                       ParallelConfig, SchedulerConfig)
+    from intellillm_tpu.models import get_model_class
+    from intellillm_tpu.ops.dispatch import set_use_pallas
+    from intellillm_tpu.worker.cache_engine import CacheEngine
+    from intellillm_tpu.worker.model_runner import ModelRunner
+
+    from chipbench import CHIP_DIR      # puts the harness on sys.path
+    from harness.server import hf_config_of
+
+    with open(os.path.join(CHIP_DIR, "configs",
+                           "phi-4-mini-flash-reasoning.json")) as f:
+        cell = json.load(f)
+    serve = cell["serve"]
+    hf = dict(hf_config_of(cell, rehearsal=False), num_hidden_layers=8)
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    model_config = ModelConfig(
+        model=str(tmp_path), dtype=serve["dtype"], load_format="dummy",
+        max_model_len=serve["max_model_len"])
+    kinds = model_config.get_layer_cache_kinds()
+    assert kinds == ["state", "kv_window", "state", "kv_window", "state",
+                     "kv"]
+    assert model_config.get_layer_cache_entries() == [0, 1, 2, 3, 4, 5,
+                                                      None, 5]
+    assert model_config.get_cache_walkers() == (2, 2, 1)
+    assert model_config.get_window_group_size() == 512
+    assert model_config.get_cache_row_shape() == (2, 10, 128)
+    model = get_model_class(model_config.hf_config.architectures)(
+        model_config)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init_random_params(0)))
+    blocks, rows, width, k = 16000, 64, 256, CELL_K
+    cache_config = CacheConfig(
+        num_device_blocks_override=blocks, swap_space_gib=0.01,
+        window_group_size=model_config.get_window_group_size())
+    cache_config.window_ahead_tokens = 512
+    cache_config.num_device_blocks = blocks
+    cache_config.size_window_group(model_config)
+    assert cache_config.num_window_device_blocks == 4000
+    cache_config.num_state_slots = rows
+    set_use_pallas(True)        # jax.default_backend() is "cpu" here
+    try:
+        runner = ModelRunner(
+            model, params, model_config,
+            SchedulerConfig(max_num_batched_tokens=512, max_num_seqs=rows,
+                            max_model_len=serve["max_model_len"],
+                            enable_chunked_prefill=True),
+            cache_config, ParallelConfig())
+
+        def sds(dtype, *shape):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        bf16, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
+        state = tuple(
+            sds(jnp.dtype(dtype), *CacheEngine.state_pool_shape(shape, rows))
+            for shape, dtype in model_config.get_state_arrays())
+        assert [s.shape for s in state] == [(64, 15360), (65, 40, 16, 128)]
+        kv = [state if kind == "state" else
+              (sds(bf16, 4000 if kind == "kv_window" else blocks, 10, 16,
+                   128), ) * 2 for kind in kinds]
+        assert not jax.config.jax_enable_compilation_cache
+        if program == "mixed":
+            compiled = runner.lower_mixed_step(
+                kv, 512, width, do_random=False).compile()
+        else:
+            b, w = rows, width
+            rest = (sds(i32, b, 1), sds(i32, b, w), sds(i32, b),
+                    sds(f32, b), sds(i32, b), sds(f32, b), sds(f32, b),
+                    sds(jnp.uint32, b), sds(f32, b), sds(f32, b),
+                    sds(f32, b), None, None)
+            compiled = runner._jit_decode_cont.lower(
+                params, kv, sds(i32, b, 4 * k), sds(i32, b, 1),
+                sds(jnp.bool_, b, 1), *rest, state_slots=sds(i32, b),
+                window_tables=sds(i32, b, w), prev_t1=k, num_steps=k,
+                logprob_k=1, do_topk=False, do_topp=False, do_minp=False,
+                do_penalties=False, do_random=False).compile()
+    finally:
+        set_use_pallas(None)
+    text = compiled.as_text()
+    scan, kernel, windowed = (
+        ("_sscan_chunk_call", "_ragged_call", "_ragged_call_window")
+        if program == "mixed" else
+        ("_sscan_step_call", "_paged_attention_call",
+         "_paged_attention_call_window"))
+    assert scan in text and "_conv_silu_call" in text
+    assert windowed in text and re.search(kernel + r"(?!_window)\b", text)
+    assert _row_loops(text, "bf16[64,15360]") == []
+    memory = compiled.memory_analysis()
+    print(program, "temporaries", memory.temp_size_in_bytes / 1e6, "MB")
+    if program == "decode_cont":
+        # No copy of a matrix pool [65, 40, 16, 128] (21 MB: three of them
+        # would be the temporaries' size again), and the temporaries
+        # under one pool and one substep's float32 logits.
+        assert _stack_sized_copies(text, (65, 640, 128, None)) == []
+        pool_bytes = 65 * 40 * 16 * 128 * 4
+        logits = rows * 200064 * 4
+        assert memory.temp_size_in_bytes < pool_bytes + logits
+
+
 # --- latent attention under a multi-stream residual --------------------------
 
 XING_BLOCKS = 4500

@@ -24,7 +24,8 @@ from intellillm_tpu.worker.worker import Worker
 
 
 def _make_worker(num_decode_steps, max_model_len=128,
-                 max_num_batched_tokens=2048, enable_chunked_prefill=False):
+                 max_num_batched_tokens=2048, enable_chunked_prefill=False,
+                 **scheduler_kwargs):
     from transformers import LlamaConfig
 
     # Smallest config that still exercises GQA: warm-up sweeps compile
@@ -48,7 +49,7 @@ def _make_worker(num_decode_steps, max_model_len=128,
         max_model_len=max_model_len,
         max_paddings=512,
         num_decode_steps=num_decode_steps,
-        enable_chunked_prefill=enable_chunked_prefill)
+        enable_chunked_prefill=enable_chunked_prefill, **scheduler_kwargs)
     worker = Worker(model_config, ParallelConfig(), scheduler_config,
                     cache_config)
     worker.init_model()
@@ -100,6 +101,41 @@ def test_warm_up_count_invariant_under_kernel_flags(monkeypatch,
     sel = worker.warmup_stats["kernel_selection"]
     assert sel["ragged"] is True
     assert sel["bgmv"] is True
+
+
+@pytest.mark.parametrize("asked, widths", [
+    (16, {1: 16, 17: 32, 40: 64, 64: 64}),     # the default: every bucket
+    (40, {1: 64, 17: 64, 40: 64, 64: 64}),     # made up to a bucket
+    (4096, {1: 64, 17: 64, 40: 64, 64: 64}),   # never past the widest
+])
+def test_min_block_table_width_is_the_narrowest_width(monkeypatch, asked,
+                                                      widths):
+    """--min-block-table-width: no step's block tables are padded to less,
+    so a deployment of long prompts compiles one width in place of one a
+    bucket; the boot's warm-up starts from it."""
+    worker = _make_worker(4, max_model_len=1024, max_num_batched_tokens=64,
+                          enable_chunked_prefill=True,
+                          min_block_table_width=asked)
+    runner = worker.model_runner
+    assert runner.max_table_width == 64
+    assert {n: runner.table_width(n) for n in widths} == widths
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    warmed, jitted = set(), runner._jit_decode_single
+
+    def spy(params, caches, tokens, positions, block_tables, *a, **kw):
+        warmed.add(block_tables.shape[1])
+        return jitted(params, caches, tokens, positions, block_tables,
+                      *a, **kw)
+
+    runner._jit_decode_single = spy
+    assert worker.warm_up_model() == 2
+    assert warmed == {widths[1]}
+
+
+def test_min_block_table_width_is_checked():
+    with pytest.raises(ValueError, match="min_block_table_width"):
+        SchedulerConfig(max_num_seqs=8, max_model_len=128,
+                        min_block_table_width=0)
 
 
 def test_warm_up_skipped_on_cpu():
