@@ -29,7 +29,8 @@ from intellillm_tpu.logger import init_logger
 from intellillm_tpu.obs import (get_alert_manager, get_boot_timeline,
                                 get_compile_tracker, get_device_telemetry,
                                 get_efficiency_tracker,
-                                get_flight_recorder, get_metrics_history,
+                                get_flight_recorder, get_heap,
+                                get_metrics_history,
                                 get_numerics_tracker, get_slo_tracker,
                                 get_step_tracer, get_watchdog,
                                 request_context)
@@ -79,6 +80,9 @@ class LLMEngine:
         length_predictor=None,
         skip_tokenizer_init: bool = False,
     ) -> None:
+        # The collector's seconds count from here, the boot's included
+        # (obs/heap.py).
+        get_heap().watch()
         logger.info(
             "Initializing intellillm-tpu engine: model=%s dtype=%s tp=%d "
             "policy=%s max_model_len=%d", model_config.model,
@@ -317,6 +321,10 @@ class LLMEngine:
         self._alerts.attach(self._history)
         self._history.attach()
         self._boot.mark_complete()
+        # Everything alive now lives as long as the server: out of the
+        # collector's sight with it, and an earlier engine's heap given
+        # back (obs/heap.py).
+        get_heap().engine_stands()
 
     def kv_cache_usage(self) -> dict:
         """KV-cache fill fractions (device HBM + CPU swap), 0..1."""

@@ -11,6 +11,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from intellillm_tpu.logger import init_logger
+from intellillm_tpu.obs.heap import GENERATIONS, get_heap
 
 logger = init_logger(__name__)
 
@@ -147,6 +148,21 @@ class _Metrics:
             "the engine had work, by the host phase (or loop_gap, "
             "unannotated) that passed meanwhile (obs.tracing).",
             list(labelnames) + ["phase"])
+        self.counter_gc_pause = Counter(
+            "intellillm_gc_pause_seconds_total",
+            "Seconds the process stood still in Python's garbage "
+            "collector, by the generation collected (obs.heap).",
+            list(labelnames) + ["generation"])
+        self.counter_gc_collections = Counter(
+            "intellillm_gc_collections_total",
+            "Collections of Python's garbage collector, by the generation "
+            "collected (obs.heap).",
+            list(labelnames) + ["generation"])
+        self.gauge_gc_frozen = Gauge(
+            "intellillm_gc_frozen_objects",
+            "Objects in the collector's permanent generation, which no "
+            "collection walks, as of the last freeze (obs.heap).",
+            labelnames)
 
     @classmethod
     def reset_for_testing(cls) -> None:
@@ -196,6 +212,21 @@ class StatLogger:
                 self.metrics.counter_device_busy.labels(*lv, program)
             for phase in IDLE_PHASES:
                 self.metrics.counter_device_idle.labels(*lv, phase)
+            for generation in GENERATIONS:
+                self.metrics.counter_gc_pause.labels(*lv, generation)
+                self.metrics.counter_gc_collections.labels(*lv, generation)
+            self._export_heap()
+
+    def _export_heap(self) -> None:
+        """The collector's seconds and counts since the last export
+        (obs/heap.py keeps them; its hook touches no collector of
+        ours)."""
+        m, lv = self.metrics, self.labels.values()
+        heap = get_heap()
+        for generation, seconds, n in heap.drain():
+            m.counter_gc_pause.labels(*lv, generation).inc(seconds)
+            m.counter_gc_collections.labels(*lv, generation).inc(n)
+        m.gauge_gc_frozen.labels(*lv).set(heap.frozen_objects)
 
     def _throughput(self, tracked: List[int], now: float) -> float:
         elapsed = now - self.last_local_log
@@ -232,6 +263,7 @@ class StatLogger:
                 m.counter_device_busy.labels(*lv, program).inc(secs)
             for phase, secs in stats.device_idle.items():
                 m.counter_device_idle.labels(*lv, phase).inc(secs)
+            self._export_heap()
 
         self.num_prompt_tokens.append(stats.num_prompt_tokens)
         self.num_generation_tokens.append(stats.num_generation_tokens)

@@ -108,8 +108,9 @@ from intellillm_tpu.obs import (EVENTS, explain_request, get_alert_manager,
                                 get_boot_timeline, get_compile_tracker,
                                 get_decision_log, get_device_telemetry,
                                 get_efficiency_tracker,
-                                get_flight_recorder, get_kernel_ledger,
-                                get_metrics_history, get_slo_tracker,
+                                get_flight_recorder, get_heap,
+                                get_kernel_ledger, get_metrics_history,
+                                get_slo_tracker,
                                 get_step_tracer, get_watchdog,
                                 get_workload_log)
 from intellillm_tpu.prediction import get_prediction_service
@@ -332,6 +333,9 @@ def add_debug_routes(app: web.Application,
             # Device seconds by program and dry seconds by host phase
             # since start, profiler off (obs/tracing.py).
             "device_clock": get_step_tracer().device_totals(),
+            # The collector's seconds and counts by generation, and what
+            # the freeze points took out of its sight (obs/heap.py).
+            "heap": get_heap().snapshot(),
             "live_requests": len(get_flight_recorder().live_request_ids()),
             # Fleet contention ledger: deferred seconds by cause +
             # decision counts (per-request decomposition at

@@ -22,6 +22,7 @@ from intellillm_tpu.obs.efficiency import (EfficiencyTracker,
                                            get_efficiency_tracker)
 from intellillm_tpu.obs.flight_recorder import (EVENTS, FlightRecorder,
                                                 get_flight_recorder)
+from intellillm_tpu.obs.heap import get_heap
 from intellillm_tpu.obs.history import MetricsHistory, get_metrics_history
 from intellillm_tpu.obs.kernels import (KernelLedger, get_kernel_ledger,
                                         load_profile, reduce_profile)
@@ -85,6 +86,7 @@ __all__ = [
     "get_device_telemetry",
     "get_efficiency_tracker",
     "get_flight_recorder",
+    "get_heap",
     "get_kernel_ledger",
     "get_kv_audit",
     "get_kv_transfer_stats",

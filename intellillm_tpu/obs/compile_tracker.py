@@ -63,6 +63,7 @@ from typing import Any, Callable, Deque, Dict, Hashable, List, Optional, Set
 
 from intellillm_tpu.logger import init_logger
 from intellillm_tpu.obs.boot import PRE_ENGINE_PHASES, get_boot_timeline
+from intellillm_tpu.obs.heap import get_heap
 from intellillm_tpu.obs.kernels import KNOWN_PROGRAMS
 
 logger = init_logger(__name__)
@@ -427,6 +428,9 @@ class CompileTracker:
         acct.close(elapsed)
         tls.last = acct
         self._record_compile(acct, elapsed)
+        # What the miss built (the program's jaxpr, its executable, jax's
+        # cache entries) lives as long as the server (obs/heap.py).
+        get_heap().first_call_done()
         return out
 
     @contextmanager
