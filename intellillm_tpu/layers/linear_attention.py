@@ -11,6 +11,13 @@ Per head of `d` key and value channels, `x` the layer's normed input:
     o = delta rule over (q, k, v, g, b)          (ops/linear_attention.py)
     y = [RMSNorm_head(o) * w_norm * sigmoid(W_g2 (W_g1 x))] W_o
 
+What a family may change, each before the seam (`ops/linear_attention.py`
+takes `g` and `b` as they come): the decay bounded below (`lower_bound`:
+`g = lower_bound * sigmoid(exp(A_log) * (. + dt_bias))`, in (lower_bound,
+0), in place of the softplus form); the decay's and the output gate's
+projections at full rank (a layer that holds `f` / `g` and no `f_a`, `f_b`
+/ `g_a`, `g_b` pair); the write strength without the 2 (`beta_scale` 1).
+
 What a sequence keeps between steps, one slot each in the layer's two
 pools (`ModelConfig.get_state_arrays`): the last three rows of
 `[q~ | k~ | v~]` (the filters' taps, oldest first, in the model's dtype)
@@ -51,14 +58,32 @@ def _l2_normalise(x: jnp.ndarray) -> jnp.ndarray:
     return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + _NORM_EPS)
 
 
-def kda_gates(x, lp, num_heads: int, head_dim: int):
+def _gate_projection(x, lp, name: str):
+    """`x` through the layer's low-rank pair `<name>_a`, `<name>_b`, or
+    through its one full-rank matrix `<name>` where it holds that."""
+    if name in lp:
+        return x @ lp[name]
+    return (x @ lp[name + "_a"]) @ lp[name + "_b"]
+
+
+def kda_gates(x, lp, num_heads: int, head_dim: int, lower_bound=None,
+              beta_scale: float = 2.0):
     """(g [B, H, d] float32 = the log of the decay, b [B, H] float32) of
-    the layer's input `x` [B, E]."""
+    the layer's input `x` [B, E]. `lower_bound` None: `g = -exp(A_log) *
+    softplus(.)`; a number (< 0): `g = lower_bound * sigmoid(exp(A_log) *
+    .)`."""
     f32 = jnp.float32
-    raw = ((x @ lp["f_a"]) @ lp["f_b"]).astype(f32) + lp["dt_bias"].astype(f32)
-    g = (-jnp.exp(lp["A_log"].astype(f32))[None, :, None]
-         * jax.nn.softplus(raw).reshape(-1, num_heads, head_dim))
-    beta = 2.0 * jax.nn.sigmoid((x @ lp["b_proj"]).astype(f32))
+    raw = _gate_projection(x, lp, "f").astype(f32) + lp["dt_bias"].astype(f32)
+    if lower_bound is None:
+        g = (-jnp.exp(lp["A_log"].astype(f32))[None, :, None]
+             * jax.nn.softplus(raw).reshape(-1, num_heads, head_dim))
+    else:
+        g = lower_bound * jax.nn.sigmoid(
+            jnp.exp(lp["A_log"].astype(f32))[None, :, None]
+            * raw.reshape(-1, num_heads, head_dim))
+    beta = jax.nn.sigmoid((x @ lp["b_proj"]).astype(f32))
+    if beta_scale != 1.0:
+        beta = beta_scale * beta
     return g, beta
 
 
@@ -118,9 +143,11 @@ def store_taps(taps, new_taps, attn_metadata, last):
 
 
 def kda_mixer(h, lp, cache, attn_metadata, positions, *, num_heads: int,
-              head_dim: int, eps: float):
+              head_dim: int, eps: float, lower_bound=None,
+              beta_scale: float = 2.0):
     """`h` [B, 1, E] -> ([B, 1, E], cache). `cache` is (taps pool, matrix
-    pool) or, staged, (taps stage, matrix pool)."""
+    pool) or, staged, (taps stage, matrix pool). `lower_bound`,
+    `beta_scale`: `kda_gates`'s."""
     b, l, e = h.shape
     assert l == 1, "a step's rows are one token each"
     x = h.reshape(b, e)
@@ -135,14 +162,14 @@ def kda_mixer(h, lp, cache, attn_metadata, positions, *, num_heads: int,
         b, num_heads, head_dim) for i in range(3))
     q = _l2_normalise(q) * head_dim ** -0.5
     k = _l2_normalise(k)
-    g, beta = kda_gates(x, lp, num_heads, head_dim)
+    g, beta = kda_gates(x, lp, num_heads, head_dim, lower_bound, beta_scale)
     new_taps = later_taps(prev, pre, taps.dtype)
     if attn_metadata.staged:
         o, matrices = kda_step(q, k, v, g, beta, matrices, slots)
     else:
         o, matrices = kda_chunk(q, k, v, g, beta, matrices, slots, pos)
     cache = (store_taps(taps, new_taps, attn_metadata, last), matrices)
-    gate = jax.nn.sigmoid(((x @ lp["g_a"]) @ lp["g_b"]).astype(jnp.float32))
+    gate = jax.nn.sigmoid(_gate_projection(x, lp, "g").astype(jnp.float32))
     o = rms_norm(o, lp["o_norm"].astype(jnp.float32), eps)
     y = (o.reshape(b, width // 3) * gate).astype(h.dtype)
     return (y @ lp["o"]).reshape(b, 1, e), cache

@@ -157,14 +157,20 @@ class LatentAttentionBlock:
 
     in the absorbed form: `W_kvb` is stored split per head as `w_uk [H,
     d_nope, r_kv]` and `w_uv [H, r_kv, d_v]` (`W_kvb,h = [W_UK,h |
-    W_UV,h]`). Stateless: the weights are the entries `param_shapes` names
-    in the caller's layer dict."""
+    W_UV,h]`). Two things a family may state otherwise: `q_lora_rank`
+    null = the query has no low-rank stage, `[q_nope | q_rope]_h = h W_q`
+    (one matrix `q`, no norm); `gated_attention_proj_granularity_type`
+    "head_wise" = head `h`'s outputs are multiplied by `sigmoid(h
+    W_gate)_h`, one scalar a head (`attn_gate`), before `W_o`. Stateless:
+    the weights are the entries `param_shapes` names in the caller's layer
+    dict."""
 
     def __init__(self, cfg, max_model_len: int) -> None:
         """`cfg`: a config with the family's keys (`num_attention_heads`,
         `q_lora_rank`, `kv_lora_rank`, `qk_nope_head_dim`,
         `qk_rope_head_dim`, `v_head_dim`, `rms_norm_eps`, `rope_theta`,
-        `rope_scaling`, `max_position_embeddings`)."""
+        `rope_scaling`, `max_position_embeddings`; optionally
+        `gated_attention_proj_granularity_type`)."""
         self.num_heads = cfg.num_attention_heads
         self.q_rank = cfg.q_lora_rank
         self.kv_rank = cfg.kv_lora_rank
@@ -172,6 +178,14 @@ class LatentAttentionBlock:
         self.d_rope = cfg.qk_rope_head_dim
         self.d_v = cfg.v_head_dim
         self.rms_eps = cfg.rms_norm_eps
+        granularity = getattr(cfg, "gated_attention_proj_granularity_type",
+                              None)
+        if granularity not in (None, "head_wise"):
+            raise NotImplementedError(
+                f"gated_attention_proj_granularity_type {granularity!r}: "
+                f"the latent block's output gate is one scalar a head "
+                f"(\"head_wise\") or absent")
+        self.head_gate = granularity == "head_wise"
         scaling = getattr(cfg, "rope_scaling", None)
         # YaRN's table has factor x original positions (131,072 rows, 34 MB
         # for cos and sin): keep the rows a position can reach.
@@ -194,8 +208,12 @@ class LatentAttentionBlock:
         b, l, _ = h.shape
         hh = self.num_heads
         with jax.named_scope("mla_q_proj"):
-            c_q = rms_norm(h @ lp["q_a"], lp["q_a_norm"], self.rms_eps)
-            q = (c_q @ lp["q_b"]).reshape(b, l, hh, self.d_nope + self.d_rope)
+            if self.q_rank:
+                c_q = rms_norm(h @ lp["q_a"], lp["q_a_norm"], self.rms_eps)
+                q = c_q @ lp["q_b"]
+            else:
+                q = h @ lp["q"]
+            q = q.reshape(b, l, hh, self.d_nope + self.d_rope)
             q_nope, q_rope = q[..., :self.d_nope], q[..., self.d_nope:]
         with jax.named_scope("mla_kv_proj"):
             ckv = h @ lp["kv_a"]
@@ -211,34 +229,49 @@ class LatentAttentionBlock:
                                     layer)
         with jax.named_scope("mla_absorb_o"):
             o = jnp.einsum("blhr,hrv->blhv", o_lat, lp["w_uv"])
+        if self.head_gate:
+            with jax.named_scope("mla_head_gate"):
+                gate = jax.nn.sigmoid(
+                    (h @ lp["attn_gate"]).astype(jnp.float32))
+                o = (o.astype(jnp.float32) * gate[..., None]).astype(h.dtype)
         return o.reshape(b, l, hh * self.d_v) @ lp["o"], kv_cache
 
     def param_shapes(self, hidden: int) -> dict:
         """The sublayer's entries of a model's `_param_shapes`, in the
         order the families' seeded draws walk them."""
         hh = self.num_heads
-        return {
-            "q_a": (hidden, self.q_rank), "q_a_norm": (self.q_rank, ),
-            "q_b": (self.q_rank, hh * (self.d_nope + self.d_rope)),
+        d_q = hh * (self.d_nope + self.d_rope)
+        shapes = ({"q_a": (hidden, self.q_rank), "q_a_norm": (self.q_rank, ),
+                   "q_b": (self.q_rank, d_q)} if self.q_rank
+                  else {"q": (hidden, d_q)})
+        shapes.update({
             "kv_a": (hidden, self.kv_rank + self.d_rope),
             "kv_a_norm": (self.kv_rank, ),
             "w_uk": (hh, self.d_nope, self.kv_rank),
             "w_uv": (hh, self.kv_rank, self.d_v),
             "o": (hh * self.d_v, hidden),
-        }
+        })
+        if self.head_gate:
+            shapes["attn_gate"] = (hidden, hh)
+        return shapes
 
     def load(self, prefix: str, T, V) -> dict:
         """The sublayer's weights from a checkpoint under the DeepSeek-V3
         family's names (`<prefix>{q_a_proj, q_a_layernorm, q_b_proj,
-        kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj, o_proj}`): `T(key)`
-        gives a matrix transposed to `[in, out]`, `V(key)` an array as
-        stored, both cast."""
+        kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj, o_proj}`; `q_proj`
+        where the query has no low-rank stage, `g_proj` the head-wise
+        gate): `T(key)` gives a matrix transposed to `[in, out]`, `V(key)`
+        an array as stored, both cast."""
         kv_b = V(prefix + "kv_b_proj.weight").reshape(
             self.num_heads, self.d_nope + self.d_v, self.kv_rank)
+        q = ({"q_a": T(prefix + "q_a_proj.weight"),
+              "q_a_norm": V(prefix + "q_a_layernorm.weight"),
+              "q_b": T(prefix + "q_b_proj.weight")} if self.q_rank
+             else {"q": T(prefix + "q_proj.weight")})
+        gate = ({"attn_gate": T(prefix + "g_proj.weight")}
+                if self.head_gate else {})
         return {
-            "q_a": T(prefix + "q_a_proj.weight"),
-            "q_a_norm": V(prefix + "q_a_layernorm.weight"),
-            "q_b": T(prefix + "q_b_proj.weight"),
+            **q, **gate,
             "kv_a": T(prefix + "kv_a_proj_with_mqa.weight"),
             "kv_a_norm": V(prefix + "kv_a_layernorm.weight"),
             "w_uk": np.ascontiguousarray(kv_b[:, :self.d_nope, :]),

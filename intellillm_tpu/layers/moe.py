@@ -124,8 +124,10 @@ class Routing:
       "sigmoid" of each expert's logit alone.
     - `n_group` / `topk_group`: group-limited selection. The experts are
       `n_group` equal consecutive groups, a group's score is its largest
-      expert score, only experts of the best `topk_group` groups may be
-      chosen. 1 / 1 = no limit.
+      expert score (`group_score_top` 1) or the sum of its
+      `group_score_top` largest (2: DeepSeek-V3's `noaux_tc`), only
+      experts of the best `topk_group` groups may be chosen. 1 / 1 = no
+      limit.
     - `renormalize`: chosen weights divided by their sum (floored at
       1e-9), or by their sum plus `sum_eps` where a family adds an epsilon
       to it; `scale`: then multiplied by it (`routed_scaling_factor`).
@@ -147,6 +149,7 @@ class Routing:
     held_start: int = 0
     select_bias: bool = False
     sum_eps: float = 0.0
+    group_score_top: int = 1
 
 
 _SOFTMAX_TOP_K = Routing()
@@ -170,7 +173,12 @@ def _route(x: jnp.ndarray, gate_w: jnp.ndarray, top_k: int,
     if routing.n_group > 1:
         t, n = weights.shape
         per = n // routing.n_group
-        group_score = choose_from.reshape(t, routing.n_group, per).max(-1)
+        grouped = choose_from.reshape(t, routing.n_group, per)
+        if routing.group_score_top == 1:
+            group_score = grouped.max(-1)
+        else:
+            group_score = jax.lax.top_k(
+                grouped, routing.group_score_top)[0].sum(-1)
         _, best = jax.lax.top_k(group_score, routing.topk_group)
         keep = jnp.zeros((t, routing.n_group), bool).at[
             jnp.arange(t)[:, None], best].set(True)

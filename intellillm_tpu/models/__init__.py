@@ -10,6 +10,7 @@ from typing import Dict, Type
 from intellillm_tpu.models.axk1 import AXK1ForCausalLM
 from intellillm_tpu.models.baichuan import (BaiChuanForCausalLM,
                                             BaichuanForCausalLM)
+from intellillm_tpu.models.bailing_hybrid import BailingHybridForCausalLM
 from intellillm_tpu.models.bloom import BloomForCausalLM
 from intellillm_tpu.models.chatglm import ChatGLMForCausalLM
 from intellillm_tpu.models.deepseek import DeepseekForCausalLM
@@ -87,6 +88,9 @@ _MODEL_REGISTRY: Dict[str, Type] = {
     # Mamba-1 scans + window layers, then GMUs and cross layers over ONE
     # full-attention layer's pool; differential attention
     "Phi4FlashForCausalLM": Phi4FlashForCausalLM,
+    # delta-rule linear attention (matrix state) + latent attention (a
+    # latent pool) in one sequence, experts routed in groups, EP share
+    "BailingHybridForCausalLM": BailingHybridForCausalLM,
 }
 
 

@@ -289,6 +289,16 @@ def _ragged_kernel(context_lens_ref, tables_ref, slots_ref, q_ref, new_ref,
     lax.fori_loop(0, rows_per_step, one_row, 0)
 
 
+def _vmem_limit(dtype):
+    """A float32 pool (a `--dtype float32` run: the reference checks'
+    sharp version) doubles the walk's buffers and the query block, and
+    under `jax.default_matmul_precision("highest")` its products take
+    several passes with their temporaries: 25 MB at 32 heads, over the
+    compiler's default scope. The 2-byte pools of the serving cells keep
+    that default (None: their programs are what they were)."""
+    return (64 << 20) if jnp.dtype(dtype).itemsize > 2 else None
+
+
 def _launch_shapes(b, h, dk, bs, w, v_width, dtype):
     """(rows a grid step, pages a group, the walk's scratch, the
     accumulators) of a launch, all from its operands' shapes."""
@@ -337,7 +347,8 @@ def _paged_attention_call_mla(q, pool, block_tables, context_lens, *,
             jax.ShapeDtypeStruct((b, 1, h, 128), jnp.float32),
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", )),
+            dimension_semantics=("arbitrary", ),
+            vmem_limit_bytes=_vmem_limit(pool.dtype)),
     )(context_lens, block_tables.reshape(-1), q, pool)
     return out[:, 0], lse[:, 0, :, 0]
 
@@ -395,7 +406,8 @@ def _ragged_call_mla(q, new, pool, slot_mapping, block_tables,
         # operand 5, aliased onto output 1 for the in-place update.
         input_output_aliases={5: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", ), has_side_effects=True),
+            dimension_semantics=("arbitrary", ), has_side_effects=True,
+            vmem_limit_bytes=_vmem_limit(pool.dtype)),
     )(context_lens, block_tables.reshape(-1), slot_mapping, q, new, pool)
     return out[:, 0], pool
 

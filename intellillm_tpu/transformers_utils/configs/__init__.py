@@ -9,6 +9,8 @@ current transformers versions parse them natively.
 from intellillm_tpu.transformers_utils.configs.aquila import AquilaConfig
 from intellillm_tpu.transformers_utils.configs.axk1 import AXK1Config
 from intellillm_tpu.transformers_utils.configs.baichuan import BaichuanConfig
+from intellillm_tpu.transformers_utils.configs.bailing_hybrid import (
+    BailingHybridConfig)
 from intellillm_tpu.transformers_utils.configs.chatglm import ChatGLMConfig
 from intellillm_tpu.transformers_utils.configs.decilm import DeciLMConfig
 from intellillm_tpu.transformers_utils.configs.deepseek import DeepseekConfig
@@ -32,6 +34,7 @@ _CONFIG_REGISTRY = {
     "aquila": AquilaConfig,
     "axk1": AXK1Config,
     "baichuan": BaichuanConfig,
+    "bailing_hybrid": BailingHybridConfig,
     "chatglm": ChatGLMConfig,
     "deci": DeciLMConfig,
     "deepseek": DeepseekConfig,
@@ -49,7 +52,8 @@ _CONFIG_REGISTRY = {
 }
 
 __all__ = [
-    "AquilaConfig", "AXK1Config", "BaichuanConfig", "ChatGLMConfig", "DeciLMConfig",
+    "AquilaConfig", "AXK1Config", "BaichuanConfig", "BailingHybridConfig",
+    "ChatGLMConfig", "DeciLMConfig",
     "DeepseekConfig", "FalconH1Config", "GraniteMoeHybridConfig", "InternLMConfig",
     "Lfm2MoeConfig", "Phi4FlashConfig", "QWenConfig",
     "SmallThinkerConfig", "SolarOpen2Config", "Xing4Config", "YiConfig",

@@ -607,6 +607,43 @@ def test_mla_ragged_paged_attention_compiles(compile_for_chip, rows, width,
             for dims in re.findall(r"\[([\d,]+)\]", line.split("=")[1]))]
 
 
+@pytest.mark.parametrize("kernel", ["ragged", "paged"])
+def test_mla_kernels_compile_for_a_float32_pool_at_highest_precision(
+        compile_for_chip, kernel):
+    """A `--dtype float32` run under `jax.default_matmul_precision(
+    "highest")` (the reference checks' sharp version) at 32 heads and
+    512-block tables: the float32 walk buffers, query block and the
+    products' passes take 25 MB of VMEM, over the compiler's default scope
+    (on the chip: `Scoped allocation with size 25.37M and limit 24.00M`,
+    my chip run c1, PR 54), so the wrappers ask for a wider one for a pool
+    wider than 2 bytes (`_vmem_limit`) and for no other."""
+    from intellillm_tpu.ops.pallas import mla_attention as mla
+    assert mla._vmem_limit(jnp.bfloat16) is None
+    assert mla._vmem_limit(jnp.float32) == 64 << 20
+    f32 = jnp.float32
+    rows, w = (256, 512) if kernel == "ragged" else (16, 512)
+
+    def ragged(q, new, pool, slots, tables, ctx):
+        return mla.mla_ragged_paged_attention(q, new, pool, slots, tables,
+                                              ctx, MLA_SCALE, MLA_DV)
+
+    def paged(q, pool, tables, ctx):
+        return mla.mla_paged_attention(q, pool, tables, ctx, MLA_SCALE,
+                                       MLA_DV)
+
+    with jax.default_matmul_precision("highest"):
+        if kernel == "ragged":
+            text = compile_for_chip(
+                ragged, ((rows, 32, MLA_DK), f32), ((rows, MLA_DK), f32),
+                _latent_pool(f32), ((rows, ), jnp.int32),
+                ((rows, w), jnp.int32), ((rows, ), jnp.int32), donate=(2, ))
+        else:
+            text = compile_for_chip(
+                paged, ((rows, 32, MLA_DK), f32), _latent_pool(f32),
+                ((rows, w), jnp.int32), ((rows, ), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 def test_mla_seams_select_the_kernels(compile_for_chip):
     """`layers/mla_attention.py` through both seams with Pallas forced on
     (what a TPU resolves to): each program holds a kernel."""
@@ -976,11 +1013,13 @@ def test_state_model_programs_compile(compiled_for_chip, one_chip, tmp_path,
 # --- a matrix state a head: the delta rule on the pool, in place ------------
 
 def _matrix_state_program(one_chip, tmp_path, program, config_name,
-                          hf_patch, kinds, state_shapes):
-    """`config_name`'s `mixed` (512 rows) or `decode_cont` (128 rows x
-    64-block tables, K=8) program as its cell dispatches it, bf16 at the
-    published widths with `hf_patch` laid over the config (the depth cut),
-    compiled for the described v5e."""
+                          hf_patch, kinds, state_shapes, rows=CELL_ROWS,
+                          table_width=CELL_TABLE_WIDTH, blocks=CELL_BLOCKS):
+    """`config_name`'s `mixed` (512 rows) or `decode_cont` (`rows` rows x
+    `table_width`-block tables, K=8: 128 x 64 unless the cell's are others)
+    program as its cell dispatches it, bf16 at the published widths with
+    `hf_patch` laid over the config (the depth cut), compiled for the
+    described v5e."""
     import json
 
     from intellillm_tpu.config import (CacheConfig, ModelConfig,
@@ -1007,15 +1046,15 @@ def _matrix_state_program(one_chip, tmp_path, program, config_name,
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         jax.eval_shape(lambda: model.init_random_params(0)))
-    cache_config = CacheConfig(num_device_blocks_override=CELL_BLOCKS,
+    cache_config = CacheConfig(num_device_blocks_override=blocks,
                                swap_space_gib=0.01)
-    cache_config.num_state_slots = CELL_ROWS
+    cache_config.num_state_slots = rows
     set_use_pallas(True)        # jax.default_backend() is "cpu" here
     try:
         runner = ModelRunner(
             model, params, model_config,
             SchedulerConfig(max_num_batched_tokens=512,
-                            max_num_seqs=CELL_ROWS,
+                            max_num_seqs=rows,
                             max_model_len=serve["max_model_len"],
                             enable_chunked_prefill=True),
             cache_config, ParallelConfig())
@@ -1026,18 +1065,18 @@ def _matrix_state_program(one_chip, tmp_path, program, config_name,
         bf16, i32, f32 = jnp.bfloat16, jnp.int32, jnp.float32
         state = tuple(
             sds(jnp.dtype(dtype),
-                *CacheEngine.state_pool_shape(shape, CELL_ROWS))
+                *CacheEngine.state_pool_shape(shape, rows))
             for shape, dtype in model_config.get_state_arrays())
         assert [s.shape for s in state] == state_shapes
         pools, kv_heads, width = model_config.get_cache_row_shape()
         kv = [state if kind == "state" else
-              (sds(bf16, CELL_BLOCKS, kv_heads, 16, width), ) * pools
+              (sds(bf16, blocks, kv_heads, 16, width), ) * pools
               for kind in kinds]
         assert not jax.config.jax_enable_compilation_cache
         if program == "mixed":
             return runner.lower_mixed_step(
-                kv, 512, CELL_TABLE_WIDTH, do_random=False).compile()
-        b, w, k = CELL_ROWS, CELL_TABLE_WIDTH, CELL_K
+                kv, 512, table_width, do_random=False).compile()
+        b, w, k = rows, table_width, CELL_K
         rest = (sds(i32, b, 1), sds(i32, b, w), sds(i32, b),
                 sds(f32, b), sds(i32, b), sds(f32, b), sds(f32, b),
                 sds(jnp.uint32, b), sds(f32, b), sds(f32, b),
@@ -1075,6 +1114,47 @@ def test_matrix_state_model_programs_compile(compiled_for_chip, one_chip,
     assert _row_loops(text, "bf16[128,73728]") == []
     pool_bytes = 129 * 64 * 128 * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+@pytest.mark.parametrize("program", ["mixed", "decode_cont"])
+def test_matrix_state_beside_latent_pool_programs_compile(
+        compiled_for_chip, one_chip, tmp_path, program):
+    """`ling-3.0-flash-ep8-l12`'s programs as its cell dispatches them
+    (`mixed` at 512 rows, `decode_cont` at 16 rows, both at 512-block
+    tables of an 8,192-token model length, K=8, bf16, published widths, 64
+    of 512 experts held; depth cut to a KDA layer over the dense MLP and a
+    latent layer over the experts, by the model's own rule at
+    `layer_group_size` 2) compile for the described v5e with the kernels
+    on: the delta rule's kernel of that program at 32 heads, the filter's,
+    the latent kernel of that program over a pool of 640-lane rows, and
+    the grouped expert kernels (16 rows of 8 choices leave most of 512
+    experts unchosen: the decode program takes the grouped form too). The
+    decode program carries the 36 MB matrix pool through the K substeps
+    without a copy of it: its temporaries stay under one state pool's
+    size beside the staged logits."""
+    compiled = _matrix_state_program(
+        one_chip, tmp_path, program, "ling-3.0-flash-ep8-l12",
+        {"num_hidden_layers": 2, "layer_group_size": 2,
+         "first_k_dense_replace": 1,
+         "expert_swiglu_limit_list": [0, 0],
+         "share_expert_swiglu_limit_list": [0, 0]},
+        ["state", "kv"], [(16, 36864), (17, 32, 128, 128)],
+        rows=16, table_width=512, blocks=16000)
+    text = compiled.as_text()
+    for kernel in (("_kda_chunk_call", "_ragged_call_mla")
+                   if program == "mixed"
+                   else ("_kda_step_call", "_paged_attention_call_mla")):
+        assert kernel in text, kernel
+    assert "_conv_silu_call" in text
+    assert "_grouped_gate_up_call" in text and "_grouped_down_call" in text
+    assert _row_loops(text, "bf16[16,36864]") == []
+    pool_bytes = 17 * 32 * 128 * 128 * 4
+    staged_logits = 16 * 19648 * 4 * CELL_K
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    if program == "decode_cont":
+        assert temps < pool_bytes + staged_logits, temps
+    else:
+        assert temps < 2 * 1024**3, temps
 
 
 @pytest.mark.parametrize("program", ["mixed", "decode_cont"])
